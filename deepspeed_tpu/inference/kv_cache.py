@@ -182,9 +182,9 @@ class PagedKVCache:
     tiles beside the pool — ``[L, NB, KH, BS]`` f32, one symmetric
     amax/127 scale per written (position, head) row (ops/quant_core.py;
     the SwitchBack per-axis idiom), laid out so a Pallas kernel's scale
-    block ``(1, 1, BS)`` puts the block_size positions on the lane dim.
-    Writers quantize on write; readers dequantize in-kernel (VMEM) or
-    at the gather. Scales are DATA in the same donated pytree — tier
+    block ``(1, KH, BS)`` puts the block_size positions on the lane dim.
+    Writers quantize on write; readers apply the scales in-kernel (VMEM)
+    or dequantize at the gather. Scales are DATA in the same donated pytree — tier
     membership and quantization never change a traced signature.
     ``None`` scales = full-precision pool (the default)."""
     k: jnp.ndarray             # [L, NB, BS, H, D] (fp or int8)

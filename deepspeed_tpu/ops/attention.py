@@ -14,14 +14,19 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+from jax.sharding import PartitionSpec as P
+
+from deepspeed_tpu.comm.mesh import (DATA_AXES, get_global_mesh,
+                                     has_global_mesh)
+from deepspeed_tpu.ops.pallas.flash_attention import (
+    DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q, flash_attention)
+from deepspeed_tpu.utils.sharding import map_kernel
 
 
 @functools.lru_cache(maxsize=1)
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def causal_attention_reference(q, k, v, scale=None, causal=True):
@@ -70,18 +75,29 @@ def causal_attention(q, k, v, block_q: int = 0, block_k: int = 0):
     ``save_only_these_names('flash_attn_out')`` (models/gpt2.py).
     """
     if _on_tpu() and q.shape[1] >= 256:
-        try:
-            from deepspeed_tpu.ops.pallas.flash_attention import (
-                DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q, flash_attention)
-        except ImportError:
-            from deepspeed_tpu.utils.logging import warning_once
-            warning_once("pallas flash attention unavailable; falling back to "
-                         "O(T^2) reference attention")
-        else:
-            from jax.ad_checkpoint import checkpoint_name
-            return checkpoint_name(
-                flash_attention(q, k, v, causal=True,
-                                block_q=block_q or DEFAULT_BLOCK_Q,
-                                block_k=block_k or DEFAULT_BLOCK_K),
-                "flash_attn_out")
+        kernel = functools.partial(
+            flash_attention, causal=True,
+            block_q=block_q or DEFAULT_BLOCK_Q,
+            block_k=block_k or DEFAULT_BLOCK_K)
+        return checkpoint_name(_over_global_mesh(kernel, q, k, v),
+                               "flash_attn_out")
     return causal_attention_reference(q, k, v)
+
+
+def _over_global_mesh(kernel, q, k, v):
+    """Run the flash kernel under the engine's mesh (utils/sharding.py
+    ``map_kernel``): batch over the data axes, heads over ``tensor``,
+    wherever they divide — an axis that does not divide stays
+    replicated. Inside an already-manual region (ring/Ulysses SP, the
+    explicit-DP steps) the caller owns the mapping and the kernel runs
+    as is."""
+    mesh = get_global_mesh() if has_global_mesh() else None
+    ctx = jax.sharding.get_abstract_mesh()
+    if mesh is None or (not ctx.empty and ctx.manual_axes):
+        return kernel(q, k, v)
+    dp = mesh.shape["data"] * mesh.shape["fsdp"]
+    tp = mesh.shape["tensor"]
+    batch = DATA_AXES if q.shape[0] % dp == 0 else None
+    heads = "tensor" if q.shape[2] % tp == k.shape[2] % tp == 0 else None
+    spec = P(batch, None, heads, None)
+    return map_kernel(kernel, mesh, (spec, spec, spec), spec)(q, k, v)

@@ -6,8 +6,10 @@ fused SIMD step (csrc/cpu_adam.cpp) updates them in place and emits the
 bf16 copy-back buffer that is pushed to the TPU — the ``fp16_param_groups``
 overlapped-copy path of the reference (``cpu_adam.py:117``).
 
-Falls back to a pure-numpy step when no C++ toolchain exists (the analog of
-``is_compatible()`` gating).
+``use_native=True`` (the default) builds the C++ op on first use and
+raises if it cannot — a missing compiler is an error, never a quiet
+numpy run two orders of magnitude slower. ``use_native=False`` asks for
+the pure-numpy definition (the tests' oracle).
 """
 from __future__ import annotations
 
@@ -17,7 +19,6 @@ from typing import Any, Dict, Optional
 import numpy as np
 
 from deepspeed_tpu.ops.op_builder import CPUAdamBuilder
-from deepspeed_tpu.utils.logging import logger
 
 
 def _as_f32p(a: np.ndarray):
@@ -41,15 +42,7 @@ class DeepSpeedCPUAdam:
         self.weight_decay = weight_decay
         self.adamw_mode = adamw_mode
         self.step_count = 0
-        self._lib = None
-        if use_native:
-            builder = CPUAdamBuilder()
-            if builder.is_compatible():
-                try:
-                    self._lib = builder.load()
-                except RuntimeError as e:
-                    logger.warning(f"cpu_adam native build failed ({e}); "
-                                   "using numpy fallback")
+        self._lib = CPUAdamBuilder().load() if use_native else None
 
     @property
     def native(self) -> bool:
@@ -110,14 +103,7 @@ class DeepSpeedCPUAdagrad:
         self.lr = lr
         self.eps = eps
         self.weight_decay = weight_decay
-        self._lib = None
-        if use_native:
-            builder = CPUAdamBuilder()
-            if builder.is_compatible():
-                try:
-                    self._lib = builder.load()
-                except RuntimeError:
-                    pass
+        self._lib = CPUAdamBuilder().load() if use_native else None
 
     def init_state(self, master):
         return {k: {"h": np.zeros_like(v)} for k, v in master.items()}

@@ -2,24 +2,31 @@
 
 TPU-native analog of the reference's fused ``softmax_context`` kernel
 (``csrc/transformer/inference/csrc/pt_binding.cpp:1701-1740`` /
-``softmax.cu``), which attends one new token against the accumulated KV
-cache each generation step. The kernel streams K/V blocks for one
-(batch, kv-head) through VMEM with the online-softmax recurrence and
-masks positions beyond the live cache length — no [S] probability vector
-ever round-trips HBM, and dead cache tail costs nothing (the loop bound
-comes from the scalar-prefetched lengths).
+``softmax.cu``), which attends new tokens against the accumulated KV
+cache each generation step. ONE kernel body serves the whole decode
+family — dense one-token decode, paged decode, paged speculative verify
+and paged chunked prefill differ only in how many query tokens a slot
+carries and where its causal bound starts, both of which ride as data.
 
-Decode is KV-bandwidth-bound, so the kernel consumes the cache in its
-STORAGE layout ``[B, S, KH, D]`` (kv_cache.py) directly — r3 transposed
-to [B, KH, S, D] before every call, a full cache read+write per token
-per layer that roughly doubled decode HBM traffic. Grouped-query
-attention is native: the grid is (batch, kv-head) and each program
-attends that head's whole query group ``[R, D]`` against one K/V stream,
-so GQA's bandwidth saving survives into decode (r3 fell back to an XLA
-path that materialized the cache repeated to H heads).
+The kernel streams K/V blocks through VMEM with the online-softmax
+recurrence — no probability vector ever round-trips HBM. It consumes the
+cache in its STORAGE layout ``[NB, BS, KH, D]`` (kv_cache.py) with no
+transpose: a pool block is DMA'd whole, as the contiguous
+``[BS, KH*D]`` slab it is in HBM, and the kv heads are walked inside the
+kernel as static lane slices of that slab. (The TPU lowering only takes
+blocks whose last two dims are tile-aligned or span the array, so a
+block cannot pick one kv head out of ``KH`` — the per-head block of
+the earlier layout was refused by the chip's compiler.) Grouped-query
+attention is native: each kv head's slice is attended by its whole query
+group ``[rows, D]`` at once, so GQA's bandwidth saving survives.
 
-Layout: q ``[B, H, D]`` (one query token per sequence, H = KH·R),
-cache ``[B, S, KH, D]``, ``lengths [B]``.
+int8 pools (kv_cache_dtype: "int8", docs/serving.md "KV quantization &
+host tiering") add per-block-per-head scale tiles ``[NB, KH, BS]`` (one
+amax/127 scale per written (position, head) row, block_size on the LANE
+dim). The HBM stream is the int8 bytes; the scales are applied in VMEM
+as ``[1, BS]`` rows against the score / probability matrices
+(``(q·kᵀ)·s_k`` and ``(p·s_v)·v`` — algebraically the dequantized
+product, without ever turning a scale row into a column).
 """
 from __future__ import annotations
 
@@ -32,24 +39,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 DEFAULT_BLOCK_K = 256
-
-# int8 paged pools (kv_cache_dtype: "int8", docs/serving.md "KV
-# quantization & host tiering"): the paged kernels take the pool in its
-# quantized storage layout plus per-block-per-head scale tiles
-# ``[NB, KH, BS]`` (one amax/127 scale per written (position, head) row,
-# block_size on the LANE dim so the scale block ``(1, 1, BS)`` loads
-# contiguous lanes). Dequantization happens on the tile already in VMEM
-# (int8 load * f32 scale), so the HBM stream is the int8 bytes — the
-# whole point: decode is KV-bandwidth-bound and the cache just halved.
-
-
-def _deq_tile(x_ref, s_ref, quantized: bool):
-    """One K/V tile ``[BS, D]`` in f32 — int8 tiles multiply by their
-    ``[BS]`` scale column in VMEM; fp tiles just upcast."""
-    x = x_ref[0, :, 0, :].astype(jnp.float32)
-    if quantized:
-        x = x * s_ref[0, 0, :][:, None]
-    return x
+# query rows (tokens x group size) one grid step keeps resident per kv
+# head: bounds the q/out blocks and the online-softmax scratch in VMEM
+# (~7 MiB at KH=16, D=128) however long a prefill chunk is
+MAX_QUERY_ROWS = 128
 
 
 def _dequant_pools(k_pool, v_pool, k_scale, v_scale):
@@ -65,121 +58,26 @@ def _dequant_pools(k_pool, v_pool, k_scale, v_scale):
     return k, v
 
 
-def _scale_specs(quantized: bool, BS: int, index_map):
-    """The two extra in_specs an int8 pool adds (k_scale, v_scale) —
-    empty for fp, so the fp kernel signature is byte-identical to the
-    pre-quantization one."""
-    if not quantized:
-        return []
-    spec = pl.BlockSpec((1, 1, BS), index_map)
-    return [spec, spec]
-
-
-def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *, block_k: int,
-                   scale: float):
-    b = pl.program_id(0)
-    length = len_ref[b]
-    q = q_ref[0, 0].astype(jnp.float32) * scale  # [R, D]
-    R = q.shape[0]
-
-    m = jnp.full((R, 1), NEG_INF, jnp.float32)
-    l = jnp.zeros((R, 1), jnp.float32)
-    acc = jnp.zeros((R, q.shape[-1]), jnp.float32)
-
-    num_kb = pl.cdiv(length, block_k)
-
-    def body(kb, carry):
-        m, l, acc = carry
-        # cache-native block [BK, D] (dim 2 of the [1, S, 1, D] ref is
-        # the kv-head singleton selected by the index map)
-        k = k_ref[0, pl.ds(kb * block_k, block_k), 0, :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(kb * block_k, block_k), 0, :].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # [R,BK]
-        col = kb * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (R, block_k), 1)
-        s = jnp.where(col < length, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_new = acc * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return m_new, l_new, acc_new
-
-    m, l, acc = jax.lax.fori_loop(0, num_kb, body, (m, l, acc))
-    o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-
-
-def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-                     lengths: jax.Array,
-                     block_k: int = DEFAULT_BLOCK_K,
-                     scale: float | None = None,
-                     interpret: bool | None = None) -> jax.Array:
-    """One-token attention against the cache, GQA-native.
-
-    q: ``[B, H, D]``; k_cache/v_cache: ``[B, S, KH, D]`` (the kv_cache.py
-    storage layout — no transpose) with ``H % KH == 0``; lengths: ``[B]``
-    int32 live lengths (query attends positions ``< lengths[b]``).
-    Returns ``[B, H, D]``.
-    """
-    B, H, D = q.shape
-    S, KH = k_cache.shape[1], k_cache.shape[2]
-    if H % KH:
-        raise ValueError(f"q heads {H} not divisible by kv heads {KH}")
-    R = H // KH
-    if scale is None:
-        scale = 1.0 / (D ** 0.5)
-    block_k = min(block_k, S)
-    if S % block_k:
-        raise ValueError(f"cache size {S} not divisible by block_k {block_k}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-
-    # [B, H, D] -> [B, KH, R, D]: group queries by the kv head they read
-    qg = q.reshape(B, KH, R, D)
-    kernel = functools.partial(_decode_kernel, block_k=block_k,
-                               scale=float(scale))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, KH),
-        in_specs=[
-            pl.BlockSpec((1, 1, R, D), lambda b, h, lens: (b, h, 0, 0)),
-            pl.BlockSpec((1, S, 1, D), lambda b, h, lens: (b, 0, h, 0)),
-            pl.BlockSpec((1, S, 1, D), lambda b, h, lens: (b, 0, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, R, D),
-                               lambda b, h, lens: (b, h, 0, 0)),
-    )
-    og = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KH, R, D), q.dtype),
-        interpret=interpret,
-    )(lengths.astype(jnp.int32), qg, k_cache, v_cache)
-    return og.reshape(B, H, D)
-
-
-def _paged_decode_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, *rest,
-                         block_size: int, scale: float, quantized: bool):
-    """Grid (slot, kv-head, block-table entry). The index maps gather K/V
-    blocks straight out of the global pool through the scalar-prefetched
-    block table — the kernel body only ever sees one ``[BS, D]`` block at
-    logical position ``i*BS``, so no per-slot contiguous cache is ever
-    materialized in HBM. Online-softmax state carries across the block
-    dimension in VMEM scratch (the block axis is innermost, so one
-    (slot, head) program's blocks run back-to-back on the core). An int8
-    pool streams two extra ``[1, 1, BS]`` scale tiles per block and
-    dequantizes in VMEM (:func:`_deq_tile`)."""
+def _paged_kernel(base_ref, bt_ref, q_ref, k_ref, v_ref, *rest,
+                  block_size: int, head_dim: int, rep: int, span: int,
+                  scale: float, quantized: bool):
+    """Grid (slot, query-row block, block-table entry). The index maps
+    gather whole K/V pool blocks through the scalar-prefetched block
+    table, so no per-slot contiguous cache is ever materialized in HBM.
+    Query rows are (token, group member) pairs, token-major, ``span``
+    tokens per row block; key position ``col`` is visible to the row's
+    token ``t`` iff ``col <= base[slot] + t``. Online-softmax state
+    carries across the (innermost) table axis in VMEM scratch, one
+    ``[rows, ·]`` plane per kv head."""
     if quantized:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
         ks_ref = vs_ref = None
         o_ref, m_ref, l_ref, acc_ref = rest
-    s, i = pl.program_id(0), pl.program_id(2)
-    length = len_ref[s]
+    s, rb, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     nb = pl.num_programs(2)
+    KH, rows = q_ref.shape[1], q_ref.shape[2]
+    base = base_ref[s] + rb * span     # bound of this block's first token
 
     @pl.when(i == 0)
     def _init():
@@ -187,31 +85,146 @@ def _paged_decode_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, *rest,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(i * block_size < length)
+    # blocks wholly beyond the last query's bound are dead for every row
+    @pl.when(i * block_size <= base + span - 1)
     def _update():
-        q = q_ref[0, 0].astype(jnp.float32) * scale      # [R, D]
-        R = q.shape[0]
-        k = _deq_tile(k_ref, ks_ref, quantized)          # [BS, D]
-        v = _deq_tile(v_ref, vs_ref, quantized)
-        sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
         col = i * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (R, block_size), 1)
-        sc = jnp.where(col < length, sc, NEG_INF)
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
-        p = jnp.exp(sc - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        m_ref[...] = m_new
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            jnp.int32, (rows, block_size), 1)
+        bound = base
+        if span > 1:
+            bound = base + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, block_size), 0) // rep
+        visible = col <= bound
+        for h in range(KH):
+            lanes = slice(h * head_dim, (h + 1) * head_dim)
+            q = q_ref[0, h].astype(jnp.float32) * scale    # [rows, D]
+            k = k_ref[0, :, lanes].astype(jnp.float32)     # [BS, D]
+            v = v_ref[0, :, lanes].astype(jnp.float32)
+            sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            if quantized:
+                sc = sc * ks_ref[0, h:h + 1, :]
+            sc = jnp.where(visible, sc, NEG_INF)
+            m_prev, l_prev = m_ref[h], l_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+            p = jnp.exp(sc - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            m_ref[h] = m_new
+            l_ref[h] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            if quantized:
+                p = p * vs_ref[0, h:h + 1, :]
+            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
     @pl.when(i == nb - 1)
     def _finish():
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] /
+                    jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+
+
+def _paged_attention(qg, k_pool, v_pool, block_tables, base, *, rep: int,
+                     scale, interpret, k_scale=None, v_scale=None):
+    """The decode family's one ``pallas_call``. qg ``[S, KH, T*rep, D]``
+    (each slot's T query tokens x ``rep`` group members, token-major,
+    grouped by the kv head they read); pools ``[NB, BS, KH, D]``;
+    block_tables ``[S, MB]`` (dead entries must be valid ids — the null
+    block); base ``[S]``: slot s's token t sees key positions
+    ``<= base[s] + t``. Returns ``[S, KH, T*rep, D]``."""
+    S, KH, rows, D = qg.shape
+    NB, BS = k_pool.shape[0], k_pool.shape[1]
+    MB = block_tables.shape[1]
+    quantized = k_scale is not None
+    if (k_pool.dtype == jnp.int8) != quantized:
+        raise ValueError("int8 pools require k_scale/v_scale (and fp "
+                         "pools must not pass them)")
+    if scale is None:
+        scale = 1.0 / (D ** 0.5)
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    # tokens per row block: halve while the rows overrun the VMEM budget
+    # and the halves still tile (a split block's sublane dim must be a
+    # multiple of 8)
+    span = rows // rep
+    while (span * rep > MAX_QUERY_ROWS and span % 2 == 0
+           and (span // 2 * rep) % 8 == 0):
+        span //= 2
+    rblk = span * rep
+
+    def kv_map(s, rb, i, base, bt):
+        # dead table entries re-name the slot's last live block: an
+        # unchanged block index skips the DMA, so the dead tail of a
+        # table costs neither bandwidth nor (pl.when above) compute
+        last = jnp.maximum(base[s] + (rb + 1) * span - 1, 0) // BS
+        return (bt[s, jnp.minimum(i, last)], 0, 0)
+
+    def q_map(s, rb, i, base, bt):
+        return (s, 0, rb, 0)
+
+    kv_spec = pl.BlockSpec((1, BS, KH * D), kv_map)
+    in_specs = [pl.BlockSpec((1, KH, rblk, D), q_map), kv_spec, kv_spec]
+    args = [base.astype(jnp.int32), block_tables.astype(jnp.int32), qg,
+            k_pool.reshape(NB, BS, KH * D), v_pool.reshape(NB, BS, KH * D)]
+    if quantized:
+        in_specs += [pl.BlockSpec((1, KH, BS), kv_map)] * 2
+        args += [k_scale, v_scale]
+    kernel = functools.partial(
+        _paged_kernel, block_size=BS, head_dim=D, rep=rep, span=span,
+        scale=float(scale), quantized=quantized)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(S, rows // rblk, MB),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, KH, rblk, D), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((KH, rblk, 1), jnp.float32),
+            pltpu.VMEM((KH, rblk, 1), jnp.float32),
+            pltpu.VMEM((KH, rblk, D), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, KH, rows, D), qg.dtype),
+        interpret=interpret,
+    )(*args)
+
+
+def _group_size(H: int, KH: int) -> int:
+    if H % KH:
+        raise ValueError(f"q heads {H} not divisible by kv heads {KH}")
+    return H // KH
+
+
+def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
+                     lengths: jax.Array,
+                     block_k: int = DEFAULT_BLOCK_K,
+                     scale: float | None = None,
+                     interpret: bool | None = None) -> jax.Array:
+    """One-token attention against the dense cache, GQA-native.
+
+    q: ``[B, H, D]``; k_cache/v_cache: ``[B, S, KH, D]`` (the kv_cache.py
+    storage layout — no transpose) with ``H % KH == 0``; lengths: ``[B]``
+    int32 live lengths (query attends positions ``< lengths[b]``).
+    Returns ``[B, H, D]``. A dense cache IS a paged pool whose block
+    table is the identity: row b's ``S // block_k`` blocks sit back to
+    back, so the reshape below is free and the paged kernel runs as is.
+    """
+    B, H, D = q.shape
+    S, KH = k_cache.shape[1], k_cache.shape[2]
+    R = _group_size(H, KH)
+    block_k = min(block_k, S)
+    if S % block_k:
+        raise ValueError(f"cache size {S} not divisible by block_k {block_k}")
+    nb = S // block_k
+    tables = jnp.arange(B * nb, dtype=jnp.int32).reshape(B, nb)
+    og = _paged_attention(
+        q.reshape(B, KH, R, D),
+        k_cache.reshape(B * nb, block_k, KH, D),
+        v_cache.reshape(B * nb, block_k, KH, D),
+        tables, lengths.astype(jnp.int32) - 1, rep=R, scale=scale,
+        interpret=interpret)
+    return og.reshape(B, H, D)
 
 
 def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
@@ -227,124 +240,22 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     ``[NB, BS, KH, D]`` (the PagedKVCache per-layer pool layout);
     block_tables: ``[S, MB]`` int32 (entry j covers logical positions
     ``j*BS..(j+1)*BS-1``; dead entries must be valid ids — the null
-    block); lengths: ``[S]`` int32 live lengths. Returns ``[S, H, D]``.
+    block); lengths: ``[S]`` int32 live lengths (the query attends
+    positions ``< lengths[s]``). Returns ``[S, H, D]``.
 
-    int8 pools pass ``k_scale``/``v_scale`` ``[NB, KH, BS]`` and the
-    kernel dequantizes each tile in VMEM — the grid, scratch, and
-    online-softmax recurrence are unchanged (scales are two more
-    streamed inputs, not a new program structure).
-
-    Entirely-dead blocks (``i*BS >= lengths[s]``) are skipped by a
-    ``pl.when`` guard, so an idle slot costs no VPU/MXU work beyond its
-    DMA stream.
+    int8 pools pass ``k_scale``/``v_scale`` ``[NB, KH, BS]``; the grid,
+    scratch and recurrence are unchanged (scales are two more streamed
+    inputs, not a new program structure). An idle slot (length 0) costs
+    no compute and one null-block DMA.
     """
     S, H, D = q.shape
-    NB, BS, KH = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
-    MB = block_tables.shape[1]
-    if H % KH:
-        raise ValueError(f"q heads {H} not divisible by kv heads {KH}")
-    quantized = k_scale is not None
-    if (k_pool.dtype == jnp.int8) != quantized:
-        raise ValueError("int8 pools require k_scale/v_scale (and fp "
-                         "pools must not pass them)")
-    R = H // KH
-    if scale is None:
-        scale = 1.0 / (D ** 0.5)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-
-    qg = q.reshape(S, KH, R, D)
-    kernel = functools.partial(_paged_decode_kernel, block_size=BS,
-                               scale=float(scale), quantized=quantized)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(S, KH, MB),
-        in_specs=[
-            pl.BlockSpec((1, 1, R, D), lambda s, h, i, lens, bt:
-                         (s, h, 0, 0)),
-            pl.BlockSpec((1, BS, 1, D), lambda s, h, i, lens, bt:
-                         (bt[s, i], 0, h, 0)),
-            pl.BlockSpec((1, BS, 1, D), lambda s, h, i, lens, bt:
-                         (bt[s, i], 0, h, 0)),
-        ] + _scale_specs(quantized, BS, lambda s, h, i, lens, bt:
-                         (bt[s, i], h, 0)),
-        out_specs=pl.BlockSpec((1, 1, R, D), lambda s, h, i, lens, bt:
-                               (s, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((R, 1), jnp.float32),
-            pltpu.VMEM((R, 1), jnp.float32),
-            pltpu.VMEM((R, D), jnp.float32),
-        ],
-    )
-    args = [lengths.astype(jnp.int32), block_tables.astype(jnp.int32),
-            qg, k_pool, v_pool]
-    if quantized:
-        args += [k_scale, v_scale]
-    og = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, KH, R, D), q.dtype),
-        interpret=interpret,
-    )(*args)
+    KH = k_pool.shape[2]
+    R = _group_size(H, KH)
+    og = _paged_attention(
+        q.reshape(S, KH, R, D), k_pool, v_pool, block_tables,
+        lengths.astype(jnp.int32) - 1, rep=R, scale=scale,
+        interpret=interpret, k_scale=k_scale, v_scale=v_scale)
     return og.reshape(S, H, D)
-
-
-def _paged_chunk_kernel(start_ref, bt_ref, q_ref, k_ref, v_ref, *rest,
-                        block_size: int, rep: int, scale: float,
-                        quantized: bool):
-    """Chunked-prefill attention for ONE slot: grid (kv-head,
-    block-table entry). Queries are the in-flight C-token chunk at
-    absolute positions ``start..start+C-1``; keys stream out of the
-    paged pool through the scalar-prefetched block table, so the chunk
-    attends over the already-resident prefix (earlier chunks AND
-    prefix-cache hits) plus itself without ever materializing a
-    contiguous per-slot cache. Per-query causal bound: key position
-    ``col`` is visible to chunk query ``qi`` iff ``col <= start + qi``.
-    Online-softmax carry in VMEM scratch across the (innermost) block
-    axis — the same recurrence as :func:`_paged_decode_kernel`, with
-    the query dim widened from one token's head group to C·R rows."""
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        ks_ref = vs_ref = None
-        o_ref, m_ref, l_ref, acc_ref = rest
-    i = pl.program_id(1)
-    nb = pl.num_programs(1)
-    start = start_ref[0]
-    CR = q_ref.shape[1]
-
-    @pl.when(i == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    # blocks wholly beyond the chunk's last query are dead for every row
-    @pl.when(i * block_size <= start + CR // rep - 1)
-    def _update():
-        q = q_ref[0].astype(jnp.float32) * scale         # [CR, D]
-        k = _deq_tile(k_ref, ks_ref, quantized)          # [BS, D]
-        v = _deq_tile(v_ref, vs_ref, quantized)
-        sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        col = i * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (CR, block_size), 1)
-        qi = jax.lax.broadcasted_iota(jnp.int32, (CR, block_size), 0) // rep
-        sc = jnp.where(col <= start + qi, sc, NEG_INF)
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
-        p = jnp.exp(sc - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        m_ref[...] = m_new
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when(i == nb - 1)
-    def _finish():
-        o_ref[0] = (acc_ref[...] /
-                    jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def paged_chunk_attention(q: jax.Array, k_pool: jax.Array,
@@ -362,118 +273,15 @@ def paged_chunk_attention(q: jax.Array, k_pool: jax.Array,
     into the pool); k_pool/v_pool: ``[NB, BS, KH, D]``; block_table:
     ``[MB]`` int32 (the prefilling slot's row; dead entries must be
     valid ids — the null block); start: scalar int32, block-aligned.
-    int8 pools pass ``k_scale``/``v_scale`` ``[NB, KH, BS]`` (VMEM
-    dequant, same grid). Returns ``[C, H, D]``.
+    The chunk attends the already-resident prefix (earlier chunks AND
+    prefix-cache hits) plus itself: key position ``col`` is visible to
+    chunk query ``qi`` iff ``col <= start + qi``. int8 pools pass
+    ``k_scale``/``v_scale`` ``[NB, KH, BS]``. Returns ``[C, H, D]``.
     """
-    C, H, D = q.shape
-    BS, KH = k_pool.shape[1], k_pool.shape[2]
-    MB = block_table.shape[0]
-    if H % KH:
-        raise ValueError(f"q heads {H} not divisible by kv heads {KH}")
-    quantized = k_scale is not None
-    if (k_pool.dtype == jnp.int8) != quantized:
-        raise ValueError("int8 pools require k_scale/v_scale (and fp "
-                         "pools must not pass them)")
-    R = H // KH
-    if scale is None:
-        scale = 1.0 / (D ** 0.5)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-
-    # [C, H, D] -> [KH, C*R, D]: rows grouped by the kv head they read,
-    # query index recoverable in-kernel as row // R
-    qg = q.reshape(C, KH, R, D).transpose(1, 0, 2, 3).reshape(KH, C * R, D)
-    kernel = functools.partial(_paged_chunk_kernel, block_size=BS,
-                               rep=R, scale=float(scale),
-                               quantized=quantized)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(KH, MB),
-        in_specs=[
-            pl.BlockSpec((1, C * R, D), lambda h, i, st, bt: (h, 0, 0)),
-            pl.BlockSpec((1, BS, 1, D), lambda h, i, st, bt:
-                         (bt[i], 0, h, 0)),
-            pl.BlockSpec((1, BS, 1, D), lambda h, i, st, bt:
-                         (bt[i], 0, h, 0)),
-        ] + _scale_specs(quantized, BS, lambda h, i, st, bt:
-                         (bt[i], h, 0)),
-        out_specs=pl.BlockSpec((1, C * R, D), lambda h, i, st, bt:
-                               (h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((C * R, 1), jnp.float32),
-            pltpu.VMEM((C * R, 1), jnp.float32),
-            pltpu.VMEM((C * R, D), jnp.float32),
-        ],
-    )
-    args = [jnp.reshape(start, (1,)).astype(jnp.int32),
-            block_table.astype(jnp.int32), qg, k_pool, v_pool]
-    if quantized:
-        args += [k_scale, v_scale]
-    og = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((KH, C * R, D), q.dtype),
-        interpret=interpret,
-    )(*args)
-    return og.reshape(KH, C, R, D).transpose(1, 0, 2, 3).reshape(C, H, D)
-
-
-def _paged_verify_kernel(len_ref, bt_ref, q_ref, k_ref, v_ref, *rest,
-                         block_size: int, rep: int, spec: int,
-                         scale: float, quantized: bool):
-    """Speculative-verify attention for ALL slots: grid (slot, kv-head,
-    block-table entry). Queries are each slot's K-token candidate chunk
-    at absolute positions ``lengths[s]..lengths[s]+K-1`` (the chunk's
-    own k/v already written into the pool at those positions —
-    kv_cache.paged_write_tokens); keys stream out of the pool through
-    the scalar-prefetched block table, per-query causal bound
-    ``col <= lengths[s] + qi``. The same online-softmax recurrence as
-    :func:`_paged_chunk_kernel`, with the per-slot ``lengths`` playing
-    the chunk kernel's ``start`` role — so varying acceptance lengths
-    ride as data, never as a new signature."""
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        ks_ref = vs_ref = None
-        o_ref, m_ref, l_ref, acc_ref = rest
-    s, i = pl.program_id(0), pl.program_id(2)
-    nb = pl.num_programs(2)
-    length = len_ref[s]
-    KR = q_ref.shape[2]
-
-    @pl.when(i == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    # blocks wholly beyond the chunk's last query position are dead for
-    # every row of this slot
-    @pl.when(i * block_size <= length + spec - 1)
-    def _update():
-        q = q_ref[0, 0].astype(jnp.float32) * scale      # [K*R, D]
-        k = _deq_tile(k_ref, ks_ref, quantized)          # [BS, D]
-        v = _deq_tile(v_ref, vs_ref, quantized)
-        sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        col = i * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (KR, block_size), 1)
-        qi = jax.lax.broadcasted_iota(jnp.int32, (KR, block_size), 0) // rep
-        sc = jnp.where(col <= length + qi, sc, NEG_INF)
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
-        p = jnp.exp(sc - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        m_ref[...] = m_new
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when(i == nb - 1)
-    def _finish():
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+    return paged_verify_attention(
+        q[None], k_pool, v_pool, block_table[None],
+        jnp.reshape(start, (1,)), scale=scale, interpret=interpret,
+        k_scale=k_scale, v_scale=v_scale)[0]
 
 
 def paged_verify_attention(q: jax.Array, k_pool: jax.Array,
@@ -488,68 +296,27 @@ def paged_verify_attention(q: jax.Array, k_pool: jax.Array,
 
     q: ``[S, K, H, D]`` (each slot's K-token candidate chunk at
     absolute positions ``lengths[s]..lengths[s]+K-1``; the chunk's own
-    k/v must already be written into the pool); k_pool/v_pool:
-    ``[NB, BS, KH, D]``; block_tables: ``[S, MB]`` int32 (dead entries
-    must be valid ids — the null block); lengths: ``[S]`` int32 live
-    lengths per slot. Returns ``[S, K, H, D]``.
+    k/v must already be written into the pool —
+    kv_cache.paged_write_tokens); k_pool/v_pool: ``[NB, BS, KH, D]``;
+    block_tables: ``[S, MB]`` int32 (dead entries must be valid ids —
+    the null block); lengths: ``[S]`` int32 live lengths per slot.
+    Per-query causal bound ``col <= lengths[s] + qi``. Returns
+    ``[S, K, H, D]``.
 
     ONE kernel signature per ``(K, num_slots, block geometry)`` —
     per-slot acceptance state rides in ``lengths``, so varying
     acceptance never retraces (the PR-8 trace-discipline contract).
-    int8 pools pass ``k_scale``/``v_scale`` ``[NB, KH, BS]`` (VMEM
-    dequant, same grid)."""
+    int8 pools pass ``k_scale``/``v_scale`` ``[NB, KH, BS]``."""
     S, K, H, D = q.shape
-    BS, KH = k_pool.shape[1], k_pool.shape[2]
-    MB = block_tables.shape[1]
-    if H % KH:
-        raise ValueError(f"q heads {H} not divisible by kv heads {KH}")
-    quantized = k_scale is not None
-    if (k_pool.dtype == jnp.int8) != quantized:
-        raise ValueError("int8 pools require k_scale/v_scale (and fp "
-                         "pools must not pass them)")
-    R = H // KH
-    if scale is None:
-        scale = 1.0 / (D ** 0.5)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-
+    KH = k_pool.shape[2]
+    R = _group_size(H, KH)
     # [S, K, H, D] -> [S, KH, K*R, D]: rows grouped by the kv head they
     # read, query index recoverable in-kernel as row // R
     qg = q.reshape(S, K, KH, R, D).transpose(0, 2, 1, 3, 4).reshape(
         S, KH, K * R, D)
-    kernel = functools.partial(_paged_verify_kernel, block_size=BS,
-                               rep=R, spec=K, scale=float(scale),
-                               quantized=quantized)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(S, KH, MB),
-        in_specs=[
-            pl.BlockSpec((1, 1, K * R, D), lambda s, h, i, lens, bt:
-                         (s, h, 0, 0)),
-            pl.BlockSpec((1, BS, 1, D), lambda s, h, i, lens, bt:
-                         (bt[s, i], 0, h, 0)),
-            pl.BlockSpec((1, BS, 1, D), lambda s, h, i, lens, bt:
-                         (bt[s, i], 0, h, 0)),
-        ] + _scale_specs(quantized, BS, lambda s, h, i, lens, bt:
-                         (bt[s, i], h, 0)),
-        out_specs=pl.BlockSpec((1, 1, K * R, D), lambda s, h, i, lens, bt:
-                               (s, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((K * R, 1), jnp.float32),
-            pltpu.VMEM((K * R, 1), jnp.float32),
-            pltpu.VMEM((K * R, D), jnp.float32),
-        ],
-    )
-    args = [lengths.astype(jnp.int32), block_tables.astype(jnp.int32),
-            qg, k_pool, v_pool]
-    if quantized:
-        args += [k_scale, v_scale]
-    og = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, KH, K * R, D), q.dtype),
-        interpret=interpret,
-    )(*args)
+    og = _paged_attention(qg, k_pool, v_pool, block_tables, lengths,
+                          rep=R, scale=scale, interpret=interpret,
+                          k_scale=k_scale, v_scale=v_scale)
     return og.reshape(S, KH, K, R, D).transpose(0, 2, 1, 3, 4).reshape(
         S, K, H, D)
 

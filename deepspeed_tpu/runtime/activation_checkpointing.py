@@ -163,6 +163,12 @@ def checkpoint(function, *args):
                     jax.default_backend())
                 _WARNED_CPU_FALLBACK = True
         else:
+            # NOT RUNNING on today's installation (jax 0.9.0, libtpu
+            # 0.0.34): compiled for a described v5e, every form of this
+            # host stash is refused with "Tensor which is moved to host
+            # ... is returned from the entry computation but the layout
+            # for this output is not set to host memory" (PERF.md,
+            # section 7). The refusal is loud, at compile time.
             mesh = get_global_mesh()
 
             def spec(x):

@@ -127,7 +127,7 @@ def _rng_key_meta(engine):
     if rng is None:
         return None
     try:
-        if hasattr(jax.random, "key_data") and _is_typed_prng_key(rng):
+        if _is_typed_prng_key(rng):
             data = np.asarray(jax.random.key_data(rng))
             return {"data": data.astype(np.uint32).tolist(),
                     "impl": str(jax.random.key_impl(rng))}

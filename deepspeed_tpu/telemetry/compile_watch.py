@@ -22,10 +22,13 @@ the engines' step/decode/prefill closures) so that every (re)trace is:
 The AOT path manages its own executable cache (one ``Compiled`` per
 signature) instead of re-entering ``jax.jit`` dispatch — that is what
 makes compile time exact (no first-execution pollution) and the cost
-analysis free (no second compile). If AOT ever fails (jax API drift, a
-placement corner the cache key is too coarse for), the wrapper degrades
-to plain jit dispatch for that signature and keeps serving — the watch
-must never break the engine it watches.
+analysis free (no second compile). A compile error raises from the call
+that asked for the program: there is no retry through plain dispatch,
+which would compile a second time and surface the same refusal from
+another frame. The one call the AOT path cannot serve is a call under an
+outer trace (``jax.eval_shape`` / ``jit`` / ``grad`` around a watched
+function): a ``Compiled`` takes no tracers, so such a call goes to the
+plain ``jax.jit`` and is inlined into the outer program, unrecorded.
 
 Hot-path cost: the cache-hit path is one C-level ``tree_flatten`` plus
 an O(leaves) python key build and the AOT ``Compiled.__call__``
@@ -92,8 +95,6 @@ def executable_cost(compiled) -> Dict[str, float]:
     c: Any = {}
     try:
         c = compiled.cost_analysis() or {}
-        if isinstance(c, (list, tuple)):   # older jax returns [dict]
-            c = c[0] if c else {}
     except Exception:  # noqa: BLE001 — cost analysis is best-effort
         c = {}
     out = {"flops": float(c.get("flops", 0.0)),
@@ -121,8 +122,6 @@ class ExecutableRecord:
     compile_seconds: float
     cost: Dict[str, float]
     calls: int = 0
-    degraded: bool = False             # AOT failed; plain jit dispatch
-    succeeded: bool = False            # executable has run at least once
     compiled: Any = None
 
 
@@ -311,20 +310,13 @@ class WatchedFunction:
                      "(the silent-stall regression — see "
                      "docs/observability.md)",
                 labels={"fn": self.name}).inc()
-        compiled, degraded = None, False
-        t0 = time.perf_counter()
-        try:
-            compiled = self._jit.lower(*args, **kwargs).compile()
-        except Exception:  # noqa: BLE001 — AOT drift degrades, never breaks
-            degraded = True
+        t0 = time.perf_counter()   # a compile error raises from here
+        compiled = self._jit.lower(*args, **kwargs).compile()
         dt = time.perf_counter() - t0
-        cost = (executable_cost(compiled) if compiled is not None
-                else {"flops": 0.0, "bytes_accessed": 0.0,
-                      "hbm_bytes": 0.0})
+        cost = executable_cost(compiled)
         rec = ExecutableRecord(
             index=len(self._records), summary=summary, leaves=leaves,
-            compile_seconds=dt, cost=cost, degraded=degraded,
-            compiled=compiled)
+            compile_seconds=dt, cost=cost, compiled=compiled)
         self._execs[key] = rec
         self._records.append(rec)
         self._last = rec
@@ -344,15 +336,14 @@ class WatchedFunction:
         ring.record(_ev.COMPILE_END, fn=self.name, seconds=round(dt, 6),
                     flops=cost.get("flops", 0.0),
                     hbm_bytes=cost.get("hbm_bytes", 0.0),
-                    index=rec.index, degraded=degraded)
+                    index=rec.index)
         return rec
 
     def _dynamic_only(self, args, kwargs):
         """Args/kwargs with the statics stripped — ``Compiled.__call__``
         takes only the dynamic arguments (statics were burned into the
         executable at lower time); passing them through raises a pytree
-        mismatch and would silently degrade the watch to plain-jit
-        dispatch (plus a second compile)."""
+        mismatch."""
         if not self._static_idx and not self._static_names:
             return args, kwargs
         dyn = tuple(a for i, a in enumerate(args)
@@ -361,36 +352,36 @@ class WatchedFunction:
                if k not in self._static_names}
         return dyn, dkw
 
+    @staticmethod
+    def _traced(args, kwargs) -> bool:
+        """Called under an outer trace? Only asked off the hot path: on
+        a signature miss and when a ``Compiled`` refuses its arguments."""
+        import jax
+        return any(isinstance(x, jax.core.Tracer)
+                   for x in jax.tree_util.tree_leaves((args, kwargs)))
+
     def __call__(self, *args, **kwargs):
         key = self._signature(args, kwargs)
         rec = self._execs.get(key)
         if rec is None:
+            if self._traced(args, kwargs):
+                return self._jit(*args, **kwargs)   # inlined, unrecorded
             with self._lock:
                 rec = self._execs.get(key)   # lost the race → reuse
                 if rec is None:
                     rec = self._compile(key, args, kwargs)
+        dyn_args, dyn_kwargs = self._dynamic_only(args, kwargs)
+        try:
+            out = rec.compiled(*dyn_args, **dyn_kwargs)
+        except TypeError:
+            # a signature compiled earlier, met again under an outer
+            # trace: tracers carry the same avals but cannot enter a
+            # Compiled. Anything else is the caller's error.
+            if not self._traced(args, kwargs):
+                raise
+            return self._jit(*args, **kwargs)
         rec.calls += 1
-        if rec.compiled is not None:
-            dyn_args, dyn_kwargs = self._dynamic_only(args, kwargs)
-            try:
-                out = rec.compiled(*dyn_args, **dyn_kwargs)
-                rec.succeeded = True
-                return out
-            except Exception:  # noqa: BLE001 — see the gate below
-                if rec.succeeded:
-                    # an executable that has already run is failing for
-                    # a REAL reason (OOM, runtime error) — surface it,
-                    # don't silently recompile through plain dispatch
-                    raise
-                # first-ever call: a placement/validation corner the
-                # cache key is too coarse for — degrade this signature.
-                # The retry stays INSIDE the handler so that if it also
-                # fails (e.g. a donated buffer was already consumed),
-                # Python chains both tracebacks and the original error
-                # is never masked.
-                rec.compiled, rec.degraded = None, True
-                return self._jit(*args, **kwargs)
-        return self._jit(*args, **kwargs)
+        return out
 
     # ----------------------------------------------------------- jit parity
 
@@ -430,13 +421,12 @@ class WatchedFunction:
         lines = [f"{self.name}: {len(self._records)} executable(s), "
                  f"{len(self.retraces)} retrace(s)"]
         for rec in self._records:
-            tag = "  [degraded: plain jit dispatch]" if rec.degraded else ""
             lines.append(
                 f"  [{rec.index}] {rec.summary}\n"
                 f"      compile {rec.compile_seconds * 1e3:.1f} ms, "
                 f"{number_to_string(rec.cost.get('flops', 0.0))}FLOPs, "
                 f"hbm {number_to_string(rec.cost.get('hbm_bytes', 0.0))}B, "
-                f"calls {rec.calls}{tag}")
+                f"calls {rec.calls}")
         for r in self.retraces:
             lines.append("  retrace: " + "; ".join(r["changed"][:4])
                          + (" …" if len(r["changed"]) > 4 else ""))

@@ -1,4 +1,4 @@
-"""Sharding-constraint helper shared by model code.
+"""Sharding helpers shared by model code.
 
 One definition for the "constrain if meaningful" rule (previously
 duplicated in models/gpt2.py and moe/sharded_moe.py): apply
@@ -28,3 +28,17 @@ def maybe_constrain(x, spec: P):
                     types[ax] != jax.sharding.AxisType.Auto):
                 return x
     return jax.lax.with_sharding_constraint(x, spec)
+
+
+def map_kernel(kernel, mesh, in_specs, out_specs):
+    """``kernel`` as it must be called under ``mesh``. GSPMD cannot
+    partition a Mosaic (Pallas TPU) call — the lowering refuses with
+    "wrap the call in a shard_map" — so on a mesh of several devices the
+    kernel runs per shard, over the axes the specs name (attention is
+    embarrassingly parallel in batch and heads); axes a spec does not
+    name stay replicated. On one device, or with no mesh, the kernel is
+    returned as is."""
+    if mesh is None or mesh.size == 1:
+        return kernel
+    return jax.shard_map(kernel, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

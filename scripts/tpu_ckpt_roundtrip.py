@@ -5,8 +5,8 @@ with ``memory_kind='pinned_host'``; the orbax/engine checkpoint logic is
 CPU-covered by tests, but whether save/restore works over *pinned-host*
 arrays on the real backend (device_get from host memory, restore
 placement back to pinned_host) is exactly the part a CPU run cannot
-exercise (ROUND3_NOTES queue item). This script proves the round trip on
-the chip:
+exercise. This script proves the round trip on the chip (it passed on a
+TPU v5e on 2026-09-26, PR 21):
 
   1. train 2 steps with ``offload_optimizer`` (stream implementation)
   2. save_checkpoint
@@ -27,10 +27,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def main() -> None:
-    # honor DSTPU_PLATFORM so the CPU smoke run cannot contend for the
-    # real chip (env-var JAX_PLATFORMS alone does not stick — see helper)
-    from deepspeed_tpu.testing import pin_platform
-    pin_platform()
     import jax
     import jax.numpy as jnp
     import numpy as np

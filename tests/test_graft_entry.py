@@ -25,10 +25,8 @@ def test_entry_lowers():
 
 
 def test_bench_cli_parses():
-    env = dict(os.environ, DSTPU_BENCH_PLATFORM="cpu")
     p = subprocess.run([sys.executable, os.path.join(ROOT, "bench.py"),
-                       "--help"], capture_output=True, timeout=120,
-                       env=env)
+                       "--help"], capture_output=True, timeout=120)
     assert p.returncode == 0
     out = p.stdout.decode()
     assert "--phases" in out and "--budget" in out
