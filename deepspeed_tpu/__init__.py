@@ -23,6 +23,11 @@ from deepspeed_tpu.config.config import DeepSpeedConfig
 from deepspeed_tpu.runtime.engine import DeepSpeedEngine, TrainState, initialize
 from deepspeed_tpu.comm.mesh import MeshConfig, build_mesh
 from deepspeed_tpu.utils.logging import logger
+from deepspeed_tpu.telemetry.compile_watch import install_phase_listeners
+
+# compile phases by function name (telemetry/compile_watch.py
+# phase_totals): from the first program this process compiles
+install_phase_listeners()
 
 
 def init_distributed(dist_backend="xla", **kwargs):

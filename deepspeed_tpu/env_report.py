@@ -99,8 +99,7 @@ def telemetry_info():
         out["step_profile"] = (
             "on by default config (serve step phase decomposition + "
             "goodput fraction + dispatch-gap detector; /debug/goodput; "
-            f"ring/timeline sample every {cfg.step_profile_events_every}"
-            " steps)"
+            "every step's phase spans in the span log)"
             if cfg.step_profile
             else "off (set telemetry.step_profile)")
         out["kv_pool_accounting"] = (

@@ -104,6 +104,7 @@ from deepspeed_tpu.telemetry import (CANARY_TENANT, AlertEngine,
                                      rollup_capacity, start_http_server)
 from deepspeed_tpu.telemetry import events as telemetry_events
 from deepspeed_tpu.telemetry.memory import get_memory_monitor
+from deepspeed_tpu.telemetry.spans import get_span_log
 from deepspeed_tpu.telemetry.tracing import (ring_timeline_events,
                                              span_events_from_dict)
 
@@ -1677,10 +1678,12 @@ class ServingFrontend:
                     "name": "hop", "ph": "f", "bp": "e", "cat": "hop",
                     "id": fid, "pid": 1, "tid": tid,
                     "ts": round(b.start * 1e6, 3)})
-        source_pids: Dict[str, int] = {}
+        profiler_pids: Dict[int, int] = {}
         for rep, state, _age in self._fleet_states():
             pid = 10 + rep.index
-            source_pids[f"replica{rep.index}"] = pid
+            prof = getattr(rep.server, "_profiler", None)
+            if prof is not None:
+                profiler_pids[prof.uid] = pid
             events.append({
                 "name": "process_name", "ph": "M", "pid": pid, "tid": 0,
                 "args": {"name": f"replica r{rep.index} "
@@ -1688,7 +1691,7 @@ class ServingFrontend:
                                  f"{rep.health})"}})
             events.append({
                 "name": "thread_name", "ph": "M", "pid": pid, "tid": 1,
-                "args": {"name": "step phases (sampled)"}})
+                "args": {"name": "step phases"}})
             for tdict in state.get("traces") or ():
                 rid = tdict.get("trace_id")
                 tid = 100 + (rid if isinstance(rid, int)
@@ -1703,7 +1706,7 @@ class ServingFrontend:
                     extra_args={"status": tdict.get("status"),
                                 "keep_reason": tdict.get("keep_reason")})
         events.extend(ring_timeline_events(get_event_ring(),
-                                           source_pids=source_pids))
+                                           get_span_log(), profiler_pids))
         payload = {"traceEvents": events, "displayTimeUnit": "ms"}
         with open(path, "w") as f:
             json.dump(payload, f, default=str)

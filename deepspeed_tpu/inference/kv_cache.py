@@ -22,6 +22,7 @@ import numpy as np
 from flax import struct
 
 from deepspeed_tpu.ops.quant_core import dequantize_int8, quantize_int8
+from deepspeed_tpu.profiling.trace import scoped
 
 
 @struct.dataclass
@@ -89,6 +90,7 @@ def init_cache(num_layers: int, batch: int, max_seq: int, num_kv_heads: int,
                    lengths=jnp.zeros((batch,), jnp.int32))
 
 
+@scoped("kv_write")
 def write_prompt(cache: KVCache, layer: int, k: jnp.ndarray, v: jnp.ndarray,
                  lengths: jnp.ndarray) -> KVCache:
     """Prefill: write ``[B, T, H, D]`` keys/values at positions 0..T-1.
@@ -105,6 +107,7 @@ def write_prompt(cache: KVCache, layer: int, k: jnp.ndarray, v: jnp.ndarray,
     return cache.replace(k=newk, v=newv, lengths=lengths.astype(jnp.int32))
 
 
+@scoped("kv_write")
 def append_token(cache: KVCache, layer: int, k: jnp.ndarray,
                  v: jnp.ndarray) -> KVCache:
     """Decode: append one token's ``[B, H, D]`` k/v at ``lengths[b]`` per row.
@@ -125,6 +128,7 @@ def append_token(cache: KVCache, layer: int, k: jnp.ndarray,
     return cache.replace(k=newk, v=newv)
 
 
+@scoped("kv_write")
 def write_chunk(cache: KVCache, layer: int, k: jnp.ndarray,
                 v: jnp.ndarray) -> KVCache:
     """Speculative verify: write a K-token chunk's ``[B, K, H, D]`` k/v
@@ -266,6 +270,7 @@ def _quant_rows(cache: PagedKVCache, x: jnp.ndarray):
     return q, s[..., 0]
 
 
+@scoped("kv_write")
 def paged_write_prompt(cache: PagedKVCache, layer: int, k: jnp.ndarray,
                        v: jnp.ndarray, slot: jnp.ndarray) -> PagedKVCache:
     """Prefill: scatter one prompt's ``[T, H, D]`` k/v into ``slot``'s
@@ -307,6 +312,7 @@ def _scatter_blocks(cache: PagedKVCache, layer: int, idx: jnp.ndarray,
     return out
 
 
+@scoped("kv_write")
 def paged_append_token(cache: PagedKVCache, layer: int, k: jnp.ndarray,
                        v: jnp.ndarray) -> PagedKVCache:
     """Decode: append one token's ``[S, H, D]`` k/v at ``lengths[s]`` for
@@ -341,6 +347,7 @@ def _scatter_positions(cache: PagedKVCache, layer: int, blk: jnp.ndarray,
     return out
 
 
+@scoped("kv_write")
 def paged_write_tokens(cache: PagedKVCache, layer: int, k: jnp.ndarray,
                        v: jnp.ndarray) -> PagedKVCache:
     """Speculative verify: write K tokens' ``[S, K, H, D]`` k/v for
@@ -370,6 +377,7 @@ def paged_write_tokens(cache: PagedKVCache, layer: int, k: jnp.ndarray,
     return _scatter_positions(cache, layer, blk, off, k, v)
 
 
+@scoped("kv_write")
 def paged_write_chunk(cache: PagedKVCache, layer: int, k: jnp.ndarray,
                       v: jnp.ndarray, slot: jnp.ndarray,
                       start: jnp.ndarray) -> PagedKVCache:
@@ -395,6 +403,7 @@ def paged_write_chunk(cache: PagedKVCache, layer: int, k: jnp.ndarray,
     return _scatter_blocks(cache, layer, idx, k, v)
 
 
+@scoped("kv_read")
 def paged_gather_slot_kv(cache: PagedKVCache, layer: int, slot: jnp.ndarray):
     """Materialize ONE slot's cache ``[1, max_context, H, D]`` through
     its block table — the chunk-attends-over-table gather (chunked
@@ -434,6 +443,7 @@ def prefix_block_hashes(prompt, block_size: int) -> list:
     return out
 
 
+@scoped("kv_read")
 def paged_gather_kv(cache: PagedKVCache, layer: int):
     """Materialize per-slot caches ``[S, max_context, H, D]`` through the
     block tables — the pure-JAX decode fallback (CPU / ALiBi / windowed).

@@ -70,6 +70,9 @@ from deepspeed_tpu.telemetry import (NULL_STEP_HANDLE, AlertEngine,
                                      get_registry, start_http_server,
                                      watched_jit)
 from deepspeed_tpu.telemetry import events as telemetry_events
+from deepspeed_tpu.telemetry.step_profile import (DECODE_SPAN, FLUSH_SPAN,
+                                                  PREFILL_SPAN, QUEUE_SPAN,
+                                                  REQUEST_SPAN)
 
 # finish reason -> event-ring kind (every lifecycle finish leaves a
 # forensic entry; "eos"/"length" are the quiet normal path)
@@ -116,6 +119,12 @@ def _safe_cache_size(fn) -> int:
         return int(fn._cache_size())
     except Exception:  # noqa: BLE001 — any private-API drift
         return -1
+
+
+def _sample(logits):
+    """Greedy choice, under the serving programs' ``sample`` scope."""
+    with jax.named_scope("sample"):
+        return jnp.argmax(logits, -1).astype(jnp.int32)
 
 
 class _RequestTrace:
@@ -281,8 +290,6 @@ class ContinuousBatchingServer:
         if tcfg is None or tcfg.step_profile:
             self._profiler = StepProfiler(
                 registry=self.telemetry, clock=self._clock,
-                events_every=(tcfg.step_profile_events_every
-                              if tcfg is not None else 32),
                 source=profile_source)
             self._pool_acct = KVPoolAccountant(
                 registry=self.telemetry, clock=self._clock)
@@ -488,6 +495,10 @@ class ContinuousBatchingServer:
         self._swap_alarm = False
         self._host_mem_getter = None
         self._submit_ts: Dict[int, float] = {}
+        # open request-lifecycle spans (see submit / _request_phase);
+        # empty when the step profiler is off
+        self._req_spans: Dict[int, list] = {}
+        self._admitted_step = 0
         # when the request last ENTERED the queue (submit or preemption
         # requeue) — the shed guard's notion of "how long has this
         # waiter actually been waiting"; _submit_ts must stay the
@@ -987,26 +998,26 @@ class ContinuousBatchingServer:
     def _prefill_fn(params, ids, length, cache, slot, *, cfg, mesh):
         logits, cache = paged_prefill(params, cfg, ids, length, cache,
                                       slot, mesh=mesh)
-        return jnp.argmax(logits, -1).astype(jnp.int32), cache
+        return _sample(logits), cache
 
     @staticmethod
     def _decode_fn(params, tokens, cache, active, *, cfg, mesh):
         logits, cache = paged_decode_step(params, cfg, tokens, cache,
                                           active, mesh=mesh)
-        return jnp.argmax(logits, -1).astype(jnp.int32), cache
+        return _sample(logits), cache
 
     @staticmethod
     def _chunk_fn(params, ids, start, length, cache, slot, *, cfg, mesh):
         logits, cache = paged_prefill_chunk(params, cfg, ids, start,
                                             length, cache, slot,
                                             mesh=mesh)
-        return jnp.argmax(logits, -1).astype(jnp.int32), cache
+        return _sample(logits), cache
 
     @staticmethod
     def _verify_fn(params, tokens, cache, *, cfg, mesh):
         logits, cache = paged_verify_step(params, cfg, tokens, cache,
                                           mesh=mesh)
-        return jnp.argmax(logits, -1).astype(jnp.int32), cache
+        return _sample(logits), cache
 
     def _make_pool(self, num_blocks: int) -> PagedKVCache:
         mcfg = self.engine.model_config
@@ -1247,6 +1258,11 @@ class ContinuousBatchingServer:
                               tenant=tenant)
         self._submit_ts[request_id] = now
         self._queued_ts[request_id] = now
+        if self._profiler is not None:
+            # request lifecycle in the span log: [span id, submit,
+            # start of the open phase, the open phase's span name]
+            self._req_spans[request_id] = [
+                self._profiler.span_log.next_id(), now, now, QUEUE_SPAN]
         if deadline_ts is not None:
             self._deadlines[request_id] = deadline_ts
         if self.tracer is not None:
@@ -1270,6 +1286,33 @@ class ContinuousBatchingServer:
         if self._fi is not None:
             self._fi.on_submit(request_id)
         return request_id
+
+    def _request_phase(self, rid: int, now: float, opens: str,
+                       attrs: Optional[dict] = None) -> None:
+        """Close the request's open lifecycle phase at ``now`` (one
+        ``serve:queue_wait`` / ``serve:prefill`` / ``serve:decode``
+        record in the span log, from stamps the loop reads anyway) and
+        open ``opens`` there, so the phases tile ``serve:request``."""
+        st = self._req_spans.get(rid)
+        if st is None:
+            return
+        self._profiler.span_log.record(st[3], st[2], now, parent=st[0],
+                                       key=rid, attrs=attrs)
+        st[2], st[3] = now, opens
+
+    def _request_done(self, req: Request, now: float, tokens_out: int,
+                      reason: str) -> None:
+        st = self._req_spans.pop(req.request_id, None)
+        if st is None:
+            return
+        log = self._profiler.span_log
+        log.record(st[3], st[2], now, parent=st[0], key=req.request_id)
+        log.record(REQUEST_SPAN, st[1], now, key=req.request_id,
+                   span_id=st[0], attrs={
+                       "prompt_tokens": len(req.prompt),
+                       "output_tokens": tokens_out,
+                       "finish_reason": reason,
+                       "preemptions": req.preemptions})
 
     def _count_rejection(self, reason: str,
                          request_id: Optional[int] = None,
@@ -1363,6 +1406,8 @@ class ContinuousBatchingServer:
         self._submit_ts.pop(rid, None)
         self._queued_ts.pop(rid, None)
         self._deadlines.pop(rid, None)
+        self._request_done(req, self._clock(),
+                           max(len(tokens) - len(req.prompt), 0), reason)
         if self._ledger is not None:
             # closes the record (and any still-open KV residency); the
             # finishing step's own device share still lands on it via
@@ -1602,7 +1647,10 @@ class ContinuousBatchingServer:
                                register_extension=not mid)
         # requeue moment: the shed guard measures wait from HERE, not
         # from the original submit
-        self._queued_ts[req.request_id] = self._clock()
+        t_requeue = self._clock()
+        self._queued_ts[req.request_id] = t_requeue
+        self._request_phase(req.request_id, t_requeue, QUEUE_SPAN,
+                            {"preempted": True})
         self._reset_slot_arrays(slot)
         self._c_preempted.inc()
         self._lifecycle_counts["preempted"] += 1
@@ -1661,6 +1709,8 @@ class ContinuousBatchingServer:
             req = state.request
             sched_prompt = req.sched_prompt
             t_admit = self._clock()
+            self._admitted_step += 1
+            self._request_phase(req.request_id, t_admit, PREFILL_SPAN)
             if not state.resumed:
                 self._h_queue_wait.observe(
                     t_admit - self._submit_ts.get(req.request_id,
@@ -1765,6 +1815,7 @@ class ContinuousBatchingServer:
                 self._ledger.add_weight(req.request_id, T)
             tok0 = int(np.asarray(tok0)[0])   # host sync: prefill done
             now_t = self._clock()
+            self._request_phase(req.request_id, now_t, DECODE_SPAN)
             # prefill compute runs inside the admission phase; its
             # dispatch->fetch interval is still device-attributed (and
             # advances the dispatch-gap boundary — the device was busy)
@@ -1911,6 +1962,7 @@ class ContinuousBatchingServer:
             self.scheduler.commit_prefix(state)
         tok0 = int(tok[0])
         now = self._clock()
+        self._request_phase(req.request_id, now, DECODE_SPAN)
         if not state.generated:
             # first-ever token for this request (see the monolithic
             # site): resumed-with-committed skips, resumed-before-first-
@@ -2018,8 +2070,10 @@ class ContinuousBatchingServer:
         ts = self._submit_ts.pop(req.request_id, None)
         self._queued_ts.pop(req.request_id, None)
         self._deadlines.pop(req.request_id, None)
+        t_done = self._clock()
         if ts is not None:
-            self._h_request.observe(self._clock() - ts)
+            self._h_request.observe(t_done - ts)
+        self._request_done(req, t_done, len(state.generated), reason)
         if self._ledger is not None:
             # moves the record to pending-close: the retiring step's
             # own device share still settles onto it before it emits
@@ -2081,6 +2135,7 @@ class ContinuousBatchingServer:
         sp = (self._profiler.begin() if self._profiler is not None
               else NULL_STEP_HANDLE)
         finished: List[int] = []
+        self._admitted_step = 0
         self._take_deferred(finished)
         self._tick += 1
         if self.canary is not None:
@@ -2133,7 +2188,7 @@ class ContinuousBatchingServer:
                 self.watchdog.notify_progress()
             # nothing resident: the device idles for lack of WORK, so
             # the dispatch-gap baseline resets (a lull is not host tax)
-            sp.finish(live=False)
+            self._finish_step(sp)
             return finished
         if self.spec_tokens:
             self._decode_speculative(finished, sp)
@@ -2148,8 +2203,17 @@ class ContinuousBatchingServer:
         sp.mark("publish")
         # live=False when this step retired the last resident: the gap
         # to the NEXT dispatch would measure traffic, not host tax
-        sp.finish(live=bool(self.scheduler.slots))
+        self._finish_step(sp)
         return finished
+
+    def _finish_step(self, sp) -> None:
+        """Close the step's profile. ``live`` is false when nothing is
+        resident after it (idle poll, or the step retired the last
+        resident): the gap to the NEXT dispatch would measure traffic,
+        not host tax."""
+        slots = len(self.scheduler.slots)
+        sp.finish(live=bool(slots), slots=slots,
+                  admitted=self._admitted_step)
 
     # ------------------------------------------------ async dispatch loop
 
@@ -2195,7 +2259,7 @@ class ContinuousBatchingServer:
             if self.watchdog is not None:
                 # an IDLE server being polled is alive, not stalled
                 self.watchdog.notify_progress()
-            sp.finish(live=False)
+            self._finish_step(sp)
             return finished
         if self.spec_tokens:
             self._pipelined_verify(finished, sp)
@@ -2206,7 +2270,7 @@ class ContinuousBatchingServer:
         if self._capacity is not None:
             self._capacity.maybe_evaluate()
         sp.mark("publish")
-        sp.finish(live=bool(self.scheduler.slots))
+        self._finish_step(sp)
         return finished
 
     def _pipelined_decode(self, finished: List[int], sp) -> None:
@@ -2262,7 +2326,7 @@ class ContinuousBatchingServer:
         sp.mark("propose", now=t0, dispatch=True)
         nxt, self._cache = self._decode_jit(
             self.engine.params, tok_in, self._cache, jnp.asarray(active))
-        sp.mark("dispatch")
+        sp.mark("dispatch", program=self._decode_jit.name)
         chain.append(InFlightStep("decode", nxt, states, t0))
         if rec is None:
             self._async_stats["pipeline_starts"] += 1
@@ -2305,7 +2369,8 @@ class ContinuousBatchingServer:
         nxt = np.asarray(rec.tokens)         # host sync: the lagged fetch
         t1 = self._clock()
         if in_step:
-            sp.mark("sync_wait", now=t1, fetch=True)
+            sp.mark("sync_wait", now=t1, fetch=True,
+                    program=self._decode_jit.name)
         elif self._profiler is not None:
             self._profiler.note_fetch(t1)
         self._realize_chunk_span(sp, t1)
@@ -2447,7 +2512,7 @@ class ContinuousBatchingServer:
             tok_arg, d_props = jnp.asarray(tokens), None
         t_toks, self._cache = self._verify_jit(
             self.engine.params, tok_arg, self._cache)
-        sp.mark("dispatch")
+        sp.mark("dispatch", program=self._verify_jit.name)
         self.profiler_capture.step_end()
         if rec is None:
             self._async_stats["pipeline_starts"] += 1
@@ -2483,7 +2548,7 @@ class ContinuousBatchingServer:
                      else np.asarray(rec.props))
         t1 = self._clock()
         if in_step and getattr(sp, "_pipelined_mode", False):
-            sp.mark("sync_wait", now=t1)
+            sp.mark("sync_wait", now=t1, program=self._verify_jit.name)
             # device busy from step begin (the round was in flight
             # across the call boundary) until this fetch; 0.0 clamps to
             # the handle's begin. note_dispatch=False: the dispatch was
@@ -2492,7 +2557,8 @@ class ContinuousBatchingServer:
         elif in_step:
             # flush inside a sync action step: the plain fetch-wait
             # attribution (mode off — the sliver credit IS the span)
-            sp.mark("sync_wait", now=t1, fetch=True)
+            sp.mark("sync_wait", now=t1, fetch=True,
+                    program=self._verify_jit.name)
         elif self._profiler is not None:
             self._profiler.note_fetch(t1)
         self._realize_chunk_span(sp, t1)
@@ -2650,6 +2716,7 @@ class ContinuousBatchingServer:
         per-step gap attribution stays honest across the drain)."""
         if self._inflight:
             depth = len(self._inflight)
+            t_flush = self._clock()
             while self._inflight:
                 rec = self._inflight.popleft()
                 if rec.kind == "decode":
@@ -2664,6 +2731,14 @@ class ContinuousBatchingServer:
             fl[reason] = fl.get(reason, 0) + 1
             fd = self._async_stats["flush_depths"].setdefault(reason, {})
             fd[depth] = fd.get(depth, 0) + 1
+            if sp is not NULL_STEP_HANDLE:
+                sp.flush_span(t_flush, reason, depth)
+            elif self._profiler is not None:
+                # out-of-step flush (cancel / drain / close between
+                # steps): no step to parent it
+                self._profiler.span_log.record(
+                    FLUSH_SPAN, t_flush, self._clock(),
+                    attrs={"reason": reason, "programs": depth})
         self._drain_publishing()
 
     def _decode_once(self, finished: List[int],
@@ -2697,14 +2772,15 @@ class ContinuousBatchingServer:
         nxt, self._cache = self._decode_jit(
             self.engine.params, jnp.asarray(tokens), self._cache,
             jnp.asarray(active))
-        sp.mark("dispatch")
+        sp.mark("dispatch", program=self._decode_jit.name)
         self._step_clock += 1
         n_active = int(active.sum())
         self._active_slot_steps += n_active
         nxt = np.asarray(nxt)             # host sync: the step completed
         t1 = self._clock()
         dt = t1 - t0
-        sp.mark("sync_wait", now=t1, fetch=True)
+        sp.mark("sync_wait", now=t1, fetch=True,
+                program=self._decode_jit.name)
         if self._fi is not None:
             # injected latency is ACCOUNTED, never slept — the SLO /
             # shedding chaos tests collapse latency with no real delay
@@ -2822,7 +2898,7 @@ class ContinuousBatchingServer:
             tok_arg, d_props = jnp.asarray(tokens), None
         t_toks, self._cache = self._verify_jit(
             self.engine.params, tok_arg, self._cache)
-        sp.mark("dispatch")
+        sp.mark("dispatch", program=self._verify_jit.name)
         self._step_clock += 1
         self._active_slot_steps += n_active
         t_np = np.asarray(t_toks)         # host sync: the verify ran
@@ -2830,7 +2906,8 @@ class ContinuousBatchingServer:
             props_np = np.asarray(d_props)
         t1 = self._clock()
         dt = t1 - t0
-        sp.mark("sync_wait", now=t1, fetch=True)
+        sp.mark("sync_wait", now=t1, fetch=True,
+                program=self._verify_jit.name)
         if self._fi is not None:
             # injected latency is ACCOUNTED, never slept (see step())
             dt += self._fi.step_latency()
@@ -3003,8 +3080,11 @@ class ContinuousBatchingServer:
                 "request tracing is off — set telemetry."
                 "trace_sample_rate > 0 (docs/observability.md "
                 "'Request tracing & SLOs')")
-        return self.tracer.dump_timeline(path,
-                                         event_ring=get_event_ring())
+        prof = self._profiler
+        return self.tracer.dump_timeline(
+            path, event_ring=get_event_ring(),
+            span_log=prof.span_log if prof is not None else None,
+            profiler_pids={prof.uid: 3} if prof is not None else None)
 
     def capture_decode_steps(self, num_steps: int, logdir: str) -> None:
         """Arm an on-demand ``jax.profiler`` capture: the next

@@ -46,6 +46,7 @@ from deepspeed_tpu.inference.kv_cache import (KVCache, PagedKVCache, advance,
                                               write_prompt)
 from deepspeed_tpu.ops.pallas import decode_attention as _kernels
 from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+from deepspeed_tpu.profiling.trace import scoped
 from deepspeed_tpu.utils.sharding import map_kernel
 from deepspeed_tpu.ops.int8_gemm import (maybe_int8_einsum,
                                          maybe_int8_matmul)
@@ -314,6 +315,7 @@ def _w(w, dtype):
     return w.astype(dtype) if w.dtype != dtype else w
 
 
+@scoped("ln")
 def _layer_norm(x, p, eps):
     """LayerNorm, or RMSNorm when the param dict carries no bias (the
     LLaMA family: no centering, scale only) — data-driven so every call
@@ -415,6 +417,7 @@ def _use_decode_kernel(cfg: InferenceTransformerConfig, H: int, KH: int,
             and not cfg.seq_shard_kv)
 
 
+@scoped("attn_kernel")
 def _prefill_attention(q, k, v, cfg: InferenceTransformerConfig,
                        causal: bool = True, key_mask=None, window=None,
                        mesh=None):
@@ -462,6 +465,7 @@ def _prefill_attention(q, k, v, cfg: InferenceTransformerConfig,
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
 
 
+@scoped("attn_kernel")
 def _decode_attention(q, k_cache, v_cache, live,
                       cfg: InferenceTransformerConfig, window=None,
                       mesh=None):
@@ -513,17 +517,21 @@ def _paged_kernel(kernel, q, cache: PagedKVCache, layer_idx: int,
     hs = _head_axis(mesh, q.shape[-2], cache.k.shape[3])
     q_spec = P(*[None] * (q.ndim - 2), hs, None)     # [..., H, D]
     pool = P(None, None, hs, None)
-    scales = ([] if cache.k_scale is None else
-              [cache.k_scale[layer_idx], cache.v_scale[layer_idx]])
+    with jax.named_scope("kv_read"):
+        # the per-layer cut of K and V (and their scales) out of the pool
+        k_pool, v_pool = cache.k[layer_idx], cache.v[layer_idx]
+        scales = ([] if cache.k_scale is None else
+                  [cache.k_scale[layer_idx], cache.v_scale[layer_idx]])
 
     def call(q, k, v, table, bound, *sc):
         return kernel(q, k, v, table, bound, scale=cfg.scale,
                       **dict(zip(("k_scale", "v_scale"), sc)))
-    return map_kernel(
-        call, mesh,
-        (q_spec, pool, pool, P(), P(), *[P(None, hs, None)] * len(scales)),
-        q_spec)(q, cache.k[layer_idx], cache.v[layer_idx], table, bound,
-                *scales)
+    with jax.named_scope("attn_kernel"):
+        return map_kernel(
+            call, mesh,
+            (q_spec, pool, pool, P(), P(),
+             *[P(None, hs, None)] * len(scales)),
+            q_spec)(q, k_pool, v_pool, table, bound, *scales)
 
 
 def _paged_decode_attention(q, cache: PagedKVCache, layer_idx: int,
@@ -544,6 +552,7 @@ def _paged_decode_attention(q, cache: PagedKVCache, layer_idx: int,
     return _decode_attention(q, k_cache, v_cache, live, cfg, window=window)
 
 
+@scoped("attn_kernel")
 def _chunk_attention(q, k_cache, v_cache, lengths,
                      cfg: InferenceTransformerConfig, window=None):
     """Speculative-verify attention: ``q [B, K, H, D]`` for K tokens at
@@ -621,6 +630,7 @@ def _paged_chunk_attention(q, cache: PagedKVCache, layer_idx: int,
 
 # ---------------------------------------------------------------- blocks
 
+@scoped("attn_qkv")
 def _qkv(x, a, cfg, positions):
     """x [..., E] → q [..., H, D], k/v [..., KH, D] with rotary applied."""
     dt = x.dtype
@@ -720,6 +730,15 @@ def _maybe_expert_constrain(t, mesh):
     return t
 
 
+@scoped("attn_out")
+def _attn_out(attn, a, spec: str, dtype, cfg):
+    """The attention output projection (``spec``: the call site's
+    einsum, ``...hd,hde->...e`` or its one-token form)."""
+    return maybe_int8_einsum(spec, attn, a["wo"], dtype, cfg.int8_compute,
+                             2, 1) + a["bo"]
+
+
+@scoped("mlp")
 def _ffn(x, layer, cfg, mesh=None):
     """MLP or MoE, by layer schema."""
     if "moe" in layer:
@@ -764,8 +783,7 @@ def _block_seq(x, layer, cfg, positions, lengths, cache, layer_idx,
     window = (cfg.local_windows[layer_idx] if cfg.local_windows else None)
     attn = _prefill_attention(q, k, v, cfg, causal=causal, key_mask=key_mask,
                               window=window, mesh=mesh)
-    attn_out = maybe_int8_einsum("...hd,hde->...e", attn, a["wo"],
-                                 x.dtype, cfg.int8_compute, 2, 1) + a["bo"]
+    attn_out = _attn_out(attn, a, "...hd,hde->...e", x.dtype, cfg)
     return _post_attn(x, ln1_out, attn_out, layer, cfg, mesh), cache
 
 
@@ -781,8 +799,7 @@ def _block_decode(x, layer, cfg, cache, layer_idx, mesh=None):
     attn = _decode_attention(q, cache.k[layer_idx], cache.v[layer_idx],
                              cache.lengths + 1, cfg, window=window,
                              mesh=mesh)
-    attn_out = maybe_int8_einsum("bhd,hde->be", attn, a["wo"],
-                                 x.dtype, cfg.int8_compute, 2, 1) + a["bo"]
+    attn_out = _attn_out(attn, a, "bhd,hde->be", x.dtype, cfg)
     return _post_attn(x, ln1_out, attn_out, layer, cfg, mesh), cache
 
 
@@ -799,8 +816,7 @@ def _block_chunk(x, layer, cfg, cache, layer_idx, mesh=None):
     window = (cfg.local_windows[layer_idx] if cfg.local_windows else None)
     attn = _chunk_attention(q, cache.k[layer_idx], cache.v[layer_idx],
                             cache.lengths, cfg, window=window)
-    attn_out = maybe_int8_einsum("...hd,hde->...e", attn, a["wo"],
-                                 x.dtype, cfg.int8_compute, 2, 1) + a["bo"]
+    attn_out = _attn_out(attn, a, "...hd,hde->...e", x.dtype, cfg)
     return _post_attn(x, ln1_out, attn_out, layer, cfg, mesh), cache
 
 
@@ -829,6 +845,7 @@ def decode_chunk(params, cfg: InferenceTransformerConfig, tokens,
 
 # ---------------------------------------------------------------- model
 
+@scoped("embed")
 def _embed(params, cfg, ids, positions, token_type_ids=None):
     x = params["wte"][ids].astype(cfg.dtype)
     if cfg.embed_scale != 1.0:   # Gemma: x * sqrt(E), head reads raw wte
@@ -844,6 +861,7 @@ def _embed(params, cfg, ids, positions, token_type_ids=None):
     return x
 
 
+@scoped("lm_head")
 def _logits(params, cfg, x):
     head = (params["wte"].T if cfg.tied_lm_head else params["lm_head"])
     out = (x @ head.astype(x.dtype)).astype(jnp.float32)
@@ -903,8 +921,7 @@ def _block_decode_paged(x, layer, cfg, cache: PagedKVCache, layer_idx,
     attn = _paged_decode_attention(q, cache, layer_idx, cfg,
                                    cache.lengths + 1, window=window,
                                    mesh=mesh)
-    attn_out = maybe_int8_einsum("bhd,hde->be", attn, a["wo"],
-                                 x.dtype, cfg.int8_compute, 2, 1) + a["bo"]
+    attn_out = _attn_out(attn, a, "bhd,hde->be", x.dtype, cfg)
     return _post_attn(x, ln1_out, attn_out, layer, cfg, mesh), cache
 
 
@@ -946,8 +963,7 @@ def _block_chunk_paged(x, layer, cfg, cache: PagedKVCache, layer_idx,
     window = (cfg.local_windows[layer_idx] if cfg.local_windows else None)
     attn = _paged_chunk_attention(q, cache, layer_idx, cfg, slot, start,
                                   window=window, mesh=mesh)
-    attn_out = maybe_int8_einsum("...hd,hde->...e", attn, a["wo"],
-                                 x.dtype, cfg.int8_compute, 2, 1) + a["bo"]
+    attn_out = _attn_out(attn, a, "...hd,hde->...e", x.dtype, cfg)
     return _post_attn(x, ln1_out, attn_out, layer, cfg, mesh), cache
 
 
@@ -1013,8 +1029,7 @@ def _block_verify_paged(x, layer, cfg, cache: PagedKVCache, layer_idx,
     window = (cfg.local_windows[layer_idx] if cfg.local_windows else None)
     attn = _paged_verify_attention(q, cache, layer_idx, cfg,
                                    window=window, mesh=mesh)
-    attn_out = maybe_int8_einsum("...hd,hde->...e", attn, a["wo"],
-                                 x.dtype, cfg.int8_compute, 2, 1) + a["bo"]
+    attn_out = _attn_out(attn, a, "...hd,hde->...e", x.dtype, cfg)
     return _post_attn(x, ln1_out, attn_out, layer, cfg, mesh), cache
 
 

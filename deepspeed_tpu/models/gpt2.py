@@ -161,8 +161,11 @@ class CausalSelfAttention(nn.Module):
                 y = ring_self_attention(q, k, v, get_global_mesh())
         elif cfg.use_flash_attention:
             from deepspeed_tpu.ops.attention import causal_attention
-            y = causal_attention(q, k, v, block_q=cfg.flash_block,
-                                 block_k=cfg.flash_block)
+            # the serving model's scope names (docs/observability.md
+            # "Spans"); flax's own module names give the rest of the path
+            with jax.named_scope("attn_kernel"):
+                y = causal_attention(q, k, v, block_q=cfg.flash_block,
+                                     block_k=cfg.flash_block)
         else:
             scale = 1.0 / jnp.sqrt(C // H).astype(cfg.dtype)
             att = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
@@ -291,8 +294,9 @@ class GPT2(nn.Module):
             wpe = _fetch_to_device(wpe, "wpe", self.fetch_table)
         # gather rows THEN cast (16 MB vs casting the whole fp32 table to
         # a 100+ MB bf16 copy per step), and slice positions statically
-        x = wte[input_ids].astype(cfg.dtype) + \
-            wpe[:T].astype(cfg.dtype)[None]
+        with jax.named_scope("embed"):
+            x = wte[input_ids].astype(cfg.dtype) + \
+                wpe[:T].astype(cfg.dtype)[None]
         x = _maybe_constrain(x, P(DATA_AXES, "seq", None))
         if cfg.dropout > 0.0 and not deterministic:
             x = nn.Dropout(cfg.dropout)(x, deterministic=False)
@@ -339,7 +343,8 @@ class GPT2(nn.Module):
                     t, "ln_f", self.fetch_table),
                 trans_out_fn=lambda t: t, mutable=True, init=True)
         x = ln_f(dtype=cfg.dtype, name="ln_f")(x)
-        logits = lm_logits(x, wte.astype(cfg.dtype), cfg.int8_training)
+        with jax.named_scope("lm_head"):
+            logits = lm_logits(x, wte.astype(cfg.dtype), cfg.int8_training)
         if moe_set:
             return logits, l_aux_total
         return logits

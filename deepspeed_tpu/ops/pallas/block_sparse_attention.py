@@ -123,4 +123,5 @@ def block_sparse_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, T, D), q.dtype),
         interpret=interpret,
+        name="block_sparse_attention",
     )(counts.astype(jnp.int32), lut.astype(jnp.int32), q, k, v)

@@ -124,8 +124,11 @@ def _paged_kernel(base_ref, bt_ref, q_ref, k_ref, v_ref, *rest,
 
 
 def _paged_attention(qg, k_pool, v_pool, block_tables, base, *, rep: int,
-                     scale, interpret, k_scale=None, v_scale=None):
-    """The decode family's one ``pallas_call``. qg ``[S, KH, T*rep, D]``
+                     scale, interpret, name: str, k_scale=None,
+                     v_scale=None):
+    """The decode family's one ``pallas_call``, named ``name`` in the
+    compiled program and the device trace (the entry point's name: the
+    kernel body is shared). qg ``[S, KH, T*rep, D]``
     (each slot's T query tokens x ``rep`` group members, token-major,
     grouped by the kv head they read); pools ``[NB, BS, KH, D]``;
     block_tables ``[S, MB]`` (dead entries must be valid ids — the null
@@ -187,6 +190,7 @@ def _paged_attention(qg, k_pool, v_pool, block_tables, base, *, rep: int,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, KH, rows, D), qg.dtype),
         interpret=interpret,
+        name=name,
     )(*args)
 
 
@@ -223,7 +227,7 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         k_cache.reshape(B * nb, block_k, KH, D),
         v_cache.reshape(B * nb, block_k, KH, D),
         tables, lengths.astype(jnp.int32) - 1, rep=R, scale=scale,
-        interpret=interpret)
+        interpret=interpret, name="decode_attention")
     return og.reshape(B, H, D)
 
 
@@ -254,7 +258,8 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     og = _paged_attention(
         q.reshape(S, KH, R, D), k_pool, v_pool, block_tables,
         lengths.astype(jnp.int32) - 1, rep=R, scale=scale,
-        interpret=interpret, k_scale=k_scale, v_scale=v_scale)
+        interpret=interpret, name="paged_decode_attention",
+        k_scale=k_scale, v_scale=v_scale)
     return og.reshape(S, H, D)
 
 
@@ -278,10 +283,10 @@ def paged_chunk_attention(q: jax.Array, k_pool: jax.Array,
     chunk query ``qi`` iff ``col <= start + qi``. int8 pools pass
     ``k_scale``/``v_scale`` ``[NB, KH, BS]``. Returns ``[C, H, D]``.
     """
-    return paged_verify_attention(
+    return _paged_multi_token(
         q[None], k_pool, v_pool, block_table[None],
-        jnp.reshape(start, (1,)), scale=scale, interpret=interpret,
-        k_scale=k_scale, v_scale=v_scale)[0]
+        jnp.reshape(start, (1,)), "paged_chunk_attention", scale=scale,
+        interpret=interpret, k_scale=k_scale, v_scale=v_scale)[0]
 
 
 def paged_verify_attention(q: jax.Array, k_pool: jax.Array,
@@ -307,6 +312,16 @@ def paged_verify_attention(q: jax.Array, k_pool: jax.Array,
     per-slot acceptance state rides in ``lengths``, so varying
     acceptance never retraces (the PR-8 trace-discipline contract).
     int8 pools pass ``k_scale``/``v_scale`` ``[NB, KH, BS]``."""
+    return _paged_multi_token(q, k_pool, v_pool, block_tables, lengths,
+                              "paged_verify_attention", scale=scale,
+                              interpret=interpret, k_scale=k_scale,
+                              v_scale=v_scale)
+
+
+def _paged_multi_token(q, k_pool, v_pool, block_tables, lengths, name, *,
+                       scale, interpret, k_scale, v_scale):
+    """What the verify and chunk entry points share: K query tokens per
+    slot, the kernel call named ``name``."""
     S, K, H, D = q.shape
     KH = k_pool.shape[2]
     R = _group_size(H, KH)
@@ -316,7 +331,7 @@ def paged_verify_attention(q: jax.Array, k_pool: jax.Array,
         S, KH, K * R, D)
     og = _paged_attention(qg, k_pool, v_pool, block_tables, lengths,
                           rep=R, scale=scale, interpret=interpret,
-                          k_scale=k_scale, v_scale=v_scale)
+                          name=name, k_scale=k_scale, v_scale=v_scale)
     return og.reshape(S, KH, K, R, D).transpose(0, 2, 1, 3, 4).reshape(
         S, K, H, D)
 

@@ -156,6 +156,7 @@ def _flash_fwd(q3, k3, v3, *, scale, block_q, block_k, causal, interpret):
         ],
         out_shape=out_shape,
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q3, k3, v3)
     return o, lse
 
@@ -317,6 +318,7 @@ def _flash_bwd(q3, k3, v3, o3, lse, do3, *, scale, block_q, block_k,
         out_specs=pl.BlockSpec((1, block_q, D), qmap),
         out_shape=jax.ShapeDtypeStruct(q3.shape, q3.dtype),
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(q3, k3, v3, do3, lse, delta)
 
     dkv_kernel = functools.partial(_bwd_dkv_kernel, scale=scale,
@@ -352,6 +354,7 @@ def _flash_bwd(q3, k3, v3, o3, lse, do3, *, scale, block_q, block_k,
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(q3, k3, v3, do3, lse, delta)
     return dq, dk, dv
 

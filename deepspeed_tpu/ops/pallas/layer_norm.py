@@ -85,6 +85,7 @@ def _ln_fwd(x2, w, b, *, eps, block_rows, interpret):
             jax.ShapeDtypeStruct((R, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="layer_norm_fwd",
     )(x2, w[None], b[None])
     return o, mean, rstd
 
@@ -113,6 +114,7 @@ def _ln_bwd(x2, w, mean, rstd, g2, *, block_rows, interpret):
             jax.ShapeDtypeStruct((1, N), jnp.float32),
         ],
         interpret=interpret,
+        name="layer_norm_bwd",
     )(x2, w[None], mean, rstd, g2)
     return dx, dw[0], db[0]
 

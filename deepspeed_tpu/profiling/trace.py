@@ -38,6 +38,22 @@ def instrument(fn: Optional[Callable] = None, *, name: Optional[str] = None):
     return deco(fn) if fn is not None else deco
 
 
+def scoped(name: str):
+    """``@scoped("mlp")``: trace the function under
+    ``jax.named_scope(name)`` and nothing else. The scope is metadata on
+    the compiled instructions (``op_name="jit(f)/mlp/dot_general"``),
+    which the compile watch's scope table turns into device time per
+    layer (docs/observability.md "Spans"); the program's arithmetic,
+    shardings and donation do not change."""
+    def deco(f):
+        @functools.wraps(f)
+        def wrapper(*args, **kwargs):
+            with jax.named_scope(name):
+                return f(*args, **kwargs)
+        return wrapper
+    return deco
+
+
 @contextlib.contextmanager
 def trace(logdir: str, create_perfetto_link: bool = False):
     """Capture an xplane trace for everything inside the block."""

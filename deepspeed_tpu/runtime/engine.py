@@ -42,6 +42,8 @@ from deepspeed_tpu.runtime.precision import (PRECISION_DTYPES, LossScaleState,
                                              update_loss_scale)
 from deepspeed_tpu.runtime.utils import clip_coef
 from deepspeed_tpu.runtime.zero.partition import ZeroShardingPolicy
+from deepspeed_tpu.telemetry.spans import annotation as span_annotation
+from deepspeed_tpu.telemetry.spans import get_span_log
 from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import ThroughputTimer
 
@@ -321,6 +323,7 @@ class DeepSpeedEngine:
                                                           collate_fn)
 
         self._step_fn = None  # compiled lazily (first train_batch)
+        self._step_spans: list = []   # this step's timed intervals
         self._grad_fn = None
         self._pending_grads = None
         self._pending_losses = []
@@ -788,58 +791,67 @@ class DeepSpeedEngine:
             # ``numerics_on: static:False -> static:True``.
             from deepspeed_tpu.telemetry.numerics import block_sq_norms
             scale = state.loss_scale.scale if fp16 else jnp.float32(1.0)
-            grads, mean_loss, aux, gnorm, finite, bstats = grad_core(
-                state.params, scale, batch, rng,
-                want_numerics=numerics_on)
-            lr = schedule(state.step)
-            master = state.master if mixed else state.params
+            # the step's two scopes (docs/observability.md "Spans"):
+            # metadata the compile watch's scope table reads, so that
+            # device time splits into forward+backward and optimizer
+            # (the offload stream's transfers included)
+            with jax.named_scope("fwd_bwd"):
+                grads, mean_loss, aux, gnorm, finite, bstats = grad_core(
+                    state.params, scale, batch, rng,
+                    want_numerics=numerics_on)
+            with jax.named_scope("optimizer"):
+                lr = schedule(state.step)
+                master = state.master if mixed else state.params
 
-            def do_update(operand):
-                grads_, master_, opt_state_ = operand
-                if stream:
-                    if mixed:
-                        master_ = to_dev(master_, master_host_sh)
-                    opt_state_ = to_dev(opt_state_, opt_host_sh)
-                updates, new_opt = optimizer.update(
-                    grads_, opt_state_, master_, lr)
-                new_master = jax.tree.map(jnp.add, master_, updates)
-                upd_sq = (block_sq_norms(updates, numerics_spec)
-                          if numerics_on else ())
-                return new_master, new_opt, upd_sq
+                def do_update(operand):
+                    grads_, master_, opt_state_ = operand
+                    if stream:
+                        if mixed:
+                            master_ = to_dev(master_, master_host_sh)
+                        opt_state_ = to_dev(opt_state_, opt_host_sh)
+                    updates, new_opt = optimizer.update(
+                        grads_, opt_state_, master_, lr)
+                    new_master = jax.tree.map(jnp.add, master_, updates)
+                    upd_sq = (block_sq_norms(updates, numerics_spec)
+                              if numerics_on else ())
+                    return new_master, new_opt, upd_sq
 
-            def skip_update(operand):
-                _, master_, opt_state_ = operand
-                upd_sq = (jnp.zeros((len(numerics_spec.names),),
-                                    jnp.float32) if numerics_on else ())
-                return master_, opt_state_, upd_sq
+                def skip_update(operand):
+                    _, master_, opt_state_ = operand
+                    upd_sq = (jnp.zeros((len(numerics_spec.names),),
+                                        jnp.float32) if numerics_on else ())
+                    return master_, opt_state_, upd_sq
 
-            if fp16:
-                new_master, new_opt, upd_sq = jax.lax.cond(
-                    finite, do_update, skip_update,
-                    (grads, master, state.opt_state))
-            else:
-                new_master, new_opt, upd_sq = do_update(
-                    (grads, master, state.opt_state))
+                if fp16:
+                    new_master, new_opt, upd_sq = jax.lax.cond(
+                        finite, do_update, skip_update,
+                        (grads, master, state.opt_state))
+                else:
+                    new_master, new_opt, upd_sq = do_update(
+                        (grads, master, state.opt_state))
 
-            if mixed:
-                # cast to compute dtype while the fresh master is still in
-                # device space (stream: BEFORE spilling it back to host —
-                # a host-space input here would put the cast off-device)
-                new_params = cast_tree(new_master, self.compute_dtype)
-                if stream:
-                    new_master = to_host(new_master, master_host_sh)
-                    new_opt = to_host(new_opt, opt_host_sh)
-                new_state = state.replace(
-                    step=state.step + 1, params=new_params,
-                    master=new_master, opt_state=new_opt,
-                    loss_scale=update_loss_scale(state.loss_scale, finite))
-            else:
-                if stream:
-                    new_opt = to_host(new_opt, opt_host_sh)
-                new_state = state.replace(
-                    step=state.step + 1, params=new_master,
-                    opt_state=new_opt,
-                    loss_scale=update_loss_scale(state.loss_scale, finite))
+                if mixed:
+                    # cast to compute dtype while the fresh master is
+                    # still in device space (stream: BEFORE spilling it
+                    # back to host — a host-space input here would put
+                    # the cast off-device)
+                    new_params = cast_tree(new_master, self.compute_dtype)
+                    if stream:
+                        new_master = to_host(new_master, master_host_sh)
+                        new_opt = to_host(new_opt, opt_host_sh)
+                    new_state = state.replace(
+                        step=state.step + 1, params=new_params,
+                        master=new_master, opt_state=new_opt,
+                        loss_scale=update_loss_scale(state.loss_scale,
+                                                     finite))
+                else:
+                    if stream:
+                        new_opt = to_host(new_opt, opt_host_sh)
+                    new_state = state.replace(
+                        step=state.step + 1, params=new_master,
+                        opt_state=new_opt,
+                        loss_scale=update_loss_scale(state.loss_scale,
+                                                     finite))
 
             metrics = {"loss": mean_loss, "grad_norm": gnorm, "lr": lr,
                        "loss_scale": scale,
@@ -883,7 +895,8 @@ class DeepSpeedEngine:
         apply_update = self._make_replicated_update()
 
         def local_step(state: TrainState, batch, rng):
-            grads, mean_loss = local_grads(state.params, batch, rng)
+            with jax.named_scope("fwd_bwd"):
+                grads, mean_loss = local_grads(state.params, batch, rng)
             # clip acts on the per-worker LOCAL gradient: a global norm
             # cannot be formed without the exact exchange this algorithm
             # exists to avoid; reported grad_norm is the worker mean
@@ -892,7 +905,9 @@ class DeepSpeedEngine:
             if clip > 0.0:
                 coef = clip_coef(clip, gnorm)
                 grads = jax.tree.map(lambda g: g * coef, grads)
-            new_state, lr = apply_update(state, grads)
+            with jax.named_scope("optimizer"):
+                # the 1-bit optimizer owns the gradient exchange
+                new_state, lr = apply_update(state, grads)
             metrics = {"loss": jax.lax.pmean(mean_loss, axes),
                        "grad_norm": jax.lax.pmean(gnorm, axes),
                        "lr": lr,
@@ -1067,18 +1082,21 @@ class DeepSpeedEngine:
         apply_update = self._make_replicated_update()
 
         def local_step(state: TrainState, batch, rng):
-            grads, mean_loss = local_grads(state.params, batch, rng)
+            with jax.named_scope("fwd_bwd"):
+                grads, mean_loss = local_grads(state.params, batch, rng)
             # the DP exchange — the one piece that differs from pmean;
             # clip/update then run on replicated (global) grads, exactly
             # like the fused GSPMD step
-            grads = exchange(grads)
-            mean_loss = jax.lax.pmean(mean_loss, axes)
+            with jax.named_scope("grad_exchange"):
+                grads = exchange(grads)
+                mean_loss = jax.lax.pmean(mean_loss, axes)
             gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g))
                                  for g in jax.tree.leaves(grads)))
             if clip > 0.0:
                 coef = clip_coef(clip, gnorm)
                 grads = jax.tree.map(lambda g: g * coef, grads)
-            new_state, lr = apply_update(state, grads)
+            with jax.named_scope("optimizer"):
+                new_state, lr = apply_update(state, grads)
             metrics = {"loss": mean_loss, "grad_norm": gnorm, "lr": lr,
                        "loss_scale": jnp.float32(1.0),
                        "skipped": jnp.bool_(False)}
@@ -1196,8 +1214,9 @@ class DeepSpeedEngine:
         from deepspeed_tpu.telemetry.numerics import block_sq_norms
 
         def grad_fn(params, scale, batch, rng):
-            grads, loss, aux, gnorm, finite, bstats = grad_core(
-                params, scale, batch, rng, want_numerics=numerics_on)
+            with jax.named_scope("fwd_bwd"):
+                grads, loss, aux, gnorm, finite, bstats = grad_core(
+                    params, scale, batch, rng, want_numerics=numerics_on)
             out = {"loss": loss, "grad_norm": gnorm,
                    "finite": finite, **aux}
             if numerics_on:
@@ -1222,6 +1241,7 @@ class DeepSpeedEngine:
         # an overflow-skipped step must keep the old params alive.
         donate = ((0,) if (self._offload_grad_stage or
                            not self.config.fp16.enabled) else ())
+        grad_fn.__name__ = grad_fn.__qualname__ = "train_offload_grads"
         self._offload_grad_fn = jax.jit(
             grad_fn,
             in_shardings=(param_in_sh, None, batch_sh, None),
@@ -1241,8 +1261,12 @@ class DeepSpeedEngine:
         t_disp = time.perf_counter()
         grads, metrics = self._offload_grad_fn(
             params_in, jnp.float32(scale), batch, rng)
+        t_sent = time.perf_counter()
         finite = bool(metrics["finite"])   # host sync — grads are ready
-        self._offload_device_s = time.perf_counter() - t_disp
+        t_ready = time.perf_counter()
+        self._offload_device_s = t_ready - t_disp
+        self._step_spans += [("train:dispatch", t_disp, t_sent),
+                             ("train:wait", t_sent, t_ready)]
         numer = metrics.pop("_numerics", None)
         lr = float(self.lr_scheduler(self.state.step))
         skipped = fp16 and not finite
@@ -1266,6 +1290,8 @@ class DeepSpeedEngine:
                     self.host_opt.step(grads_host, lr, self.compute_dtype),
                     self._state_shardings.params)
             self.state = self.state.replace(params=new_params)
+        self._step_spans.append(
+            ("train:host_optimizer", t_ready, time.perf_counter()))
         # step advances even when skipped — matches the in-HBM step_fn so
         # the lr schedule is identical across both paths
         self.state = self.state.replace(step=self.state.step + 1)
@@ -1302,9 +1328,13 @@ class DeepSpeedEngine:
         micro-batches (SURVEY §3.2)."""
         t_wall = time.perf_counter()   # goodput: the step wall interval
         data_wait = 0.0
+        ann = span_annotation("train:step", step=self.global_steps + 1)
+        self._step_spans = []
         if batch is None:
             batch = next(self.training_dataloader)
             data_wait = time.perf_counter() - t_wall
+            self._step_spans.append(
+                ("train:data", t_wall, t_wall + data_wait))
         batch = self._global_micro_batch(batch)
         leading = jax.tree.leaves(batch)[0].shape[0]
         expected = self.micro_batch_size * self.gas * \
@@ -1328,6 +1358,7 @@ class DeepSpeedEngine:
             self._record_step_trace(
                 time.perf_counter() - t_wall, data_wait,
                 getattr(self, "_offload_device_s", 0.0))
+            self._record_step_spans(t_wall, ann)
             return out
         if (self._sparse_grad_axes and self._step_fn is not None and
                 tuple(tuple(x.shape) for x in jax.tree.leaves(batch))
@@ -1376,12 +1407,18 @@ class DeepSpeedEngine:
         t_disp = time.perf_counter()
         self.state, metrics = self._step_fn(self.state, batch, rng,
                                             self._numerics_on)
+        t_sent = time.perf_counter()
+        self._step_spans.append(("train:dispatch", t_disp, t_sent))
         device_s = 0.0
         if self.goodput.enabled:
             # the goodput device bucket IS this sync: dispatch → outputs
-            # ready (the documented cost of telemetry.goodput)
+            # ready (the documented cost of telemetry.goodput); without
+            # the meter the step has no train:wait span — nothing here
+            # adds a sync
             jax.block_until_ready(metrics)
-            device_s = time.perf_counter() - t_disp
+            t_ready = time.perf_counter()
+            device_s = t_ready - t_disp
+            self._step_spans.append(("train:wait", t_sent, t_ready))
         if t_step is not None and self.global_steps > 0 and \
                 (self.global_steps + 1) % self.config.steps_per_print == 0:
             # wall_clock_breakdown (reference EngineTimers): the fused
@@ -1457,7 +1494,24 @@ class DeepSpeedEngine:
                                  data_wait, device_s)
         self._record_step_trace(time.perf_counter() - t_wall,
                                 data_wait, device_s)
+        self._record_step_spans(t_wall, ann)
         return metrics
+
+    def _record_step_spans(self, t_wall: float, ann) -> None:
+        """This step in the span log (telemetry/spans.py): ``train:step``
+        from the call to its return, and under it the intervals the step
+        already timed (``train:data`` / ``train:dispatch`` /
+        ``train:wait``, on the host-offload path ``train:host_optimizer``).
+        Dispatch is asynchronous: without a wait span the step's end is
+        the host's, not the device's."""
+        log = get_span_log()
+        sid = log.next_id()
+        for name, t0, t1 in self._step_spans:
+            log.record(name, t0, t1, parent=sid, key=self.global_steps)
+        log.record("train:step", t_wall, time.perf_counter(),
+                   key=self.global_steps, span_id=sid)
+        if ann is not None:
+            ann.__exit__(None, None, None)
 
     def _record_step_trace(self, wall: float, data_wait: float,
                            device_s: float) -> None:

@@ -28,6 +28,9 @@ from deepspeed_tpu.telemetry.compile_watch import (WatchedFunction,
                                                    all_watched,
                                                    compile_report,
                                                    executable_cost,
+                                                   kernel_table,
+                                                   phase_totals,
+                                                   scope_table,
                                                    watched_jit)
 from deepspeed_tpu.telemetry.config import (AccountingConfig,
                                             CanaryConfig,
@@ -68,7 +71,8 @@ from deepspeed_tpu.telemetry.registry import (DEFAULT_TIME_BUCKETS, Counter,
                                               sanitize_metric_name,
                                               set_registry)
 from deepspeed_tpu.telemetry.slo import SLOMonitor
-from deepspeed_tpu.telemetry.spans import span, timed
+from deepspeed_tpu.telemetry.spans import (SpanLog, get_span_log,
+                                            set_span_log, span, timed)
 from deepspeed_tpu.telemetry.step_profile import (NULL_STEP_HANDLE,
                                                   StepProfiler)
 from deepspeed_tpu.telemetry.tracing import (Trace, Tracer, TraceSpan,
@@ -80,12 +84,14 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricRegistry",
     "DEFAULT_TIME_BUCKETS", "exponential_buckets", "get_registry",
     "set_registry", "sanitize_metric_name", "span", "timed",
+    "SpanLog", "get_span_log", "set_span_log",
     "TelemetryHTTPServer", "start_http_server", "ProfilerCapture",
     "TelemetryConfig", "SLOConfig",
     # flight recorder (events ring / compile watch / memory / watchdog)
     "EventRing", "get_event_ring", "set_event_ring", "record_event",
     "install_fault_dump", "WatchedFunction", "watched_jit",
     "compile_report", "all_watched", "executable_cost",
+    "phase_totals", "scope_table", "kernel_table",
     "MemoryMonitor", "get_memory_monitor", "set_memory_monitor",
     "Watchdog",
     # training numerics observatory + goodput accounting

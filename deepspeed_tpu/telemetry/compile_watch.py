@@ -30,6 +30,26 @@ outer trace (``jax.eval_shape`` / ``jit`` / ``grad`` around a watched
 function): a ``Compiled`` takes no tracers, so such a call goes to the
 plain ``jax.jit`` and is inlined into the outer program, unrecorded.
 
+Names and phases (docs/observability.md "Spans"):
+
+* the jitted callable carries the watch's name, so the compiled module
+  and the device trace's ``XLA Modules`` events read
+  ``jit_serve_decode``, never ``jit__unknown``;
+* every compile leaves a ``compile:<program>`` span in the span log with
+  its ``compile:trace`` / ``compile:lower`` and ``compile:backend_compile``
+  or ``compile:cache_read`` children;
+* ``jax.monitoring`` listeners, registered once
+  (:func:`install_phase_listeners`), keep per function name jax reports
+  (watched or not) the seconds traced, lowered, compiled and read from
+  the persistent cache, and hit / miss counts: :func:`phase_totals`;
+* each compiled program's optimized HLO is parsed on demand into a
+  table ``instruction name -> scopes`` (and ``-> kernel name`` for
+  custom calls): :func:`scope_table`, :func:`kernel_table`. The device
+  trace names instructions but carries no ``op_name``, and the
+  executables are gone by the time a trace is read: a watched
+  function's modules are taken when it is finalized, so the tables
+  survive it and setting a program up pays nothing for them.
+
 Hot-path cost: the cache-hit path is one C-level ``tree_flatten`` plus
 an O(leaves) python key build and the AOT ``Compiled.__call__``
 (measured ~90 µs/call over plain jit dispatch on a 40-leaf tree, CPU) —
@@ -39,6 +59,8 @@ catches. Path strings and signature diffs are built only on a miss.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import re
 import threading
 import time
 import weakref
@@ -46,6 +68,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import deepspeed_tpu.telemetry.events as _ev
 from deepspeed_tpu.telemetry.registry import MetricRegistry, get_registry
+from deepspeed_tpu.telemetry.spans import annotation, get_span_log
 
 # compile times span ~1 ms (tiny CPU test program) to ~30 min (cold
 # multi-host train step); the default 100 µs ladder covers it
@@ -113,6 +136,326 @@ def executable_cost(compiled) -> Dict[str, float]:
     return out
 
 
+# ------------------------------------------------ compile phases (monitoring)
+
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+_phase_lock = threading.Lock()
+_phases: Dict[str, Dict[str, float]] = {}
+# persistent-cache hits jax announced, and how many of them a backend
+# event has been counted as (a hit is announced inside its interval)
+_hits = {"seen": 0, "claimed": 0}
+_listening = [False]
+_tracing = threading.local()  # this thread's open and just-ended traces
+
+
+def _new_phase_row() -> Dict[str, float]:
+    return {"trace_s": 0.0, "lower_s": 0.0, "compile_s": 0.0,
+            "cache_read_s": 0.0, "text_s": 0.0, "traces": 0, "compiles": 0,
+            "cache_hits": 0, "cache_misses": 0}
+
+
+def _program_of(fun_name: Optional[str]) -> str:
+    """jax reports a function's own name while tracing and the module's
+    (``jit(<name>)`` / ``jit_<name>``) while lowering and compiling: one
+    key for both."""
+    name = fun_name or "_unnamed_"
+    if name.startswith("jit(") and name.endswith(")"):
+        return name[4:-1]
+    return name[4:] if name.startswith("jit_") else name
+
+
+def _on_begin(event: str, value: float, **kw) -> None:
+    if event == TRACE_EVENT:
+        _tracing.depth = getattr(_tracing, "depth", 0) + 1
+
+
+def _own_trace_seconds(start: float, seconds: float) -> float:
+    """A trace's seconds less those of the traces that ran inside it
+    (a jitted function traced while an outer one was being traced
+    reports first, and the outer one's interval covers it): summed over
+    functions, ``trace_s`` is then wall time and counts nothing twice."""
+    ended = getattr(_tracing, "ended", None)
+    if ended is None:
+        ended = _tracing.ended = []
+    inner = 0.0
+    while ended and ended[-1][0] >= start:
+        inner += ended.pop()[1]
+    depth = _tracing.depth = max(getattr(_tracing, "depth", 1) - 1, 0)
+    if depth:
+        ended.append((start, seconds))     # for the trace still open
+    else:
+        ended.clear()
+    return max(seconds - inner, 0.0)
+
+
+def _on_span(event: str, start: float, end: float, **kw) -> None:
+    field = {TRACE_EVENT: "trace_s", LOWER_EVENT: "lower_s",
+             BACKEND_EVENT: "compile_s"}.get(event)
+    if field is None:
+        return
+    seconds = end - start
+    if event == TRACE_EVENT:
+        seconds = _own_trace_seconds(start, seconds)
+    with _phase_lock:
+        row = _phases.setdefault(_program_of(kw.get("fun_name")),
+                                 _new_phase_row())
+        if event == TRACE_EVENT:
+            row["traces"] += 1
+        if event == BACKEND_EVENT:
+            # jax times compile_or_get_cached as one interval; a hit of
+            # the persistent cache was announced inside it
+            row["compiles"] += 1
+            if _hits["seen"] > _hits["claimed"]:
+                row["cache_hits"] += 1
+                field = "cache_read_s"
+            else:
+                row["cache_misses"] += 1
+            _hits["claimed"] = _hits["seen"]
+        row[field] += seconds
+
+
+def _on_event(event: str, **kw) -> None:
+    if event == CACHE_HIT_EVENT:
+        with _phase_lock:
+            _hits["seen"] += 1
+
+
+def install_phase_listeners() -> None:
+    """Register the ``jax.monitoring`` listeners behind
+    :func:`phase_totals`, once per process (``import deepspeed_tpu``
+    does, so a program compiled before the first watch is counted)."""
+    with _phase_lock:
+        if _listening[0]:
+            return
+        _listening[0] = True
+    import jax
+    jax.monitoring.register_scalar_listener(_on_begin)
+    jax.monitoring.register_event_time_span_listener(_on_span)
+    jax.monitoring.register_event_listener(_on_event)
+
+
+def phase_totals() -> Dict[str, Dict[str, float]]:
+    """Per function name jax reported (watched or not): seconds tracing
+    its own body (``trace_s``: functions traced inside it are under
+    their own names), lowering to MLIR (``lower_s``), in backend
+    compiles the persistent cache did not serve (``compile_s``) and in
+    those it did (``cache_read_s``), for a watched program the seconds
+    taking its modules for the scope table at teardown (``text_s``), with
+    the counts ``traces``, ``compiles``, ``cache_hits``,
+    ``cache_misses`` (``compiles`` = hits + misses; with the cache off
+    every compile is a miss)."""
+    with _phase_lock:
+        return {k: dict(v) for k, v in _phases.items()}
+
+
+def _named(fun, name: str):
+    """``fun`` under the watch's name. ``jax.jit`` names the compiled
+    module after ``fun.__name__`` and a ``functools.partial`` has none
+    (``jit__unknown``); the wrapper keeps the signature jit resolves
+    ``static_argnames`` / ``donate_argnames`` against."""
+    @functools.wraps(fun)
+    def named(*args, **kwargs):
+        return fun(*args, **kwargs)
+    named.__name__ = named.__qualname__ = name
+    return named
+
+
+# ------------------------------------------------- scopes on the device
+
+# the scopes the programs open (model_implementations/transformer.py,
+# inference/kv_cache.py, inference/server.py, runtime/engine.py)
+SCOPES = frozenset((
+    "embed", "ln", "attn_qkv", "kv_write", "kv_read", "attn_kernel",
+    "attn_out", "mlp", "lm_head", "sample",
+    "fwd_bwd", "optimizer", "grad_exchange"))
+
+_INSTR = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+)\s*=\s")
+_COMPUTATION = re.compile(
+    r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->.*\{\s*$")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"(?:calls|to_apply|body)=%?([\w.\-]+)")
+_OPERAND = re.compile(r"\(\s*(?:[\w\[\]{},:()\s]*?)%([\w.\-]+)")
+_WORD = re.compile(r"[A-Za-z_][A-Za-z0-9_]*")
+# an instruction that only moves or forwards data: where the compiler
+# gave it no metadata (it gives a memory-space copy none) it takes its
+# operand's scope, and failing that its first scoped consumer's
+_FORWARDS = ("copy-start", "copy-done", "async-start", "async-done",
+             "slice-start", "slice-done", "all-gather-done",
+             "all-reduce-done", "collective-permute-done",
+             "get-tuple-element", "bitcast")
+_NAMES = re.compile(r"%([\w.\-]+)")
+
+_texts: Dict[str, list] = {}     # program -> modules of executables now gone
+KEEP_EXECUTABLES = 8             # of them, per program name
+_tables: Dict[str, tuple] = {}   # program -> (sources parsed, its tables)
+_tables_lock = threading.Lock()
+
+
+def scopes_of(op_name: str) -> Optional[str]:
+    """``jit(serve_decode)/kv_read/slice`` -> ``kv_read``;
+    ``jit(train_step)/fwd_bwd/transpose(jvp(mlp))/dot_general`` ->
+    ``fwd_bwd/mlp``: the known scopes on an instruction's path,
+    outermost first (the innermost is the last part), or None."""
+    found = []
+    # where the compiler merged instructions it joined their op_names
+    # with ";", the consumer first: the producer's (last) path is kept,
+    # so decode's pool ``squeeze`` stays ``kv_read`` after merging with
+    # the kernel's input reshape
+    for part in op_name.rsplit(";", 1)[-1].split("/")[:-1]:
+        for word in _WORD.findall(part):
+            if word in SCOPES and (not found or found[-1] != word):
+                found.append(word)
+    return "/".join(found) if found else None
+
+
+def parse_scopes(text: str) -> Tuple[Dict[str, Optional[str]],
+                                     Dict[str, str]]:
+    """A compiled program's text (``compiled.as_text()``) to
+    ``({instruction: scopes or None}, {custom-call instruction: kernel
+    name})``. An instruction's scopes come from its own ``op_name``
+    metadata; a fusion (or any call) without one takes its called
+    computation's root's; a copy (``copy-start`` / ``copy-done``, the
+    offload stream's transfers among them), ``get-tuple-element`` or
+    ``bitcast`` without one its first operand's and, failing that, its
+    first scoped consumer's."""
+    scope: Dict[str, Optional[str]] = {}
+    kernels: Dict[str, str] = {}
+    roots: Dict[str, str] = {}          # computation -> its root instruction
+    calls: Dict[str, str] = {}          # instruction -> computation it calls
+    forwards: Dict[str, str] = {}       # instruction -> its first operand
+    users: Dict[str, List[str]] = {}    # such an instruction -> consumers
+    computation = None
+    for line in text.splitlines():
+        m = _INSTR.match(line)
+        if m is None:
+            c = _COMPUTATION.match(line)
+            if c is not None:
+                computation = c.group(1)
+            continue
+        name = m.group(2)
+        if m.group(1) and computation is not None:
+            roots[computation] = name
+        op = _OP_NAME.search(line)
+        found = scopes_of(op.group(1)) if op else None
+        scope[name] = found
+        rhs = line[m.end():]
+        if "custom_call_target=\"tpu_custom_call\"" in rhs:
+            kernels[name] = ".".join(
+                p for p in name.split(".") if not p.isdigit())
+        if found is None:
+            c = _CALLS.search(rhs)
+            if c is not None:
+                calls[name] = c.group(1)
+            elif any(f" {k}(" in rhs for k in _FORWARDS):
+                o = _OPERAND.search(rhs[rhs.index("("):]
+                                    if "(" in rhs else "")
+                if o is not None:
+                    forwards[name] = o.group(1)
+                users[name] = []
+        for operand in _NAMES.findall(rhs):
+            if operand in users:
+                users[operand].append(name)
+    for name, comp in calls.items():
+        root = roots.get(comp)
+        if root is not None and scope.get(root) is not None:
+            scope[name] = scope[root]
+    for _ in range(3):                  # done(start(...)) chains are short
+        for name, src in forwards.items():
+            if scope.get(name) is None and scope.get(src) is not None:
+                scope[name] = scope[src]
+    for _ in range(3):                  # start <- done <- the consumer
+        for name, used_by in users.items():
+            if scope.get(name) is None:
+                scope[name] = next((scope[u] for u in used_by
+                                    if scope.get(u) is not None), None)
+    return scope, kernels
+
+
+def _modules_of(compiled):
+    """What a scope table is parsed from: the executable's optimized
+    HLO modules (host objects; rendering them to text waits for the
+    first reader), or its text where it cannot hand its modules out."""
+    try:
+        return compiled.runtime_executable().hlo_modules()
+    except Exception:  # noqa: BLE001 — e.g. compiled for a described device
+        return compiled.as_text()
+
+
+def _harvest(name: str, records: List["ExecutableRecord"]) -> None:
+    """Finalizer of a :class:`WatchedFunction`: take its executables'
+    modules just before they go (``engine.destroy()`` /
+    ``server.close()`` come before a trace is read). Taking them costs
+    tenths of a second for a large program, so it is done here, at
+    teardown, and never while a program is being set up."""
+    t0 = time.perf_counter()
+    kept = []
+    for rec in records:
+        try:
+            kept.append(_modules_of(rec.compiled))
+        except Exception:  # noqa: BLE001 — a table is best-effort
+            pass
+    with _phase_lock:
+        _phases.setdefault(name, _new_phase_row())["text_s"] += \
+            time.perf_counter() - t0
+    with _tables_lock:
+        # bounded: the newest executables of a name (its prompt buckets
+        # and retraces), however many servers a process builds and drops
+        _texts[name] = (_texts.get(name, []) + kept)[-KEEP_EXECUTABLES:]
+        _tables.pop(name, None)
+
+
+def _tables_for(name: str):
+    live = [rec.compiled for w in all_watched() if w.name == name
+            for rec in w._records]
+    with _tables_lock:
+        kept = list(_texts.get(name, ()))
+        got = _tables.get(name)
+        if got is not None and got[0] == (len(kept), len(live)):
+            return got[1]
+    scope: Dict[str, Optional[str]] = {}
+    kernels: Dict[str, str] = {}
+    for source in kept + [_modules_of(c) for c in live]:
+        sc, kn = parse_scopes(
+            source if isinstance(source, str) else
+            "\n\n".join(m.to_string() for m in source))
+        for k, v in sc.items():
+            # executables of one program (prompt buckets) may number
+            # their instructions alike: a name that means two scopes
+            # means none
+            scope[k] = v if scope.get(k, v) == v else None
+        kernels.update(kn)
+    with _tables_lock:
+        _tables[name] = ((len(kept), len(live)), (scope, kernels))
+    return scope, kernels
+
+
+def scope_table(name: str) -> Dict[str, Optional[str]]:
+    """For the watched program ``name`` (every executable compiled under
+    it in this process): compiled instruction name -> the known scopes on
+    its path, ``"fwd_bwd/mlp"`` (innermost last), or None where it has
+    none. Parsed on demand from the live executables' modules and from
+    those harvested when a watched function went; survives the
+    executables."""
+    return _tables_for(name)[0]
+
+
+def kernel_table(name: str) -> Dict[str, str]:
+    """Custom-call (Pallas) instruction name -> kernel name, for the
+    watched program ``name``."""
+    return _tables_for(name)[1]
+
+
+def watched_programs() -> List[str]:
+    """Names a table can be made for: live or harvested."""
+    with _tables_lock:
+        kept = set(_texts)
+    return sorted(kept | {w.name for w in all_watched() if w._records})
+
+
 @dataclasses.dataclass
 class ExecutableRecord:
     """One compiled executable of a watched function."""
@@ -145,9 +488,10 @@ class WatchedFunction:
                  registry: Optional[MetricRegistry] = None,
                  ring: Optional[_ev.EventRing] = None, **jit_kwargs):
         import jax
+        install_phase_listeners()
         self._fun = fun
         self.name = name
-        self._jit = jax.jit(fun, **jit_kwargs)
+        self._jit = jax.jit(_named(fun, name), **jit_kwargs)
         self._registry = registry
         self._ring = ring
         self._static_names = tuple(jit_kwargs.get("static_argnames") or ())
@@ -168,6 +512,8 @@ class WatchedFunction:
             _watched_counter[0] += 1
             self._order_id = _watched_counter[0]
         _watched.add(self)
+        # the scope tables outlive the executables (see _harvest)
+        weakref.finalize(self, _harvest, name, self._records).atexit = False
 
     @staticmethod
     def _positional_names(fun) -> List[str]:
@@ -310,9 +656,29 @@ class WatchedFunction:
                      "(the silent-stall regression — see "
                      "docs/observability.md)",
                 labels={"fn": self.name}).inc()
+        index = len(self._records)
+        ann = annotation("compile:" + self.name, index=index)
+        hits0 = _hits["seen"]
         t0 = time.perf_counter()   # a compile error raises from here
-        compiled = self._jit.lower(*args, **kwargs).compile()
-        dt = time.perf_counter() - t0
+        traced = self._jit.trace(*args, **kwargs)
+        t1 = time.perf_counter()
+        lowered = traced.lower()
+        t2 = time.perf_counter()
+        compiled = lowered.compile()
+        t3 = time.perf_counter()
+        dt = t3 - t0
+        cache_hit = _hits["seen"] > hits0
+        log = get_span_log()
+        sid = log.next_id()
+        for phase, a, b in (("trace", t0, t1), ("lower", t1, t2),
+                            ("cache_read" if cache_hit
+                             else "backend_compile", t2, t3)):
+            log.record("compile:" + phase, a, b, parent=sid, key=index)
+        log.record("compile:" + self.name, t0, t3, key=index, span_id=sid,
+                   attrs={"program": self.name, "index": index,
+                          "cache_hit": cache_hit})
+        if ann is not None:
+            ann.__exit__(None, None, None)
         cost = executable_cost(compiled)
         rec = ExecutableRecord(
             index=len(self._records), summary=summary, leaves=leaves,
@@ -450,7 +816,7 @@ def compile_report() -> str:
     after-the-fact answer to "why did that step take 40 s"."""
     watched = all_watched()
     if not watched:
-        return "compile report: no watched functions"
+        return "compile report: no watched functions\n" + phase_report()
     total_execs = sum(len(w._records) for w in watched)
     total_re = sum(len(w.retraces) for w in watched)
     total_s = sum(r.compile_seconds for w in watched for r in w._records)
@@ -458,4 +824,24 @@ def compile_report() -> str:
              f"{total_execs} executable(s), {total_re} retrace(s), "
              f"{total_s:.2f} s total compile time"]
     lines += [w.report() for w in watched]
+    return "\n".join(lines + [phase_report()])
+
+
+def phase_report(top: int = 12) -> str:
+    """Where compile time went, by the function name jax reported
+    (watched or not): tracing, lowering, backend compiles and reads of
+    the persistent cache, slowest first."""
+    rows = sorted(phase_totals().items(),
+                  key=lambda kv: -(kv[1]["trace_s"] + kv[1]["lower_s"]
+                                   + kv[1]["compile_s"]
+                                   + kv[1]["cache_read_s"]))
+    lines = ["compile phases (s): function trace(own) lower compile "
+             "cache_read hits misses"]
+    for name, r in rows[:top]:
+        lines.append(
+            f"  {name}: {r['trace_s']:.3f} {r['lower_s']:.3f} "
+            f"{r['compile_s']:.3f} {r['cache_read_s']:.3f} "
+            f"{r['cache_hits']} {r['cache_misses']}")
+    if len(rows) > top:
+        lines.append(f"  … and {len(rows) - top} more")
     return "\n".join(lines)

@@ -375,10 +375,6 @@ class TelemetryConfig(DeepSpeedConfigModel):
     # output byte-identical and registers none of the serve_step_* /
     # serve_kv_block_* metric families.
     step_profile: bool = True
-    # sample every Nth profiled step's ordered phase slices into the
-    # flight-recorder ring (rendered by dump_timeline as the "server
-    # host" track); 0 = no ring/timeline sampling
-    step_profile_events_every: int = 32
     # serving SLO gates (telemetry/slo.py) — see the SLOConfig schema
     slo: SLOConfig = Field(default_factory=SLOConfig)
     # synthetic canary prober (telemetry/canary.py) — see CanaryConfig
@@ -433,15 +429,6 @@ class TelemetryConfig(DeepSpeedConfigModel):
             raise ValueError(
                 f"{info.field_name} must be > 0 seconds (or null to "
                 f"disable), got {v}")
-        return v
-
-    @field_validator("step_profile_events_every")
-    @classmethod
-    def _valid_every(cls, v):
-        if v < 0:
-            raise ValueError(
-                "step_profile_events_every must be >= 0 (0 = no ring/"
-                f"timeline sampling), got {v}")
         return v
 
     @field_validator("numerics_block_depth")

@@ -53,9 +53,11 @@ FAULT_INJECTED = "fault_injected"
 # decoding"): rolling acceptance rate collapsed — every verify forward
 # is wasted width until the workload turns lookup-friendly again
 SPEC_COLLAPSE = "spec_collapse"
-# serving step observatory (telemetry/step_profile.py): every Nth
-# step's ordered phase slices — dump_timeline's "server host" track
-SERVER_STEP_PROFILE = "server_step_profile"
+# serving step observatory (telemetry/step_profile.py): a worked step
+# whose wall passed max(0.4 s, 8 x the running median) — its phase
+# spans, chain depth and the program it waited on (the steps themselves
+# live in the span log, telemetry/spans.py)
+SLOW_STEP = "slow_step"
 # KV-pool famine (telemetry/memory.py KVPoolAccountant): an allocation
 # the pool could not cover froze the allocator state here — one event
 # per famine episode, re-armed by the next successful allocation

@@ -69,21 +69,60 @@ accounting"):
 cumulative wall — the serving sibling of ``train_goodput_fraction``;
 ``1 - fraction`` is the host tax.
 
-Host-pure: no jax import. Config-gated by ``telemetry.step_profile``
-(default ON — the cost is a handful of clock reads and histogram
-observes per step); ``telemetry.step_profile_events_every`` samples
-every Nth step's ordered phase slices into the flight-recorder ring,
-where ``Tracer.dump_timeline`` renders them as a "server host" track
-beside the request and device tracks.
+Every worked step also leaves its spans in the process span log
+(:mod:`telemetry.spans`): one ``serve:step`` record and, parented under
+it, one ``serve:<phase>`` record per mark interval, so the phase spans
+of a step tile it exactly (the sum identity, on spans). ``dispatch``
+spans carry the dispatch gap and chain depth observed at their opening
+boundary, ``dispatch`` / ``sync_wait`` the watched program's name.
+``Tracer.dump_timeline`` renders them as the "server host" track. While
+a profiler session runs, the same intervals are ``TraceMe`` events on
+``/host:CPU``: ``serve:step`` and, because a mark names an interval
+only when it closes, ``serve:phase`` with the phase as its ``phase``
+stat. A worked step slower than ``max(SLOW_STEP_S, SLOW_STEP_FACTOR x
+running median)`` leaves one ``slow_step`` event in the flight-recorder
+ring with its phases, chain depth and the program it waited on.
+
+Host-pure: no jax import (the annotations go through
+``telemetry.spans.annotation``). Config-gated by
+``telemetry.step_profile`` (default ON — the cost is a handful of clock
+reads, tuple appends and histogram observes per step).
 """
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from collections import deque
+from statistics import median
 from typing import Callable, Deque, Dict, List, Optional
 
 from deepspeed_tpu.telemetry.registry import MetricRegistry, get_registry
+from deepspeed_tpu.telemetry.spans import SpanLog, annotation, get_span_log
+
+# THE phase vocabulary (module docstring); every span name derives from it
+PHASES = ("admission", "prefill_chunk", "propose", "dispatch", "sync_wait",
+          "commit", "publish", "other")
+STEP_SPAN = "serve:step"
+FLUSH_SPAN = "serve:flush"
+PHASE_ANNOTATION = "serve:phase"
+# the request lifecycle the server records from its own stamps: the
+# three phases tile the request, each requeue its own queue_wait
+REQUEST_SPAN = "serve:request"
+QUEUE_SPAN = "serve:queue_wait"
+PREFILL_SPAN = "serve:prefill"
+DECODE_SPAN = "serve:decode"
+_PHASE_SPAN = {p: "serve:" + p for p in PHASES}
+
+_UIDS = itertools.count(1)
+
+# a worked step is slow when its wall passes both (flight-recorder
+# ``slow_step`` event); the median runs over the last SLOW_STEP_WINDOW
+# worked steps and needs SLOW_STEP_MIN_HISTORY of them
+SLOW_STEP_S = 0.4
+SLOW_STEP_FACTOR = 8.0
+SLOW_STEP_WINDOW = 64
+SLOW_STEP_MIN_HISTORY = 8
 
 # phases whose whole interval is device-attributed (the program runs /
 # the host blocks on it); prefill intervals attribute via
@@ -114,7 +153,11 @@ class _NullStepHandle:
     __slots__ = ()
 
     def mark(self, phase: str, now: Optional[float] = None,
-             dispatch: bool = False, fetch: bool = False) -> None:
+             dispatch: bool = False, fetch: bool = False,
+             program: Optional[str] = None) -> None:
+        return None
+
+    def flush_span(self, t0: float, reason: str, programs: int) -> None:
         return None
 
     def device_interval(self, t0: float, t1: float,
@@ -130,7 +173,8 @@ class _NullStepHandle:
     def pipelined_mode(self) -> None:
         return None
 
-    def finish(self, live: bool = True) -> None:
+    def finish(self, live: bool = True, slots: Optional[int] = None,
+               admitted: Optional[int] = None) -> None:
         return None
 
 
@@ -141,9 +185,10 @@ class _StepHandle:
     """One step's phase accounting (reused across steps — ``begin()``
     resets it; the serving loop is single-threaded per server)."""
 
-    __slots__ = ("_prof", "_t0", "_last", "acc", "device", "_sampled",
-                 "slices", "worked", "_pipelined_since",
-                 "_pipelined_mode")
+    __slots__ = ("_prof", "_t0", "_last", "acc", "device", "spans",
+                 "seq", "span_id", "worked", "_pipelined_since",
+                 "_pipelined_mode", "_dispatch_attrs", "program",
+                 "_ann_step", "_ann_phase")
 
     def __init__(self, prof: "StepProfiler"):
         self._prof = prof
@@ -151,8 +196,18 @@ class _StepHandle:
         self._last = 0.0
         self.acc: Dict[str, float] = {}
         self.device = 0.0
-        self._sampled = False
-        self.slices: List[List[float]] = []
+        # this step's closed span records (telemetry.spans layout),
+        # written to the log at finish() — only then is it known
+        # whether the step worked
+        self.spans: List[tuple] = []
+        self.seq = 0          # the step number: the spans' shared key
+        self.span_id = 0      # id of this step's serve:step record
+        # gap/depth observed at a dispatch boundary, for the dispatch
+        # span that opens there; the program last dispatched or fetched
+        self._dispatch_attrs: Optional[dict] = None
+        self.program: Optional[str] = None
+        self._ann_step = None
+        self._ann_phase = None
         # did this step engage the device at all (decode/verify/prefill
         # dispatch)? A workless idle poll must not accumulate into the
         # goodput fraction — it would track traffic pattern, not host
@@ -162,31 +217,56 @@ class _StepHandle:
         self._pipelined_since: Optional[float] = None
         self._pipelined_mode = False
 
-    def _reset(self, now: float, sampled: bool) -> None:
+    def _reset(self, now: float, seq: int, span_id: int) -> None:
         self._t0 = now
         self._last = now
         self.acc = {}
         self.device = 0.0
-        self._sampled = sampled
-        self.slices = []
+        self.spans = []
+        self.seq = seq
+        self.span_id = span_id
         self.worked = False
         self._pipelined_since = None
         self._pipelined_mode = False
+        self._dispatch_attrs = None
+        self.program = None
+        self._ann_step = annotation(STEP_SPAN, step=seq)
+        self._ann_phase = annotation(PHASE_ANNOTATION)
+
+    def _span(self, phase: str, t0: float, t1: float,
+              attrs: Optional[dict]) -> None:
+        """One closed phase interval: a span record for finish() to
+        write, and the profiler range that was opened at ``t0``."""
+        if t1 > t0:
+            self.spans.append((
+                _PHASE_SPAN.get(phase) or "serve:" + phase, t0, t1,
+                self.span_id, self._prof.span_log.next_id(), self.seq,
+                attrs))
+        a = self._ann_phase
+        if a is not None:
+            a.set_metadata(phase=phase, **{
+                k: v for k, v in (attrs or {}).items() if v is not None})
+            a.__exit__(None, None, None)
+            self._ann_phase = None
 
     def mark(self, phase: str, now: Optional[float] = None,
-             dispatch: bool = False, fetch: bool = False) -> float:
+             dispatch: bool = False, fetch: bool = False,
+             program: Optional[str] = None) -> float:
         """Close the interval since the previous mark and attribute it
         to ``phase``. ``dispatch=True`` flags this boundary as a device
         program dispatch (the dispatch gap is observed against the last
         fetch); ``fetch=True`` flags it as a result-fetch completion
-        (the device went idle here). Returns the boundary time so the
-        caller can reuse the clock read."""
+        (the device went idle here). ``program`` names the watched
+        program the interval dispatched or waited on. Returns the
+        boundary time so the caller can reuse the clock read."""
         prof = self._prof
         if now is None:
             now = prof.clock()
-        dt = now - self._last
+        last = self._last
+        dt = now - last
         if dt < 0.0:            # clock weirdness must not corrupt sums
             dt = 0.0
+            now = last
         self._last = now
         self.acc[phase] = self.acc.get(phase, 0.0) + dt
         if phase in DEVICE_PHASES and not self._pipelined_mode:
@@ -194,14 +274,31 @@ class _StepHandle:
             # INSIDE the explicitly-credited busy windows — crediting
             # both would double count
             self.device += dt
-        if self._sampled and dt > 1e-9:
-            self.slices.append([phase, dt])
+        attrs = None
+        if program is not None:
+            self.program = program
+            attrs = {"program": program}
+        if phase == "dispatch" and self._dispatch_attrs is not None:
+            attrs = dict(self._dispatch_attrs, **(attrs or {}))
+            self._dispatch_attrs = None
+        self._span(phase, last, now, attrs)
         if dispatch:
             self.worked = True
-            prof._note_dispatch(now)
+            self._dispatch_attrs = prof._note_dispatch(now)
         if fetch:
             prof._note_fetch(now)
+        self._ann_phase = annotation(PHASE_ANNOTATION)
         return now
+
+    def flush_span(self, t0: float, reason: str, programs: int) -> None:
+        """A pipeline flush that ran inside this step, from ``t0`` to
+        now: a ``serve:flush`` record beside the phase spans (it covers
+        the ``sync_wait`` / ``commit`` intervals of the programs it
+        fetched)."""
+        self.spans.append((
+            FLUSH_SPAN, t0, self._prof.clock(), self.span_id,
+            self._prof.span_log.next_id(), self.seq,
+            {"reason": reason, "programs": programs}))
 
     def device_interval(self, t0: float, t1: float,
                         note_dispatch: bool = True) -> None:
@@ -251,9 +348,12 @@ class _StepHandle:
         a later :meth:`pipelined` tail (the async verify round)."""
         self._pipelined_mode = True
 
-    def finish(self, live: bool = True) -> None:
+    def finish(self, live: bool = True, slots: Optional[int] = None,
+               admitted: Optional[int] = None) -> None:
         """Close the step: the tail since the last mark becomes the
         ``other`` residual, and ``wall == sum(phases)`` exactly.
+        ``slots`` (resident after the step) and ``admitted`` (into a
+        slot during it) ride on the ``serve:step`` span.
 
         ``live=False`` (no sequences resident after this step) resets
         the dispatch-gap baseline: with nothing to decode the device is
@@ -261,11 +361,10 @@ class _StepHandle:
         way — a traffic lull must never read as a multi-second
         dispatch gap (it would dominate the p90 the async-loop A/B is
         judged on, keyed to load pattern instead of host tax)."""
-        end = self._prof.clock()
-        tail = max(end - self._last, 0.0)
+        end = max(self._prof.clock(), self._last)
+        tail = end - self._last
         self.acc["other"] = self.acc.get("other", 0.0) + tail
-        if self._sampled and tail > 1e-9:
-            self.slices.append(["other", tail])
+        self._span("other", self._last, end, None)
         wall = max(end - self._t0, 0.0)
         if self._pipelined_since is not None:
             # additive, then clamped: phase slivers in DEVICE_PHASES may
@@ -277,7 +376,11 @@ class _StepHandle:
             self.device = wall
         if not live:
             self._prof._last_fetch = None
-        self._prof._record(wall, self)
+        self._prof._record(wall, self, end, slots, admitted)
+        a = self._ann_step
+        if a is not None:
+            self._ann_step = None
+            a.__exit__(None, None, None)
 
 
 class StepProfiler:
@@ -285,24 +388,28 @@ class StepProfiler:
 
     ``clock`` defaults to ``time.perf_counter`` and should be the
     SERVER's clock so fake-clock chaos tests drive the profiler
-    coherently with deadlines and SLO windows. ``events_every`` samples
-    every Nth profiled step's ordered phase slices into the event ring
-    (0 = never) — the timeline track's source. Thread-safety: the
-    serving loop writes, the scrape endpoint reads ``snapshot()``.
+    coherently with deadlines and SLO windows; the spans it writes to
+    ``span_log`` (default: the process log) carry that clock's readings.
+    Thread-safety: the serving loop writes, the scrape endpoint reads
+    ``snapshot()``.
     """
 
     def __init__(self, registry: Optional[MetricRegistry] = None,
                  clock: Callable[[], float] = time.perf_counter,
-                 events_every: int = 32, source: str = "serve"):
-        if events_every < 0:
-            raise ValueError(
-                f"events_every must be >= 0 (0 = no ring/timeline "
-                f"sampling), got {events_every}")
+                 source: str = "serve",
+                 span_log: Optional[SpanLog] = None):
         self.registry = registry if registry is not None else get_registry()
         self.clock = clock
-        self.events_every = int(events_every)
         self.source = source
+        self.span_log = span_log if span_log is not None else get_span_log()
+        # which profiler wrote a serve:step span (several servers share
+        # the process log and may share a source name)
+        self.uid = next(_UIDS)
         self._lock = threading.Lock()
+        self._seq = 0            # step() calls seen: the spans' key
+        self._idle_run = 0       # consecutive workless polls
+        self._recent_walls: Deque[float] = deque(maxlen=SLOW_STEP_WINDOW)
+        self.slow_steps = 0
         self.steps = 0
         self.wall_total = 0.0
         self.device_total = 0.0
@@ -379,12 +486,15 @@ class StepProfiler:
         """Start profiling one ``step()`` call; returns the handle the
         loop marks phase boundaries on. A handle must be ``finish()``ed
         before the next ``begin()`` (single-threaded serving loop)."""
-        sampled = self.events_every > 0 and \
-            (self.steps % self.events_every == 0)
-        self._handle._reset(self.clock(), sampled)
+        self._seq += 1
+        self._handle._reset(self.clock(), self._seq,
+                            self.span_log.next_id())
         return self._handle
 
-    def _note_dispatch(self, now: float) -> None:
+    def _note_dispatch(self, now: float) -> dict:
+        """Observe the dispatch boundary at ``now``; returns the gap and
+        chain depth seen there (``gap_s`` None: no fetch to measure
+        against), for the ``serve:dispatch`` span."""
         if self.outstanding > 0:
             # another program is still in flight: the device moves
             # straight from it to this one — zero idle by construction.
@@ -400,13 +510,14 @@ class StepProfiler:
                 self.pipelined_dispatches += 1
                 self._recent_gaps.append(0.0)
                 self.depth_hist[depth] = self.depth_hist.get(depth, 0) + 1
-            return
+            # zero by construction: countable as such on the span
+            return {"gap_s": 0.0, "depth": depth, "busy": True}
         self.outstanding = 1
         self._h_depth.observe(1.0)
         if self._last_fetch is None:
             with self._lock:
                 self.depth_hist[1] = self.depth_hist.get(1, 0) + 1
-            return
+            return {"gap_s": None, "depth": 1, "busy": False}
         gap = max(now - self._last_fetch, 0.0)
         self._last_fetch = None      # one gap per idle span
         self._h_gap.observe(gap)
@@ -418,6 +529,7 @@ class StepProfiler:
             self.depth_hist[1] = self.depth_hist.get(1, 0) + 1
             self.depth_gap_total[1] = \
                 self.depth_gap_total.get(1, 0.0) + gap
+        return {"gap_s": gap, "depth": 1, "busy": False}
 
     def _note_fetch(self, now: float) -> None:
         self.outstanding = max(self.outstanding - 1, 0)
@@ -455,16 +567,43 @@ class StepProfiler:
             self._phase_hist[phase] = h
         return h
 
-    def _record(self, wall: float, handle: _StepHandle) -> None:
+    def _write_spans(self, handle: _StepHandle, end: float,
+                     attrs: dict) -> None:
+        log = self.span_log
+        log.extend(handle.spans)
+        log.record(STEP_SPAN, handle._t0, end, key=handle.seq, attrs=attrs,
+                   span_id=handle.span_id)
+
+    def _record(self, wall: float, handle: _StepHandle, end: float,
+                slots: Optional[int], admitted: Optional[int]) -> None:
         if not handle.worked:
             # idle poll: nothing dispatched, no device interval — the
             # step is counted for visibility but kept OUT of the
-            # wall/phase/goodput accumulators and the ring (a lull's
-            # workless steps are load pattern, not host tax)
+            # wall/phase/goodput accumulators (a lull's workless steps
+            # are load pattern, not host tax). Only the FIRST poll of a
+            # lull leaves spans: a caller spinning on an empty server
+            # must not push the worked steps out of the bounded log.
             with self._lock:
                 self.idle_steps += 1
                 self.idle_wall_total += wall
+            self._idle_run += 1
+            if self._idle_run == 1:
+                self._write_spans(handle, end, {
+                    "source": self.source, "profiler": self.uid,
+                    "idle": True, "slots": slots})
             return
+        self._idle_run = 0
+        self._write_spans(handle, end, {
+            "source": self.source, "profiler": self.uid, "slots": slots,
+            "admitted": admitted,
+            "depth": self.outstanding, "device_s": handle.device,
+            "pipelined": handle._pipelined_mode})
+        recent = self._recent_walls
+        if wall > SLOW_STEP_S and len(recent) >= SLOW_STEP_MIN_HISTORY:
+            typical = median(recent)
+            if wall > SLOW_STEP_FACTOR * typical:
+                self._slow_step(wall, handle, typical)
+        recent.append(wall)
         with self._lock:
             self.steps += 1
             if handle._pipelined_mode:
@@ -476,22 +615,26 @@ class StepProfiler:
                     self.phase_totals.get(phase, 0.0) + dt
             fraction = (self.device_total / self.wall_total
                         if self.wall_total > 0 else 0.0)
-            step_no = self.steps
         if self.on_step_device is not None:
             self.on_step_device(handle.device)
         self._h_wall.observe(wall)
         for phase, dt in handle.acc.items():
             self._phase_h(phase).observe(dt)
         self._g_goodput.set(fraction)
-        if handle._sampled:
-            from deepspeed_tpu.telemetry.events import (
-                SERVER_STEP_PROFILE, record_event)
-            record_event(
-                SERVER_STEP_PROFILE, source=self.source, step=step_no,
-                wall=round(wall, 7),
-                goodput_fraction=round(fraction, 4),
-                slices=[[p, round(dt, 7)] for p, dt in handle.slices],
-                sampled_every=self.events_every)
+
+    def _slow_step(self, wall: float, handle: _StepHandle,
+                   typical: float) -> None:
+        """One ``slow_step`` ring event: which phase of a stalled step
+        was blocked, how deep the chain was, which program it waited
+        on (PERF.md "Stalls")."""
+        from deepspeed_tpu.telemetry.events import SLOW_STEP, record_event
+        self.slow_steps += 1
+        record_event(
+            SLOW_STEP, source=self.source, step=handle.seq,
+            start=handle._t0, wall=wall, median_wall=typical,
+            phases=[[r[0], r[2] - r[1]] for r in handle.spans],
+            depth=self.outstanding, program=handle.program,
+            pipelined=handle._pipelined_mode)
 
     # --------------------------------------------------------- snapshot
 
@@ -542,5 +685,5 @@ class StepProfiler:
                                        sorted(self.depth_gap_total
                                               .items())},
                 },
-                "events_every": self.events_every,
+                "slow_steps": self.slow_steps,
             }

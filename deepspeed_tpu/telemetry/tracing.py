@@ -191,11 +191,11 @@ class Trace:
 # flight-recorder ring as Chrome trace tracks. Track layout:
 #   pid 2 "device":      tid 1 decode steps, tid 2 compiles,
 #                        tid 3 instant markers (everything else)
-#   pid 3 "server host": tid 1 serving-step phase slices
-#                        (telemetry/step_profile.py ring samples)
+#   pid 3 "server host": tid 1 serving-step phase slices, from the span
+#                        log (telemetry/spans.py, span_timeline_events)
 
-def ring_timeline_events(event_ring,
-                         source_pids: Optional[Dict[str, int]] = None
+def ring_timeline_events(event_ring, span_log=None,
+                         profiler_pids: Optional[Dict[int, int]] = None
                          ) -> List[dict]:
     """Convert the event ring into Chrome trace-event slices, in ONE
     place (the r8 export rebuilt device slices inline, so a second
@@ -206,49 +206,21 @@ def ring_timeline_events(event_ring,
     a re-recorded step) must not emit overlapping duplicates that break
     the timeline validator's non-overlap invariant.
 
-    ``source_pids`` maps a step-profile ``source`` tag (the profiler's
-    ``source=`` constructor arg, e.g. ``"replica0"``) to a dedicated
-    Chrome pid, so a replicated frontend renders each replica's host
-    phases as its own process group; the caller owns those pids' meta
-    events. Untagged/unmapped sources keep the classic pid-3 "server
-    host" track, so single-server dumps are unchanged."""
+    With ``span_log`` and ``profiler_pids`` the serving steps' phase
+    spans join as host tracks (:func:`span_timeline_events`)."""
     slices: List[dict] = []
     seen = set()
-    have_server = False
-
-    def _slice(name, pid, tid, cat, ts, dur, args):
-        key = (pid, tid, round(ts * 1e6, 3))
-        if key in seen:
-            return
-        seen.add(key)
-        slices.append({
-            "name": name, "ph": "X", "cat": cat, "pid": pid, "tid": tid,
-            "ts": round(ts * 1e6, 3),
-            "dur": round(max(dur, 0.0) * 1e6, 3), "args": args})
 
     for ev in event_ring.snapshot():
         kind, ts, data = ev["kind"], ev["ts"], dict(ev["data"])
         dur = data.get("seconds")
         if kind == "step_end" and dur is not None:
-            _slice(f"decode step {data.get('step', '?')}", 2, 1,
-                   "device", ts - dur, dur, data)
+            _timeline_slice(slices, seen, f"decode step "
+                            f"{data.get('step', '?')}", 2, 1,
+                            "device", ts - dur, dur, data)
         elif kind == "compile_end" and dur is not None:
-            _slice(f"compile {data.get('fn', '?')}", 2, 2,
-                   "device", ts - dur, dur, data)
-        elif kind == "server_step_profile":
-            # contiguous phase slices reconstructed backwards from the
-            # record timestamp (the step's finish boundary): the last
-            # phase ends at ts, each earlier one abuts the next
-            pid = (source_pids or {}).get(data.get("source"), 3)
-            have_server = have_server or pid == 3
-            end = ts
-            step = data.get("step", "?")
-            for entry in reversed(data.get("slices", [])):
-                name, pdur = entry[0], float(entry[1])
-                _slice(f"{name}", pid, 1, "server_host",
-                       end - pdur, pdur,
-                       {"step": step, "phase": name})
-                end -= pdur
+            _timeline_slice(slices, seen, f"compile {data.get('fn', '?')}",
+                            2, 2, "device", ts - dur, dur, data)
         else:
             # everything else (retraces, admission rejects, SLO
             # violations, famine snapshots, …) as instant markers
@@ -264,13 +236,60 @@ def ring_timeline_events(event_ring,
         {"name": "thread_name", "ph": "M", "pid": 2, "tid": 2,
          "args": {"name": "compiles"}},
     ]
-    if have_server:
-        meta.extend([
+    host = (span_timeline_events(span_log, profiler_pids)
+            if span_log is not None and profiler_pids else [])
+    return meta + slices + host
+
+
+def _timeline_slice(slices, seen, name, pid, tid, cat, ts, dur, args):
+    key = (pid, tid, round(ts * 1e6, 3))
+    if key in seen:
+        return
+    seen.add(key)
+    slices.append({
+        "name": name, "ph": "X", "cat": cat, "pid": pid, "tid": tid,
+        "ts": round(ts * 1e6, 3),
+        "dur": round(max(dur, 0.0) * 1e6, 3), "args": args})
+
+
+def span_timeline_events(span_log, profiler_pids: Dict[int, int],
+                         offset: Optional[float] = None) -> List[dict]:
+    """The serving steps of the given step profilers as Chrome trace
+    slices: each ``serve:<phase>`` span of the log whose ``serve:step``
+    parent was written by a profiler in ``profiler_pids`` (its ``uid``
+    -> the Chrome pid to draw it under; 3 is the classic "server host"
+    track, whose meta events are emitted here; the caller owns any
+    other pid's). A step's phase spans tile it, so a track's slices are
+    contiguous and never overlap. ``offset`` maps the log's clock onto
+    the timeline's (``time.time``, the ring's and the tracer's);
+    default: the two clocks read now."""
+    from deepspeed_tpu.telemetry import spans as _sp
+    if offset is None:
+        offset = time.time() - span_log.clock()
+    records = span_log.snapshot(prefix="serve:")
+    step_pid = {r[_sp.ID]: profiler_pids[r[_sp.ATTRS]["profiler"]]
+                for r in records
+                if r[_sp.NAME] == "serve:step" and r[_sp.ATTRS]
+                and r[_sp.ATTRS].get("profiler") in profiler_pids}
+    slices: List[dict] = []
+    seen = set()
+    for r in records:
+        pid = step_pid.get(r[_sp.PARENT])
+        if pid is None or r[_sp.NAME] == "serve:flush":
+            continue
+        phase = r[_sp.NAME].split(":", 1)[1]
+        _timeline_slice(slices, seen, phase, pid, 1, "server_host",
+                        r[_sp.START] + offset, r[_sp.END] - r[_sp.START],
+                        dict(r[_sp.ATTRS] or {}, step=r[_sp.KEY],
+                             phase=phase))
+    meta = []
+    if any(s["pid"] == 3 for s in slices):
+        meta = [
             {"name": "process_name", "ph": "M", "pid": 3, "tid": 0,
              "args": {"name": "server host"}},
             {"name": "thread_name", "ph": "M", "pid": 3, "tid": 1,
-             "args": {"name": "step phases (sampled)"}},
-        ])
+             "args": {"name": "step phases"}},
+        ]
     return meta + slices
 
 
@@ -458,13 +477,16 @@ class Tracer:
         for child in span.children:
             Tracer._emit_span(events, child, pid, tid)
 
-    def trace_events(self, event_ring=None) -> List[dict]:
+    def trace_events(self, event_ring=None, span_log=None,
+                     profiler_pids: Optional[Dict[int, int]] = None
+                     ) -> List[dict]:
         """Chrome trace-event list: one track (tid) per kept trace under
         the ``requests`` process, plus ``device`` / ``server host``
-        tracks rebuilt from the flight-recorder ring by
-        :func:`ring_timeline_events` — sampled decode-step slices,
-        compile slices, and serving-step phase slices: "what were the
-        device AND the host doing meanwhile"."""
+        tracks rebuilt by :func:`ring_timeline_events` from the
+        flight-recorder ring (sampled decode-step slices, compile
+        slices) and the span log (the phase spans of the serving steps
+        of the profilers in ``profiler_pids``): "what were the device
+        AND the host doing meanwhile"."""
         events: List[dict] = [
             {"name": "process_name", "ph": "M", "pid": 1, "tid": 0,
              "args": {"name": "requests"}},
@@ -480,14 +502,18 @@ class Tracer:
                             extra_args={"status": tr.status,
                                         "keep_reason": tr.keep_reason})
         if event_ring is not None:
-            events.extend(ring_timeline_events(event_ring))
+            events.extend(ring_timeline_events(event_ring, span_log,
+                                               profiler_pids))
         return events
 
-    def dump_timeline(self, path: str, event_ring=None) -> int:
+    def dump_timeline(self, path: str, event_ring=None, span_log=None,
+                      profiler_pids: Optional[Dict[int, int]] = None
+                      ) -> int:
         """Write Perfetto/chrome://tracing-loadable trace-event JSON;
         returns the event count."""
-        payload = {"traceEvents": self.trace_events(event_ring),
-                   "displayTimeUnit": "ms"}
+        payload = {"traceEvents": self.trace_events(
+            event_ring, span_log, profiler_pids),
+            "displayTimeUnit": "ms"}
         with open(path, "w") as f:
             json.dump(payload, f, default=str)
         return len(payload["traceEvents"])

@@ -441,9 +441,9 @@ def main():
                     help="print the rolling serving-step phase "
                          "breakdown (admission/propose/dispatch/"
                          "sync-wait/commit/publish, goodput fraction, "
-                         "dispatch gaps) after the drain, and sample "
-                         "EVERY step's phase slices into the timeline "
-                         "(combine with --trace-dump for the merged "
+                         "dispatch gaps) after the drain (every "
+                         "step's phase spans are in the timeline: "
+                         "combine with --trace-dump for the merged "
                          "Perfetto view; docs/observability.md "
                          "'Serving goodput & KV-pool accounting')")
     ap.add_argument("--trace-dump", default=None, metavar="PATH",
@@ -494,10 +494,6 @@ def main():
         telemetry["http_port"] = args.metrics_port
     if args.trace_dump:
         telemetry["trace_sample_rate"] = 1.0
-    if args.step_profile:
-        # dense timeline: every step's phase slices reach the ring, so
-        # --trace-dump renders a gap-free server-host track
-        telemetry["step_profile_events_every"] = 1
     if args.slo:
         # compliance gates PLUS the closed loop (docs/observability.md
         # "SLOs, alerting & incidents"): burn-rate alert rules, the

@@ -403,8 +403,7 @@ def test_profiler_pipelined_dispatch_zero_gap_and_pairing():
     is outstanding observes a ZERO gap; the next real gap is measured
     against the fetch that actually drained the device."""
     fc = FakeClock()
-    prof = StepProfiler(registry=MetricRegistry(), clock=fc,
-                        events_every=0)
+    prof = StepProfiler(registry=MetricRegistry(), clock=fc)
     # step 1: pipeline start — dispatch, no fetch
     sp = prof.begin()
     fc.t = 1.0
@@ -450,8 +449,7 @@ def test_profiler_pipelined_phases_sum_and_device_credit():
     step N+1, and a pipelined step's device credit is the full wall
     (the device verifiably had work the whole step) — never more."""
     fc = FakeClock()
-    prof = StepProfiler(registry=MetricRegistry(), clock=fc,
-                        events_every=0)
+    prof = StepProfiler(registry=MetricRegistry(), clock=fc)
     sp = prof.begin()                       # t=0; step N in flight
     fc.t = 0.5
     sp.mark("admission")
@@ -484,8 +482,7 @@ def test_profiler_deferred_chunk_span_clamped_and_paired():
     note_dispatch=False — outstanding pairing stays balanced and the
     credit clamps to the current step's window."""
     fc = FakeClock()
-    prof = StepProfiler(registry=MetricRegistry(), clock=fc,
-                        events_every=0)
+    prof = StepProfiler(registry=MetricRegistry(), clock=fc)
     sp = prof.begin()
     fc.t = 1.0
     sp.note_dispatch(1.0)                   # chunk leaves the host

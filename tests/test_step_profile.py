@@ -29,24 +29,28 @@ from deepspeed_tpu.inference.kv_cache import BlockAllocator
 from deepspeed_tpu.model_implementations.transformer import (
     InferenceTransformerConfig, init_params)
 from deepspeed_tpu.telemetry import (EventRing, KVPoolAccountant,
-                                     MetricRegistry, StepProfiler,
+                                     MetricRegistry, SpanLog, StepProfiler,
                                      get_event_ring, get_registry,
-                                     set_event_ring, set_registry)
+                                     get_span_log, set_event_ring,
+                                     set_registry, set_span_log)
 from deepspeed_tpu.telemetry.exporter import ROUTES
 from deepspeed_tpu.telemetry.step_profile import NULL_STEP_HANDLE
-from deepspeed_tpu.telemetry.tracing import ring_timeline_events
+from deepspeed_tpu.telemetry.tracing import (ring_timeline_events,
+                                             span_timeline_events)
 
 
 @pytest.fixture()
 def fresh_telemetry():
-    """Private process registry + event ring for one test."""
+    """Private process registry + event ring + span log for one test."""
     prev_reg = set_registry(MetricRegistry())
     prev_ring = set_event_ring(EventRing(256))
+    prev_log = set_span_log(SpanLog())
     try:
         yield get_registry()
     finally:
         set_registry(prev_reg)
         set_event_ring(prev_ring)
+        set_span_log(prev_log)
 
 
 class FakeClock:
@@ -77,7 +81,7 @@ def test_phases_sum_to_wall_exactly(fresh_telemetry):
     the sum is EXACT, not approximate."""
     fc = FakeClock()
     reg = MetricRegistry()
-    prof = StepProfiler(registry=reg, clock=fc, events_every=0)
+    prof = StepProfiler(registry=reg, clock=fc)
     sp = prof.begin()
     fc.t = 1.0
     sp.mark("admission")
@@ -122,7 +126,7 @@ def test_dispatch_gap_between_fetch_and_next_dispatch(fresh_telemetry):
     and exactly one gap per idle span."""
     fc = FakeClock()
     reg = MetricRegistry()
-    prof = StepProfiler(registry=reg, clock=fc, events_every=0)
+    prof = StepProfiler(registry=reg, clock=fc)
     sp = prof.begin()
     fc.t = 1.0
     sp.mark("propose", dispatch=True)    # no prior fetch: no gap
@@ -154,8 +158,7 @@ def test_idle_finish_resets_dispatch_gap_baseline(fresh_telemetry):
     lull) resets the gap baseline — device idle for lack of WORK must
     never read as a multi-second host-tax gap."""
     fc = FakeClock()
-    prof = StepProfiler(registry=MetricRegistry(), clock=fc,
-                        events_every=0)
+    prof = StepProfiler(registry=MetricRegistry(), clock=fc)
     sp = prof.begin()
     fc.t = 1.0
     sp.mark("sync_wait", fetch=True)
@@ -187,8 +190,7 @@ def test_device_interval_attributes_and_advances_gap(fresh_telemetry):
     toward the goodput fraction and moves the dispatch-gap boundary —
     the device was busy, not idle, across it."""
     fc = FakeClock()
-    prof = StepProfiler(registry=MetricRegistry(), clock=fc,
-                        events_every=0)
+    prof = StepProfiler(registry=MetricRegistry(), clock=fc)
     sp = prof.begin()
     fc.t = 1.0
     sp.mark("sync_wait", fetch=True)     # decode fetch at t=1
@@ -210,39 +212,47 @@ def test_device_interval_attributes_and_advances_gap(fresh_telemetry):
     assert gaps["max_s"] == 2.0
 
 
-def test_ring_sampling_and_contiguous_slices(fresh_telemetry):
-    """events_every=1: every step freezes its ordered phase slices into
-    the event ring; the slices are contiguous and sum to wall."""
+def test_step_spans_are_contiguous_and_tile_the_step(fresh_telemetry):
+    """Every worked step leaves its ordered phase spans in the span
+    log, parented under one serve:step; they are contiguous and sum to
+    wall. A workless poll after it leaves one idle step, a second
+    consecutive one nothing."""
     fc = FakeClock()
-    prof = StepProfiler(registry=MetricRegistry(), clock=fc,
-                        events_every=1)
+    prof = StepProfiler(registry=MetricRegistry(), clock=fc)
     sp = prof.begin()
     fc.t = 0.5
     sp.mark("admission")
     fc.t = 0.6
     sp.mark("propose", dispatch=True)
     fc.t = 0.75
-    sp.mark("dispatch")
+    sp.mark("dispatch", program="serve_decode")
     fc.t = 1.0
     sp.finish()
-    evs = [e for e in get_event_ring().snapshot()
-           if e["kind"] == "server_step_profile"]
-    assert len(evs) == 1
-    data = evs[0]["data"]
-    assert data["step"] == 1
-    assert data["wall"] == 1.0
-    assert [s[0] for s in data["slices"]] == ["admission", "propose",
-                                              "dispatch", "other"]
-    assert sum(s[1] for s in data["slices"]) == pytest.approx(1.0)
-    # events_every=0 records nothing (step worked, sampling off)
-    prof0 = StepProfiler(registry=MetricRegistry(), clock=fc,
-                         events_every=0)
-    sp = prof0.begin()
-    fc.t += 1.0
-    sp.mark("propose", dispatch=True)
-    sp.finish()
-    assert len([e for e in get_event_ring().snapshot()
-                if e["kind"] == "server_step_profile"]) == 1
+    recs = get_span_log().snapshot()
+    steps = [r for r in recs if r[0] == "serve:step"]
+    assert len(steps) == 1
+    name, t0, t1, parent, sid, key, attrs = steps[0]
+    assert (t0, t1, parent, key) == (0.0, 1.0, 0, 1)
+    assert attrs["profiler"] == prof.uid and attrs["pipelined"] is False
+    kids = [r for r in recs if r[3] == sid]
+    assert [r[0] for r in kids] == ["serve:admission", "serve:propose",
+                                    "serve:dispatch", "serve:other"]
+    assert sum(r[2] - r[1] for r in kids) == pytest.approx(1.0)
+    for a, b in zip(kids, kids[1:]):
+        assert a[2] == b[1]
+    assert all(r[5] == 1 for r in kids)
+    # the dispatch span carries what the gap detector saw at its start
+    disp = kids[2][6]
+    assert disp == {"gap_s": None, "depth": 1, "busy": False,
+                    "program": "serve_decode"}
+    for k in (2, 3):                  # two workless polls
+        sp = prof.begin()
+        fc.t += 1.0
+        sp.mark("admission")
+        sp.finish(live=False)
+    steps = [r for r in get_span_log().snapshot() if r[0] == "serve:step"]
+    assert [r[5] for r in steps] == [1, 2]
+    assert steps[1][6]["idle"] is True
 
 
 def test_null_handle_is_inert():
@@ -251,9 +261,9 @@ def test_null_handle_is_inert():
     assert NULL_STEP_HANDLE.finish() is None
 
 
-def test_events_every_validated():
-    with pytest.raises(ValueError, match="events_every"):
-        StepProfiler(registry=MetricRegistry(), events_every=-1)
+def test_span_log_capacity_validated():
+    with pytest.raises(ValueError, match="capacity"):
+        SpanLog(capacity=0)
 
 
 # ============================================= KV-pool accountant (fake clock)
@@ -373,7 +383,7 @@ def test_idle_poll_steps_do_not_dilute_goodput(fresh_telemetry):
     regression gate reads."""
     fc = FakeClock()
     reg = MetricRegistry()
-    prof = StepProfiler(registry=reg, clock=fc, events_every=1)
+    prof = StepProfiler(registry=reg, clock=fc)
     sp = prof.begin()
     fc.t = 1.0
     sp.mark("propose", dispatch=True)
@@ -394,9 +404,9 @@ def test_idle_poll_steps_do_not_dilute_goodput(fresh_telemetry):
     assert snap["goodput_fraction"] == 0.5
     rs = reg.snapshot()
     assert rs["serve_step_wall_seconds"]["series"][0]["count"] == 1
-    # idle polls leave no ring samples either
-    assert len([e for e in get_event_ring().snapshot()
-                if e["kind"] == "server_step_profile"]) == 1
+    # a lull leaves ONE idle step in the span log, however long polled
+    steps = [r for r in get_span_log().snapshot() if r[0] == "serve:step"]
+    assert [bool(r[6].get("idle")) for r in steps] == [False, True]
 
 
 def test_fragmentation_gauge_on_crafted_holes(fresh_telemetry):
@@ -639,7 +649,6 @@ def test_timeline_track_and_debug_goodput_over_http(fresh_telemetry,
     valid JSON over HTTP."""
     assert "/debug/goodput" in ROUTES
     eng = make_engine(telemetry={"trace_sample_rate": 1.0,
-                                 "step_profile_events_every": 1,
                                  "http_port": 0})
     srv = ContinuousBatchingServer(eng)
     for i in range(3):
@@ -697,25 +706,32 @@ def test_ring_slices_dedupe_same_track_and_ts(fresh_telemetry,
     _validate_trace_events({"traceEvents": out})
 
 
-def test_server_step_profile_slices_reconstruct_backwards(
-        fresh_telemetry, monkeypatch):
-    """A server_step_profile ring event becomes contiguous slices
-    ending at the event timestamp."""
-    from deepspeed_tpu.telemetry import events as ev_mod
-    ring = EventRing(16)
-    monkeypatch.setattr(ev_mod.time, "time", lambda: 50.0)
-    ring.record("server_step_profile", source="serve", step=7,
-                wall=0.6, goodput_fraction=0.5,
-                slices=[["admission", 0.1], ["propose", 0.2],
-                        ["sync_wait", 0.3]])
-    out = ring_timeline_events(ring)
-    host = sorted([e for e in out if e["ph"] == "X" and e["pid"] == 3],
+def test_step_spans_render_as_contiguous_host_slices(fresh_telemetry):
+    """The span log's phase spans of one profiler become contiguous
+    slices on its track, on the timeline's clock; another profiler's
+    steps stay off it."""
+    fc = FakeClock(10.0)
+    log = get_span_log()
+    prof = StepProfiler(registry=MetricRegistry(), clock=fc)
+    other = StepProfiler(registry=MetricRegistry(), clock=fc)
+    for p in (prof, other):
+        sp = p.begin()
+        fc.t += 0.1
+        sp.mark("admission")
+        fc.t += 0.2
+        sp.mark("propose", dispatch=True)
+        fc.t += 0.3
+        sp.mark("sync_wait", fetch=True)
+        sp.finish()
+    out = span_timeline_events(log, {prof.uid: 3}, offset=40.0)
+    host = sorted([e for e in out if e["ph"] == "X"],
                   key=lambda e: e["ts"])
+    assert {e["pid"] for e in host} == {3}
     assert [e["name"] for e in host] == ["admission", "propose",
                                          "sync_wait"]
-    # contiguous, ending at ts=50s
-    assert host[-1]["ts"] + host[-1]["dur"] == pytest.approx(50.0 * 1e6)
+    assert host[0]["ts"] == pytest.approx(50.0 * 1e6)
     for a, b in zip(host, host[1:]):
         assert a["ts"] + a["dur"] == pytest.approx(b["ts"])
-    assert host[0]["ts"] == pytest.approx((50.0 - 0.6) * 1e6)
+    assert host[-1]["ts"] + host[-1]["dur"] == pytest.approx(50.6 * 1e6)
+    assert host[0]["args"]["step"] == 1
     _validate_trace_events({"traceEvents": out})

@@ -148,3 +148,140 @@ def test_kernel_maps_over_a_mesh(chips):
     text = jax.jit(map_kernel(fn, mesh, specs, q)).lower(
         *args).compile().as_text()
     assert "tpu_custom_call" in text and "all-gather(" not in text
+
+
+# ------------------------------------------------ names on the device
+# (ISSUE 24) What a device trace shows of a program is its module name,
+# its instructions' names and nothing else: the watch names the module,
+# ``pallas_call(name=...)`` the kernels, and the compile watch's scope
+# table maps instructions to the ``jax.named_scope`` layer boundaries.
+# Read back here from the text of programs compiled for the chip.
+
+L_NAMES = 2
+SERVE_SCOPES = {"embed", "ln", "attn_qkv", "kv_write", "attn_kernel",
+                "attn_out", "mlp", "lm_head", "sample"}
+
+
+def _serve_program(kind, device):
+    """``(jitted program named as the server names it, its name,
+    abstract arguments)`` at d_head 128, block 128, 8 slots."""
+    from deepspeed_tpu.inference.kv_cache import init_paged_cache
+    from deepspeed_tpu.inference.server import ContinuousBatchingServer as Srv
+    from deepspeed_tpu.model_implementations.transformer import (
+        InferenceTransformerConfig, init_params)
+    from deepspeed_tpu.telemetry import compile_watch
+    one = SingleDeviceSharding(device)
+    cfg = InferenceTransformerConfig(
+        vocab_size=512, n_positions=1024, n_embd=256, n_layer=L_NAMES,
+        n_head=2, dtype=BF16)
+
+    def abstract(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one), tree)
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    params = abstract(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    cache = abstract(jax.eval_shape(lambda: init_paged_cache(
+        L_NAMES, S, NB, BS, MB, 2, 128, BF16)))
+    fn, name, args = {
+        "decode": (Srv._decode_fn, "serve_decode",
+                   (params, arr((S,)), cache, arr((S,), jnp.bool_))),
+        "prefill": (Srv._prefill_fn, "serve_prefill",
+                    (params, arr((1, C)), arr((1,)), cache, arr(()))),
+        "chunk": (Srv._chunk_fn, "serve_prefill_chunk",
+                  (params, arr((1, C)), arr(()), arr((1,)), cache,
+                   arr(()))),
+        "verify": (Srv._verify_fn, "serve_spec_verify",
+                   (params, arr((S, K)), cache)),
+    }[kind]
+    prog = jax.jit(compile_watch._named(
+        functools.partial(fn, cfg=cfg, mesh=None), name),
+        donate_argnames=("cache",))
+    return prog, name, args
+
+
+@pytest.mark.parametrize("kind,kernel,extra", [
+    ("decode", "paged_decode_attention", {"kv_read"}),
+    ("prefill", "flash_attention_fwd", set()),
+    ("chunk", "paged_chunk_attention", {"kv_read"}),
+    ("verify", "paged_verify_attention", {"kv_read"}),
+])
+def test_serving_programs_carry_their_names(chips, monkeypatch, kind,
+                                            kernel, extra):
+    """Module name, kernel name and every layer scope of a serving
+    program, read back from its compiled text."""
+    from deepspeed_tpu.telemetry import compile_watch
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(fa, "_should_interpret", lambda: False)
+    prog, name, args = _serve_program(kind, chips[0])
+    text = prog.lower(*args).compile().as_text()
+    assert f"HloModule jit_{name}" in text
+    scopes, kernels = compile_watch.parse_scopes(text)
+    assert set(kernels.values()) == {kernel}
+    assert len(kernels) == L_NAMES            # one call a layer
+    assert all(scopes[k] == "attn_kernel" for k in kernels)
+    innermost = {v.rsplit("/", 1)[-1] for v in scopes.values() if v}
+    assert innermost >= SERVE_SCOPES | extra, SERVE_SCOPES - innermost
+    if "kv_read" in extra:
+        # the per-layer cut of K and V out of the pool is kv_read's: a
+        # slice of the whole pool and the squeeze the compiler merges
+        # with the kernel's input reshape
+        cuts = [k for k, v in scopes.items() if v == "kv_read"
+                and k.split(".")[0] in ("slice", "squeeze")]
+        assert len(cuts) >= 2 * L_NAMES
+
+
+def test_train_model_kernels_and_scopes(chips, monkeypatch):
+    """The train step's model under the step's ``fwd_bwd`` scope: the
+    three flash kernels by name, under a gradient as in the forward."""
+    from deepspeed_tpu.models.gpt2 import GPT2LMModel, config_for
+    from deepspeed_tpu.telemetry import compile_watch
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(fa, "_should_interpret", lambda: False)
+    one = SingleDeviceSharding(chips[0])
+    tm = GPT2LMModel(config_for("gpt2-125m", dtype=BF16, n_embd=256,
+                                n_layer=2, n_head=2, vocab_size=512,
+                                n_positions=256))
+    params = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one),
+        jax.eval_shape(lambda: tm.init(jax.random.PRNGKey(0), batch_size=1,
+                                       seq_len=256)))
+    batch = {"input_ids": jax.ShapeDtypeStruct((2, 256), jnp.int32,
+                                               sharding=one)}
+
+    def train_step(p, b):
+        with jax.named_scope("fwd_bwd"):
+            return jax.value_and_grad(lambda q: tm.loss_fn(q, b))(p)
+    text = jax.jit(train_step).lower(params, batch).compile().as_text()
+    assert "HloModule jit_train_step" in text
+    scopes, kernels = compile_watch.parse_scopes(text)
+    assert set(kernels.values()) == {
+        "flash_attention_fwd", "flash_attention_bwd_dq",
+        "flash_attention_bwd_dkv"}
+    assert all(scopes[k] == "fwd_bwd/attn_kernel" for k in kernels)
+    paths = {v for v in scopes.values() if v}
+    assert all(v.split("/")[0] == "fwd_bwd" for v in paths)
+    assert {v.rsplit("/", 1)[-1] for v in paths} >= {
+        "fwd_bwd", "embed", "attn_kernel", "mlp", "lm_head"}
+
+
+def test_kernel_names_are_the_same_under_a_mesh(chips):
+    """``map_kernel``'s shard_map does not rename the call: the decode
+    kernel reads ``paged_decode_attention`` on four devices as on one."""
+    from deepspeed_tpu.telemetry import compile_watch
+    from deepspeed_tpu.utils.sharding import map_kernel
+    mesh = Mesh(np.asarray(chips).reshape(1, 1, 4),
+                ("expert", "seq", "tensor"))
+    fn, shapes = _paged_decode()
+    hs = "tensor"
+    specs = (P(None, hs, None), P(None, None, hs, None),
+             P(None, None, hs, None), P(), P())
+    args = [jax.ShapeDtypeStruct(shape, dtype,
+                                 sharding=NamedSharding(mesh, spec))
+            for (shape, dtype), spec in zip(shapes, specs)]
+    mapped = map_kernel(fn, mesh, specs, specs[0])
+    text = jax.jit(mapped).lower(*args).compile().as_text()
+    _, kernels = compile_watch.parse_scopes(text)
+    assert set(kernels.values()) == {"paged_decode_attention"}
