@@ -268,18 +268,33 @@ def decode_scopes(trace, program: str = "serve_decode") -> Optional[dict]:
                 p.get(UNKNOWN, 0.0) for p in per) / busy if busy else None}
 
 
+def _is_kernel(program: str, kernel: str):
+    """Whether an instruction's text is a call of the kernel NAMED
+    ``kernel``: by the program's kernel table, else by the
+    instruction's own name."""
+    _, kernels = tables(program)
+    return lambda text: kernels.get(instruction(text),
+                                    op_name(text)) == kernel
+
+
+def kernel_calls(trace, program: str, kernel: str
+                 ) -> List[Tuple[float, float]]:
+    """Start and end of every call of the kernel NAMED ``kernel``
+    inside the executions of the program on chip 0."""
+    is_it = _is_kernel(program, kernel)
+    return [(s, e) for ops in ops_by_execution(trace, program)
+            for t, s, e, _ in ops if is_it(t)]
+
+
 def kernel_ms(trace, program: str, kernel: str) -> Optional[float]:
     """Median over executions of the program on chip 0 of the device
     time of the kernel calls NAMED ``kernel``."""
     if trace is None:
         return None
-    _, kernels = tables(program)
     runs = ops_by_execution(trace, program)
     if not runs:
         return None
-
-    def is_it(text: str) -> bool:
-        return kernels.get(instruction(text), op_name(text)) == kernel
+    is_it = _is_kernel(program, kernel)
     per = [sum(e - s for t, s, e, _ in ops if is_it(t)) for ops in runs]
     return 1e3 * median(per) if any(per) else None
 

@@ -238,38 +238,67 @@ def itl_gaps_ms(reqs: List[Tracked]) -> List[float]:
             for a, b in zip(r.token_times, r.token_times[1:])]
 
 
-def run_backlog(sess: Session, reqs: List[Tracked], seconds: float,
+def run_backlog(sess: Session, reqs: List[Tracked], ring, seconds: float,
                 tracer, trace_seconds: float) -> dict:
-    """Everything is queued at time zero (set-up); the window opens at
-    the first step boundary at which every slot is resident and no
-    prefill is pending, and closes at the first step boundary at or
-    after ``seconds``. Output tokens stamped inside it count, whether or
-    not their request finished in it."""
+    """``reqs`` (lap 0 of the backlog's ring) is queued at time zero
+    (set-up); the window opens at the first step boundary at which every
+    slot is resident and no prefill is pending, and closes at the first
+    step boundary at or after ``seconds``. Output tokens stamped inside
+    it count, whether or not their request finished in it.
+
+    After every step the queue is topped up from ``ring`` (``(number,
+    request)`` pairs without an end) to the depth it had at time zero,
+    between two calls of ``server.step()`` and never inside one, so the
+    server finds the same depth of queue however fast it empties its
+    slots. The requests topped up are appended to ``reqs``. A refused
+    top-up is a failed request, and ends that top-up."""
+    depth = len(reqs)
     for r in reqs:
         sess.submit(r)
+    sched = sess.server.scheduler
     slots = sess.server.num_slots
-    while len(sess.server.scheduler.slots) < slots and sess.busy:
+    top = {"requests": 0, "seconds": 0.0, "lowest_queue": depth}
+
+    def step() -> bool:
+        """One step and its top-up; whether the step ended with a free
+        slot and nothing queued (the next step admits what this one's
+        retirements made room for, so a free slot alone is not dry)."""
         sess.step()
-    sess.step()                        # one step with every slot decoding
+        queued = sched.pending_requests
+        top["lowest_queue"] = min(top["lowest_queue"], queued)
+        if queued < depth:
+            t = sess.clock()
+            while sched.pending_requests < depth:
+                number, r = next(ring)
+                extra = Tracked(number, r["prompt"], r["out"], r["due"],
+                                r["counted"])
+                reqs.append(extra)
+                sess.submit(extra)
+                top["requests"] += 1
+                if extra.refused:
+                    break
+            top["seconds"] += sess.clock() - t
+        return sess.steps[-1][2] < slots and not queued
+
+    while len(sched.slots) < slots and sess.busy:
+        step()
+    step()                             # one step with every slot decoding
     gc.collect()
     gc.freeze()
     tracer.start()
     t0 = sess.clock()
     first_step = len(sess.steps)
+    top.update(requests=0, seconds=0.0, lowest_queue=depth)
     dry = False
     while True:
-        sess.step()
+        dry = step() or dry
         now = sess.clock()
         if tracer.active and now - t0 >= trace_seconds:
             tracer.stop()
-        # a slot retired in a step is refilled by the next step's
-        # admission, so fewer live slots mean a dry backlog only when
-        # nothing is queued
-        dry = dry or (sess.steps[-1][2] < slots
-                      and not sess.server.scheduler.pending_requests)
         if now - t0 >= seconds:
             break
-    return {"t0": t0, "t1": now, "first_step": first_step, "ran_dry": dry}
+    return {"t0": t0, "t1": now, "first_step": first_step, "ran_dry": dry,
+            "top_up": top}
 
 
 def run_open_loop(sess: Session, reqs: List[Tracked], seconds: float,
@@ -335,7 +364,10 @@ def run(cell: dict, args, t_start: float, family, devices) -> dict:
         compiled_before = compiles.count
         trace_seconds = float(traffic.get("trace_seconds", 4.0))
         if traffic["kind"] == "backlog":
-            win = run_backlog(sess, reqs, args.seconds, tracer,
+            ring = traffic_lib.backlog_ring(
+                traffic, args.seed, config["model"]["vocab_size"],
+                first_lap=1)
+            win = run_backlog(sess, reqs, ring, args.seconds, tracer,
                               trace_seconds)
         else:
             lead_in = float(traffic.get("lead_in_s", 0.0))
@@ -373,11 +405,24 @@ def run(cell: dict, args, t_start: float, family, devices) -> dict:
                                       for a, d in sess.gc_pauses]})
     counted = [r for r in reqs if r.counted and r.submitted is not None]
     if traffic["kind"] == "backlog":
-        # the offline job's requests that the window touched
-        touched = [r for r in counted if r.token_times
-                   and r.token_times[-1] > t0 and r.token_times[0] <= t1]
+        # the offline job's requests that the window touched, and any
+        # the server refused
+        touched = [r for r in counted if r.refused or (
+            r.token_times and r.token_times[-1] > t0
+            and r.token_times[0] <= t1)]
         failed = [r for r in touched if r.refused or (
             r.done is not None and r.reason not in OK_REASONS)]
+        per_lap = made["totals"]["requests"]
+        top = win["top_up"]
+        made["totals"].update(
+            requests_offered=len(counted),
+            laps_touched=1 + max((r.rid for r in touched),
+                                 default=0) // per_lap)
+        harness.log({"backlog": dict(
+            made["totals"], topped_up_in_window=top["requests"],
+            lowest_queue_in_window=top["lowest_queue"],
+            top_up_seconds_in_window=top["seconds"],
+            top_up_share_of_window_pct=100.0 * top["seconds"] / (t1 - t0))})
     else:
         touched = counted
         failed = [r for r in touched if r.failed]
@@ -408,8 +453,7 @@ def run(cell: dict, args, t_start: float, family, devices) -> dict:
         "requests": reqs, "counted": touched, "steps": sess.steps,
         "first_step": win["first_step"], "admissions": sess.admissions,
         "window_tokens": window_tokens, "totals": made["totals"],
-        "num_slots": server.num_slots, "block_size": server.block_size,
-        "num_blocks": 1 + server.num_slots * server.max_blocks_per_slot,
+        "num_slots": server.num_slots,
         "reference_check": check,
         "compile_s": harness.watched_compile_seconds(),
         "jax_compile_s": compiles.seconds,

@@ -10,8 +10,11 @@ ran on the core, named by the instruction's whole text, ``%copy-done.29
 inside it) and ``Async XLA Ops`` (the start-to-done span of each
 asynchronous copy or collective). The host is the plane ``/host:CPU``,
 one line per thread; ``jax.profiler.TraceAnnotation`` spans appear on
-the thread that opened them under their own name. All planes share one
-clock, in nanoseconds.
+the thread that opened them under their own name: the benchmark's
+``bench:*`` and, since the program annotates itself, ``serve:step``,
+``train:step`` and ``serve:phase`` (one name for every phase of a server
+step, the phase in its ``phase`` stat: read here as ``serve:<phase>``).
+All planes share one clock, in nanoseconds.
 
 Everything below works on plain tuples, so that it can be checked
 against a recorded trace without a chip (``benchmark/testdata``).
@@ -27,7 +30,8 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 Interval = Tuple[float, float]          # start, end (seconds)
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
-SPAN_PREFIX = "bench:"
+SPAN_PREFIXES = ("bench:", "serve:", "train:")
+PHASE_SPAN = "serve:phase"
 WINDOW_SPAN = "bench:window"
 CONTAINERS = ("while", "conditional", "call")
 COPY_OPS = ("copy-start", "copy-done")
@@ -221,8 +225,8 @@ class Reduced:
 
     # ---- idle gaps by what the host was doing
     def idle_gaps(self, top: int = 10) -> List[List]:
-        """Idle time of chip 0 by the innermost ``bench:`` span that
-        covers each gap's middle."""
+        """Idle time of chip 0 by the innermost host span (the
+        benchmark's or the program's) that covers each gap's middle."""
         acc: Dict[str, float] = collections.defaultdict(float)
         dev = self.devices[0]
         for s, e in gaps(dev.busy, self.lo, self.hi):
@@ -258,20 +262,15 @@ class Reduced:
                 "kernels": rank(kern), "kernel_samples": sample,
                 "host_spans": sorted({n for n, _, _ in self.host_spans})}
 
-    # ---- programs
-    def modules_with(self, pred, device: int = 0
-                     ) -> List[Tuple[str, float, float, list]]:
-        """Executions of compiled programs on one chip that hold at
-        least one kernel call whose text satisfies ``pred``, each with
-        those calls: ``(name, start, end, [(text, s, e)])``."""
-        dev = self.devices[device]
-        kern = dev.kernels()
-        out = []
-        for name, s, e in dev.modules:
-            inside = [k for k in kern if k[1] >= s and k[2] <= e]
-            if inside and all(pred(k[0]) for k in inside):
-                out.append((name, s, e, inside))
-        return out
+
+def span_name(ev) -> str:
+    """A host event's name; a ``serve:phase`` event is named by its
+    ``phase`` stat (``serve:dispatch``, ``serve:sync_wait``...)."""
+    if ev.name == PHASE_SPAN:
+        for key, value in ev.stats:
+            if key == "phase" and value:
+                return "serve:" + str(value)
+    return ev.name
 
 
 def read(path: str) -> Reduced:
@@ -304,9 +303,9 @@ def read(path: str) -> Reduced:
         elif plane.name == "/host:CPU":
             for line in plane.lines:
                 for ev in line.events:
-                    if ev.name.startswith(SPAN_PREFIX):
+                    if ev.name.startswith(SPAN_PREFIXES):
                         s = ev.start_ns * 1e-9
-                        spans.append((ev.name, s,
+                        spans.append((span_name(ev), s,
                                       s + ev.duration_ns * 1e-9))
     if not devices:
         raise ValueError(f"{path} holds no /device:TPU plane")

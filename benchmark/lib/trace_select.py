@@ -1,26 +1,20 @@
-"""What the serving metrics share: telling the paged-decode kernel from
-the flash kernel in a trace, and cutting the run's host-side records to
-the traced window.
+"""What the serving metrics share: the executions of a named program in
+a trace, the kernel calls of a given name inside them, and the run's
+host-side records cut to the traced window.
 
-The program gives its kernels no names (a Pallas call's instruction is
-named after whatever Python function encloses it), so a kernel call is
-told by its operands: the paged kernels read the KV pool, a
-``[num_blocks, block_size, heads * head_dim]`` operand that nothing else
-has. Stable kernel names are listed in PERF.md for the tracing PR.
+A reader names a program (``jit_<program>`` on the ``XLA Modules``
+line), a kernel or a scope; none matches an operand's shape, so a
+change to how the KV pool is laid out or handed to a kernel moves no
+reader off its program.
 """
 from __future__ import annotations
 
 from statistics import median
 
+from benchmark.lib import program_spans as ps
 
-def is_paged(text: str, run: dict) -> bool:
-    """A kernel call that reads the paged KV pool."""
-    s = run["shapes"]
-    pool = (f"[{run['num_blocks']},{run['block_size']},"
-            f"{s['kv_heads'] * s['head_dim']}]")
-    alt = (f"[{run['num_blocks']},{run['block_size']},{s['kv_heads']},"
-           f"{s['head_dim']}]")
-    return pool in text or alt in text
+DECODE = ("serve_decode",)
+PREFILL = ("serve_prefill", "serve_prefill_chunk")
 
 
 def traced_steps(run: dict) -> list:
@@ -39,10 +33,21 @@ def traced_admissions(run: dict, programs: int) -> list:
     return got[:programs] if programs else []
 
 
+def program_runs(trace, programs) -> list:
+    """``(start, end)`` of every execution on chip 0 of the programs
+    named in ``programs``, wholly inside the traced window."""
+    return sorted(x for p in programs for x in ps.executions(trace, p))
+
+
+def kernel_calls(trace, programs, kernel: str) -> list:
+    """``(start, end)`` of every call of the kernel NAMED ``kernel``
+    inside those executions."""
+    return [x for p in programs for x in ps.kernel_calls(trace, p, kernel)]
+
+
 def decode_program_ms(run: dict, trace):
-    """Median device time of one execution of the program that holds the
-    paged-decode kernel."""
+    """Median device time of one execution of the decode program."""
     if trace is None or run["kind"] != "serve":
         return None
-    progs = trace.modules_with(lambda t: is_paged(t, run))
-    return median((e - s) * 1e3 for _, s, e, _ in progs) if progs else None
+    runs = program_runs(trace, DECODE)
+    return median((e - s) * 1e3 for s, e in runs) if runs else None

@@ -19,6 +19,12 @@ same total tokens. What ``--seed`` changes is the order (``"order"``):
 
 Token ids and (elsewhere) weights always come from the seed.
 
+A ``backlog`` is an endless ring of laps (:func:`backlog_ring`): lap 0
+is the list :func:`build_requests` returns, lap ``k >= 1`` the same
+multiset in another stratified order with other token ids, drawn from
+``(seed, k)``. The runner queues lap 0 at time zero and tops the queue
+up from the ring, so the queue is as deep at any speed of the server.
+
 Length distributions (``{"dist": ...}``):
   ``loguniform``  lo, hi
   ``lognormal``   median, sigma, lo, hi   (clipped)
@@ -37,9 +43,10 @@ conditioned on its count so that the offered rate is exact:
 """
 from __future__ import annotations
 
+import itertools
 import math
 from statistics import NormalDist
-from typing import Dict, List, Tuple
+from typing import Dict, Iterator, List, Tuple
 
 import numpy as np
 
@@ -156,11 +163,61 @@ def token_ids(rng: np.random.Generator, length: int, vocab: int,
     return ids.tolist()
 
 
+def _lap_rngs(traffic: dict, seed: int, vocab: int, lap: int = 0):
+    """``(rng, order_rng, prefixes)`` of one lap. Lap 0 draws everything
+    from ``seed``; a later lap draws from ``(seed, lap)`` and keeps lap
+    0's shared prefixes."""
+    rng = np.random.default_rng(seed)
+    prefix_len = int(traffic.get("shared_prefix_tokens", 0))
+    groups = int(traffic.get("shared_prefix_groups", 1))
+    prefixes = [rng.integers(1, vocab, size=prefix_len)
+                for _ in range(groups)] if prefix_len else None
+    if lap:
+        rng = np.random.default_rng((seed, lap))
+    rotation = traffic.get("order", "permutation") == "rotation"
+    order_rng = (np.random.default_rng(int(traffic["order_seed"]))
+                 if rotation else rng)
+    return rng, order_rng, prefixes
+
+
+def _part(traffic: dict, n: int, start: float, span: float, counted: bool,
+          vocab: int, rng, order_rng, prefixes
+          ) -> Tuple[Iterator[dict], List[Tuple[int, int]]]:
+    """``n`` requests due in ``[start, start + span)`` as an iterator
+    (a request's token ids are drawn when it is taken, in due order) and
+    the multiset they are made from."""
+    kind = traffic["kind"]
+    block = int(traffic.get("stratify_block", 16))
+    pairs = multiset(n, traffic["prompt_len"], traffic["output_len"],
+                     int(traffic["max_total_tokens"]), block)
+    order = stratified_order(n, block, order_rng)
+    if kind == "open_loop":
+        due = arrivals(n, start, span, traffic.get("arrivals", {}),
+                       order_rng, block)
+    else:
+        due = np.zeros((n,))
+    if traffic.get("order", "permutation") == "rotation" and n:
+        r = int(rng.integers(n))
+        order = order[r:] + order[:r]
+        due = rotate_arrivals(due, start, span, r)
+
+    def requests():
+        for k, i in enumerate(order):
+            p, o = pairs[i]
+            shared = (prefixes[int(rng.integers(len(prefixes)))]
+                      if prefixes else None)
+            yield {"due": float(due[k]),
+                   "prompt": token_ids(rng, p, vocab, shared),
+                   "out": o, "counted": counted}
+    return requests(), pairs
+
+
 def build_requests(traffic: dict, seconds: float, seed: int, vocab: int
                    ) -> Dict:
     """The requests of one run of a serving cell.
 
-    ``backlog``: ``requests`` pairs, all due at time zero.
+    ``backlog``: ``requests`` pairs, all due at time zero (lap 0 of
+    :func:`backlog_ring`).
     ``open_loop``: ``round(rate * lead_in_s)`` lead-in requests (served,
     not counted) and ``round(rate * seconds)`` window requests, each
     part its own fixed multiset, arrival times relative to the opening
@@ -168,41 +225,12 @@ def build_requests(traffic: dict, seconds: float, seed: int, vocab: int
 
     Returns ``{"requests": [...], "totals": {...}}``; a request is
     ``{"due", "prompt", "out", "counted"}`` in due order."""
-    rng = np.random.default_rng(seed)
     kind = traffic["kind"]
-    max_total = int(traffic["max_total_tokens"])
-    block = int(traffic.get("stratify_block", 16))
-    prefix_len = int(traffic.get("shared_prefix_tokens", 0))
-    groups = int(traffic.get("shared_prefix_groups", 1))
-    prefixes = [rng.integers(1, vocab, size=prefix_len)
-                for _ in range(groups)] if prefix_len else None
-
-    rotation = traffic.get("order", "permutation") == "rotation"
-    order_rng = (np.random.default_rng(int(traffic["order_seed"]))
-                 if rotation else rng)
+    draws = _lap_rngs(traffic, seed, vocab)
 
     def part(n, start, span, counted):
-        pairs = multiset(n, traffic["prompt_len"], traffic["output_len"],
-                         max_total, block)
-        order = stratified_order(n, block, order_rng)
-        if kind == "open_loop":
-            due = arrivals(n, start, span, traffic.get("arrivals", {}),
-                           order_rng, block)
-        else:
-            due = np.zeros((n,))
-        if rotation and n:
-            r = int(rng.integers(n))
-            order = order[r:] + order[:r]
-            due = rotate_arrivals(due, start, span, r)
-        out = []
-        for k, i in enumerate(order):
-            p, o = pairs[i]
-            shared = (prefixes[int(rng.integers(groups))]
-                      if prefixes else None)
-            out.append({"due": float(due[k]),
-                        "prompt": token_ids(rng, p, vocab, shared),
-                        "out": o, "counted": counted})
-        return out, pairs
+        reqs, pairs = _part(traffic, n, start, span, counted, vocab, *draws)
+        return list(reqs), pairs
 
     if kind == "backlog":
         reqs, pairs = part(int(traffic["requests"]), 0.0, 0.0, True)
@@ -221,3 +249,19 @@ def build_requests(traffic: dict, seconds: float, seed: int, vocab: int
               "longest_prompt": max(p for p, _ in pairs + lead_pairs),
               "shortest_prompt": min(p for p, _ in pairs + lead_pairs)}
     return {"requests": reqs, "totals": totals}
+
+
+def backlog_ring(traffic: dict, seed: int, vocab: int, first_lap: int = 0
+                 ) -> Iterator[Tuple[int, dict]]:
+    """A backlog without an end: ``(request number, request)`` from lap
+    ``first_lap`` on, numbers running on across laps (request ``i`` of
+    lap ``k`` is ``k * requests + i``). Lap 0 is
+    :func:`build_requests`' list, request for request; every later lap
+    is the same multiset in a stratified order of its own. A request is
+    drawn only when it is taken."""
+    n = int(traffic["requests"])
+    for lap in itertools.count(first_lap):
+        reqs, _ = _part(traffic, n, 0.0, 0.0, True, vocab,
+                        *_lap_rngs(traffic, seed, vocab, lap))
+        for i, r in enumerate(reqs):
+            yield lap * n + i, r

@@ -1,7 +1,7 @@
 """Device time of the kernel NAMED ``paged_decode_attention`` in one
 execution of the decode program (``jit_serve_decode``), median over the
-executions of the traced window: the name-keyed twin of what
-``paged_decode_roofline`` finds by the pool's shape."""
+executions of the traced window: per execution, the calls whose total
+``paged_decode_roofline`` holds against the bytes they had to read."""
 
 from benchmark.lib import program_spans as ps
 

@@ -1,15 +1,16 @@
-"""Device time of the prefill programs (the compiled programs that hold
-the flash kernel and no paged kernel) in the traced window, per thousand
-prompt tokens they prefilled."""
+"""Device time of the prefill programs (``jit_serve_prefill``, and
+``jit_serve_prefill_chunk`` where a cell runs it) in the traced window,
+per thousand prompt tokens they prefilled."""
 
-from benchmark.lib.trace_select import is_paged, traced_admissions
+from benchmark.lib.trace_select import (PREFILL, program_runs,
+                                        traced_admissions)
 
 
 def read(run, trace):
     if trace is None or run["kind"] != "serve":
         return None
-    progs = trace.modules_with(lambda t: not is_paged(t, run))
+    progs = program_runs(trace, PREFILL)
     tokens = sum(traced_admissions(run, len(progs)))
     if not progs or not tokens:
         return None
-    return sum(e - s for _, s, e, _ in progs) * 1e3 / (tokens / 1000.0)
+    return sum(e - s for s, e in progs) * 1e3 / (tokens / 1000.0)

@@ -256,14 +256,9 @@ def test_reduction_of_a_hand_made_trace():
     # 4.25-6 -> the second step (its middle, 5.125, is past the stamp
     # span's end), 7-10 -> no span at 8.5
     assert gaps == {"bench:step": 2.75, "_no_host_span_": 3.0}
-    run = {"shapes": {"kv_heads": 16, "head_dim": 128}, "num_blocks": 49,
-           "block_size": 128}
-    from benchmark.lib.trace_select import is_paged
-    dec = red.modules_with(lambda t: is_paged(t, run))
-    pre = red.modules_with(lambda t: not is_paged(t, run))
-    assert [m[0] for m in dec] == ["jit__unknown(1)"]
-    assert [m[0] for m in pre] == ["jit__unknown(2)"]
-    assert dec[0][3][0][2] - dec[0][3][0][1] == 0.5
+    # kernels are Pallas calls, whatever encloses or names them
+    assert [tr.op_name(t) for t, _, _ in red.devices[0].kernels()] == [
+        "call", "attn"]
 
 
 def test_recorded_tpu_trace_reduces():
