@@ -38,7 +38,7 @@ from deepspeed_tpu.inference.speculation import (
 from deepspeed_tpu.model_implementations.transformer import (
     InferenceTransformerConfig, causal_forward, decode_chunk, decode_step,
     encoder_forward,
-    init_params, prefill, tp_param_specs)
+    init_params, model_family, prefill, tp_param_specs)
 from deepspeed_tpu.telemetry import (MetricRegistry, get_registry,
                                      watched_jit)
 
@@ -130,6 +130,11 @@ class InferenceEngine:
         elif isinstance(model, InferenceTransformerConfig):
             self.model_config = model
             params = init_params(jax.random.PRNGKey(0), model)
+        elif model_family(model) is not None:
+            # a configuration that names the module that runs it
+            self.model_config = model
+            params = model_family(model).init_params(jax.random.PRNGKey(0),
+                                                     model)
         else:
             # torch nn.Module / HF model → policy conversion
             try:
@@ -141,6 +146,8 @@ class InferenceEngine:
                     "params) instead") from e
             self.model_config, params = convert_hf_model(
                 model, dtype=self._act_dtype)
+        if getattr(self.model_config, "cache_kind", "kv") == "latent":
+            self._refuse_for_latent()
         # engine dtype wins over the model config's (one source of truth):
         # activations are cast to model_config.dtype inside the forward
         self.model_config = dataclasses.replace(self.model_config,
@@ -233,6 +240,26 @@ class InferenceEngine:
                               mesh=self.mesh),
             name="infer_causal_forward", registry=self.telemetry)
         self._gen_loops: Dict[Any, Any] = {}
+
+    def _refuse_for_latent(self) -> None:
+        """A latent-attention model (``cache_kind == "latent"``) runs on one
+        device with full-precision weights: its parameter tree has no
+        Megatron specs, its expert layer issues no exchange, and nothing
+        quantizes its latents. Each switch that would need one of those
+        is refused here by name."""
+        c = self.config
+        on = [name for name, is_on in (
+            ("dtype='int8' / quant.enabled", self._weight_quant),
+            ("quant.activation.enabled", c.quant.activation.enabled),
+            ("tp_size", c.tp_size > 1),
+            ("moe.ep_size", c.moe.ep_size > 1),
+            ("seq_parallel_size", c.seq_parallel_size > 1)) if is_on]
+        if on:
+            raise NotImplementedError(
+                f"a {type(self.model_config).__name__} model cannot be "
+                f"served with {', '.join(on)}: it runs on one device as "
+                "its share of an expert-parallel deployment "
+                "(experts_held), with the weights in the serving dtype")
 
     def _loop_cache_get(self, key):
         """Decode-loop cache lookup with hit/miss telemetry: a rising

@@ -463,11 +463,122 @@ def paged_gather_kv(cache: PagedKVCache, layer: int):
             v.reshape(S, cache.max_context, *v.shape[3:]))
 
 
-def paged_advance(cache: PagedKVCache, active: jnp.ndarray) -> PagedKVCache:
+def paged_advance(cache, active: jnp.ndarray):
     """Advance live slots' lengths by one; idle slots stay pinned at 0 so
-    their appends keep landing in the null block."""
+    their appends keep landing in the null block. Either kind of pool."""
     return cache.replace(
         lengths=cache.lengths + active.astype(jnp.int32))
+
+
+# ------------------------------------------------------------ latent pool
+# Latent attention (MLA) caches ONE row per token per attention: the
+# normed compressed latent and the shared rotary key, ``[c_kv ; k_rope]``
+# (576 values at the published widths), not K and V per head. A model may
+# hold several attentions a layer (a shortcut-connected double block has
+# two). The pool is ONE BUFFER PER ATTENTION, ``rows[i] [NB, W, BS]``
+# (a block's positions on the last, lane, dim: W = 576 is not a multiple
+# of 128 lanes and BS = 128 is; ops/pallas/latent_decode_attention.py),
+# under the same block tables, lengths, null block and allocator as
+# :class:`PagedKVCache`: no program ever cuts one attention's rows out of
+# a stacked ``[L, NB, ...]`` array (PERF.md section 5: that cut is 82 % of
+# the K/V pool's decode step), and an append is an in-place update of a
+# donated buffer.
+
+
+@struct.dataclass
+class LatentPagedCache:
+    """Paged decode workspace of a latent-attention model.
+
+    rows: one ``[num_blocks, W, block_size]`` pool per attention
+    sub-block, in the order the model runs them (layer-major); row
+    ``t`` of a block is ``rows[i][block, :, t]``.
+    block_tables / lengths: as :class:`PagedKVCache`.
+    aux: an int32 array that belongs to the MODEL (shape
+    ``cfg.aux_shape``): its programs may accumulate into it as they run
+    and the server fetches it with the sampled tokens. The cache never
+    reads it and does not know what it holds; it rides here because the
+    cache is the one donated value every serving program threads
+    through, so it costs no extra output, fetch or sync."""
+    rows: tuple                # of [NB, W, BS]
+    block_tables: jnp.ndarray  # [S, MB] int32
+    lengths: jnp.ndarray       # [S] int32
+    aux: jnp.ndarray           # the model's; int32
+
+    @property
+    def block_size(self) -> int:
+        return self.rows[0].shape[2]
+
+    @property
+    def num_blocks(self) -> int:
+        return self.rows[0].shape[0]
+
+
+def init_latent_paged_cache(num_attentions: int, num_slots: int,
+                            num_blocks: int, block_size: int,
+                            max_blocks_per_slot: int, width: int,
+                            aux_shape=(1, 1),
+                            dtype=jnp.bfloat16) -> LatentPagedCache:
+    """``num_blocks`` includes the reserved null block 0, as in
+    :func:`init_paged_cache`."""
+    return LatentPagedCache(
+        rows=tuple(jnp.zeros((num_blocks, width, block_size), dtype)
+                   for _ in range(num_attentions)),
+        block_tables=jnp.zeros((num_slots, max_blocks_per_slot), jnp.int32),
+        lengths=jnp.zeros((num_slots,), jnp.int32),
+        aux=jnp.zeros(aux_shape, jnp.int32))
+
+
+def _with_rows(cache: LatentPagedCache, idx: int, new) -> LatentPagedCache:
+    return cache.replace(
+        rows=cache.rows[:idx] + (new,) + cache.rows[idx + 1:])
+
+
+@scoped("latent_write")
+def latent_write_prompt(cache: LatentPagedCache, idx: int,
+                        rows: jnp.ndarray, slot) -> LatentPagedCache:
+    """Prefill: scatter one prompt's ``[T, W]`` rows of attention ``idx``
+    into ``slot``'s blocks at positions ``0..T-1`` (T a multiple of the
+    block size). The same right-pad invariant as
+    :func:`paged_write_prompt`; lengths are pinned by the caller."""
+    BS = cache.block_size
+    nb = rows.shape[0] // BS
+    blocks = jax.lax.dynamic_slice_in_dim(cache.block_tables, slot, 1,
+                                          0)[0, :nb]
+    pool = cache.rows[idx]
+    return _with_rows(cache, idx, pool.at[blocks].set(
+        jnp.swapaxes(rows.reshape(nb, BS, -1), 1, 2).astype(pool.dtype)))
+
+
+@scoped("latent_write")
+def latent_append_token(cache: LatentPagedCache, idx: int,
+                        rows: jnp.ndarray) -> LatentPagedCache:
+    """Decode: append one token's ``[S, W]`` row of attention ``idx`` at
+    ``lengths[s]`` for every slot; idle slots write into the null block.
+    Lengths advance once a step (:func:`paged_advance`). On a TPU the
+    Pallas writer (it rewrites the one block a slot appends to); the
+    scatter elsewhere."""
+    pool = cache.rows[idx]
+    if jax.default_backend() == "tpu":
+        from deepspeed_tpu.ops.pallas.latent_decode_attention import (
+            paged_latent_append)
+        return _with_rows(cache, idx, paged_latent_append(
+            pool, rows, cache.block_tables, cache.lengths))
+    BS = cache.block_size
+    pos = cache.lengths
+    blk = jnp.take_along_axis(cache.block_tables, (pos // BS)[:, None],
+                              axis=1)[:, 0]
+    return _with_rows(cache, idx, pool.at[blk, :, pos % BS].set(
+        rows.astype(pool.dtype)))
+
+
+def pool_arrays(cache) -> tuple:
+    """The device arrays that make up a pool's payload, whichever kind
+    of pool it is (memory accounting reads their sizes)."""
+    if isinstance(cache, LatentPagedCache):
+        return tuple(cache.rows)
+    if cache.k_scale is None:
+        return (cache.k, cache.v)
+    return (cache.k, cache.v, cache.k_scale, cache.v_scale)
 
 
 # ------------------------------------------------------------- host tier

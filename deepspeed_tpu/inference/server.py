@@ -49,15 +49,16 @@ from deepspeed_tpu.inference.async_loop import InFlightStep, PublishWorker
 from deepspeed_tpu.inference.engine import (InferenceEngine, _bucket,
                                             check_draft_compat)
 from deepspeed_tpu.inference.kv_cache import (HostKVTier, PagedKVCache,
+                                              init_latent_paged_cache,
                                               init_paged_cache,
                                               paged_read_block,
-                                              paged_swap_in)
+                                              paged_swap_in, pool_arrays)
 from deepspeed_tpu.inference.scheduler import Request, Scheduler
 from deepspeed_tpu.inference.speculation import (LookupIndex,
                                                  draft_propose,
                                                  greedy_accept_host)
 from deepspeed_tpu.model_implementations.transformer import (
-    paged_decode_step, paged_prefill, paged_prefill_chunk,
+    model_family, paged_decode_step, paged_prefill, paged_prefill_chunk,
     paged_verify_step)
 from deepspeed_tpu.telemetry import (NULL_STEP_HANDLE, AlertEngine,
                                      CanaryProber, CapacityModel,
@@ -178,6 +179,14 @@ class ContinuousBatchingServer:
                 "unsupported — the paged pool is already the "
                 "long-context memory lever")
         self.engine = engine
+        # a latent-attention model (kv_cache.LatentPagedCache): the pool
+        # holds one row a token an attention, not K and V per head, and
+        # what cannot honour that yet is refused here by name
+        self._latent = getattr(engine.model_config, "cache_kind",
+                               "kv") == "latent"
+        if self._latent:
+            self._refuse_for_latent(engine.config, draft_engine,
+                                    handoff_import)
         # supervised = this server is ONE REPLICA under a ServingFrontend
         # (inference/frontend.py): the frontend owns the scrape port and
         # installs its own heartbeat watchdog on self.watchdog, so the
@@ -623,6 +632,14 @@ class ContinuousBatchingServer:
                 name="serve_draft_decode", registry=self.telemetry,
                 donate_argnames=("cache",))
         self._results: Dict[int, List[int]] = {}
+        # what a latent-attention model's programs accumulate on the
+        # device (``cache.aux``) and the registry series its module
+        # gives each cell of it
+        self._aux_seen = self._aux_series = None
+        if self._latent:
+            self._aux_seen = np.zeros(mcfg.aux_shape, np.int64)
+            self._aux_series = model_family(mcfg).aux_series(
+                mcfg, self.telemetry)
         self._next_id = 0
         self._step_clock = 0           # decode steps executed
         # scheduler tick: advances on EVERY step() call, decode or not —
@@ -757,11 +774,9 @@ class ContinuousBatchingServer:
             srv = ref()
             if srv is None:
                 return None
-            c = srv._cache
             # int8 pools carry their scale tiles in the same bucket —
             # the pool's HBM cost is payload + scales
-            return ((c.k, c.v) if c.k_scale is None
-                    else (c.k, c.v, c.k_scale, c.v_scale))
+            return pool_arrays(srv._cache)
 
         def _params():
             srv = ref()
@@ -994,6 +1009,41 @@ class ContinuousBatchingServer:
             self.scheduler.allocator.free_ids)
         return self._pool_acct.snapshot()
 
+    # switches whose code reads or writes K/V pools ``[L, NB, BS, H, D]``
+    # (block copies, scale tiles, chunk / verify kernels): a latent pool
+    # has none of those shapes, and nothing falls back to K/V code
+    _LATENT_REFUSES = (
+        ("kv_cache_dtype", lambda c: c.kv_cache_dtype != "fp",
+         "the latent pool has no int8 rows or scale tiles"),
+        ("kv_host_offload", lambda c: c.kv_host_offload,
+         "block payloads are read and swapped as K/V slabs"),
+        ("enable_prefix_caching", lambda c: c.enable_prefix_caching,
+         "a cache hit prefills its tail through the chunk program"),
+        ("prefill_chunk_tokens", lambda c: bool(c.prefill_chunk_tokens),
+         "chunked prefill attends the pool with the K/V chunk kernel"),
+        ("prefill_chain", lambda c: c.prefill_chain,
+         "it chains chunked prefill"),
+        ("speculation_tokens", lambda c: bool(c.speculation_tokens),
+         "the batched verify attends the pool with the K/V verify kernel"),
+    )
+
+    @classmethod
+    def _refuse_for_latent(cls, cfg, draft_engine, handoff_import) -> None:
+        on = [(name, why) for name, test, why in cls._LATENT_REFUSES
+              if test(cfg)]
+        if draft_engine is not None or cfg.speculation_draft is not None:
+            on.append(("speculation_draft / draft_engine",
+                       "a draft pool mirrors K/V block tables"))
+        if handoff_import:
+            on.append(("handoff_import", "handoff payloads are K/V slabs"))
+        if on:
+            raise NotImplementedError(
+                "a latent-attention model (latent paged cache) cannot be "
+                "served with " + "; ".join(
+                    f"{name} ({why})" for name, why in on)
+                + " — leave these at their defaults: monolithic bucketed "
+                "prefill and plain paged decode serve it")
+
     @staticmethod
     def _prefill_fn(params, ids, length, cache, slot, *, cfg, mesh):
         logits, cache = paged_prefill(params, cfg, ids, length, cache,
@@ -1019,8 +1069,16 @@ class ContinuousBatchingServer:
                                           mesh=mesh)
         return _sample(logits), cache
 
-    def _make_pool(self, num_blocks: int) -> PagedKVCache:
+    def _make_pool(self, num_blocks: int):
         mcfg = self.engine.model_config
+        if self._latent:
+            # one buffer per attention sub-block: no program cuts a
+            # layer's rows out of a stacked pool
+            return init_latent_paged_cache(
+                mcfg.attentions, self.num_slots, num_blocks,
+                self.block_size, self.max_blocks_per_slot,
+                mcfg.latent_width, aux_shape=mcfg.aux_shape,
+                dtype=self.engine._act_dtype)
         cache = init_paged_cache(
             mcfg.n_layer, self.num_slots, num_blocks, self.block_size,
             self.max_blocks_per_slot, mcfg.kv_heads, mcfg.head_dim,
@@ -1813,7 +1871,7 @@ class ContinuousBatchingServer:
                 # weight = the PADDED bucket actually computed, so the
                 # step's device split follows the work the device did
                 self._ledger.add_weight(req.request_id, T)
-            tok0 = int(np.asarray(tok0)[0])   # host sync: prefill done
+            tok0 = int(self._fetch_tokens(tok0)[0])   # host sync: prefill done
             now_t = self._clock()
             self._request_phase(req.request_id, now_t, DECODE_SPAN)
             # prefill compute runs inside the admission phase; its
@@ -2776,7 +2834,7 @@ class ContinuousBatchingServer:
         self._step_clock += 1
         n_active = int(active.sum())
         self._active_slot_steps += n_active
-        nxt = np.asarray(nxt)             # host sync: the step completed
+        nxt = self._fetch_tokens(nxt)     # host sync: the step completed
         t1 = self._clock()
         dt = t1 - t0
         sp.mark("sync_wait", now=t1, fetch=True,
@@ -2806,6 +2864,32 @@ class ContinuousBatchingServer:
             self._commit_slot_token(slot, state, int(nxt[slot]),
                                     finished)
         sp.mark("commit")
+
+    def _fetch_tokens(self, tokens) -> np.ndarray:
+        """The synchronous loop's fetch of a program's sampled tokens.
+        A latent-attention model's programs count on the device as
+        they run (``cache.aux``, the model's own, cumulative); the array
+        comes over in the same ``device_get`` as the tokens, after the
+        same wait, and its growth goes to the registry."""
+        if not self._latent:
+            return np.asarray(tokens)
+        got, aux = jax.device_get((tokens, self._cache.aux))
+        self._publish_aux(aux)
+        return got
+
+    def _publish_aux(self, aux=None) -> None:
+        """Publish what the model's device counters grew by since the
+        last call, each cell to the series its module named. Without
+        ``aux`` the array is read from the pool: only where no program
+        is in flight (``stats``, ``close``)."""
+        if not self._latent:
+            return
+        if aux is None:
+            aux = np.asarray(self._cache.aux)
+        grew = aux - self._aux_seen
+        self._aux_seen = aux
+        for p, col in zip(*np.nonzero(grew)):
+            self._aux_series[p][col].inc(float(grew[p, col]))
 
     def _commit_slot_token(self, slot: int, state, tok: int,
                            finished: List[int]) -> None:
@@ -3124,6 +3208,7 @@ class ContinuousBatchingServer:
         # drain() must not silently drop a pipelined step's committed
         # tokens, finishes, or metrics
         self._flush_pipeline(self._deferred_finished, reason="close")
+        self._publish_aux()
         if self._ledger is not None:
             self._ledger.flush_pending()
         self._worker.close()
@@ -3142,6 +3227,7 @@ class ContinuousBatchingServer:
         # worker first so every registry instrument agrees with the
         # host mirrors below
         self._drain_publishing()
+        self._publish_aux()
         units = self._step_clock * self.num_slots
         alloc = self.scheduler.allocator
         return {
@@ -3217,11 +3303,8 @@ class ContinuousBatchingServer:
             # included), and the host tier's residency + swap traffic
             "kv_tier": {
                 "kv_dtype": self.kv_dtype,
-                "pool_bytes": int(
-                    self._cache.k.nbytes + self._cache.v.nbytes
-                    + (self._cache.k_scale.nbytes
-                       + self._cache.v_scale.nbytes
-                       if self._cache.k_scale is not None else 0)),
+                "pool_bytes": int(sum(
+                    a.nbytes for a in pool_arrays(self._cache))),
                 "host_offload": (self.host_tier is not None
                                  and not self._import_only_tier),
                 "host_blocks": (len(self.host_tier)

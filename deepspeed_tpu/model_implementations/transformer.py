@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import importlib
 import math
 from typing import Any, Dict, Optional
 
@@ -52,6 +53,27 @@ from deepspeed_tpu.ops.int8_gemm import (maybe_int8_einsum,
                                          maybe_int8_matmul)
 
 NEG_INF = -1e30
+
+
+def model_family(cfg):
+    """The module that runs ``cfg``'s model, or None for this module's
+    own decoder. A configuration of another architecture names its
+    module (``cfg.family``; it has ``init_params`` and this file's
+    ``paged_prefill`` / ``paged_decode_step`` / ``causal_forward`` under
+    the same contracts), and the entry points below hand over to it; no
+    model is imported here by name. The entry points such a module does
+    not have (a dense cache, a draft chunk, a prompt chunk) say so."""
+    name = getattr(cfg, "family", None)
+    return importlib.import_module(name) if name else None
+
+
+def _own_decoder_only(cfg, what: str) -> None:
+    if model_family(cfg) is not None:
+        raise NotImplementedError(
+            f"{what} is not implemented for a {type(cfg).__name__} "
+            f"model ({cfg.family}): it is served through "
+            "ContinuousBatchingServer (monolithic paged prefill + paged "
+            "decode) and scored through InferenceEngine.forward")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -664,12 +686,22 @@ def _mlp(x, m, cfg):
 
 
 def _moe_mlp(x, moe, cfg, mesh=None):
-    """MoE FFN (reference moe_inference.py: gate → einsum dispatch →
-    all-to-all → expert FFN → all-to-all → combine). Dense dispatch over
-    ``[X, S, ...]`` with a sharding constraint on the expert dim: when the
-    mesh has an ``expert`` axis, XLA lowers the dispatch/combine einsums to
-    the all-to-all pair the reference issues by hand
-    (``einsum_sec_sm_ecm`` + ``_AllToAll``, moe_inference.py:1-466).
+    """MoE FFN of the generic decoder (reference moe_inference.py: gate →
+    einsum dispatch → all-to-all → expert FFN → all-to-all → combine).
+    Which path does what:
+
+    * THIS function dispatches densely over ``[X, S, ...]``: every expert
+      runs on every token, masked. Only under a mesh with an ``expert``
+      axis of more than one device does the sharding constraint on the
+      expert dim make XLA lower the dispatch/combine einsums to the
+      all-to-all pair the reference issues by hand (``einsum_sec_sm_ecm``
+      + ``_AllToAll``, moe_inference.py:1-466). On one device, or on a
+      mesh without that axis, it issues NO collective.
+    * The held-experts layer (``longcat_flash.moe_layer``) never issues
+      one: a process computes the experts it holds over the picks that
+      landed on them (a grouped matmul) and leaves the picks on absent
+      experts to their holders. It has no ``[X, S, E]`` tensor.
+
     Inference gating is exact top-k (no capacity drop: serving must not
     silently zero tokens the way capacity-bound training may)."""
     dt = x.dtype
@@ -830,6 +862,7 @@ def decode_chunk(params, cfg: InferenceTransformerConfig, tokens,
     is the target-model half of speculative decoding; there is no
     reference analog (the reference's engine is strictly one-token
     decode, csrc/transformer/inference)."""
+    _own_decoder_only(cfg, "decode_chunk (speculative verify)")
     if cfg.seq_shard_kv:
         raise NotImplementedError(
             "decode_chunk with seq-sharded KV is unsupported — run "
@@ -889,6 +922,7 @@ def prefill(params, cfg: InferenceTransformerConfig, input_ids, lengths,
             cache: KVCache, mesh=None):
     """Run the right-padded prompt ``[B, T]`` through the model, populating
     the cache. Returns (next-token logits ``[B, V]``, cache)."""
+    _own_decoder_only(cfg, "prefill into a dense KV cache (generate)")
     x, cache = _causal_trunk(params, cfg, input_ids, lengths, cache,
                              mesh=mesh)
     # logits at the last live token of each row
@@ -900,6 +934,7 @@ def decode_step(params, cfg: InferenceTransformerConfig, tokens,
                 cache: KVCache, mesh=None):
     """One generation step: ``tokens [B]`` int32 → (logits [B, V], cache).
     Appends k/v for the new token and advances lengths."""
+    _own_decoder_only(cfg, "decode_step over a dense KV cache (generate)")
     x = _embed(params, cfg, tokens[:, None], cache.lengths[:, None])[:, 0]
     for i, layer in enumerate(params["layers"]):
         x, cache = _block_decode(x, layer, cfg, cache, i, mesh)
@@ -934,6 +969,10 @@ def paged_prefill(params, cfg: InferenceTransformerConfig, input_ids,
 
     ``slot`` is a traced scalar, so one trace per prompt BUCKET serves
     every slot; T must be a multiple of the pool block size."""
+    family = model_family(cfg)
+    if family is not None:
+        return family.paged_prefill(params, cfg, input_ids, length, cache,
+                                    slot, mesh=mesh)
     if cfg.seq_shard_kv:
         raise NotImplementedError(
             "paged serving with a seq-sharded KV pool is unsupported — "
@@ -989,6 +1028,7 @@ def paged_prefill_chunk(params, cfg: InferenceTransformerConfig,
     return the chunk-tail row, which the caller discards. Chunk
     right-pad past ``length`` lands as masked garbage, overwritten by
     the first decode appends — the standard bucket-padding invariant."""
+    _own_decoder_only(cfg, "paged_prefill_chunk (chunked prefill)")
     if cfg.seq_shard_kv:
         raise NotImplementedError(
             "paged serving with a seq-sharded KV pool is unsupported — "
@@ -1046,6 +1086,7 @@ def paged_verify_step(params, cfg: InferenceTransformerConfig, tokens,
     dense cache). ONE traced signature per ``(K, num_slots,
     block_size)``: per-slot acceptance state rides in ``lengths``, so
     varying acceptance lengths never retrace."""
+    _own_decoder_only(cfg, "paged_verify_step (speculative verify)")
     if cfg.seq_shard_kv:
         raise NotImplementedError(
             "paged serving with a seq-sharded KV pool is unsupported — "
@@ -1066,6 +1107,10 @@ def paged_decode_step(params, cfg: InferenceTransformerConfig, tokens,
     ``lengths[s]`` and advances only ``active`` slots — idle slots stay
     pinned at length 0, writing into the reserved null block, so one
     traced program serves every request mix."""
+    family = model_family(cfg)
+    if family is not None:
+        return family.paged_decode_step(params, cfg, tokens, cache, active,
+                                        mesh=mesh)
     x = _embed(params, cfg, tokens[:, None], cache.lengths[:, None])[:, 0]
     for i, layer in enumerate(params["layers"]):
         x, cache = _block_decode_paged(x, layer, cfg, cache, i, mesh)
@@ -1081,6 +1126,10 @@ def causal_forward(params, cfg: InferenceTransformerConfig, input_ids,
     ``attention_mask [B, T]`` masks pad keys (HF semantics) so padded rows
     are not scored against pad context. No cache; ``generate`` keeps the
     last-token fast path."""
+    family = model_family(cfg)
+    if family is not None:
+        return family.causal_forward(params, cfg, input_ids,
+                                     attention_mask, mesh=mesh)
     x, _ = _causal_trunk(params, cfg, input_ids, None, None,
                          key_mask=attention_mask, mesh=mesh)
     if cfg.head == "none":
@@ -1092,6 +1141,7 @@ def encoder_forward(params, cfg: InferenceTransformerConfig, input_ids,
                     attention_mask=None, token_type_ids=None, mesh=None):
     """Bidirectional encoder forward (BERT/DistilBERT policies). Returns
     final hidden states ``[B, T, E]``."""
+    _own_decoder_only(cfg, "encoder_forward")
     B, T = input_ids.shape
     positions = jnp.arange(T)[None, :].repeat(B, 0)
     x = _embed(params, cfg, input_ids, positions, token_type_ids)

@@ -271,7 +271,10 @@ def _named(fun, name: str):
 SCOPES = frozenset((
     "embed", "ln", "attn_qkv", "kv_write", "kv_read", "attn_kernel",
     "attn_out", "mlp", "lm_head", "sample",
-    "fwd_bwd", "optimizer", "grad_exchange"))
+    "fwd_bwd", "optimizer", "grad_exchange",
+    # model_implementations/longcat_flash.py
+    "mla_qkv", "latent_write", "mla_attn", "dense_ffn", "moe_router",
+    "moe_dispatch", "moe_experts", "moe_combine"))
 
 _INSTR = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+)\s*=\s")
 _COMPUTATION = re.compile(

@@ -1,0 +1,600 @@
+"""LongCat-Flash on the normal serving path, at a small size on the CPU:
+widths cut, structure whole (2 double-block layers, 2 latent attentions
+each, real and identity experts, top-k > 1, a non-zero correction bias,
+a share of the experts held).
+
+The program (``model_implementations/longcat_flash.py`` through
+``InferenceEngine`` + ``ContinuousBatchingServer`` + the latent paged
+cache) is held to the plain reference (``benchmark/lib/
+reference_longcat.py``), and the benchmark's cell is rehearsed at the
+tiny size through the harness's own runner and readers.
+"""
+import argparse
+import json
+import os
+import shutil
+import sys
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+from benchmark.lib import harness  # noqa: E402
+from deepspeed_tpu.inference import (ContinuousBatchingServer,  # noqa: E402
+                                     DeepSpeedInferenceConfig,
+                                     InferenceEngine)
+from deepspeed_tpu.inference.kv_cache import (  # noqa: E402
+    init_latent_paged_cache)
+from deepspeed_tpu.model_implementations import (  # noqa: E402
+    longcat_flash as lf)
+from deepspeed_tpu.model_implementations import transformer  # noqa: E402
+from deepspeed_tpu.ops.pallas import latent_decode_attention as lda  # noqa: E402
+
+BENCH = os.path.join(REPO, "benchmark")
+family = harness.load_family("longcat_flash")
+ref = family.reference
+
+MODEL = dict(
+    family="longcat_flash", dtype="float32", vocab_size=256, hidden_size=64,
+    num_layers=2, num_attention_heads=4, ffn_hidden_size=128,
+    expert_ffn_hidden_size=32, q_lora_rank=32, kv_lora_rank=16,
+    qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+    mla_scale_q_lora=True, mla_scale_kv_lora=True, n_routed_experts=8,
+    zero_expert_num=4, moe_topk=3, routed_scaling_factor=6,
+    rms_norm_eps=1e-5, rope_theta=1e7, max_position_embeddings=1024,
+    experts_held=[2, 6])
+BS, MB, SLOTS = 16, 4, 3
+
+
+def _model(dtype="float32", held=(2, 6), seed=3):
+    return family.serve_model(dict(MODEL, dtype=dtype,
+                                   experts_held=list(held)), seed)
+
+
+@pytest.fixture(scope="module")
+def f32():
+    cfg, params = _model()
+    assert float(jnp.abs(params["layers"][0]["moe"]["router_bias"]).max()) > 0
+    return cfg, params, family.reference_from_serve(cfg, params)
+
+
+def _ids(n, t, seed=0):
+    return np.random.default_rng(seed).integers(1, MODEL["vocab_size"],
+                                                size=(n, t))
+
+
+def _rel(a, b):
+    return float(jnp.abs(a - b).max() / jnp.abs(b).max())
+
+
+def _pool(cfg, slots=SLOTS):
+    """A latent pool whose slot ``s`` owns blocks ``1 + s MB ..``."""
+    cache = init_latent_paged_cache(
+        cfg.attentions, slots, 1 + slots * MB, BS, MB, cfg.latent_width,
+        aux_shape=cfg.aux_shape, dtype=cfg.dtype)
+    return cache.replace(block_tables=jnp.asarray(
+        1 + np.arange(slots * MB).reshape(slots, MB), jnp.int32))
+
+
+# ------------------------------------------- float32 program = reference
+
+def test_full_sequence_logits_match_the_reference(f32):
+    cfg, params, weights = f32
+    ids = _ids(2, 24)
+    got = transformer.causal_forward(params, cfg, jnp.asarray(ids))
+    assert _rel(got, ref.logits(weights, ids)) < 1e-4
+
+
+def test_paged_prefill_and_decode_match_the_reference(f32):
+    """Prompts of two lengths prefilled into two slots (the third stays
+    idle), then five decode steps over the pool: the logits of every
+    step are the reference's at that position of the full sequence."""
+    cfg, params, weights = f32
+    lens, steps = (11, 27), 5
+    seqs = _ids(2, max(lens) + steps, seed=1)
+    cache = _pool(cfg)
+    prefill = jax.jit(lambda *a: transformer.paged_prefill(a[0], cfg, *a[1:]))
+    decode = jax.jit(lambda *a: transformer.paged_decode_step(a[0], cfg,
+                                                              *a[1:]))
+    logits = {}
+    for s, n in enumerate(lens):
+        ids = np.zeros((1, 2 * BS), np.int32)
+        ids[0, :n] = seqs[s, :n]
+        lg, cache = prefill(
+            params, jnp.asarray(ids), jnp.asarray([n], jnp.int32),
+            cache, jnp.int32(s))
+        logits[s, n - 1] = lg[0]
+    active = jnp.asarray([True, True, False])
+    for k in range(steps):
+        toks = [seqs[s, n + k] for s, n in enumerate(lens)] + [0]
+        lg, cache = decode(params, jnp.asarray(toks, jnp.int32), cache,
+                           active)
+        for s, n in enumerate(lens):
+            logits[s, n + k] = lg[s]
+    assert [int(x) for x in cache.lengths] == [n + steps for n in lens] + [0]
+    for s, n in enumerate(lens):
+        want = ref.logits(weights, seqs[s:s + 1, :n + steps])[0]
+        for (slot, pos), got in logits.items():
+            if slot == s:
+                assert _rel(got, want[pos]) < 1e-4, (slot, pos)
+    # the routing counters: every valid token routed once a layer, each
+    # with top-k picks that are held, absent or identity
+    c = np.asarray(cache.aux)
+    held = cfg.num_held
+    tail = dict(zip(lf.COUNTER_TAIL, c[:, held:].T))
+    assert list(tail["tokens_routed"]) == [2 * steps * cfg.num_layers,
+                                           sum(lens) * cfg.num_layers]
+    assert list(tail["layer_calls"]) == [steps * cfg.num_layers,
+                                         2 * cfg.num_layers]
+    assert (c[:, :held].sum(1) + tail["identity_picks"]
+            + tail["absent_picks"] == tail["tokens_routed"] * cfg.moe_topk
+            ).all()
+
+
+# --------------------------------------- absorbed = materialised attention
+
+def test_absorbed_decode_equals_materialised_attention_on_one_cache(f32):
+    """The same cached rows, attended both ways: K and V built per head
+    from the latent (what prefill does) against queries carried into the
+    latent space (what decode does)."""
+    cfg, params, _ = f32
+    a = params["layers"][0]["attn"][1]
+    rng = np.random.default_rng(5)
+    T = 23
+    h = jnp.asarray(rng.normal(size=(1, T, cfg.hidden_size)), jnp.float32)
+    positions = jnp.arange(T)[None]
+    q_nope, q_rope, rows = lf._mla_project(h, a, cfg, positions)
+    want = lf._materialised_attention(q_nope, q_rope, rows, a, cfg)[0, -1]
+    cache = _pool(cfg, slots=1)
+    padded = jnp.zeros((2 * BS, cfg.latent_width)).at[:T].set(rows[0])
+    from deepspeed_tpu.inference.kv_cache import latent_write_prompt
+    cache = latent_write_prompt(cache, 1, padded, jnp.int32(0))
+    got = lf._absorbed_attention(
+        q_nope[0, -1:], q_rope[0, -1:], cache.rows[1], cache.block_tables,
+        jnp.asarray([T], jnp.int32), a, cfg)[0]
+    assert _rel(got, want) < 1e-5
+
+
+@pytest.mark.parametrize("lengths", [(0, 5, 16, 41), (48, 1, 17, 32)])
+def test_latent_decode_kernel_matches_its_oracle(lengths):
+    """The Pallas kernel in interpret mode against the XLA formulation:
+    idle slots, partial blocks, whole blocks, a full table."""
+    rng = np.random.default_rng(0)
+    S, H, W, V, bs, mb = 4, 8, 40, 32, 16, 3
+    q = jnp.asarray(rng.normal(size=(S, H, W)), jnp.float32)
+    pool = jnp.asarray(rng.normal(size=(1 + S * mb, W, bs)), jnp.float32)
+    tables = jnp.asarray(1 + rng.permutation(S * mb).reshape(S, mb),
+                         jnp.int32)
+    live = jnp.asarray(lengths, jnp.int32)
+    got = lda.paged_latent_decode_attention(
+        q, pool, tables, live, value_dim=V, scale=0.2, interpret=True)
+    want = lda.paged_latent_decode_attention_reference(
+        q, pool, tables, live, value_dim=V, scale=0.2)
+    assert float(jnp.abs(got - want).max()) < 1e-5
+    assert float(jnp.abs(got[np.asarray(lengths) == 0]).max(initial=0)) == 0
+
+
+def test_latent_append_kernel_writes_what_the_scatter_writes():
+    """The Pallas pool writer in interpret mode against the XLA scatter:
+    every slot's row lands in its block's column, idle slots in the null
+    block, nothing else moves."""
+    from deepspeed_tpu.inference.kv_cache import latent_append_token
+    cfg, _ = _model()
+    rng = np.random.default_rng(4)
+    cache = _pool(cfg)
+    cache = cache.replace(
+        rows=tuple(jnp.asarray(rng.normal(size=r.shape), r.dtype)
+                   for r in cache.rows),
+        lengths=jnp.asarray([0, 17, 2 * BS - 1], jnp.int32),
+        block_tables=cache.block_tables.at[0].set(0))      # slot 0 idle
+    rows = jnp.asarray(rng.normal(size=(SLOTS, cfg.latent_width)),
+                       jnp.float32)
+    want = latent_append_token(cache, 1, rows).rows[1]
+    got = lda.paged_latent_append(cache.rows[1], rows, cache.block_tables,
+                                  cache.lengths, interpret=True)
+    np.testing.assert_array_equal(got, want)
+    assert float(jnp.abs(got[1 + MB + 1, :, 1] - rows[1]).max()) == 0
+    assert int((np.asarray(got) != np.asarray(cache.rows[1])).sum()) \
+        <= SLOTS * cfg.latent_width
+
+
+# ------------------------------------------------- the share and the model
+
+def _moe_input(cfg, n=40, seed=7):
+    return jnp.asarray(np.random.default_rng(seed).normal(
+        size=(n, cfg.hidden_size)), jnp.float32)
+
+
+def test_the_shares_of_one_expert_layer_sum_to_the_uncut_layer():
+    """Four shares of two experts each, run by the PROGRAM: the real
+    experts' parts (each by its holder) and the identity term (once)
+    sum to the uncut layer of the REFERENCE."""
+    cfg_all, params = _model(held=(0, 8))
+    moe = params["layers"][1]["moe"]
+    u = _moe_input(cfg_all)
+    weights = family.reference_from_serve(cfg_all, params)
+    whole = ref._moe(u, weights["layers"][1], ref._sizes(weights))
+    valid = jnp.ones((u.shape[0],), bool)
+    picks, w = lf._route(u, moe, cfg_all)
+    identity = jnp.sum(jnp.where(picks >= cfg_all.n_routed_experts, w, 0.0),
+                       -1)[:, None] * u
+    total = identity
+    for lo in range(0, 8, 2):
+        cfg = lf.LongcatFlashConfig(**{
+            **{f.name: getattr(cfg_all, f.name)
+               for f in cfg_all.__dataclass_fields__.values()},
+            "experts_held": (lo, lo + 2)})
+        part = dict(moe, experts=jax.tree.map(lambda a: a[lo:lo + 2],
+                                              moe["experts"]))
+        m, counts = lf.moe_layer(u, part, cfg, valid)
+        total = total + (m - identity)       # a share's real experts
+        assert int(counts[:2].sum()) == int(
+            ((picks >= lo) & (picks < lo + 2)).sum())
+    assert _rel(total, whole) < 1e-5
+
+
+@pytest.mark.parametrize("held,branch", [((2, 4), "fast"), ((0, 8), "all")])
+def test_expert_layer_is_exact_on_both_row_buffers(held, branch):
+    """200 tokens x top-3 = 600 picks, 128 fast rows: a share of two
+    experts lands ~100 picks (the small buffer), all eight experts land
+    ~400 (the fallback over every pick). Both equal the reference."""
+    cfg, params = _model(held=held)
+    moe = params["layers"][0]["moe"]
+    u = _moe_input(cfg, n=200)
+    m, counts = lf.moe_layer(u, moe, cfg, jnp.ones((200,), bool))
+    landed = int(counts[:cfg.num_held].sum())
+    assert (landed <= lf._fast_rows(200, cfg.moe_topk)) == (branch == "fast")
+    weights = family.reference_from_serve(cfg, params)
+    want = ref._moe(u, weights["layers"][0], ref._sizes(weights))
+    assert _rel(m, want) < 1e-5
+
+
+def test_identity_experts_cost_no_matmul_and_bias_moves_only_selection():
+    cfg, params = _model()
+    moe = dict(params["layers"][0]["moe"])
+    u = _moe_input(cfg, n=12)
+    valid = jnp.ones((12,), bool)
+    free_picks, free_w = lf._route(u, moe, cfg)
+    # a correction bias that puts every identity expert first
+    bias = jnp.where(jnp.arange(cfg.router_outputs)
+                     >= cfg.n_routed_experts, 10.0, 0.0)
+    moe["router_bias"] = bias
+    picks, w = lf._route(u, moe, cfg)
+    assert bool((picks >= cfg.n_routed_experts).all())
+    assert not bool((free_picks >= cfg.n_routed_experts).all())
+    # the weights are the raw scores times the factor, bias or no bias
+    scores = jax.nn.softmax(u @ moe["router"], axis=-1)
+    np.testing.assert_allclose(
+        w, 6.0 * jnp.take_along_axis(scores, picks, -1), rtol=1e-5)
+    np.testing.assert_allclose(
+        free_w, 6.0 * jnp.take_along_axis(scores, free_picks, -1), rtol=1e-5)
+    m, counts = lf.moe_layer(u, moe, cfg, valid)
+    # no pick landed on an expert: the grouped matmul has no row to visit
+    assert int(counts[:cfg.num_held].sum()) == 0
+    assert int(counts[cfg.num_held]) == 12 * cfg.moe_topk
+    np.testing.assert_allclose(m, w.sum(-1)[:, None] * u, rtol=1e-5)
+
+
+def test_the_seeded_bias_holds_the_identity_share_and_loads_experts_alike():
+    """At the published router sizes the seeded correction bias lifts the
+    zero-compute experts, which the seeded router scores lower, back to
+    a third of the picks (8 real experts of 12 a token); every expert of
+    a chip's share sees 256 x 12 / 768 = 4 of 256 tokens, give or take
+    one; without the bias the identity share falls by more than a third
+    of itself."""
+    cfg = lf.LongcatFlashConfig(vocab_size=256, experts_held=(0, 16))
+    n = 16384
+    u = jax.random.normal(jax.random.PRNGKey(1), (n, cfg.hidden_size))
+    u = u * jax.lax.rsqrt(jnp.mean(u * u, -1, keepdims=True))
+    moe = {"router": lf.init_router(jax.random.PRNGKey(2), cfg),
+           "router_bias": lf.router_bias(cfg)}
+    picks, _ = lf._route(u, moe, cfg)
+    picks = np.asarray(picks)
+    assert 0.31 < (picks >= 512).mean() < 0.355
+    per_256 = np.bincount(picks.reshape(-1), minlength=768)[:16] * 256.0 / n
+    assert per_256.min() > 2.8 and per_256.max() < 5.2, per_256
+    free, _ = lf._route(u, dict(moe, router_bias=jnp.zeros(768)), cfg)
+    assert (np.asarray(free) >= 512).mean() < 0.2
+
+
+def _shapes_with_experts_first(text, cfg, tokens):
+    """Tensors ``[experts, tokens, ...]`` named in a program's text."""
+    import re
+    found = []
+    for experts in (cfg.num_held, cfg.n_routed_experts, cfg.router_outputs):
+        found += re.findall(rf"[\[<]{experts}[,x] ?{tokens}[,x]", text)
+    return found
+
+
+def test_no_dense_expert_tensor_in_the_decode_program(f32):
+    """The held-experts layer gathers the landed picks and runs a grouped
+    matmul (``ragged_dot``) over them: the decode program's jaxpr holds
+    no ``[experts, tokens, ...]`` tensor. (What a backend makes of
+    ``ragged_dot`` is its own: the CPU expands it, a TPU runs a Mosaic
+    kernel; the next test compiles for one.)"""
+    cfg, params, _ = f32
+    cache = _pool(cfg)
+    jaxpr = str(jax.make_jaxpr(lambda p, t, c, a: transformer.paged_decode_step(
+        p, cfg, t, c, a))(params, jnp.zeros((SLOTS,), jnp.int32), cache,
+                          jnp.ones((SLOTS,), bool)))
+    assert "ragged_dot" in jaxpr
+    assert not _shapes_with_experts_first(jaxpr, cfg, SLOTS)
+
+
+def test_expert_layer_compiles_for_v5e_as_a_grouped_matmul_kernel():
+    """Compiled for a described (not attached) TPU v5e, at widths large
+    enough that the compiler does not expand the grouped matmul: the
+    expert layer is the chip's ``ragged-dot`` Mosaic custom call, and the
+    optimized module has no ``[experts, tokens, ...]`` tensor."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu on this box
+        pytest.skip(f"cannot describe a v5e topology: {e}")
+    cfg = lf.LongcatFlashConfig(
+        vocab_size=256, hidden_size=256, expert_ffn_hidden_size=128,
+        n_routed_experts=8, zero_expert_num=4, moe_topk=3,
+        experts_held=(2, 6), num_layers=1)
+    tokens = 64
+    one = SingleDeviceSharding(topo.devices[0])
+    moe = jax.eval_shape(lambda k: lf._init_layer(k, cfg)["moe"],
+                         jax.random.PRNGKey(0))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), tree)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(lambda u, m, v: lf.moe_layer(u, m, cfg, v)).lower(
+            on_chip(jax.ShapeDtypeStruct((tokens, 256), jnp.bfloat16)),
+            on_chip(moe),
+            on_chip(jax.ShapeDtypeStruct((tokens,), bool))
+        ).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+    assert "ragged-dot" in text and "tpu_custom_call" in text
+    assert not _shapes_with_experts_first(text, cfg, tokens)
+    assert not _shapes_with_experts_first(text, cfg, tokens * cfg.moe_topk)
+    # what ``_fast_rows`` rests on: at the published sizes the compiler
+    # tiles the grouped matmul's rows by min(rows, 512) and computes
+    # whole tiles per group, so the 128-row buffer of 256 slots costs a
+    # quarter of a 512-row tile per expert and all 3072 picks would cost
+    # a whole one. If this changes, measure ``_fast_rows`` again.
+    import re
+    full = lf.LongcatFlashConfig(vocab_size=256, experts_held=(0, 16))
+    E, Fe, X = full.hidden_size, full.expert_ffn_hidden_size, full.num_held
+    assert lf._fast_rows(256, full.moe_topk) == 128
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        for rows, tile in ((128, 128), (256 * full.moe_topk, 512)):
+            text = jax.jit(jax.lax.ragged_dot).lower(
+                on_chip(jax.ShapeDtypeStruct((rows, E), jnp.bfloat16)),
+                on_chip(jax.ShapeDtypeStruct((X, E, 2 * Fe), jnp.bfloat16)),
+                on_chip(jax.ShapeDtypeStruct((X,), jnp.int32))
+            ).compile().as_text()
+            assert re.findall(r'ragged_dot_tiling="(\d+),', text) == [
+                str(tile)], (rows, tile)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+# ----------------------------------------- bfloat16 program, float32 reference
+
+TIE_EPS = 5e-3        # biased-score margin under which a pick may flip
+
+
+def test_bfloat16_routing_flips_only_at_ties_and_logits_agree():
+    """Every routing disagreement between the bfloat16 program and the
+    float32 reference sits where the reference's margin between its last
+    pick and its first loser is under ``TIE_EPS``; told to break exactly
+    those ties the program's way, the reference's logits agree with the
+    program's to a bfloat16 tolerance."""
+    cfg, params = _model(dtype="bfloat16")
+    weights = family.reference_from_serve(cfg, params)
+    ids = _ids(2, 40, seed=11)
+    seen = []
+    route = lf._route
+
+    def spy(u, moe, c):
+        picks, w = route(u, moe, c)
+        seen.append(np.asarray(picks))
+        return picks, w
+    lf._route = spy
+    try:
+        got = lf.causal_forward(params, cfg, jnp.asarray(ids))
+    finally:
+        lf._route = route
+    # the reference routed as the program routed; ``record`` holds what it
+    # would have picked itself, layer by layer, from the same (tied)
+    # hidden: where that differs, it must have been a tie. (Layer by
+    # layer matters: breaking a tie in one layer moves the next layer's
+    # hidden, and with it that layer's own ties.)
+    record = []
+    tied = ref.logits(weights, ids, route_as=seen, record=record)
+    flips = 0
+    for mine, theirs in zip(seen, record):
+        same = (np.sort(mine, -1) == np.sort(np.asarray(theirs["picks"]),
+                                             -1)).all(-1)
+        assert (np.asarray(theirs["margin"])[~same] < TIE_EPS).all()
+        flips += int((~same).sum())
+    scale = float(jnp.abs(tied).max())
+    assert float(jnp.abs(got - tied).max()) < 0.05 * scale
+    # free routing is the default (what ``logits_at`` uses), and the
+    # override is what moved the reference where picks differed
+    if flips:
+        assert float(jnp.abs(ref.logits(weights, ids) - tied).max()) > 0
+
+
+# ------------------------------------- the latent cache under the allocator
+
+def _server(**engine):
+    cfg, params = _model()
+    conf = dict(dtype="float32", max_out_tokens=BS * MB, block_size=BS,
+                num_slots=2, max_queued_requests=32)
+    conf.update(engine)
+    return cfg, params, InferenceEngine(
+        (cfg, params), DeepSpeedInferenceConfig(**conf))
+
+
+def test_slots_retire_and_blocks_are_reused_without_cross_talk(f32):
+    """Seven requests through two slots: blocks go back to the allocator
+    and out again, and every served token is the float32 reference's
+    choice for that request's own sequence (to 1e-4 of the top logit)."""
+    cfg, params, weights = f32
+    _, _, engine = _server()
+    server = ContinuousBatchingServer(engine)
+    rng = np.random.default_rng(2)
+    prompts = [rng.integers(1, cfg.vocab_size, size=n).tolist()
+               for n in (5, 17, 30, 9, 33, 16, 21)]
+    rids = [server.submit(p, max_new_tokens=7, eos_token_id=None)
+            for p in prompts]
+    server.drain()
+    assert (server.scheduler.allocator.free_blocks
+            == 2 * server.max_blocks_per_slot)
+    pool = server._cache
+    assert len(pool.rows) == 2 * cfg.num_layers
+    assert pool.rows[0].shape == (1 + 2 * server.max_blocks_per_slot,
+                                  cfg.latent_width, BS)
+    served = [server.result(rid) for rid in rids]
+    batch = np.zeros((len(served), max(map(len, served)) - 1), np.int32)
+    for i, full in enumerate(served):            # causal: padding is inert
+        batch[i, :len(full) - 1] = full[:-1]
+    logits = np.asarray(ref.logits(weights, batch))
+    for rid, p, full, lg in zip(rids, prompts, served, logits):
+        assert server.finish_reason(rid) == "length" and len(full) == len(p) + 7
+        for pos in range(len(p) - 1, len(full) - 1):
+            top = lg[pos].max()
+            assert top - lg[pos, full[pos + 1]] <= 1e-4 * max(1.0, abs(top))
+    stats = server.stats
+    assert stats["kv_tier"]["pool_bytes"] == sum(r.nbytes for r in pool.rows)
+    snap = server.telemetry.snapshot()
+    assert snap["serve_moe_tokens_routed_total"]["series"]
+    server.close()
+
+
+@pytest.mark.parametrize("switch,value", [
+    ("kv_cache_dtype", "int8"),
+    ("enable_prefix_caching", True),
+    ("prefill_chunk_tokens", BS),
+    ("speculation_tokens", 4),
+])
+def test_server_switches_the_latent_cache_cannot_honour_are_refused(
+        switch, value):
+    _, _, engine = _server(**{switch: value})
+    with pytest.raises(NotImplementedError, match=switch):
+        ContinuousBatchingServer(engine)
+
+
+def test_host_offload_is_refused_by_name():
+    _, _, engine = _server(kv_host_offload=True, enable_prefix_caching=True)
+    with pytest.raises(NotImplementedError, match="kv_host_offload"):
+        ContinuousBatchingServer(engine)
+
+
+@pytest.mark.parametrize("switch,conf", [
+    ("int8", dict(dtype="int8")),
+    ("tp_size", dict(tensor_parallel={"tp_size": 2})),
+])
+def test_engine_switches_are_refused_by_name(switch, conf):
+    cfg, params = _model()
+    with pytest.raises(NotImplementedError, match=switch):
+        InferenceEngine((cfg, params), DeepSpeedInferenceConfig(
+            **{"max_out_tokens": 64, **conf}))
+
+
+def test_generate_over_a_dense_cache_is_refused():
+    cfg, params = _model()
+    with pytest.raises(NotImplementedError, match="ContinuousBatching"):
+        transformer.decode_step(params, cfg, jnp.zeros((1,), jnp.int32), None)
+
+
+# ------------------------------------------------ the benchmark's new cell
+
+CELL = "serve-longcat-flash-ep32-decode-batch"
+
+
+def test_configuration_file_states_the_published_sizes_once():
+    """The top level holds the catalog's keys (the three reduced ones at
+    their reduced values); the ``model`` block is what runs and may
+    differ only where the share is stated another way."""
+    contract = harness.load_contract()
+    entry = harness.find(contract["configs"], "longcat-flash-ep32-serve",
+                         "config")
+    conf = harness.load_json(os.path.join(REPO, entry["file"]))
+    model = conf["model"]
+    for key, value in conf.items():
+        if key in model and key != "n_routed_experts":
+            assert model[key] == value, key
+    lo, hi = model["experts_held"]
+    assert hi - lo == conf["n_routed_experts"] == 16
+    assert model["n_routed_experts"] + model["zero_expert_num"] == 768
+    assert sorted(entry["reduced"]) == sorted(conf["reduced"])
+    cell = harness.resolve_cell(contract, CELL)
+    assert cell["config"]["engine"]["num_slots"] == 256
+    assert "serve_out_tokens_per_s" in cell["end_to_end"]
+    new = [m for m in contract["per_layer"] if m.get("workloads") == [CELL]]
+    assert len(new) == 12
+    for m in new + [m for m in contract["per_layer"]
+                    if CELL in m.get("workloads", ())]:
+        assert os.path.exists(os.path.join(BENCH, "metrics",
+                                           m["name"] + ".py"))
+    # the accepted readers of the layers this cell runs too
+    assert {"serve_goodput_pct", "trace_lower_s", "compile_cache_misses"} <= {
+        m["name"] for m in contract["per_layer"]
+        if CELL in m.get("workloads", ())}
+    traffic = harness.load_json(os.path.join(
+        BENCH, "traffic", "longcat-decode-batch.json"))
+    assert traffic["trace_seconds"] == 4
+
+
+def test_the_cell_runs_at_a_tiny_size_through_the_harness(tmp_path):
+    """The harness's own runner, the real readers and family, the tiny
+    configuration under a backlog of 24: correct, nothing failed, the
+    backlog never dry, and every counter-fed metric prints a number (the
+    device-trace ones need a chip and are left out without a trace)."""
+    root = tmp_path / "bench"
+    for d in ("metrics", "models"):
+        shutil.copytree(os.path.join(BENCH, d), root / d)
+    os.makedirs(root / "configs")
+    os.makedirs(root / "traffic")
+    shutil.copy(os.path.join(BENCH, "testdata", "configs",
+                             "tiny-longcat-serve.json"), root / "configs")
+    shutil.copy(os.path.join(BENCH, "testdata", "traffic",
+                             "tiny-longcat-decode-batch.json"),
+                root / "traffic")
+    contract = json.loads(json.dumps(harness.load_contract()))
+    contract["configs"] = [{"name": "tiny-longcat-serve",
+                            "file": "bench/configs/tiny-longcat-serve.json"}]
+    contract["workloads"] = [{"name": CELL, "config": "tiny-longcat-serve",
+                              "traffic": "tiny-longcat-decode-batch",
+                              "chips": 1}]
+    cell = harness.resolve_cell(contract, CELL, repo=str(tmp_path))
+    args = argparse.Namespace(seed=2 ** 31 + 5, seconds=1.0, trace=0)
+    run, _ = harness.run_cell(cell, args, time.time(), jax.devices()[:1],
+                              "TPU v5 lite")
+    assert all(run["checks"].values()), (run["checks"],
+                                         run["reference_check"])
+    assert run["failed"] == 0 and run["attempted"] > 0
+    metrics = harness.read_metrics(
+        cell["end_to_end"] + cell["per_layer"], run, None,
+        harness.units_of(contract), cell["root"])
+    for name in ("serve_out_tokens_per_s", "setup_s",
+                 "moe_tokens_per_held_expert", "moe_held_load_max_over_mean",
+                 "moe_identity_pick_pct", "longcat_peak_hbm_gb"):
+        assert name in metrics, name
+    assert 0 < metrics["moe_identity_pick_pct"]["value"] < 100
+    assert metrics["moe_held_load_max_over_mean"]["value"] >= 1.0
