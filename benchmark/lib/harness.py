@@ -92,7 +92,8 @@ def read_metrics(names: List[str], run: dict, trace, units: Dict[str, str],
                  root: str = ROOT) -> dict:
     out = {}
     for name in names:
-        value = load_reader(name, root)(run, trace)
+        with TAIL.timed("metrics_read", name):
+            value = load_reader(name, root)(run, trace)
         if value is not None:
             out[name] = {"value": float(value), "unit": units[name]}
     return out
@@ -111,14 +112,62 @@ class Marks:
         self.t_start = t_start
         self.at = []
 
-    def mark(self, label: str) -> None:
-        self.at.append([label, round(time.time() - self.t_start, 3)])
+    def mark(self, label: str, ago: float = 0.0) -> None:
+        """``ago``: seconds before now at which the point was passed."""
+        self.at.append([label, round(time.time() - self.t_start - ago, 3)])
+
+
+class TailMarks(Marks):
+    """Where a run's time goes once its window has closed (a traced run
+    spends more there than a cold set-up leaves it): seconds since the
+    process started at each named point, seconds spent inside named
+    pieces of work (``profiler_stopped`` is the time inside
+    ``jax.profiler.stop_trace()``; ``tables_parsed`` and
+    ``metrics_read`` hold one entry per program and per reader), and
+    what the trace held. Printed on an earlier line, ``tail_marks``, in
+    traced and untraced runs alike."""
+
+    def __init__(self):
+        self.begin(time.time())
+
+    def begin(self, t_start: float) -> None:
+        Marks.__init__(self, t_start)
+        self.seconds: Dict[str, object] = {}
+        self.counts: Dict[str, object] = {}
+
+    @contextlib.contextmanager
+    def timed(self, label: str, key: Optional[str] = None):
+        """Seconds inside the block, under ``label`` (summed) or under
+        ``label[key]``."""
+        t0 = time.perf_counter()
+        try:
+            yield
+        finally:
+            dt = round(time.perf_counter() - t0, 4)
+            if key is None:
+                self.seconds[label] = round(
+                    self.seconds.get(label, 0.0) + dt, 4)
+            else:
+                self.seconds.setdefault(label, {})[key] = dt
+
+    def line(self) -> dict:
+        return {"tail_marks": self.at, "tail_seconds": self.seconds,
+                **self.counts}
+
+
+TAIL = TailMarks()
 
 
 # ------------------------------------------------------------- the device
 
 class NoDevice(RuntimeError):
     pass
+
+
+class RunCeiling(RuntimeError):
+    """A loop of a runner passed a ceiling of its own
+    (``serve_cell.ServerStalled``, ``DrainCeiling``): the run failed,
+    with its reason, well inside the time a run is given."""
 
 
 def require_tpu(chips: int) -> dict:
@@ -199,8 +248,10 @@ class Tracer:
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         opts.host_tracer_level = 2
-        jax.profiler.start_trace(self.dir, profiler_options=opts)
+        with TAIL.timed("profiler_started"):
+            jax.profiler.start_trace(self.dir, profiler_options=opts)
         self.active = True
+        self._on_at = time.perf_counter()
         if window:
             self.open_window()
 
@@ -220,7 +271,11 @@ class Tracer:
         if self._win is not None:
             self.t1 = time.perf_counter()
             self._win.__exit__(None, None, None)
-        jax.profiler.stop_trace()
+        TAIL.seconds["profiled_s"] = round(
+            time.perf_counter() - self._on_at, 3)
+        with TAIL.timed("profiler_stopped"):
+            jax.profiler.stop_trace()
+        TAIL.mark("profiler_stopped")
         self.active = False
 
     def reduced(self):
@@ -228,7 +283,15 @@ class Tracer:
             return None
         from benchmark.lib import trace_reduce
         import shutil
-        red = trace_reduce.read(self.dir)
+        from benchmark.lib.program_spans import watched_programs
+        with TAIL.timed("trace_read"):
+            red = trace_reduce.read(self.dir, watched_programs())
+        TAIL.mark("trace_read")
+        TAIL.counts.update(traced_executions=red.traced_executions(),
+                           trace_events=red.events,
+                           trace_window_cut_s=red.cut_s)
+        if red.cut_s > 0:     # the host's records follow the cut
+            self.t1 = self.t0 + red.window_s
         shutil.rmtree(self.dir, ignore_errors=True)
         return red
 
@@ -259,11 +322,16 @@ def run_cell(cell: dict, args, t_start: float, devices, device_kind: str):
 
 def result_line(correct: bool, attempted: int, failed: int, metrics: dict,
                 device: dict, breakdown: Optional[dict] = None,
-                reason: Optional[str] = None) -> str:
+                reason: Optional[str] = None,
+                compared: Optional[dict] = None) -> str:
+    """``compared``: each number the run's ``correct`` was decided from
+    as ``name: [number, limit]``; it comes last on the line."""
     line = {"correct": bool(correct), "attempted": int(attempted),
             "failed": int(failed), "metrics": metrics, "device": device}
     if breakdown:
         line["breakdown"] = breakdown
     if reason:
         line["reason"] = reason
+    if compared:
+        line["compared"] = compared
     return json.dumps(line, default=float)

@@ -13,7 +13,6 @@ from statistics import median
 from typing import Dict, Optional, Sequence
 
 from benchmark.lib import program_spans as ps
-from benchmark.lib.trace_reduce import op_name
 
 DECODE = "serve_decode"
 KERNEL = "paged_latent_decode_attention"
@@ -26,40 +25,29 @@ DENSE = ("dense_ffn",)
 # ("ragged-dot-none"), not the scope they were traced under
 EXPERT_KERNELS = ("ragged-dot-none", "ragged-dot-metadata")
 
-_RUNS: dict = {}      # (id of the trace, program) -> ops by execution
-
-
-def _runs(trace, program: str):
-    """``ps.ops_by_execution`` once per trace and program (it sorts
-    every instruction of the window; a cell reads a dozen scopes)."""
-    key = (id(trace), program)
-    if key not in _RUNS:
-        _RUNS.clear()
-        _RUNS[key] = ps.ops_by_execution(trace, program)
-    return _RUNS[key]
-
 
 def scope_group_ms(trace, scopes: Sequence[str], kernels: Sequence[str] = (),
                    program: str = DECODE) -> Optional[float]:
     """Median over the executions of ``program`` on chip 0 of the self
     time of the instructions inside any of ``scopes`` (sibling scopes:
     no instruction is in two), plus that of the kernel calls NAMED in
-    ``kernels`` that carry no scope of their own."""
+    ``kernels`` that carry no scope of their own. Scopes that the
+    compile watch knows and no instruction carries read 0.0 (the work
+    is gone); scopes it does not know (another program) read None."""
     if trace is None:
         return None
-    table, named = ps.tables(program)
-    runs = _runs(trace, program)
-    if not runs or not any(s and set(scopes) & set(s.split("/"))
-                           for s in table.values()):
+    table, _ = ps.tables(program)
+    runs = ps.ops_by_execution(trace, program)
+    if not runs or not table:
         return None
-
-    def unscoped_kernels(ops):
-        return sum(own for text, _, _, own in ops
-                   if named.get(ps.instruction(text), op_name(text))
-                   in kernels and not table.get(ps.instruction(text)))
+    if not set(scopes) <= ps.known_scopes() and not any(
+            s and set(scopes) & set(s.split("/")) for s in table.values()):
+        return None
     return 1e3 * median(
-        sum(ps.in_scope(ops, table, s) for s in scopes)
-        + unscoped_kernels(ops) for ops in runs)
+        sum(ps.in_scope(ops, s) for s in scopes)
+        + sum(op.own for op in ops
+              if op.kernel in kernels and not op.scope)
+        for ops in runs)
 
 
 def experts_ms(trace) -> Optional[float]:

@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import bisect
 import collections
+import functools
 import re
 from statistics import median, quantiles
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
@@ -75,6 +76,15 @@ def tables(program: str) -> Tuple[dict, dict]:
         return scope_table(program), kernel_table(program)
     except Exception:  # noqa: BLE001
         return {}, {}
+
+
+def watched_programs() -> List[str]:
+    """Names of the programs the compile watch can make a table for."""
+    try:
+        from deepspeed_tpu.telemetry.compile_watch import watched_programs
+        return watched_programs()
+    except Exception:  # noqa: BLE001
+        return []
 
 
 def slow_steps() -> list:
@@ -165,6 +175,7 @@ def program_of(module: str) -> Optional[str]:
     return m.group(1) if m else None
 
 
+@functools.lru_cache(maxsize=1 << 16)
 def instruction(text: str) -> str:
     """``%fusion.12 = bf16[...] fusion(...)`` -> ``fusion.12``."""
     m = _INSTR.match(text)
@@ -208,58 +219,101 @@ def innermost_seconds(ops: Sequence[tuple]) -> List[float]:
     return own
 
 
+Op = collections.namedtuple(
+    "Op", "text start end own name scope kernel")
+Op.__doc__ = """One instruction of one execution, with everything a
+reader asks of it resolved once: ``own`` the seconds it was the
+innermost one running, ``name`` its instruction name (``fusion.12``),
+``scope`` its path in the program's scope table (``fwd_bwd/mlp`` or
+None), ``kernel`` its name in the kernel table, else its instruction
+name without the numbers."""
+
+
+def _grouped(trace, device: int) -> list:
+    """ONE pass over a chip's instructions: ``[(program, start, end,
+    [raw ops])]`` for every execution of every program inside the
+    window, oldest first; an instruction belongs to the execution that
+    holds it. Kept on the trace."""
+    memo = trace.__dict__.setdefault("_grouped", {})
+    if device not in memo:
+        dev = trace.devices[device]
+        mods = sorted((s, e, n) for n, s, e in dev.modules)
+        starts = [m[0] for m in mods]
+        held: List[list] = [[] for _ in mods]
+        for op in dev.ops:
+            i = bisect.bisect_right(starts, op[1]) - 1
+            if i >= 0 and op[2] <= mods[i][1] + 1e-9:
+                held[i].append(op)
+        memo[device] = [(program_of(n), s, e, ops)
+                        for (s, e, n), ops in zip(mods, held)]
+    return memo[device]
+
+
 def ops_by_execution(trace, program: str, device: int = 0
-                     ) -> List[List[tuple]]:
-    """For each execution of the program on one chip, the ``(text,
-    start, end, innermost seconds)`` of the instructions that ran inside
-    it."""
-    dev = trace.devices[device]
-    runs = executions(trace, program, device)
-    starts = [s for s, _ in runs]
-    out: List[List[tuple]] = [[] for _ in runs]
-    for op in dev.ops:
-        i = bisect.bisect_right(starts, op[1]) - 1
-        if i >= 0 and op[2] <= runs[i][1] + 1e-9:
-            out[i].append(op)
-    for k, ops in enumerate(out):
-        out[k] = [(t, s, e, own) for (t, s, e, _), own
-                  in zip(ops, innermost_seconds(ops))]
-    return out
+                     ) -> List[List[Op]]:
+    """For each execution of the program on one chip, the :class:`Op`
+    of every instruction that ran inside it. Built once per trace,
+    program and chip, and kept on the trace: every reader of a program
+    shares it."""
+    memo = trace.__dict__.setdefault("_ops", {})
+    key = (program, device)
+    if key not in memo:
+        table, named = tables(program)
+        out = []
+        for prog, _, _, ops in _grouped(trace, device):
+            if prog != program:
+                continue
+            row = []
+            for (text, s, e, _), own in zip(ops, innermost_seconds(ops)):
+                name = instruction(text)
+                row.append(Op(text, s, e, own, name, table.get(name),
+                              named.get(name) or op_name(text)))
+            out.append(row)
+        memo[key] = out
+    return memo[key]
 
 
-def scope_seconds(ops: Iterable[tuple], scope_table: dict) -> Dict[str, float]:
+def known_scopes() -> frozenset:
+    """The scopes the program's compile watch knows (a scope a program
+    could open), or none for a program without a watch."""
+    try:
+        from deepspeed_tpu.telemetry.compile_watch import SCOPES
+        return frozenset(SCOPES)
+    except Exception:  # noqa: BLE001
+        return frozenset()
+
+
+def scope_seconds(ops: Iterable[Op]) -> Dict[str, float]:
     """Self seconds of a set of instructions by innermost scope;
     instructions the table gives no scope are under ``UNKNOWN``."""
     acc: Dict[str, float] = collections.defaultdict(float)
-    for text, _, _, own in ops:
-        sc = scope_table.get(instruction(text))
-        acc[sc.rsplit("/", 1)[-1] if sc else UNKNOWN] += own
+    for op in ops:
+        acc[op.scope.rsplit("/", 1)[-1] if op.scope else UNKNOWN] += op.own
     return dict(acc)
 
 
-def in_scope(ops: Iterable[tuple], scope_table: dict, scope: str) -> float:
+def in_scope(ops: Iterable[Op], scope: str) -> float:
     """Self seconds of the instructions with ``scope`` anywhere on their
     path (``fwd_bwd/mlp`` is in ``fwd_bwd`` and in ``mlp``)."""
-    total = 0.0
-    for text, _, _, own in ops:
-        sc = scope_table.get(instruction(text))
-        if sc and scope in sc.split("/"):
-            total += own
-    return total
+    return sum(op.own for op in ops
+               if op.scope and scope in op.scope.split("/"))
 
 
 def decode_scopes(trace, program: str = "serve_decode") -> Optional[dict]:
     """Per execution of the decode program on chip 0: median ms in each
     innermost scope, and the share of its device time with no known
-    scope."""
+    scope. A scope the compile watch knows and that no instruction of
+    the program carries reads 0.0: a program that no longer does the
+    work a scope names spends no time in it. None stays for no trace,
+    no scope table, or a program that never ran in the window."""
     if trace is None:
         return None
     table, _ = tables(program)
     runs = ops_by_execution(trace, program)
     if not table or not runs:
         return None
-    per = [scope_seconds(ops, table) for ops in runs]
-    names = sorted({k for p in per for k in p})
+    per = [scope_seconds(ops) for ops in runs]
+    names = sorted({k for p in per for k in p} | known_scopes())
     busy = sum(sum(p.values()) for p in per)
     return {"executions": len(runs),
             "ms_by_scope": {k: 1e3 * median(p.get(k, 0.0) for p in per)
@@ -268,52 +322,48 @@ def decode_scopes(trace, program: str = "serve_decode") -> Optional[dict]:
                 p.get(UNKNOWN, 0.0) for p in per) / busy if busy else None}
 
 
-def _is_kernel(program: str, kernel: str):
-    """Whether an instruction's text is a call of the kernel NAMED
-    ``kernel``: by the program's kernel table, else by the
-    instruction's own name."""
-    _, kernels = tables(program)
-    return lambda text: kernels.get(instruction(text),
-                                    op_name(text)) == kernel
-
-
 def kernel_calls(trace, program: str, kernel: str
                  ) -> List[Tuple[float, float]]:
-    """Start and end of every call of the kernel NAMED ``kernel``
+    """Start and end of every call of the kernel NAMED ``kernel`` (by
+    the program's kernel table, else by the instruction's own name)
     inside the executions of the program on chip 0."""
-    is_it = _is_kernel(program, kernel)
-    return [(s, e) for ops in ops_by_execution(trace, program)
-            for t, s, e, _ in ops if is_it(t)]
+    return [(op.start, op.end) for ops in ops_by_execution(trace, program)
+            for op in ops if op.kernel == kernel]
 
 
 def kernel_ms(trace, program: str, kernel: str) -> Optional[float]:
     """Median over executions of the program on chip 0 of the device
-    time of the kernel calls NAMED ``kernel``."""
+    time of the kernel calls NAMED ``kernel``. A kernel that is not
+    there reads None, never 0: a missing kernel is a loss, not a gain."""
     if trace is None:
         return None
     runs = ops_by_execution(trace, program)
     if not runs:
         return None
-    is_it = _is_kernel(program, kernel)
-    per = [sum(e - s for t, s, e, _ in ops if is_it(t)) for ops in runs]
+    per = [sum(op.end - op.start for op in ops if op.kernel == kernel)
+           for ops in runs]
     return 1e3 * median(per) if any(per) else None
 
 
 def train_scope_ms(run: dict, trace, scope: str,
                    program: str = "train_step") -> Optional[float]:
     """Self time of the instructions in ``scope`` per traced step, on
-    the chip where it is largest."""
+    the chip where it is largest; 0.0 for a scope the compile watch
+    knows and no instruction of the executed program carries."""
     if trace is None or run.get("kind") != "train":
         return None
     table, _ = tables(program)
     if not table or not run.get("trace_steps"):
         return None
-    worst = 0.0
+    worst, ran = 0.0, False
     for k in range(len(trace.devices)):
-        ops = [op for grp in ops_by_execution(trace, program, k)
-               for op in grp]
-        worst = max(worst, in_scope(ops, table, scope))
-    return 1e3 * worst / run["trace_steps"] if worst > 0 else None
+        runs = ops_by_execution(trace, program, k)
+        ran = ran or bool(runs)
+        worst = max(worst, in_scope((op for grp in runs for op in grp),
+                                    scope))
+    if worst <= 0 and not (ran and scope in known_scopes()):
+        return None
+    return 1e3 * worst / run["trace_steps"]
 
 
 def dispatch_gaps(run: dict, trace, program: str = "serve_decode"

@@ -20,8 +20,35 @@ from typing import Dict, List
 import numpy as np
 
 from benchmark.lib import harness, traffic as traffic_lib
+from benchmark.lib.trace_reduce import MAX_EXECUTIONS
 
 OK_REASONS = ("length", "eos")
+# ceilings a run keeps by itself, so that a server that never finishes
+# a request is a failed run with a reason and not the driver's time-out
+# (360 s for a whole run). STALL_S: seconds without a new token or a
+# finished request while the server is busy (a step that compiles a
+# 24-layer program takes up to a minute, in set-up only). DRAIN_S:
+# seconds an open loop may stay busy after its last request was due
+# (today about one).
+STALL_S = 150.0
+DRAIN_S = 90.0
+# the profiler is stopped after ``MAX_EXECUTIONS`` steps of the traced
+# window even where ``trace_seconds`` have not passed (a step runs the decode
+# program at most once): stopping it costs ~40 us for every instruction
+# it saw (PERF.md, section 3), so what it sees may not grow with the
+# server's speed. An open loop's traced schedule starts it this long
+# before its window opens (starting takes 0.04 s, in which no step
+# runs), and no earlier: nothing reads the lead-in.
+PROFILE_LEAD_S = 0.25
+
+
+class ServerStalled(harness.RunCeiling):
+    """The server was busy and nothing moved for ``STALL_S`` seconds."""
+
+
+class DrainCeiling(harness.RunCeiling):
+    """An open loop was still busy ``DRAIN_S`` seconds after its last
+    request was due."""
 
 
 class Tracked:
@@ -54,6 +81,7 @@ class Session:
         self.clock = time.perf_counter
         self.reqs: Dict[int, Tracked] = {}
         self.steps: List[tuple] = []   # (t_start, t_end, live, live_tokens)
+        self.moved_at = self.clock()   # the last new token or finish
         self.admissions: List[tuple] = []   # (t_step_start, prompt_len)
         # when a run reads far off, these say why: steps that took over
         # SLOW_STEP_S (with the CPU seconds this process used in them: a
@@ -117,7 +145,7 @@ class Session:
                 r = self.reqs[rid]
                 r.tokens = self.server.result(rid)[len(r.prompt):]
                 self._seen(r, len(r.tokens), t, t_start)
-                r.done = t
+                r.done = self.moved_at = t
                 r.reason = self.server.finish_reason(rid)
                 self.server.forget(rid)
             self.steps.append((t_start, t, live, live_tokens))
@@ -129,13 +157,26 @@ class Session:
         new = n - len(r.token_times)
         if new > 0:
             r.token_times.extend([t] * new)
+            self.moved_at = t
 
-    def drain(self, limit_s: float = 600.0) -> None:
-        end = self.clock() + limit_s
+    def alive(self, stall_s: float = STALL_S) -> None:
+        """Raises :class:`ServerStalled` where the server has been busy
+        for ``stall_s`` seconds with no new token and no finished
+        request (called between steps by every loop here)."""
+        idle = self.clock() - self.moved_at
+        if idle > stall_s:
+            sched = self.server.scheduler
+            raise ServerStalled(
+                f"the server is busy and nothing has moved for {idle:.0f} s "
+                f"(limit {stall_s:.0f}): {len(self.steps)} steps taken, "
+                f"{len(sched.slots)} slots resident, "
+                f"{sched.pending_requests} queued")
+
+    def drain(self, stall_s: float = STALL_S) -> None:
+        self.moved_at = self.clock()
         while self.busy:
             self.step()
-            if self.clock() > end:
-                raise RuntimeError("the server did not drain")
+            self.alive(stall_s)
 
 
 def build(config: dict, seed: int, family):
@@ -239,7 +280,8 @@ def itl_gaps_ms(reqs: List[Tracked]) -> List[float]:
 
 
 def run_backlog(sess: Session, reqs: List[Tracked], ring, seconds: float,
-                tracer, trace_seconds: float) -> dict:
+                tracer, trace_seconds: float,
+                stall_s: float = STALL_S) -> dict:
     """``reqs`` (lap 0 of the backlog's ring) is queued at time zero
     (set-up); the window opens at the first step boundary at which every
     slot is resident and no prefill is pending, and closes at the first
@@ -280,8 +322,10 @@ def run_backlog(sess: Session, reqs: List[Tracked], ring, seconds: float,
             top["seconds"] += sess.clock() - t
         return sess.steps[-1][2] < slots and not queued
 
+    sess.moved_at = sess.clock()
     while len(sched.slots) < slots and sess.busy:
         step()
+        sess.alive(stall_s)     # slots that never fill: ServerStalled
     step()                             # one step with every slot decoding
     gc.collect()
     gc.freeze()
@@ -293,7 +337,8 @@ def run_backlog(sess: Session, reqs: List[Tracked], ring, seconds: float,
     while True:
         dry = step() or dry
         now = sess.clock()
-        if tracer.active and now - t0 >= trace_seconds:
+        if tracer.active and (now - t0 >= trace_seconds or len(sess.steps)
+                              - first_step >= MAX_EXECUTIONS):
             tracer.stop()
         if now - t0 >= seconds:
             break
@@ -303,36 +348,64 @@ def run_backlog(sess: Session, reqs: List[Tracked], ring, seconds: float,
 
 def run_open_loop(sess: Session, reqs: List[Tracked], seconds: float,
                   lead_in: float, tracer, trace_seconds: float,
-                  stop_after=None) -> dict:
+                  stop_after=None, stall_s: float = STALL_S,
+                  drain_s: float = DRAIN_S,
+                  leave_when_traced: bool = False) -> dict:
     """Requests are submitted when they are due (never earlier; how much
     later is the generator's lateness). The schedule starts ``lead_in``
     seconds before the window opens. After the window closes the
-    requests that were due in it are drained and still count. A tracer
-    whose profiler is already running has its window opened when this
-    schedule's window opens, and is stopped ``trace_seconds`` later."""
+    requests that were due in it are drained and still count. An
+    enabled tracer has its profiler started ``PROFILE_LEAD_S`` before
+    this schedule's window opens (starting it takes tenths of a second,
+    in which no step runs: not at the window's edge), its window opened
+    with this schedule's, and is stopped ``trace_seconds`` or
+    ``MAX_EXECUTIONS`` steps later, whichever comes first; with
+    ``leave_when_traced`` the loop then ends, with whatever is still in
+    flight (nothing reads it).
+    A server that stays busy with nothing moving for ``stall_s``
+    seconds, or stays busy ``drain_s`` seconds after the last request
+    was due, ends the run with :class:`ServerStalled` /
+    :class:`DrainCeiling`."""
     gc.collect()
     gc.freeze()
-    begin = sess.clock()
+    begin = sess.moved_at = sess.clock()
     t0 = begin + lead_in
     for r in reqs:
         r.due += t0
+    last_due = max([t0 + seconds] + [r.due for r in reqs])
     first_step = None
     i, n = 0, len(reqs)
     while True:
         now = sess.clock()
+        if tracer.enabled and not tracer.active and tracer.t1 is None \
+                and now >= t0 - PROFILE_LEAD_S:
+            tracer.start(window=False)
+            now = sess.clock()
         if first_step is None and now >= t0:
             first_step = len(sess.steps)
             tracer.open_window()
-        if tracer.active and now - t0 >= trace_seconds:
+        if tracer.active and (now - t0 >= trace_seconds or (
+                first_step is not None
+                and len(sess.steps) - first_step >= MAX_EXECUTIONS)):
             tracer.stop()
+            if leave_when_traced:
+                break
         while i < n and reqs[i].due <= now:
             sess.submit(reqs[i])
             i += 1
         if sess.busy:
             sess.step()
+            sess.alive(stall_s)
+            if now - last_due > drain_s:
+                raise DrainCeiling(
+                    f"the server is still busy {now - last_due:.0f} s after "
+                    f"the last request was due (limit {drain_s:.0f}): "
+                    f"{sum(1 for r in reqs if r.done is None)} of {n} "
+                    "requests unfinished")
         elif i >= n:
             break
         else:
+            sess.moved_at = now        # idle, not stalled
             with harness.span("bench:wait"):
                 time.sleep(min(max(reqs[i].due - sess.clock(), 0.0), 0.002))
         if stop_after is not None and now - t0 >= stop_after:
@@ -373,6 +446,9 @@ def run(cell: dict, args, t_start: float, family, devices) -> dict:
             lead_in = float(traffic.get("lead_in_s", 0.0))
             win = run_open_loop(sess, reqs, args.seconds, lead_in,
                                 harness.Tracer(False, ""), 0.0)
+        tail = harness.TAIL
+        tail.mark("window_closed", ago=sess.clock() - win["t1"])
+        tail.mark("drained")
         # set-up ends where the measured window opens: the lead-in and
         # the filling of the slots are set-up that the traffic needs
         setup_s = (time.time() - t_start) - (sess.clock() - win["t0"])
@@ -383,21 +459,22 @@ def run(cell: dict, args, t_start: float, family, devices) -> dict:
         mem = memory_peak_bytes(devices)
         if tracer.enabled and traffic["kind"] == "open_loop":
             # the trace is taken AFTER the measured window has drained:
-            # starting and stopping the profiler stall the loop for
-            # seconds, which inside the window would be read as queue
-            # wait. A second, short schedule of the same mix (lead-in,
-            # then ``trace_seconds``) runs under the profiler; its
-            # requests are served and not counted.
-            tail = make_tracked(traffic_lib.build_requests(
+            # stopping the profiler stalls the loop for seconds, which
+            # inside the window would be read as queue wait. A second,
+            # short schedule of the same mix (lead-in, then
+            # ``trace_seconds``) is traced; its requests are served and
+            # not counted, and it ends where its trace does.
+            extra = make_tracked(traffic_lib.build_requests(
                 traffic, trace_seconds, args.seed + 1,
                 config["model"]["vocab_size"])["requests"],
                 base=2 * 10 ** 9, counted=False)
-            tracer.start(window=False)
-            run_open_loop(sess, tail, trace_seconds, lead_in, tracer,
-                          trace_seconds)
+            run_open_loop(sess, extra, trace_seconds, lead_in, tracer,
+                          trace_seconds, leave_when_traced=True)
+            tail.mark("trace_schedule_done")
     finally:
         tracer.stop()
         sess.close()
+        harness.TAIL.mark("server_closed")
     t0, t1 = win["t0"], win["t1"]
     harness.log({"slow_steps": [dict(s, at=s["at"] - t0)
                                 for s in sess.slow_steps],
@@ -459,5 +536,11 @@ def run(cell: dict, args, t_start: float, family, devices) -> dict:
         "jax_compile_s": compiles.seconds,
         "compiles_in_window": compiles_in_window,
         "memory_peak_bytes": mem, "checks": checks,
+        # each number compared, beside its limit
+        "compared": {
+            "max_gap": [check.get("max_gap"), check.get("tolerance")],
+            "compiles_in_window": [compiles_in_window, 0],
+            "failed_requests": [len(failed), 0],
+            "steps_run_dry": [int(win["ran_dry"]), 0]},
         "attempted": len(touched), "failed": len(failed), "tracer": tracer,
     }

@@ -22,7 +22,9 @@ against a recorded trace without a chip (``benchmark/testdata``).
 from __future__ import annotations
 
 import collections
+import functools
 import glob
+import heapq
 import os
 import re
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
@@ -41,8 +43,18 @@ COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
                "collective-permute", "collective-broadcast",
                "async-collective")
 _NAME = re.compile(r"^%?([^\s=]+)")
+# executions of one named program inside ``bench:window`` that the
+# readers walk; the window is cut where the first program passes it, so
+# what a traced run costs after its window does not grow with the
+# server's speed. Today's windows hold 64-139 (PERF.md, section 3).
+MAX_EXECUTIONS = 160
 
 
+# an instruction's text comes back once per execution: parsed once
+_once = functools.lru_cache(maxsize=1 << 16)
+
+
+@_once
 def op_name(text: str) -> str:
     """``%fusion.4266.remat = ...`` -> ``fusion.remat``: the
     instruction's name without the numbers XLA appends."""
@@ -51,6 +63,7 @@ def op_name(text: str) -> str:
     return ".".join(p for p in name.split(".") if not p.isdigit()) or name
 
 
+@_once
 def op_kind(text: str) -> str:
     """The HLO opcode of an instruction's text (``copy-done``,
     ``fusion``, ``custom-call``...): the word before the first ``(``
@@ -73,6 +86,7 @@ def op_kind(text: str) -> str:
     return op_name(text).split(".")[0]
 
 
+@_once
 def is_kernel(text: str) -> bool:
     """A Pallas (Mosaic) kernel call."""
     return op_kind(text) == "custom-call" and (
@@ -179,13 +193,35 @@ def _share(s: float, e: float, lo: float, hi: float) -> float:
     return (min(e, hi) - max(s, lo)) / (e - s) if e > s else 0.0
 
 
+def capped(modules: Sequence[Tuple[str, float, float]], window: Interval,
+           programs: Sequence[str], limit: int = MAX_EXECUTIONS) -> Interval:
+    """``window``, or the part of it that ends with execution number
+    ``limit`` of the first of ``programs`` (``serve_decode`` for the
+    module ``jit_serve_decode(<id>)``) to run that often wholly inside
+    it on this chip."""
+    lo, hi = window
+    names = {"jit_" + p for p in programs}
+    seen: Dict[str, int] = collections.Counter()
+    for n, s, e in sorted(modules, key=lambda m: m[1]):
+        n = n.split("(")[0]
+        if n in names and s >= lo and e <= hi:
+            seen[n] += 1
+            if seen[n] == limit:
+                # the next one is wholly inside no longer
+                return lo, min(hi, e + 1e-9)
+    return lo, hi
+
+
 class Reduced:
     """A trace cut to its window: the devices, the host's spans, and the
-    reductions over them."""
+    reductions over them. ``cap_programs`` names the programs whose
+    executions in the window are held to ``MAX_EXECUTIONS``
+    (:func:`capped`); ``cut_s`` is what that took off the window."""
 
     def __init__(self, device_events: Dict[int, dict],
                  host_spans: List[Tuple[str, float, float]],
-                 window: Optional[Interval] = None):
+                 window: Optional[Interval] = None,
+                 cap_programs: Sequence[str] = ()):
         spans = [(n, s, e) for n, s, e in host_spans if n != WINDOW_SPAN]
         win = [(s, e) for n, s, e in host_spans if n == WINDOW_SPAN]
         if window is None and win:
@@ -194,9 +230,19 @@ class Reduced:
             pts = [(s, e) for d in device_events.values()
                    for _, s, e in d["ops"]]
             window = (min(s for s, _ in pts), max(e for _, e in pts))
-        self.lo, self.hi = window
+        first = device_events[min(device_events)]["modules"]
+        self.lo, self.hi = capped(first, window, cap_programs)
+        self.cut_s = window[1] - self.hi
         self.window_s = self.hi - self.lo
         self.host_spans = spans
+        # what the file held, before the cut to the window
+        self.events = {
+            "device_ops": sum(len(d["ops"]) for d in device_events.values()),
+            "modules": sum(len(d["modules"])
+                           for d in device_events.values()),
+            "host_spans": len(host_spans)}
+        self.profiled = collections.Counter(
+            n.split("(")[0] for n, _, _ in first)
         self.devices = [Device(k, d["ops"], d["modules"], self.lo, self.hi)
                         for k, d in sorted(device_events.items())]
 
@@ -226,20 +272,38 @@ class Reduced:
     # ---- idle gaps by what the host was doing
     def idle_gaps(self, top: int = 10) -> List[List]:
         """Idle time of chip 0 by the innermost host span (the
-        benchmark's or the program's) that covers each gap's middle."""
+        benchmark's or the program's) that covers each gap's middle:
+        the shortest span open there. One sweep over the gaps and the
+        spans in time order (a gap per instruction boundary times a
+        span per step would be quadratic in the server's speed)."""
         acc: Dict[str, float] = collections.defaultdict(float)
-        dev = self.devices[0]
-        for s, e in gaps(dev.busy, self.lo, self.hi):
+        spans = sorted((ss, se, n) for n, ss, se in self.host_spans)
+        open_: List[Tuple[float, float, str]] = []     # (end, length, name)
+        k = 0
+        for s, e in gaps(self.devices[0].busy, self.lo, self.hi):
             mid = (s + e) / 2
-            cover = [(se - ss, n) for n, ss, se in self.host_spans
-                     if ss <= mid <= se]
-            acc[min(cover)[1] if cover else "_no_host_span_"] += e - s
+            while k < len(spans) and spans[k][0] <= mid:
+                ss, se, n = spans[k]
+                heapq.heappush(open_, (se, se - ss, n))
+                k += 1
+            while open_ and open_[0][0] < mid:
+                heapq.heappop(open_)
+            acc[min((d, n) for _, d, n in open_)[1] if open_
+                else "_no_host_span_"] += e - s
         return [[k, v] for k, v in sorted(acc.items(),
                                           key=lambda kv: -kv[1])[:top]]
 
     def breakdown(self) -> dict:
         return {"device_ops": self.device_ops(),
                 "idle_gaps": self.idle_gaps()}
+
+    def traced_executions(self) -> dict:
+        """Per program on chip 0: executions under the profiler, and of
+        those the ones wholly inside ``bench:window``."""
+        inside = collections.Counter(
+            n.split("(")[0] for n, _, _ in self.devices[0].modules)
+        return {n: [k, inside.get(n, 0)]
+                for n, k in self.profiled.most_common()}
 
     def summary(self, top: int = 12) -> dict:
         """For an earlier line of a traced run: the programs that ran
@@ -273,8 +337,12 @@ def span_name(ev) -> str:
     return ev.name
 
 
-def read(path: str) -> Reduced:
-    """The newest ``*.xplane.pb`` under ``path`` (or the file itself)."""
+def read(path: str, cap_programs: Sequence[str] = ()) -> Reduced:
+    """The newest ``*.xplane.pb`` under ``path`` (or the file itself).
+    The host's plane is read first: it has ``bench:window``, and an
+    instruction that ran wholly outside it (a lead-in under the
+    profiler, what ``cap_programs`` cuts off) is counted and never made
+    into a tuple."""
     from jax.profiler import ProfileData
     if not os.path.isfile(path):
         found = sorted(glob.glob(os.path.join(path, "**", "*.xplane.pb"),
@@ -282,31 +350,48 @@ def read(path: str) -> Reduced:
         if not found:
             raise FileNotFoundError(f"no *.xplane.pb under {path}")
         path = found[-1]
-    data = ProfileData.from_file(path)
-    devices: Dict[int, dict] = {}
+    planes = list(ProfileData.from_file(path).planes)
     spans: List[Tuple[str, float, float]] = []
-    for plane in data.planes:
+    host_events = 0
+    for plane in planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                host_events += 1
+                if ev.name.startswith(SPAN_PREFIXES):
+                    s = ev.start_ns * 1e-9
+                    spans.append((span_name(ev), s,
+                                  s + ev.duration_ns * 1e-9))
+    window = next(((s, e) for n, s, e in spans if n == WINDOW_SPAN), None)
+    devices: Dict[int, dict] = {}
+    op_lines = []
+    for plane in planes:
         m = DEVICE_PLANE.match(plane.name)
-        if m:
-            dev = devices.setdefault(int(m.group(1)),
-                                     {"ops": [], "modules": []})
-            for line in plane.lines:
-                if line.name == "XLA Ops":
-                    dst = dev["ops"]
-                elif line.name == "XLA Modules":
-                    dst = dev["modules"]
-                else:
-                    continue
+        if not m:
+            continue
+        dev = devices.setdefault(int(m.group(1)), {"ops": [], "modules": []})
+        for line in plane.lines:
+            if line.name == "XLA Modules":
                 for ev in line.events:
                     s = ev.start_ns * 1e-9
-                    dst.append((ev.name, s, s + ev.duration_ns * 1e-9))
-        elif plane.name == "/host:CPU":
-            for line in plane.lines:
-                for ev in line.events:
-                    if ev.name.startswith(SPAN_PREFIXES):
-                        s = ev.start_ns * 1e-9
-                        spans.append((span_name(ev), s,
-                                      s + ev.duration_ns * 1e-9))
+                    dev["modules"].append((ev.name, s,
+                                           s + ev.duration_ns * 1e-9))
+            elif line.name == "XLA Ops":
+                op_lines.append((dev["ops"], line))
     if not devices:
         raise ValueError(f"{path} holds no /device:TPU plane")
-    return Reduced(devices, spans)
+    lo, hi = (capped(devices[min(devices)]["modules"], window, cap_programs)
+              if window else (float("-inf"), float("inf")))
+    device_ops = 0
+    for dst, line in op_lines:
+        for ev in line.events:
+            device_ops += 1
+            s = ev.start_ns * 1e-9
+            e = s + ev.duration_ns * 1e-9
+            if e > lo and s < hi:
+                dst.append((ev.name, s, e))
+    red = Reduced(devices, spans, cap_programs=cap_programs)
+    red.events.update(device_ops=device_ops, host_events=host_events,
+                      file_bytes=os.path.getsize(path))
+    return red

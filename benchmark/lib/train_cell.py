@@ -102,12 +102,14 @@ def run(cell: dict, args, t_start: float, family, devices) -> dict:
             if t1 - t0 >= args.seconds:
                 break
         window_s = t1 - t0
+        harness.TAIL.mark("window_closed")
         compiles_in_window = compiles.count - compiled_before
         from benchmark.lib.peaks import memory_peak_bytes
         mem = memory_peak_bytes(devices)
     finally:
         tracer.stop()
         engine.destroy()
+        harness.TAIL.mark("engine_destroyed")
     marks.at.append(["window_opens", round(setup_s, 3)])
     harness.log({"setup_marks": marks.at})
     harness.log({"step_seconds": step_s, "losses": losses,
@@ -136,6 +138,12 @@ def run(cell: dict, args, t_start: float, family, devices) -> dict:
         "jax_compile_s": compiles.seconds,
         "compiles_in_window": compiles_in_window,
         "memory_peak_bytes": mem, "checks": checks,
+        # each number compared, beside its limit (the last loss has to
+        # be under the first: its limit is exclusive)
+        "compared": {
+            "first_loss_error": [abs(warm[0] - built["ref_loss"]), tol],
+            "last_loss_less_first": [losses[-1] - warm[0], 0.0],
+            "compiles_in_window": [compiles_in_window, 0]},
         "attempted": len(step_s), "failed": 0,
         "trace_steps": trace_steps, "tracer": tracer,
     }

@@ -6,6 +6,7 @@ run anywhere but on a TPU.
     python -m pytest benchmark/tests -q -p no:cacheprovider
 """
 import argparse
+import glob
 import json
 import os
 import re
@@ -32,10 +33,27 @@ from benchmark.lib import (flops, harness, peaks, trace_reduce as tr,  # noqa: E
 CONTRACT = harness.load_contract()
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 CELLS = [w["name"] for w in CONTRACT["workloads"]]
-TWINS = {"train-gpt2-1.3b-offload": ("tiny-train", "tiny-offload", 1),
-         "train-gpt2-1.3b-zero3-x4": ("tiny-train", "tiny-zero3-x4", 4),
-         "serve-gpt2-1.3b-batch": ("tiny-serve", "tiny-batch", 1),
-         "serve-gpt2-1.3b-chat-p80": ("tiny-serve", "tiny-chat", 1)}
+
+
+def _twins_of(kind):
+    """``testdata/<kind>/*.json`` by the name each says it is the tiny
+    twin of (its ``twin_of`` key)."""
+    out = {}
+    for path in sorted(glob.glob(os.path.join(BENCH, "testdata", kind,
+                                              "*.json"))):
+        twin_of = harness.load_json(path).get("twin_of")
+        if twin_of:
+            out[twin_of] = os.path.basename(path)[:-len(".json")]
+    return out
+
+
+# every cell of BENCHMARK.json is rehearsed through the tiny twins of its
+# configuration and its traffic: a new cell brings testdata files that
+# name what they are twins of, and edits nothing here
+TWIN_CONFIGS, TWIN_TRAFFIC = _twins_of("configs"), _twins_of("traffic")
+TWINS = {w["name"]: (TWIN_CONFIGS.get(w["config"]),
+                     TWIN_TRAFFIC.get(w["traffic"]), w["chips"])
+         for w in CONTRACT["workloads"]}
 
 
 # ------------------------------------------------------------ the contract
@@ -299,7 +317,7 @@ def _tmp_benchmark(tmp_path, extra_metric=None):
     contract = json.loads(json.dumps(CONTRACT))
     contract["configs"] = [
         {"name": n, "file": f"bench/configs/{n}.json"}
-        for n in ("tiny-train", "tiny-serve")]
+        for n in sorted(set(TWIN_CONFIGS.values()))]
     contract["workloads"] = [
         {"name": w, "config": c, "traffic": t, "chips": k}
         for w, (c, t, k) in TWINS.items()]
@@ -321,6 +339,10 @@ def _run_cell(contract, repo, workload, seconds=1.0, seed=3):
 
 @pytest.mark.parametrize("workload", CELLS)
 def test_each_cells_control_flow_at_a_tiny_size(tmp_path, workload):
+    assert all(TWINS[workload]), (
+        f"{workload} has no tiny twin: add testdata/configs and "
+        "testdata/traffic files whose twin_of names its configuration "
+        "and its traffic")
     _, contract = _tmp_benchmark(tmp_path)
     cell, run, metrics = _run_cell(contract, tmp_path, workload)
     assert all(run["checks"].values()), run["checks"]
@@ -347,6 +369,48 @@ def test_each_cells_control_flow_at_a_tiny_size(tmp_path, workload):
             # tokens inside the window, whether or not the request ended
             assert 0 < run["window_tokens"] < got
             assert run["steps"][run["first_step"] - 1][2] == run["num_slots"]
+
+
+@pytest.mark.parametrize("workload", ["serve-gpt2-1.3b-batch",
+                                      "train-gpt2-1.3b-offload"])
+def test_a_broken_timed_path_comes_out_not_correct(tmp_path, workload,
+                                                   monkeypatch):
+    """The rest of a run with the timed path broken underneath: a served
+    token altered where the server hands it out; a step whose loss is
+    not the batch's."""
+    _, contract = _tmp_benchmark(tmp_path)
+    if workload.startswith("serve"):
+        from deepspeed_tpu.inference import ContinuousBatchingServer
+        result = ContinuousBatchingServer.result
+
+        def altered(self, rid):
+            tokens = list(result(self, rid))
+            tokens[-1] = (tokens[-1] + 1) % 64
+            return tokens
+        monkeypatch.setattr(ContinuousBatchingServer, "result", altered)
+        broken = "matches_reference"
+    else:
+        import deepspeed_tpu
+        initialize = deepspeed_tpu.initialize
+
+        def wrapped(*a, **k):
+            got = initialize(*a, **k)
+            step = got[0].train_batch
+            got[0].train_batch = lambda batch: dict(
+                step(batch), loss=step(batch)["loss"] + 0.05)
+            return got
+        monkeypatch.setattr(deepspeed_tpu, "initialize", wrapped)
+        broken = "first_loss_matches_reference"
+    cell, run, _ = _run_cell(contract, tmp_path, workload)
+    assert run["checks"][broken] is False
+    assert not all(run["checks"].values())          # ``correct``: false
+    number, limit = run["compared"][
+        "max_gap" if workload.startswith("serve") else "first_loss_error"]
+    assert number > limit
+    line = json.loads(harness.result_line(
+        all(run["checks"].values()), run["attempted"], run["failed"], {},
+        {}, compared=run["compared"]))
+    assert line["correct"] is False and list(line)[-1] == "compared"
 
 
 def test_a_new_cell_is_files_and_entries_only(tmp_path):

@@ -52,13 +52,16 @@ def log():
 def test_the_contract_names_each_new_reader_and_its_file():
     contract = harness.load_contract()
     by = {m["name"]: m for m in contract["per_layer"]}
-    assert [m["name"] for m in contract["per_layer"][-11:]] == list(NEW)
-    layers = {m["layer"] for m in contract["per_layer"][:-11]}
+    assert set(NEW) <= set(by)
+    # over whatever the contract lists now, not over its last entries
+    layers = {m["layer"] for m in contract["per_layer"]
+              if m["name"] not in NEW}
     cells = {w["name"] for w in contract["workloads"]}
-    for name in NEW:
+    for name in by:
         assert os.path.isfile(os.path.join(BENCH, "metrics", name + ".py"))
-        assert by[name]["layer"] in layers      # a layer PERF.md has
-        assert set(by[name]["workloads"]) <= cells
+        if name in NEW:
+            assert by[name]["layer"] in layers      # a layer PERF.md has
+        assert set(by[name].get("workloads", ())) <= cells
         assert by[name]["moves"] in {m["name"]
                                      for m in contract["end_to_end"]}
 
