@@ -149,8 +149,11 @@ def kernels_phase(size: Size, seed: int) -> dict:
     NB = S * MB + 1
     K, C = 4, min(size.chunk_tokens, size.seq // 2)
     ks = iter(jax.random.split(jax.random.PRNGKey(seed), 8))
-    kp = jax.random.normal(next(ks), (NB, BS, H, D), jnp.float32)
-    vp = jax.random.normal(next(ks), (NB, BS, H, D), jnp.float32)
+    # a two-layer pool whose layers hold different data; the kernels
+    # attend the SECOND (a wrong block offset reads the first)
+    LAYERS, LAYER = 2, 1
+    kp = jax.random.normal(next(ks), (LAYERS, NB, BS, H, D), jnp.float32)
+    vp = jax.random.normal(next(ks), (LAYERS, NB, BS, H, D), jnp.float32)
     # every slot owns MB distinct blocks, in a shuffled order; lengths
     # span idle, sub-block, mid-block and full
     perm = jax.random.permutation(next(ks), jnp.arange(1, NB))
@@ -165,20 +168,31 @@ def kernels_phase(size: Size, seed: int) -> dict:
     check(int(start) + C <= size.seq, "chunk window overruns the table")
     vlens = jnp.minimum(lens, size.seq - K)   # verify writes K past lens
 
+    def rows(pool):                  # as PagedKVCache stores it
+        return pool.reshape(LAYERS, NB, BS, H * D)
+
     def int8(pool):
-        q, s = quantize_int8(pool, -1)              # [NB,BS,H,D], [..,1]
-        return q, jnp.transpose(s[..., 0], (0, 2, 1))   # scales [NB,H,BS]
+        q, s = quantize_int8(pool, -1)            # [L,NB,BS,H,D], [..,1]
+        return rows(q), jnp.transpose(s[..., 0], (0, 1, 3, 2))  # [L,NB,H,BS]
 
     # (k, v, scales...) — the kernels and their references take the
-    # scales as the two trailing positional-or-keyword arguments
+    # scales as the two trailing positional-or-keyword arguments; a
+    # kernel takes the whole pool and a layer, its reference that layer
     (k8, ksc), (v8, vsc) = int8(kp), int8(vp)
-    pools = {"fp": (kp.astype(dt), vp.astype(dt)),
+    pools = {"fp": (rows(kp.astype(dt)), rows(vp.astype(dt))),
              "int8": (k8, v8, ksc, vsc)}
 
-    def with_scales(fn):
+    def scales(sc):
+        return dict(zip(("k_scale", "v_scale"), sc))
+
+    def kernel_of(fn):       # the whole pool and the layer to attend
         return lambda q, k, v, table, bound, *sc: fn(
-            q, k, v, table, bound,
-            **dict(zip(("k_scale", "v_scale"), sc)))
+            q, k, v, table, bound, layer=LAYER, **scales(sc))
+
+    def oracle_of(fn):       # that layer alone
+        return lambda q, k, v, table, bound, *sc: fn(
+            q, k[LAYER], v[LAYER], table, bound,
+            **scales(s[LAYER] for s in sc))
 
     cases = {}
     for tag, (k, v, *sc) in pools.items():
@@ -186,12 +200,11 @@ def kernels_phase(size: Size, seed: int) -> dict:
                                       ("verify", qk, bt, vlens),
                                       ("chunk", qc, bt[1], start)):
             cases[f"paged_{kind}-{tag}"] = (
-                with_scales(getattr(da, f"paged_{kind}_attention")),
-                with_scales(getattr(da,
-                                    f"paged_{kind}_attention_reference")),
+                kernel_of(getattr(da, f"paged_{kind}_attention")),
+                oracle_of(getattr(da, f"paged_{kind}_attention_reference")),
                 (q, k, v, table, bound, *sc))
-    kd = kp[bt].reshape(S, size.seq, H, D).astype(dt)   # dense caches
-    vd = vp[bt].reshape(S, size.seq, H, D).astype(dt)
+    kd = kp[LAYER][bt].reshape(S, size.seq, H, D).astype(dt)  # dense caches
+    vd = vp[LAYER][bt].reshape(S, size.seq, H, D).astype(dt)
     cases["decode"] = (
         lambda *a: da.decode_attention(*a, block_k=BS),
         da.decode_attention_reference, (q1, kd, vd, lens))

@@ -157,7 +157,7 @@ def advance(cache: KVCache, n: int = 1) -> KVCache:
 
 # ---------------------------------------------------------------- paged
 # vLLM-style PagedAttention, translated to the static-shape TPU world: one
-# global block pool ``[L, num_blocks, block_size, H, D]`` shared by every
+# global block pool ``[L, num_blocks, block_size, H*D]`` shared by every
 # live sequence, plus a per-SLOT int32 block table mapping logical cache
 # positions to pool blocks. All shapes are static, so the jitted decode
 # step is traced ONCE per (num_slots, block_size) configuration and
@@ -174,7 +174,22 @@ def advance(cache: KVCache, n: int = 1) -> KVCache:
 class PagedKVCache:
     """Paged decode workspace over ``num_slots`` resident sequences.
 
-    k/v: ``[L, num_blocks, block_size, H, D]`` global pool.
+    k/v: ``[L, num_blocks, block_size, KH*D]`` global pool, ONE stacked
+    array each, stored in the byte order the paged kernels read
+    (ops/pallas/decode_attention.py): a position's row is its
+    ``num_kv_heads`` heads side by side, ``KH*D`` lanes wide, so a block
+    is one contiguous ``[block_size, KH*D]`` slab and the kernels take
+    the whole array as their operand — a layer is a block offset in
+    their index map, and no program copies a layer out of the pool or
+    reorders it on the way to a kernel. (On a TPU ``[.., BS, KH, D]``
+    and ``[.., BS, KH*D]`` are different byte orders: stored the first
+    way, every kernel call paid a layer-sized conversion of K and of V.)
+    ``KH*D`` should be a multiple of the 128 lanes; a narrower or ragged
+    row is stored the other way round by the compiler and converted
+    around every call. Writers reshape the NEW rows, never the pool.
+    num_kv_heads: static; how the ``KH*D`` lanes split into heads
+    (``head_dim`` follows). Under a ``tensor`` mesh the lane dim is the
+    sharded one: heads are its major part, so a shard keeps whole heads.
     block_tables: ``[num_slots, max_blocks]`` int32 — pool block ids per
     slot, in logical order (entry j covers positions
     ``j*block_size .. (j+1)*block_size-1``); unallocated entries are 0
@@ -191,16 +206,21 @@ class PagedKVCache:
     or dequantize at the gather. Scales are DATA in the same donated pytree — tier
     membership and quantization never change a traced signature.
     ``None`` scales = full-precision pool (the default)."""
-    k: jnp.ndarray             # [L, NB, BS, H, D] (fp or int8)
-    v: jnp.ndarray             # [L, NB, BS, H, D]
+    k: jnp.ndarray             # [L, NB, BS, KH*D] (fp or int8)
+    v: jnp.ndarray             # [L, NB, BS, KH*D]
     block_tables: jnp.ndarray  # [S, MB] int32
     lengths: jnp.ndarray       # [S] int32
+    num_kv_heads: int = struct.field(pytree_node=False)
     k_scale: Optional[jnp.ndarray] = None   # [L, NB, KH, BS] f32 | None
     v_scale: Optional[jnp.ndarray] = None
 
     @property
     def quantized(self) -> bool:
         return self.k_scale is not None
+
+    @property
+    def head_dim(self) -> int:
+        return self.k.shape[3] // self.num_kv_heads
 
     @property
     def block_size(self) -> int:
@@ -237,7 +257,7 @@ def init_paged_cache(num_layers: int, num_slots: int, num_blocks: int,
     int8 pool (payload dtype int8 regardless of ``dtype``) with
     all-ones scale tiles — unwritten garbage dequantizes to exact
     zeros, the same dead-memory story as the fp pool."""
-    shape = (num_layers, num_blocks, block_size, num_kv_heads, head_dim)
+    shape = (num_layers, num_blocks, block_size, num_kv_heads * head_dim)
     pool_dtype = jnp.int8 if quantized else dtype
 
     def scales():
@@ -254,7 +274,7 @@ def init_paged_cache(num_layers: int, num_slots: int, num_blocks: int,
         block_tables=jnp.zeros((num_slots, max_blocks_per_slot),
                                jnp.int32),
         lengths=jnp.zeros((num_slots,), jnp.int32),
-        k_scale=scales(), v_scale=scales())
+        num_kv_heads=num_kv_heads, k_scale=scales(), v_scale=scales())
 
 
 def _quant_rows(cache: PagedKVCache, x: jnp.ndarray):
@@ -268,6 +288,11 @@ def _quant_rows(cache: PagedKVCache, x: jnp.ndarray):
         return x.astype(cache.k.dtype), None
     q, s = quantize_int8(x, -1)
     return q, s[..., 0]
+
+
+def _head_rows(cache: PagedKVCache, x: jnp.ndarray) -> jnp.ndarray:
+    """Gathered pool rows ``[..., KH*D]`` as heads ``[..., KH, D]``."""
+    return x.reshape(*x.shape[:-1], cache.num_kv_heads, -1)
 
 
 @scoped("kv_write")
@@ -298,8 +323,10 @@ def _scatter_blocks(cache: PagedKVCache, layer: int, idx: jnp.ndarray,
     nb = idx.shape[0]
     qk, sk = _quant_rows(cache, k)
     qv, sv = _quant_rows(cache, v)
-    newk = cache.k.at[layer, idx].set(qk.reshape(nb, BS, *k.shape[1:]))
-    newv = cache.v.at[layer, idx].set(qv.reshape(nb, BS, *v.shape[1:]))
+    # the NEW rows take the pool's layout (heads side by side in a
+    # position's row); the pool itself is never reshaped
+    newk = cache.k.at[layer, idx].set(qk.reshape(nb, BS, -1))
+    newv = cache.v.at[layer, idx].set(qv.reshape(nb, BS, -1))
     out = cache.replace(k=newk, v=newv)
     if sk is not None:
         # [T, KH] -> per-block [nb, KH, BS] scale tiles
@@ -337,8 +364,8 @@ def _scatter_positions(cache: PagedKVCache, layer: int, blk: jnp.ndarray,
     exactly the ``[..., KH]`` shape :func:`_quant_rows` returns."""
     qk, sk = _quant_rows(cache, k)
     qv, sv = _quant_rows(cache, v)
-    newk = cache.k.at[layer, blk, off].set(qk)
-    newv = cache.v.at[layer, blk, off].set(qv)
+    newk = cache.k.at[layer, blk, off].set(qk.reshape(*blk.shape, -1))
+    newv = cache.v.at[layer, blk, off].set(qv.reshape(*blk.shape, -1))
     out = cache.replace(k=newk, v=newv)
     if sk is not None:
         out = out.replace(
@@ -412,8 +439,8 @@ def paged_gather_slot_kv(cache: PagedKVCache, layer: int, slot: jnp.ndarray):
     dequantizes at the gather (f32 out — the fused multiply is free
     next to the gather's HBM traffic)."""
     row = jax.lax.dynamic_slice_in_dim(cache.block_tables, slot, 1, 0)[0]
-    k = cache.k[layer][row]        # [MB, BS, H, D]
-    v = cache.v[layer][row]
+    k = _head_rows(cache, cache.k[layer][row])        # [MB, BS, H, D]
+    v = _head_rows(cache, cache.v[layer][row])
     if cache.k_scale is not None:
         # scale tiles [MB, KH, BS] -> [MB, BS, KH, 1] against the pool
         k = dequantize_int8(
@@ -451,8 +478,8 @@ def paged_gather_kv(cache: PagedKVCache, layer: int):
     attention is bit-identical to the dense-cache path. An int8 pool
     dequantizes at the gather (f32 out)."""
     S, MB = cache.block_tables.shape
-    k = cache.k[layer][cache.block_tables]   # [S, MB, BS, H, D]
-    v = cache.v[layer][cache.block_tables]
+    k = _head_rows(cache, cache.k[layer][cache.block_tables])
+    v = _head_rows(cache, cache.v[layer][cache.block_tables])  # [S,MB,BS,H,D]
     if cache.k_scale is not None:
         # scale tiles [S, MB, KH, BS] -> [S, MB, BS, KH, 1]
         ks = cache.k_scale[layer][cache.block_tables]
@@ -480,9 +507,9 @@ def paged_advance(cache, active: jnp.ndarray):
 # of 128 lanes and BS = 128 is; ops/pallas/latent_decode_attention.py),
 # under the same block tables, lengths, null block and allocator as
 # :class:`PagedKVCache`: no program ever cuts one attention's rows out of
-# a stacked ``[L, NB, ...]`` array (PERF.md section 5: that cut is 82 % of
-# the K/V pool's decode step), and an append is an in-place update of a
-# donated buffer.
+# a stacked ``[L, NB, ...]`` array (the K/V pool avoids that cut its own
+# way: its kernels take the stacked array whole), and an append is an
+# in-place update of a donated buffer.
 
 
 @struct.dataclass
@@ -605,13 +632,14 @@ def _read_block_impl(cache: PagedKVCache, block):
 
 def paged_read_block(cache: PagedKVCache, block: int) -> Dict[str, Any]:
     """Device→host copy of one pool block's payload across all layers:
-    ``{"k": [L, BS, H, D], "v": ..., ("k_scale"/"v_scale": [L, KH, BS])}``
-    as numpy arrays (the demotion copy — ``np.asarray`` forces the
-    transfer, so by return the content is host-durable and the device
-    block is safe to recycle). The gather is jitted with the block id
-    as TRACED data — the same one-executable-per-pool-geometry
-    contract as :func:`paged_swap_in`, so demotions never grow the
-    compile cache however many distinct blocks tier out."""
+    ``{"k": [L, BS, KH*D], "v": ..., ("k_scale"/"v_scale": [L, KH, BS])}``
+    as numpy arrays, rows in the pool's own layout (the demotion copy —
+    ``np.asarray`` forces the transfer, so by return the content is
+    host-durable and the device block is safe to recycle). The gather
+    is jitted with the block id as TRACED data — the same
+    one-executable-per-pool-geometry contract as :func:`paged_swap_in`,
+    so demotions never grow the compile cache however many distinct
+    blocks tier out."""
     out = _read_block_impl(cache, jnp.int32(block))
     if len(out) == 4:
         return {"k": np.asarray(out[0]), "v": np.asarray(out[1]),
@@ -623,9 +651,9 @@ def paged_read_block(cache: PagedKVCache, block: int) -> Dict[str, Any]:
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _swap_in_impl(cache: PagedKVCache, block, k, v, ks, vs):
     newk = jax.lax.dynamic_update_slice(cache.k, k[:, None],
-                                        (0, block, 0, 0, 0))
+                                        (0, block, 0, 0))
     newv = jax.lax.dynamic_update_slice(cache.v, v[:, None],
-                                        (0, block, 0, 0, 0))
+                                        (0, block, 0, 0))
     out = cache.replace(k=newk, v=newv)
     if ks is not None:
         out = out.replace(

@@ -1009,7 +1009,7 @@ class ContinuousBatchingServer:
             self.scheduler.allocator.free_ids)
         return self._pool_acct.snapshot()
 
-    # switches whose code reads or writes K/V pools ``[L, NB, BS, H, D]``
+    # switches whose code reads or writes K/V pools ``[L, NB, BS, KH*D]``
     # (block copies, scale tiles, chunk / verify kernels): a latent pool
     # has none of those shapes, and nothing falls back to K/V code
     _LATENT_REFUSES = (
@@ -1087,9 +1087,11 @@ class ContinuousBatchingServer:
         mesh = self.engine.mesh
         if mesh is not None:
             # kv heads shard over `tensor` exactly like the dense cache
-            # (engine._make_cache); the block dim stays replicated —
-            # every device owns the whole table, its heads of every block
-            sh = NamedSharding(mesh, P(None, None, None, "tensor", None))
+            # (engine._make_cache): they are the major part of the
+            # pool's [L, NB, BS, KH*D] lane dim. The block dim stays
+            # replicated — every device owns the whole table, its heads
+            # of every block
+            sh = NamedSharding(mesh, P(None, None, None, "tensor"))
             cache = cache.replace(
                 k=jax.device_put(cache.k, sh),
                 v=jax.device_put(cache.v, sh))
@@ -1113,7 +1115,7 @@ class ContinuousBatchingServer:
             dtype=self.draft._act_dtype, quantized=False)
         mesh = self.draft.mesh
         if mesh is not None:
-            sh = NamedSharding(mesh, P(None, None, None, "tensor", None))
+            sh = NamedSharding(mesh, P(None, None, None, "tensor"))
             cache = cache.replace(k=jax.device_put(cache.k, sh),
                                   v=jax.device_put(cache.v, sh))
         return cache

@@ -531,29 +531,31 @@ def _paged_kernel(kernel, q, cache: PagedKVCache, layer_idx: int,
                   cfg: InferenceTransformerConfig, mesh, table, bound):
     """One call shape for the three paged Pallas kernels: ``q`` against
     layer ``layer_idx`` of the pool through ``table``, causal bound
-    ``bound`` (live lengths / chunk start). An int8 pool adds its two
-    scale tiles (empty for fp pools — the call, and therefore the traced
-    signature, is unchanged). Under the engine's mesh the kernel is
-    mapped over the ``tensor`` axis: each shard attends its own kv heads
-    of the pool, which is how the pool is laid out across devices."""
-    hs = _head_axis(mesh, q.shape[-2], cache.k.shape[3])
+    ``bound`` (live lengths / chunk start). The kernel's operand is the
+    pool as it is stored, all layers of it: the layer is a block offset
+    in the kernel's index map, so no K or V byte is copied between the
+    pool and the call. An int8 pool adds its two scale tiles (empty for
+    fp pools — the call, and therefore the traced signature, is
+    unchanged). Under the engine's mesh the kernel is mapped over the
+    ``tensor`` axis: each shard attends its own kv heads of the pool
+    (the major part of the pool's lane dim), which is how the pool is
+    laid out across devices."""
+    hs = _head_axis(mesh, q.shape[-2], cache.num_kv_heads)
     q_spec = P(*[None] * (q.ndim - 2), hs, None)     # [..., H, D]
-    pool = P(None, None, hs, None)
-    with jax.named_scope("kv_read"):
-        # the per-layer cut of K and V (and their scales) out of the pool
-        k_pool, v_pool = cache.k[layer_idx], cache.v[layer_idx]
-        scales = ([] if cache.k_scale is None else
-                  [cache.k_scale[layer_idx], cache.v_scale[layer_idx]])
+    pool = P(None, None, None, hs)                   # [L, NB, BS, KH*D]
+    scales = ([] if cache.k_scale is None else
+              [cache.k_scale, cache.v_scale])        # [L, NB, KH, BS]
 
     def call(q, k, v, table, bound, *sc):
         return kernel(q, k, v, table, bound, scale=cfg.scale,
+                      layer=layer_idx,
                       **dict(zip(("k_scale", "v_scale"), sc)))
     with jax.named_scope("attn_kernel"):
         return map_kernel(
             call, mesh,
             (q_spec, pool, pool, P(), P(),
-             *[P(None, hs, None)] * len(scales)),
-            q_spec)(q, k_pool, v_pool, table, bound, *scales)
+             *[P(None, None, hs, None)] * len(scales)),
+            q_spec)(q, cache.k, cache.v, table, bound, *scales)
 
 
 def _paged_decode_attention(q, cache: PagedKVCache, layer_idx: int,
@@ -567,7 +569,7 @@ def _paged_decode_attention(q, cache: PagedKVCache, layer_idx: int,
     block table with XLA, then reuse :func:`_decode_attention` — gathered
     position j is logical position j, so the math (and every masked
     softmax bit) is identical to the dense-cache path."""
-    if _use_decode_kernel(cfg, q.shape[1], cache.k.shape[3], window):
+    if _use_decode_kernel(cfg, q.shape[1], cache.num_kv_heads, window):
         return _paged_kernel(_kernels.paged_decode_attention, q, cache,
                              layer_idx, cfg, mesh, cache.block_tables, live)
     k_cache, v_cache = paged_gather_kv(cache, layer_idx)
@@ -618,7 +620,7 @@ def _paged_verify_attention(q, cache: PagedKVCache, layer_idx: int,
     with XLA and reuse :func:`_chunk_attention` with per-slot
     ``lengths`` — the identical per-query causal bound, so the paged
     verify cannot diverge from the dense :func:`decode_chunk` math."""
-    if _use_decode_kernel(cfg, q.shape[2], cache.k.shape[3], window):
+    if _use_decode_kernel(cfg, q.shape[2], cache.num_kv_heads, window):
         return _paged_kernel(_kernels.paged_verify_attention, q, cache,
                              layer_idx, cfg, mesh, cache.block_tables,
                              cache.lengths)
@@ -639,7 +641,7 @@ def _paged_chunk_attention(q, cache: PagedKVCache, layer_idx: int,
     ONE slot's cache with XLA and reuse :func:`_chunk_attention` with
     ``lengths = start`` — the identical per-query causal bound, so the
     chunked path cannot diverge from the verify/dense math."""
-    if _use_decode_kernel(cfg, q.shape[2], cache.k.shape[3], window):
+    if _use_decode_kernel(cfg, q.shape[2], cache.num_kv_heads, window):
         row = jax.lax.dynamic_slice_in_dim(cache.block_tables, slot, 1,
                                            0)[0]
         return _paged_kernel(_kernels.paged_chunk_attention, q[0], cache,

@@ -10,23 +10,28 @@ carries and where its causal bound starts, both of which ride as data.
 
 The kernel streams K/V blocks through VMEM with the online-softmax
 recurrence — no probability vector ever round-trips HBM. It consumes the
-cache in its STORAGE layout ``[NB, BS, KH, D]`` (kv_cache.py) with no
-transpose: a pool block is DMA'd whole, as the contiguous
-``[BS, KH*D]`` slab it is in HBM, and the kv heads are walked inside the
-kernel as static lane slices of that slab. (The TPU lowering only takes
-blocks whose last two dims are tile-aligned or span the array, so a
-block cannot pick one kv head out of ``KH`` — the per-head block of
-the earlier layout was refused by the chip's compiler.) Grouped-query
-attention is native: each kv head's slice is attended by its whole query
-group ``[rows, D]`` at once, so GQA's bandwidth saving survives.
+paged pool WHERE IT LIES: the whole stacked ``[L, NB, BS, KH*D]`` array
+of kv_cache.PagedKVCache is the operand (merging the two leading dims
+moves no byte), and a layer is the static block offset ``layer * NB``
+the index map adds to every table entry — no program cuts a layer's K
+or V out of the pool or reorders a byte of it before the call. A pool
+block is DMA'd whole, as the contiguous ``[BS, KH*D]`` slab it is in
+HBM, and the kv heads are walked inside the kernel as static lane
+slices of that slab. (The TPU lowering only takes blocks whose last two
+dims are tile-aligned or span the array, so a block cannot pick one kv
+head out of ``KH`` — the per-head block of the earlier layout was
+refused by the chip's compiler.) Grouped-query attention is native: each
+kv head's slice is attended by its whole query group ``[rows, D]`` at
+once, so GQA's bandwidth saving survives.
 
 int8 pools (kv_cache_dtype: "int8", docs/serving.md "KV quantization &
-host tiering") add per-block-per-head scale tiles ``[NB, KH, BS]`` (one
-amax/127 scale per written (position, head) row, block_size on the LANE
-dim). The HBM stream is the int8 bytes; the scales are applied in VMEM
-as ``[1, BS]`` rows against the score / probability matrices
-(``(q·kᵀ)·s_k`` and ``(p·s_v)·v`` — algebraically the dequantized
-product, without ever turning a scale row into a column).
+host tiering") add per-block-per-head scale tiles ``[L, NB, KH, BS]``
+(one amax/127 scale per written (position, head) row, block_size on the
+LANE dim), read through the same index map. The HBM stream is the int8
+bytes; the scales are applied in VMEM as ``[1, BS]`` rows against the
+score / probability matrices (``(q·kᵀ)·s_k`` and ``(p·s_v)·v`` —
+algebraically the dequantized product, without ever turning a scale row
+into a column).
 """
 from __future__ import annotations
 
@@ -45,16 +50,17 @@ DEFAULT_BLOCK_K = 256
 MAX_QUERY_ROWS = 128
 
 
-def _dequant_pools(k_pool, v_pool, k_scale, v_scale):
-    """XLA-side pool dequantization for the reference oracles: scales
-    ``[NB, KH, BS]`` broadcast against the ``[NB, BS, KH, D]`` pool."""
+def _layer_pools(k_pool, v_pool, D, k_scale, v_scale):
+    """What the reference oracles attend: ONE layer's pools
+    ``[NB, BS, KH*D]`` as ``[NB, BS, KH, D]``, an int8 pool dequantized
+    by XLA (scales ``[NB, KH, BS]`` broadcast against it)."""
     from deepspeed_tpu.ops.quant_core import dequantize_int8
+    k = k_pool.reshape(*k_pool.shape[:2], -1, D)
+    v = v_pool.reshape(*v_pool.shape[:2], -1, D)
     if k_scale is None:
-        return k_pool, v_pool
-    k = dequantize_int8(k_pool,
-                        jnp.transpose(k_scale, (0, 2, 1))[..., None])
-    v = dequantize_int8(v_pool,
-                        jnp.transpose(v_scale, (0, 2, 1))[..., None])
+        return k, v
+    k = dequantize_int8(k, jnp.transpose(k_scale, (0, 2, 1))[..., None])
+    v = dequantize_int8(v, jnp.transpose(v_scale, (0, 2, 1))[..., None])
     return k, v
 
 
@@ -124,23 +130,30 @@ def _paged_kernel(base_ref, bt_ref, q_ref, k_ref, v_ref, *rest,
 
 
 def _paged_attention(qg, k_pool, v_pool, block_tables, base, *, rep: int,
-                     scale, interpret, name: str, k_scale=None,
-                     v_scale=None):
+                     scale, interpret, name: str, layer: int = 0,
+                     k_scale=None, v_scale=None):
     """The decode family's one ``pallas_call``, named ``name`` in the
     compiled program and the device trace (the entry point's name: the
     kernel body is shared). qg ``[S, KH, T*rep, D]``
     (each slot's T query tokens x ``rep`` group members, token-major,
-    grouped by the kv head they read); pools ``[NB, BS, KH, D]``;
-    block_tables ``[S, MB]`` (dead entries must be valid ids — the null
-    block); base ``[S]``: slot s's token t sees key positions
-    ``<= base[s] + t``. Returns ``[S, KH, T*rep, D]``."""
+    grouped by the kv head they read); pools ``[L, NB, BS, KH*D]``, the
+    whole stacked pool, of which the call attends layer ``layer``
+    (static); block_tables ``[S, MB]`` of that layer's block ids (dead
+    entries must be valid ids — the null block); base ``[S]``: slot s's
+    token t sees key positions ``<= base[s] + t``. Returns
+    ``[S, KH, T*rep, D]``."""
     S, KH, rows, D = qg.shape
-    NB, BS = k_pool.shape[0], k_pool.shape[1]
+    L, NB, BS, W = k_pool.shape
     MB = block_tables.shape[1]
     quantized = k_scale is not None
     if (k_pool.dtype == jnp.int8) != quantized:
         raise ValueError("int8 pools require k_scale/v_scale (and fp "
                          "pools must not pass them)")
+    if W != KH * D:
+        raise ValueError(f"pool rows are {W} wide; {KH} kv heads x "
+                         f"{D} need {KH * D}")
+    if not 0 <= layer < L:
+        raise ValueError(f"layer {layer} of a {L}-layer pool")
     if scale is None:
         scale = 1.0 / (D ** 0.5)
     if interpret is None:
@@ -159,18 +172,21 @@ def _paged_attention(qg, k_pool, v_pool, block_tables, base, *, rep: int,
         # unchanged block index skips the DMA, so the dead tail of a
         # table costs neither bandwidth nor (pl.when above) compute
         last = jnp.maximum(base[s] + (rb + 1) * span - 1, 0) // BS
-        return (bt[s, jnp.minimum(i, last)], 0, 0)
+        return (bt[s, jnp.minimum(i, last)] + layer * NB, 0, 0)
 
     def q_map(s, rb, i, base, bt):
         return (s, 0, rb, 0)
 
-    kv_spec = pl.BlockSpec((1, BS, KH * D), kv_map)
+    # the layers' blocks back to back: merging the two leading dims of
+    # the stored array is free, and layer l's block b is block l*NB + b
+    kv_spec = pl.BlockSpec((1, BS, W), kv_map)
     in_specs = [pl.BlockSpec((1, KH, rblk, D), q_map), kv_spec, kv_spec]
     args = [base.astype(jnp.int32), block_tables.astype(jnp.int32), qg,
-            k_pool.reshape(NB, BS, KH * D), v_pool.reshape(NB, BS, KH * D)]
+            k_pool.reshape(L * NB, BS, W), v_pool.reshape(L * NB, BS, W)]
     if quantized:
         in_specs += [pl.BlockSpec((1, KH, BS), kv_map)] * 2
-        args += [k_scale, v_scale]
+        args += [k_scale.reshape(L * NB, KH, BS),
+                 v_scale.reshape(L * NB, KH, BS)]
     kernel = functools.partial(
         _paged_kernel, block_size=BS, head_dim=D, rep=rep, span=span,
         scale=float(scale), quantized=quantized)
@@ -210,9 +226,9 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     q: ``[B, H, D]``; k_cache/v_cache: ``[B, S, KH, D]`` (the kv_cache.py
     storage layout — no transpose) with ``H % KH == 0``; lengths: ``[B]``
     int32 live lengths (query attends positions ``< lengths[b]``).
-    Returns ``[B, H, D]``. A dense cache IS a paged pool whose block
-    table is the identity: row b's ``S // block_k`` blocks sit back to
-    back, so the reshape below is free and the paged kernel runs as is.
+    Returns ``[B, H, D]``. A dense cache IS a one-layer paged pool
+    whose block table is the identity: row b's ``S // block_k`` blocks
+    sit back to back, and the paged kernel runs as is.
     """
     B, H, D = q.shape
     S, KH = k_cache.shape[1], k_cache.shape[2]
@@ -224,8 +240,8 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     tables = jnp.arange(B * nb, dtype=jnp.int32).reshape(B, nb)
     og = _paged_attention(
         q.reshape(B, KH, R, D),
-        k_cache.reshape(B * nb, block_k, KH, D),
-        v_cache.reshape(B * nb, block_k, KH, D),
+        k_cache.reshape(1, B * nb, block_k, KH * D),
+        v_cache.reshape(1, B * nb, block_k, KH * D),
         tables, lengths.astype(jnp.int32) - 1, rep=R, scale=scale,
         interpret=interpret, name="decode_attention")
     return og.reshape(B, H, D)
@@ -237,28 +253,30 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            scale: float | None = None,
                            interpret: bool | None = None,
                            k_scale: jax.Array | None = None,
-                           v_scale: jax.Array | None = None) -> jax.Array:
+                           v_scale: jax.Array | None = None,
+                           layer: int = 0) -> jax.Array:
     """One-token attention through a paged KV pool, GQA-native.
 
     q: ``[S, H, D]`` (one query per slot); k_pool/v_pool:
-    ``[NB, BS, KH, D]`` (the PagedKVCache per-layer pool layout);
+    ``[L, NB, BS, KH*D]`` (the PagedKVCache pool as stored, all layers;
+    the call attends layer ``layer``, a static int);
     block_tables: ``[S, MB]`` int32 (entry j covers logical positions
     ``j*BS..(j+1)*BS-1``; dead entries must be valid ids — the null
     block); lengths: ``[S]`` int32 live lengths (the query attends
     positions ``< lengths[s]``). Returns ``[S, H, D]``.
 
-    int8 pools pass ``k_scale``/``v_scale`` ``[NB, KH, BS]``; the grid,
-    scratch and recurrence are unchanged (scales are two more streamed
-    inputs, not a new program structure). An idle slot (length 0) costs
-    no compute and one null-block DMA.
+    int8 pools pass ``k_scale``/``v_scale`` ``[L, NB, KH, BS]``; the
+    grid, scratch and recurrence are unchanged (scales are two more
+    streamed inputs, not a new program structure). An idle slot (length
+    0) costs no compute and one null-block DMA.
     """
     S, H, D = q.shape
-    KH = k_pool.shape[2]
+    KH = k_pool.shape[-1] // D
     R = _group_size(H, KH)
     og = _paged_attention(
         q.reshape(S, KH, R, D), k_pool, v_pool, block_tables,
         lengths.astype(jnp.int32) - 1, rep=R, scale=scale,
-        interpret=interpret, name="paged_decode_attention",
+        interpret=interpret, name="paged_decode_attention", layer=layer,
         k_scale=k_scale, v_scale=v_scale)
     return og.reshape(S, H, D)
 
@@ -269,24 +287,27 @@ def paged_chunk_attention(q: jax.Array, k_pool: jax.Array,
                           scale: float | None = None,
                           interpret: bool | None = None,
                           k_scale: jax.Array | None = None,
-                          v_scale: jax.Array | None = None) -> jax.Array:
+                          v_scale: jax.Array | None = None,
+                          layer: int = 0) -> jax.Array:
     """Chunked-prefill attention for one slot through the paged pool,
     GQA-native.
 
     q: ``[C, H, D]`` (the in-flight chunk, absolute positions
     ``start..start+C-1``; the chunk's own k/v must already be written
-    into the pool); k_pool/v_pool: ``[NB, BS, KH, D]``; block_table:
+    into the pool); k_pool/v_pool: ``[L, NB, BS, KH*D]``, of which
+    layer ``layer`` is attended; block_table:
     ``[MB]`` int32 (the prefilling slot's row; dead entries must be
     valid ids — the null block); start: scalar int32, block-aligned.
     The chunk attends the already-resident prefix (earlier chunks AND
     prefix-cache hits) plus itself: key position ``col`` is visible to
     chunk query ``qi`` iff ``col <= start + qi``. int8 pools pass
-    ``k_scale``/``v_scale`` ``[NB, KH, BS]``. Returns ``[C, H, D]``.
+    ``k_scale``/``v_scale`` ``[L, NB, KH, BS]``. Returns ``[C, H, D]``.
     """
     return _paged_multi_token(
         q[None], k_pool, v_pool, block_table[None],
         jnp.reshape(start, (1,)), "paged_chunk_attention", scale=scale,
-        interpret=interpret, k_scale=k_scale, v_scale=v_scale)[0]
+        interpret=interpret, k_scale=k_scale, v_scale=v_scale,
+        layer=layer)[0]
 
 
 def paged_verify_attention(q: jax.Array, k_pool: jax.Array,
@@ -295,14 +316,16 @@ def paged_verify_attention(q: jax.Array, k_pool: jax.Array,
                            scale: float | None = None,
                            interpret: bool | None = None,
                            k_scale: jax.Array | None = None,
-                           v_scale: jax.Array | None = None) -> jax.Array:
+                           v_scale: jax.Array | None = None,
+                           layer: int = 0) -> jax.Array:
     """Batched speculative-verify attention through a paged KV pool,
     GQA-native.
 
     q: ``[S, K, H, D]`` (each slot's K-token candidate chunk at
     absolute positions ``lengths[s]..lengths[s]+K-1``; the chunk's own
     k/v must already be written into the pool —
-    kv_cache.paged_write_tokens); k_pool/v_pool: ``[NB, BS, KH, D]``;
+    kv_cache.paged_write_tokens); k_pool/v_pool: ``[L, NB, BS, KH*D]``,
+    of which layer ``layer`` is attended;
     block_tables: ``[S, MB]`` int32 (dead entries must be valid ids —
     the null block); lengths: ``[S]`` int32 live lengths per slot.
     Per-query causal bound ``col <= lengths[s] + qi``. Returns
@@ -311,19 +334,19 @@ def paged_verify_attention(q: jax.Array, k_pool: jax.Array,
     ONE kernel signature per ``(K, num_slots, block geometry)`` —
     per-slot acceptance state rides in ``lengths``, so varying
     acceptance never retraces (the PR-8 trace-discipline contract).
-    int8 pools pass ``k_scale``/``v_scale`` ``[NB, KH, BS]``."""
+    int8 pools pass ``k_scale``/``v_scale`` ``[L, NB, KH, BS]``."""
     return _paged_multi_token(q, k_pool, v_pool, block_tables, lengths,
                               "paged_verify_attention", scale=scale,
                               interpret=interpret, k_scale=k_scale,
-                              v_scale=v_scale)
+                              v_scale=v_scale, layer=layer)
 
 
 def _paged_multi_token(q, k_pool, v_pool, block_tables, lengths, name, *,
-                       scale, interpret, k_scale, v_scale):
+                       scale, interpret, k_scale, v_scale, layer):
     """What the verify and chunk entry points share: K query tokens per
     slot, the kernel call named ``name``."""
     S, K, H, D = q.shape
-    KH = k_pool.shape[2]
+    KH = k_pool.shape[-1] // D
     R = _group_size(H, KH)
     # [S, K, H, D] -> [S, KH, K*R, D]: rows grouped by the kv head they
     # read, query index recoverable in-kernel as row // R
@@ -331,19 +354,21 @@ def _paged_multi_token(q, k_pool, v_pool, block_tables, lengths, name, *,
         S, KH, K * R, D)
     og = _paged_attention(qg, k_pool, v_pool, block_tables, lengths,
                           rep=R, scale=scale, interpret=interpret,
-                          name=name, k_scale=k_scale, v_scale=v_scale)
+                          name=name, layer=layer, k_scale=k_scale,
+                          v_scale=v_scale)
     return og.reshape(S, KH, K, R, D).transpose(0, 2, 1, 3, 4).reshape(
         S, K, H, D)
 
 
 def paged_verify_attention_reference(q, k_pool, v_pool, block_tables,
                                      lengths, k_scale=None, v_scale=None):
-    """Numerics oracle for :func:`paged_verify_attention`: gather each
-    slot's cache through its table, dense masked softmax with the
+    """Numerics oracle for :func:`paged_verify_attention` over ONE
+    layer's pools ``[NB, BS, KH*D]`` (scales ``[NB, KH, BS]``): gather
+    each slot's cache through its table, dense masked softmax with the
     per-query causal bound ``col <= lengths[s] + qi``. int8 pools
-    dequantize up front (:func:`_dequant_pools`)."""
-    k_pool, v_pool = _dequant_pools(k_pool, v_pool, k_scale, v_scale)
+    dequantize up front (:func:`_layer_pools`)."""
     S, K, H, D = q.shape
+    k_pool, v_pool = _layer_pools(k_pool, v_pool, D, k_scale, v_scale)
     BS, KH = k_pool.shape[1], k_pool.shape[2]
     MB = block_tables.shape[1]
     rep = H // KH
@@ -363,12 +388,12 @@ def paged_verify_attention_reference(q, k_pool, v_pool, block_tables,
 
 def paged_chunk_attention_reference(q, k_pool, v_pool, block_table, start,
                                     k_scale=None, v_scale=None):
-    """Numerics oracle for :func:`paged_chunk_attention`: gather the
-    slot's cache through its table, dense masked softmax with the
-    per-query causal bound ``col <= start + qi``. int8 pools
-    dequantize up front."""
-    k_pool, v_pool = _dequant_pools(k_pool, v_pool, k_scale, v_scale)
+    """Numerics oracle for :func:`paged_chunk_attention` over ONE
+    layer's pools ``[NB, BS, KH*D]``: gather the slot's cache through
+    its table, dense masked softmax with the per-query causal bound
+    ``col <= start + qi``. int8 pools dequantize up front."""
     C, H, D = q.shape
+    k_pool, v_pool = _layer_pools(k_pool, v_pool, D, k_scale, v_scale)
     BS, KH = k_pool.shape[1], k_pool.shape[2]
     MB = block_table.shape[0]
     rep = H // KH
@@ -388,11 +413,12 @@ def paged_chunk_attention_reference(q, k_pool, v_pool, block_table, start,
 
 def paged_decode_attention_reference(q, k_pool, v_pool, block_tables,
                                      lengths, k_scale=None, v_scale=None):
-    """Numerics oracle: gather each slot's cache through its block table
-    (gathered position j IS logical position j), then run the dense
-    masked-softmax reference. Same layouts as
-    :func:`paged_decode_attention`; int8 pools dequantize up front."""
-    k_pool, v_pool = _dequant_pools(k_pool, v_pool, k_scale, v_scale)
+    """Numerics oracle over ONE layer's pools ``[NB, BS, KH*D]``: gather
+    each slot's cache through its block table (gathered position j IS
+    logical position j), then run the dense masked-softmax reference.
+    int8 pools dequantize up front."""
+    k_pool, v_pool = _layer_pools(k_pool, v_pool, q.shape[-1], k_scale,
+                                  v_scale)
     S, MB = block_tables.shape
     BS = k_pool.shape[1]
     kc = k_pool[block_tables].reshape(S, MB * BS, *k_pool.shape[2:])

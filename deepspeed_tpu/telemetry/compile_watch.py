@@ -306,8 +306,8 @@ def scopes_of(op_name: str) -> Optional[str]:
     found = []
     # where the compiler merged instructions it joined their op_names
     # with ";", the consumer first: the producer's (last) path is kept,
-    # so decode's pool ``squeeze`` stays ``kv_read`` after merging with
-    # the kernel's input reshape
+    # so a gather's ``squeeze`` stays ``kv_read`` after merging with its
+    # consumer's reshape
     for part in op_name.rsplit(";", 1)[-1].split("/")[:-1]:
         for word in _WORD.findall(part):
             if word in SCOPES and (not found or found[-1] != word):

@@ -203,26 +203,28 @@ def test_duplicate_request_id_rejected():
 def test_paged_kernel_interpret_matches_reference():
     """The Pallas paged kernel (interpret mode) against the gather
     oracle — block-table indirection, partial tail blocks, an idle
-    slot, and out-of-order block ids."""
+    slot, out-of-order block ids, and the second layer of a two-layer
+    pool."""
     from deepspeed_tpu.ops.pallas.decode_attention import (
         paged_decode_attention, paged_decode_attention_reference)
     S, H, KH, D, NB, BS, MB = 3, 8, 2, 16, 12, 32, 4
     q = jax.random.normal(jax.random.PRNGKey(0), (S, H, D), jnp.float32)
-    kp = jax.random.normal(jax.random.PRNGKey(1), (NB, BS, KH, D),
-                           jnp.float32)
-    vp = jax.random.normal(jax.random.PRNGKey(2), (NB, BS, KH, D),
-                           jnp.float32)
+    kp = jax.random.normal(jax.random.PRNGKey(1), (2, NB, BS, KH * D),
+                           jnp.float32)   # two layers, as stored
+    vp = jax.random.normal(jax.random.PRNGKey(2), (2, NB, BS, KH * D),
+                           jnp.float32)   # two layers, as stored
     bt = jnp.asarray([[3, 5, 0, 0], [1, 2, 7, 9], [11, 0, 0, 0]],
                      jnp.int32)
     lens = jnp.asarray([40, 100, 17], jnp.int32)
-    got = paged_decode_attention(q, kp, vp, bt, lens, interpret=True)
-    want = paged_decode_attention_reference(q, kp, vp, bt, lens)
+    got = paged_decode_attention(q, kp, vp, bt, lens, interpret=True,
+                                 layer=1)
+    want = paged_decode_attention_reference(q, kp[1], vp[1], bt, lens)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
     # an idle slot (length 0) must produce zeros, not NaN
     got0 = paged_decode_attention(q, kp, vp, bt,
                                   jnp.asarray([0, 100, 17], jnp.int32),
-                                  interpret=True)
+                                  interpret=True, layer=1)
     assert not np.any(np.isnan(np.asarray(got0)))
     np.testing.assert_array_equal(np.asarray(got0[0]), 0.0)
 

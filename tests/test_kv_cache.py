@@ -15,11 +15,18 @@ import pytest
 from deepspeed_tpu.inference.kv_cache import (
     BlockAllocator, advance, append_token, init_cache, init_paged_cache,
     paged_append_token, paged_gather_kv, paged_write_prompt,
-    paged_write_tokens, write_chunk, write_prompt)
+    paged_write_tokens, pool_arrays, write_chunk, write_prompt)
 
 
 def _rand(key, shape):
     return jax.random.normal(jax.random.PRNGKey(key), shape, jnp.float32)
+
+
+def _heads(cache, layer=0):
+    """One layer of the K pool, rows split into heads:
+    ``[NB, BS, KH, D]`` out of the stored ``[L, NB, BS, KH*D]``."""
+    pool = np.asarray(cache.k[layer])
+    return pool.reshape(*pool.shape[:2], cache.num_kv_heads, cache.head_dim)
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2])
@@ -105,7 +112,7 @@ def test_paged_append_isolates_idle_slots():
                           lengths=jnp.asarray([5, 0], jnp.int32))
     k = _rand(3, (2, H, D))
     cache = paged_append_token(cache, 0, k, k)
-    pool = np.asarray(cache.k[0])
+    pool = _heads(cache)
     # slot 0's token landed at block 2, offset 5
     np.testing.assert_array_equal(pool[2, 5], np.asarray(k[0]))
     # slot 1's (discarded) token landed in null block 0, nowhere else
@@ -191,7 +198,7 @@ def test_paged_write_tokens_overshoot_spills_to_null_block():
         lengths=jnp.asarray([BS * MB - 1], jnp.int32))  # one slot left
     k = _rand(1, (1, 3, H, D))
     cache = paged_write_tokens(cache, 0, k, k)
-    pool = np.asarray(cache.k[0])
+    pool = _heads(cache)
     # position 7 (last live) landed in block 5 offset 3; the two
     # overshooting positions landed in null block 0 offsets 0..1
     np.testing.assert_array_equal(pool[5, 3], np.asarray(k[0, 0]))
@@ -234,13 +241,144 @@ def test_paged_garbage_beyond_lengths_invisible_with_k_gt_1():
         for p in range(plen):
             live[bt[slot][p // BS], p % BS] = True
     garbage = np.asarray(_rand(7, cache.k.shape)) * 100.0
-    mask = live[None, :, :, None, None]
+    mask = live[None, :, :, None]
     cache_dirty = cache.replace(
         k=jnp.where(mask, cache.k, garbage),
         v=jnp.where(mask, cache.v, garbage * 2))
     logits_dirty, _ = paged_verify_step(params, cfg, toks, cache_dirty)
     np.testing.assert_array_equal(np.asarray(logits_clean),
                                   np.asarray(logits_dirty))
+
+
+# ------------------------------------------------- a layer is an offset
+# The paged kernels take the whole stacked pool and find a layer by a
+# block offset in their index map: reading the wrong layer is the new
+# way to be wrong, so every test below gives every layer its own data.
+
+def _layered_pools(L, NB, BS, KH, D, quantized):
+    """``(k, v, scales)``: pools ``[L, NB, BS, KH*D]`` as PagedKVCache
+    stores them (int8 + ``[L, NB, KH, BS]`` scale tiles when
+    ``quantized``), every layer and the null block 0 holding their own
+    random rows."""
+    from deepspeed_tpu.ops.quant_core import quantize_int8
+    out, scales = [], {}
+    for name, seed in (("k", 11), ("v", 12)):
+        pool = _rand(seed, (L, NB, BS, KH, D))
+        if quantized:
+            pool, s = quantize_int8(pool, -1)
+            scales[f"{name}_scale"] = s[..., 0].transpose(0, 1, 3, 2)
+        out.append(pool.reshape(L, NB, BS, KH * D))
+    return out[0], out[1], scales
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["fp", "int8"])
+@pytest.mark.parametrize("kind", ["decode", "verify", "chunk"])
+def test_paged_kernels_attend_their_own_layer(kind, quantized):
+    """Each paged kernel (interpret mode) over a three-layer pool whose
+    layers hold different data, every layer against the per-layer
+    oracle: block-table indirection, a table whose dead tail names the
+    null block (which holds garbage of its own), GQA grouping, an idle
+    slot."""
+    from deepspeed_tpu.ops.pallas import decode_attention as da
+    L, S, H, KH, D, NB, BS, Kq = 3, 3, 8, 2, 16, 12, 32, 4
+    k, v, scales = _layered_pools(L, NB, BS, KH, D, quantized)
+    bt = jnp.asarray([[3, 5, 0, 0], [1, 2, 7, 9], [0, 0, 0, 0]], jnp.int32)
+    lens = jnp.asarray([40, 100, 0], jnp.int32)
+    q, table, bound = {
+        "decode": (_rand(0, (S, H, D)), bt, lens),
+        "verify": (_rand(0, (S, Kq, H, D)), bt, lens),
+        "chunk": (_rand(0, (BS, H, D)), bt[1], jnp.int32(2 * BS)),
+    }[kind]
+    kernel = getattr(da, f"paged_{kind}_attention")
+    oracle = getattr(da, f"paged_{kind}_attention_reference")
+    outs = []
+    for layer in range(L):
+        got = kernel(q, k, v, table, bound, interpret=True, layer=layer,
+                     **scales)
+        want = oracle(q, k[layer], v[layer], table, bound,
+                      **{n: s[layer] for n, s in scales.items()})
+        live = slice(0, 2) if kind == "decode" else slice(None)
+        np.testing.assert_allclose(np.asarray(got[live]),
+                                   np.asarray(want[live]),
+                                   rtol=2e-5, atol=2e-5,
+                                   err_msg=f"layer {layer}")
+        outs.append(np.asarray(got))
+    # the layers' answers differ, so a wrong offset cannot pass above
+    assert not np.allclose(outs[0], outs[1], atol=1e-2)
+    assert not np.allclose(outs[1], outs[2], atol=1e-2)
+    with pytest.raises(ValueError, match="3-layer pool"):
+        kernel(q, k, v, table, bound, interpret=True, layer=L, **scales)
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["fp", "int8"])
+@pytest.mark.parametrize("kind", ["decode", "verify", "chunk"])
+def test_paged_steps_read_the_layer_they_wrote(kind, quantized, monkeypatch):
+    """Writer and reader agree on where a layer lies: a three-layer
+    model's paged steps with attention through the Pallas kernels
+    (interpret mode; the whole pool and a layer offset) against the same
+    steps through the XLA gathers (one layer cut out, as on the CPU),
+    over a pool the paged writers filled; decode also against the dense
+    cache."""
+    from deepspeed_tpu.model_implementations import transformer as tf
+    V, E, L, H, BS, MB = 64, 32, 3, 4, 16, 4
+    cfg = tf.InferenceTransformerConfig(
+        vocab_size=V, n_positions=128, n_embd=E, n_layer=L, n_head=H,
+        dtype=jnp.float32)
+    params = tf.init_params(jax.random.PRNGKey(0), cfg)
+    cache = init_paged_cache(L, 2, 10, BS, MB, cfg.kv_heads, cfg.head_dim,
+                             jnp.float32, quantized=quantized)
+    bt = np.zeros((2, MB), np.int32)
+    bt[0], bt[1] = [2, 5, 1, 0], [4, 3, 0, 0]
+    cache = cache.replace(block_tables=jnp.asarray(bt))
+    ids = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, V)
+    plens = (32, 9)
+    for slot, plen in enumerate(plens):
+        _, cache = tf.paged_prefill(params, cfg, ids[slot:slot + 1],
+                                    jnp.asarray([plen], jnp.int32), cache,
+                                    jnp.int32(slot))
+    tok = jnp.asarray([5, 9], jnp.int32)
+
+    def step(cache):
+        if kind == "decode":
+            return tf.paged_decode_step(params, cfg, tok, cache,
+                                        jnp.asarray([True, True]))[0]
+        if kind == "verify":
+            return tf.paged_verify_step(
+                params, cfg, jnp.stack([tok, tok + 1, tok + 2], 1), cache)[0]
+        return tf.paged_prefill_chunk(      # slot 0's second block again
+            params, cfg, ids[:1, BS:2 * BS], jnp.int32(BS),
+            jnp.asarray([2 * BS], jnp.int32), cache, jnp.int32(0))[0]
+
+    by_gather = np.asarray(step(cache))
+    monkeypatch.setattr(tf, "_use_decode_kernel", lambda *a: True)
+    by_kernel = np.asarray(step(cache))
+    np.testing.assert_allclose(by_kernel, by_gather, rtol=2e-4, atol=2e-4)
+    if kind == "decode" and not quantized:
+        monkeypatch.undo()
+        dense = init_cache(L, 2, 64, cfg.kv_heads, cfg.head_dim, jnp.float32)
+        _, dense = tf.prefill(params, cfg, ids,
+                              jnp.asarray(plens, jnp.int32), dense)
+        want, _ = tf.decode_step(params, cfg, tok, dense)
+        np.testing.assert_allclose(by_kernel, np.asarray(want),
+                                   rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["fp", "int8"])
+def test_pool_arrays_are_the_stored_pool(quantized):
+    """Memory accounting reads ``pool_arrays``: K and V as stored, one
+    ``[L, NB, BS, KH*D]`` array each, and an int8 pool's two scale
+    tiles."""
+    L, NB, BS, KH, D = 3, 5, 16, 2, 8
+    cache = init_paged_cache(L, 2, NB, BS, 2, KH, D, jnp.bfloat16,
+                             quantized=quantized)
+    arrays = pool_arrays(cache)
+    assert [a.shape for a in arrays[:2]] == [(L, NB, BS, KH * D)] * 2
+    assert (cache.num_kv_heads, cache.head_dim) == (KH, D)
+    assert (cache.num_layers, cache.num_blocks, cache.block_size) == (
+        L, NB, BS)
+    rows = L * NB * BS * KH
+    assert sum(a.nbytes for a in arrays) == (
+        2 * rows * (D + 4) if quantized else 2 * rows * D * 2)
 
 
 def test_block_allocator_free_list():

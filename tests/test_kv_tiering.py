@@ -78,11 +78,13 @@ def test_quant_training_alias_unchanged():
 # ----------------------------------------------------- int8 pool writers
 
 
-def _quant_pool(seed, NB, BS, KH, D):
-    """A random int8 pool + matching [NB, KH, BS] scale tiles."""
-    kp = _rand(seed, (NB, BS, KH, D))
+def _quant_pool(seed, NB, BS, KH, D, L=2):
+    """A random int8 pool of ``L`` layers as PagedKVCache stores it,
+    ``[L, NB, BS, KH*D]``, + matching ``[L, NB, KH, BS]`` scale tiles."""
+    kp = _rand(seed, (L, NB, BS, KH, D))
     q, s = quantize_int8(kp, -1)
-    return kp, q, s[..., 0].transpose(0, 2, 1)
+    return (kp, q.reshape(L, NB, BS, KH * D),
+            s[..., 0].transpose(0, 1, 3, 2))
 
 
 def test_int8_write_tokens_k1_equals_append():
@@ -205,7 +207,8 @@ def test_int8_garbage_beyond_lengths_invisible():
 def test_int8_paged_kernels_match_reference():
     """The three Pallas paged kernels (interpret mode) with VMEM
     dequant against the dequantize-then-dense oracle — block-table
-    indirection, partial tails, idle slot."""
+    indirection, partial tails, idle slot; the second layer of a
+    two-layer pool against the reference over that layer."""
     from deepspeed_tpu.ops.pallas.decode_attention import (
         paged_chunk_attention, paged_chunk_attention_reference,
         paged_decode_attention, paged_decode_attention_reference,
@@ -217,31 +220,34 @@ def test_int8_paged_kernels_match_reference():
                      jnp.int32)
     lens = jnp.asarray([40, 100, 17], jnp.int32)
     q = _rand(0, (S, H, D))
+    LAYER = 1
+    one = dict(k_scale=ks[LAYER], v_scale=vs[LAYER])   # the oracle's
     got = paged_decode_attention(q, qk, qv, bt, lens, interpret=True,
-                                 k_scale=ks, v_scale=vs)
-    want = paged_decode_attention_reference(q, qk, qv, bt, lens,
-                                            k_scale=ks, v_scale=vs)
+                                 k_scale=ks, v_scale=vs, layer=LAYER)
+    want = paged_decode_attention_reference(q, qk[LAYER], qv[LAYER], bt,
+                                            lens, **one)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
     # an idle slot (length 0) must produce zeros, not NaN
     got0 = paged_decode_attention(q, qk, qv, bt,
                                   jnp.asarray([0, 100, 17], jnp.int32),
                                   interpret=True, k_scale=ks,
-                                  v_scale=vs)
+                                  v_scale=vs, layer=LAYER)
     assert not np.any(np.isnan(np.asarray(got0)))
     np.testing.assert_array_equal(np.asarray(got0[0]), 0.0)
     qv_q = _rand(3, (S, 3, H, D))
-    gotv = paged_verify_attention(qv_q, qk, qv, bt, lens,
+    gotv = paged_verify_attention(qv_q, qk, qv, bt, lens, layer=LAYER,
                                   interpret=True, k_scale=ks, v_scale=vs)
-    wantv = paged_verify_attention_reference(qv_q, qk, qv, bt, lens,
-                                             k_scale=ks, v_scale=vs)
+    wantv = paged_verify_attention_reference(qv_q, qk[LAYER], qv[LAYER],
+                                             bt, lens, **one)
     np.testing.assert_allclose(np.asarray(gotv), np.asarray(wantv),
                                rtol=2e-5, atol=2e-5)
     qc = _rand(4, (BS, H, D))
     gotc = paged_chunk_attention(qc, qk, qv, bt[1], jnp.int32(BS),
-                                 interpret=True, k_scale=ks, v_scale=vs)
-    wantc = paged_chunk_attention_reference(qc, qk, qv, bt[1], BS,
-                                            k_scale=ks, v_scale=vs)
+                                 interpret=True, k_scale=ks, v_scale=vs,
+                                 layer=LAYER)
+    wantc = paged_chunk_attention_reference(qc, qk[LAYER], qv[LAYER],
+                                            bt[1], BS, **one)
     np.testing.assert_allclose(np.asarray(gotc), np.asarray(wantc),
                                rtol=2e-5, atol=2e-5)
 
@@ -258,7 +264,7 @@ def test_scale_mismatch_is_loud():
     _, qk, ks = _quant_pool(1, NB, BS, KH, D)
     with pytest.raises(ValueError, match="require k_scale"):
         paged_decode_attention(q, qk, qk, bt, lens, interpret=True)
-    fp = _rand(2, (NB, BS, KH, D))
+    fp = _rand(2, (2, NB, BS, KH * D))
     with pytest.raises(ValueError, match="must not pass"):
         paged_decode_attention(q, fp, fp, bt, lens, interpret=True,
                                k_scale=ks, v_scale=ks)
@@ -622,25 +628,47 @@ def test_config_validation():
     assert cfg.kv_host_blocks == 64
 
 
-def test_swap_in_roundtrip_preserves_bytes():
-    """paged_read_block → HostKVTier → paged_swap_in is byte-exact for
-    int8 pools (payload and scale tiles)."""
-    cache = init_paged_cache(2, 1, 5, 16, 2, 2, 8, jnp.float32,
-                             quantized=True)
-    k = _rand(0, (32, 2, 8))
+@pytest.mark.parametrize("quantized", [False, True], ids=["fp", "int8"])
+def test_swap_in_roundtrip_preserves_bytes(quantized):
+    """paged_read_block → HostKVTier → paged_swap_in is byte-exact
+    (payload and, for an int8 pool, scale tiles), and the host payload
+    holds, per (layer, position, head), the row that was written there:
+    the pool's layout is the payload's, a position's heads side by
+    side."""
+    L, BS, KH, D = 2, 16, 2, 8
+    cache = init_paged_cache(L, 1, 5, BS, 2, KH, D, jnp.float32,
+                             quantized=quantized)
+    rows = [_rand(layer, (2 * BS, KH, D)) for layer in range(L)]
     cache = cache.replace(
         block_tables=jnp.asarray([[1, 3]], jnp.int32))
-    cache = paged_write_prompt(cache, 0, k, k, jnp.int32(0))
-    payload = paged_read_block(cache, 3)
+    for layer, k in enumerate(rows):      # every layer its own data
+        cache = paged_write_prompt(cache, layer, k, 2 * k, jnp.int32(0))
+    payload = paged_read_block(cache, 3)  # positions BS..2*BS-1
+    for layer, k in enumerate(rows):
+        want, scale = k[BS:], None
+        if quantized:
+            want, scale = quantize_int8(want, -1)
+        np.testing.assert_array_equal(
+            payload["k"][layer].reshape(BS, KH, D), np.asarray(want))
+        if quantized:
+            np.testing.assert_array_equal(
+                payload["k_scale"][layer].T, np.asarray(scale[..., 0]))
+    assert payload["k"].nbytes == L * BS * KH * D * (1 if quantized else 4)
     # snapshot before the swap-in DONATES the cache buffers
-    golden = {f: np.asarray(getattr(cache, f)[:, 3])
-              for f in ("k", "v", "k_scale", "v_scale")}
+    golden = {f: np.asarray(getattr(cache, f)[:, 3]) for f in payload}
+    assert set(golden) == ({"k", "v", "k_scale", "v_scale"} if quantized
+                           else {"k", "v"})
     tier = HostKVTier()
     tier.put(b"h", payload)
     out = paged_swap_in(cache, 4, tier.take(b"h"))
     for field, want in golden.items():
         np.testing.assert_array_equal(
             np.asarray(getattr(out, field)[:, 4]), want, err_msg=field)
+    for layer, k in enumerate(rows):      # and reads back through a table
+        out = out.replace(block_tables=jnp.asarray([[1, 4]], jnp.int32))
+        gk, gv = paged_gather_kv(out, layer)
+        np.testing.assert_allclose(np.asarray(gk[0]), np.asarray(k),
+                                   atol=0.05 if quantized else 0)
 
 
 def test_block_transfer_traces_once_per_geometry():

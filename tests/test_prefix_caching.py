@@ -314,19 +314,20 @@ def test_config_validation():
 
 def test_paged_chunk_kernel_interpret_matches_reference():
     """The Pallas chunked-prefill kernel (interpret mode) against the
-    gather oracle — table indirection, nonzero start, GQA grouping."""
+    gather oracle — table indirection, nonzero start, GQA grouping,
+    the second layer of a two-layer pool."""
     from deepspeed_tpu.ops.pallas.decode_attention import (
         paged_chunk_attention, paged_chunk_attention_reference)
     C, H, KH, D, NB, BS = 32, 8, 2, 16, 12, 16
     q = jax.random.normal(jax.random.PRNGKey(0), (C, H, D), jnp.float32)
-    kp = jax.random.normal(jax.random.PRNGKey(1), (NB, BS, KH, D),
-                           jnp.float32)
-    vp = jax.random.normal(jax.random.PRNGKey(2), (NB, BS, KH, D),
-                           jnp.float32)
+    kp = jax.random.normal(jax.random.PRNGKey(1), (2, NB, BS, KH * D),
+                           jnp.float32)   # two layers, as stored
+    vp = jax.random.normal(jax.random.PRNGKey(2), (2, NB, BS, KH * D),
+                           jnp.float32)   # two layers, as stored
     bt = jnp.asarray([3, 5, 7, 2, 9, 0], jnp.int32)
     for start in (0, 16, 48):
         got = paged_chunk_attention(q, kp, vp, bt, jnp.int32(start),
-                                    interpret=True)
-        want = paged_chunk_attention_reference(q, kp, vp, bt, start)
+                                    interpret=True, layer=1)
+        want = paged_chunk_attention_reference(q, kp[1], vp[1], bt, start)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)

@@ -283,28 +283,30 @@ def test_spec_efficiency_fewer_steps_than_plain_decode():
 def test_paged_verify_kernel_interpret_matches_reference():
     """The Pallas batched-verify kernel (interpret mode) against the
     gather oracle — block-table indirection, per-slot lengths, partial
-    tail blocks, an idle slot, out-of-order block ids, GQA grouping."""
+    tail blocks, an idle slot, out-of-order block ids, GQA grouping, the
+    second layer of a two-layer pool."""
     from deepspeed_tpu.ops.pallas.decode_attention import (
         paged_verify_attention, paged_verify_attention_reference)
     S, Kq, H, KH, D, NB, BS = 3, 4, 8, 2, 16, 12, 32
     q = jax.random.normal(jax.random.PRNGKey(0), (S, Kq, H, D),
                           jnp.float32)
-    kp = jax.random.normal(jax.random.PRNGKey(1), (NB, BS, KH, D),
-                           jnp.float32)
-    vp = jax.random.normal(jax.random.PRNGKey(2), (NB, BS, KH, D),
-                           jnp.float32)
+    kp = jax.random.normal(jax.random.PRNGKey(1), (2, NB, BS, KH * D),
+                           jnp.float32)   # two layers, as stored
+    vp = jax.random.normal(jax.random.PRNGKey(2), (2, NB, BS, KH * D),
+                           jnp.float32)   # two layers, as stored
     bt = jnp.asarray([[3, 5, 0, 0], [1, 2, 7, 9], [11, 0, 0, 0]],
                      jnp.int32)
     lens = jnp.asarray([40, 100, 17], jnp.int32)
-    got = paged_verify_attention(q, kp, vp, bt, lens, interpret=True)
-    want = paged_verify_attention_reference(q, kp, vp, bt, lens)
+    got = paged_verify_attention(q, kp, vp, bt, lens, interpret=True,
+                                 layer=1)
+    want = paged_verify_attention_reference(q, kp[1], vp[1], bt, lens)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
     # an idle slot (length 0) attends only its own chunk: finite, and
     # the first query (bound col <= 0) sees exactly position 0
     got0 = paged_verify_attention(q, kp, vp, bt,
                                   jnp.asarray([0, 100, 17], jnp.int32),
-                                  interpret=True)
+                                  interpret=True, layer=1)
     assert not np.any(np.isnan(np.asarray(got0)))
 
 

@@ -9,7 +9,9 @@ Nothing executes: a pass says the kernel lowers and fits, never that it
 computes the right thing (the smoke's kernel-vs-reference phase does).
 """
 import functools
+import math
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -25,8 +27,10 @@ from deepspeed_tpu.ops.pallas import flash_attention as fa
 from deepspeed_tpu.ops.pallas import layer_norm as ln
 
 # GPT-2 1.3B serving geometry: 16 heads x D=128, block 128, 8 slots,
-# 1024-token context, 4-token speculation window, 256-token chunk
+# 1024-token context, 4-token speculation window, 256-token chunk; the
+# kernels attend the second layer of a two-layer pool
 S, MB, BS, NB, K, C = 8, 8, 128, 65, 4, 256
+POOL_LAYERS, LAYER = 2, 1
 BF16 = jnp.bfloat16
 
 
@@ -53,14 +57,17 @@ def chips():
 def _paged(kind, q_shape, table_shape, bound_shape, KH=16, D=128,
            int8=False):
     """One paged kernel (``decode`` / ``verify`` / ``chunk``) with its
-    operands as shapes: q, k pool, v pool, table, bound[, k_scale,
-    v_scale]. ``q_shape`` is given without its trailing ``(H, D)``."""
+    operands as shapes, the pool as ``PagedKVCache`` stores it: q, k
+    pool, v pool ``[L, NB, BS, KH*D]``, table, bound[, k_scale, v_scale
+    ``[L, NB, KH, BS]``]. ``q_shape`` is given without its trailing
+    ``(H, D)``."""
     kernel = getattr(da, f"paged_{kind}_attention")
-    pool = ((NB, BS, KH, D), jnp.int8 if int8 else BF16)
-    scales = [((NB, KH, BS), jnp.float32)] * 2 if int8 else []
+    pool = ((POOL_LAYERS, NB, BS, KH * D), jnp.int8 if int8 else BF16)
+    scales = ([((POOL_LAYERS, NB, KH, BS), jnp.float32)] * 2 if int8
+              else [])
 
     def fn(q, k, v, table, bound, *sc):
-        return kernel(q, k, v, table, bound, interpret=False,
+        return kernel(q, k, v, table, bound, interpret=False, layer=LAYER,
                       **dict(zip(("k_scale", "v_scale"), sc)))
     return fn, [((*q_shape, 16, D), BF16), pool, pool,
                 (table_shape, jnp.int32), (bound_shape, jnp.int32), *scales]
@@ -125,20 +132,64 @@ def test_kernel_compiles_for_v5e(case, chips, monkeypatch):
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one)
             for shape, dtype in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    if case.startswith("paged_"):
+        # the pool goes to the kernel as it is stored: nothing as large
+        # as one layer of K, and nothing shaped like a layer's scale
+        # tiles, is written on the way in
+        (_, NB_, BS_, W), dtype = shapes[1]
+        layer = NB_ * BS_ * W * jnp.dtype(dtype).itemsize
+        assert compiled.memory_analysis().temp_size_in_bytes < layer
+        tiles = set()
+        if len(shapes) > 5:
+            L_, _, KH_, _ = whole = shapes[-1][0]
+            tiles = {whole, whole[1:], (L_ * NB_, KH_, BS_)}
+        assert not _copies(
+            text, lambda dims, nbytes: nbytes >= layer or dims in tiles)
+
+
+# an instruction's name, output type, dims and opcode in compiled text
+_OUT = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\][^ ]* ([\w\-]+)\(")
+_ITEMSIZE = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4, "s8": 1,
+             "u8": 1, "pred": 1}
+# what moves no byte of its operand, and what updates the pool in place
+_FREE = {"parameter", "get-tuple-element", "tuple", "bitcast"}
+_IN_PLACE = {"fusion", "scatter", "dynamic-update-slice"}
+
+
+def _copies(text, is_big, pool_dims=None, kernels=()):
+    """The instructions of a compiled program whose output ``is_big(dims,
+    nbytes)`` and that are neither free (a parameter, a bitcast), a
+    kernel call, nor an in-place write of the whole pool
+    (``pool_dims``): each is a copy of a layer of the pool, or more."""
+    out = []
+    for line in text.splitlines():
+        m = _OUT.match(line)
+        if m is None or m.group(2) not in _ITEMSIZE:
+            continue
+        name, dtype, dims, op = m.groups()
+        dims = tuple(int(d) for d in dims.split(",") if d)
+        if (op in _FREE or name in kernels
+                or not is_big(dims, math.prod(dims) * _ITEMSIZE[dtype])
+                or (op in _IN_PLACE and dims == pool_dims)):
+            continue
+        out.append(f"{name} = {dtype}{list(dims)} {op}")
+    return out
 
 
 def test_kernel_maps_over_a_mesh(chips):
     """GSPMD cannot partition a Mosaic call ("wrap the call in a
     shard_map"): on four devices the paged decode kernel goes through
-    ``map_kernel`` over the kv-head axis, as tensor-parallel serving
-    lays the pool out — and the compiler must not have gathered the
-    pool to make that work."""
+    ``map_kernel`` over the kv-head axis (the major part of the pool's
+    lane dim), as tensor-parallel serving lays the pool out — and the
+    compiler must not have gathered the pool to make that work."""
     from deepspeed_tpu.utils.sharding import map_kernel
     mesh = Mesh(np.asarray(chips).reshape(1, 1, 4),
                 ("expert", "seq", "tensor"))
     fn, shapes = _paged_decode()
-    q, pool = P(None, "tensor", None), P(None, None, "tensor", None)
+    q, pool = P(None, "tensor", None), P(None, None, None, "tensor")
     specs = (q, pool, pool, P(), P())
     args = [jax.ShapeDtypeStruct(shape, dtype,
                                  sharding=NamedSharding(mesh, spec))
@@ -160,11 +211,17 @@ def test_kernel_maps_over_a_mesh(chips):
 L_NAMES = 2
 SERVE_SCOPES = {"embed", "ln", "attn_qkv", "kv_write", "attn_kernel",
                 "attn_out", "mlp", "lm_head", "sample"}
+# serve-gpt2-1.3b-batch's own geometry: 24 layers, 32 slots of 8 blocks
+# and the null block, d_model 2048 = 16 x 128, GPT-2's vocabulary
+CELL = dict(layers=24, slots=32, blocks=257, heads=16, embd=2048,
+            vocab=50257)
 
 
-def _serve_program(kind, device):
+def _serve_program(kind, device, layers=L_NAMES, slots=S, blocks=NB,
+                   heads=2, embd=256, vocab=512):
     """``(jitted program named as the server names it, its name,
-    abstract arguments)`` at d_head 128, block 128, 8 slots."""
+    abstract arguments)`` at d_head 128, block 128; 8 slots of a small
+    model unless told otherwise."""
     from deepspeed_tpu.inference.kv_cache import init_paged_cache
     from deepspeed_tpu.inference.server import ContinuousBatchingServer as Srv
     from deepspeed_tpu.model_implementations.transformer import (
@@ -172,8 +229,8 @@ def _serve_program(kind, device):
     from deepspeed_tpu.telemetry import compile_watch
     one = SingleDeviceSharding(device)
     cfg = InferenceTransformerConfig(
-        vocab_size=512, n_positions=1024, n_embd=256, n_layer=L_NAMES,
-        n_head=2, dtype=BF16)
+        vocab_size=vocab, n_positions=1024, n_embd=embd, n_layer=layers,
+        n_head=heads, dtype=BF16)
 
     def abstract(tree):
         return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
@@ -184,17 +241,18 @@ def _serve_program(kind, device):
     params = abstract(jax.eval_shape(
         lambda: init_params(jax.random.PRNGKey(0), cfg)))
     cache = abstract(jax.eval_shape(lambda: init_paged_cache(
-        L_NAMES, S, NB, BS, MB, 2, 128, BF16)))
+        layers, slots, blocks, BS, MB, heads, 128, BF16)))
     fn, name, args = {
         "decode": (Srv._decode_fn, "serve_decode",
-                   (params, arr((S,)), cache, arr((S,), jnp.bool_))),
+                   (params, arr((slots,)), cache,
+                    arr((slots,), jnp.bool_))),
         "prefill": (Srv._prefill_fn, "serve_prefill",
                     (params, arr((1, C)), arr((1,)), cache, arr(()))),
         "chunk": (Srv._chunk_fn, "serve_prefill_chunk",
                   (params, arr((1, C)), arr(()), arr((1,)), cache,
                    arr(()))),
         "verify": (Srv._verify_fn, "serve_spec_verify",
-                   (params, arr((S, K)), cache)),
+                   (params, arr((slots, K)), cache)),
     }[kind]
     prog = jax.jit(compile_watch._named(
         functools.partial(fn, cfg=cfg, mesh=None), name),
@@ -202,35 +260,48 @@ def _serve_program(kind, device):
     return prog, name, args
 
 
-@pytest.mark.parametrize("kind,kernel,extra", [
-    ("decode", "paged_decode_attention", {"kv_read"}),
-    ("prefill", "flash_attention_fwd", set()),
-    ("chunk", "paged_chunk_attention", {"kv_read"}),
-    ("verify", "paged_verify_attention", {"kv_read"}),
-])
+@pytest.mark.parametrize("kind,kernel,geometry", [
+    ("decode", "paged_decode_attention", {}),
+    ("prefill", "flash_attention_fwd", {}),
+    ("chunk", "paged_chunk_attention", {}),
+    ("verify", "paged_verify_attention", {}),
+    ("decode", "paged_decode_attention", CELL),
+], ids=["decode", "prefill", "chunk", "verify", "decode-cell"])
 def test_serving_programs_carry_their_names(chips, monkeypatch, kind,
-                                            kernel, extra):
+                                            kernel, geometry):
     """Module name, kernel name and every layer scope of a serving
-    program, read back from its compiled text."""
+    program, read back from its compiled text — and that the three
+    paged programs attend the pool where it lies: no instruction's scope
+    is ``kv_read`` (only the XLA fallback gathers cut the pool), and
+    apart from the kernel calls and the in-place writes of the pool
+    nothing writes as much as one layer's K; so the program needs no
+    temporary of that size either (the cell's 24-layer decode program
+    held 2.8 GB of them when a layer was cut out for every call)."""
     from deepspeed_tpu.telemetry import compile_watch
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(fa, "_should_interpret", lambda: False)
-    prog, name, args = _serve_program(kind, chips[0])
-    text = prog.lower(*args).compile().as_text()
+    prog, name, args = _serve_program(kind, chips[0], **geometry)
+    compiled = prog.lower(*args).compile()
+    text = compiled.as_text()
     assert f"HloModule jit_{name}" in text
     scopes, kernels = compile_watch.parse_scopes(text)
+    layers = geometry.get("layers", L_NAMES)
     assert set(kernels.values()) == {kernel}
-    assert len(kernels) == L_NAMES            # one call a layer
+    assert len(kernels) == layers             # one call a layer
     assert all(scopes[k] == "attn_kernel" for k in kernels)
     innermost = {v.rsplit("/", 1)[-1] for v in scopes.values() if v}
-    assert innermost >= SERVE_SCOPES | extra, SERVE_SCOPES - innermost
-    if "kv_read" in extra:
-        # the per-layer cut of K and V out of the pool is kv_read's: a
-        # slice of the whole pool and the squeeze the compiler merges
-        # with the kernel's input reshape
-        cuts = [k for k, v in scopes.items() if v == "kv_read"
-                and k.split(".")[0] in ("slice", "squeeze")]
-        assert len(cuts) >= 2 * L_NAMES
+    assert innermost >= SERVE_SCOPES, SERVE_SCOPES - innermost
+    if kind != "prefill":
+        assert "kv_read" not in innermost
+        pool = args[2 if kind != "chunk" else 4].k
+        layer_k = math.prod(pool.shape[1:]) * pool.dtype.itemsize
+        if not geometry:    # at the cell's widths its weights are larger
+            assert not _copies(text, lambda dims, nbytes: nbytes >= layer_k,
+                               pool_dims=pool.shape, kernels=kernels)
+        assert not _copies(
+            text, lambda dims, nbytes: dims[-2:] == pool.shape[-2:]
+            and nbytes >= layer_k, pool_dims=pool.shape)
+        assert compiled.memory_analysis().temp_size_in_bytes < layer_k
 
 
 def test_train_model_kernels_and_scopes(chips, monkeypatch):
@@ -276,8 +347,8 @@ def test_kernel_names_are_the_same_under_a_mesh(chips):
                 ("expert", "seq", "tensor"))
     fn, shapes = _paged_decode()
     hs = "tensor"
-    specs = (P(None, hs, None), P(None, None, hs, None),
-             P(None, None, hs, None), P(), P())
+    specs = (P(None, hs, None), P(None, None, None, hs),
+             P(None, None, None, hs), P(), P())
     args = [jax.ShapeDtypeStruct(shape, dtype,
                                  sharding=NamedSharding(mesh, spec))
             for (shape, dtype), spec in zip(shapes, specs)]
