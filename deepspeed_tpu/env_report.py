@@ -166,11 +166,11 @@ def telemetry_info():
             # serve_commit_lag_depth histogram records the chain depth
             # at every dispatch in this process — report its deepest
             # bucket beside the config knob when any server has run
-            blurb = (f"on by default config (pipelined dispatch, "
-                     f"lag-{icfg.max_commit_lag} host commit "
-                     f"(max_commit_lag), worker-thread publish, flush "
-                     f"on host actions — docs/serving.md 'Async "
-                     f"dispatch loop')")
+            blurb = (f"on by default config (per-step commit lag: 0 "
+                     f"when the host has a state change to make, "
+                     f"{icfg.max_commit_lag} (max_commit_lag) otherwise, "
+                     f"with worker-thread publish — docs/serving.md "
+                     f"'Async dispatch loop')")
             fam = reg.snapshot().get("serve_commit_lag_depth")
             if fam:
                 # buckets are [upper_bound, count] pairs; the deepest
@@ -185,7 +185,8 @@ def telemetry_info():
             out["serve_async_loop"] = blurb
         else:
             out["serve_async_loop"] = (
-                "off (set DeepSpeedInferenceConfig.async_loop=true)")
+                "off: every step commits at lag 0 (set "
+                "DeepSpeedInferenceConfig.async_loop=true)")
         out["serve_kv_dtype"] = (
             "int8 by default config (per-block-per-head scales, VMEM "
             "dequant in the paged kernels)"

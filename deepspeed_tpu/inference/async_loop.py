@@ -1,17 +1,19 @@
-"""Async serving loop support: in-flight step records + publish worker.
+"""Serving loop support: in-flight step records + publish worker.
 
-The pieces behind ``inference.async_loop`` (docs/serving.md "Async
+The pieces of the server's one step loop (docs/serving.md "Async
 dispatch loop") that are not scheduler policy:
 
 * :class:`InFlightStep` — the host-side record of ONE device program
-  whose results have not been fetched yet. The pipelined loop holds a
-  FIFO chain of up to ``max_commit_lag`` of them (lag-N commit; the
-  default of 1 is the original lag-1 loop): the decode path dispatches
-  step N+1 chained from step N's device-resident outputs, and only once
-  the chain is full does the host fetch + commit the OLDEST record; the
-  verify path dispatches the next round right after committing the
-  previous one (verify chains never deepen past one — proposals go
-  stale at commit boundaries). Everything commit needs later rides
+  whose results have not been fetched yet. Every decode or verify
+  program the loop dispatches becomes one, and the step's commit lag
+  says how many may outlive it: 0 where the host has a state change to
+  make (the step commits the record it just made), ``max_commit_lag``
+  otherwise — then the server holds a FIFO chain of them: the decode
+  path dispatches step N+1 chained from step N's device-resident
+  outputs and commits the OLDEST record only once the chain is deeper
+  than the lag; the verify path dispatches the next round right after
+  committing the previous one (verify chains never deepen past one —
+  proposals go stale at commit boundaries). Everything commit needs rides
   here: the output device array, the slot→state snapshot taken at
   dispatch (identity-checked at commit so a slot retired or recycled in
   between discards its in-flight garbage tokens instead of corrupting a
@@ -22,8 +24,9 @@ dispatch loop") that are not scheduler policy:
   record's ``prev_fetch`` so fetch-to-fetch latency attribution stays
   honest at any depth.
 
-* :class:`PublishWorker` — the worker thread metric publishing moves to
-  under the async loop. Commit computes every value on the owner thread
+* :class:`PublishWorker` — the worker thread a LAGGED commit's metric
+  publishing moves to (a lag-0 commit publishes inline: no device work
+  waits on it). Commit computes every value on the owner thread
   (durations come from the server's injectable clock — jobs never read
   a clock, so fake-clock chaos tests stay deterministic) and enqueues a
   closure of pure registry operations; the thread drains them off the
@@ -73,8 +76,9 @@ class InFlightStep:
 
 class PublishWorker:
     """Single daemon thread draining metric-publish closures (see
-    module doc). Thread creation is lazy: a sync-fallback server (or an
-    async server that never reaches steady state) costs nothing."""
+    module doc). Thread creation is lazy: a server that only ever
+    commits at lag 0 (``async_loop`` off, or never a steady-state step)
+    costs nothing."""
 
     def __init__(self, name: str = "serve-publish"):
         self._name = name

@@ -286,28 +286,27 @@ class DeepSpeedInferenceConfig(DeepSpeedConfigModel):
     # host-tier capacity in blocks (None = unbounded): past it the
     # OLDEST host payload drops for good, exactly like a plain eviction
     kv_host_blocks: Optional[int] = None
-    # pipelined dispatch with lag-1 host commit (docs/serving.md "Async
-    # dispatch loop"): in steady-state decode the server dispatches
-    # step N+1 from step N's device-resident outputs BEFORE fetching
-    # step N's tokens, and runs host commit (EOS/length checks,
-    # retirement, metric publishing) one step behind on the fetched
-    # lag-1 results — the device pipelines instead of idling on host
-    # work between steps. Any host-driven state change (admission,
-    # chunk scheduling, preemption, shed, cancel, deadline reap)
-    # forces a bounded pipeline flush, so the scheduler always acts on
-    # committed state; greedy output stays token-identical to the sync
-    # loop (and to one-shot generate()). False = the PR-1 synchronous
-    # loop, byte-identical to servers before this knob existed.
+    # per-step commit lag (docs/serving.md "Async dispatch loop"): a
+    # step with no host state change to make dispatches step N+1 from
+    # step N's device-resident outputs BEFORE fetching step N's
+    # tokens, and runs host commit (EOS/length checks, retirement,
+    # metric publishing) max_commit_lag steps behind — the device
+    # pipelines instead of idling on host work between steps. Any
+    # host-driven state change (admission, chunk scheduling,
+    # preemption, shed, cancel, deadline reap) runs at lag 0 behind a
+    # bounded pipeline flush, so the scheduler always acts on
+    # committed state; greedy output is token-identical at any lag
+    # (and to one-shot generate()). False = lag 0 in every step.
     async_loop: bool = True
     # async dispatch-chain depth: up to this many decode steps chain
     # device-side (each dispatched from the previous step's device-
     # resident tokens) before one host commit drains the OLDEST fetch.
-    # 1 = the lag-1 loop above, byte-identical. Deeper chains absorb
-    # more host-side commit latency per device step; every flush rule
-    # is unchanged — any host-driven state change drains the whole
-    # chain, finishes surface <= N steps late, and a slot that finished
-    # mid-chain runs <= N-1 garbage rows that commit discards by
-    # SlotState identity. Greedy output is token-identical at any depth.
+    # Deeper chains absorb more host-side commit latency per device
+    # step; every flush rule holds — a host-driven state change drains
+    # the whole chain, finishes surface <= N steps late, and a slot
+    # that finished mid-chain runs <= N-1 garbage rows that commit
+    # discards by SlotState identity. Greedy output is token-identical
+    # at any depth.
     max_commit_lag: int = 1
     # chain the NON-FINAL chunks of one prompt's chunked prefill as a
     # single device-side dispatch chain instead of one chunk (and one

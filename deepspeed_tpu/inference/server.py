@@ -534,7 +534,7 @@ class ContinuousBatchingServer:
         if self.host_tier is not None:
             # the allocator decides WHEN to tier; the server owns the
             # device arrays, so the copies are its callbacks. Both run
-            # only inside admission-time allocation — the sync body
+            # only inside admission-time allocation — a lag-0 step,
             # after any pipeline flush — so a tier copy can never race
             # an in-flight donated step. An import-only tier wires the
             # swap-in side ONLY: handoff payloads swap in on prefix
@@ -682,13 +682,13 @@ class ContinuousBatchingServer:
         self._prefilling: Deque[dict] = deque()
         self._mid_prefill: set = set()
         # ---- async dispatch loop (docs/serving.md "Async dispatch
-        # loop"): pipelined dispatch with lag-N host commit. Up to
-        # max_commit_lag decode programs chain device-side across
-        # step() calls (each dispatched from the previous step's
-        # device-resident tokens), committed FIFO; every host-driven
-        # state change flushes the whole chain first, so the scheduler
-        # only ever acts on committed state. max_commit_lag=1 is the
-        # PR-10 lag-1 loop, byte-identical.
+        # loop"): every step picks its commit lag. At max_commit_lag,
+        # that many decode programs chain device-side across step()
+        # calls (each dispatched from the previous step's
+        # device-resident tokens), committed FIFO; a step with a
+        # host-driven state change to make runs at lag 0 and flushes
+        # the whole chain first, so the scheduler only ever acts on
+        # committed state. async_loop off = lag 0 in every step.
         self._async = cfg.async_loop
         self._max_lag = max(int(cfg.max_commit_lag), 1)
         self._inflight: Deque[InFlightStep] = deque()
@@ -696,10 +696,10 @@ class ContinuousBatchingServer:
         # loop"): dispatch ALL of the head prompt's non-final chunks as
         # one device-side chain per step instead of one chunk per step
         self._prefill_chain = cfg.prefill_chain and bool(self.chunk_tokens)
-        # metric publishing rides a worker thread under the async loop
-        # (drained at every flush / drain() / stats read); built even
-        # when async is off so close()/stats stay uniform — the thread
-        # itself is lazy and never starts in sync fallback
+        # a lagged commit's metric publishing rides a worker thread
+        # (drained at every flush / drain() / stats read); the thread
+        # is lazy, so a server that only ever commits at lag 0
+        # (async_loop off) never starts it
         self._worker = PublishWorker()
         # finishes discovered by an out-of-step flush (cancel/drain
         # between steps): returned by the NEXT step() call
@@ -2177,18 +2177,18 @@ class ContinuousBatchingServer:
         finishes AND lifecycle finishes (fetch outputs via ``result`` /
         ``drain``; ``finish_reasons`` tells them apart).
 
-        With ``inference.async_loop`` (default) a steady-state step —
-        no queued work, no chunked prefill in flight, no expired
-        deadline — runs PIPELINED: the decode path dispatches step N+1
-        chained from step N's device-resident outputs before fetching
-        N, and commits the OLDEST in-flight step once the chain is
-        ``max_commit_lag`` deep (docs/serving.md "Async dispatch
-        loop"); finishes therefore surface up to ``max_commit_lag``
-        ``step()`` calls after their device step. Any step with
-        host-driven state change flushes the whole chain first and runs
-        the synchronous body below, so admission, chunk scheduling,
-        preemption, shedding, and fault injection always act on
-        committed state."""
+        Every step picks its COMMIT LAG — how many dispatched decode
+        programs may stay unfetched when it returns (docs/serving.md
+        "Async dispatch loop"). A step with a host-driven state change
+        to make (queued work, a chunked prefill in flight) or with
+        ``inference.async_loop`` off runs at lag 0: it commits whatever
+        is in flight first, so admission, chunk scheduling, preemption,
+        shedding and fault injection act on committed state, and it
+        commits the program it dispatches before it returns. Any other
+        step runs at ``max_commit_lag``: it dispatches step N+1 chained
+        from step N's device-resident outputs and commits only the
+        records beyond the lag, so finishes surface up to
+        ``max_commit_lag`` ``step()`` calls after their device step."""
         # step observatory (telemetry/step_profile.py): phase marks at
         # boundaries the loop already crosses — monotonic-clock reads
         # only, zero new device syncs; OFF = the shared no-op handle
@@ -2206,7 +2206,7 @@ class ContinuousBatchingServer:
             self.canary.tick()
         if self.alerts is not None:
             # cadence-gated like slo/capacity; sits at the top so every
-            # step shape (sync, pipelined, idle early-return) evaluates
+            # step shape (lag 0, lagged, idle early-return) evaluates
             self.alerts.maybe_evaluate()
         if self._fi is not None:
             self._fi.apply_famine(self.scheduler.allocator)
@@ -2215,32 +2215,42 @@ class ContinuousBatchingServer:
         # an out-of-step flush inside a reap-triggered cancel defers its
         # collateral finishes — fold them into THIS round's return
         self._take_deferred(finished)
-        if (self._async and not self.scheduler.queue
-                and not self._prefilling):
-            return self._step_pipelined(sp, finished)
-        if self._inflight:
-            # host-driven state change ahead (admission / chunk
-            # scheduling / preemption ladder): commit the whole
-            # in-flight chain FIRST so every decision below sees
-            # committed state
-            self._flush_pipeline(finished, sp, reason="host_action")
-        self._admit(finished, sp)
-        # degradation ladder, rung 2 (rung 1, prefix-LRU eviction,
-        # already ran inside the allocator during admission): preempt
-        # strictly-lower-priority residents for the blocked waiter,
-        # re-admitting after each victim frees its slot + blocks
-        guard = self.num_slots
-        while guard > 0 and self._preempt_for_head(finished):
-            guard -= 1
+        # this step's commit lag: 0 whenever the host has a state
+        # change to make (or async_loop is off), max_commit_lag while
+        # the only host work is committing what the device finished
+        lag = (self._max_lag if (self._async and not self.scheduler.queue
+                                 and not self._prefilling) else 0)
+        if lag == 0:
+            if self._inflight:
+                # admission / chunk scheduling / the preemption ladder
+                # ahead: commit the whole in-flight chain FIRST so
+                # every decision below sees committed state
+                self._flush_pipeline(finished, sp, reason="host_action")
             self._admit(finished, sp)
-        # tier health: sample the admission round's swap-in traffic
-        # into the thrash window (demotion/swap-in only ever runs
-        # inside the admissions above)
-        self._check_swap_thrash()
-        sp.mark("admission")
-        self._run_prefill_chunk(finished, sp)
+            # degradation ladder, rung 2 (rung 1, prefix-LRU eviction,
+            # already ran inside the allocator during admission):
+            # preempt strictly-lower-priority residents for the blocked
+            # waiter, re-admitting after each victim frees its slot +
+            # blocks
+            guard = self.num_slots
+            while guard > 0 and self._preempt_for_head(finished):
+                guard -= 1
+                self._admit(finished, sp)
+            # tier health: sample the admission round's swap-in traffic
+            # into the thrash window (demotion/swap-in only ever runs
+            # inside the admissions above)
+            self._check_swap_thrash()
+        sp.mark("admission")     # at lag > 0: the reap/shed checks above
+        self._run_prefill_chunk(finished, sp)   # none in flight at lag > 0
         sp.mark("prefill_chunk")
         if not self.scheduler.slots:
+            if self._inflight:
+                # every resident retired at the last commit; the steps
+                # dispatched beside and after that commit are pure
+                # garbage — fetch and discard them so their writes
+                # complete before any future admission reuses the
+                # released blocks
+                self._flush_pipeline(finished, sp, reason="drain_tail")
             if self.watchdog is not None:
                 # an IDLE server being polled is alive, not stalled —
                 # without this heartbeat every traffic lull longer than
@@ -2251,9 +2261,9 @@ class ContinuousBatchingServer:
             self._finish_step(sp)
             return finished
         if self.spec_tokens:
-            self._decode_speculative(finished, sp)
+            self._verify_round(finished, sp, lag)
         else:
-            self._decode_once(finished, sp)
+            self._decode_round(finished, sp, lag)
         if self.slo is not None and not self._shedding:
             # with shedding armed, _maybe_shed already refreshed the
             # monitor this step — don't pay a second registry snapshot
@@ -2301,112 +2311,82 @@ class ContinuousBatchingServer:
             self._profiler.note_fetch(t1)
         self._chunk_pending_t0 = None
 
-    def _step_pipelined(self, sp, finished: List[int]) -> List[int]:
-        """Steady-state async round: no queued work, no chunked prefill,
-        no lifecycle action — the only host work is the lag-N commit of
-        the oldest in-flight step, so the device pipelines across
-        step() calls."""
-        sp.mark("admission")      # the reap/shed/famine checks above
-        sp.mark("prefill_chunk")  # by definition: no chunk work here
-        if not self.scheduler.slots:
-            if self._inflight:
-                # every resident retired at the last commit; the steps
-                # dispatched beside and after that commit are pure
-                # garbage — fetch and discard them so their writes
-                # complete before any future admission reuses the
-                # released blocks
-                self._flush_pipeline(finished, sp, reason="drain_tail")
-            if self.watchdog is not None:
-                # an IDLE server being polled is alive, not stalled
-                self.watchdog.notify_progress()
-            self._finish_step(sp)
-            return finished
-        if self.spec_tokens:
-            self._pipelined_verify(finished, sp)
-        else:
-            self._pipelined_decode(finished, sp)
-        if self.slo is not None and not self._shedding:
-            self.slo.maybe_evaluate()
-        if self._capacity is not None:
-            self._capacity.maybe_evaluate()
-        sp.mark("publish")
-        self._finish_step(sp)
-        return finished
+    def _decode_round(self, finished: List[int], sp, lag: int) -> None:
+        """One decode program for all active resident slots, committed
+        ``lag`` steps late: dispatch, append the record to the in-flight
+        chain, then commit the OLDEST record while the chain is deeper
+        than ``lag``.
 
-    def _pipelined_decode(self, finished: List[int], sp) -> None:
-        """THE tentpole mechanism: dispatch decode step N+1 BEFORE
-        fetching step N. Step N's greedy outputs are already a device
-        array, so N+1's inputs chain from them with no host round trip
-        (tokens feed back directly; lengths advanced in-graph by
-        ``paged_decode_step``; the cache is the donated thread) — JAX
-        async dispatch then overlaps N's device compute with the lag-1
-        host commit of N-1 for free. A slot that turns out to have
-        finished at step N already ran one garbage row in step N+1:
-        commit discards it by state identity (advance-only rollback —
-        the retire path reset its lengths/table, so the garbage KV sits
-        masked in released blocks no one can reuse before the next
-        flush fetches N+1).
-
-        With ``max_commit_lag`` N > 1 the dispatches CHAIN: each step
-        dispatches from the newest in-flight record's tokens and only
-        once the chain holds more than N programs does the oldest
-        commit — the host runs N steps behind the device, absorbing N
-        commits' worth of host latency into one device-busy window. A
-        slot that finished mid-chain runs <= N-1 garbage rows, each
-        discarded at its own commit by the same identity check."""
+        Lag 0 (the chain is empty on entry — ``step`` flushed it) builds
+        the inputs on the host and commits the step it just dispatched.
+        Lag N dispatches step N+1 BEFORE fetching step N: N's greedy
+        outputs are already a device array, so N+1's inputs chain from
+        them with no host round trip (tokens feed back directly; lengths
+        advanced in-graph by ``paged_decode_step``; the cache is the
+        donated thread) and JAX async dispatch overlaps the device's
+        compute with the host's commit of the record N steps back. A
+        slot that turns out to have finished at step N already ran <= N
+        garbage rows in the steps chained after it: each commit discards
+        its row by state identity (advance-only rollback — the retire
+        path reset its lengths/table, so the garbage KV sits masked in
+        released blocks no one can reuse before the next flush fetches
+        the chain)."""
         chain = self._inflight
         rec = chain[-1] if chain else None
         S = self.num_slots
+        tokens = np.zeros((S,), np.int32)
         active = np.zeros((S,), bool)
         states: Dict[int, object] = {}
         for slot, state in self.scheduler.slots.items():
             if slot in self._mid_prefill:
-                continue   # unreachable here (chunks force sync steps)
+                continue   # resident but still prefilling: not decoded
+            tokens[slot] = state.pending
             active[slot] = True
             states[slot] = state
         if not states:
+            # every resident slot is mid-prefill — the chunk above was
+            # this step's progress; nothing to decode yet
             sp.mark("propose")
             return
         self.profiler_capture.step_begin()
-        if rec is None:
-            # pipeline start: host-built inputs (identical to the sync
-            # path), dispatched WITHOUT a fetch — the lag begins here
-            tokens = np.zeros((S,), np.int32)
-            for slot, state in states.items():
-                tokens[slot] = state.pending
-            tok_in = jnp.asarray(tokens)
-        else:
-            tok_in = rec.tokens    # device-side token feedback
         t0 = self._clock()
-        # device-credit window: with a step already in flight the device
-        # verifiably has work for this WHOLE step (N runs until its
-        # fetch, N+1 from before that fetch onward); a pipeline start is
-        # busy from its own dispatch to the step's end
-        sp.pipelined(since=None if rec is not None else t0)
+        if lag:
+            # device-credit window: with a step already in flight the
+            # device verifiably has work for this WHOLE step (N runs
+            # until its fetch, N+1 from before that fetch onward); a
+            # chain's first dispatch is busy from t0 to the step's end
+            sp.pipelined(since=None if rec is not None else t0)
+        else:
+            # any deferred chunk span closes HERE: the device was busy
+            # with the chunk from its dispatch until (at least) this
+            # boundary, and the decode's own dispatch/sync_wait slivers
+            # cover the rest — adjacent windows, no double count
+            self._realize_chunk_span(sp, t0)
+        # the propose phase ends HERE and the decode program dispatches:
+        # the dispatch-gap detector measures this boundary against the
+        # last fetch that drained the device
         sp.mark("propose", now=t0, dispatch=True)
         nxt, self._cache = self._decode_jit(
-            self.engine.params, tok_in, self._cache, jnp.asarray(active))
+            self.engine.params,
+            # an empty chain starts from the host's pending tokens, a
+            # live one from the newest record's device-resident outputs
+            jnp.asarray(tokens) if rec is None else rec.tokens,
+            self._cache, jnp.asarray(active))
         sp.mark("dispatch", program=self._decode_jit.name)
         chain.append(InFlightStep("decode", nxt, states, t0))
-        if rec is None:
-            self._async_stats["pipeline_starts"] += 1
-            sp.mark("sync_wait")
-            sp.mark("commit")
-            if self.watchdog is not None:
-                self.watchdog.notify_progress()   # a dispatch IS progress
-        elif len(chain) > self._max_lag:
-            # the chain is full: drain the OLDEST fetch (lag-N commit)
-            # and rethread the new-oldest record's latency baseline to
-            # this fetch, so its eventual fetch-to-fetch dt stays honest
-            oldest = chain.popleft()
-            t1 = self._commit_decode_record(oldest, finished, sp)
-            chain[0].prev_fetch = t1
-            self._async_stats["pipelined_steps"] += 1
+        if lag:
+            self._async_stats["pipeline_starts" if rec is None
+                              else "pipelined_steps"] += 1
+        if len(chain) > lag:
+            # commit the OLDEST record and rethread the new-oldest one's
+            # latency baseline to this fetch, so its eventual
+            # fetch-to-fetch dt stays honest
+            t1 = self._commit_decode_record(chain.popleft(), finished, sp,
+                                            lagged=bool(lag))
+            if chain:
+                chain[0].prev_fetch = t1
         else:
-            # deepening the chain (depth < max_commit_lag): dispatch
-            # only — no fetch, no commit this step. The profiler's
-            # depth histogram records the dispatch-into-busy-device
-            self._async_stats["pipelined_steps"] += 1
+            # deepening the chain: dispatch only — no fetch, no commit
             sp.mark("sync_wait")
             sp.mark("commit")
             if self.watchdog is not None:
@@ -2415,18 +2395,24 @@ class ContinuousBatchingServer:
 
     def _commit_decode_record(self, rec: InFlightStep,
                               finished: List[int], sp=NULL_STEP_HANDLE,
-                              discard_rid: Optional[int] = None) -> float:
-        """Lag-N host commit of one in-flight decode step (the chain's
+                              discard_rid: Optional[int] = None,
+                              lagged: bool = True) -> float:
+        """Host commit of one in-flight decode step (the chain's
         oldest): fetch its tokens, append/EOS-check/retire for every
         slot whose SlotState is still the one that was resident at
-        dispatch, and hand the metric publishing to the worker thread.
-        ``discard_rid`` drops
-        one request's token on the floor (cancel/deadline teardown in
-        progress: the caller observed the committed boundary, and the
-        slot's arrays are about to be reset anyway). Returns the fetch
-        timestamp."""
+        dispatch, and publish the step's metrics. ``lagged`` is false
+        for a record committed by the lag-0 step that dispatched it:
+        nothing ran after it, so the latent model's counters come over
+        in the same fetch and the metrics publish inline; a lagged
+        record's pool has been donated onward, and its publishing rides
+        the worker thread. ``discard_rid`` drops one request's token on
+        the floor (cancel/deadline teardown in progress: the caller
+        observed the committed boundary, and the slot's arrays are
+        about to be reset anyway). Returns the fetch timestamp."""
         in_step = sp is not NULL_STEP_HANDLE
-        nxt = np.asarray(rec.tokens)         # host sync: the lagged fetch
+        # host sync: the step completed
+        nxt = (np.asarray(rec.tokens) if lagged
+               else self._fetch_tokens(rec.tokens))
         t1 = self._clock()
         if in_step:
             sp.mark("sync_wait", now=t1, fetch=True,
@@ -2443,8 +2429,8 @@ class ContinuousBatchingServer:
             # injected latency is ACCOUNTED, never slept (see step())
             dt += self._fi.step_latency()
         n_live = 0
-        # insertion order (scheduler.slots iteration at dispatch) —
-        # deterministic, and commit order matches the sync loop's
+        # insertion order (scheduler.slots iteration at dispatch):
+        # deterministic at any lag
         for slot, state in rec.states.items():
             if self.scheduler.slots.get(slot) is not state:
                 # retired / torn down after this step dispatched: the
@@ -2457,34 +2443,47 @@ class ContinuousBatchingServer:
                 self._async_stats["discarded_tokens"] += 1
                 continue
             n_live += 1
-            self._commit_slot_token(slot, state, int(nxt[slot]),
-                                    finished)
+            tok = int(nxt[slot])
+            state.generated.append(tok)
+            if self._ledger is not None:
+                self._ledger.add_weight(state.request.request_id, 1)
+            if self.tracer is not None:
+                rt = self._rt.get(state.request.request_id)
+                if rt is not None and rt.decode is not None:
+                    rt.steps += 1
+                    rt.tokens += 1
+            if self._finished(state, tok):
+                self._retire(slot, state, finished)
+            else:
+                state.pending = tok
         if in_step:
             sp.mark("commit")
         if n_live == 0:
             # pure garbage (every slot vanished between dispatch and
             # commit): the device step ran but served nothing — not a
-            # decode step in any accounting the sync loop would count
+            # decode step in any accounting
             self._async_stats["garbage_steps"] += 1
             return t1
         self._step_clock += 1
         self._active_slot_steps += n_live
-        self._queue_publish("decode", dt, n_live,
-                            n_live / self.num_slots)
+        # every live slot committed one token this step, each costing
+        # one step of wall time — THE per-token serving latency
+        self._publish(self._publish_decode_step, lagged, dt, n_live,
+                      n_live / self.num_slots)
         if self.watchdog is not None:
             self.watchdog.notify_progress()
         if self._step_clock % self._EVENT_EVERY == 1:
             get_event_ring().record(
                 telemetry_events.STEP_END, source="serve_decode",
                 step=self._step_clock, live=n_live,
-                seconds=round(dt, 6), pipelined=True,
+                seconds=round(dt, 6), pipelined=lagged,
                 sampled_every=self._EVENT_EVERY)
         return t1
 
     def _publish_decode_step(self, dt: float, n_live: int,
                              occ: float) -> None:
-        """Worker-thread metric publish for one committed decode step
-        (values computed on the owner thread — the worker never reads a
+        """Metric publish for one committed decode step (values computed
+        on the owner thread — run on the worker, this never reads a
         clock or scheduler state)."""
         self._h_decode_step.observe(dt)
         self._h_token.observe(dt)
@@ -2492,14 +2491,19 @@ class ContinuousBatchingServer:
         self._c_tokens.inc(n_live)
         self._g_occupancy.set(occ)
 
-    def _pipelined_verify(self, finished: List[int], sp) -> None:
-        """Async speculation round: commit the in-flight verify, then
-        propose + dispatch the NEXT one and return with it in flight —
-        its device compute overlaps the publish work (worker thread),
-        the inter-step host time, and the next round's checks.
+    def _verify_round(self, finished: List[int], sp, lag: int) -> None:
+        """One speculative round for all active resident slots: each
+        slot proposes up to K-1 tokens, ONE batched verify forward
+        scores every slot's ``[pending, p_1..p_{K-1}]`` chunk through
+        the block tables, and :meth:`_commit_verify_record` commits the
+        accepted prefix — 1..K tokens per slot per round. At lag 0 the
+        round commits before it returns; at any lag > 0 it returns with
+        the verify in flight (its device compute overlaps the publish
+        work on the worker thread, the inter-step host time and the
+        next round's checks) and the NEXT round commits it first.
 
         The verify path deliberately commits BEFORE dispatching (the
-        opposite ordering from :meth:`_pipelined_decode`): prompt-lookup
+        opposite ordering from :meth:`_decode_round`): prompt-lookup
         proposals are a host data structure over the *committed*
         history, so chaining N+1's inputs from N's un-fetched outputs
         would mean proposing from a history K tokens stale — acceptance
@@ -2514,20 +2518,23 @@ class ContinuousBatchingServer:
         ``max_commit_lag`` (draft-model proposals would go equally
         stale: the draft pool only advances at commit).
 
-        With a draft engine the proposals come from
+        Proposals come from prompt lookup over the slot's own committed
+        history or, with a draft engine, from
         ``speculation.draft_propose`` — K chained draft decode
-        forwards, all device-resident — instead of the LookupIndex,
-        and the [S, K] token block is built by device concatenation.
-        Same aval, SAME verify executable."""
+        forwards over the mirrored draft pool, all device-resident, the
+        [S, K] token block built by device concatenation. Same aval,
+        SAME verify executable, and greedy acceptance keeps the output
+        exactly greedy either way."""
         chain = self._inflight
-        rec = chain[-1] if chain else None
         prev_fetch = None
-        # device credit in this round rides explicit spans ([step begin
-        # → fetch] at commit, [dispatch → step end] via pipelined())
-        sp.pipelined_mode()
-        if rec is not None:
-            prev_fetch = self._commit_verify_record(rec, finished, sp)
-            chain.clear()          # verify chains are depth <= 1
+        if lag:
+            # device credit in this round rides explicit spans ([step
+            # begin → fetch] at commit, [dispatch → step end] via
+            # pipelined())
+            sp.pipelined_mode()
+        if chain:
+            prev_fetch = self._commit_verify_record(
+                chain.popleft(), finished, sp)     # depth <= 1
             self._async_stats["pipelined_steps"] += 1
         K = self.spec_tokens
         S = self.num_slots
@@ -2537,11 +2544,15 @@ class ContinuousBatchingServer:
         states: Dict[int, object] = {}
         for slot, state in self.scheduler.slots.items():
             if slot in self._mid_prefill:
-                continue   # unreachable here (chunks force sync steps)
+                continue   # resident but still prefilling: not decoded
             if not use_draft:
-                # proposal source = committed history ONLY (see
-                # _decode_speculative — this is the same incremental
-                # LookupIndex discipline)
+                # proposal source = committed history ONLY (prompt +
+                # every generated token incl. pending) — never the
+                # speculative garbage beyond it, so a preempted slot's
+                # requeue prompt (prompt + committed) replays the same
+                # proposals. The LookupIndex makes this O(1) per step:
+                # full build at the slot's first verify, tail-sync
+                # after.
                 entry = self._spec_hist.get(slot)
                 if entry is None or entry[0] is not state:
                     idx = LookupIndex(state.request.prompt)
@@ -2559,43 +2570,55 @@ class ContinuousBatchingServer:
             tokens[slot, 0] = state.pending
             states[slot] = state
         if not states:
-            # the commit above retired every resident — nothing to
-            # dispatch; the caller's live=False finish resets the gap
+            # every resident is mid-prefill, or the commit above retired
+            # them all — nothing to dispatch (the caller's live=False
+            # finish resets the gap)
             sp.mark("propose")
             return
         self.profiler_capture.step_begin()
         t0 = self._clock()
+        if not lag:
+            self._realize_chunk_span(sp, t0)   # see _decode_round
+        # proposal scan ends, the batched verify dispatches (the
+        # dispatch-gap boundary — see _decode_round)
         sp.mark("propose", now=t0, dispatch=True)
         if use_draft:
-            tok_arg, d_props = self._draft_propose(states)
+            tok_arg, props = self._draft_propose(states)
         else:
-            tok_arg, d_props = jnp.asarray(tokens), None
+            tok_arg = jnp.asarray(tokens)
         t_toks, self._cache = self._verify_jit(
             self.engine.params, tok_arg, self._cache)
         sp.mark("dispatch", program=self._verify_jit.name)
+        rec = InFlightStep("verify", t_toks, states, t0, props=props,
+                           prev_fetch=prev_fetch)
+        if lag:
+            if prev_fetch is None:      # nothing was in flight: a start
+                self._async_stats["pipeline_starts"] += 1
+                if self.watchdog is not None:
+                    self.watchdog.notify_progress()  # a dispatch IS progress
+            # device busy from this dispatch through the step's end (the
+            # [step-begin → fetch] half was credited at commit)
+            sp.pipelined(since=t0)
+            chain.append(rec)
+        else:
+            self._commit_verify_record(rec, finished, sp, lagged=False)
         self.profiler_capture.step_end()
-        if rec is None:
-            self._async_stats["pipeline_starts"] += 1
-            if self.watchdog is not None:
-                self.watchdog.notify_progress()   # a dispatch IS progress
-        # device busy from this dispatch through the step's end (the
-        # [step-begin → fetch] half was credited at commit)
-        sp.pipelined(since=t0)
-        chain.append(InFlightStep(
-            "verify", t_toks, states, t0,
-            props=d_props if use_draft else props,
-            prev_fetch=prev_fetch))
 
     def _commit_verify_record(self, rec: InFlightStep,
                               finished: List[int], sp=NULL_STEP_HANDLE,
-                              discard_rid: Optional[int] = None) -> float:
-        """Commit one in-flight verify round: fetch the target argmaxes,
+                              discard_rid: Optional[int] = None,
+                              lagged: bool = True) -> float:
+        """Commit one verify round: fetch the target argmaxes,
         greedy-accept against the proposals the round was scored with,
         append/EOS-check/retire per surviving slot, advance lengths over
-        the accepted prefixes in ONE vectorized update, and hand metric
-        publishing to the worker. Mirrors ``_decode_speculative``'s
-        post-fetch half exactly (same helpers, same order) so the sync
-        and async commit paths cannot drift."""
+        the accepted prefixes in ONE vectorized update, and publish the
+        round's metrics (inline for the lag-0 round that just dispatched
+        it, through the worker for a ``lagged`` one). The verify wrote K
+        candidate positions past each slot's live length without
+        advancing it; commit = advance the length over the accepted
+        prefix only, so rejected KV is never rolled back, just left as
+        masked garbage the next round overwrites (the
+        garbage-beyond-lengths invariant)."""
         in_step = sp is not NULL_STEP_HANDLE
         K = self.spec_tokens
         S = self.num_slots
@@ -2615,8 +2638,9 @@ class ContinuousBatchingServer:
             # noted when the round left the host.
             sp.device_interval(0.0, t1, note_dispatch=False)
         elif in_step:
-            # flush inside a sync action step: the plain fetch-wait
-            # attribution (mode off — the sliver credit IS the span)
+            # a lag-0 step (its own round, or the flush ahead of a host
+            # action): the plain fetch-wait attribution (mode off — the
+            # sliver credit IS the span)
             sp.mark("sync_wait", now=t1, fetch=True,
                     program=self._verify_jit.name)
         elif self._profiler is not None:
@@ -2659,7 +2683,16 @@ class ContinuousBatchingServer:
                     done = True
                     break
             committed_total += n_committed
+            # collected PER SLOT-FORWARD (not a cross-slot step mean):
+            # the histogram's distribution must expose per-slot
+            # acceptance skew — one lookup-friendly request carrying an
+            # otherwise-collapsed batch shows as {K, 1, 1, 1}, not 1.75
             per_slot_commits.append(n_committed)
+            # a continuing slot's cache gains [pending, p_1..p_m]; the
+            # correction becomes the next pending (its KV, like any
+            # pending token's, is written by the NEXT verify). A
+            # retiring slot's lengths are reset right below, so its
+            # adv value never matters.
             adv[slot] = n_committed
             if self._ledger is not None:
                 rid_ = state.request.request_id
@@ -2705,8 +2738,11 @@ class ContinuousBatchingServer:
         self._spec_steps += 1
         self._spec_slot_steps += n_live
         self._maybe_spec_collapse(proposed, accepted_total)
-        self._queue_publish("verify", dt, n_live, committed_total,
-                            proposed, accepted_total, per_slot_commits)
+        # per-token latency keeps meaning "wall per committed token per
+        # slot" under speculation
+        self._publish(self._publish_verify_step, lagged, dt, n_live,
+                      committed_total, proposed, accepted_total,
+                      per_slot_commits)
         if self.watchdog is not None:
             self.watchdog.notify_progress()
         if self._step_clock % self._EVENT_EVERY == 1:
@@ -2714,7 +2750,7 @@ class ContinuousBatchingServer:
                 telemetry_events.STEP_END, source="serve_spec_verify",
                 step=self._step_clock, live=n_live,
                 committed=committed_total, accepted=accepted_total,
-                seconds=round(dt, 6), pipelined=True,
+                seconds=round(dt, 6), pipelined=lagged,
                 sampled_every=self._EVENT_EVERY)
         return t1
 
@@ -2722,8 +2758,8 @@ class ContinuousBatchingServer:
                              committed_total: int, proposed: int,
                              accepted: int,
                              per_slot_commits: List[int]) -> None:
-        """Worker-thread metric publish for one committed verify round
-        (same instruments and semantics as the sync path)."""
+        """Metric publish for one committed verify round (see
+        :meth:`_publish_decode_step`)."""
         self._h_decode_step.observe(dt)
         self._h_token.observe(dt * n_live / max(committed_total, 1))
         self._c_decode_steps.inc()
@@ -2737,8 +2773,14 @@ class ContinuousBatchingServer:
     # one worker job per this many buffered step records (see _pub_buf)
     _PUBLISH_BATCH = 16
 
-    def _queue_publish(self, kind: str, *vals) -> None:
-        self._pub_buf.append((kind, vals))
+    def _publish(self, publisher, lagged: bool, *vals) -> None:
+        """One committed step's metrics: inline for a lag-0 commit,
+        buffered for the worker thread for a lagged one (the device is
+        waiting on this thread's next dispatch)."""
+        if not lagged:
+            publisher(*vals)
+            return
+        self._pub_buf.append((publisher, vals))
         if len(self._pub_buf) >= self._PUBLISH_BATCH:
             self._ship_publish_buf()
 
@@ -2749,11 +2791,8 @@ class ContinuousBatchingServer:
         buf, self._pub_buf = self._pub_buf, []
 
         def job():
-            for kind, vals in buf:
-                if kind == "decode":
-                    self._publish_decode_step(*vals)
-                else:
-                    self._publish_verify_step(*vals)
+            for publisher, vals in buf:
+                publisher(*vals)
 
         self._worker.submit(job)
 
@@ -2801,74 +2840,9 @@ class ContinuousBatchingServer:
                     attrs={"reason": reason, "programs": depth})
         self._drain_publishing()
 
-    def _decode_once(self, finished: List[int],
-                     sp=NULL_STEP_HANDLE) -> None:
-        """One plain decode step for all active resident slots — the
-        speculation-off hot path, byte-identical to a server without
-        the speculative layer."""
-        tokens = np.zeros((self.num_slots,), np.int32)
-        active = np.zeros((self.num_slots,), bool)
-        for slot, state in self.scheduler.slots.items():
-            if slot in self._mid_prefill:
-                continue   # resident but still prefilling: not decoded
-            tokens[slot] = state.pending
-            active[slot] = True
-        if not active.any():
-            # every resident slot is mid-prefill — the chunk above was
-            # this step's progress; nothing to decode yet
-            sp.mark("propose")
-            return
-        self.profiler_capture.step_begin()
-        t0 = self._clock()
-        # any deferred chunk span closes HERE: the device was busy with
-        # the chunk from its dispatch until (at least) this boundary,
-        # and the decode's own dispatch/sync_wait slivers cover the rest
-        # — adjacent windows, no double count
-        self._realize_chunk_span(sp, t0)
-        # the propose phase ends HERE and the decode program dispatches:
-        # the dispatch-gap detector measures this boundary against the
-        # previous fetch (how long the device sat idle on host work)
-        sp.mark("propose", now=t0, dispatch=True)
-        nxt, self._cache = self._decode_jit(
-            self.engine.params, jnp.asarray(tokens), self._cache,
-            jnp.asarray(active))
-        sp.mark("dispatch", program=self._decode_jit.name)
-        self._step_clock += 1
-        n_active = int(active.sum())
-        self._active_slot_steps += n_active
-        nxt = self._fetch_tokens(nxt)     # host sync: the step completed
-        t1 = self._clock()
-        dt = t1 - t0
-        sp.mark("sync_wait", now=t1, fetch=True,
-                program=self._decode_jit.name)
-        if self._fi is not None:
-            # injected latency is ACCOUNTED, never slept — the SLO /
-            # shedding chaos tests collapse latency with no real delay
-            dt += self._fi.step_latency()
-        self.profiler_capture.step_end()
-        # the shared publish body (inline here — the sync loop has no
-        # worker): every live slot committed one token this step, each
-        # costing one step of wall time — THE per-token serving latency
-        self._publish_decode_step(dt, n_active, n_active / self.num_slots)
-        if self.watchdog is not None:
-            self.watchdog.notify_progress()
-        if self._step_clock % self._EVENT_EVERY == 1:
-            get_event_ring().record(
-                telemetry_events.STEP_END, source="serve_decode",
-                step=self._step_clock, live=n_active,
-                seconds=round(dt, 6),
-                sampled_every=self._EVENT_EVERY)
-        sp.mark("publish")
-        for slot in list(self.scheduler.slots):   # _retire mutates
-            if slot in self._mid_prefill:
-                continue   # not decoded this step; nothing to commit
-            state = self.scheduler.slots[slot]
-            self._commit_slot_token(slot, state, int(nxt[slot]),
-                                    finished)
-        sp.mark("commit")
-
     def _fetch_tokens(self, tokens) -> np.ndarray:
-        """The synchronous loop's fetch of a program's sampled tokens.
+        """Fetch of a program's sampled tokens where no later program
+        is in flight (a monolithic prefill, a lag-0 decode step).
         A latent-attention model's programs count on the device as
         they run (``cache.aux``, the model's own, cumulative); the array
         comes over in the same ``device_get`` as the tokens, after the
@@ -2892,196 +2866,6 @@ class ContinuousBatchingServer:
         self._aux_seen = aux
         for p, col in zip(*np.nonzero(grew)):
             self._aux_series[p][col].inc(float(grew[p, col]))
-
-    def _commit_slot_token(self, slot: int, state, tok: int,
-                           finished: List[int]) -> None:
-        """Commit ONE decode token for one slot — append, trace bump,
-        EOS/budget check, retire-or-continue. THE shared per-slot
-        commit body: the sync loop and the async lag-1 commit both
-        route through it, so finish semantics and token accounting
-        cannot drift between the paths (the byte-identical
-        sync-fallback oracle depends on exactly this)."""
-        state.generated.append(tok)
-        if self._ledger is not None:
-            self._ledger.add_weight(state.request.request_id, 1)
-        if self.tracer is not None:
-            rt = self._rt.get(state.request.request_id)
-            if rt is not None and rt.decode is not None:
-                rt.steps += 1
-                rt.tokens += 1
-        if self._finished(state, tok):
-            self._retire(slot, state, finished)
-        else:
-            state.pending = tok
-
-    def _decode_speculative(self, finished: List[int],
-                            sp=NULL_STEP_HANDLE) -> None:
-        """One speculative round for all active resident slots: each
-        slot proposes up to K-1 tokens by prompt lookup over its own
-        committed history (prompt + generated, the pending token
-        included), ONE batched verify forward scores every slot's
-        ``[pending, p_1..p_{K-1}]`` chunk through the block tables, and
-        the accepted prefix commits host-side — 1..K tokens per slot
-        per step. The verify writes K candidate positions past each
-        slot's live length without advancing it; commit = advance the
-        length over the accepted prefix only, so rejected KV is never
-        rolled back, just left as masked garbage the next round
-        overwrites (the garbage-beyond-lengths invariant).
-
-        With a draft engine, proposals come from K-1 chained draft
-        decode forwards over the mirrored draft pool instead of the
-        lookup — same ``[S, K]`` verify input aval, SAME verify
-        executable, and greedy acceptance keeps the output exactly
-        greedy either way."""
-        K = self.spec_tokens
-        S = self.num_slots
-        use_draft = self.draft is not None
-        tokens = np.zeros((S, K), np.int32)
-        props: Dict[int, List[int]] = {}
-        active_slots: List[int] = []
-        for slot, state in self.scheduler.slots.items():
-            if slot in self._mid_prefill:
-                continue   # resident but still prefilling: not decoded
-            if not use_draft:
-                # proposal source = committed history ONLY (prompt +
-                # every generated token incl. pending) — never the
-                # speculative garbage beyond it, so a preempted slot's
-                # requeue prompt (prompt + committed) replays the same
-                # proposals. The LookupIndex makes this O(1) per step:
-                # full build at the slot's first verify, tail-sync
-                # after.
-                entry = self._spec_hist.get(slot)
-                if entry is None or entry[0] is not state:
-                    idx = LookupIndex(state.request.prompt)
-                    idx.extend(state.generated)
-                    self._spec_hist[slot] = (state, idx)
-                else:
-                    idx = entry[1]
-                    grown = (len(state.request.prompt)
-                             + len(state.generated) - len(idx.hist))
-                    if grown > 0:
-                        idx.extend(state.generated[-grown:])
-                prop = idx.proposals(K - 1)
-                tokens[slot, 1:] = prop
-                props[slot] = prop
-            tokens[slot, 0] = state.pending
-            active_slots.append(slot)
-        if not active_slots:
-            sp.mark("propose")
-            return
-        n_active = len(active_slots)
-        self.profiler_capture.step_begin()
-        t0 = self._clock()
-        self._realize_chunk_span(sp, t0)   # see _decode_once
-        # proposal scan ends, the batched verify dispatches (the
-        # dispatch-gap boundary — see _decode_once)
-        sp.mark("propose", now=t0, dispatch=True)
-        if use_draft:
-            tok_arg, d_props = self._draft_propose(
-                {slot: self.scheduler.slots[slot]
-                 for slot in active_slots})
-        else:
-            tok_arg, d_props = jnp.asarray(tokens), None
-        t_toks, self._cache = self._verify_jit(
-            self.engine.params, tok_arg, self._cache)
-        sp.mark("dispatch", program=self._verify_jit.name)
-        self._step_clock += 1
-        self._active_slot_steps += n_active
-        t_np = np.asarray(t_toks)         # host sync: the verify ran
-        if use_draft:
-            props_np = np.asarray(d_props)
-        t1 = self._clock()
-        dt = t1 - t0
-        sp.mark("sync_wait", now=t1, fetch=True,
-                program=self._verify_jit.name)
-        if self._fi is not None:
-            # injected latency is ACCOUNTED, never slept (see step())
-            dt += self._fi.step_latency()
-        self.profiler_capture.step_end()
-        # accept + commit, host-side (the scheduler lives here anyway):
-        # greedy acceptance against the verify argmaxes, per-token EOS/
-        # budget bookkeeping, ONE vectorized length advance at the end
-        adv = np.zeros((S,), np.int32)
-        committed_total = 0
-        accepted_total = 0
-        per_slot_commits: List[int] = []
-        retire: List[int] = []
-        for slot in active_slots:
-            state = self.scheduler.slots[slot]
-            m, committed = greedy_accept_host(
-                t_np[slot], props_np[slot] if use_draft else props[slot])
-            accepted_total += m
-            rt = (self._rt.get(state.request.request_id)
-                  if self.tracer is not None else None)
-            if rt is not None and rt.decode is not None:
-                rt.steps += 1
-            done = False
-            n_committed = 0
-            for tok in committed:
-                state.generated.append(tok)
-                n_committed += 1
-                if rt is not None and rt.decode is not None:
-                    rt.tokens += 1
-                if self._finished(state, tok):
-                    done = True
-                    break
-            committed_total += n_committed
-            # collected PER SLOT-FORWARD (not a cross-slot step mean):
-            # the histogram's distribution must expose per-slot
-            # acceptance skew — one lookup-friendly request carrying an
-            # otherwise-collapsed batch shows as {K, 1, 1, 1}, not 1.75
-            per_slot_commits.append(n_committed)
-            # a continuing slot's cache gains [pending, p_1..p_m]; the
-            # correction becomes the next pending (its KV, like any
-            # pending token's, is written by the NEXT verify). A
-            # retiring slot's lengths are reset right below, so its
-            # adv value never matters.
-            adv[slot] = n_committed
-            if self._ledger is not None:
-                rid_ = state.request.request_id
-                self._ledger.add_weight(rid_, n_committed)
-                self._ledger.note_spec(rid_, K - 1, m)
-            if done:
-                retire.append(slot)
-            else:
-                state.pending = committed[-1]
-        self._cache = self._cache.replace(
-            lengths=self._cache.lengths + jnp.asarray(adv))
-        if use_draft:
-            # reconcile the draft pool to the committed prefixes (the
-            # proposal round advanced it by K per active slot in-graph);
-            # runs before the retire loop, which zeroes finishers' rows
-            d_adj = np.zeros((S,), np.int32)
-            for slot in active_slots:
-                d_adj[slot] = int(adv[slot]) - K
-            self._draft_cache = self._draft_cache.replace(
-                lengths=self._draft_cache.lengths + jnp.asarray(d_adj))
-        for slot in retire:
-            self._retire(slot, self.scheduler.slots[slot], finished)
-        sp.mark("commit")
-        proposed = n_active * (K - 1)
-        # the shared publish body (inline here — the sync loop has no
-        # worker); per-token latency keeps meaning "wall per committed
-        # token per slot" under speculation
-        self._publish_verify_step(dt, n_active, committed_total,
-                                  proposed, accepted_total,
-                                  per_slot_commits)
-        self._spec_proposed += proposed
-        self._spec_accepted += accepted_total
-        self._spec_committed += committed_total
-        self._spec_steps += 1
-        self._spec_slot_steps += n_active
-        self._maybe_spec_collapse(proposed, accepted_total)
-        if self.watchdog is not None:
-            self.watchdog.notify_progress()
-        if self._step_clock % self._EVENT_EVERY == 1:
-            get_event_ring().record(
-                telemetry_events.STEP_END, source="serve_spec_verify",
-                step=self._step_clock, live=n_active,
-                committed=committed_total, accepted=accepted_total,
-                seconds=round(dt, 6),
-                sampled_every=self._EVENT_EVERY)
-        sp.mark("publish")
 
     def _maybe_spec_collapse(self, proposed: int, accepted: int) -> None:
         """Ring-event an acceptance-rate collapse ONCE per episode: over

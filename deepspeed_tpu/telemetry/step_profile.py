@@ -29,8 +29,9 @@ with the same discipline:
   ``now - last_fetch`` into ``serve_dispatch_gap_seconds``; the
   cumulative gap is the exact wall-time budget an async loop can win
   back.
-* **Commit lag awareness.** The async serving loop dispatches step
-  N+1 BEFORE fetching step N (``inference.async_loop``), so a naive
+* **Commit lag awareness.** A serving step that runs at a commit lag
+  above 0 (``inference.async_loop``, a step with no host state change
+  to make) dispatches step N+1 BEFORE fetching step N, so a naive
   fetch→dispatch pairing would charge the lag-1 commit+publish work as
   device idle even though the device moved straight from N to N+1.
   The profiler counts dispatches outstanding (dispatched, not yet

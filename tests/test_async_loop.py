@@ -308,6 +308,80 @@ def test_preemption_mid_pipeline_flushes_then_preempts(fresh_telemetry):
     assert out[b] == eng.generate([[4, 5, 6]], max_new_tokens=4)[0]
 
 
+# what the loop before ISSUE 32 (its pipelined and synchronous bodies
+# separate functions) counted on the script below: the one loop with a
+# per-step lag must count the same
+_MIDCHAIN_PARENT = {
+    "decode-lag1": dict(
+        pipeline_starts=2, pipelined_steps=20, discarded_tokens=2,
+        garbage_steps=1, flushes={"host_action": 1, "drain": 1},
+        flush_depths={"host_action": {"1": 1}, "drain": {"1": 1}}),
+    "decode-lag2": dict(
+        pipeline_starts=2, pipelined_steps=21, discarded_tokens=4,
+        garbage_steps=2, flushes={"host_action": 1, "drain": 1},
+        flush_depths={"host_action": {"2": 1}, "drain": {"2": 1}}),
+    "verify-lag1": dict(
+        pipeline_starts=2, pipelined_steps=9, discarded_tokens=0,
+        garbage_steps=0, flushes={"host_action": 1},
+        flush_depths={"host_action": {"1": 1}}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_MIDCHAIN_PARENT))
+def test_queue_arriving_mid_chain_runs_one_lag0_round(fresh_telemetry,
+                                                      case):
+    """A request queued while a chain is in flight: the next step
+    flushes the chain (``host_action``), admits, and runs ONE round at
+    lag 0 — it returns with nothing in flight and is not a pipelined
+    step — and the step after it starts a new chain. Fake clock; the
+    counters are the ones the two-loop server produced."""
+    kind, lag = case.split("-lag")
+    knobs = {"max_commit_lag": int(lag)}
+    if kind == "verify":
+        knobs["speculation_tokens"] = 4
+    eng = make_engine(num_slots=2, **knobs)
+    srv = ContinuousBatchingServer(eng, clock=FakeClock(auto=0.001))
+    a = srv.submit([1, 2, 3, 1, 2, 3], max_new_tokens=24)
+    for _ in range(4):          # admission at lag 0, then the chain
+        srv.step()
+    depth = srv.stats["async_loop"]["commit_lag"]
+    assert depth == (1 if kind == "verify" else min(int(lag), 3))
+    b = srv.submit([4, 5, 6], max_new_tokens=3)
+    before = srv.stats
+    srv.step()                  # the lag-0 step
+    st = srv.stats
+    assert st["async_loop"]["flushes"] == {"host_action": 1}
+    assert st["async_loop"]["flush_depths"] == {
+        "host_action": {str(depth): 1}}
+    assert st["async_loop"]["commit_lag"] == 0
+    assert st["async_loop"]["pipeline_starts"] \
+        == before["async_loop"]["pipeline_starts"] == 1
+    assert st["async_loop"]["pipelined_steps"] \
+        == before["async_loop"]["pipelined_steps"]
+    assert st["step_profile"]["commit_lag"]["pipelined_steps"] \
+        == before["step_profile"]["commit_lag"]["pipelined_steps"]
+    assert st["step_profile"]["steps"] \
+        == before["step_profile"]["steps"] + 1
+    # the flush committed the chain and the round its own program
+    assert st["decode_steps"] == before["decode_steps"] + depth + 1
+    assert len(srv.scheduler.slots) == 2
+    srv.step()                  # queue empty again: a new chain
+    st = srv.stats["async_loop"]
+    assert st["commit_lag"] == 1 and st["pipeline_starts"] == 2
+    out = srv.drain()
+    ref = make_engine(num_slots=2, async_loop=False, **knobs)
+    got = ContinuousBatchingServer(ref)
+    ra = got.submit([1, 2, 3, 1, 2, 3], max_new_tokens=24)
+    rb = got.submit([4, 5, 6], max_new_tokens=3)
+    want = got.drain()
+    assert (out[a], out[b]) == (want[ra], want[rb])
+    st = srv.stats["async_loop"]
+    counters = {k: st[k] for k in (
+        "pipeline_starts", "pipelined_steps", "flushes", "flush_depths",
+        "discarded_tokens", "garbage_steps")}
+    assert counters == _MIDCHAIN_PARENT[case]
+
+
 def test_drain_timeout_terminates_wedged_inflight_step(fresh_telemetry):
     """The PR-7 termination proof survives pipelining: a wedged slot
     decodes forever through CHAINED steps; the bounded drain cancels it

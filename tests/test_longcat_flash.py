@@ -486,6 +486,41 @@ def test_slots_retire_and_blocks_are_reused_without_cross_talk(f32):
     server.close()
 
 
+@pytest.mark.parametrize("async_loop", [False, True])
+def test_routing_series_follow_the_device_counters(async_loop):
+    """The routing series are the pool's ``aux`` array, cell for cell.
+    A step at lag 0 brings the array over with its tokens, so with
+    ``async_loop`` off the two agree after every step; a lagged commit
+    cannot (its pool has been donated into the next program), and
+    ``stats`` catches the series up. The totals of the two servers may
+    differ: a chained step runs a finished slot's row once more."""
+    from deepspeed_tpu.telemetry import MetricRegistry, set_registry
+    prev = set_registry(MetricRegistry())
+    try:
+        cfg, _, engine = _server(async_loop=async_loop)
+        server = ContinuousBatchingServer(engine)
+
+        def series():
+            return np.array([[c.value for c in row]
+                             for row in server._aux_series])
+
+        rng = np.random.default_rng(4)
+        for n in (5, 17, 9):        # three requests, two slots: a queue
+            server.submit(rng.integers(1, cfg.vocab_size, size=n).tolist(),
+                          max_new_tokens=6, eos_token_id=None)
+        while not server.scheduler.idle:
+            server.step()
+            if not async_loop:
+                assert (series() == np.asarray(server._cache.aux)).all()
+        loop = server.stats["async_loop"]       # catches the series up
+        assert (loop["pipelined_steps"] > 0) == async_loop
+        assert series().sum() > 0
+        assert (series() == np.asarray(server._cache.aux)).all()
+        server.close()
+    finally:
+        set_registry(prev)
+
+
 @pytest.mark.parametrize("switch,value", [
     ("kv_cache_dtype", "int8"),
     ("enable_prefix_caching", True),

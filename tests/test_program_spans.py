@@ -128,13 +128,21 @@ def _steps_with_phases(log):
     return out
 
 
-@pytest.mark.parametrize("shape", ["sync", "pipelined", "idle"])
+@pytest.mark.parametrize("shape", [
+    "sync", "pipelined", "idle", "sync-verify", "pipelined-verify",
+    "pipelined-lag2"])
 def test_phase_spans_tile_the_step(fresh, shape):
-    """The sum identity, on spans: for the synchronous, the pipelined and
-    the idle step shapes the phase spans of a step cover ``serve:step``
-    exactly, under a clock that ticks at every read."""
-    srv = ContinuousBatchingServer(
-        make_engine(async_loop=(shape != "sync")), clock=TickClock())
+    """The sum identity, on spans: at lag 0, at lag 1 and 2, with the
+    decode and with the verify program, and for an idle poll, the phase
+    spans of a step cover ``serve:step`` exactly, under a clock that
+    ticks at every read. A step that ran at lag 0 is not ``pipelined``,
+    whichever server ran it."""
+    knobs = {"async_loop": not shape.startswith("sync")}
+    if shape.endswith("verify"):
+        knobs["speculation_tokens"] = 4
+    if shape.endswith("lag2"):
+        knobs["max_commit_lag"] = 2
+    srv = ContinuousBatchingServer(make_engine(**knobs), clock=TickClock())
     if shape == "idle":
         srv.step()
     else:
@@ -151,7 +159,11 @@ def test_phase_spans_tile_the_step(fresh, shape):
         assert [a.get("idle") for a in attrs] == [True]
     else:
         worked = [a for a in attrs if not a.get("idle")]
-        assert any(a["pipelined"] for a in worked) == (shape == "pipelined")
+        assert any(a["pipelined"] for a in worked) \
+            == shape.startswith("pipelined")
+        # a step that admitted had a queue to serve: it ran at lag 0
+        admitting = [a for a in worked if a["admitted"]]
+        assert admitting and not any(a["pipelined"] for a in admitting)
         assert sum(a["admitted"] for a in worked) == 3
         # the profiler's own totals are the same identity
         snap = srv.stats["step_profile"]
@@ -160,11 +172,16 @@ def test_phase_spans_tile_the_step(fresh, shape):
         assert len(worked) == snap["steps"]
         assert sum(a["device_s"] for a in worked) == pytest.approx(
             snap["device_s"])
+        assert snap["commit_lag"]["depth_max"] == (
+            1 if shape.startswith("sync") or shape.endswith("verify")
+            else 3 if shape.endswith("lag2") else 2)
         # dispatch and sync_wait name the watched program
         named = [k[ATTRS]["program"] for _, kids in got for k in kids
                  if k[NAME] in ("serve:dispatch", "serve:sync_wait")
                  and k[ATTRS]]
-        assert named and set(named) == {"serve_decode"}
+        assert named and set(named) == {
+            "serve_spec_verify" if shape.endswith("verify")
+            else "serve_decode"}
     srv.close()
 
 
