@@ -1492,6 +1492,8 @@ def phase_serve(args) -> dict:
             s.drain()                                 # warm the traces
             sub_t = {}
             fin = {}
+            sub_step = {}
+            fin_step = {}
             plen_by = {}
             rids = []
             nxt_i, vclk = 0, 0
@@ -1509,23 +1511,31 @@ def phase_serve(args) -> dict:
                     rids.append(rid)
                     plen_by[rid] = len(prompt)
                     sub_t[rid] = time.time()
+                    sub_step[rid] = vclk
                     nxt_i += 1
                 if s.scheduler.idle:
                     vclk = arrive_ov[nxt_i]
                     continue
                 for rid in s.step():
                     fin[rid] = time.time()
+                    fin_step[rid] = vclk + 1
                 vclk += 1
             wall_ov = time.time() - t0
+            accepted = [r for r in rids
+                        if s.finish_reason(r) in ("eos", "length")]
+            new_tokens = {r: len(s.result(r)) - plen_by[r]
+                          for r in accepted}
             raw = {
                 "wall": wall_ov,
                 "stats": s.stats,
                 # (request latency seconds, new tokens) per accepted
                 # (eos/length) request — everything the judgement needs
-                "done": [(fin[r] - sub_t[r],
-                          len(s.result(r)) - plen_by[r])
-                         for r in rids
-                         if s.finish_reason(r) in ("eos", "length")],
+                "done": [(fin[r] - sub_t[r], new_tokens[r])
+                         for r in accepted],
+                # the same latencies in step() calls: what a machine's
+                # load cannot stretch
+                "done_steps": [(fin_step[r] - sub_step[r], new_tokens[r])
+                               for r in accepted],
             }
             s.close()
             return raw
@@ -1535,16 +1545,20 @@ def phase_serve(args) -> dict:
             per-token p90, and goodput counting only tokens of requests
             that finished inside the deadline."""
             st_ = raw["stats"]
-            lat = sorted(t * 1e3 / max(n, 1) for t, n in raw["done"])
+
+            def p90_per_token(done, scale=1.0):
+                # None, not 0.0, when the leg accepted nothing — a
+                # zero sentinel would read as a perfect-latency win
+                lat = sorted(t * scale / max(n, 1) for t, n in done)
+                return (round(lat[min(int(len(lat) * 0.9), len(lat) - 1)],
+                              3) if lat else None)
+
             good = sum(n for t, n in raw["done"] if t <= deadline_s)
             return {
                 "requests": ov_n,
                 "accepted": len(raw["done"]),
-                # None, not 0.0, when the leg accepted nothing — a
-                # zero sentinel would read as a perfect-latency win
-                "token_p90_ms": (round(
-                    lat[min(int(len(lat) * 0.9), len(lat) - 1)], 3)
-                    if lat else None),
+                "token_p90_ms": p90_per_token(raw["done"], 1e3),
+                "token_p90_steps": p90_per_token(raw["done_steps"]),
                 "goodput_tokens_per_s": round(
                     good / max(raw["wall"], 1e-9), 1),
                 "wall_s": round(raw["wall"], 3),
@@ -1798,6 +1812,12 @@ def phase_serve(args) -> dict:
                 "dispatch_gap_total_s": round(
                     spf["dispatch_gap"]["total_s"], 6),
                 "pipelined_steps": st["async_loop"]["pipelined_steps"],
+                # counts, the same on an idle and on a loaded machine:
+                # dispatch boundaries observed, and how many of them
+                # landed on a busy device (a zero gap by construction)
+                "dispatch_boundaries": spf["dispatch_gap"]["count"],
+                "pipelined_dispatches":
+                    spf["commit_lag"]["pipelined_dispatches"],
                 "flushes": sum(st["async_loop"]["flushes"].values()),
                 "commit_lag_depth_max": (s._profiler.snapshot()
                                          .get("commit_lag", {})

@@ -287,7 +287,8 @@ class DeepSpeedInferenceConfig(DeepSpeedConfigModel):
     # OLDEST host payload drops for good, exactly like a plain eviction
     kv_host_blocks: Optional[int] = None
     # per-step commit lag (docs/serving.md "Async dispatch loop"): a
-    # step with no host state change to make dispatches step N+1 from
+    # step with no host state change it can make (an empty queue, or
+    # a backlog behind full slots) dispatches step N+1 from
     # step N's device-resident outputs BEFORE fetching step N's
     # tokens, and runs host commit (EOS/length checks, retirement,
     # metric publishing) max_commit_lag steps behind — the device

@@ -325,6 +325,28 @@ class Scheduler:
         i = self._next_eligible(step_clock, now)
         return None if i is None else self.queue[i]
 
+    def may_act(self, step_clock: int, now: Optional[float] = None,
+                preemption: bool = True) -> bool:
+        """Whether :meth:`admit_next` or the server's preemption ladder
+        COULD change state right now: a side-effect-free peek (no
+        allocation, no prefix match, no counter) the server takes every
+        step to choose its commit lag. False only when it is certain
+        that neither can: nothing queued is eligible, or every slot is
+        resident and the eligible head (the highest priority among the
+        eligible) outranks no resident. A free slot always answers True,
+        whether or not the blocks would cover the head: the free list,
+        the prefix LRU and the host tier are :meth:`admit_next`'s to
+        try. ``preemption`` False (the server's ``max_preemptions`` is
+        0) leaves the free slot as the only way in."""
+        idx = self._next_eligible(step_clock, now)
+        if idx is None:
+            return False
+        if self._free_slots:
+            return True
+        head = self.queue[idx].priority
+        return preemption and any(s.request.priority < head
+                                  for s in self.slots.values())
+
     def admit_next(self, step_clock: int = 0,
                    now: Optional[float] = None):
         """Pop the first eligible request into a free slot when its

@@ -2180,15 +2180,19 @@ class ContinuousBatchingServer:
         Every step picks its COMMIT LAG — how many dispatched decode
         programs may stay unfetched when it returns (docs/serving.md
         "Async dispatch loop"). A step with a host-driven state change
-        to make (queued work, a chunked prefill in flight) or with
+        it can make now (:meth:`_host_can_act`: a chunked prefill in
+        flight, or an eligible queued request with a free slot to take
+        or a lower-priority resident to preempt) or with
         ``inference.async_loop`` off runs at lag 0: it commits whatever
         is in flight first, so admission, chunk scheduling, preemption,
         shedding and fault injection act on committed state, and it
         commits the program it dispatches before it returns. Any other
-        step runs at ``max_commit_lag``: it dispatches step N+1 chained
+        step — an empty queue, or a backlog waiting behind full slots —
+        runs at ``max_commit_lag``: it dispatches step N+1 chained
         from step N's device-resident outputs and commits only the
         records beyond the lag, so finishes surface up to
-        ``max_commit_lag`` ``step()`` calls after their device step."""
+        ``max_commit_lag`` ``step()`` calls after their device step
+        (and the slot a finish frees is refilled by the step after)."""
         # step observatory (telemetry/step_profile.py): phase marks at
         # boundaries the loop already crosses — monotonic-clock reads
         # only, zero new device syncs; OFF = the shared no-op handle
@@ -2216,10 +2220,10 @@ class ContinuousBatchingServer:
         # collateral finishes — fold them into THIS round's return
         self._take_deferred(finished)
         # this step's commit lag: 0 whenever the host has a state
-        # change to make (or async_loop is off), max_commit_lag while
-        # the only host work is committing what the device finished
-        lag = (self._max_lag if (self._async and not self.scheduler.queue
-                                 and not self._prefilling) else 0)
+        # change it can make NOW (or async_loop is off), max_commit_lag
+        # while the only host work is committing what the device
+        # finished — an empty queue, or a backlog behind full slots
+        lag = 0 if self._host_can_act() else self._max_lag
         if lag == 0:
             if self._inflight:
                 # admission / chunk scheduling / the preemption ladder
@@ -2236,9 +2240,12 @@ class ContinuousBatchingServer:
             while guard > 0 and self._preempt_for_head(finished):
                 guard -= 1
                 self._admit(finished, sp)
+        if lag == 0 or self.scheduler.queue:
             # tier health: sample the admission round's swap-in traffic
             # into the thrash window (demotion/swap-in only ever runs
-            # inside the admissions above)
+            # inside the admissions above; a lagged step with a backlog
+            # behind full slots samples its zero, so the window stays
+            # "steps with work waiting")
             self._check_swap_thrash()
         sp.mark("admission")     # at lag > 0: the reap/shed checks above
         self._run_prefill_chunk(finished, sp)   # none in flight at lag > 0
@@ -2275,6 +2282,25 @@ class ContinuousBatchingServer:
         # to the NEXT dispatch would measure traffic, not host tax
         self._finish_step(sp)
         return finished
+
+    def _host_can_act(self) -> bool:
+        """Whether this step has a host-driven state change it could
+        make: ``async_loop`` is off (every step commits what it
+        dispatched), a chunked prefill is in flight, or the queue holds
+        an eligible request AND a slot is free or that request outranks
+        a resident (``Scheduler.may_act``: conservative, a free slot
+        answers yes whatever the pool holds). When every slot is
+        resident and the eligible head outranks nobody, admission and
+        the preemption ladder cannot change anything, however deep the
+        queue: the step runs lagged, like one with an empty queue. The
+        step after a retirement sees the free slot and flushes."""
+        if not self._async or self._prefilling:
+            return True
+        if not self.scheduler.queue:
+            return False
+        now = self._clock() if self._deadlines else None
+        return self.scheduler.may_act(
+            self._tick, now, preemption=self.max_preemptions > 0)
 
     def _finish_step(self, sp) -> None:
         """Close the step's profile. ``live`` is false when nothing is

@@ -382,6 +382,260 @@ def test_queue_arriving_mid_chain_runs_one_lag0_round(fresh_telemetry,
     assert counters == _MIDCHAIN_PARENT[case]
 
 
+# ----------------------------------- a backlog behind full slots (ISSUE 33)
+
+def make_latent_engine(num_slots=2, **knobs):
+    """The latent-cache family (LongCat-Flash) at a tiny size: the
+    other pool the one step loop serves."""
+    from deepspeed_tpu.model_implementations.longcat_flash import (
+        LongcatFlashConfig, init_params as init_latent)
+    cfg = LongcatFlashConfig(
+        dtype=jnp.float32, vocab_size=128, hidden_size=64, num_layers=2,
+        num_attention_heads=4, ffn_hidden_size=128,
+        expert_ffn_hidden_size=32, q_lora_rank=32, kv_lora_rank=16,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        n_routed_experts=8, zero_expert_num=4, moe_topk=3,
+        max_position_embeddings=1024, experts_held=(2, 6))
+    params = init_latent(jax.random.PRNGKey(3), cfg)
+    return InferenceEngine((cfg, params), DeepSpeedInferenceConfig(
+        dtype="float32", max_out_tokens=64, block_size=16,
+        num_slots=num_slots, **knobs))
+
+
+_SHARED = [1 + (i % 90) for i in range(64)]
+_SHORT = [[1, 2, 3, 1, 2, 3], [4, 5, 6], [7, 8, 9, 7], [3, 2, 1], [9, 9, 8]]
+# name -> (engine builder, prompts): five requests through two slots,
+# budgets apart so that retirements come one at a time
+_BACKLOG_FAMILIES = {
+    "gpt2": (lambda **k: make_engine(num_slots=2, **k), _SHORT),
+    "latent": (make_latent_engine, _SHORT),
+    # prefix caching implies one-block chunked prefill: a shared
+    # two-block prefix, distinct tails
+    "prefix-chunked": (
+        lambda **k: make_engine(num_slots=2, max_out_tokens=128,
+                                enable_prefix_caching=True, **k),
+        [_SHARED + [3, 7, 11], _SHARED + [5, 9], _SHARED + [2, 4, 6, 8],
+         _SHARED[:40] + [13], _SHARED + [17, 19]]),
+}
+_BACKLOG_BUDGETS = [9, 14, 6, 11, 5]
+_BACKLOG_CASES = [
+    "decode-lag1-gpt2", "decode-lag3-gpt2", "verify-lag1-gpt2",
+    "verify-lag3-gpt2", "decode-lag1-latent", "decode-lag3-latent",
+    "decode-lag1-prefix-chunked", "decode-lag3-prefix-chunked",
+    "verify-lag1-prefix-chunked"]
+
+
+def _backlog_server(case, clock=None, **extra):
+    kind, lag, family = case.split("-", 2)
+    knobs = {"max_commit_lag": int(lag[3:]), **extra}
+    if kind == "verify":
+        knobs["speculation_tokens"] = 4
+    build, prompts = _BACKLOG_FAMILIES[family]
+    kw = {} if clock is None else {"clock": clock}
+    return ContinuousBatchingServer(build(**knobs), **kw), prompts
+
+
+@pytest.mark.parametrize("case", _BACKLOG_CASES)
+def test_backlog_behind_full_slots_pipelines_until_a_retirement(
+        fresh_telemetry, case):
+    """A queue deeper than the slots, every slot resident: nothing the
+    host could do needs committed state, so the steps run lagged (no
+    ``host_action`` flush, the chain stays in flight) exactly like steps
+    with an empty queue. A retirement is found at a lagged commit; the
+    NEXT step sees the free slot, flushes the chain, admits into it and
+    is not pipelined; the one after (slots full again) starts a new
+    chain. The served tokens equal ``async_loop: false`` token for
+    token. Fake clock."""
+    srv, prompts = _backlog_server(case, clock=FakeClock(auto=0.001))
+    sched = srv.scheduler
+    ids = [srv.submit(p, max_new_tokens=b)
+           for p, b in zip(prompts, _BACKLOG_BUDGETS)]
+
+    def loop():
+        a = srv._async_stats
+        return (a["pipeline_starts"] + a["pipelined_steps"],
+                a["flushes"].get("host_action", 0), len(srv._inflight))
+
+    seen = {"lagged_with_backlog": 0, "flush_then_admit": 0,
+            "restart": 0}
+    was_lag0_refill = False
+    guard = 0
+    while not sched.idle:
+        guard += 1
+        assert guard < 400
+        full = not sched._free_slots
+        backlog = bool(sched.queue)
+        chunking = bool(srv._prefilling)
+        resident0 = set(sched.slots)
+        lagged0, flushes0, depth0 = loop()
+        srv.step()
+        lagged1, flushes1, depth1 = loop()
+        if backlog and full and not chunking:
+            # the host had nothing it could change: a lagged step
+            assert flushes1 == flushes0
+            assert lagged1 == lagged0 + 1
+            # (a verify round whose commit retired everyone dispatches
+            # nothing)
+            assert depth1 >= 1 or not sched.slots
+            seen["lagged_with_backlog"] += 1
+            if was_lag0_refill:
+                assert depth0 == 0 and depth1 == 1      # a new chain
+                seen["restart"] += 1
+            was_lag0_refill = False
+        elif backlog and not full:
+            # a free slot and a waiter: lag 0, flush first, then admit
+            assert lagged1 == lagged0
+            assert flushes1 == flushes0 + (1 if depth0 else 0)
+            assert depth1 == 0
+            assert set(sched.slots) - resident0      # somebody moved in
+            seen["flush_then_admit"] += 1 if depth0 else 0
+            was_lag0_refill = not sched._free_slots and bool(sched.queue)
+        else:
+            was_lag0_refill = False
+    out = srv.drain()
+    assert seen["lagged_with_backlog"] >= 4
+    # (a verify round commits up to K tokens a slot: fewer rounds)
+    assert seen["flush_then_admit"] >= (2 if case.startswith("decode")
+                                        else 1)
+    assert seen["restart"] >= 1
+    assert srv.stats["retraces"] == 0
+    ref, _ = _backlog_server(case, async_loop=False)
+    rids = [ref.submit(p, max_new_tokens=b)
+            for p, b in zip(prompts, _BACKLOG_BUDGETS)]
+    want = ref.drain()
+    assert ref.stats["async_loop"]["pipelined_steps"] == 0
+    assert [out[i] for i in ids] == [want[i] for i in rids]
+
+
+@pytest.mark.parametrize("case", ["decode-lag1-gpt2", "decode-lag3-gpt2",
+                                  "verify-lag1-gpt2", "verify-lag3-gpt2"])
+def test_queued_head_that_outranks_a_resident_keeps_lag0_and_preempts(
+        fresh_telemetry, case):
+    """Full slots and a backlog of equals: lagged steps. A waiter that
+    outranks a resident is something the host CAN act on: the next step
+    runs at lag 0, flushes the chain and preempts, as it always did.
+    With preemption off (``max_preemptions: 0``) the same waiter cannot
+    get in, and the steps stay lagged."""
+    srv, prompts = _backlog_server(case, clock=FakeClock(auto=0.001))
+    ids = [srv.submit(p, max_new_tokens=30) for p in prompts[:4]]
+    for _ in range(6):
+        srv.step()
+    st = srv.stats
+    assert st["async_loop"]["commit_lag"] >= 1
+    assert st["async_loop"]["flushes"].get("host_action", 0) == 0
+    assert st["preempted"] == 0 and len(srv.scheduler.queue) == 2
+    vip = srv.submit([4, 5, 6], max_new_tokens=4, priority=5)
+    srv.step()
+    st = srv.stats
+    assert st["async_loop"]["flushes"]["host_action"] == 1
+    assert st["async_loop"]["commit_lag"] == 0
+    assert st["preempted"] == 1
+    assert srv.scheduler.find_slot(vip) is not None
+    out = srv.drain()
+    ref, _ = _backlog_server(case, async_loop=False)
+    rids = [ref.submit(p, max_new_tokens=30) for p in prompts[:4]]
+    want = ref.drain()
+    assert [out[i] for i in ids] == [want[i] for i in rids]
+    assert out[vip][:3] == [4, 5, 6] and len(out[vip]) == 7
+
+    off, _ = _backlog_server(case, clock=FakeClock(auto=0.001),
+                             max_preemptions=0)
+    for p in prompts[:4]:
+        off.submit(p, max_new_tokens=30)
+    for _ in range(6):
+        off.step()
+    off.submit([4, 5, 6], max_new_tokens=4, priority=5)
+    before = off.stats["async_loop"]
+    off.step()
+    after = off.stats["async_loop"]
+    assert after["flushes"].get("host_action", 0) == 0
+    assert after["commit_lag"] >= 1
+    assert (after["pipelined_steps"] + after["pipeline_starts"]
+            == before["pipelined_steps"] + before["pipeline_starts"] + 1)
+    off.drain()
+    assert off.stats["preempted"] == 0
+
+
+def _allocator_state(sched):
+    a = sched.allocator
+    return (list(a._free), set(a._free_set), dict(a._refcount),
+            dict(a._hash_to_block), dict(a._block_hash), list(a._lru),
+            a.evictions, a.demotions, a.swap_ins, sched.prefix_hits,
+            sched.prefix_misses, sched._c_hits.value,
+            sched._c_misses.value, list(sched._free_slots),
+            [r.request_id for r in sched.queue], sorted(sched.slots))
+
+
+@pytest.mark.parametrize("prefix_caching", [False, True],
+                         ids=["plain", "prefix-cache"])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_may_act_is_side_effect_free_and_never_a_false_cannot(
+        fresh_telemetry, seed, prefix_caching):
+    """``Scheduler.may_act`` over a random walk of scheduler states
+    (submits at mixed priorities, admissions, releases, preemptions
+    with back-off, deadlines, injected famine, a pool too small for the
+    queue): it leaves the allocator's refcounts, free lists, prefix
+    index and the hit / miss counters as they were, and whenever it
+    answers "cannot", ``admit_next`` admits nothing and no resident
+    ranks below the waiter — a false "cannot" would let a chain run
+    past a state change the host could have made."""
+    from deepspeed_tpu.inference.scheduler import Request, Scheduler
+    rng = np.random.default_rng(seed)
+    BS = 4
+    sched = Scheduler(num_slots=3, num_blocks=14, block_size=BS,
+                      max_blocks_per_slot=6, max_queued_requests=64,
+                      registry=MetricRegistry(),
+                      enable_prefix_caching=prefix_caching)
+    shared = list(range(1, 9))
+    rid = clock = 0
+    answers = {True: 0, False: 0}
+    for _ in range(400):
+        clock += 1
+        now = float(clock)
+        op = rng.integers(0, 10)
+        if op < 4 and len(sched.queue) < 8:
+            plen = int(rng.integers(1, 12))
+            prompt = (shared[:plen] if rng.random() < 0.5 else
+                      rng.integers(1, 50, size=plen).tolist())
+            rid += 1
+            sched.submit(Request(
+                rid, prompt, max_new_tokens=int(rng.integers(1, 10)),
+                priority=int(rng.integers(0, 3)),
+                deadline_ts=(now + float(rng.integers(1, 6))
+                             if rng.random() < 0.2 else None)))
+        elif op < 6 and sched.slots:
+            slot = int(rng.choice(sorted(sched.slots)))
+            state = sched.slots[slot]
+            if prefix_caching:
+                sched.commit_prefix(state)
+            sched.release(slot)
+        elif op < 7 and sched.slots:
+            slot = int(rng.choice(sorted(sched.slots)))
+            sched.slots[slot].generated.extend([5, 6])
+            sched.preempt(slot, clock, backoff_steps=int(rng.integers(0, 4)))
+        elif op < 8:
+            sched.allocator.set_reserved(int(rng.integers(0, 6)))
+        before = _allocator_state(sched)
+        can = sched.may_act(clock, now)
+        can_in = sched.may_act(clock, now, preemption=False)
+        assert _allocator_state(sched) == before
+        answers[can] += 1
+        head = sched.next_ready(clock, now)
+        victim = sched.pick_preemption_victim()
+        outranks = (head is not None and victim is not None
+                    and victim[1].request.priority < head.priority)
+        free_slot = bool(sched._free_slots)
+        # exact where it can be: an eligible head with a free slot, or
+        # with a resident it outranks, is "the host may act" (whatever
+        # the pool holds); nothing else is
+        assert can == (head is not None and (free_slot or outranks))
+        assert can_in == (head is not None and free_slot)
+        adm = sched.admit_next(clock, now)
+        if adm is not None:
+            assert can and can_in
+    assert answers[True] > 20 and answers[False] > 20
+
+
 def test_drain_timeout_terminates_wedged_inflight_step(fresh_telemetry):
     """The PR-7 termination proof survives pipelining: a wedged slot
     decodes forever through CHAINED steps; the bounded drain cancels it
