@@ -146,8 +146,8 @@ class InferenceEngine:
                     "params) instead") from e
             self.model_config, params = convert_hf_model(
                 model, dtype=self._act_dtype)
-        if getattr(self.model_config, "cache_kind", "kv") == "latent":
-            self._refuse_for_latent()
+        if model_family(self.model_config) is not None:
+            self._refuse_for_family()
         # engine dtype wins over the model config's (one source of truth):
         # activations are cast to model_config.dtype inside the forward
         self.model_config = dataclasses.replace(self.model_config,
@@ -241,12 +241,13 @@ class InferenceEngine:
             name="infer_causal_forward", registry=self.telemetry)
         self._gen_loops: Dict[Any, Any] = {}
 
-    def _refuse_for_latent(self) -> None:
-        """A latent-attention model (``cache_kind == "latent"``) runs on one
+    def _refuse_for_family(self) -> None:
+        """A model of another family (``model_family``: latent attention
+        over a latent pool, retention over a state pool) runs on one
         device with full-precision weights: its parameter tree has no
-        Megatron specs, its expert layer issues no exchange, and nothing
-        quantizes its latents. Each switch that would need one of those
-        is refused here by name."""
+        Megatron specs, it issues no exchange, and nothing quantizes its
+        weights. Each switch that would need one of those is refused
+        here by name."""
         c = self.config
         on = [name for name, is_on in (
             ("dtype='int8' / quant.enabled", self._weight_quant),
@@ -258,8 +259,9 @@ class InferenceEngine:
             raise NotImplementedError(
                 f"a {type(self.model_config).__name__} model cannot be "
                 f"served with {', '.join(on)}: it runs on one device as "
-                "its share of an expert-parallel deployment "
-                "(experts_held), with the weights in the serving dtype")
+                "its share of a deployment (an expert-parallel layer's "
+                "held experts, a pipeline's stage), with the weights in "
+                "the serving dtype")
 
     def _loop_cache_get(self, key):
         """Decode-loop cache lookup with hit/miss telemetry: a rising

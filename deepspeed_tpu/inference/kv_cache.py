@@ -598,9 +598,60 @@ def latent_append_token(cache: LatentPagedCache, idx: int,
         rows.astype(pool.dtype)))
 
 
+# ------------------------------------------------------------- state pool
+# A retention (gated linear attention) layer caches no row per token: a
+# sequence's cache is a STATE of fixed size a layer, which every decode
+# step reads, updates and writes back whatever the context length
+# (ops/pallas/power_retention.py: ``S [KH, R, dv, d]`` and ``z [KH, Rz,
+# d]``, float32). The pool is one slot-major buffer a layer for each, so
+# no program cuts a layer out of a stacked array and a prefill writes one
+# slot of a donated buffer in place. There are no blocks and no block
+# tables: what binds admission is a free slot. ``lengths`` are kept for
+# rotary positions only.
+
+
+@struct.dataclass
+class RecurrentStateCache:
+    """Decode workspace of a model whose layers keep a recurrent state.
+
+    S / z: one buffer a layer, ``[slots, KH, R, dv, d]`` and ``[slots,
+    KH, Rz, d]`` float32. A retired slot's state is overwritten by the
+    next prefill and never read.
+    lengths: ``[slots]`` int32, the tokens each slot has consumed (the
+    next rotary position); idle slots stay at 0.
+    aux: the MODEL's int32 counters, as :class:`LatentPagedCache`."""
+    S: tuple                   # of [slots, KH, R, dv, d]
+    z: tuple                   # of [slots, KH, Rz, d]
+    lengths: jnp.ndarray       # [slots] int32
+    aux: jnp.ndarray           # the model's; int32
+
+
+def init_recurrent_state_cache(num_layers: int, num_slots: int,
+                               s_shape: tuple, z_shape: tuple,
+                               aux_shape=(1, 1),
+                               dtype=jnp.float32) -> RecurrentStateCache:
+    """``s_shape`` / ``z_shape``: one slot's state of one layer."""
+    return RecurrentStateCache(
+        S=tuple(jnp.zeros((num_slots, *s_shape), dtype)
+                for _ in range(num_layers)),
+        z=tuple(jnp.zeros((num_slots, *z_shape), dtype)
+                for _ in range(num_layers)),
+        lengths=jnp.zeros((num_slots,), jnp.int32),
+        aux=jnp.zeros(aux_shape, jnp.int32))
+
+
+def with_layer_state(cache: RecurrentStateCache, layer: int, S,
+                     z) -> RecurrentStateCache:
+    return cache.replace(
+        S=cache.S[:layer] + (S,) + cache.S[layer + 1:],
+        z=cache.z[:layer] + (z,) + cache.z[layer + 1:])
+
+
 def pool_arrays(cache) -> tuple:
     """The device arrays that make up a pool's payload, whichever kind
     of pool it is (memory accounting reads their sizes)."""
+    if isinstance(cache, RecurrentStateCache):
+        return tuple(cache.S) + tuple(cache.z)
     if isinstance(cache, LatentPagedCache):
         return tuple(cache.rows)
     if cache.k_scale is None:

@@ -142,8 +142,15 @@ class Scheduler:
                  registry: Optional[MetricRegistry] = None,
                  enable_prefix_caching: bool = False,
                  tracer=None, spec_margin: int = 0,
-                 pool_accountant=None, host_tier=None):
+                 pool_accountant=None, host_tier=None,
+                 pool_has_blocks: bool = True):
         self.num_slots = num_slots
+        # a pool without blocks (a recurrent state a slot): the block
+        # accounting below stays as a budget of POSITIONS that the
+        # server sizes so that it never binds (a free slot does), and
+        # the block gauges stay at 0: they would report rows that do
+        # not exist
+        self.pool_has_blocks = pool_has_blocks
         # speculative-verify overshoot (speculation_tokens - 1): every
         # request's block span reserves this many extra cache positions
         # so a verify forward's K-token write window never runs past
@@ -223,10 +230,12 @@ class Scheduler:
     def _update_gauges(self) -> None:
         """Refresh level gauges at every admission-state transition —
         pool pressure is readable between steps, not just at drain."""
-        self._g_free.set(self.allocator.free_blocks)
-        # DISTINCT blocks (allocator view): a shared prefix block counts
-        # once however many slots hold it, so used + free == capacity
-        self._g_used.set(self.allocator.live_blocks)
+        if self.pool_has_blocks:
+            self._g_free.set(self.allocator.free_blocks)
+            # DISTINCT blocks (allocator view): a shared prefix block
+            # counts once however many slots hold it, so used + free ==
+            # capacity
+            self._g_used.set(self.allocator.live_blocks)
         self._g_queue.set(len(self.queue))
         self._g_active.set(len(self.slots))
         self._g_cached.set(self.allocator.cached_blocks)

@@ -35,6 +35,7 @@ generate remains the latency king for a single fixed batch.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import time
 from collections import deque
@@ -51,6 +52,7 @@ from deepspeed_tpu.inference.engine import (InferenceEngine, _bucket,
 from deepspeed_tpu.inference.kv_cache import (HostKVTier, PagedKVCache,
                                               init_latent_paged_cache,
                                               init_paged_cache,
+                                              init_recurrent_state_cache,
                                               paged_read_block,
                                               paged_swap_in, pool_arrays)
 from deepspeed_tpu.inference.scheduler import Request, Scheduler
@@ -145,6 +147,90 @@ class _RequestTrace:
         self.tokens = 0       # tokens committed by decode steps
 
 
+# ------------------------------------------------------ kinds of pool
+# What the server does differently for each kind of cache a model's
+# programs thread through (``model_config.cache_kind``): how the pool is
+# built, whether its programs read a block table, and which switches it
+# cannot honour (a model that counts on the device says so itself: its
+# configuration has an ``aux_shape``). A
+# switch's code reads or writes K/V pools ``[L, NB, BS, KH*D]`` (block
+# copies, scale tiles, chunk / verify kernels); another kind of pool has
+# none of those shapes, nothing falls back to K/V code, and each such
+# switch is refused at construction by name.
+
+def _rows_switches(int8, offload, prefix, chunk, chain, spec):
+    """The switches whose code works on K/V rows, each with why this kind
+    of pool refuses it: ``(switch, is it on, why not)`` over ``(config,
+    draft_engine, handoff_import)``. The last two read the same for every
+    kind without rows."""
+    return (
+        ("kv_cache_dtype", lambda c, d, h: c.kv_cache_dtype != "fp", int8),
+        ("kv_host_offload", lambda c, d, h: c.kv_host_offload, offload),
+        ("enable_prefix_caching",
+         lambda c, d, h: c.enable_prefix_caching, prefix),
+        ("prefill_chunk_tokens",
+         lambda c, d, h: bool(c.prefill_chunk_tokens), chunk),
+        ("prefill_chain", lambda c, d, h: c.prefill_chain, chain),
+        ("speculation_tokens",
+         lambda c, d, h: bool(c.speculation_tokens), spec),
+        ("speculation_draft / draft_engine",
+         lambda c, d, h: d is not None or c.speculation_draft is not None,
+         "a draft pool mirrors K/V block tables"),
+        ("handoff_import", lambda c, d, h: h,
+         "handoff payloads are K/V slabs"))
+
+
+@dataclasses.dataclass(frozen=True)
+class _PoolKind:
+    make_pool: str                 # the server's method: (num_blocks) -> pool
+    block_tables: bool = True      # its programs read a block table
+    what: str = ""                 # the model and its pool, in a refusal
+    serves: str = ""               # what does serve it
+    refuses: tuple = ()            # (switch, is it on, why not)
+
+    def refuse(self, cfg, draft_engine, handoff_import) -> None:
+        on = [(name, why) for name, test, why in self.refuses
+              if test(cfg, draft_engine, handoff_import)]
+        if on:
+            raise NotImplementedError(
+                f"{self.what} cannot be served with " + "; ".join(
+                    f"{name} ({why})" for name, why in on)
+                + f" — leave these at their defaults: {self.serves}")
+
+
+_POOL_KINDS = {
+    "kv": _PoolKind(make_pool="_make_kv_pool"),
+    "latent": _PoolKind(
+        make_pool="_make_latent_pool",
+        what="a latent-attention model (latent paged cache)",
+        serves="monolithic bucketed prefill and plain paged decode "
+               "serve it",
+        refuses=_rows_switches(
+            "the latent pool has no int8 rows or scale tiles",
+            "block payloads are read and swapped as K/V slabs",
+            "a cache hit prefills its tail through the chunk program",
+            "chunked prefill attends the pool with the K/V chunk kernel",
+            "it chains chunked prefill",
+            "the batched verify attends the pool with the K/V verify "
+            "kernel")),
+    "state": _PoolKind(
+        make_pool="_make_state_pool", block_tables=False,
+        what="a retention model (recurrent state pool)",
+        serves="monolithic bucketed prefill (the chunked form inside "
+               "one program) and state-update decode serve it",
+        refuses=_rows_switches(
+            "the state is float32 and has no rows or scale tiles",
+            "a state has no blocks to demote to a host tier",
+            "a state holds no per-token rows to share; reusing a prefix "
+            "needs a snapshot of its state",
+            "no program carries a slot's state from one prompt chunk to "
+            "the next",
+            "it chains chunked prefill",
+            "a rejected draft token cannot be taken back out of a "
+            "state")),
+}
+
+
 class ContinuousBatchingServer:
     """``submit() / step() / drain()`` serving loop over an
     :class:`InferenceEngine`'s weights.
@@ -179,14 +265,12 @@ class ContinuousBatchingServer:
                 "unsupported — the paged pool is already the "
                 "long-context memory lever")
         self.engine = engine
-        # a latent-attention model (kv_cache.LatentPagedCache): the pool
-        # holds one row a token an attention, not K and V per head, and
-        # what cannot honour that yet is refused here by name
-        self._latent = getattr(engine.model_config, "cache_kind",
-                               "kv") == "latent"
-        if self._latent:
-            self._refuse_for_latent(engine.config, draft_engine,
-                                    handoff_import)
+        # the kind of pool the model's programs thread through (K and V
+        # per head; one latent row an attention; a recurrent state a
+        # slot): what a kind cannot honour is refused here by name
+        self._pool_kind = _POOL_KINDS[getattr(engine.model_config,
+                                              "cache_kind", "kv")]
+        self._pool_kind.refuse(engine.config, draft_engine, handoff_import)
         # supervised = this server is ONE REPLICA under a ServingFrontend
         # (inference/frontend.py): the frontend owns the scrape port and
         # installs its own heartbeat watchdog on self.watchdog, so the
@@ -529,8 +613,9 @@ class ContinuousBatchingServer:
             tracer=self.tracer,
             spec_margin=max(self.spec_tokens - 1, 0),
             pool_accountant=self._pool_acct,
-            host_tier=self.host_tier)
-        self._cache = self._make_pool(num_blocks)
+            host_tier=self.host_tier,
+            pool_has_blocks=self._pool_kind.block_tables)
+        self._cache = getattr(self, self._pool_kind.make_pool)(num_blocks)
         if self.host_tier is not None:
             # the allocator decides WHEN to tier; the server owns the
             # device arrays, so the copies are its callbacks. Both run
@@ -632,11 +717,11 @@ class ContinuousBatchingServer:
                 name="serve_draft_decode", registry=self.telemetry,
                 donate_argnames=("cache",))
         self._results: Dict[int, List[int]] = {}
-        # what a latent-attention model's programs accumulate on the
+        # what a model of another family's programs accumulate on the
         # device (``cache.aux``) and the registry series its module
         # gives each cell of it
         self._aux_seen = self._aux_series = None
-        if self._latent:
+        if hasattr(mcfg, "aux_shape"):
             self._aux_seen = np.zeros(mcfg.aux_shape, np.int64)
             self._aux_series = model_family(mcfg).aux_series(
                 mcfg, self.telemetry)
@@ -1009,41 +1094,6 @@ class ContinuousBatchingServer:
             self.scheduler.allocator.free_ids)
         return self._pool_acct.snapshot()
 
-    # switches whose code reads or writes K/V pools ``[L, NB, BS, KH*D]``
-    # (block copies, scale tiles, chunk / verify kernels): a latent pool
-    # has none of those shapes, and nothing falls back to K/V code
-    _LATENT_REFUSES = (
-        ("kv_cache_dtype", lambda c: c.kv_cache_dtype != "fp",
-         "the latent pool has no int8 rows or scale tiles"),
-        ("kv_host_offload", lambda c: c.kv_host_offload,
-         "block payloads are read and swapped as K/V slabs"),
-        ("enable_prefix_caching", lambda c: c.enable_prefix_caching,
-         "a cache hit prefills its tail through the chunk program"),
-        ("prefill_chunk_tokens", lambda c: bool(c.prefill_chunk_tokens),
-         "chunked prefill attends the pool with the K/V chunk kernel"),
-        ("prefill_chain", lambda c: c.prefill_chain,
-         "it chains chunked prefill"),
-        ("speculation_tokens", lambda c: bool(c.speculation_tokens),
-         "the batched verify attends the pool with the K/V verify kernel"),
-    )
-
-    @classmethod
-    def _refuse_for_latent(cls, cfg, draft_engine, handoff_import) -> None:
-        on = [(name, why) for name, test, why in cls._LATENT_REFUSES
-              if test(cfg)]
-        if draft_engine is not None or cfg.speculation_draft is not None:
-            on.append(("speculation_draft / draft_engine",
-                       "a draft pool mirrors K/V block tables"))
-        if handoff_import:
-            on.append(("handoff_import", "handoff payloads are K/V slabs"))
-        if on:
-            raise NotImplementedError(
-                "a latent-attention model (latent paged cache) cannot be "
-                "served with " + "; ".join(
-                    f"{name} ({why})" for name, why in on)
-                + " — leave these at their defaults: monolithic bucketed "
-                "prefill and plain paged decode serve it")
-
     @staticmethod
     def _prefill_fn(params, ids, length, cache, slot, *, cfg, mesh):
         logits, cache = paged_prefill(params, cfg, ids, length, cache,
@@ -1069,16 +1119,8 @@ class ContinuousBatchingServer:
                                           mesh=mesh)
         return _sample(logits), cache
 
-    def _make_pool(self, num_blocks: int):
+    def _make_kv_pool(self, num_blocks: int) -> PagedKVCache:
         mcfg = self.engine.model_config
-        if self._latent:
-            # one buffer per attention sub-block: no program cuts a
-            # layer's rows out of a stacked pool
-            return init_latent_paged_cache(
-                mcfg.attentions, self.num_slots, num_blocks,
-                self.block_size, self.max_blocks_per_slot,
-                mcfg.latent_width, aux_shape=mcfg.aux_shape,
-                dtype=self.engine._act_dtype)
         cache = init_paged_cache(
             mcfg.n_layer, self.num_slots, num_blocks, self.block_size,
             self.max_blocks_per_slot, mcfg.kv_heads, mcfg.head_dim,
@@ -1102,6 +1144,24 @@ class ContinuousBatchingServer:
                     k_scale=jax.device_put(cache.k_scale, ssh),
                     v_scale=jax.device_put(cache.v_scale, ssh))
         return cache
+
+    def _make_latent_pool(self, num_blocks: int):
+        # one buffer per attention sub-block: no program cuts a layer's
+        # rows out of a stacked pool
+        mcfg = self.engine.model_config
+        return init_latent_paged_cache(
+            mcfg.attentions, self.num_slots, num_blocks, self.block_size,
+            self.max_blocks_per_slot, mcfg.latent_width,
+            aux_shape=mcfg.aux_shape, dtype=self.engine._act_dtype)
+
+    def _make_state_pool(self, num_blocks: int):
+        # one buffer a layer for S and for z, slot-major: a prefill
+        # writes one slot of a donated buffer in place. No blocks: the
+        # scheduler's block budget is positions only (it never binds)
+        mcfg = self.engine.model_config
+        return init_recurrent_state_cache(
+            mcfg.n_layer, self.num_slots, *mcfg.state_shapes,
+            aux_shape=mcfg.aux_shape, dtype=mcfg.state_dtype)
 
     def _make_draft_pool(self, num_blocks: int) -> PagedKVCache:
         """Draft-model pool: the target pool's geometry (slots, blocks,
@@ -1403,9 +1463,8 @@ class ContinuousBatchingServer:
         an all-null block table, so interleaved decode appends land in
         the null block until the next admission repopulates the row."""
         self._cache = self._cache.replace(
-            lengths=self._cache.lengths.at[slot].set(0),
-            block_tables=self._cache.block_tables.at[slot].set(
-                jnp.zeros((self.max_blocks_per_slot,), jnp.int32)))
+            lengths=self._cache.lengths.at[slot].set(0))
+        self._set_block_row(slot, ())
         if self._draft_cache is not None:
             # the draft pool mirrors the target's tables at each use; a
             # vacated slot only needs its length zeroed so stale draft
@@ -1415,6 +1474,18 @@ class ContinuousBatchingServer:
         # every slot-vacating path (retire / cancel / preempt / fault)
         # runs through here — drop its lookup state with it
         self._spec_hist.pop(slot, None)
+
+    def _set_block_row(self, slot: int, blocks) -> None:
+        """``slot``'s row of the device block table: ``blocks`` first,
+        the null block after. A pool without blocks (a recurrent state)
+        has no table and nothing to write."""
+        if not self._pool_kind.block_tables:
+            return
+        row = np.zeros((self.max_blocks_per_slot,), np.int32)
+        row[:len(blocks)] = blocks
+        self._cache = self._cache.replace(
+            block_tables=self._cache.block_tables.at[slot].set(
+                jnp.asarray(row)))
 
     def _drop_prefill_job(self, slot: int) -> None:
         """Forget any in-flight chunked prefill for a vacated slot."""
@@ -1807,11 +1878,7 @@ class ContinuousBatchingServer:
             # block table first — the prefill scatter reads it. Entries
             # beyond the allocated span stay 0 (null block), so bucket/
             # chunk padding past the span spills harmlessly.
-            row = np.zeros((self.max_blocks_per_slot,), np.int32)
-            row[:len(state.blocks)] = state.blocks
-            self._cache = self._cache.replace(
-                block_tables=self._cache.block_tables.at[slot].set(
-                    jnp.asarray(row)))
+            self._set_block_row(slot, state.blocks)
             if rt is not None:
                 # admission work (slot pick, block table) is done —
                 # close the span BEFORE the fault site, so an injected
@@ -2869,11 +2936,11 @@ class ContinuousBatchingServer:
     def _fetch_tokens(self, tokens) -> np.ndarray:
         """Fetch of a program's sampled tokens where no later program
         is in flight (a monolithic prefill, a lag-0 decode step).
-        A latent-attention model's programs count on the device as
+        A model of another family's programs count on the device as
         they run (``cache.aux``, the model's own, cumulative); the array
         comes over in the same ``device_get`` as the tokens, after the
         same wait, and its growth goes to the registry."""
-        if not self._latent:
+        if self._aux_series is None:
             return np.asarray(tokens)
         got, aux = jax.device_get((tokens, self._cache.aux))
         self._publish_aux(aux)
@@ -2884,7 +2951,7 @@ class ContinuousBatchingServer:
         last call, each cell to the series its module named. Without
         ``aux`` the array is read from the pool: only where no program
         is in flight (``stats``, ``close``)."""
-        if not self._latent:
+        if self._aux_series is None:
             return
         if aux is None:
             aux = np.asarray(self._cache.aux)

@@ -274,7 +274,9 @@ SCOPES = frozenset((
     "fwd_bwd", "optimizer", "grad_exchange",
     # model_implementations/longcat_flash.py
     "mla_qkv", "latent_write", "mla_attn", "dense_ffn", "moe_router",
-    "moe_dispatch", "moe_experts", "moe_combine"))
+    "moe_dispatch", "moe_experts", "moe_combine",
+    # model_implementations/brumby.py
+    "ret_qkvg", "ret_state", "ret_out"))
 
 _INSTR = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+)\s*=\s")
 _COMPUTATION = re.compile(

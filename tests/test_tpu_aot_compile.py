@@ -104,7 +104,34 @@ def _layer_norm():
         ((2048,), jnp.float32)]
 
 
+def _retention(kind):
+    """The power retention kernels at Brumby-14B's widths (8 key/value
+    heads of 128 with 5 query heads each, 32 slots; a 1024-token bucket
+    in chunks of 256), the state pool float32."""
+    from deepspeed_tpu.ops.pallas import power_retention as pr
+    slots, KH, G, d, T = 32, 8, 5, 128, 1024
+    f32 = jnp.float32
+    pool = [((slots, KH, pr.pair_rows(d), d, d), f32),
+            ((slots, KH, pr.z_rows(d), d), f32)]
+    if kind == "decode":
+        fn = functools.partial(pr.power_retention_decode, eps=1e-6,
+                               interpret=False)
+        return fn, [((slots, KH, G, d), BF16), ((slots, KH, d), BF16),
+                    ((slots, KH, d), BF16), ((slots, KH), f32),
+                    ((slots,), jnp.bool_), *pool]
+
+    def fn(q, k, v, g, n, S, z, slot):
+        return pr.power_retention_prefill(q, k, v, g, n, S, z, slot,
+                                          chunk=256, eps=1e-6,
+                                          interpret=False)
+    return fn, [((T, KH, G, d), BF16), ((T, KH, d), BF16),
+                ((T, KH, d), BF16), ((T, KH), f32), ((), jnp.int32), *pool,
+                ((), jnp.int32)]
+
+
 CASES = {
+    "retention_decode": functools.partial(_retention, "decode"),
+    "retention_prefill": functools.partial(_retention, "prefill"),
     "decode": _dense_decode,
     "paged_decode-fp": _paged_decode,
     "paged_decode-int8": functools.partial(_paged_decode, int8=True),
@@ -302,6 +329,71 @@ def test_serving_programs_carry_their_names(chips, monkeypatch, kind,
             text, lambda dims, nbytes: dims[-2:] == pool.shape[-2:]
             and nbytes >= layer_k, pool_dims=pool.shape)
         assert compiled.memory_analysis().temp_size_in_bytes < layer_k
+
+
+RETENTION_SCOPES = {"embed", "ln", "ret_qkvg", "ret_state", "ret_out",
+                    "mlp", "lm_head", "sample"}
+
+
+@pytest.mark.parametrize("kind,kernel", [
+    ("decode", "power_retention_decode"),
+    ("prefill", "power_retention_prefill")])
+def test_retention_programs_update_the_state_in_place(chips, monkeypatch,
+                                                      kind, kernel):
+    """The state pool's two serving programs at the Brumby cell's widths
+    (two layers, a small vocabulary), read back from their compiled
+    text: module, kernel and scope names; ONE kernel call a layer (the
+    state crosses HBM once each way: no second pass reads it for the
+    queries); and apart from those calls no instruction writes anything
+    shaped like a slot's state or as large as a layer's pool, and the
+    program's temporaries are a few slots' worth at most (a prefill
+    writes its slot of the donated pool in place)."""
+    from deepspeed_tpu.inference.kv_cache import init_recurrent_state_cache
+    from deepspeed_tpu.inference.server import ContinuousBatchingServer as Srv
+    from deepspeed_tpu.model_implementations import brumby
+    from deepspeed_tpu.telemetry import compile_watch
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(chips[0])
+    layers, slots = 2, 32
+    cfg = brumby.BrumbyConfig(vocab_size=2048, num_hidden_layers=layers)
+
+    def abstract(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one), tree)
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    params = abstract(jax.eval_shape(
+        lambda: brumby.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = abstract(jax.eval_shape(lambda: init_recurrent_state_cache(
+        layers, slots, *cfg.state_shapes, aux_shape=cfg.aux_shape)))
+    fn, name, args = {
+        "decode": (Srv._decode_fn, "serve_decode",
+                   (params, arr((slots,)), cache,
+                    arr((slots,), jnp.bool_))),
+        "prefill": (Srv._prefill_fn, "serve_prefill",
+                    (params, arr((1, 1024)), arr((1,)), cache, arr(()))),
+    }[kind]
+    compiled = jax.jit(compile_watch._named(
+        functools.partial(fn, cfg=cfg, mesh=None), name),
+        donate_argnames=("cache",)).lower(*args).compile()
+    text = compiled.as_text()
+    assert f"HloModule jit_{name}" in text
+    scopes, kernels = compile_watch.parse_scopes(text)
+    assert set(kernels.values()) == {kernel}
+    assert len(kernels) == layers
+    assert all(scopes[k] == "ret_state" for k in kernels)
+    innermost = {v.rsplit("/", 1)[-1] for v in scopes.values() if v}
+    assert innermost >= RETENTION_SCOPES, RETENTION_SCOPES - innermost
+    pool = cache.S[0]
+    layer_pool = math.prod(pool.shape) * pool.dtype.itemsize
+    assert not _copies(
+        text, lambda dims, nbytes: dims[-3:] == pool.shape[-3:]
+        or nbytes >= layer_pool, kernels=kernels)
+    # decode: less than ONE slot's state of one layer; prefill: the
+    # activations of its 1024 tokens (74 MB), far under a layer's pool
+    room = cfg.state_bytes * (1 if kind == "decode" else 4)
+    assert compiled.memory_analysis().temp_size_in_bytes < room
 
 
 def test_train_model_kernels_and_scopes(chips, monkeypatch):
