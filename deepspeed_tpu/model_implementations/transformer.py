@@ -533,8 +533,8 @@ def _paged_kernel(kernel, q, cache: PagedKVCache, layer_idx: int,
     layer ``layer_idx`` of the pool through ``table``, causal bound
     ``bound`` (live lengths / chunk start). The kernel's operand is the
     pool as it is stored, all layers of it: the layer is a block offset
-    in the kernel's index map, so no K or V byte is copied between the
-    pool and the call. An int8 pool adds its two scale tiles (empty for
+    added to the table entries the kernel walks, so no K or V byte is
+    copied between the pool and the call. An int8 pool adds its two scale tiles (empty for
     fp pools — the call, and therefore the traced signature, is
     unchanged). Under the engine's mesh the kernel is mapped over the
     ``tensor`` axis: each shard attends its own kv heads of the pool

@@ -11,27 +11,61 @@ carries and where its causal bound starts, both of which ride as data.
 The kernel streams K/V blocks through VMEM with the online-softmax
 recurrence — no probability vector ever round-trips HBM. It consumes the
 paged pool WHERE IT LIES: the whole stacked ``[L, NB, BS, KH*D]`` array
-of kv_cache.PagedKVCache is the operand (merging the two leading dims
-moves no byte), and a layer is the static block offset ``layer * NB``
-the index map adds to every table entry — no program cuts a layer's K
-or V out of the pool or reorders a byte of it before the call. A pool
-block is DMA'd whole, as the contiguous ``[BS, KH*D]`` slab it is in
-HBM, and the kv heads are walked inside the kernel as static lane
-slices of that slab. (The TPU lowering only takes blocks whose last two
-dims are tile-aligned or span the array, so a block cannot pick one kv
-head out of ``KH`` — the per-head block of the earlier layout was
-refused by the chip's compiler.) Grouped-query attention is native: each
-kv head's slice is attended by its whole query group ``[rows, D]`` at
-once, so GQA's bandwidth saving survives.
+of kv_cache.PagedKVCache is the operand, left in HBM (merging the two
+leading dims moves no byte), and a layer is the static block offset
+``layer * NB`` added to every table entry — no program cuts a layer's K
+or V out of the pool or reorders a byte of it before the call.
+
+The walk. The grid is ``(slot, query-row block)`` and runs in order;
+one grid step walks its slot's LIVE blocks in a loop whose trip count,
+``ceil(positions seen / BS)``, is read from the scalar-prefetched
+bounds. Block ``j`` of the table is copied whole, as the contiguous
+``[BS, KH*D]`` slab it is in HBM, into one of two VMEM buffers
+(``make_async_copy``) while block ``j - 1`` is attended; the last
+block's iteration starts the FIRST block of the next grid step that has
+one, so the stream does not drain where one slot ends and the next
+begins. A dead table entry is never read and costs nothing; an idle
+slot (bound below zero) costs one empty grid step that writes zeros.
+Every byte the kernel moves is a live block's, every grid step but an
+idle slot's moves some, and what a step costs beyond its bytes is paid
+once a slot, not once a table entry.
+
+The arithmetic. K and V go to the MXU as they are stored (bfloat16
+pools: no float32 copy of a block; int8 blocks are cast to the query's
+dtype, which holds them exactly), the softmax scale is applied to the
+float32 scores, and the running max, sum and accumulator are float32.
+``p`` goes into ``p·v`` in the query's dtype; a 16-bit dtype takes it
+in two parts (what the cast keeps and what it drops, a product each
+into one float32 sum), so the probabilities keep float32's worth of
+mantissa: on the chip the second product costs 0.7 % of a call, and
+the served tokens are the float32-operand kernel's to the digit. How the kv heads of a block share the MXU is chosen from the
+static shapes alone:
+
+- few query rows in all (``KH * rows <= MAX_BATCHED_ROWS``: one-token
+  decode, small verify windows): the queries form a block-diagonal
+  ``[KH*rows, KH*D]`` operand (built once a slot in VMEM), so ONE
+  product against the slab gives all heads' scores ``[KH*rows, BS]``
+  with the heads down the sublanes — one max / exp / sum over full
+  registers — and ``p·v`` ``[KH*rows, KH*D]`` holds every head's output
+  in its diagonal ``D``-wide lane block, picked out once at the end.
+  Each K and V tile passes through the MXU once, as it would anyway;
+  what goes is a product, a cast and a one-sublane softmax per head.
+- more rows (prefill chunks, wide verify windows): a product per kv
+  head at M = rows against that head's static lane slice of the slab
+  (the TPU lowering only takes blocks whose last two dims are
+  tile-aligned or span the array, so a copy cannot pick one kv head out
+  of ``KH``). Grouped-query attention is native on both paths: a kv
+  head's slice is attended by its whole query group at once, so GQA's
+  bandwidth saving survives.
 
 int8 pools (kv_cache_dtype: "int8", docs/serving.md "KV quantization &
 host tiering") add per-block-per-head scale tiles ``[L, NB, KH, BS]``
 (one amax/127 scale per written (position, head) row, block_size on the
-LANE dim), read through the same index map. The HBM stream is the int8
-bytes; the scales are applied in VMEM as ``[1, BS]`` rows against the
-score / probability matrices (``(q·kᵀ)·s_k`` and ``(p·s_v)·v`` —
-algebraically the dequantized product, without ever turning a scale row
-into a column).
+LANE dim) that ride the same walk: two more copies a block into two
+more pairs of buffers. The HBM stream is the int8 bytes; the scales are
+applied in VMEM as rows against the score / probability matrices
+(``(q·kᵀ)·s_k`` and ``(p·s_v)·v`` — algebraically the dequantized
+product, without ever turning a scale row into a column).
 """
 from __future__ import annotations
 
@@ -48,6 +82,11 @@ DEFAULT_BLOCK_K = 256
 # head: bounds the q/out blocks and the online-softmax scratch in VMEM
 # (~7 MiB at KH=16, D=128) however long a prefill chunk is
 MAX_QUERY_ROWS = 128
+# query rows of ALL kv heads that one product may carry: up to here the
+# heads of a block share a block-diagonal product, beyond it each head
+# has rows enough for a product of its own (the shared accumulator grows
+# with the square of the head count)
+MAX_BATCHED_ROWS = 32
 
 
 def _layer_pools(k_pool, v_pool, D, k_scale, v_scale):
@@ -64,69 +103,214 @@ def _layer_pools(k_pool, v_pool, D, k_scale, v_scale):
     return k, v
 
 
-def _paged_kernel(base_ref, bt_ref, q_ref, k_ref, v_ref, *rest,
+def _live_blocks(seen, block_size: int, max_blocks: int):
+    """Table entries a query that sees ``seen`` positions has to walk."""
+    blocks = jax.lax.div(jnp.maximum(seen, 0) + (block_size - 1), block_size)
+    return jnp.minimum(blocks, max_blocks)
+
+
+def _paged_kernel(base_ref, bt_ref, offset_ref, q_ref, *rest,
                   block_size: int, head_dim: int, rep: int, span: int,
-                  scale: float, quantized: bool):
-    """Grid (slot, query-row block, block-table entry). The index maps
-    gather whole K/V pool blocks through the scalar-prefetched block
-    table, so no per-slot contiguous cache is ever materialized in HBM.
+                  scale: float, quantized: bool, batched: bool):
+    """Grid (slot, query-row block): one step walks ITS slot's live
+    blocks. The pools stay in HBM; block ``bt[slot, j] + offset[0]``
+    (a layer is ``layer * NB`` blocks in) is copied whole into one of
+    two VMEM buffers while block ``j - 1`` is attended, and the last
+    block's iteration starts the first block of the NEXT grid step that
+    has one, so the stream does not drain at a slot's edge (the grid
+    runs in order on one core; which buffer is next rides in SMEM). The
+    trip count ``ceil(positions seen / BS)`` is read from the
+    scalar-prefetched ``base``: a dead table entry costs nothing, an
+    idle slot one grid step that writes zeros.
+
     Query rows are (token, group member) pairs, token-major, ``span``
     tokens per row block; key position ``col`` is visible to the row's
-    token ``t`` iff ``col <= base[slot] + t``. Online-softmax state
-    carries across the (innermost) table axis in VMEM scratch, one
-    ``[rows, ·]`` plane per kv head."""
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        ks_ref = vs_ref = None
-        o_ref, m_ref, l_ref, acc_ref = rest
-    s, rb, i = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    nb = pl.num_programs(2)
-    KH, rows = q_ref.shape[1], q_ref.shape[2]
-    base = base_ref[s] + rb * span     # bound of this block's first token
+    token ``t`` iff ``col <= base[slot] + t``. K and V enter the MXU as
+    stored (an int8 block cast to the query's dtype, exactly), p in two
+    parts when that dtype has 16 bits; the scores, ``m``, ``l`` and the
+    accumulator are float32. How the heads
+    share a block's products is static (``batched``):
 
-    @pl.when(i == 0)
-    def _init():
+    - few rows a head (decode): q is the block-diagonal ``[KH*rows,
+      KH*D]`` operand, so ONE product against the slab ``[BS, KH*D]``
+      gives every head's scores with heads down the sublanes
+      (``[KH*rows, BS]``: one max / exp / sum over full registers), and
+      ``p @ v`` gives every head's output in the diagonal ``D``-wide
+      lane blocks of one ``[KH*rows, KH*D]`` accumulator, picked out at
+      the end;
+    - else (verify, prefill chunks) a product per kv head at M = rows
+      against that head's lane slice of the slab, one ``[rows, ·]``
+      scratch plane a head.
+    """
+    if quantized:
+        (k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf,
+         sems, next_buf, m_ref, l_ref, acc_ref, *rest) = rest
+        streams = ((k_hbm, k_buf), (v_hbm, v_buf), (ks_hbm, ks_buf),
+                   (vs_hbm, vs_buf))
+    else:
+        (k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, next_buf, m_ref, l_ref,
+         acc_ref, *rest) = rest
+        ks_buf = vs_buf = None
+        streams = ((k_hbm, k_buf), (v_hbm, v_buf))
+    qbd_ref, = rest or (None,)      # the block-diagonal q, if batched
+    s, rb = pl.program_id(0), pl.program_id(1)
+    S, RB = pl.num_programs(0), pl.num_programs(1)
+    MB = bt_ref.shape[1]
+    D, BS = head_dim, block_size
+    KH = k_buf.shape[-1] // D
+    rows = acc_ref.shape[-2] // KH if batched else acc_ref.shape[-2]
+    cdt = q_ref.dtype
+
+    def copies(slot, j, buf):
+        block = bt_ref[slot, j] + offset_ref[0]
+        return [pltpu.make_async_copy(hbm.at[block], vmem.at[buf],
+                                      sems.at[i, buf])
+                for i, (hbm, vmem) in enumerate(streams)]
+
+    def start(slot, j, buf):
+        for copy in copies(slot, j, buf):
+            copy.start()
+
+    base = base_ref[s] + rb * span     # bound of this block's first token
+    n = _live_blocks(base + span, BS, MB)
+    # the grid step after this one, and whether it has a block to fetch
+    wraps = rb + 1 == RB
+    s_next = jnp.minimum(jnp.where(wraps, s + 1, s), S - 1)
+    rb_next = jnp.where(wraps, 0, rb + 1)
+    n_next = jnp.where(
+        jnp.logical_and(wraps, s + 1 == S), 0,
+        _live_blocks(base_ref[s_next] + (rb_next + 1) * span, BS, MB))
+
+    def start_next(buf):
+        @pl.when(n_next > 0)
+        def _():
+            start(s_next, 0, buf)
+
+    @pl.when(jnp.logical_and(s == 0, rb == 0))
+    def _first():
+        next_buf[0] = 0
+
+        @pl.when(n > 0)
+        def _():
+            start(s, 0, 0)
+
+    buf0 = next_buf[0]      # where this step's first block is landing
+
+    @pl.when(n == 0)
+    def _idle():
+        o_ref[...] = jnp.zeros_like(o_ref)
+        start_next(buf0)
+
+    @pl.when(n > 0)
+    def _walk():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    # blocks wholly beyond the last query's bound are dead for every row
-    @pl.when(i * block_size <= base + span - 1)
-    def _update():
-        col = i * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, block_size), 1)
+        R = acc_ref.shape[-2]
+        row = jax.lax.broadcasted_iota(jnp.int32, (R, BS), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (R, BS), 1)
+        # a row's bound; a block compares it less its own first column
         bound = base
         if span > 1:
-            bound = base + jax.lax.broadcasted_iota(
-                jnp.int32, (rows, block_size), 0) // rep
-        visible = col <= bound
-        for h in range(KH):
-            lanes = slice(h * head_dim, (h + 1) * head_dim)
-            q = q_ref[0, h].astype(jnp.float32) * scale    # [rows, D]
-            k = k_ref[0, :, lanes].astype(jnp.float32)     # [BS, D]
-            v = v_ref[0, :, lanes].astype(jnp.float32)
-            sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            if quantized:
-                sc = sc * ks_ref[0, h:h + 1, :]
+            bound = base + (row % rows if batched else row) // rep
+
+        def own_head(pick, width, zero=0.0):
+            """Batched rows: ``pick(h)`` (``[R or 1, width]``) where row
+            r belongs to head h, zero elsewhere, for every h."""
+            head = jax.lax.broadcasted_iota(jnp.int32, (R, width), 0) // rows
+            return [jnp.where(head == h, pick(h), zero) for h in range(KH)]
+
+        if batched:     # q on the diagonal, one lane block a head
+            zero = jnp.zeros((), cdt)
+            for h, q in enumerate(own_head(lambda h: q_ref[0], D, zero)):
+                qbd_ref[:, h * D:(h + 1) * D] = q
+
+        def rows_of(tile):
+            """``[KH, BS]`` scale tile -> a scale row per query row."""
+            if rows == 1:
+                return tile
+            return functools.reduce(
+                jnp.add, own_head(lambda h: tile[h:h + 1, :], BS))
+
+        def softmax_step(sc, m_prev, l_prev, visible):
             sc = jnp.where(visible, sc, NEG_INF)
-            m_prev, l_prev = m_ref[h], l_ref[h]
             m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
             p = jnp.exp(sc - m_new)
             alpha = jnp.exp(m_prev - m_new)
-            m_ref[h] = m_new
-            l_ref[h] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            if quantized:
-                p = p * vs_ref[0, h:h + 1, :]
-            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            return p, alpha, m_new, l_prev * alpha + jnp.sum(
+                p, axis=-1, keepdims=True)
 
-    @pl.when(i == nb - 1)
-    def _finish():
-        o_ref[0] = (acc_ref[...] /
-                    jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        def operand(x):
+            return x if x.dtype == cdt else x.astype(cdt)
+
+        def p_dot_v(p, v):
+            """``p @ v`` with p at float32's worth of mantissa: in a
+            16-bit dtype as two parts (what the cast keeps, and what it
+            drops), a product each into one float32 sum."""
+            def dot(part):
+                return jax.lax.dot_general(
+                    part, v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+            kept = p.astype(cdt)
+            if cdt == jnp.float32:
+                return dot(kept)
+            return dot(kept) + dot((p - kept.astype(jnp.float32)).astype(cdt))
+
+        def attend(j, buf):
+            visible = col <= bound - j * BS
+            if batched:
+                sc = jax.lax.dot_general(
+                    qbd_ref[...], operand(k_buf[buf]),
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                if quantized:
+                    sc = sc * rows_of(ks_buf[buf])
+                p, alpha, m_ref[...], l_ref[...] = softmax_step(
+                    sc, m_ref[...], l_ref[...], visible)
+                if quantized:
+                    p = p * rows_of(vs_buf[buf])
+                acc_ref[...] = acc_ref[...] * alpha + p_dot_v(
+                    p, operand(v_buf[buf]))
+                return
+            for h in range(KH):
+                lanes = slice(h * D, (h + 1) * D)
+                sc = jax.lax.dot_general(
+                    q_ref[0, h], operand(k_buf[buf, :, lanes]),
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                if quantized:
+                    sc = sc * ks_buf[buf, h:h + 1, :]
+                p, alpha, m_ref[h], l_ref[h] = softmax_step(
+                    sc, m_ref[h], l_ref[h], visible)
+                if quantized:
+                    p = p * vs_buf[buf, h:h + 1, :]
+                acc_ref[h] = acc_ref[h] * alpha + p_dot_v(
+                    p, operand(v_buf[buf, :, lanes]))
+
+        def block(j, carry):
+            buf = (buf0 + j) % 2
+
+            @pl.when(j + 1 < n)
+            def _():
+                start(s, j + 1, 1 - buf)
+
+            @pl.when(j + 1 == n)
+            def _():
+                start_next(1 - buf)
+            for copy in copies(s, j, buf):
+                copy.wait()
+            attend(j, buf)
+            return carry
+
+        jax.lax.fori_loop(0, n, block, 0)
+        next_buf[0] = (buf0 + n) % 2
+        l = jnp.maximum(l_ref[...], 1e-30)
+        if batched:     # each row's own diagonal block of the accumulator
+            out = functools.reduce(jnp.add, own_head(
+                lambda h: acc_ref[:, h * D:(h + 1) * D], D))
+            o_ref[0] = (out / l).astype(o_ref.dtype)
+        else:
+            o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def _paged_attention(qg, k_pool, v_pool, block_tables, base, *, rep: int,
@@ -138,13 +322,16 @@ def _paged_attention(qg, k_pool, v_pool, block_tables, base, *, rep: int,
     (each slot's T query tokens x ``rep`` group members, token-major,
     grouped by the kv head they read); pools ``[L, NB, BS, KH*D]``, the
     whole stacked pool, of which the call attends layer ``layer``
-    (static); block_tables ``[S, MB]`` of that layer's block ids (dead
-    entries must be valid ids — the null block); base ``[S]``: slot s's
+    (static); block_tables ``[S, MB]`` of that layer's block ids (only
+    the entries a slot's bound reaches are read); base ``[S]``: slot s's
     token t sees key positions ``<= base[s] + t``. Returns
-    ``[S, KH, T*rep, D]``."""
+    ``[S, KH, T*rep, D]``.
+
+    The layer reaches the kernel as DATA (its block offset, one more
+    prefetched scalar), so the calls of a model's layers are one traced
+    kernel reused (:func:`_paged_call`), not a trace a layer."""
     S, KH, rows, D = qg.shape
     L, NB, BS, W = k_pool.shape
-    MB = block_tables.shape[1]
     quantized = k_scale is not None
     if (k_pool.dtype == jnp.int8) != quantized:
         raise ValueError("int8 pools require k_scale/v_scale (and fp "
@@ -158,6 +345,36 @@ def _paged_attention(qg, k_pool, v_pool, block_tables, base, *, rep: int,
         scale = 1.0 / (D ** 0.5)
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    # the layers' blocks back to back: merging the two leading dims of
+    # the stored array is free, and layer l's block b is block l*NB + b
+    pools = [k_pool.reshape(L * NB, BS, W), v_pool.reshape(L * NB, BS, W)]
+    if quantized:
+        pools += [k_scale.reshape(L * NB, KH, BS),
+                  v_scale.reshape(L * NB, KH, BS)]
+    call = _paged_call(
+        name, bool(interpret), (S, KH, rows, D), qg.dtype.name,
+        tuple((x.shape, x.dtype.name) for x in pools),
+        block_tables.shape[1], rep, float(scale))
+    return call(base.astype(jnp.int32), block_tables.astype(jnp.int32),
+                jnp.full((1,), layer * NB, jnp.int32), qg, *pools)
+
+
+@functools.lru_cache(maxsize=None)
+def _paged_call(name: str, interpret: bool, q_shape, q_dtype: str, pools,
+                MB: int, rep: int, scale: float):
+    """The ``pallas_call`` of one static signature: ``(base [S], tables
+    [S, MB], block offset [1], qg [S, KH, rows, D], *pools) -> [S, KH,
+    rows, D]``. Grid ``(S, row blocks)``, in order; the pools (``pools``:
+    shape and dtype of K, V and an int8 pool's two scale-tile arrays,
+    layers merged into the block dim) stay in HBM and
+    :func:`_paged_kernel` copies the blocks it walks into two VMEM
+    buffers a stream. The heads share one product a block when all
+    their rows fit ``MAX_BATCHED_ROWS``. Kept per signature, because
+    jax traces a call it has seen before from its cache: the 24 layers
+    of a decode program trace the kernel body once."""
+    S, KH, rows, D = q_shape
+    (_, BS, W), pool_dtype = pools[0]
+    batched = KH * rows <= MAX_BATCHED_ROWS
     # tokens per row block: halve while the rows overrun the VMEM budget
     # and the halves still tile (a split block's sublane dim must be a
     # multiple of 8)
@@ -166,48 +383,45 @@ def _paged_attention(qg, k_pool, v_pool, block_tables, base, *, rep: int,
            and (span // 2 * rep) % 8 == 0):
         span //= 2
     rblk = span * rep
-
-    def kv_map(s, rb, i, base, bt):
-        # dead table entries re-name the slot's last live block: an
-        # unchanged block index skips the DMA, so the dead tail of a
-        # table costs neither bandwidth nor (pl.when above) compute
-        last = jnp.maximum(base[s] + (rb + 1) * span - 1, 0) // BS
-        return (bt[s, jnp.minimum(i, last)] + layer * NB, 0, 0)
-
-    def q_map(s, rb, i, base, bt):
-        return (s, 0, rb, 0)
-
-    # the layers' blocks back to back: merging the two leading dims of
-    # the stored array is free, and layer l's block b is block l*NB + b
-    kv_spec = pl.BlockSpec((1, BS, W), kv_map)
-    in_specs = [pl.BlockSpec((1, KH, rblk, D), q_map), kv_spec, kv_spec]
-    args = [base.astype(jnp.int32), block_tables.astype(jnp.int32), qg,
-            k_pool.reshape(L * NB, BS, W), v_pool.reshape(L * NB, BS, W)]
-    if quantized:
-        in_specs += [pl.BlockSpec((1, KH, BS), kv_map)] * 2
-        args += [k_scale.reshape(L * NB, KH, BS),
-                 v_scale.reshape(L * NB, KH, BS)]
+    f32 = jnp.float32
+    if batched:
+        # heads down the rows: merging the two dims moves no byte
+        q_block = (S, KH * rows, D)
+        q_spec = pl.BlockSpec((1, KH * rows, D), lambda s, rb, *_: (s, 0, 0))
+        softmax_state = [pltpu.VMEM((KH * rows, 1), f32)] * 2 + [
+            pltpu.VMEM((KH * rows, W), f32),
+            pltpu.VMEM((KH * rows, W), q_dtype)]    # block-diagonal q
+    else:
+        q_block = q_shape
+        q_spec = pl.BlockSpec((1, KH, rblk, D),
+                              lambda s, rb, *_: (s, 0, rb, 0))
+        softmax_state = [pltpu.VMEM((KH, rblk, 1), f32)] * 2 + [
+            pltpu.VMEM((KH, rblk, D), f32)]
     kernel = functools.partial(
         _paged_kernel, block_size=BS, head_dim=D, rep=rep, span=span,
-        scale=float(scale), quantized=quantized)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(S, rows // rblk, MB),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, KH, rblk, D), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((KH, rblk, 1), jnp.float32),
-            pltpu.VMEM((KH, rblk, 1), jnp.float32),
-            pltpu.VMEM((KH, rblk, D), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
+        scale=scale, quantized=len(pools) == 4, batched=batched)
+    call = pl.pallas_call(
         kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, KH, rows, D), qg.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(S, rows // rblk),
+            in_specs=[q_spec] + [pl.BlockSpec(memory_space=pl.ANY)
+                                 ] * len(pools),
+            out_specs=q_spec,
+            scratch_shapes=[
+                *[pltpu.VMEM((2, *shape[1:]), dtype)
+                  for shape, dtype in pools],
+                pltpu.SemaphoreType.DMA((len(pools), 2)),
+                pltpu.SMEM((1,), jnp.int32), *softmax_state]),
+        out_shape=jax.ShapeDtypeStruct(q_block, q_dtype),
+        # in order: a step starts the next step's first block
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name=name,
-    )(*args)
+    )
+    return lambda base, tables, offset, qg, *pools: call(
+        base, tables, offset, qg.reshape(q_block), *pools).reshape(q_shape)
 
 
 def _group_size(H: int, KH: int) -> int:
@@ -261,14 +475,14 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     ``[L, NB, BS, KH*D]`` (the PagedKVCache pool as stored, all layers;
     the call attends layer ``layer``, a static int);
     block_tables: ``[S, MB]`` int32 (entry j covers logical positions
-    ``j*BS..(j+1)*BS-1``; dead entries must be valid ids — the null
-    block); lengths: ``[S]`` int32 live lengths (the query attends
+    ``j*BS..(j+1)*BS-1``; entries beyond a slot's length are never
+    read); lengths: ``[S]`` int32 live lengths (the query attends
     positions ``< lengths[s]``). Returns ``[S, H, D]``.
 
     int8 pools pass ``k_scale``/``v_scale`` ``[L, NB, KH, BS]``; the
-    grid, scratch and recurrence are unchanged (scales are two more
-    streamed inputs, not a new program structure). An idle slot (length
-    0) costs no compute and one null-block DMA.
+    grid, the walk and the recurrence are unchanged (scales are two
+    more streams of the same block walk, not a new program structure).
+    An idle slot (length 0) reads nothing and returns zeros.
     """
     S, H, D = q.shape
     KH = k_pool.shape[-1] // D
@@ -296,8 +510,8 @@ def paged_chunk_attention(q: jax.Array, k_pool: jax.Array,
     ``start..start+C-1``; the chunk's own k/v must already be written
     into the pool); k_pool/v_pool: ``[L, NB, BS, KH*D]``, of which
     layer ``layer`` is attended; block_table:
-    ``[MB]`` int32 (the prefilling slot's row; dead entries must be
-    valid ids — the null block); start: scalar int32, block-aligned.
+    ``[MB]`` int32 (the prefilling slot's row; entries beyond the chunk's
+    end are never read); start: scalar int32, block-aligned.
     The chunk attends the already-resident prefix (earlier chunks AND
     prefix-cache hits) plus itself: key position ``col`` is visible to
     chunk query ``qi`` iff ``col <= start + qi``. int8 pools pass
@@ -326,8 +540,8 @@ def paged_verify_attention(q: jax.Array, k_pool: jax.Array,
     k/v must already be written into the pool —
     kv_cache.paged_write_tokens); k_pool/v_pool: ``[L, NB, BS, KH*D]``,
     of which layer ``layer`` is attended;
-    block_tables: ``[S, MB]`` int32 (dead entries must be valid ids —
-    the null block); lengths: ``[S]`` int32 live lengths per slot.
+    block_tables: ``[S, MB]`` int32 (entries beyond a slot's window are
+    never read); lengths: ``[S]`` int32 live lengths per slot.
     Per-query causal bound ``col <= lengths[s] + qi``. Returns
     ``[S, K, H, D]``.
 

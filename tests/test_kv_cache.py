@@ -255,7 +255,7 @@ def test_paged_garbage_beyond_lengths_invisible_with_k_gt_1():
 # block offset in their index map: reading the wrong layer is the new
 # way to be wrong, so every test below gives every layer its own data.
 
-def _layered_pools(L, NB, BS, KH, D, quantized):
+def _layered_pools(L, NB, BS, KH, D, quantized, dtype=jnp.float32):
     """``(k, v, scales)``: pools ``[L, NB, BS, KH*D]`` as PagedKVCache
     stores them (int8 + ``[L, NB, KH, BS]`` scale tiles when
     ``quantized``), every layer and the null block 0 holding their own
@@ -267,45 +267,116 @@ def _layered_pools(L, NB, BS, KH, D, quantized):
         if quantized:
             pool, s = quantize_int8(pool, -1)
             scales[f"{name}_scale"] = s[..., 0].transpose(0, 1, 3, 2)
+        else:
+            pool = pool.astype(dtype)
         out.append(pool.reshape(L, NB, BS, KH * D))
     return out[0], out[1], scales
 
 
-@pytest.mark.parametrize("quantized", [False, True], ids=["fp", "int8"])
-@pytest.mark.parametrize("kind", ["decode", "verify", "chunk"])
-def test_paged_kernels_attend_their_own_layer(kind, quantized):
+def _poisoned(k, v, scales, tables, seen):
+    """The pools with every byte a right walk never uses made lethal:
+    rows at or beyond ``seen[slot]`` positions of a slot's last live
+    block hold large finite garbage (a missing bound shows), and every
+    block no slot's walk reaches, the null block among them, holds NaN
+    (an int8 pool: NaN scales), in every layer, so one block too many
+    shows however it is masked."""
+    L, NB, BS, W = k.shape
+    live = np.zeros((NB, BS), bool)       # rows some slot attends
+    reached = np.zeros(NB, bool)          # blocks some walk fetches
+    for row, n in zip(tables, seen):
+        for pos in range(n):
+            live[row[pos // BS], pos % BS] = True
+        reached[row[:-(-n // BS)]] = True
+    tail = jnp.asarray(reached[:, None] & ~live)[None, :, :, None]
+    dead = jnp.asarray(~reached)
+    if scales:
+        fill = lambda pool, big: jnp.where(tail, jnp.int8(big), pool)
+        kill = lambda tile: jnp.where(dead[None, :, None, None], jnp.nan,
+                                      tile)
+        return fill(k, 127), fill(v, -127), {n: kill(t)
+                                             for n, t in scales.items()}
+    fill = lambda pool, big: jnp.where(
+        dead[None, :, None, None], jnp.nan,
+        jnp.where(tail, big, pool)).astype(pool.dtype)
+    return fill(k, 3e4), fill(v, -3e4), {}
+
+
+# what the one kernel body is asked for: one query a slot, a verify
+# window, a prefill chunk of one and of several row blocks
+_KINDS = {"decode": None, "verify": 4, "chunk128": 128, "chunk256": 256}
+# pool: (dtype of q and a float pool, int8 pool + scales, tolerance)
+_POOLS = {"fp32": (jnp.float32, False, 2e-5),
+          "bf16": (jnp.bfloat16, False, 3e-2),
+          "int8": (jnp.float32, True, 2e-5)}
+
+
+@pytest.mark.parametrize("pool", sorted(_POOLS))
+@pytest.mark.parametrize("heads", ["mha", "gqa4"])
+@pytest.mark.parametrize("kind", sorted(_KINDS))
+def test_paged_kernels_attend_their_own_layer(kind, heads, pool):
     """Each paged kernel (interpret mode) over a three-layer pool whose
-    layers hold different data, every layer against the per-layer
-    oracle: block-table indirection, a table whose dead tail names the
-    null block (which holds garbage of its own), GQA grouping, an idle
-    slot."""
+    layers hold different data, first and last layer against the
+    per-layer float32 oracle: a shuffled block table, ragged bounds (an
+    idle slot, 1, one short of a block, a block, one past it, the whole
+    table), heads batched into one product (decode; GQA verify) and a
+    product a head (MHA verify, chunks; a 256-token chunk is several
+    row blocks), and a pool POISONED wherever a right walk does not
+    look (:func:`_poisoned`): the oracle reads the clean pool."""
     from deepspeed_tpu.ops.pallas import decode_attention as da
-    L, S, H, KH, D, NB, BS, Kq = 3, 3, 8, 2, 16, 12, 32, 4
-    k, v, scales = _layered_pools(L, NB, BS, KH, D, quantized)
-    bt = jnp.asarray([[3, 5, 0, 0], [1, 2, 7, 9], [0, 0, 0, 0]], jnp.int32)
-    lens = jnp.asarray([40, 100, 0], jnp.int32)
-    q, table, bound = {
-        "decode": (_rand(0, (S, H, D)), bt, lens),
-        "verify": (_rand(0, (S, Kq, H, D)), bt, lens),
-        "chunk": (_rand(0, (BS, H, D)), bt[1], jnp.int32(2 * BS)),
-    }[kind]
-    kernel = getattr(da, f"paged_{kind}_attention")
-    oracle = getattr(da, f"paged_{kind}_attention_reference")
+    dtype, quantized, tol = _POOLS[pool]
+    H, KH = {"mha": (16, 16), "gqa4": (8, 2)}[heads]
+    L, D, NB, BS, MB, T = 3, 16, 24, 32, 12, _KINDS[kind]
+    k, v, scales = _layered_pools(L, NB, BS, KH, D, quantized, dtype)
+    ids = iter(np.random.default_rng(5).permutation(np.arange(1, NB)))
+    both = (0, L - 1)
+    if kind.startswith("chunk"):
+        # one slot's table; the chunk at the table's start (both layers)
+        # and at its end (one will do)
+        calls = []
+        for start, layers in ((0, both), (MB * BS - T, both[1:])):
+            row = np.zeros(MB, np.int32)
+            blocks = (start + T) // BS
+            row[:blocks] = [next(ids) for _ in range(blocks)]
+            calls.append((_rand(start, (T, H, D)).astype(dtype), row,
+                          jnp.int32(start), [start + T], layers))
+        kernel, oracle = (da.paged_chunk_attention,
+                          da.paged_chunk_attention_reference)
+    else:
+        # live lengths; a verify window sees its own K tokens beyond them
+        K = T or 0
+        lens = np.asarray([0, 1, BS - 1, BS, BS + 1, MB * BS - K])
+        seen = [int(n) + K for n in lens]
+        bt = np.zeros((len(lens), MB), np.int32)
+        for slot, n in enumerate(seen):
+            bt[slot, :-(-n // BS)] = [next(ids) for _ in range(-(-n // BS))]
+        q = _rand(0, (len(lens), K, H, D) if T else (len(lens), H, D))
+        calls = [(q.astype(dtype), bt, jnp.asarray(lens, jnp.int32), seen,
+                  both)]
+        kernel, oracle = ((da.paged_verify_attention,
+                           da.paged_verify_attention_reference) if T else
+                          (da.paged_decode_attention,
+                           da.paged_decode_attention_reference))
     outs = []
-    for layer in range(L):
-        got = kernel(q, k, v, table, bound, interpret=True, layer=layer,
-                     **scales)
-        want = oracle(q, k[layer], v[layer], table, bound,
-                      **{n: s[layer] for n, s in scales.items()})
-        live = slice(0, 2) if kind == "decode" else slice(None)
-        np.testing.assert_allclose(np.asarray(got[live]),
-                                   np.asarray(want[live]),
-                                   rtol=2e-5, atol=2e-5,
-                                   err_msg=f"layer {layer}")
-        outs.append(np.asarray(got))
+    for q, table, bound, seen, layers in calls:
+        bad_k, bad_v, bad_scales = _poisoned(k, v, scales,
+                                             np.atleast_2d(table), seen)
+        table = jnp.asarray(table)
+        for layer in layers:
+            got = kernel(q, bad_k, bad_v, table, bound, interpret=True,
+                         layer=layer, **bad_scales)
+            want = oracle(q.astype(jnp.float32), k[layer], v[layer], table,
+                          bound,
+                          **{n: s[layer] for n, s in scales.items()})
+            got = np.asarray(got.astype(jnp.float32))
+            live = np.asarray(seen) > 0 if T is None else slice(None)
+            np.testing.assert_allclose(got[live], np.asarray(want)[live],
+                                       rtol=tol, atol=tol,
+                                       err_msg=f"layer {layer}")
+            if T is None:                  # an idle slot reads nothing
+                assert not got[~live].any()
+            outs.append(got)
     # the layers' answers differ, so a wrong offset cannot pass above
-    assert not np.allclose(outs[0], outs[1], atol=1e-2)
-    assert not np.allclose(outs[1], outs[2], atol=1e-2)
+    assert not np.allclose(outs[0], outs[1], atol=5e-2)
     with pytest.raises(ValueError, match="3-layer pool"):
         kernel(q, k, v, table, bound, interpret=True, layer=L, **scales)
 

@@ -171,17 +171,31 @@ class TestFlashAttention:
 
 
 class TestDecodeAttention:
-    def test_parity_with_ragged_lengths(self):
-        B, H, S, D = 3, 4, 512, 64
+    @pytest.mark.parametrize("block_k", [128, 256])
+    @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-4),
+                                           (jnp.bfloat16, 3e-2)],
+                             ids=["fp32", "bf16"])
+    def test_parity_with_ragged_lengths(self, dtype, tol, block_k):
+        # a dense cache is a one-layer pool with an identity table: the
+        # lengths sit on every edge of a block, and the rows beyond a
+        # length hold garbage that a missing bound would let in
+        B, H, S, D = 6, 4, 512, 64
         key = jax.random.PRNGKey(1)
+        lengths = jnp.asarray([1, block_k - 1, block_k, block_k + 1, 200,
+                               S], jnp.int32)
+        dead = (jnp.arange(S)[None, :] >= lengths[:, None])[..., None, None]
         q = jax.random.normal(jax.random.fold_in(key, 0), (B, H, D))
         kc = jax.random.normal(jax.random.fold_in(key, 1), (B, S, H, D))
         vc = jax.random.normal(jax.random.fold_in(key, 2), (B, S, H, D))
-        lengths = jnp.asarray([1, 200, 512], jnp.int32)
-        o = decode_attention(q, kc, vc, lengths)
-        o_ref = decode_attention_reference(q, kc, vc, lengths)
-        np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
-                                   rtol=2e-4, atol=2e-4)
+        q, kc, vc = (x.astype(dtype) for x in (q, kc, vc))
+        o = decode_attention(q, jnp.where(dead, 3e4, kc).astype(dtype),
+                             jnp.where(dead, -3e4, vc).astype(dtype),
+                             lengths, block_k=block_k)
+        o_ref = decode_attention_reference(
+            q.astype(jnp.float32), kc.astype(jnp.float32),
+            vc.astype(jnp.float32), lengths)
+        np.testing.assert_allclose(np.asarray(o.astype(jnp.float32)),
+                                   np.asarray(o_ref), rtol=tol, atol=tol)
 
     def test_single_token_is_value(self):
         # with length 1, the output must equal v_cache[:, :, 0]
@@ -195,10 +209,13 @@ class TestDecodeAttention:
         np.testing.assert_allclose(np.asarray(o), np.asarray(vc[:, 0]),
                                    rtol=1e-5, atol=1e-5)
 
-    def test_gqa_native_groups(self):
-        # H=8 query heads over KH=2 kv heads: the kernel must match the
-        # expanded reference WITHOUT materializing repeated k/v
-        B, H, KH, S, D = 2, 8, 2, 256, 64
+    @pytest.mark.parametrize("H,KH", [(8, 2), (8, 4), (48, 12)],
+                             ids=["rep4", "rep2", "a-product-a-head"])
+    def test_gqa_native_groups(self, H, KH):
+        # query heads in groups over KH kv heads: the kernel must match
+        # the expanded reference WITHOUT materializing repeated k/v,
+        # whether the heads share one product (few rows in all) or not
+        B, S, D = 2, 256, 64
         key = jax.random.PRNGKey(3)
         q = jax.random.normal(jax.random.fold_in(key, 0), (B, H, D))
         kc = jax.random.normal(jax.random.fold_in(key, 1), (B, S, KH, D))

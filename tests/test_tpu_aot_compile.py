@@ -140,6 +140,9 @@ CASES = {
     "paged_verify-fp": _paged_verify,
     "paged_verify-int8": functools.partial(_paged_verify, int8=True),
     "paged_decode-gqa-kh4": functools.partial(_paged_decode, KH=4),
+    "paged_verify-gqa-kh4": functools.partial(_paged_verify, KH=4),
+    "paged_chunk-gqa-kh4-int8": functools.partial(_paged_chunk, KH=4,
+                                                  int8=True),
     "paged_decode-d64-int8": functools.partial(_paged_decode, D=64,
                                                int8=True),
     "flash-fwd": functools.partial(_flash, grad=False),
@@ -206,17 +209,21 @@ def _copies(text, is_big, pool_dims=None, kernels=()):
     return out
 
 
-def test_kernel_maps_over_a_mesh(chips):
+@pytest.mark.parametrize("kind", ["decode", "verify", "chunk"])
+def test_kernel_maps_over_a_mesh(chips, kind):
     """GSPMD cannot partition a Mosaic call ("wrap the call in a
-    shard_map"): on four devices the paged decode kernel goes through
+    shard_map"): on four devices a paged kernel goes through
     ``map_kernel`` over the kv-head axis (the major part of the pool's
     lane dim), as tensor-parallel serving lays the pool out — and the
-    compiler must not have gathered the pool to make that work."""
+    compiler must not have gathered the pool to make that work. A shard
+    walks the same blocks over its own four heads' lanes."""
     from deepspeed_tpu.utils.sharding import map_kernel
     mesh = Mesh(np.asarray(chips).reshape(1, 1, 4),
                 ("expert", "seq", "tensor"))
-    fn, shapes = _paged_decode()
-    q, pool = P(None, "tensor", None), P(None, None, None, "tensor")
+    fn, shapes = {"decode": _paged_decode, "verify": _paged_verify,
+                  "chunk": _paged_chunk}[kind]()
+    q = P(*[None] * (len(shapes[0][0]) - 2), "tensor", None)
+    pool = P(None, None, None, "tensor")
     specs = (q, pool, pool, P(), P())
     args = [jax.ShapeDtypeStruct(shape, dtype,
                                  sharding=NamedSharding(mesh, spec))
@@ -245,7 +252,7 @@ CELL = dict(layers=24, slots=32, blocks=257, heads=16, embd=2048,
 
 
 def _serve_program(kind, device, layers=L_NAMES, slots=S, blocks=NB,
-                   heads=2, embd=256, vocab=512):
+                   heads=2, embd=256, vocab=512, quantized=False):
     """``(jitted program named as the server names it, its name,
     abstract arguments)`` at d_head 128, block 128; 8 slots of a small
     model unless told otherwise."""
@@ -268,7 +275,8 @@ def _serve_program(kind, device, layers=L_NAMES, slots=S, blocks=NB,
     params = abstract(jax.eval_shape(
         lambda: init_params(jax.random.PRNGKey(0), cfg)))
     cache = abstract(jax.eval_shape(lambda: init_paged_cache(
-        layers, slots, blocks, BS, MB, heads, 128, BF16)))
+        layers, slots, blocks, BS, MB, heads, 128, BF16,
+        quantized=quantized)))
     fn, name, args = {
         "decode": (Srv._decode_fn, "serve_decode",
                    (params, arr((slots,)), cache,
@@ -293,7 +301,9 @@ def _serve_program(kind, device, layers=L_NAMES, slots=S, blocks=NB,
     ("chunk", "paged_chunk_attention", {}),
     ("verify", "paged_verify_attention", {}),
     ("decode", "paged_decode_attention", CELL),
-], ids=["decode", "prefill", "chunk", "verify", "decode-cell"])
+    ("decode", "paged_decode_attention", {"quantized": True}),
+], ids=["decode", "prefill", "chunk", "verify", "decode-cell",
+        "decode-int8"])
 def test_serving_programs_carry_their_names(chips, monkeypatch, kind,
                                             kernel, geometry):
     """Module name, kernel name and every layer scope of a serving
@@ -320,6 +330,10 @@ def test_serving_programs_carry_their_names(chips, monkeypatch, kind,
     assert innermost >= SERVE_SCOPES, SERVE_SCOPES - innermost
     if kind != "prefill":
         assert "kv_read" not in innermost
+    # an int8 pool's WRITERS still cut a layer out to requantize it (on
+    # the parent too; PERF.md section 7): that case only shows that the
+    # program compiles around the kernel's scale-tile streams
+    if kind != "prefill" and not geometry.get("quantized"):
         pool = args[2 if kind != "chunk" else 4].k
         layer_k = math.prod(pool.shape[1:]) * pool.dtype.itemsize
         if not geometry:    # at the cell's widths its weights are larger
