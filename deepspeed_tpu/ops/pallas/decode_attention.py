@@ -16,19 +16,16 @@ leading dims moves no byte), and a layer is the static block offset
 ``layer * NB`` added to every table entry — no program cuts a layer's K
 or V out of the pool or reorders a byte of it before the call.
 
-The walk. The grid is ``(slot, query-row block)`` and runs in order;
-one grid step walks its slot's LIVE blocks in a loop whose trip count,
-``ceil(positions seen / BS)``, is read from the scalar-prefetched
-bounds. Block ``j`` of the table is copied whole, as the contiguous
-``[BS, KH*D]`` slab it is in HBM, into one of two VMEM buffers
-(``make_async_copy``) while block ``j - 1`` is attended; the last
-block's iteration starts the FIRST block of the next grid step that has
-one, so the stream does not drain where one slot ends and the next
-begins. A dead table entry is never read and costs nothing; an idle
-slot (bound below zero) costs one empty grid step that writes zeros.
-Every byte the kernel moves is a live block's, every grid step but an
-idle slot's moves some, and what a step costs beyond its bytes is paid
-once a slot, not once a table entry.
+The walk (``block_walk.walk_live_blocks``: the scaffold, shared with
+the latent pool's decode kernel, is described there). The grid is
+``(slot, query-row block)`` and runs in order; one grid step walks its
+slot's LIVE blocks in a loop whose trip count, ``ceil(positions seen /
+BS)``, is read from the scalar-prefetched bounds. Block ``j`` of the
+table is copied whole, as the contiguous ``[BS, KH*D]`` slab it is in
+HBM, into one of two VMEM buffers while block ``j - 1`` is attended, and
+the last block's iteration starts the first block of the next grid step
+that has one. A dead table entry is never read; an idle slot (bound
+below zero) costs one empty grid step that writes zeros.
 
 The arithmetic. K and V go to the MXU as they are stored (bfloat16
 pools: no float32 copy of a block; int8 blocks are cast to the query's
@@ -76,6 +73,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.ops.pallas.block_walk import live_blocks, walk_live_blocks
+
 NEG_INF = -1e30
 DEFAULT_BLOCK_K = 256
 # query rows (tokens x group size) one grid step keeps resident per kv
@@ -103,23 +102,16 @@ def _layer_pools(k_pool, v_pool, D, k_scale, v_scale):
     return k, v
 
 
-def _live_blocks(seen, block_size: int, max_blocks: int):
-    """Table entries a query that sees ``seen`` positions has to walk."""
-    blocks = jax.lax.div(jnp.maximum(seen, 0) + (block_size - 1), block_size)
-    return jnp.minimum(blocks, max_blocks)
-
-
 def _paged_kernel(base_ref, bt_ref, offset_ref, q_ref, *rest,
                   block_size: int, head_dim: int, rep: int, span: int,
                   scale: float, quantized: bool, batched: bool):
     """Grid (slot, query-row block): one step walks ITS slot's live
-    blocks. The pools stay in HBM; block ``bt[slot, j] + offset[0]``
-    (a layer is ``layer * NB`` blocks in) is copied whole into one of
-    two VMEM buffers while block ``j - 1`` is attended, and the last
-    block's iteration starts the first block of the NEXT grid step that
-    has one, so the stream does not drain at a slot's edge (the grid
-    runs in order on one core; which buffer is next rides in SMEM). The
-    trip count ``ceil(positions seen / BS)`` is read from the
+    blocks (:func:`~deepspeed_tpu.ops.pallas.block_walk.walk_live_blocks`
+    has the scaffold: two VMEM buffers a stream, the next step's first
+    block started in this step's last iteration, the buffer parity in
+    SMEM). The pools stay in HBM; table entry ``j`` of a slot is block
+    ``bt[slot, j] + offset[0]`` (a layer is ``layer * NB`` blocks in).
+    The trip count ``ceil(positions seen / BS)`` is read from the
     scalar-prefetched ``base``: a dead table entry costs nothing, an
     idle slot one grid step that writes zeros.
 
@@ -161,48 +153,20 @@ def _paged_kernel(base_ref, bt_ref, offset_ref, q_ref, *rest,
     rows = acc_ref.shape[-2] // KH if batched else acc_ref.shape[-2]
     cdt = q_ref.dtype
 
-    def copies(slot, j, buf):
-        block = bt_ref[slot, j] + offset_ref[0]
-        return [pltpu.make_async_copy(hbm.at[block], vmem.at[buf],
-                                      sems.at[i, buf])
-                for i, (hbm, vmem) in enumerate(streams)]
-
-    def start(slot, j, buf):
-        for copy in copies(slot, j, buf):
-            copy.start()
-
     base = base_ref[s] + rb * span     # bound of this block's first token
-    n = _live_blocks(base + span, BS, MB)
+    n = live_blocks(base + span, BS, MB)
     # the grid step after this one, and whether it has a block to fetch
     wraps = rb + 1 == RB
     s_next = jnp.minimum(jnp.where(wraps, s + 1, s), S - 1)
     rb_next = jnp.where(wraps, 0, rb + 1)
     n_next = jnp.where(
         jnp.logical_and(wraps, s + 1 == S), 0,
-        _live_blocks(base_ref[s_next] + (rb_next + 1) * span, BS, MB))
+        live_blocks(base_ref[s_next] + (rb_next + 1) * span, BS, MB))
 
-    def start_next(buf):
-        @pl.when(n_next > 0)
-        def _():
-            start(s_next, 0, buf)
-
-    @pl.when(jnp.logical_and(s == 0, rb == 0))
-    def _first():
-        next_buf[0] = 0
-
-        @pl.when(n > 0)
-        def _():
-            start(s, 0, 0)
-
-    buf0 = next_buf[0]      # where this step's first block is landing
-
-    @pl.when(n == 0)
-    def _idle():
+    def idle():
         o_ref[...] = jnp.zeros_like(o_ref)
-        start_next(buf0)
 
-    @pl.when(n > 0)
-    def _walk():
+    def walk(loop):
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
@@ -287,23 +251,7 @@ def _paged_kernel(base_ref, bt_ref, offset_ref, q_ref, *rest,
                 acc_ref[h] = acc_ref[h] * alpha + p_dot_v(
                     p, operand(v_buf[buf, :, lanes]))
 
-        def block(j, carry):
-            buf = (buf0 + j) % 2
-
-            @pl.when(j + 1 < n)
-            def _():
-                start(s, j + 1, 1 - buf)
-
-            @pl.when(j + 1 == n)
-            def _():
-                start_next(1 - buf)
-            for copy in copies(s, j, buf):
-                copy.wait()
-            attend(j, buf)
-            return carry
-
-        jax.lax.fori_loop(0, n, block, 0)
-        next_buf[0] = (buf0 + n) % 2
+        loop(attend)
         l = jnp.maximum(l_ref[...], 1e-30)
         if batched:     # each row's own diagonal block of the accumulator
             out = functools.reduce(jnp.add, own_head(
@@ -311,6 +259,12 @@ def _paged_kernel(base_ref, bt_ref, offset_ref, q_ref, *rest,
             o_ref[0] = (out / l).astype(o_ref.dtype)
         else:
             o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+    walk_live_blocks(
+        streams, sems, next_buf,
+        lambda slot, j: bt_ref[slot, j] + offset_ref[0],
+        slot=s, n=n, first=jnp.logical_and(s == 0, rb == 0),
+        slot_next=s_next, n_next=n_next, idle=idle, walk=walk)
 
 
 def _paged_attention(qg, k_pool, v_pool, block_tables, base, *, rep: int,

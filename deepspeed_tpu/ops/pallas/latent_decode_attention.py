@@ -21,11 +21,27 @@ kernel call (16 copies of 302 MB, a third of the step; my chip run, PR
 compiler had chosen, and makes the score product a plain ``[H, W] x [W,
 BS]``.
 
-The kernel streams pool blocks through VMEM by the scalar-prefetched
-block table, as ``decode_attention.py``'s family does (same null block,
-same dead-tail rule: a table entry past the live length re-names the
-last live block, so it costs no DMA, and ``pl.when`` skips its compute),
-with the online-softmax recurrence in float32 scratch. It is named
+The kernel walks a slot's LIVE blocks as ``decode_attention.py``'s
+family does, by the same scaffold (``block_walk.walk_live_blocks``): the
+grid is the slots, in order; the pool stays in HBM; one grid step reads
+its trip count from the scalar-prefetched lengths and copies the table
+entries its slot has, each the contiguous ``[W, BS]`` slab it is, into
+VMEM buffers while the entries before them are attended, and its last
+iteration starts the first entries of the next slot that has any. A dead
+table entry is never read (it need not even be a valid id), an idle slot
+costs one empty grid step that writes zeros, and the step's own cost is
+paid once a slot: 256 grid steps a call at 256 slots, where a grid over
+``(slot, table entry)`` paid 2048 for the same bytes.
+
+A latent block is small, and on the chip what one costs is the latency
+of its chain (a 147 KB copy, two score products with 64 rows, two lane
+reductions, ``exp``, ``p . lat``: ~0.5 us where its bytes are 0.18), not
+work. So an iteration attends ``ENTRIES`` table entries, each copied
+through a stream of its own: their score products and copies are
+independent and overlap, and only the online-softmax recurrence (float32
+scratch, re-initialised once a slot) runs over them one after another,
+in table order, as it would a block at a time: the sums and their order
+are a block-at-a-time kernel's, to the bit. The kernel is named
 ``paged_latent_decode_attention`` in the compiled program and the trace;
 ``paged_latent_append`` is the pool's decode-time writer.
 """
@@ -38,53 +54,101 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.ops.pallas.block_walk import live_blocks, walk_live_blocks
+
 NEG_INF = -1e30
 NAME = "paged_latent_decode_attention"
+# table entries a loop iteration attends, each through a copy stream of
+# its own (the module docstring has why): three is where the LongCat
+# cell's contexts, 2-6 live blocks a slot, gain most (PERF.md section 6,
+# PR 39: 1 / 2 / 3 / 4 / 8 entries read 381 / 310 / 295 / 311 / 323 us a
+# call there)
+ENTRIES = 3
 
 
-def _kernel(base_ref, bt_ref, q_ref, r_ref, o_ref, m_ref, l_ref, acc_ref, *,
+def _kernel(base_ref, bt_ref, q_ref, pool_hbm, o_ref, *rest,
             block_size: int, value_dim: int, scale: float):
-    """Grid (slot, block-table entry). ``base[s]`` is the last visible
-    key position of slot ``s`` (live length - 1; -1 for an idle slot)."""
-    s, i = pl.program_id(0), pl.program_id(1)
-    nb = pl.num_programs(1)
+    """Grid (slot,): one step walks ITS slot's live blocks
+    (:func:`~deepspeed_tpu.ops.pallas.block_walk.walk_live_blocks`),
+    ``ENTRIES`` table entries an iteration, entry ``k`` of a group
+    through stream ``k`` (the same pool, buffers of its own). An entry
+    past the slot's last live block sits out: no copy, no arithmetic.
+    ``base[s]`` is the last visible key position of slot ``s`` (live
+    length - 1; -1 for an idle slot)."""
+    bufs = rest[:ENTRIES]
+    sems, next_buf, m_ref, l_ref, acc_ref = rest[ENTRIES:]
+    s, S = pl.program_id(0), pl.num_programs(0)
+    MB = bt_ref.shape[1]
     base = base_ref[s]
 
-    @pl.when(i == 0)
-    def _init():
+    def blocks(slot):
+        return live_blocks(base_ref[slot] + 1, block_size, MB)
+
+    def groups(slot):
+        return jax.lax.div(blocks(slot) + (ENTRIES - 1), ENTRIES)
+
+    def block_ids(slot, j):
+        first = j * ENTRIES     # live whenever the walk reaches group j
+        return (bt_ref[slot, first],) + tuple(
+            (bt_ref[slot, jnp.minimum(first + k, MB - 1)],
+             first + k < blocks(slot)) for k in range(1, ENTRIES))
+
+    def idle():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    def walk(loop):
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(i * block_size <= base)
-    def _update():
-        q = q_ref[0]                                   # [H, W]
-        rows = r_ref[0]                                # [W, BS]
-        lat, rope = rows[:value_dim], rows[value_dim:]
-        # two products (latent part, rotary part): both contractions are
-        # lane-aligned, which one over W = 576 is not
-        sc = jnp.dot(q[:, :value_dim], lat,
-                     preferred_element_type=jnp.float32)
-        sc += jnp.dot(q[:, value_dim:], rope,
-                      preferred_element_type=jnp.float32)
-        sc = sc * scale                                # [H, BS]
-        col = i * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, sc.shape, 1)
-        sc = jnp.where(col <= base, sc, NEG_INF)
-        m_prev, l_prev = m_ref[...], l_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
-        p = jnp.exp(sc - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        m_ref[...] = m_new
-        l_ref[...] = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(lat.dtype), lat, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        def attend_entries(j, buf, live: int):
+            """The first ``live`` entries of group ``j``: every entry's
+            scores (independent of one another), then the recurrence
+            over them in table order."""
+            q = q_ref[0]                                   # [H, W]
+            scores = []
+            for k in range(live):
+                lat = bufs[k][buf, :value_dim]             # [V, BS]
+                rope = bufs[k][buf, value_dim:]
+                # two products (latent part, rotary part): both
+                # contractions are lane-aligned, one over W = 576 is not
+                sc = jnp.dot(q[:, :value_dim], lat,
+                             preferred_element_type=jnp.float32)
+                sc += jnp.dot(q[:, value_dim:], rope,
+                              preferred_element_type=jnp.float32)
+                sc = sc * scale                            # [H, BS]
+                col = (j * ENTRIES + k) * block_size + (
+                    jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1))
+                scores.append(jnp.where(col <= base, sc, NEG_INF))
+            m, l, acc = m_ref[...], l_ref[...], acc_ref[...]
+            for k, sc in enumerate(scores):
+                lat = bufs[k][buf, :value_dim]
+                m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
+                p = jnp.exp(sc - m_new)
+                alpha = jnp.exp(m - m_new)
+                l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+                acc = acc * alpha + jax.lax.dot_general(
+                    p.astype(lat.dtype), lat, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                m = m_new
+            m_ref[...], l_ref[...], acc_ref[...] = m, l, acc
 
-    @pl.when(i == nb - 1)
-    def _finish():
+        def attend(j, buf):
+            live = jnp.minimum(blocks(s) - j * ENTRIES, ENTRIES)
+            for k in range(1, ENTRIES + 1):
+                pl.when(live == k)(
+                    functools.partial(attend_entries, j, buf, k))
+
+        loop(attend)
         o_ref[0] = (acc_ref[...] /
                     jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+
+    s_next = jnp.minimum(s + 1, S - 1)
+    walk_live_blocks(
+        tuple((pool_hbm, buf) for buf in bufs), sems, next_buf, block_ids,
+        slot=s, n=groups(s), first=s == 0, slot_next=s_next,
+        n_next=jnp.where(s + 1 == S, 0, groups(s_next)), idle=idle,
+        walk=walk)
 
 
 def paged_latent_decode_attention(q: jax.Array, pool: jax.Array,
@@ -99,44 +163,64 @@ def paged_latent_decode_attention(q: jax.Array, pool: jax.Array,
     rotary part after it); pool: ``[NB, W, BS]`` (one attention's rows of
     a :class:`~deepspeed_tpu.inference.kv_cache.LatentPagedCache`, each
     block transposed);
-    block_tables: ``[S, MB]`` int32 (dead entries must be valid ids: the
-    null block); lengths: ``[S]`` int32 live lengths (the query attends
-    positions ``< lengths[s]``). Returns the latent outputs ``[S, H,
-    value_dim]``; the caller carries them through ``W_kvb_v``. An idle
-    slot (length 0) costs no compute and one null-block DMA, and returns
-    zeros."""
+    block_tables: ``[S, MB]`` int32 (entry j covers positions ``j*BS ..
+    (j+1)*BS - 1``; entries beyond a slot's length are never read);
+    lengths: ``[S]`` int32 live lengths (the query attends positions
+    ``< lengths[s]``). Returns the latent outputs ``[S, H, value_dim]``;
+    the caller carries them through ``W_kvb_v``. An idle slot (length 0)
+    reads nothing and returns zeros."""
     S, H, W = q.shape
     NB, Wp, BS = pool.shape
-    MB = block_tables.shape[1]
     if Wp != W or not 0 < value_dim < W:
         raise ValueError(f"q width {W}, pool width {Wp}, value_dim "
                          f"{value_dim} do not describe one latent row")
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    call = _latent_call(bool(interpret), q.shape, q.dtype.name, pool.shape,
+                        pool.dtype.name, block_tables.shape[1], value_dim,
+                        float(scale))
+    return call(lengths.astype(jnp.int32) - 1,
+                block_tables.astype(jnp.int32), q, pool)
 
-    def row_map(s, i, base, bt):
-        last = jnp.maximum(base[s], 0) // BS
-        return (bt[s, jnp.minimum(i, last)], 0, 0)
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(S, MB),
-        in_specs=[pl.BlockSpec((1, H, W), lambda s, i, base, bt: (s, 0, 0)),
-                  pl.BlockSpec((1, W, BS), row_map)],
-        out_specs=pl.BlockSpec((1, H, value_dim),
-                               lambda s, i, base, bt: (s, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((H, 1), jnp.float32),
-                        pltpu.VMEM((H, 1), jnp.float32),
-                        pltpu.VMEM((H, value_dim), jnp.float32)])
-    return pl.pallas_call(
+@functools.lru_cache(maxsize=None)
+def _latent_call(interpret: bool, q_shape, q_dtype: str, pool_shape,
+                 pool_dtype: str, MB: int, value_dim: int, scale: float):
+    """The ``pallas_call`` of one static signature: ``(base [S], tables
+    [S, MB], q [S, H, W], pool [NB, W, BS]) -> [S, H, value_dim]``. Grid
+    ``(S,)``, in order; the pool stays in HBM and :func:`_kernel` copies
+    the blocks it walks into two VMEM buffers a stream. Kept per
+    signature, as ``decode_attention._paged_call`` is, and jitted: a
+    decode program's attentions have one signature and a pool buffer
+    each, so jax finds every call after the first in its caches: the
+    kernel body is traced once and the call lowered once a program
+    (un-jitted, each call re-did everything around the body's trace:
+    0.6 s more of the cell's set-up on the chip's host)."""
+    S, H, W = q_shape
+    _, _, BS = pool_shape
+    f32 = jnp.float32
+    return jax.jit(pl.pallas_call(
         functools.partial(_kernel, block_size=BS, value_dim=value_dim,
-                          scale=float(scale)),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, H, value_dim), q.dtype),
+                          scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(S,),
+            in_specs=[pl.BlockSpec((1, H, W), lambda s, *_: (s, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, H, value_dim),
+                                   lambda s, *_: (s, 0, 0)),
+            scratch_shapes=[*[pltpu.VMEM((2, W, BS), pool_dtype)] * ENTRIES,
+                            pltpu.SemaphoreType.DMA((ENTRIES, 2)),
+                            pltpu.SMEM((1,), jnp.int32),
+                            pltpu.VMEM((H, 1), f32), pltpu.VMEM((H, 1), f32),
+                            pltpu.VMEM((H, value_dim), f32)]),
+        out_shape=jax.ShapeDtypeStruct((S, H, value_dim), q_dtype),
+        # in order: a step starts the next step's first block
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name=NAME,
-    )(lengths.astype(jnp.int32) - 1, block_tables.astype(jnp.int32), q,
-      pool)
+    ))
 
 
 def _append_kernel(blk_ref, off_ref, row_ref, pool_ref, out_ref):

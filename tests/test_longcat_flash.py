@@ -179,6 +179,144 @@ def test_latent_decode_kernel_matches_its_oracle(lengths):
     assert float(jnp.abs(got[np.asarray(lengths) == 0]).max(initial=0)) == 0
 
 
+# the walk's own cases over an eight-entry table: every length around a
+# block's edge, every number of live blocks (the kernel attends the
+# table in groups of ``lda.ENTRIES``: a group short of entries, a full
+# one, several), and every way a slot can be idle beside a live one (an
+# idle slot passes the start of the next slot's first blocks on)
+_WALK_BS, _WALK_MB = 16, 8
+_WALKS = {
+    "ragged": (0, 1, _WALK_BS - 1, _WALK_BS, _WALK_BS + 1,
+               _WALK_MB * _WALK_BS),
+    "every-count": tuple(n * _WALK_BS - 3 for n in range(1, _WALK_MB + 1)),
+    "full-tables": (_WALK_MB * _WALK_BS,) * 4,
+    "all-idle": (0,) * 6,
+    "first-idle": (0, 5, 17, 33, 50, 100),
+    "last-idle": (5, 17, 33, 50, 100, 0),
+    "idle-run": (7, 0, 0, 0, 70, 16),
+}
+
+
+def _walk_case(lengths, H=8, W=40, bs=_WALK_BS, mb=_WALK_MB,
+               dtype=jnp.float32, seed=0):
+    """``(q, pool, poisoned pool, tables, lengths)``: a shuffled table
+    whose DEAD entries name blocks no walk reaches, and a second pool in
+    which every value a right walk never uses is lethal: the columns of
+    a slot's last live block at or past its length hold large finite
+    garbage (a missing bound shows), every block no walk reaches, the
+    null block among them, holds NaN (one block too many shows however
+    it is masked)."""
+    rng = np.random.default_rng(seed)
+    S = len(lengths)
+    NB = 1 + 2 * S * mb
+    q = jnp.asarray(rng.normal(size=(S, H, W)), dtype)
+    pool = jnp.asarray(rng.normal(size=(NB, W, bs)), dtype)
+    tables = 1 + rng.permutation(NB - 1)[:S * mb].reshape(S, mb)
+    live = np.zeros((NB, bs), bool)
+    reached = np.zeros(NB, bool)
+    for row, n in zip(tables, lengths):
+        for pos in range(n):
+            live[row[pos // bs], pos % bs] = True
+        reached[row[:-(-n // bs)]] = True
+    tail = jnp.asarray(reached[:, None] & ~live)[:, None, :]
+    bad = jnp.where(jnp.asarray(~reached)[:, None, None], jnp.nan,
+                    jnp.where(tail, 3e4, pool)).astype(dtype)
+    return (q, pool, bad, jnp.asarray(tables, jnp.int32),
+            jnp.asarray(lengths, jnp.int32))
+
+
+@pytest.mark.parametrize("pool", ["clean", "poisoned"])
+@pytest.mark.parametrize("walk", sorted(_WALKS))
+def test_latent_decode_kernel_walks_live_blocks_only(walk, pool):
+    """The kernel in interpret mode against the oracle on the clean
+    pool, to 1e-5 in float32; over the poisoned pool its output is
+    finite and the clean pool's to the bit, and an idle slot's is
+    zeros."""
+    lengths = _WALKS[walk]
+    q, clean, bad, tables, live = _walk_case(lengths)
+    kernel = lambda rows: lda.paged_latent_decode_attention(
+        q, rows, tables, live, value_dim=32, scale=0.2, interpret=True)
+    want = lda.paged_latent_decode_attention_reference(
+        q, clean, tables, live, value_dim=32, scale=0.2)
+    got = np.asarray(kernel(clean if pool == "clean" else bad))
+    assert np.isfinite(got).all()
+    assert float(np.abs(got - np.asarray(want)).max()) < 1e-5
+    assert not got[np.asarray(lengths) == 0].any()
+    if pool == "poisoned":
+        np.testing.assert_array_equal(got, np.asarray(kernel(clean)))
+
+
+def test_latent_decode_kernel_at_the_cell_widths_in_bfloat16():
+    """64 heads over rows of 512 + 64 values, blocks of 128, tables of
+    8, bfloat16 (the LongCat cell's kernel signature but for the slot
+    count), over a poisoned pool: an idle slot, a partial block, a block
+    and a row, a full table."""
+    lengths = (0, 77, 129, 1024)
+    q, clean, bad, tables, live = _walk_case(
+        lengths, H=64, W=576, bs=128, mb=8, dtype=jnp.bfloat16, seed=1)
+    got = lda.paged_latent_decode_attention(
+        q, bad, tables, live, value_dim=512, scale=192 ** -0.5,
+        interpret=True)
+    want = lda.paged_latent_decode_attention_reference(
+        q.astype(jnp.float32), clean.astype(jnp.float32), tables, live,
+        value_dim=512, scale=192 ** -0.5)
+    got = np.asarray(got.astype(jnp.float32))
+    assert got.shape == (4, 64, 512) and np.isfinite(got).all()
+    np.testing.assert_allclose(got, np.asarray(want), rtol=2e-2, atol=2e-2)
+    assert not got[0].any()
+
+
+@pytest.mark.parametrize("family", ["latent", "kv"])
+def test_eight_calls_of_one_signature_trace_the_kernel_once(family,
+                                                            monkeypatch):
+    """A decode program's attentions have one static signature (each its
+    own pool buffer; a K/V layer is data, a block offset), and the
+    ``pallas_call`` is kept per signature: eight calls under one
+    ``jax.jit`` trace the kernel BODY once, not eight times."""
+    from deepspeed_tpu.ops.pallas import decode_attention as da
+    module, body, kept = {"latent": (lda, "_kernel", lda._latent_call),
+                          "kv": (da, "_paged_kernel", da._paged_call)}[family]
+    traced = []
+    real = getattr(module, body)
+
+    def counting(*refs, **static):
+        traced.append(1)
+        return real(*refs, **static)
+    monkeypatch.setattr(module, body, counting)
+    kept.cache_clear()          # a kept call holds the body it was built on
+    rng = np.random.default_rng(2)
+    tables = jnp.asarray(1 + rng.permutation(8).reshape(2, 4), jnp.int32)
+    live = jnp.asarray([19, 64], jnp.int32)
+    try:
+        if family == "latent":
+            q = jnp.asarray(rng.normal(size=(2, 8, 40)), jnp.float32)
+            pools = [jnp.asarray(rng.normal(size=(9, 40, 16)), jnp.float32)
+                     for _ in range(8)]
+            calls = [lambda q, pool=pool: lda.paged_latent_decode_attention(
+                q, pool, tables, live, value_dim=32, scale=0.2,
+                interpret=True) for pool in pools]
+            oracles = [lda.paged_latent_decode_attention_reference(
+                q, pool, tables, live, value_dim=32, scale=0.2)
+                for pool in pools]
+        else:
+            q = jnp.asarray(rng.normal(size=(2, 4, 16)), jnp.float32)
+            k, v = (jnp.asarray(rng.normal(size=(8, 9, 16, 64)), jnp.float32)
+                    for _ in range(2))
+            calls = [lambda q, layer=layer: da.paged_decode_attention(
+                q, k, v, tables, live, interpret=True, layer=layer)
+                for layer in range(8)]
+            oracles = [da.paged_decode_attention_reference(
+                q, k[layer], v[layer], tables, live) for layer in range(8)]
+        outs = jax.jit(lambda q: [call(q) for call in calls])(q)
+        info = kept.cache_info()
+    finally:
+        kept.cache_clear()
+    assert len(traced) == 1
+    assert (info.misses, info.hits) == (1, 7)
+    for got, want in zip(outs, oracles):
+        assert float(jnp.abs(got - want).max()) < 1e-5
+
+
 def test_latent_append_kernel_writes_what_the_scatter_writes():
     """The Pallas pool writer in interpret mode against the XLA scatter:
     every slot's row lands in its block's column, idle slots in the null

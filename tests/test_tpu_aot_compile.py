@@ -129,7 +129,26 @@ def _retention(kind):
                 ((), jnp.int32)]
 
 
+# serve-longcat-flash-ep32-decode-batch's own geometry: 256 slots of 8
+# blocks and the null block, 64 heads over rows of 512 + 64 values
+LATENT = dict(slots=256, blocks=2049, heads=64, width=576, value_dim=512)
+
+
+def _latent_decode():
+    """The latent decode kernel at the LongCat cell's geometry: q, one
+    attention's pool ``[NB, W, BS]``, table, lengths."""
+    from deepspeed_tpu.ops.pallas import latent_decode_attention as lda
+    g = LATENT
+    fn = functools.partial(lda.paged_latent_decode_attention,
+                           value_dim=g["value_dim"], scale=192 ** -0.5,
+                           interpret=False)
+    return fn, [((g["slots"], g["heads"], g["width"]), BF16),
+                ((g["blocks"], g["width"], BS), BF16),
+                ((g["slots"], MB), jnp.int32), ((g["slots"],), jnp.int32)]
+
+
 CASES = {
+    "latent-decode": _latent_decode,
     "retention_decode": functools.partial(_retention, "decode"),
     "retention_prefill": functools.partial(_retention, "prefill"),
     "decode": _dense_decode,
@@ -164,11 +183,11 @@ def test_kernel_compiles_for_v5e(case, chips, monkeypatch):
     compiled = jax.jit(fn).lower(*args).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
-    if case.startswith("paged_"):
+    if case.startswith(("paged_", "latent-")):
         # the pool goes to the kernel as it is stored: nothing as large
-        # as one layer of K, and nothing shaped like a layer's scale
-        # tiles, is written on the way in
-        (_, NB_, BS_, W), dtype = shapes[1]
+        # as one layer of K (one attention's latent pool), and nothing
+        # shaped like a layer's scale tiles, is written on the way in
+        (*_, NB_, BS_, W), dtype = shapes[1]
         layer = NB_ * BS_ * W * jnp.dtype(dtype).itemsize
         assert compiled.memory_analysis().temp_size_in_bytes < layer
         tiles = set()
@@ -251,6 +270,12 @@ CELL = dict(layers=24, slots=32, blocks=257, heads=16, embd=2048,
             vocab=50257)
 
 
+def _abstract(tree, sharding):
+    """A tree of arrays (or of shapes) as shapes on ``sharding``."""
+    return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=sharding), tree)
+
+
 def _serve_program(kind, device, layers=L_NAMES, slots=S, blocks=NB,
                    heads=2, embd=256, vocab=512, quantized=False):
     """``(jitted program named as the server names it, its name,
@@ -266,9 +291,7 @@ def _serve_program(kind, device, layers=L_NAMES, slots=S, blocks=NB,
         vocab_size=vocab, n_positions=1024, n_embd=embd, n_layer=layers,
         n_head=heads, dtype=BF16)
 
-    def abstract(tree):
-        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=one), tree)
+    abstract = functools.partial(_abstract, sharding=one)
 
     def arr(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
@@ -345,6 +368,72 @@ def test_serving_programs_carry_their_names(chips, monkeypatch, kind,
         assert compiled.memory_analysis().temp_size_in_bytes < layer_k
 
 
+LATENT_SCOPES = {"embed", "ln", "mla_qkv", "latent_write", "mla_attn",
+                 "attn_out", "dense_ffn", "moe_router", "moe_dispatch",
+                 "moe_experts", "moe_combine", "lm_head", "sample"}
+
+
+def test_latent_decode_program_walks_the_pool_where_it_lies(chips,
+                                                            monkeypatch):
+    """LongCat's ``serve_decode`` at the cell's widths, slots and pool
+    (two of its four layers, a small vocabulary, two held experts), read
+    back from its compiled text: module, kernel and scope names; one
+    ``paged_latent_decode_attention`` and one ``paged_latent_append`` an
+    attention; and apart from those calls (the append rewrites its
+    donated pool in place) nothing writes as much as one attention's
+    pool: no copy, no transpose, no temporary of that size."""
+    from deepspeed_tpu.inference.kv_cache import init_latent_paged_cache
+    from deepspeed_tpu.inference.server import ContinuousBatchingServer as Srv
+    from deepspeed_tpu.model_implementations import longcat_flash as lf
+    from deepspeed_tpu.ops.pallas import latent_decode_attention as lda
+    from deepspeed_tpu.telemetry import compile_watch
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(chips[0])
+    g = LATENT
+    cfg = lf.LongcatFlashConfig(vocab_size=2048, num_layers=2,
+                                experts_held=(0, 2))
+    assert (cfg.num_attention_heads, cfg.latent_width, cfg.kv_lora_rank) == (
+        g["heads"], g["width"], g["value_dim"])
+
+    abstract = functools.partial(_abstract, sharding=one)
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    params = abstract(jax.eval_shape(
+        lambda: lf.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = abstract(jax.eval_shape(lambda: init_latent_paged_cache(
+        cfg.attentions, g["slots"], g["blocks"], BS, MB, cfg.latent_width,
+        aux_shape=cfg.aux_shape)))
+    lda._latent_call.cache_clear()
+    compiled = jax.jit(compile_watch._named(
+        functools.partial(Srv._decode_fn, cfg=cfg, mesh=None),
+        "serve_decode"), donate_argnames=("cache",)).lower(
+        params, arr((g["slots"],)), cache,
+        arr((g["slots"],), jnp.bool_)).compile()
+    # four attentions, one signature: one kept call, found again thrice
+    info = lda._latent_call.cache_info()
+    assert (info.misses, info.hits) == (1, cfg.attentions - 1)
+    text = compiled.as_text()
+    assert "HloModule jit_serve_decode" in text
+    scopes, kernels = compile_watch.parse_scopes(text)
+    # (the compiler's grouped matmuls are kernels of its own naming)
+    ours = {k: name for k, name in kernels.items()
+            if name.startswith("paged_latent")}
+    assert sorted(ours.values()) == sorted(
+        [lda.NAME, "paged_latent_append"] * cfg.attentions)
+    assert all(scopes[k].rsplit("/", 1)[-1] ==
+               ("mla_attn" if name == lda.NAME else "latent_write")
+               for k, name in ours.items())
+    innermost = {v.rsplit("/", 1)[-1] for v in scopes.values() if v}
+    assert innermost >= LATENT_SCOPES, LATENT_SCOPES - innermost
+    pool = cache.rows[0]
+    pool_bytes = math.prod(pool.shape) * pool.dtype.itemsize
+    assert not _copies(
+        text, lambda dims, nbytes: nbytes >= pool_bytes
+        or sorted(dims[-2:]) == sorted(pool.shape[-2:]), kernels=kernels)
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+
+
 RETENTION_SCOPES = {"embed", "ln", "ret_qkvg", "ret_state", "ret_out",
                     "mlp", "lm_head", "sample"}
 
@@ -371,9 +460,7 @@ def test_retention_programs_update_the_state_in_place(chips, monkeypatch,
     layers, slots = 2, 32
     cfg = brumby.BrumbyConfig(vocab_size=2048, num_hidden_layers=layers)
 
-    def abstract(tree):
-        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=one), tree)
+    abstract = functools.partial(_abstract, sharding=one)
 
     def arr(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
