@@ -10,10 +10,13 @@ still make sense:
   with env discovery, the analog of comm/comm.py:599.
 * rank/world-size accessors (process-level and device-level).
 * in-jit collective dispatchers (``all_reduce``/``all_gather``/…) usable inside
-  ``shard_map`` bodies, dispatching to ``jax.lax`` primitives — with a
-  CommsLogger counting call sites and volumes (analog of the @timed_op
-  decorator, comm/comm.py:112; timing itself comes from XLA profiles since
-  ops inside jit cannot be individually wall-clocked).
+  ``shard_map`` bodies, dispatching to ``jax.lax`` primitives.
+* ``comms_logger`` (the ``comms_logger`` config block; analog of the
+  @timed_op decorator, comm/comm.py:112): what each watched program
+  moves a step, read off its COMPILED text (``telemetry/compile_watch.py``
+  ``movement_table``), so the collectives the partitioner inserted and the
+  offload stream's transfers are in it; timing comes from XLA profiles,
+  since ops inside jit cannot be individually wall-clocked.
 * host-level helpers (``barrier``, ``broadcast_obj``) built on
   ``jax.experimental.multihost_utils``.
 """
@@ -41,34 +44,50 @@ PROD = "prod"
 
 
 class CommsLogger:
-    """Counts collective invocations & element volume per op name.
+    """What the watched programs move: per program and kind of transfer
+    (``host_to_device`` / ``device_to_host``, ``all-gather``,
+    ``reduce-scatter``, ``all-reduce``, ``all-to-all``, ...) the transfers
+    and bytes of ONE execution, from the compile watch's movement table.
 
-    Analog of deepspeed/utils/comms_logging.py — wall-time per op is not
-    observable from inside jit, so we record trace-time call counts/volumes;
-    runtime timing comes from the jax profiler (§5.1 SURVEY).
+    Analog of deepspeed/utils/comms_logging.py. The reference counts calls
+    of its own dispatcher; here the exchanges of a ZeRO or tensor-parallel
+    step are written by XLA's partitioner and no dispatcher sees them, so
+    the compiled program is what is read. Parsed when asked (``summary``,
+    ``log_all``), never while a program is set up.
     """
 
     def __init__(self):
         self.enabled = False
         self.verbose = False
-        self.comms_dict: dict = {}
 
     def configure(self, enabled=False, verbose=False, prof_all=True, debug=False):
         self.enabled = enabled
         self.verbose = verbose
 
-    def append(self, op_name: str, nelems: int, dtype) -> None:
-        if not self.enabled:
-            return
-        rec = self.comms_dict.setdefault(op_name, {"count": 0, "elements": 0})
-        rec["count"] += 1
-        rec["elements"] += int(nelems)
-        if self.verbose:
-            logger.info(f"comm op: {op_name} | elements: {nelems} | dtype: {dtype}")
+    def summary(self) -> dict:
+        """``{program: {kind: {"calls", "bytes"}}}`` a step, for every
+        watched program that moves anything."""
+        from deepspeed_tpu.telemetry import compile_watch
+        out = {}
+        for program in compile_watch.watched_programs():
+            moved = compile_watch.movement_per_step(program)
+            if moved:
+                out[program] = moved
+        return out
 
     def log_all(self):
-        for name, rec in sorted(self.comms_dict.items()):
-            logger.info(f"{name}: {rec['count']} calls, {rec['elements']} elements")
+        from deepspeed_tpu.telemetry.compile_watch import movement_per_step
+        for program, kinds in sorted(self.summary().items()):
+            for kind, rec in sorted(kinds.items()):
+                logger.info(f"comm: {program}: {kind}: {rec['calls']} "
+                            f"transfers, {rec['bytes']:.0f} bytes a step")
+            if self.verbose:
+                for (kind, pass_, scope), rec in sorted(
+                        movement_per_step(program, detail=True).items(),
+                        key=str):
+                    logger.info(f"comm: {program}: {kind} | pass {pass_} | "
+                                f"scope {scope}: {rec['calls']} transfers, "
+                                f"{rec['bytes']:.0f} bytes a step")
 
 
 comms_logger = CommsLogger()
@@ -80,13 +99,6 @@ def configure(deepspeed_config=None, enabled=None, verbose=None, **kwargs):
         comms_logger.configure(enabled=cl.enabled, verbose=cl.verbose)
     elif enabled is not None:
         comms_logger.configure(enabled=enabled, verbose=bool(verbose))
-
-
-def _log(op_name: str, x) -> None:
-    if comms_logger.enabled:
-        nelems = sum(int(jnp.size(l)) for l in jax.tree.leaves(x))
-        leaves = jax.tree.leaves(x)
-        comms_logger.append(op_name, nelems, leaves[0].dtype if leaves else None)
 
 
 # ---------------------------------------------------------------------------
@@ -238,7 +250,6 @@ def get_device_count() -> int:
 # ---------------------------------------------------------------------------
 
 def all_reduce(x, op: str = SUM, axis_name: str = "data"):
-    _log(f"all_reduce[{axis_name}]", x)
     if op == SUM:
         return lax.psum(x, axis_name)
     if op == AVG:
@@ -251,7 +262,6 @@ def all_reduce(x, op: str = SUM, axis_name: str = "data"):
 
 
 def all_gather(x, axis_name: str = "data", axis: int = 0, tiled: bool = True):
-    _log(f"all_gather[{axis_name}]", x)
     return lax.all_gather(x, axis_name, axis=axis, tiled=tiled)
 
 
@@ -259,7 +269,6 @@ def reduce_scatter(x, axis_name: str = "data", axis: int = 0, tiled: bool = True
     """Sum-reduce then scatter along ``axis`` — analog of
     reduce_scatter_coalesced (runtime/comm/coalesced_collectives.py:30);
     bucketing/coalescing is XLA's job."""
-    _log(f"reduce_scatter[{axis_name}]", x)
     return lax.psum_scatter(x, axis_name, scatter_dimension=axis, tiled=tiled)
 
 
@@ -267,7 +276,6 @@ def all_to_all(x, axis_name: str = "expert", split_axis: int = 0,
                concat_axis: int = 0, tiled: bool = True):
     """MoE dispatch/combine exchange (reference: _AllToAll autograd fn,
     moe/sharded_moe.py:89)."""
-    _log(f"all_to_all[{axis_name}]", x)
     return lax.all_to_all(x, axis_name, split_axis=split_axis,
                           concat_axis=concat_axis, tiled=tiled)
 
@@ -275,7 +283,6 @@ def all_to_all(x, axis_name: str = "expert", split_axis: int = 0,
 def broadcast(x, src_index: int = 0, axis_name: str = "data"):
     """Broadcast from one index of the named axis to all (reference:
     comm/comm.py broadcast; engine._broadcast_model engine.py:1087)."""
-    _log(f"broadcast[{axis_name}]", x)
     # one ring rotation: every member receives from the previous member;
     # after |axis| applications of `select src's value` semantics, a single
     # all_gather-free way to do this is to gather ONLY the src shard.
@@ -288,7 +295,6 @@ def broadcast(x, src_index: int = 0, axis_name: str = "data"):
 
 def ppermute(x, perm, axis_name: str = "pipe"):
     """Neighbor exchange for pipeline parallelism (reference: pipe/p2p.py)."""
-    _log(f"ppermute[{axis_name}]", x)
     return lax.ppermute(x, axis_name, perm)
 
 
@@ -301,7 +307,6 @@ def reduce(x, dst_index: int = 0, op: str = SUM, axis_name: str = "data"):
     no one-sided result: ``dst_index`` receives the reduction, every
     other index keeps its input unchanged (the reference's in-place
     semantics on non-dst ranks)."""
-    _log(f"reduce[{axis_name}]", x)
     red = all_reduce(x, op=op, axis_name=axis_name)
     here = lax.axis_index(axis_name) == dst_index
     return jnp.where(here, red, x)
@@ -311,7 +316,6 @@ def gather(x, dst_index: int = 0, axis_name: str = "data", axis: int = 0):
     """Gather onto one index (reference comm.py:428): ``dst_index`` gets
     the concatenation along ``axis``; others get zeros of that shape
     (fixed SPMD shapes — the reference's non-dst ranks get nothing)."""
-    _log(f"gather[{axis_name}]", x)
     gathered = lax.all_gather(x, axis_name, axis=axis, tiled=True)
     here = lax.axis_index(axis_name) == dst_index
     return jnp.where(here, gathered, jnp.zeros_like(gathered))
@@ -320,7 +324,6 @@ def gather(x, dst_index: int = 0, axis_name: str = "data", axis: int = 0):
 def scatter(x, src_index: int = 0, axis_name: str = "data", axis: int = 0):
     """Each index receives its chunk of ``src_index``'s array along
     ``axis`` (reference comm.py:445)."""
-    _log(f"scatter[{axis_name}]", x)
     n = lax.axis_size(axis_name)
     if x.shape[axis] % n:
         raise ValueError(f"scatter: dim {axis} size {x.shape[axis]} not "

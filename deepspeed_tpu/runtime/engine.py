@@ -22,6 +22,7 @@ overlaps the collectives the reference hand-schedules (stage_1_and_2.py:937,
 from __future__ import annotations
 
 import time
+import weakref
 from typing import Any, Callable, Dict, Optional
 
 import jax
@@ -48,6 +49,12 @@ from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import ThroughputTimer
 
 from deepspeed_tpu.comm.mesh import DATA_AXES  # noqa: F401
+
+
+def _close(ann) -> None:
+    """Close a profiler range ``span_annotation`` may have opened."""
+    if ann is not None:
+        ann.__exit__(None, None, None)
 
 
 @struct.dataclass
@@ -348,6 +355,13 @@ class DeepSpeedEngine:
             batch_size=self.train_batch_size,
             steps_per_output=config.steps_per_print,
             registry=self.telemetry)
+        # what a step moves (train_moved_bytes_* / train_movement_calls_*):
+        # derived from the compiled step's text when the registry is READ,
+        # never here or in a step (the parse costs tenths of a second)
+        publish = weakref.WeakMethod(self._publish_movement)
+        self._movement_collector = lambda: (publish() or (lambda: None))()
+        self.telemetry.add_collector(self._movement_collector)
+        self._comms_logged = False
         self.monitor = self._build_monitor()
         # step metrics route through the telemetry registry FIRST —
         # MonitorMaster (tb/wandb/csv) is one sink of several, and the
@@ -1258,12 +1272,16 @@ class DeepSpeedEngine:
         if self._offload_grad_stage:
             params_in = jax.device_put(params_in,
                                        self._device_param_shardings)
+        phase = span_annotation("train:dispatch")
         t_disp = time.perf_counter()
         grads, metrics = self._offload_grad_fn(
             params_in, jnp.float32(scale), batch, rng)
         t_sent = time.perf_counter()
+        _close(phase)
+        phase = span_annotation("train:wait")
         finite = bool(metrics["finite"])   # host sync — grads are ready
         t_ready = time.perf_counter()
+        _close(phase)
         self._offload_device_s = t_ready - t_disp
         self._step_spans += [("train:dispatch", t_disp, t_sent),
                              ("train:wait", t_sent, t_ready)]
@@ -1331,8 +1349,10 @@ class DeepSpeedEngine:
         ann = span_annotation("train:step", step=self.global_steps + 1)
         self._step_spans = []
         if batch is None:
+            phase = span_annotation("train:data")
             batch = next(self.training_dataloader)
             data_wait = time.perf_counter() - t_wall
+            _close(phase)
             self._step_spans.append(
                 ("train:data", t_wall, t_wall + data_wait))
         batch = self._global_micro_batch(batch)
@@ -1404,10 +1424,15 @@ class DeepSpeedEngine:
             self.flops_profiler.start_profile()
         t_step = (time.perf_counter()
                   if self.config.wall_clock_breakdown else None)
+        # the step's phases are profiler ranges too (one is_enabled()
+        # read each with no profiler): a device trace then lays an idle
+        # gap to dispatch or wait, not to the whole train:step
+        phase = span_annotation("train:dispatch")
         t_disp = time.perf_counter()
         self.state, metrics = self._step_fn(self.state, batch, rng,
                                             self._numerics_on)
         t_sent = time.perf_counter()
+        _close(phase)
         self._step_spans.append(("train:dispatch", t_disp, t_sent))
         device_s = 0.0
         if self.goodput.enabled:
@@ -1415,8 +1440,10 @@ class DeepSpeedEngine:
             # ready (the documented cost of telemetry.goodput); without
             # the meter the step has no train:wait span — nothing here
             # adds a sync
+            phase = span_annotation("train:wait")
             jax.block_until_ready(metrics)
             t_ready = time.perf_counter()
+            _close(phase)
             device_s = t_ready - t_disp
             self._step_spans.append(("train:wait", t_sent, t_ready))
         if t_step is not None and self.global_steps > 0 and \
@@ -1494,24 +1521,68 @@ class DeepSpeedEngine:
                                  data_wait, device_s)
         self._record_step_trace(time.perf_counter() - t_wall,
                                 data_wait, device_s)
-        self._record_step_spans(t_wall, ann)
+        self._record_step_spans(t_wall, ann, program=self._step_fn.name,
+                                executable=self._step_fn.last_index)
+        if comm.comms_logger.enabled and not self._comms_logged:
+            # the comms_logger block: what the compiled step moves, once
+            self._comms_logged = True
+            comm.comms_logger.log_all()
         return metrics
 
-    def _record_step_spans(self, t_wall: float, ann) -> None:
+    def _record_step_spans(self, t_wall: float, ann,
+                           program: Optional[str] = None,
+                           executable: Optional[int] = None) -> None:
         """This step in the span log (telemetry/spans.py): ``train:step``
         from the call to its return, and under it the intervals the step
         already timed (``train:data`` / ``train:dispatch`` /
         ``train:wait``, on the host-offload path ``train:host_optimizer``).
         Dispatch is asynchronous: without a wait span the step's end is
-        the host's, not the device's."""
+        the host's, not the device's. ``program`` and ``executable`` (the
+        watched program the step ran and which of its executables, by
+        index) become the span's attributes: whose movement-table rows
+        the step ran, where a program was compiled more than once."""
         log = get_span_log()
         sid = log.next_id()
         for name, t0, t1 in self._step_spans:
             log.record(name, t0, t1, parent=sid, key=self.global_steps)
         log.record("train:step", t_wall, time.perf_counter(),
-                   key=self.global_steps, span_id=sid)
-        if ann is not None:
-            ann.__exit__(None, None, None)
+                   key=self.global_steps, span_id=sid,
+                   attrs=None if program is None else
+                   {"program": program, "executable": executable})
+        _close(ann)
+
+    def _publish_movement(self) -> None:
+        """The registry's read-time collector: what ONE execution of the
+        compiled step moves, by kind (host-link copies by direction,
+        collectives by opcode), from the movement table of the
+        executable the last step ran, and the bytes all steps moved."""
+        fn = self._step_fn
+        if fn is None or fn.last_index is None:
+            return
+        from deepspeed_tpu.telemetry.compile_watch import (
+            executable_tables, movement_per_step)
+        per_step = {rec.index: movement_per_step(
+            executable_tables(rec).movement) for rec in fn.executables}
+        for kind, moved in per_step[fn.last_index].items():
+            labels = {"kind": kind}
+            self.telemetry.gauge(
+                "train_moved_bytes_per_step",
+                help="bytes one train step moves over the host link or "
+                     "the interconnect, per chip, by kind of transfer "
+                     "(from the compiled step's movement table)",
+                labels=labels).set(moved["bytes"])
+            self.telemetry.gauge(
+                "train_movement_calls_per_step",
+                help="transfers (copy pairs, collectives) one train step "
+                     "makes, by kind", labels=labels).set(moved["calls"])
+            total = self.telemetry.counter(
+                "train_moved_bytes_total",
+                help="steps taken x train_moved_bytes_per_step, by kind",
+                labels=labels)
+            moved_so_far = sum(
+                rec.calls * per_step[rec.index].get(kind, {"bytes": 0.0})[
+                    "bytes"] for rec in fn.executables)
+            total.inc(max(moved_so_far - total.value, 0.0))
 
     def _record_step_trace(self, wall: float, data_wait: float,
                            device_s: float) -> None:
@@ -2124,6 +2195,7 @@ class DeepSpeedEngine:
                     if ckpt_err is None:
                         ckpt_err = RuntimeError(
                             f"checkpoint engine close failed: {e!r}")
+        self.telemetry.remove_collector(self._movement_collector)
         self._step_fn = None
         self._grad_fn = None
         self._apply_fn = None

@@ -29,6 +29,8 @@ from deepspeed_tpu.telemetry.compile_watch import (WatchedFunction,
                                                    compile_report,
                                                    executable_cost,
                                                    kernel_table,
+                                                   movement_table,
+                                                   pass_table,
                                                    phase_totals,
                                                    scope_table,
                                                    watched_jit)
@@ -91,7 +93,8 @@ __all__ = [
     "EventRing", "get_event_ring", "set_event_ring", "record_event",
     "install_fault_dump", "WatchedFunction", "watched_jit",
     "compile_report", "all_watched", "executable_cost",
-    "phase_totals", "scope_table", "kernel_table",
+    "phase_totals", "scope_table", "kernel_table", "movement_table",
+    "pass_table",
     "MemoryMonitor", "get_memory_monitor", "set_memory_monitor",
     "Watchdog",
     # training numerics observatory + goodput accounting

@@ -42,10 +42,17 @@ Names and phases (docs/observability.md "Spans"):
   (:func:`install_phase_listeners`), keep per function name jax reports
   (watched or not) the seconds traced, lowered, compiled and read from
   the persistent cache, and hit / miss counts: :func:`phase_totals`;
-* each compiled program's optimized HLO is parsed on demand into a
-  table ``instruction name -> scopes`` (and ``-> kernel name`` for
-  custom calls): :func:`scope_table`, :func:`kernel_table`. The device
-  trace names instructions but carries no ``op_name``, and the
+* each compiled program's optimized HLO is parsed on demand, in one
+  pass, into four tables keyed by instruction name: its scopes
+  (:func:`scope_table`), for custom calls the kernel's name
+  (:func:`kernel_table`), the pass of a train step it belongs to
+  (:func:`pass_table`: ``fwd`` / ``recompute`` / ``bwd`` /
+  ``optimizer``) and, for every instruction that moves data between
+  the host's memory and the device's or between chips, what it moves
+  (:func:`movement_table`: kind, bytes, the other half of its pair,
+  pass, scopes; the collectives the partitioner inserted and the
+  offload stream's copies are in no Python source, only here). The
+  device trace names instructions but carries no ``op_name``, and the
   executables are gone by the time a trace is read: a watched
   function's modules are taken when it is finalized, so the tables
   survive it and setting a program up pays nothing for them.
@@ -58,6 +65,7 @@ catches. Path strings and signature diffs are built only on a miss.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import re
@@ -267,7 +275,8 @@ def _named(fun, name: str):
 # ------------------------------------------------- scopes on the device
 
 # the scopes the programs open (model_implementations/transformer.py,
-# inference/kv_cache.py, inference/server.py, runtime/engine.py)
+# inference/kv_cache.py, inference/server.py; runtime/engine.py opens
+# the train step's three: fwd_bwd, optimizer, grad_exchange)
 SCOPES = frozenset((
     "embed", "ln", "attn_qkv", "kv_write", "kv_read", "attn_kernel",
     "attn_out", "mlp", "lm_head", "sample",
@@ -285,14 +294,44 @@ _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _CALLS = re.compile(r"(?:calls|to_apply|body)=%?([\w.\-]+)")
 _OPERAND = re.compile(r"\(\s*(?:[\w\[\]{},:()\s]*?)%([\w.\-]+)")
 _WORD = re.compile(r"[A-Za-z_][A-Za-z0-9_]*")
-# an instruction that only moves or forwards data: where the compiler
-# gave it no metadata (it gives a memory-space copy none) it takes its
-# operand's scope, and failing that its first scoped consumer's
-_FORWARDS = ("copy-start", "copy-done", "async-start", "async-done",
-             "slice-start", "slice-done", "all-gather-done",
-             "all-reduce-done", "collective-permute-done",
-             "get-tuple-element", "bitcast")
 _NAMES = re.compile(r"%([\w.\-]+)")
+# the opcode: the first word followed by "(" after the result's shape (a
+# shape has no space before a parenthesis: "{1,0:T(8,128)S(5)}")
+_OPCODE = re.compile(r"(?:^|\s)([a-z][a-z0-9\-]*)\(")
+# one array of a shape: element type, dimensions, layout (its memory
+# space is the "S(n)" in the layout: none = the device's HBM)
+_ARRAY = re.compile(r"\b([a-z]+\d+\w*|pred)\[([\d,]*)\](\{[^}]*\})?")
+_SPACE = re.compile(r"S\((\d+)\)")
+_GROUPS = re.compile(r"replica_groups=(?:\[\d+,(\d+)\]|\{\{([\d,]*)\})")
+_TRIPS = re.compile(r'known_trip_count"?:\{"?n"?:"?(\d+)')
+_CONTROL = re.compile(
+    r"(?:to_apply|true_computation|false_computation)=%?([\w.\-]+)"
+    r"|branch_computations=\{([^}]*)\}")
+HOST_SPACE = 5                   # pinned host memory, as the TPU compiler
+#                                  writes it: "f32[2048]{0:T(1024)S(5)}"
+
+# what moves data between chips (the opcodes; each may come as a
+# "<op>-start" / "<op>-done" pair)
+COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
+               "collective-permute", "collective-broadcast",
+               "ragged-all-to-all")
+# ONE list of the opcodes that move or forward data. True: where the
+# compiler gave such an instruction no metadata (it gives a memory-space
+# copy none) the scope table lets it take its operand's scope and,
+# failing that, its first scoped consumer's. The movement table reads
+# the rest of the list: every copy pair, collective and async wrapper.
+_DATA_OPS = {
+    "copy-start": True, "copy-done": True, "async-start": True,
+    "async-done": True, "slice-start": True, "slice-done": True,
+    "all-gather-done": True, "all-reduce-done": True,
+    "collective-permute-done": True, "get-tuple-element": True,
+    "bitcast": True, "async-update": False,
+    **{c + h: False for c in COLLECTIVES for h in ("", "-start", "-done")
+       if c + h not in ("all-gather-done", "all-reduce-done",
+                        "collective-permute-done")}}
+_FORWARDS = frozenset(k for k, forwards in _DATA_OPS.items() if forwards)
+_ASYNC_TARGETS = {"AsyncCollectiveStart": "start",
+                  "AsyncCollectiveDone": "done"}
 
 _texts: Dict[str, list] = {}     # program -> modules of executables now gone
 KEEP_EXECUTABLES = 8             # of them, per program name
@@ -317,22 +356,259 @@ def scopes_of(op_name: str) -> Optional[str]:
     return "/".join(found) if found else None
 
 
-def parse_scopes(text: str) -> Tuple[Dict[str, Optional[str]],
-                                     Dict[str, str]]:
-    """A compiled program's text (``compiled.as_text()``) to
-    ``({instruction: scopes or None}, {custom-call instruction: kernel
-    name})``. An instruction's scopes come from its own ``op_name``
-    metadata; a fusion (or any call) without one takes its called
-    computation's root's; a copy (``copy-start`` / ``copy-done``, the
-    offload stream's transfers among them), ``get-tuple-element`` or
-    ``bitcast`` without one its first operand's and, failing that, its
-    first scoped consumer's."""
+def pass_of(op_name: str) -> Optional[str]:
+    """The pass of a train step an instruction belongs to, from the path
+    it carries: ``fwd`` (``jvp(`` without ``transpose(``), ``bwd``
+    (``transpose(``), ``recompute`` (``rematted_computation``: what
+    ``jax.checkpoint`` runs again inside the backward pass; the word
+    ``checkpoint`` alone is on every instruction of a rematerialised
+    block's backward, its real gradients included, and says nothing),
+    ``optimizer`` (the step's own scope), or None. Read off the two
+    train cells' programs compiled for the TPU:
+    ``jit(train_step)/fwd_bwd/jvp(GPT2)/h_0/mlp/c_fc/dot_general``,
+    ``.../fwd_bwd/transpose(jvp(GPT2))/fwd_bwd/jvp(GPT2)/checkpoint/
+    h_1/attn/c_proj/dot_general``, ``.../transpose(jvp(GPT2))/fwd_bwd/
+    jvp(GPT2)/checkpoint/rematted_computation/h_1/ln_1/mul``."""
+    path = op_name.rsplit(";", 1)[-1]
+    if "rematted_computation" in path:
+        return "recompute"
+    if "transpose(" in path:
+        return "bwd"
+    if "jvp(" in path:
+        return "fwd"
+    return "optimizer" if "optimizer" in path.split("/")[:-1] else None
+
+
+def _arrays(shape: str) -> List[Tuple[float, int]]:
+    """``(bytes, memory space)`` of each array in a shape's text."""
+    out = []
+    for m in _ARRAY.finditer(shape):
+        bits = 8 if m.group(1) == "pred" else int(
+            re.search(r"\d+", m.group(1)).group())
+        n = 1
+        for d in m.group(2).split(","):
+            n *= int(d) if d else 1
+        space = _SPACE.search(m.group(3) or "")
+        out.append((n * bits / 8, int(space.group(1)) if space else 0))
+    return out
+
+
+def _elements(shape: str) -> List[str]:
+    """A tuple shape's top-level elements (the shape itself where it is
+    no tuple): ``(f32[4]{0}, (f32[2]{0}, u32[]))`` -> two."""
+    shape = shape.strip()
+    if not shape.startswith("("):
+        return [shape]
+    out, depth, at = [], 0, 1
+    for i, ch in enumerate(shape):
+        if ch in "([{":
+            depth += 1
+        elif ch in ")]}":
+            depth -= 1
+            if depth == 0:
+                out.append(shape[at:i])
+                break
+        elif ch == "," and depth == 1:
+            out.append(shape[at:i])
+            at = i + 1
+    return out
+
+
+def wire_bytes(kind: str, nbytes: float, group: Optional[int]) -> float:
+    """Bytes that cross a link to or from ONE chip when a buffer of
+    ``nbytes`` goes through a collective over ``group`` chips: a gather,
+    a reduce-scatter or an all-to-all keeps 1/n at home, an all-reduce
+    is a reduce-scatter and a gather; a host copy, a permute or a
+    broadcast moves the buffer whole."""
+    if kind not in ("all-gather", "reduce-scatter", "all-to-all",
+                    "all-reduce", "ragged-all-to-all") or not group:
+        return nbytes
+    part = nbytes * (group - 1) / group
+    return 2 * part if kind == "all-reduce" else part
+
+
+def _resolve(values: Dict[str, Optional[str]], calls, roots, forwards,
+             users) -> None:
+    """Fill the None entries of ``values`` (scopes, or passes): a call
+    takes its called computation's root's, a forwarding instruction its
+    operand's and, failing that, its first consumer's that has one."""
+    for name, comp in calls.items():
+        root = roots.get(comp)
+        if values.get(name) is None and root is not None \
+                and values.get(root) is not None:
+            values[name] = values[root]
+    for _ in range(3):                  # done(start(...)) chains are short
+        for name, src in forwards.items():
+            if values.get(name) is None and values.get(src) is not None:
+                values[name] = values[src]
+    for _ in range(3):                  # start <- done <- the consumer
+        for name, used_by in users.items():
+            if values.get(name) is None:
+                values[name] = next((values[u] for u in used_by
+                                     if values.get(u) is not None), None)
+
+
+def _movement_row(opcode: str, shape: str, rhs: str) -> Optional[dict]:
+    """The start of a movement row for a data-moving opcode (None for
+    one that only forwards); ``kind`` None = not known from this line
+    alone (a ``-done`` learns it from its ``-start``, a copy inside one
+    memory keeps None and is dropped)."""
+    base, half = opcode, "sync"
+    for suffix in ("-start", "-done"):
+        if opcode.endswith(suffix):
+            base, half = opcode[:-len(suffix)], suffix[1:]
+    if base == "copy" and half != "sync":
+        row = {"kind": None, "role": half, "bytes": 0.0, "group": None}
+        arrays = _arrays(shape)
+        if half == "start" and len(arrays) >= 2:
+            (nbytes, dst), (_, src) = arrays[0], arrays[1]
+            if (dst == HOST_SPACE) != (src == HOST_SPACE):
+                row.update(kind="device_to_host" if dst == HOST_SPACE
+                           else "host_to_device", bytes=nbytes)
+        return row
+    if base == "async" and half != "sync":
+        return {"kind": None, "role": half, "bytes": 0.0, "group": None}
+    if base not in COLLECTIVES:
+        return None
+    g = _GROUPS.search(rhs)
+    group = None
+    if g is not None:
+        group = int(g.group(1)) if g.group(1) else \
+            len([x for x in g.group(2).split(",") if x])
+    # a combined collective moves several arrays: a synchronous one's
+    # result holds them all, a start's holds (operands, results, context)
+    sizes = [sum(b for b, _ in _arrays(part)) for part in _elements(shape)]
+    if half == "start" and len(sizes) >= 2:
+        nbytes = max(sizes[0], sizes[1])
+    else:
+        nbytes = sum(sizes)
+    if base == "reduce-scatter" and half == "sync":
+        nbytes *= group or 1            # the operand's: what is reduced
+    return {"kind": base, "role": half, "bytes": nbytes,
+            "group": group or None}
+
+
+def _finish_movement(rows, wrapped, forwards, loops, parent, scope,
+                     passes) -> Dict[str, dict]:
+    """Pair the halves, drop what is no movement, count the loops."""
+    rows = {k: r for k, r in rows.items()
+            if r["computation"] not in wrapped}
+
+    def start_of(name: str, hops: int = 64) -> Optional[str]:
+        """The ``-start`` a ``-done`` descends from: through the
+        ``get-tuple-element`` of its tuple and, on the TPU, through the
+        matmul fusions that carry the collective along."""
+        todo = list(reversed(rows[name]["operands"]))
+        while todo and hops > 0:
+            src, hops = todo.pop(), hops - 1
+            while src not in rows and src in forwards:
+                src = forwards[src]
+            row = rows.get(src)
+            if row is None:
+                continue
+            if row["role"] == "start" and "pair" not in row:
+                return src
+            if row["role"] == "carrier":
+                todo.extend(reversed(row["operands"][:2]))
+        return None
+
+    for name, row in rows.items():
+        if row["role"] == "done":
+            src = start_of(name)
+            if src is not None:
+                rows[src]["pair"], row["pair"] = name, src
+                for key in ("kind", "bytes", "group"):
+                    row[key] = rows[src][key]
+    out = {}
+    for name, row in rows.items():
+        if row["kind"] is None:
+            continue                    # a copy inside one memory
+        calls, known, comp = 1, True, row["computation"]
+        while comp in loops or comp in parent:
+            if comp in loops:
+                known = known and loops[comp] is not None
+                calls *= loops[comp] or 1
+            comp = parent.get(comp)
+        other = row.get("pair")
+        out[name] = {
+            "kind": row["kind"], "role": row["role"],
+            "bytes": row["bytes"] * calls, "group": row["group"],
+            "wire_bytes": wire_bytes(row["kind"], row["bytes"],
+                                     row["group"]) * calls,
+            "calls": calls, "per_iteration": not known,
+            "pair": other,
+            # the halves of a pair say the same: a start the compiler
+            # gave no metadata takes its done's
+            "pass": passes.get(name) or passes.get(other),
+            "scopes": scope.get(name) or scope.get(other)}
+    return out
+
+
+Tables = collections.namedtuple("Tables", "scopes kernels movement passes")
+Tables.__doc__ = """What one parse of a compiled program's text gives:
+``scopes`` and ``kernels`` (:func:`parse_scopes`), ``movement``
+(:func:`movement_table`) and ``passes`` (:func:`pass_table`)."""
+
+
+def parse(text: str) -> Tables:
+    """A compiled program's text (``compiled.as_text()``) to its four
+    tables, in one pass over its lines.
+
+    Scopes: an instruction's come from its own ``op_name`` metadata; a
+    fusion (or any call) without one takes its called computation's
+    root's; a copy (``copy-start`` / ``copy-done``, the offload stream's
+    transfers among them), ``get-tuple-element`` or ``bitcast`` without
+    one its first operand's and, failing that, its first scoped
+    consumer's. Passes (:func:`pass_of`) travel the same way.
+
+    Movement: one row for every instruction that moves data between the
+    host's memory and the device's, or between chips, and that the
+    device trace can show (an instruction INSIDE a fusion is not one):
+
+    * ``kind``: ``host_to_device`` / ``device_to_host`` for a
+      ``copy-start`` / ``copy-done`` pair with pinned host memory
+      (``S(5)``) on one side only (a copy inside the device, HBM to HBM
+      or to its on-chip memories, is no row); else the collective's
+      opcode (:data:`COLLECTIVES`), also for what wraps one: an
+      ``async-start`` / ``async-done`` pair takes the opcode of the
+      computation it wraps, and so does a fusion that holds a collective;
+    * ``role``: ``sync``, ``start`` or ``done`` (the halves of an
+      asynchronous pair: the compiler's own ``<op>-start`` / ``-done``,
+      ``async-start`` / ``-done``, or the TPU's fusions around an
+      ``AsyncCollectiveStart`` / ``AsyncCollectiveDone`` call, which the
+      trace shows as ``async-collective-start.N``), ``fused`` (a fusion
+      that is nothing but a collective, such as the TPU's all-reduce +
+      slice written as ``fusion.N``) or ``carrier`` (a matmul fusion that
+      carries a collective's steps along: compute, with the exchange
+      under it);
+    * ``bytes``: the buffer that moves, per execution of the program:
+      the result's for a copy, a gather (the gathered array: the larger
+      of operand and result), an all-reduce, an all-to-all or a permute,
+      the OPERAND's (result x group) for a reduce-scatter; of a pair,
+      both halves carry it. ``wire_bytes``: what of it crosses a link to
+      or from one chip (:func:`wire_bytes`; ``group`` = chips in the
+      instruction's replica group). ``calls``: executions per execution
+      of the program, the product of the trip counts of the ``while``
+      bodies around it where the text states them
+      (``known_trip_count``); where it does not, ``per_iteration`` is
+      True and bytes and calls are those of ONE iteration;
+    * ``pair``: the ``-done`` of a ``-start`` and the other way;
+    * ``pass`` and ``scopes``: as in the pass and scope tables."""
     scope: Dict[str, Optional[str]] = {}
+    passes: Dict[str, Optional[str]] = {}
     kernels: Dict[str, str] = {}
     roots: Dict[str, str] = {}          # computation -> its root instruction
     calls: Dict[str, str] = {}          # instruction -> computation it calls
     forwards: Dict[str, str] = {}       # instruction -> its first operand
     users: Dict[str, List[str]] = {}    # such an instruction -> consumers
+    rows: Dict[str, dict] = {}          # the movement table, being made
+    inside: Dict[str, dict] = {}        # computation -> collective in it
+    computes = set()                    # computations with a matmul
+    async_root: Dict[str, str] = {}     # computation -> start | done
+    root_op: Dict[str, str] = {}        # computation -> its root's opcode
+    wrapped = set()                     # computations of fusions / asyncs
+    loops: Dict[str, Optional[int]] = {}    # while body -> trip count
+    parent: Dict[str, str] = {}         # computation -> where it is called
     computation = None
     for line in text.splitlines():
         m = _INSTR.match(line)
@@ -345,39 +621,85 @@ def parse_scopes(text: str) -> Tuple[Dict[str, Optional[str]],
         if m.group(1) and computation is not None:
             roots[computation] = name
         op = _OP_NAME.search(line)
-        found = scopes_of(op.group(1)) if op else None
-        scope[name] = found
+        scope[name] = scopes_of(op.group(1)) if op else None
+        # XLA's own rematerialisation names its clones "<name>.remat<n>"
+        # and leaves them their original's (forward) path
+        passes[name] = "recompute" if ".remat" in name else \
+            pass_of(op.group(1)) if op else None
         rhs = line[m.end():]
+        at = _OPCODE.search(rhs)
+        opcode = at.group(1) if at else ""
         if "custom_call_target=\"tpu_custom_call\"" in rhs:
             kernels[name] = ".".join(
                 p for p in name.split(".") if not p.isdigit())
-        if found is None:
-            c = _CALLS.search(rhs)
-            if c is not None:
-                calls[name] = c.group(1)
-            elif any(f" {k}(" in rhs for k in _FORWARDS):
-                o = _OPERAND.search(rhs[rhs.index("("):]
-                                    if "(" in rhs else "")
-                if o is not None:
-                    forwards[name] = o.group(1)
-                users[name] = []
+        c = _CALLS.search(rhs)
+        if c is not None:
+            calls[name] = c.group(1)
+        elif opcode in _FORWARDS:
+            o = _OPERAND.search(rhs[rhs.index("("):]
+                                if "(" in rhs else "")
+            if o is not None:
+                forwards[name] = o.group(1)
+            users[name] = []
         for operand in _NAMES.findall(rhs):
             if operand in users:
                 users[operand].append(name)
-    for name, comp in calls.items():
-        root = roots.get(comp)
-        if root is not None and scope.get(root) is not None:
-            scope[name] = scope[root]
-    for _ in range(3):                  # done(start(...)) chains are short
-        for name, src in forwards.items():
-            if scope.get(name) is None and scope.get(src) is not None:
-                scope[name] = scope[src]
-    for _ in range(3):                  # start <- done <- the consumer
-        for name, used_by in users.items():
-            if scope.get(name) is None:
-                scope[name] = next((scope[u] for u in used_by
-                                    if scope.get(u) is not None), None)
-    return scope, kernels
+        # ---- what the movement table needs of this line
+        if m.group(1) and computation is not None:
+            root_op[computation] = opcode
+            if opcode == "custom-call":
+                for target, role in _ASYNC_TARGETS.items():
+                    if target in rhs:
+                        async_root[computation] = role
+        if opcode in ("convolution", "dot"):
+            computes.add(computation)
+        elif opcode == "while":
+            body = re.search(r"body=%?([\w.\-]+)", rhs)
+            trips = _TRIPS.search(rhs)
+            if body is not None:
+                loops[body.group(1)] = int(trips.group(1)) if trips \
+                    else None
+                parent[body.group(1)] = computation
+        elif opcode in ("call", "conditional"):
+            for one, many in _CONTROL.findall(rhs):
+                for callee in _NAMES.findall(many) if many else (one,):
+                    parent[callee] = computation
+        row = _movement_row(opcode, rhs[:at.start()], rhs) \
+            if opcode in _DATA_OPS else None
+        if opcode in ("fusion", "async-start") and c is not None:
+            callee = c.group(1)
+            wrapped.add(callee)
+            held = inside.get(callee)
+            if held is not None:
+                row = dict(held, role=async_root.get(callee) or (
+                    "start" if opcode == "async-start" else
+                    "carrier" if callee in computes else "fused"))
+                if row["kind"] == "all-reduce" and row["role"] == "fused" \
+                        and root_op.get(callee) == "dynamic-slice":
+                    # the TPU's reduce-scatter: reduce all, keep a slice
+                    row["kind"] = "reduce-scatter"
+        if row is not None:
+            row["computation"] = computation
+            row["operands"] = _NAMES.findall(
+                rhs[at.end():].split("), ", 1)[0])
+            rows[name] = row
+            if row["kind"] in COLLECTIVES and computation not in inside:
+                inside[computation] = {k: row[k] for k in
+                                       ("kind", "bytes", "group")}
+    _resolve(scope, {k: v for k, v in calls.items() if scope[k] is None},
+             roots, {k: v for k, v in forwards.items() if scope[k] is None},
+             {k: v for k, v in users.items() if scope[k] is None})
+    _resolve(passes, calls, roots, forwards, users)
+    return Tables(scope, kernels,
+                  _finish_movement(rows, wrapped, forwards, loops, parent,
+                                   scope, passes), passes)
+
+
+def parse_scopes(text: str) -> Tuple[Dict[str, Optional[str]],
+                                     Dict[str, str]]:
+    """``({instruction: scopes or None}, {custom-call instruction: kernel
+    name})`` of a compiled program's text: :func:`parse`'s first two."""
+    return parse(text)[:2]
 
 
 def _modules_of(compiled):
@@ -413,29 +735,42 @@ def _harvest(name: str, records: List["ExecutableRecord"]) -> None:
         _tables.pop(name, None)
 
 
-def _tables_for(name: str):
-    live = [rec.compiled for w in all_watched() if w.name == name
+def _text_of(source) -> str:
+    return source if isinstance(source, str) else \
+        "\n\n".join(m.to_string() for m in source)
+
+
+def executable_tables(rec: "ExecutableRecord") -> Tables:
+    """The tables of ONE executable of a watched function, parsed when
+    first asked for and kept on its record."""
+    if rec.tables is None:
+        rec.tables = parse(_text_of(_modules_of(rec.compiled)))
+    return rec.tables
+
+
+def _tables_for(name: str) -> Tables:
+    live = [rec for w in all_watched() if w.name == name
             for rec in w._records]
     with _tables_lock:
         kept = list(_texts.get(name, ()))
         got = _tables.get(name)
         if got is not None and got[0] == (len(kept), len(live)):
             return got[1]
-    scope: Dict[str, Optional[str]] = {}
-    kernels: Dict[str, str] = {}
-    for source in kept + [_modules_of(c) for c in live]:
-        sc, kn = parse_scopes(
-            source if isinstance(source, str) else
-            "\n\n".join(m.to_string() for m in source))
-        for k, v in sc.items():
-            # executables of one program (prompt buckets) may number
-            # their instructions alike: a name that means two scopes
-            # means none
-            scope[k] = v if scope.get(k, v) == v else None
-        kernels.update(kn)
+    merged = Tables({}, {}, {}, {})
+    for one in [parse(_text_of(source)) for source in kept] + \
+            [executable_tables(rec) for rec in live]:
+        # executables of one program (prompt buckets) may number their
+        # instructions alike: a name that means two scopes (passes,
+        # movement rows) means none
+        for into, table in ((merged.scopes, one.scopes),
+                            (merged.passes, one.passes),
+                            (merged.movement, one.movement)):
+            for k, v in table.items():
+                into[k] = v if into.get(k, v) == v else None
+        merged.kernels.update(one.kernels)
     with _tables_lock:
-        _tables[name] = ((len(kept), len(live)), (scope, kernels))
-    return scope, kernels
+        _tables[name] = ((len(kept), len(live)), merged)
+    return merged
 
 
 def scope_table(name: str) -> Dict[str, Optional[str]]:
@@ -445,13 +780,57 @@ def scope_table(name: str) -> Dict[str, Optional[str]]:
     none. Parsed on demand from the live executables' modules and from
     those harvested when a watched function went; survives the
     executables."""
-    return _tables_for(name)[0]
+    return _tables_for(name).scopes
 
 
 def kernel_table(name: str) -> Dict[str, str]:
     """Custom-call (Pallas) instruction name -> kernel name, for the
     watched program ``name``."""
-    return _tables_for(name)[1]
+    return _tables_for(name).kernels
+
+
+def movement_table(name: str) -> Dict[str, Optional[dict]]:
+    """Instruction name -> its movement row (:func:`parse`: ``kind``,
+    ``role``, ``bytes``, ``wire_bytes``, ``group``, ``calls``,
+    ``per_iteration``, ``pair``, ``pass``, ``scopes``) for every
+    instruction of the watched program ``name`` that moves data between
+    the host's memory and the device's or between chips; None for a name
+    that means two different rows in two executables. The same parse, the
+    same laziness and the same survival as :func:`scope_table`."""
+    return _tables_for(name).movement
+
+
+def pass_table(name: str) -> Dict[str, Optional[str]]:
+    """Instruction name -> ``fwd`` / ``recompute`` / ``bwd`` /
+    ``optimizer`` / None, for every instruction of the watched program
+    ``name``: from its path (:func:`pass_of`), or ``recompute`` for a
+    clone XLA's rematerialisation made to save memory (named
+    ``<instruction>.remat<n>``; its path is still the forward's)."""
+    return _tables_for(name).passes
+
+
+def movement_per_step(table, detail: bool = False) -> Dict:
+    """What one execution moves, by kind: ``{kind: {"bytes": wire bytes,
+    "calls": transfers}}`` (``detail``: by ``(kind, pass, innermost
+    scope)``), of a watched program by name (every executable compiled
+    under it) or of one movement table
+    (``executable_tables(rec).movement``: one executable's). Each pair
+    and each synchronous instruction is counted once (a compute fusion
+    that carries a collective is its pair's, not a transfer of its own);
+    rows in a loop of unknown trip count for one iteration."""
+    if isinstance(table, str):
+        table = movement_table(table)
+    out: Dict = {}
+    for row in table.values():
+        if row is None or row["role"] in ("done", "carrier"):
+            continue
+        key = row["kind"] if not detail else (
+            row["kind"], row["pass"],
+            (row["scopes"] or "").rsplit("/", 1)[-1] or None)
+        moved = out.setdefault(key, {"bytes": 0.0, "calls": 0})
+        moved["bytes"] += row["wire_bytes"]
+        moved["calls"] += row["calls"]
+    return out
 
 
 def watched_programs() -> List[str]:
@@ -471,6 +850,7 @@ class ExecutableRecord:
     cost: Dict[str, float]
     calls: int = 0
     compiled: Any = None
+    tables: Any = None                 # executable_tables(), once asked
 
 
 _registry_lock = threading.Lock()
@@ -504,6 +884,7 @@ class WatchedFunction:
         self._execs: Dict[Tuple, ExecutableRecord] = {}
         self._records: List[ExecutableRecord] = []   # creation order
         self._last: Optional[ExecutableRecord] = None
+        self.last_index: Optional[int] = None    # executable last CALLED
         self.retraces: List[dict] = []
         self._lock = threading.RLock()
         self._arg_names = self._positional_names(fun)
@@ -752,6 +1133,7 @@ class WatchedFunction:
                 raise
             return self._jit(*args, **kwargs)
         rec.calls += 1
+        self.last_index = rec.index
         return out
 
     # ----------------------------------------------------------- jit parity

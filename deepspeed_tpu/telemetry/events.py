@@ -58,6 +58,10 @@ SPEC_COLLAPSE = "spec_collapse"
 # spans, chain depth and the program it waited on (the steps themselves
 # live in the span log, telemetry/spans.py)
 SLOW_STEP = "slow_step"
+# the span log (telemetry/spans.py) dropped its first record: what reads
+# it from here on reads a window that has lost its oldest part. One
+# event per log, with its capacity
+SPAN_LOG_OVERFLOW = "span_log_overflow"
 # KV-pool famine (telemetry/memory.py KVPoolAccountant): an allocation
 # the pool could not cover froze the allocator state here — one event
 # per famine episode, re-armed by the next successful allocation

@@ -23,7 +23,7 @@ from __future__ import annotations
 import math
 import re
 import threading
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Tuple
 
 _NAME_RE = re.compile(r"^[a-zA-Z_:][a-zA-Z0-9_:]*$")
 _LABEL_RE = re.compile(r"^[a-zA-Z_][a-zA-Z0-9_]*$")
@@ -215,6 +215,32 @@ class MetricRegistry:
     def __init__(self):
         self._lock = threading.RLock()
         self._families: Dict[str, _Family] = {}
+        self._collectors: List[Callable[[], None]] = []
+
+    # ----------------------------------------------------------- collect
+
+    def add_collector(self, fn: Callable[[], None]) -> None:
+        """``fn()`` runs at the top of every READ of the registry
+        (``prometheus_text`` / ``snapshot`` / ``export_state``) and sets
+        the series it owns: for numbers that cost something to derive
+        and that nobody needs until somebody looks (the train step's
+        movement families parse a compiled program's text)."""
+        with self._lock:
+            self._collectors.append(fn)
+
+    def remove_collector(self, fn: Callable[[], None]) -> None:
+        with self._lock:
+            if fn in self._collectors:
+                self._collectors.remove(fn)
+
+    def _collect(self) -> None:
+        with self._lock:
+            fns = list(self._collectors)
+        for fn in fns:
+            try:
+                fn()
+            except Exception:  # noqa: BLE001 — a read must not fail
+                pass
 
     # ------------------------------------------------------------ create
 
@@ -284,6 +310,7 @@ class MetricRegistry:
         family, cumulative ``_bucket{le=...}`` + ``_sum``/``_count`` for
         histograms. Deterministic ordering (sorted names, sorted label
         sets) so golden tests and scrape diffs are stable."""
+        self._collect()
         out: List[str] = []
         with self._lock:
             for name in sorted(self._families):
@@ -314,6 +341,7 @@ class MetricRegistry:
         """JSON-able dump of every series; histograms include derived
         p50/p90/p99 so consumers (bench.py, dashboards) never re-derive
         quantiles from buckets themselves."""
+        self._collect()
         snap: dict = {}
         with self._lock:
             for name, fam in self._families.items():
@@ -348,6 +376,7 @@ class MetricRegistry:
         can fold it in via ``import_state`` without losing precision.
         Pure builtins, so ``json.dumps`` round-trips it byte-exactly —
         the process-per-replica transport serializes this verbatim."""
+        self._collect()
         state: dict = {}
         with self._lock:
             for name, fam in self._families.items():

@@ -40,6 +40,7 @@ import time
 from collections import deque
 from typing import Callable, Dict, List, Optional, Tuple
 
+from deepspeed_tpu.telemetry.events import SPAN_LOG_OVERFLOW, record_event
 from deepspeed_tpu.telemetry.registry import (MetricRegistry, get_registry,
                                               sanitize_metric_name)
 from deepspeed_tpu.telemetry.tracing import TraceSpan, current_span
@@ -54,13 +55,20 @@ SpanRecord = Tuple[str, float, float, int, int, object, Optional[dict]]
 class SpanLog:
     """Bounded log of closed spans (see the module docstring).
 
-    ``capacity`` defaults to 65,536 records: a 50 s serving window is
-    about 1,100 steps x 9 spans plus a few hundred requests x 4. One
+    ``capacity`` defaults to 524,288 records, five traced runs of the
+    fastest cell: a traced chat run leaves 101,296 records (my chip run,
+    PR 40: 10,969 ``serve:step`` spans, worked steps and the first poll
+    of each lull, over the lead-in, the 50 s window and the traced
+    second schedule, each with up to 9 phase spans, plus 290 requests x
+    4), where the 65,536 of before had lost 35,688 and with them the
+    window's first 16 s. The deque grows only as records arrive (about
+    0.15 GB of host memory when full). The first record dropped leaves
+    one ``span_log_overflow`` event in the flight-recorder ring. One
     writer thread per feeder is assumed (the serving loop, the train
     loop); ids come from one atomic counter, so feeders on different
     threads never collide."""
 
-    def __init__(self, capacity: int = 65536,
+    def __init__(self, capacity: int = 524288,
                  clock: Callable[[], float] = time.perf_counter):
         if capacity < 1:
             raise ValueError(f"capacity must be >= 1, got {capacity}")
@@ -82,9 +90,14 @@ class SpanLog:
         sid = span_id or next(self._ids)
         buf = self._buf
         if len(buf) == self.capacity:
-            self.dropped += 1
+            self._drop(1)
         buf.append((name, start, end, parent, sid, key, attrs))
         return sid
+
+    def _drop(self, n: int) -> None:
+        if not self.dropped:
+            record_event(SPAN_LOG_OVERFLOW, capacity=self.capacity)
+        self.dropped += n
 
     def extend(self, records: List[SpanRecord]) -> None:
         """Append records the caller built in this log's layout (ids
@@ -92,7 +105,7 @@ class SpanLog:
         buf = self._buf
         over = len(buf) + len(records) - self.capacity
         if over > 0:
-            self.dropped += over
+            self._drop(over)
         buf.extend(records)
 
     def __len__(self) -> int:

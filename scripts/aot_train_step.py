@@ -1,0 +1,199 @@
+"""The engine's OWN ``train_step`` of a benchmark train cell, compiled for
+a TPU that is described and not attached (``on-chip-measurement`` guide,
+section 2, third rehearsal): the compiled text, its sha256, and what the
+compile watch's movement table reads in it. No chip, no chip time.
+
+    JAX_PLATFORMS=cpu python scripts/aot_train_step.py \\
+        --traffic offload --layers 24 --out /root/scratch/offload.txt
+    JAX_PLATFORMS=cpu python scripts/aot_train_step.py \\
+        --repo _parent --traffic zero3-x4 --layers 2
+
+Two uses. (1) To show that a change left the compiled step what it was:
+run it on a ``git archive`` of the parent (``--repo``) and on the tree
+(24 layers of the offload cell: ~110 s, of the x4 cell: ~5 min) and
+compare ``sha256_instructions``: the text less what only says WHERE in
+the source an instruction came from (the location tables it opens with,
+and the flash kernels' ``backend_config``, a serialized Mosaic module
+that holds the same file names and line numbers: a checkout at another
+path, or a line added above a call site, changes those bytes and
+nothing the chip runs). ``sha256`` is over every byte. (2) To read what
+the TPU's compiler writes (the words on ``op_name`` paths, how it wraps
+collectives, which memory space a copy crosses) before fixing a rule in
+``compile_watch.parse``.
+
+How: an engine builds its state by RUNNING jitted functions and
+``device_put``, which a described device cannot do. While the engine is
+built, ``jax.jit`` and ``jax.device_put`` are replaced by stand-ins that
+answer abstract inputs with ``jax.eval_shape`` and shapes that carry the
+shardings asked for; ``jax.default_backend`` says ``tpu`` so that the
+engine and the model take their TPU paths (the streamed offload, the
+flash kernel). Both are put back before the step is lowered. Nothing
+runs, so this says nothing about results or times.
+"""
+from __future__ import annotations
+
+import argparse
+import hashlib
+import json
+import os
+import sys
+import time
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+
+LOCATION_TABLES = ("FileNames", "FunctionNames", "FileLocations",
+                   "StackFrames")
+
+
+def instructions(text: str) -> str:
+    """``text`` without its source locations (see the module docstring)."""
+    out, table = [], False
+    for line in text.splitlines():
+        if line.startswith(LOCATION_TABLES):
+            table = True
+        elif table:
+            table = bool(line.strip())
+        elif "tpu_custom_call" in line and "backend_config=" in line:
+            out.append(line.split("backend_config=", 1)[0])
+        else:
+            out.append(line)
+    return "\n".join(out)
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--repo", default=os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), help="the checkout to compile from")
+    ap.add_argument("--traffic", default="offload",
+                    help="benchmark/traffic/<name>.json of a train cell")
+    ap.add_argument("--config", default="gpt2-1.3b-train")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="depth (default: the configuration's)")
+    ap.add_argument("--out", default=None, help="write the text here")
+    args = ap.parse_args()
+    repo = os.path.abspath(args.repo)
+    sys.path.insert(0, repo)
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.experimental import topologies
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    SDS = jax.ShapeDtypeStruct
+
+    def abstract(tree) -> bool:
+        return any(isinstance(x, SDS) for x in jax.tree.leaves(tree))
+
+    real_jit, real_put, real_backend = (jax.jit, jax.device_put,
+                                        jax.default_backend)
+
+    class Jit:
+        """``jax.jit`` that answers abstract arguments with shapes."""
+
+        def __init__(self, fn, **kw):
+            self.fn, self.kw, self.real = fn, kw, real_jit(fn, **kw)
+
+        def __call__(self, *a, **k):
+            sh = self.kw.get("out_shardings")
+            if not (abstract((a, k)) or sh is not None):
+                return self.real(*a, **k)
+            outs = jax.eval_shape(self.fn, *a, **k)
+            if sh is None:
+                return outs
+            return jax.tree.map(
+                lambda o, s: SDS(o.shape, o.dtype, sharding=s), outs, sh,
+                is_leaf=lambda x: x is None)
+
+        def __getattr__(self, name):
+            return getattr(self.real, name)
+
+    def jit(fn=None, **kw):
+        return Jit(fn, **kw) if fn is not None else (
+            lambda f: Jit(f, **kw))
+
+    def put(x, device=None, **kw):
+        if device is None:
+            return real_put(x, **kw)
+
+        def one(v, s):
+            v = v if hasattr(v, "dtype") else jnp.asarray(v)
+            return SDS(v.shape, v.dtype, sharding=s)
+        if hasattr(device, "device_set"):
+            return jax.tree.map(lambda v: one(v, device), x)
+        return jax.tree.map(one, x, device)
+
+    from deepspeed_tpu.ops.pallas import flash_attention as fa
+    jax.default_backend = lambda: "tpu"
+    fa._should_interpret = lambda: False
+    from benchmark.lib import harness
+    bench = os.path.join(repo, "benchmark")
+    config = harness.load_json(os.path.join(
+        bench, "configs", args.config + ".json"))
+    traffic = harness.load_json(os.path.join(
+        bench, "traffic", args.traffic + ".json"))
+    family = harness.load_family(config["model"]["family"], bench)
+    model = dict(config["model"])
+    if args.layers is not None:
+        model["n_layer"] = args.layers
+    tm = family.train_model(model)
+    import deepspeed_tpu
+    from deepspeed_tpu.comm.mesh import MeshConfig, build_mesh
+    chips = int(np.prod(list(traffic["mesh"].values())))
+    mesh = build_mesh(MeshConfig(**traffic["mesh"]),
+                      devices=list(topo.devices)[:chips])
+    everywhere = NamedSharding(mesh, P())
+    params = jax.tree.map(
+        lambda x: SDS(x.shape, x.dtype, sharding=everywhere),
+        jax.eval_shape(lambda: family.train_params(tm, 0)))
+    jax.jit, jax.device_put = jit, put
+    try:
+        engine, _, _, _ = deepspeed_tpu.initialize(
+            model=tm, model_parameters=params, mesh=mesh,
+            config=traffic["engine"])
+    finally:
+        jax.jit, jax.device_put = real_jit, real_put
+    ej = traffic["engine"]
+    rows = ej["train_micro_batch_size_per_gpu"] * ej.get(
+        "gradient_accumulation_steps", 1) * mesh.shape["data"] \
+        * mesh.shape["fsdp"]
+    batch = {"input_ids": SDS((rows, int(traffic["seq_len"])), jnp.int32,
+                              sharding=everywhere)}
+    engine._compile_step(batch)
+    batch = jax.tree.map(lambda x, s: SDS(x.shape, x.dtype, sharding=s),
+                         batch, engine._batch_sharding(batch))
+    rng = SDS((2,), jnp.uint32, sharding=everywhere)
+    t0 = time.time()
+    text = engine._step_fn.lower(engine.state, batch, rng,
+                                 False).compile().as_text()
+    seconds = time.time() - t0
+    jax.default_backend = real_backend
+    if args.out:
+        with open(args.out, "w") as fh:
+            fh.write(text)
+    report = {"repo": repo, "traffic": args.traffic,
+              "layers": model["n_layer"], "chips": chips,
+              "compile_s": round(seconds, 1), "chars": len(text),
+              "sha256": hashlib.sha256(text.encode()).hexdigest(),
+              "sha256_instructions": hashlib.sha256(
+                  instructions(text).encode()).hexdigest()}
+    try:
+        from deepspeed_tpu.telemetry import compile_watch
+        t0 = time.time()
+        tables = compile_watch.parse(text)
+        report["parse_s"] = round(time.time() - t0, 2)
+        report["movement_rows"] = len(tables.movement)
+        report["moved_per_step"] = compile_watch.movement_per_step(
+            tables.movement)
+    except AttributeError:      # a checkout before the movement table
+        pass
+    print(json.dumps(report))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

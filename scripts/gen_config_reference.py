@@ -227,7 +227,16 @@ def generate() -> str:
               "`prof[\"module_breakdown\"]` / `prof[\"module_table\"]` "
               "(`profiling/flops_profiler.py`; reference "
               "`flops_profiler/profiler.py`'s torch-hook module tree)."))
-    emit_model(buf, "comms_logger", C.CommsLoggerConfig)
+    emit_model(buf, "comms_logger", C.CommsLoggerConfig,
+               note=("`enabled`: after the first step the engine logs what "
+                     "each compiled program moves a step, by kind of "
+                     "transfer (host-link copies by direction, collectives "
+                     "by opcode; `verbose`: also by pass and scope), read "
+                     "off the compiled program, so partitioner-inserted "
+                     "collectives and the offload stream are in it "
+                     "(docs/observability.md \"Training step: what "
+                     "moves\"). `prof_all` / `debug` are accepted for "
+                     "reference parity."))
     emit_model(buf, "tensorboard", C.TensorBoardConfig)
     emit_model(buf, "wandb", C.WandbConfig)
     emit_model(buf, "csv_monitor", C.CSVConfig)

@@ -75,15 +75,27 @@ def make_engine(num_slots=2, **knobs):
 # ================================================================ the log
 
 def test_log_is_bounded_and_counts_drops():
-    log = SpanLog(capacity=4)
-    for i in range(6):
-        log.record("x", float(i), i + 0.5, key=i)
-    assert len(log) == 4 and log.dropped == 2
-    assert [r[KEY] for r in log.snapshot()] == [2, 3, 4, 5]
-    log.extend([("y", 0.0, 1.0, 0, log.next_id(), 9, None)] * 3)
-    assert len(log) == 4 and log.dropped == 5
-    assert log.stats() == {"capacity": 4, "records": 4, "dropped": 5}
-    assert get_span_log().capacity >= 65536
+    prev = set_event_ring(EventRing(64))
+    try:
+        log = SpanLog(capacity=4)
+        for i in range(4):
+            log.record("x", float(i), i + 0.5, key=i)
+        assert not get_event_ring().snapshot()      # full, nothing lost yet
+        for i in range(4, 6):
+            log.record("x", float(i), i + 0.5, key=i)
+        assert len(log) == 4 and log.dropped == 2
+        assert [r[KEY] for r in log.snapshot()] == [2, 3, 4, 5]
+        log.extend([("y", 0.0, 1.0, 0, log.next_id(), 9, None)] * 3)
+        assert len(log) == 4 and log.dropped == 5
+        assert log.stats() == {"capacity": 4, "records": 4, "dropped": 5}
+        # the loss is said where it happens, once: at the first drop
+        events = [e for e in get_event_ring().snapshot()
+                  if e["kind"] == "span_log_overflow"]
+        assert len(events) == 1 and events[0]["data"] == {"capacity": 4}
+    finally:
+        set_event_ring(prev)
+    # ISSUE 40's floor; a traced chat run leaves 101,296 records
+    assert get_span_log().capacity >= max(327680, 4 * 101296)
     log.clear()
     assert len(log) == 0 and log.dropped == 0
 
@@ -336,6 +348,9 @@ def test_train_step_spans(fresh):
     recs = fresh.snapshot(prefix="train:")
     steps = [r for r in recs if r[NAME] == "train:step"]
     assert [s[KEY] for s in steps] == [1, 2]
+    # which executable's movement-table rows each step ran
+    assert [s[ATTRS] for s in steps] == [
+        {"program": "train_step", "executable": 0}] * 2
     for s in steps:
         kids = sorted((r for r in recs if r[PARENT] == s[ID]),
                       key=lambda r: r[START])
@@ -349,6 +364,105 @@ def test_train_step_spans(fresh):
               if v}
     assert any(v.split("/")[0] == "fwd_bwd" for v in scopes)
     assert any(v.split("/")[0] == "optimizer" for v in scopes)
+
+
+
+def test_zero3_step_says_what_it_moves(fresh, monkeypatch):
+    """A ZeRO-3 step on four devices: every exchange in it was written
+    by the partitioner, none by the program. The movement table reads
+    them off the compiled text: parameters gathered (n-1)/n over the
+    wire, gradients reduced (the CPU partitioner writes the
+    reduce-scatter as an all-reduce and a slice: counted as written);
+    the engine's families say it when the registry is read, and the
+    ``comms_logger`` block prints it."""
+    import deepspeed_tpu
+    import numpy as np
+    from deepspeed_tpu import comm
+    from deepspeed_tpu.comm.mesh import MeshConfig, build_mesh
+    said = []
+    monkeypatch.setattr(comm.comm.logger, "info",
+                        lambda msg, *a, **k: said.append(str(msg)))
+    was = (comm.comms_logger.enabled, comm.comms_logger.verbose)
+    params = {"blk0": {"w": jnp.full((64, 128), 0.1, jnp.float32)},
+              "blk1": {"w": jnp.full((128, 32), 0.1, jnp.float32)}}
+    param_bytes = (64 * 128 + 128 * 32) * 4
+
+    def loss_fn(p, b, rng):
+        y = jnp.tanh(b["x"] @ p["blk0"]["w"]) @ p["blk1"]["w"]
+        return jnp.mean((y - b["y"]) ** 2)
+    n = 4
+    mesh = build_mesh(MeshConfig(data=1, fsdp=n), devices=jax.devices()[:n])
+    # rows enough that gathering the parameters is cheaper than
+    # gathering the batch: the partitioner chooses by bytes
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        loss_fn=loss_fn, model_parameters=params, mesh=mesh,
+        config={"train_micro_batch_size_per_gpu": 256,
+                "optimizer": {"type": "sgd", "params": {"lr": 0.01}},
+                "zero_optimization": {
+                    "stage": 3, "stage3_param_persistence_threshold": 0},
+                "comms_logger": {"enabled": True, "verbose": True}})
+    try:
+        rng = np.random.default_rng(0)
+        B = engine.train_batch_size
+        for _ in range(3):
+            engine.train_batch({
+                "x": jnp.asarray(rng.normal(size=(B, 64)), jnp.float32),
+                "y": jnp.zeros((B, 32), jnp.float32)})
+        # this engine's own executable (the program-level table merges
+        # every executable a process compiled under the name)
+        table = compile_watch.executable_tables(
+            engine._step_fn.executables[0]).movement
+        rows = list(table.values())
+        assert {r["kind"] for r in rows} <= set(compile_watch.COLLECTIVES)
+        gathers = [r for r in rows if r["kind"] == "all-gather"]
+        # each parameter gathered once (a program this small keeps the
+        # gathered copy for its backward pass): passes = 1
+        assert sum(r["bytes"] for r in gathers) == param_bytes
+        assert sum(r["wire_bytes"] for r in gathers) == \
+            param_bytes * (n - 1) / n
+        assert all(r["group"] == n and r["pass"] == "fwd"
+                   and r["scopes"] == "fwd_bwd" for r in gathers)
+        # the gradients: as many bytes as the parameters, reduced in the
+        # backward pass
+        reduces = [r for r in rows if r["pass"] == "bwd"
+                   and r["kind"] in ("all-reduce", "reduce-scatter")]
+        assert sum(r["bytes"] for r in reduces) == param_bytes
+        per_step = compile_watch.movement_per_step(table)
+        assert per_step["all-gather"] == {
+            "bytes": param_bytes * (n - 1) / n, "calls": len(gathers)}
+        # the families appear when the registry is READ, not before
+        reg = get_registry()
+        assert "train_moved_bytes_per_step" not in reg._families
+        snap = reg.snapshot()
+
+        def series(name):
+            return {s["labels"]["kind"]: s["value"]
+                    for s in snap[name]["series"]}
+        assert series("train_moved_bytes_per_step") == {
+            k: v["bytes"] for k, v in per_step.items()}
+        assert series("train_movement_calls_per_step") == {
+            k: v["calls"] for k, v in per_step.items()}
+        assert series("train_moved_bytes_total") == {
+            k: 3 * v["bytes"] for k, v in per_step.items()}
+        engine.train_batch({
+            "x": jnp.asarray(rng.normal(size=(B, 64)), jnp.float32),
+            "y": jnp.zeros((B, 32), jnp.float32)})
+        assert "train_moved_bytes_total{kind=\"all-gather\"} " + str(
+            int(4 * per_step["all-gather"]["bytes"])) \
+            in reg.prometheus_text()
+        # the comms_logger block printed the step's table once, after
+        # the first step, by kind and (verbose) by pass and scope
+        lines = [m for m in said if m.startswith("comm: train_step: ")]
+        assert sum("all-gather: %d transfers" % len(gathers) in m
+                   for m in lines) == 1
+        assert any("all-gather | pass fwd | scope fwd_bwd" in m
+                   for m in lines)
+        assert comm.comms_logger.summary()["train_step"] == \
+            compile_watch.movement_per_step("train_step")
+    finally:
+        engine.destroy()
+        comm.comms_logger.configure(*was)
+    assert not get_registry()._collectors        # gone with the engine
 
 
 # ======================================================= the compile watch
@@ -474,3 +588,224 @@ def test_scope_table_parser_on_a_fixed_text():
     assert compile_watch.scopes_of("jit(f)/jit(main)/mul") is None
     assert compile_watch.scopes_of(
         "jit(f)/optimizer/optimizer/add") == "optimizer"
+
+
+# ------------------------------------------------------ the movement table
+
+MOVES = """\
+HloModule jit_train_step, is_scheduled=true
+
+%add.clone (x: f32[], y: f32[]) -> f32[] {
+  %x = f32[] parameter(0)
+  %y = f32[] parameter(1)
+  ROOT %add.2 = f32[] add(%x, %y)
+}
+
+%wrapped_rs (p0: f32[1024,64]) -> f32[256,64] {
+  %p0 = f32[1024,64]{1,0} parameter(0)
+  ROOT %reduce-scatter.9 = f32[256,64]{1,0} reduce-scatter(%p0), channel_id=3, replica_groups=[1,4]<=[4], dimensions={0}, to_apply=%add.clone
+}
+
+%fused_computation.5 (param_0.1: bf16[512,64]) -> (bf16[512,64], bf16[2048,64], u32[]) {
+  %param_0.1 = bf16[512,64]{1,0:T(8,128)(2,1)S(1)} parameter(0)
+  %all-gather.7 = bf16[2048,64]{1,0:T(8,128)(2,1)} all-gather(%param_0.1), channel_id=5, replica_groups=[1,4]<=[4], dimensions={0}, metadata={op_name="jit(train_step)/fwd_bwd/transpose(jvp(GPT2))/fwd_bwd/jvp(GPT2)/checkpoint/h_1/mlp/c_fc/dot_general"}
+  ROOT %custom-call.3 = (bf16[512,64]{1,0:T(8,128)(2,1)S(1)}, bf16[2048,64]{1,0:T(8,128)(2,1)S(1)}, u32[]{:S(2)}) custom-call(%all-gather.7), custom_call_target="AsyncCollectiveStart"
+}
+
+%async_collective_fusion.6 (param_0.2: bf16[64,64], param_1.2: bf16[512,64]) -> (bf16[64,64], bf16[512,64], bf16[2048,64], u32[]) {
+  %param_0.2 = bf16[64,64]{1,0} parameter(0)
+  %param_1.2 = bf16[512,64]{1,0} parameter(1)
+  %convolution.4 = bf16[64,64]{1,0} convolution(%param_0.2, %param_0.2), dim_labels=bf_io->bf, metadata={op_name="jit(train_step)/fwd_bwd/transpose(jvp(GPT2))/fwd_bwd/jvp(GPT2)/checkpoint/h_1/attn/c_proj/dot_general"}
+  %all-gather.8 = bf16[2048,64]{1,0} all-gather(%param_1.2), channel_id=5, replica_groups=[1,4]<=[4], dimensions={0}
+  ROOT %tuple.6 = (bf16[64,64]{1,0}, bf16[512,64]{1,0}, bf16[2048,64]{1,0}, u32[]{:S(2)}) tuple(%convolution.4, %param_1.2, %all-gather.8)
+}
+
+%fused_computation.7 (param_0.3: bf16[512,64], param_1.3: bf16[2048,64]) -> bf16[2048,64] {
+  %param_0.3 = bf16[512,64]{1,0} parameter(0)
+  %param_1.3 = bf16[2048,64]{1,0} parameter(1)
+  %all-gather.9 = bf16[2048,64]{1,0} all-gather(%param_0.3), channel_id=5, replica_groups=[1,4]<=[4], dimensions={0}
+  ROOT %custom-call.4 = bf16[2048,64]{1,0:T(8,128)(2,1)S(1)} custom-call(%param_0.3, %param_1.3, %all-gather.9), custom_call_target="AsyncCollectiveDone"
+}
+
+%all-reduce-scatter.1 (input.1: bf16[16,64]) -> bf16[4,64] {
+  %input.1 = bf16[16,64]{1,0} parameter(0)
+  %all-reduce.6 = bf16[16,64]{1,0} all-reduce(%input.1), channel_id=9, replica_groups={{0,1,2,3}}, to_apply=%add.clone
+  %partition-id.1 = u32[] partition-id()
+  ROOT %dynamic-slice.2 = bf16[4,64]{1,0} dynamic-slice(%all-reduce.6, %partition-id.1), dynamic_slice_sizes={4,64}
+}
+
+%body.3 (p: (s32[], f32[4,64])) -> (s32[], f32[4,64]) {
+  %p = (s32[], f32[4,64]{1,0}) parameter(0)
+  %get-tuple-element.5 = f32[4,64]{1,0} get-tuple-element(%p), index=1
+  %all-reduce.3 = f32[4,64]{1,0} all-reduce(%get-tuple-element.5), channel_id=4, replica_groups={{0,1,2,3}}, to_apply=%add.clone, metadata={op_name="jit(train_step)/fwd_bwd/while/body/closed_call/transpose(jvp(GPT2))/jvp(GPT2)/checkpoint/rematted_computation/h_0/ln_1/mul"}
+  ROOT %tuple.3 = (s32[], f32[4,64]{1,0}) tuple(%get-tuple-element.5, %all-reduce.3)
+}
+
+%body.4 (q: (s32[], f32[4,64])) -> (s32[], f32[4,64]) {
+  %q = (s32[], f32[4,64]{1,0}) parameter(0)
+  %get-tuple-element.6 = f32[4,64]{1,0} get-tuple-element(%q), index=1
+  %collective-permute.2 = f32[4,64]{1,0} collective-permute(%get-tuple-element.6), channel_id=6, source_target_pairs={{0,1},{1,2}}
+  ROOT %tuple.4 = (s32[], f32[4,64]{1,0}) tuple(%get-tuple-element.6, %collective-permute.2)
+}
+
+ENTRY %main.9 (master: f32[2048,64], grads: f32[1024,64], w: bf16[512,64]) -> f32[2048,64] {
+  %master = f32[2048,64]{1,0:T(8,128)S(5)} parameter(0), metadata={op_name="state.master"}
+  %grads = f32[1024,64]{1,0} parameter(1)
+  %w = bf16[512,64]{1,0} parameter(2)
+  %copy-start.1 = (f32[2048,64]{1,0:T(8,128)}, f32[2048,64]{1,0:T(8,128)S(5)}, u32[]{:S(2)}) copy-start(%master)
+  %copy-done.1 = f32[2048,64]{1,0:T(8,128)} copy-done(%copy-start.1)
+  %copy-start.2 = (f32[2048,64]{1,0:T(8,128)S(1)}, f32[2048,64]{1,0:T(8,128)}, u32[]{:S(2)}) copy-start(%copy-done.1)
+  %copy-done.2 = f32[2048,64]{1,0:T(8,128)S(1)} copy-done(%copy-start.2)
+  %all-gather.1 = bf16[2048,64]{1,0} all-gather(%w), channel_id=1, replica_groups=[1,4]<=[4], dimensions={0}, use_global_device_ids=true, metadata={op_name="jit(train_step)/fwd_bwd/jvp(GPT2)/h_0/mlp/c_fc/dot_general"}
+  %async-start.1 = ((f32[1024,64]{1,0}), f32[256,64]{1,0}, u32[]) async-start(%grads), calls=%wrapped_rs
+  %async-done.1 = f32[256,64]{1,0} async-done(%async-start.1), metadata={op_name="jit(train_step)/fwd_bwd/transpose(jvp(GPT2))/h_0/mlp/c_fc/dot_general"}
+  %all-to-all.1 = bf16[4,64,4,8]{3,1,0,2} all-to-all(%all-gather.1), channel_id=2, replica_groups=[1,4]<=[4], dimensions={2}, metadata={op_name="jit(train_step)/fwd_bwd/jvp(GPT2)/h_0/add"}
+  %async-collective-start.1 = (bf16[512,64]{1,0:S(1)}, bf16[2048,64]{1,0:S(1)}, u32[]{:S(2)}) fusion(%w), kind=kCustom, calls=%fused_computation.5
+  %get-tuple-element.7 = bf16[512,64]{1,0} get-tuple-element(%async-collective-start.1), index=0
+  %fusion.6 = (bf16[64,64]{1,0}, bf16[512,64]{1,0}, bf16[2048,64]{1,0}, u32[]{:S(2)}) fusion(%w, %get-tuple-element.7), kind=kOutput, calls=%async_collective_fusion.6, metadata={op_name="jit(train_step)/fwd_bwd/transpose(jvp(GPT2))/fwd_bwd/jvp(GPT2)/checkpoint/h_1/attn/c_proj/dot_general"}
+  %get-tuple-element.8 = bf16[512,64]{1,0} get-tuple-element(%fusion.6), index=1
+  %get-tuple-element.9 = bf16[2048,64]{1,0} get-tuple-element(%fusion.6), index=2
+  %async-collective-done.1 = bf16[2048,64]{1,0:S(1)} fusion(%get-tuple-element.8, %get-tuple-element.9), kind=kCustom, calls=%fused_computation.7, metadata={op_name="jit(train_step)/fwd_bwd/transpose(jvp(GPT2))/fwd_bwd/jvp(GPT2)/checkpoint/h_1/mlp/c_fc/dot_general"}
+  %fusion.5 = bf16[4,64]{1,0} fusion(%all-to-all.1), kind=kCustom, calls=%all-reduce-scatter.1, metadata={op_name="jit(train_step)/fwd_bwd/jvp(GPT2)/h_0/mlp/c_fc/dot_general"}
+  %while.3 = (s32[], f32[4,64]{1,0}) while(%tuple.0), condition=%cond.3, body=%body.3, backend_config={"known_trip_count":{"n":"8"}}
+  %while.4 = (s32[], f32[4,64]{1,0}) while(%tuple.0), condition=%cond.4, body=%body.4
+  %fusion.8 = f32[2048,64]{1,0:T(8,128)} fusion(%copy-done.2), kind=kLoop, calls=%add.clone, metadata={op_name="jit(train_step)/optimizer/add"}
+  %copy-start.3 = (f32[2048,64]{1,0:T(8,128)S(5)}, f32[2048,64]{1,0:T(8,128)}, u32[]{:S(2)}) copy-start(%fusion.8)
+  ROOT %copy-done.3 = f32[2048,64]{1,0:T(8,128)S(5)} copy-done(%copy-start.3)
+}
+"""
+
+_F32_2048_64 = 2048 * 64 * 4
+_BF16_2048_64 = 2048 * 64 * 2
+
+MOVE_ROWS = {
+    # a pinned-host parameter into HBM: no metadata, so pass and scope
+    # are its consumer's consumer's (forwarded as in the scope table)
+    "copy-start.1": dict(kind="host_to_device", role="start",
+                         bytes=_F32_2048_64, wire_bytes=_F32_2048_64,
+                         pair="copy-done.1", calls=1, per_iteration=False),
+    "copy-done.1": dict(kind="host_to_device", role="done",
+                        bytes=_F32_2048_64, pair="copy-start.1"),
+    # HBM to on-chip memory: inside the device, no row
+    "copy-start.2": None, "copy-done.2": None,
+    "copy-start.3": dict(kind="device_to_host", role="start",
+                         bytes=_F32_2048_64, pair="copy-done.3",
+                         scopes="optimizer", **{"pass": "optimizer"}),
+    "copy-done.3": dict(kind="device_to_host", role="done",
+                        pair="copy-start.3", scopes="optimizer",
+                        **{"pass": "optimizer"}),
+    "all-gather.1": dict(kind="all-gather", role="sync", group=4,
+                         bytes=_BF16_2048_64,
+                         wire_bytes=_BF16_2048_64 * 3 / 4, pair=None,
+                         scopes="fwd_bwd/mlp", **{"pass": "fwd"}),
+    # a generic async pair is named by what it wraps; a reduce-scatter
+    # moves its OPERAND; the start takes its done's pass and scope
+    "async-start.1": dict(kind="reduce-scatter", role="start", group=4,
+                          bytes=1024 * 64 * 4,
+                          wire_bytes=1024 * 64 * 4 * 3 / 4,
+                          pair="async-done.1", scopes="fwd_bwd/mlp",
+                          **{"pass": "bwd"}),
+    "async-done.1": dict(kind="reduce-scatter", role="done",
+                         bytes=1024 * 64 * 4, pair="async-start.1",
+                         **{"pass": "bwd"}),
+    "all-to-all.1": dict(kind="all-to-all", role="sync", group=4,
+                         bytes=4 * 64 * 4 * 8 * 2, scopes="fwd_bwd",
+                         **{"pass": "fwd"}),
+    # the TPU's async pair: fusions around AsyncCollectiveStart / Done,
+    # joined through the matmul fusion that carries the gather along
+    "async-collective-start.1": dict(
+        kind="all-gather", role="start", bytes=_BF16_2048_64,
+        pair="async-collective-done.1", scopes="fwd_bwd/mlp",
+        **{"pass": "bwd"}),
+    "async-collective-done.1": dict(
+        kind="all-gather", role="done", bytes=_BF16_2048_64,
+        pair="async-collective-start.1", **{"pass": "bwd"}),
+    "fusion.6": dict(kind="all-gather", role="carrier", pair=None,
+                     scopes="fwd_bwd", **{"pass": "bwd"}),
+    # all-reduce + slice in a fusion's clothes: the TPU's reduce-scatter
+    "fusion.5": dict(kind="reduce-scatter", role="fused", group=4,
+                     bytes=16 * 64 * 2, wire_bytes=16 * 64 * 2 * 3 / 4,
+                     **{"pass": "fwd"}),
+    # in a while body whose trip count the text states: x 8
+    "all-reduce.3": dict(kind="all-reduce", role="sync", calls=8,
+                         per_iteration=False, bytes=8 * 4 * 64 * 4,
+                         wire_bytes=8 * 2 * 4 * 64 * 4 * 3 / 4,
+                         **{"pass": "recompute"}),
+    # in one whose trip count it does not state: one iteration's
+    "collective-permute.2": dict(kind="collective-permute", role="sync",
+                                 calls=1, per_iteration=True,
+                                 bytes=4 * 64 * 4, wire_bytes=4 * 64 * 4,
+                                 group=None, **{"pass": None}),
+    # instructions INSIDE a fusion or an async wrapper are no rows
+    "all-gather.7": None, "all-gather.8": None, "reduce-scatter.9": None,
+    "all-reduce.6": None,
+}
+
+
+@pytest.mark.parametrize("name", sorted(MOVE_ROWS))
+def test_movement_table_parser_on_a_fixed_text(name):
+    table = compile_watch.parse(MOVES).movement
+    want = MOVE_ROWS[name]
+    if want is None:
+        assert name not in table
+        return
+    row = table[name]
+    assert {k: row[k] for k in want} == want
+    assert row["kind"] not in ("async-collective", "async", "fusion")
+
+
+def test_movement_table_totals_passes_and_disagreement(fresh):
+    parsed = compile_watch.parse(MOVES)
+    # the scope table of the same parse is what parse_scopes gives
+    assert (parsed.scopes, parsed.kernels) == \
+        compile_watch.parse_scopes(MOVES)
+    assert parsed.passes["all-gather.1"] == "fwd"
+    assert parsed.passes["fusion.8"] == "optimizer"
+    assert parsed.passes["copy-start.3"] == "optimizer"   # its operand's
+    assert parsed.passes["master"] is None
+    # XLA's own rematerialisation: the clone keeps the forward's path
+    remat = compile_watch.parse(MOVES.replace("%all-gather.1 =",
+                                              "%all-gather.1.remat2 ="))
+    assert remat.passes["all-gather.1.remat2"] == "recompute"
+    assert remat.movement["all-gather.1.remat2"]["pass"] == "recompute"
+    for path, want in [
+            ("jit(train_step)/fwd_bwd/jvp(GPT2)/h_0/mlp/c_fc/dot_general",
+             "fwd"),
+            ("jit(train_step)/fwd_bwd/while/body/closed_call/"
+             "transpose(jvp(GPT2))/jvp(GPT2)/checkpoint/h_1/attn/add", "bwd"),
+            ("jit(train_step)/fwd_bwd/transpose(jvp(GPT2))/fwd_bwd/"
+             "jvp(GPT2)/checkpoint/rematted_computation/shard_map/"
+             "flash_attention_fwd/pallas_call", "recompute"),
+            ("jit(train_step)/optimizer/mul", "optimizer"),
+            ("jit(train_step)/fwd_bwd/add", None),
+            ("jit(serve_decode)/mlp/mul", None)]:
+        assert compile_watch.pass_of(path) == want, path
+    # one watched program, two executables that number their
+    # instructions alike and disagree about one of them: no row
+    other = MOVES.replace(
+        "all-to-all(%all-gather.1), channel_id=2, replica_groups=[1,4]<=[4]",
+        "all-to-all(%all-gather.1), channel_id=2, replica_groups=[1,2]<=[2]")
+    compile_watch._harvest("moves_fixture", [])
+    with compile_watch._tables_lock:
+        compile_watch._texts["moves_fixture"] = [MOVES, other]
+        compile_watch._tables.pop("moves_fixture", None)
+    try:
+        table = compile_watch.movement_table("moves_fixture")
+        assert table["all-to-all.1"] is None
+        assert table["all-gather.1"]["kind"] == "all-gather"
+        assert compile_watch.pass_table("moves_fixture")["fusion.8"] == \
+            "optimizer"
+        per_step = compile_watch.movement_per_step("moves_fixture")
+        # pairs and synchronous instructions once; a carrier never
+        assert per_step["host_to_device"] == {
+            "bytes": _F32_2048_64, "calls": 1}
+        assert per_step["device_to_host"] == {
+            "bytes": _F32_2048_64, "calls": 1}
+        assert per_step["all-gather"] == {
+            "bytes": 2 * _BF16_2048_64 * 3 / 4, "calls": 2}
+        assert per_step["all-reduce"]["calls"] == 8
+        assert "all-to-all" not in per_step
+    finally:
+        with compile_watch._tables_lock:
+            compile_watch._texts.pop("moves_fixture", None)
+            compile_watch._tables.pop("moves_fixture", None)

@@ -531,6 +531,57 @@ def test_train_model_kernels_and_scopes(chips, monkeypatch):
         "fwd_bwd", "embed", "attn_kernel", "mlp", "lm_head"}
 
 
+def test_streamed_optimizer_state_moves_once_each_way(chips):
+    """The offload stream as ``engine._make_step_fn`` writes it: master
+    and moments enter in ``pinned_host``, each leaf goes to the device
+    (``device_put`` to the same sharding's ``device`` kind) for the
+    update and back after, under the ``optimizer`` scope. The movement
+    table of the program compiled for the chip reads every leaf once in
+    each direction, by its bytes, and nothing between chips."""
+    from deepspeed_tpu.telemetry import compile_watch
+    mesh = Mesh(np.asarray(chips[:1]), ("fsdp",))
+    host = NamedSharding(mesh, P(), memory_kind="pinned_host")
+    dev = host.with_memory_kind("device")
+    shapes = {"w": (512, 256), "b": (256,)}
+    tree = lambda sh: {k: jax.ShapeDtypeStruct(v, jnp.float32, sharding=sh)  # noqa: E731
+                       for k, v in shapes.items()}
+    state = {"master": tree(host), "mu": tree(host), "nu": tree(host)}
+    grads = tree(dev)
+
+    def train_step(state, grads):
+        with jax.named_scope("optimizer"):
+            on_dev = jax.tree.map(lambda x: jax.device_put(x, dev), state)
+            mu = jax.tree.map(lambda m, g: 0.9 * m + 0.1 * g,
+                              on_dev["mu"], grads)
+            nu = jax.tree.map(lambda v, g: 0.99 * v + 0.01 * g * g,
+                              on_dev["nu"], grads)
+            master = jax.tree.map(
+                lambda p, m, v: p - 1e-3 * m / (jnp.sqrt(v) + 1e-8),
+                on_dev["master"], mu, nu)
+            new = {"master": master, "mu": mu, "nu": nu}
+            params = jax.tree.map(lambda p: p.astype(BF16), master)
+            return jax.tree.map(lambda x: jax.device_put(x, host),
+                                new), params
+    text = jax.jit(train_step, donate_argnums=(0,),
+                   out_shardings=(jax.tree.map(lambda _: host, state),
+                                  {k: dev for k in shapes})
+                   ).lower(state, grads).compile().as_text()
+    table = compile_watch.parse(text).movement
+    state_bytes = 3 * sum(int(np.prod(v)) * 4 for v in shapes.values())
+    moved = {"host_to_device": 0, "device_to_host": 0}
+    for name, row in table.items():
+        assert row["kind"] in moved, (name, row)     # no collective row
+        assert row["pair"] in table and table[row["pair"]]["pair"] == name
+        assert row["pass"] == "optimizer" and row["scopes"] == "optimizer"
+        if row["role"] == "start":
+            moved[row["kind"]] += row["bytes"]
+    assert moved == {"host_to_device": state_bytes,
+                     "device_to_host": state_bytes}
+    per_step = compile_watch.movement_per_step(table)
+    assert per_step["host_to_device"] == {"bytes": state_bytes, "calls": 6}
+    assert per_step["device_to_host"] == {"bytes": state_bytes, "calls": 6}
+
+
 def test_kernel_names_are_the_same_under_a_mesh(chips):
     """``map_kernel``'s shard_map does not rename the call: the decode
     kernel reads ``paged_decode_attention`` on four devices as on one."""
