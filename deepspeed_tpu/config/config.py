@@ -67,9 +67,12 @@ class OffloadOptimizerConfig(DeepSpeedConfigModel):
     # TPU extension (not in the reference schema): how the host tier is
     # realized. "stream" keeps fp32 master+moments in the TPU host's
     # pinned memory and computes the update ON DEVICE inside the fused
-    # jitted step, with XLA streaming the host<->HBM DMAs per leaf (the
-    # PCIe-overlap role the reference's cpu_adam + copy streams play,
-    # stage_1_and_2.py:1069-1219, without leaving XLA). "host" runs the
+    # jitted step, the host<->HBM DMAs written as a pipeline over the
+    # leaves so that a leaf's store leaves while the next leaves' fetches
+    # arrive (runtime/zero/offload_stream.py: the PCIe-overlap role the
+    # reference's cpu_adam + copy streams play, stage_1_and_2.py:1069-1219,
+    # without leaving XLA; XLA's own scheduler ran the two directions in
+    # turn). "host" runs the
     # C++ SIMD Adam in process RAM (csrc/cpu_adam.cpp). "auto" picks
     # stream on TPU backends, host elsewhere.
     implementation: Literal["auto", "stream", "host"] = "auto"

@@ -42,6 +42,10 @@ from deepspeed_tpu.runtime.precision import (PRECISION_DTYPES, LossScaleState,
                                              make_loss_scale,
                                              update_loss_scale)
 from deepspeed_tpu.runtime.utils import clip_coef
+from deepspeed_tpu.runtime.zero.offload_stream import (COPY_BUDGET_OPTION,
+                                                       copy_budget,
+                                                       moment_fields,
+                                                       streamed_update)
 from deepspeed_tpu.runtime.zero.partition import ZeroShardingPolicy
 from deepspeed_tpu.telemetry.spans import annotation as span_annotation
 from deepspeed_tpu.telemetry.spans import get_span_log
@@ -248,11 +252,14 @@ class DeepSpeedEngine:
                                    oc.device != "none") else None
         # Streamed offload (config.py OffloadOptimizerConfig.implementation):
         # fp32 master+moments live in TPU-host pinned memory and the update
-        # runs on device inside the fused step, XLA overlapping the per-leaf
-        # host<->HBM DMAs — the role cpu_adam + PCIe copy streams play in
-        # the reference, kept inside one XLA program. The NVMe tier and
-        # non-TPU backends (XLA:CPU has no memory-space shardings) use the
-        # C++ host path.
+        # runs on device inside the fused step — the role cpu_adam + PCIe
+        # copy streams play in the reference, kept inside one XLA program.
+        # The per-leaf host<->HBM DMAs are NOT left to XLA's scheduler: on
+        # the chip it ran the two directions in turn (both in flight 7 % of
+        # the time); runtime/zero/offload_stream.py writes them as a
+        # pipeline over the leaves, a store leaving while the next fetches
+        # arrive. The NVMe tier and non-TPU backends (XLA:CPU has no
+        # memory-space shardings) use the C++ host path.
         self._offload_stream = False
         if self._offload_cfg is not None:
             impl = self._offload_cfg.implementation
@@ -552,10 +559,8 @@ class DeepSpeedEngine:
                 return NamedSharding(self.mesh, P())
             opt_sh = jax.tree.map(opt_leaf_sharding, opt_shape)
             # moments live under .mu/.nu (or .accum), follow master spec
-            for field in ("mu", "nu", "accum"):
-                if hasattr(opt_shape, field) and \
-                        getattr(opt_shape, field) is not None:
-                    opt_sh = opt_sh.replace(**{field: master_sh})
+            for field in moment_fields(opt_shape):
+                opt_sh = opt_sh.replace(**{field: master_sh})
             if self._offload_stream:
                 # the whole optimizer tree (moments + scalar counters)
                 # lives in TPU-host pinned memory between steps
@@ -785,16 +790,14 @@ class DeepSpeedEngine:
         stream = self._offload_stream
         numerics_spec = self._numerics_spec
         if stream:
-            # streamed offload: master/moments enter in pinned_host; move
-            # each leaf into device space for the update and back after.
-            # XLA's latency-hiding scheduler pipelines the per-leaf DMAs
-            # against the update arithmetic (the overlap the reference
-            # builds by hand with copy streams, stage_1_and_2.py:1069).
-            to_dev = lambda tree, sh: jax.tree.map(  # noqa: E731
-                lambda x, s: jax.device_put(x, s.with_memory_kind("device")),
-                tree, sh)
-            to_host = lambda tree, sh: jax.tree.map(  # noqa: E731
-                lambda x, s: jax.device_put(x, s), tree, sh)
+            # streamed offload: master/moments enter in pinned_host and
+            # go through the device leaf by leaf, in an order this
+            # program fixes itself (the overlap the reference builds by
+            # hand with copy streams, stage_1_and_2.py:1069; XLA's
+            # latency-hiding scheduler, left alone, ran fetches and
+            # stores in turn). The stream has its own update path,
+            # runtime/zero/offload_stream.py; every other configuration
+            # keeps do_update below.
             master_host_sh = self._state_shardings.master
             opt_host_sh = self._state_shardings.opt_state
 
@@ -819,10 +822,6 @@ class DeepSpeedEngine:
 
                 def do_update(operand):
                     grads_, master_, opt_state_ = operand
-                    if stream:
-                        if mixed:
-                            master_ = to_dev(master_, master_host_sh)
-                        opt_state_ = to_dev(opt_state_, opt_host_sh)
                     updates, new_opt = optimizer.update(
                         grads_, opt_state_, master_, lr)
                     new_master = jax.tree.map(jnp.add, master_, updates)
@@ -836,7 +835,20 @@ class DeepSpeedEngine:
                                         jnp.float32) if numerics_on else ())
                     return master_, opt_state_, upd_sq
 
-                if fp16:
+                if stream:
+                    # (fp16's skip cond cannot wrap memory-space
+                    # transfers: refused at construction). The bf16
+                    # cast happens inside, while each fresh master leaf
+                    # is still in device space.
+                    new_master, new_opt, new_params, upd_sq = \
+                        streamed_update(
+                            optimizer, grads, master, state.opt_state, lr,
+                            master_sh=master_host_sh, opt_sh=opt_host_sh,
+                            compute_dtype=(self.compute_dtype if mixed
+                                           else None),
+                            upd_sq_spec=(numerics_spec if numerics_on
+                                         else None))
+                elif fp16:
                     new_master, new_opt, upd_sq = jax.lax.cond(
                         finite, do_update, skip_update,
                         (grads, master, state.opt_state))
@@ -845,22 +857,15 @@ class DeepSpeedEngine:
                         (grads, master, state.opt_state))
 
                 if mixed:
-                    # cast to compute dtype while the fresh master is
-                    # still in device space (stream: BEFORE spilling it
-                    # back to host — a host-space input here would put
-                    # the cast off-device)
-                    new_params = cast_tree(new_master, self.compute_dtype)
-                    if stream:
-                        new_master = to_host(new_master, master_host_sh)
-                        new_opt = to_host(new_opt, opt_host_sh)
+                    if not stream:
+                        new_params = cast_tree(new_master,
+                                               self.compute_dtype)
                     new_state = state.replace(
                         step=state.step + 1, params=new_params,
                         master=new_master, opt_state=new_opt,
                         loss_scale=update_loss_scale(state.loss_scale,
                                                      finite))
                 else:
-                    if stream:
-                        new_opt = to_host(new_opt, opt_host_sh)
                     new_state = state.replace(
                         step=state.step + 1, params=new_master,
                         opt_state=new_opt,
@@ -1200,6 +1205,14 @@ class DeepSpeedEngine:
             in_sh = in_sh.replace(params=self._device_param_shardings)
             out_sh = out_sh.replace(params=self._device_param_shardings)
             self._eager_param_staging = True
+        options = {}
+        if self._offload_stream:
+            # the stream's pipeline needs its transfers in flight
+            # together; the compiler's own budget of outstanding host
+            # copies (5) would serialize them again
+            options["compiler_options"] = {
+                COPY_BUDGET_OPTION: copy_budget(
+                    self.state.opt_state, self.state.master is not None)}
         # numerics_on is static (one retrace per toggle); in_shardings
         # cover the three dynamic args only
         self._step_fn = watched_jit(
@@ -1208,7 +1221,7 @@ class DeepSpeedEngine:
             in_shardings=(in_sh, batch_sh, None),
             out_shardings=(out_sh, None),
             static_argnums=(3,),
-            donate_argnums=(0,))
+            donate_argnums=(0,), **options)
 
     # ------------------------------------------------------------------
     # ZeRO-Offload step: device grads → host SIMD Adam → device params

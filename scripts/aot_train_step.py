@@ -19,7 +19,11 @@ path, or a line added above a call site, changes those bytes and
 nothing the chip runs). ``sha256`` is over every byte. (2) To read what
 the TPU's compiler writes (the words on ``op_name`` paths, how it wraps
 collectives, which memory space a copy crosses) before fixing a rule in
-``compile_watch.parse``.
+``compile_watch.parse``. (3) To read the ORDER of the offload stream
+without a chip (``stream_order`` in the report: what is started and
+awaited before the accumulation loop, on how many lines both directions
+have a copy outstanding, where the last fetch and the last store end),
+so that parent and change compare in a minute.
 
 How: an engine builds its state by RUNNING jitted functions and
 ``device_put``, which a described device cannot do. While the engine is
@@ -36,6 +40,7 @@ import argparse
 import hashlib
 import json
 import os
+import re
 import sys
 import time
 
@@ -59,6 +64,93 @@ def instructions(text: str) -> str:
         else:
             out.append(line)
     return "\n".join(out)
+
+
+_ENTRY_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s")
+_WHILE = re.compile(r"\swhile\(")
+
+
+def entry_order(text: str, movement: dict):
+    """``(events, loop, length)`` of the ENTRY computation: ``events`` =
+    ``[(position, name, row)]`` for its host-link copies, in program
+    order (``position`` counts the ENTRY's instructions from 1), ``loop``
+    the position of its first ``while`` (the accumulation loop; None
+    without one), ``length`` its instruction count."""
+    events, loop, n, inside = [], None, 0, False
+    for line in text.splitlines():
+        if line.startswith("ENTRY"):
+            inside = True
+            continue
+        if not inside:
+            continue
+        if line.startswith("}"):
+            break
+        m = _ENTRY_INSTR.match(line)
+        if m is None:
+            continue
+        n += 1
+        if loop is None and _WHILE.search(line):
+            loop = n
+        row = movement.get(m.group(1))
+        if row is not None and row["kind"] in ("host_to_device",
+                                               "device_to_host"):
+            events.append((n, m.group(1), row))
+    return events, loop, n
+
+
+def stream_order(text: str, movement: dict, min_bytes: int = 1 << 20) -> dict:
+    """What the ORDER of the ENTRY's instructions says of the offload
+    stream (static: a copy may queue behind another on the chip, so
+    ``offload_duplex_pct`` decides; but an order that never has both
+    directions outstanding cannot overlap them). Copies of at least
+    ``min_bytes`` only. ``*_before_loop_gb``: started / awaited before
+    the accumulation loop; ``lines_outstanding``: positions at which a
+    copy is outstanding, ``lines_both`` at which both directions have
+    one; ``pipeline_lines`` / ``pipeline_lines_both``: the same between
+    the first store's start and the last fetch's done;
+    ``max_outstanding_gb`` a direction; the positions of the last
+    fetch's and the last store's ``copy-done`` and the program's
+    length."""
+    events, loop, length = entry_order(text, movement)
+    events = [e for e in events if e[2]["bytes"] >= min_bytes]
+    gb = lambda b: round(b / 1e9, 3)  # noqa: E731
+    before = {"start": 0.0, "done": 0.0}
+    change = {}                 # position -> [(kind, +-bytes)]
+    last_done = {"host_to_device": None, "device_to_host": None}
+    first_store = None
+    for pos, _, row in events:
+        if loop is not None and pos < loop:
+            before[row["role"]] += row["bytes"]
+        sign = 1 if row["role"] == "start" else -1
+        change.setdefault(pos, []).append((row["kind"], sign * row["bytes"]))
+        if row["role"] == "done":
+            last_done[row["kind"]] = pos
+        elif row["kind"] == "device_to_host" and first_store is None:
+            first_store = pos
+    out = {"host_to_device": 0.0, "device_to_host": 0.0}
+    most = dict(out)
+    lines = both = pipe = pipe_both = 0
+    last_fetch = last_done["host_to_device"]
+    for pos in range(1, length + 1):
+        # a start counts from its own line, a done until the line before
+        for kind, delta in change.get(pos, ()):
+            out[kind] += delta
+            most[kind] = max(most[kind], out[kind])
+        f, t = out["host_to_device"] > 0, out["device_to_host"] > 0
+        lines += f or t
+        both += f and t
+        if first_store is not None and last_fetch is not None \
+                and first_store <= pos < last_fetch:
+            pipe += 1
+            pipe_both += f and t
+    return {"loop_at": loop, "length": length,
+            "started_before_loop_gb": gb(before["start"]),
+            "awaited_before_loop_gb": gb(before["done"]),
+            "lines_outstanding": lines, "lines_both": both,
+            "pipeline_lines": pipe, "pipeline_lines_both": pipe_both,
+            "max_outstanding_gb": {k: gb(v) for k, v in most.items()},
+            "last_fetch_done_at": last_fetch,
+            "last_store_done_at": last_done["device_to_host"]}
 
 
 def main() -> int:
@@ -189,6 +281,7 @@ def main() -> int:
         report["movement_rows"] = len(tables.movement)
         report["moved_per_step"] = compile_watch.movement_per_step(
             tables.movement)
+        report["stream_order"] = stream_order(text, tables.movement)
     except AttributeError:      # a checkout before the movement table
         pass
     print(json.dumps(report))

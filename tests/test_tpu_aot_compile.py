@@ -582,6 +582,107 @@ def test_streamed_optimizer_state_moves_once_each_way(chips):
     assert per_step["device_to_host"] == {"bytes": state_bytes, "calls": 6}
 
 
+def _aot_script():
+    """``scripts/aot_train_step.py`` as a module (its ``entry_order`` /
+    ``stream_order`` read a compiled text's ENTRY order)."""
+    import importlib.util
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "scripts", "aot_train_step.py")
+    spec = importlib.util.spec_from_file_location("aot_train_step", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("fsdp", [1, 4], ids=["unsharded", "fsdp4"])
+def test_stream_pipeline_keeps_both_directions_in_flight(chips, fsdp):
+    """The stream's update as the ENGINE calls it
+    (``offload_stream.streamed_update``, with the compiler option the
+    engine sets), compiled for the chip over ten leaf triples of
+    unequal size: (i) every leaf moves once each way by its bytes and
+    nothing else crosses the link; (ii) every stage's store but the
+    first's and the last's has a fetch in flight beside it; (iii) the
+    copies outstanding at any line fill, and never pass, the budget of
+    ``LAG + 1 + AHEAD`` stages (one stage more may show half begun).
+    With ``fsdp=4`` each chip moves its own quarter of every leaf."""
+    from deepspeed_tpu.ops.adam import adam
+    from deepspeed_tpu.runtime.zero import offload_stream as osm
+    from deepspeed_tpu.telemetry import compile_watch
+    mesh = Mesh(np.asarray(chips[:fsdp]), ("fsdp",))
+    spec = P("fsdp") if fsdp > 1 else P()
+    host = NamedSharding(mesh, spec, memory_kind="pinned_host")
+    dev = host.with_memory_kind("device")
+    rows = [8, 16, 24, 32, 48, 64, 96, 128, 192, 256]   # x 512 float32
+    shapes = {f"w{i}": (r * 8, 512) for i, r in enumerate(rows)}
+    tree = lambda sh: {k: jax.ShapeDtypeStruct(v, jnp.float32, sharding=sh)  # noqa: E731
+                       for k, v in shapes.items()}
+    opt = adam(weight_decay=0.01)
+    opt_abs = jax.eval_shape(opt.init, tree(dev))
+    count_sh = NamedSharding(mesh, P(), memory_kind="pinned_host")
+    opt_sh = opt_abs.replace(count=count_sh, mu={k: host for k in shapes},
+                             nu={k: host for k in shapes})
+    opt_state = jax.tree.map(
+        lambda x, sh: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sh),
+        opt_abs, opt_sh)
+    master_sh = {k: host for k in shapes}
+    budget = osm.copy_budget(opt_abs, True)     # as engine._compile_step
+    assert budget == 3 * (osm.LAG + 1 + osm.AHEAD)
+
+    def train_step(master, opt_state, grads):
+        with jax.named_scope("optimizer"):
+            new_master, new_opt, params, _ = osm.streamed_update(
+                opt, grads, master, opt_state, jnp.float32(1e-3),
+                master_sh=master_sh, opt_sh=opt_sh, compute_dtype=BF16)
+        return new_master, new_opt, params
+    text = jax.jit(
+        train_step, donate_argnums=(0, 1),
+        out_shardings=(master_sh, opt_sh, {k: dev for k in shapes}),
+        compiler_options={osm.COPY_BUDGET_OPTION: budget},
+    ).lower(tree(host), opt_state, tree(dev)).compile().as_text()
+    table = compile_watch.parse(text).movement
+    # (i) what moves: each leaf's shard three times each way (+ the
+    # step counter's four bytes), all of it the optimizer's
+    leaf_bytes = {k: int(np.prod(v)) * 4 // fsdp for k, v in shapes.items()}
+    assert len(set(leaf_bytes.values())) == len(shapes)
+    moved = {"host_to_device": [], "device_to_host": []}
+    for name, row in table.items():
+        assert row["kind"] in moved, (name, row)     # no collective row
+        assert row["pair"] in table and table[row["pair"]]["pair"] == name
+        assert row["pass"] == "optimizer" and row["scopes"] == "optimizer"
+        assert not row["per_iteration"]              # in the ENTRY
+        if row["role"] == "start":
+            moved[row["kind"]].append(int(row["bytes"]))
+    want = sorted(3 * list(leaf_bytes.values()))
+    for kind, sizes in moved.items():
+        assert sorted(b for b in sizes if b > 4) == want, kind
+        assert len(sizes) - len(want) <= 1           # the counter
+    # (ii), (iii): the ENTRY's order. A stage is a leaf: its bytes name it
+    events, _, length = _aot_script().entry_order(text, table)
+    span = {}                   # (kind, bytes) -> [first start, last done]
+    copies = np.zeros(length + 2, int)      # outstanding at each position
+    for pos, _, row in events:
+        if row["bytes"] <= 4:
+            continue
+        copies[pos:] += 1 if row["role"] == "start" else -1
+        key = row["kind"], row["bytes"]
+        span[key] = (min(span.get(key, (pos,))[0], pos), pos)
+    fetches = [v for (kind, _), v in span.items()
+               if kind == "host_to_device"]
+    stores = sorted(v for (kind, _), v in span.items()
+                    if kind == "device_to_host")
+    assert len(fetches) == len(stores) == len(shapes)
+    for lo, hi in stores[1:-1]:
+        assert any(f_lo < hi and lo < f_hi for f_lo, f_hi in fetches), \
+            (lo, hi, sorted(fetches))
+    # the compiler's budget is in copies, three a stage, and the pipeline
+    # fills it; a stage half begun or half ended at a line still counts,
+    # so one more stage than the budget's worth may show
+    assert 3 * osm.LAG < copies.max() <= budget
+    most = max(len({b for (_, b), (lo, hi) in span.items() if lo <= pos < hi})
+               for pos in range(1, length + 1))
+    assert most <= osm.LAG + 1 + osm.AHEAD + 1, most
+
+
 def test_kernel_names_are_the_same_under_a_mesh(chips):
     """``map_kernel``'s shard_map does not rename the call: the decode
     kernel reads ``paged_decode_attention`` on four devices as on one."""
