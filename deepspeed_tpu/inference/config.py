@@ -218,6 +218,13 @@ class DeepSpeedInferenceConfig(DeepSpeedConfigModel):
     # decode step is traced once per (num_slots, block_size) — raising
     # this trades per-request latency for throughput.
     num_slots: int = 8
+    # blocks of the paged pool (beside the null block). None: num_slots x
+    # (a slot's span / block_size), so every slot can hold a whole span
+    # at once. Smaller: the pool is sized for the traffic's MEAN span,
+    # a request still reserves prompt + budget at admission
+    # (Request.blocks_needed), and a free slot waits when the free list
+    # cannot cover the queue's head.
+    kv_pool_blocks: Optional[int] = None
     # admission control: submit() refuses beyond this many queued-but-
     # unscheduled requests instead of growing host memory unboundedly
     max_queued_requests: int = 128
@@ -341,6 +348,15 @@ class DeepSpeedInferenceConfig(DeepSpeedConfigModel):
             raise ValueError(
                 f"{info.field_name} must be >= 0 (max_preemptions=0 "
                 f"disables preemption entirely), got {v}")
+        return v
+
+    @field_validator("kv_pool_blocks")
+    @classmethod
+    def _pool_blocks(cls, v):
+        if v is not None and v < 1:
+            raise ValueError(
+                f"kv_pool_blocks must be a positive integer or None (the "
+                f"default: num_slots x a slot's span), got {v}")
         return v
 
     @field_validator("max_batch_size", "num_slots", "max_queued_requests")

@@ -205,7 +205,22 @@ class PagedKVCache:
     Writers quantize on write; readers apply the scales in-kernel (VMEM)
     or dequantize at the gather. Scales are DATA in the same donated pytree — tier
     membership and quantization never change a traced signature.
-    ``None`` scales = full-precision pool (the default)."""
+    ``None`` scales = full-precision pool (the default).
+
+    Window layers (``layer_map``; None = every layer keeps its whole
+    context, the default): a layer whose attention sees only the last
+    ``window`` positions keeps a bounded RING a slot instead of blocks of
+    the pool: ``ring_k`` / ``ring_v`` ``[Lw, S*RB, BS, KH*D]``, the same
+    row and block as the pool, slot ``s``'s ring the ``RB`` consecutive
+    blocks from ``s*RB``, position ``p`` at row ``p mod (RB*BS)`` of it.
+    A ring never grows, takes no block of the pool and no entry of the
+    block tables (admission counts the other layers' blocks only), and
+    is overwritten by the slot's next prefill. ``k`` / ``v`` then hold
+    the OTHER layers only: model layer ``l`` is ``layer_map[l] = (kind,
+    i)``, ``i`` the layer's index in the pool (``"full"``) or in the
+    rings (``"window"``).
+    aux: an int32 array that belongs to the MODEL, as
+    :class:`LatentPagedCache`'s (None: the model counts nothing)."""
     k: jnp.ndarray             # [L, NB, BS, KH*D] (fp or int8)
     v: jnp.ndarray             # [L, NB, BS, KH*D]
     block_tables: jnp.ndarray  # [S, MB] int32
@@ -213,6 +228,11 @@ class PagedKVCache:
     num_kv_heads: int = struct.field(pytree_node=False)
     k_scale: Optional[jnp.ndarray] = None   # [L, NB, KH, BS] f32 | None
     v_scale: Optional[jnp.ndarray] = None
+    ring_k: Optional[jnp.ndarray] = None    # [Lw, S*RB, BS, KH*D] | None
+    ring_v: Optional[jnp.ndarray] = None
+    aux: Optional[jnp.ndarray] = None       # the model's; int32
+    layer_map: Optional[tuple] = struct.field(pytree_node=False,
+                                              default=None)
 
     @property
     def quantized(self) -> bool:
@@ -246,17 +266,71 @@ class PagedKVCache:
     def num_layers(self) -> int:
         return self.k.shape[0]
 
+    @property
+    def ring_blocks(self) -> int:
+        """Blocks of one slot's ring of one window layer."""
+        return self.ring_k.shape[1] // self.num_slots
+
+    @property
+    def ring_rows(self) -> int:
+        return self.ring_blocks * self.block_size
+
+
+def ring_blocks_for(window: int, block_size: int) -> int:
+    """Blocks of a ring that holds a window of ``window`` positions: the
+    window rounded up to whole blocks plus one block of slack. A
+    one-token append at position ``n`` overwrites position ``n - R``,
+    which no window ``<= R`` sees; the slack is for positions written
+    ahead of the committed length (up to a block of them)."""
+    return -(-window // block_size) + 1
+
+
+def window_layer_map(window_layers) -> tuple:
+    """Model layer -> ``(kind, index among the layers of its kind)``,
+    ``kind`` ``"window"`` (a ring) or ``"full"`` (the pool), from a bool
+    a layer."""
+    counts = {"full": 0, "window": 0}
+    out = []
+    for is_window in window_layers:
+        kind = "window" if is_window else "full"
+        out.append((kind, counts[kind]))
+        counts[kind] += 1
+    return tuple(out)
+
 
 def init_paged_cache(num_layers: int, num_slots: int, num_blocks: int,
                      block_size: int, max_blocks_per_slot: int,
                      num_kv_heads: int, head_dim: int,
                      dtype=jnp.bfloat16,
-                     quantized: bool = False) -> PagedKVCache:
+                     quantized: bool = False,
+                     window_layers: Optional[tuple] = None,
+                     window: int = 0,
+                     aux_shape: Optional[tuple] = None) -> PagedKVCache:
     """``num_blocks`` INCLUDES the reserved null block 0, so the usable
     pool is ``num_blocks - 1`` blocks. ``quantized=True`` builds the
     int8 pool (payload dtype int8 regardless of ``dtype``) with
     all-ones scale tiles — unwritten garbage dequantizes to exact
-    zeros, the same dead-memory story as the fp pool."""
+    zeros, the same dead-memory story as the fp pool.
+
+    ``window_layers`` (a bool a model layer) with ``window``: the layers
+    marked true keep a ring of :func:`ring_blocks_for` blocks a slot
+    beside the pool, which then holds the other layers only."""
+    layer_map = rings = None
+    if window_layers is not None and any(window_layers):
+        if quantized:
+            raise NotImplementedError("window layers' rings have no int8 "
+                                      "rows or scale tiles")
+        if len(window_layers) != num_layers:
+            raise ValueError(f"{len(window_layers)} layer kinds for "
+                             f"{num_layers} layers")
+        layer_map = window_layer_map(window_layers)
+        n_window = sum(window_layers)
+        # a model of window layers only still has a (one-layer) pool:
+        # the block tables and the null block stay what they are
+        num_layers = max(num_layers - n_window, 1)
+        rings = (n_window,
+                 num_slots * ring_blocks_for(window, block_size),
+                 block_size, num_kv_heads * head_dim)
     shape = (num_layers, num_blocks, block_size, num_kv_heads * head_dim)
     pool_dtype = jnp.int8 if quantized else dtype
 
@@ -274,7 +348,11 @@ def init_paged_cache(num_layers: int, num_slots: int, num_blocks: int,
         block_tables=jnp.zeros((num_slots, max_blocks_per_slot),
                                jnp.int32),
         lengths=jnp.zeros((num_slots,), jnp.int32),
-        num_kv_heads=num_kv_heads, k_scale=scales(), v_scale=scales())
+        num_kv_heads=num_kv_heads, k_scale=scales(), v_scale=scales(),
+        ring_k=None if rings is None else jnp.zeros(rings, dtype),
+        ring_v=None if rings is None else jnp.zeros(rings, dtype),
+        aux=None if aux_shape is None else jnp.zeros(aux_shape, jnp.int32),
+        layer_map=layer_map)
 
 
 def _quant_rows(cache: PagedKVCache, x: jnp.ndarray):
@@ -372,6 +450,57 @@ def _scatter_positions(cache: PagedKVCache, layer: int, blk: jnp.ndarray,
             k_scale=cache.k_scale.at[layer, blk, :, off].set(sk),
             v_scale=cache.v_scale.at[layer, blk, :, off].set(sv))
     return out
+
+
+def ring_newest_position(newest, rows: int):
+    """``[..., R]``: the position each row of a ring of ``R`` rows holds
+    once position ``newest [...]`` has been written: the largest ``p <=
+    newest`` with ``p = r (mod R)``. Negative: the row was never written
+    (a context shorter than the ring)."""
+    r = jnp.arange(rows, dtype=jnp.int32)
+    return newest[..., None] - jnp.mod(newest[..., None] - r, rows)
+
+
+@scoped("kv_write")
+def ring_write_prompt(cache: PagedKVCache, ring_layer: int, k: jnp.ndarray,
+                      v: jnp.ndarray, slot: jnp.ndarray,
+                      length: jnp.ndarray) -> PagedKVCache:
+    """Prefill of a window layer: of one prompt's ``[T, H, D]`` k/v
+    (``length`` live tokens) the LAST ``ring_rows`` positions go into
+    ``slot``'s ring, position ``p`` at row ``p mod ring_rows``; the rest
+    of the prompt is never stored. A row whose position would be
+    negative keeps the prompt's first row and is masked by position when
+    read."""
+    RB, R = cache.ring_blocks, cache.ring_rows
+    src = jnp.clip(ring_newest_position(length.astype(jnp.int32) - 1, R),
+                   0, k.shape[0] - 1)
+
+    def put(ring, x):
+        rows = x.reshape(x.shape[0], -1)[src].astype(ring.dtype)
+        return jax.lax.dynamic_update_slice(
+            ring, rows.reshape(1, RB, cache.block_size, -1),
+            (ring_layer, slot * RB, 0, 0))
+    return cache.replace(ring_k=put(cache.ring_k, k),
+                         ring_v=put(cache.ring_v, v))
+
+
+@scoped("kv_write")
+def ring_append_token(cache: PagedKVCache, ring_layer: int, k: jnp.ndarray,
+                      v: jnp.ndarray) -> PagedKVCache:
+    """Decode of a window layer: one token's ``[S, H, D]`` k/v at row
+    ``lengths[s] mod ring_rows`` of every slot's ring. An idle slot
+    (length 0) writes row 0 of its own ring, which its next prefill
+    overwrites."""
+    BS, RB = cache.block_size, cache.ring_blocks
+    row = jnp.mod(cache.lengths, cache.ring_rows)
+    blk = jnp.arange(cache.num_slots, dtype=jnp.int32) * RB + row // BS
+    off = row % BS
+    S = k.shape[0]
+    return cache.replace(
+        ring_k=cache.ring_k.at[ring_layer, blk, off].set(
+            k.reshape(S, -1).astype(cache.ring_k.dtype)),
+        ring_v=cache.ring_v.at[ring_layer, blk, off].set(
+            v.reshape(S, -1).astype(cache.ring_v.dtype)))
 
 
 @scoped("kv_write")
@@ -654,9 +783,10 @@ def pool_arrays(cache) -> tuple:
         return tuple(cache.S) + tuple(cache.z)
     if isinstance(cache, LatentPagedCache):
         return tuple(cache.rows)
+    rings = () if cache.ring_k is None else (cache.ring_k, cache.ring_v)
     if cache.k_scale is None:
-        return (cache.k, cache.v)
-    return (cache.k, cache.v, cache.k_scale, cache.v_scale)
+        return (cache.k, cache.v) + rings
+    return (cache.k, cache.v, cache.k_scale, cache.v_scale) + rings
 
 
 # ------------------------------------------------------------- host tier

@@ -356,6 +356,18 @@ class Scheduler:
         return preemption and any(s.request.priority < head
                                   for s in self.slots.values())
 
+    def waits_on_blocks(self, step_clock: int,
+                        now: Optional[float] = None) -> bool:
+        """Whether a slot is free and the eligible head is kept out by
+        the free list alone (a pool smaller than ``slots x span``): a
+        peek, nothing allocated. With prefix caching a hit could still
+        let the head in, so the answer there is a bound, not a fact."""
+        if not self._free_slots or not self.pool_has_blocks:
+            return False
+        idx = self._next_eligible(step_clock, now)
+        return idx is not None and self.queue[idx].blocks_needed(
+            self.block_size, self.spec_margin) > self.allocator.free_blocks
+
     def admit_next(self, step_clock: int = 0,
                    now: Optional[float] = None):
         """Pop the first eligible request into a free slot when its

@@ -200,6 +200,27 @@ class _PoolKind:
 
 _POOL_KINDS = {
     "kv": _PoolKind(make_pool="_make_kv_pool"),
+    # the K/V pool with a layer-kind map (kv_cache.PagedKVCache
+    # ``layer_map``): window layers keep a bounded ring a slot beside the
+    # block pool of the full layers. Same rows, same pool class, same
+    # block tables; what reads or writes EVERY layer through the tables
+    # cannot see a ring
+    "kv_window": _PoolKind(
+        make_pool="_make_kv_pool",
+        what="a model with window layers (a ring a slot beside the block "
+             "pool)",
+        serves="monolithic bucketed prefill and plain paged decode "
+               "serve it",
+        refuses=_rows_switches(
+            "a ring has no int8 rows or scale tiles",
+            "a block's payload is every layer's slab, and a ring layer "
+            "has none in a block",
+            "a cached block holds no window layer's rows, and the ring "
+            "of the prompt that wrote it is gone",
+            "no program carries a ring from one prompt chunk to the next",
+            "it chains chunked prefill",
+            "a rejected draft token's row cannot be taken back out of a "
+            "ring")),
     "latent": _PoolKind(
         make_pool="_make_latent_pool",
         what="a latent-attention model (latent paged cache)",
@@ -492,6 +513,17 @@ class ContinuousBatchingServer:
                                            help="decode steps executed")
         self._c_tokens = reg.counter("serve_tokens_total",
                                      help="generated tokens committed")
+        self._c_block_steps = reg.counter(
+            "serve_kv_used_block_steps_total",
+            help="pool blocks held by resident sequences, summed over "
+                 "committed decode steps and verify rounds (over "
+                 "serve_decode_steps_total x the pool's blocks: the "
+                 "mean share of the pool in use)")
+        self._c_blocked_steps = reg.counter(
+            "serve_kv_admission_blocked_steps_total",
+            help="committed decode steps that ended with a free slot "
+                 "and an eligible queued request whose blocks the free "
+                 "list could not cover")
         self._g_occupancy = reg.gauge(
             "serve_slot_occupancy",
             help="live/num_slots at the last decode step")
@@ -601,8 +633,12 @@ class ContinuousBatchingServer:
         # O(deadlined requests), zero when the feature is unused
         self._deadlines: Dict[int, float] = {}
         self.finish_reasons: Dict[int, str] = {}
-        # +1: block 0 is the reserved null block idle slots write into
-        num_blocks = 1 + self.num_slots * self.max_blocks_per_slot
+        # +1: block 0 is the reserved null block idle slots write into.
+        # ``kv_pool_blocks`` unset: every slot can hold a whole span at
+        # once; set, the pool is that many blocks and admission waits on
+        # the free list (Request.blocks_needed) with a slot free
+        num_blocks = 1 + (cfg.kv_pool_blocks
+                          or self.num_slots * self.max_blocks_per_slot)
         self.scheduler = Scheduler(
             num_slots=self.num_slots, num_blocks=num_blocks,
             block_size=self.block_size,
@@ -1125,7 +1161,16 @@ class ContinuousBatchingServer:
             mcfg.n_layer, self.num_slots, num_blocks, self.block_size,
             self.max_blocks_per_slot, mcfg.kv_heads, mcfg.head_dim,
             dtype=self.engine._act_dtype,
-            quantized=self.kv_dtype == "int8")
+            quantized=self.kv_dtype == "int8",
+            window_layers=getattr(mcfg, "window_layers", None),
+            window=getattr(mcfg, "sliding_window", 0) or 0,
+            aux_shape=getattr(mcfg, "aux_shape", None))
+        if cache.ring_k is not None:
+            self.telemetry.gauge(
+                "serve_kv_ring_bytes",
+                help="bytes of the window layers' rings (K and V, every "
+                     "slot): a fixed cost a slot, no block of the pool"
+            ).set(cache.ring_k.nbytes + cache.ring_v.nbytes)
         mesh = self.engine.mesh
         if mesh is not None:
             # kv heads shard over `tensor` exactly like the dense cache
@@ -2562,7 +2607,7 @@ class ContinuousBatchingServer:
         # every live slot committed one token this step, each costing
         # one step of wall time — THE per-token serving latency
         self._publish(self._publish_decode_step, lagged, dt, n_live,
-                      n_live / self.num_slots)
+                      n_live / self.num_slots, *self._pool_reading())
         if self.watchdog is not None:
             self.watchdog.notify_progress()
         if self._step_clock % self._EVENT_EVERY == 1:
@@ -2573,8 +2618,20 @@ class ContinuousBatchingServer:
                 sampled_every=self._EVENT_EVERY)
         return t1
 
-    def _publish_decode_step(self, dt: float, n_live: int,
-                             occ: float) -> None:
+    def _pool_reading(self) -> tuple:
+        """``(blocks held by residents, whether a free slot waits on
+        blocks)`` at a step's commit, read on the owner thread."""
+        sched = self.scheduler
+        return (sched.allocator.live_blocks,
+                sched.waits_on_blocks(self._tick))
+
+    def _publish_pool(self, used_blocks: int, blocked: bool) -> None:
+        self._c_block_steps.inc(used_blocks)
+        if blocked:
+            self._c_blocked_steps.inc()
+
+    def _publish_decode_step(self, dt: float, n_live: int, occ: float,
+                             used_blocks: int, blocked: bool) -> None:
         """Metric publish for one committed decode step (values computed
         on the owner thread — run on the worker, this never reads a
         clock or scheduler state)."""
@@ -2583,6 +2640,7 @@ class ContinuousBatchingServer:
         self._c_decode_steps.inc()
         self._c_tokens.inc(n_live)
         self._g_occupancy.set(occ)
+        self._publish_pool(used_blocks, blocked)
 
     def _verify_round(self, finished: List[int], sp, lag: int) -> None:
         """One speculative round for all active resident slots: each
@@ -2835,7 +2893,7 @@ class ContinuousBatchingServer:
         # slot" under speculation
         self._publish(self._publish_verify_step, lagged, dt, n_live,
                       committed_total, proposed, accepted_total,
-                      per_slot_commits)
+                      per_slot_commits, *self._pool_reading())
         if self.watchdog is not None:
             self.watchdog.notify_progress()
         if self._step_clock % self._EVENT_EVERY == 1:
@@ -2850,7 +2908,8 @@ class ContinuousBatchingServer:
     def _publish_verify_step(self, dt: float, n_live: int,
                              committed_total: int, proposed: int,
                              accepted: int,
-                             per_slot_commits: List[int]) -> None:
+                             per_slot_commits: List[int],
+                             used_blocks: int, blocked: bool) -> None:
         """Metric publish for one committed verify round (see
         :meth:`_publish_decode_step`)."""
         self._h_decode_step.observe(dt)
@@ -2862,6 +2921,7 @@ class ContinuousBatchingServer:
         self._c_spec_accepted.inc(accepted)
         for n in per_slot_commits:
             self._h_spec_commit.observe(n)
+        self._publish_pool(used_blocks, blocked)
 
     # one worker job per this many buffered step records (see _pub_buf)
     _PUBLISH_BATCH = 16

@@ -1,0 +1,185 @@
+"""An expert layer's HELD share: what one chip of an expert-parallel
+deployment computes of a sparse layer, for any family that routes over
+all of its router's outputs and holds a contiguous range of the real
+experts (``held = (lo, hi)``).
+
+The family routes (scores, selection and weights are its own: a softmax
+with zero-compute experts, a sigmoid with normalised weights and a
+shared expert); what is here is everything after the picks: the picks
+that landed on a held expert sorted by expert, a grouped matmul
+(``jax.lax.ragged_dot``; a Mosaic kernel on a TPU) over those rows only,
+the weighted sum back to tokens, and the routing counters. Picks on
+absent experts are left out: their holders add those parts. Nothing
+stands in for the other chips. The scopes (``moe_dispatch``,
+``moe_experts``, ``moe_combine``) are the names the trace readers know.
+
+Shared code: it imports no model.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.profiling.trace import scoped
+
+F32 = jnp.float32
+
+# the counters a family's ``cache.aux`` row ends with, after the picks on
+# each held expert
+COUNTER_TAIL = ("identity_picks", "absent_picks", "tokens_routed",
+                "layer_calls", "held_experts_hit")
+
+
+def counter_series(reg, num_held: int, programs) -> list:
+    """The registry counter behind each cell of an ``aux`` of routing
+    rows (docs/observability.md "Latent attention and the expert
+    layer"), ``[program][column]``: the picks on each held expert, then
+    :data:`COUNTER_TAIL`."""
+    def series(program: str) -> list:
+        by = {"program": program}
+        tail = {
+            "identity_picks": reg.counter(
+                "serve_moe_identity_picks_total", labels=by,
+                help="top-k picks on zero-compute (identity) "
+                     "experts: (sum of weights) x hidden, no matmul"),
+            "absent_picks": reg.counter(
+                "serve_moe_absent_picks_total", labels=by,
+                help="top-k picks on real experts this process does "
+                     "not hold (their holders add those parts)"),
+            "tokens_routed": reg.counter(
+                "serve_moe_tokens_routed_total", labels=by,
+                help="tokens the expert layers routed (one per "
+                     "token per MoE layer)"),
+            "layer_calls": reg.counter(
+                "serve_moe_layer_calls_total", labels=by,
+                help="expert-layer executions"),
+            "held_experts_hit": reg.counter(
+                "serve_moe_held_experts_hit_total", labels=by,
+                help="held experts with at least one pick, summed "
+                     "over expert-layer executions (the weights an "
+                     "execution has to read)"),
+        }
+        return [reg.counter(
+            "serve_moe_held_expert_picks_total",
+            help="top-k picks that landed on a real expert this "
+                 "process holds, by held expert",
+            labels={"program": program, "expert": str(x)})
+            for x in range(num_held)
+        ] + [tail[name] for name in COUNTER_TAIL]
+    return [series(program) for program in programs]
+
+
+@scoped("moe_dispatch")
+def sort_picks(picks, valid, held_range):
+    """The picks in the order a grouped matmul wants them: those that
+    landed on a held expert first, by expert. Returns ``order [T k]``
+    (pick numbers, sorted), ``where [T, k]`` (each pick's place in that
+    order), ``held [T, k]`` and ``group_sizes [X]``."""
+    lo, hi = held_range
+    T, k = picks.shape
+    held = (picks >= lo) & (picks < hi) & valid[:, None]
+    key = jnp.where(held, picks - lo, hi - lo).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    where = jnp.argsort(order).reshape(T, k)       # the order's inverse
+    group_sizes = jnp.sum(
+        key[:, None] == jnp.arange(hi - lo, dtype=key.dtype)[None],
+        axis=0, dtype=jnp.int32)
+    return order, where, held, group_sizes
+
+
+@scoped("moe_dispatch")
+def _gather_rows(u, order, k: int, rows: int):
+    """The tokens of the first ``rows`` sorted picks, ``[rows, E]``."""
+    return u[order[:rows] // k]
+
+
+@scoped("moe_experts")
+def _experts(xs, group_sizes, ex):
+    """SwiGLU of each row's expert: a grouped matmul (``ragged_dot``; a
+    Mosaic kernel on a TPU) that visits only the rows inside the groups.
+    Rows past the groups come back as whatever the kernel left there."""
+    dt = xs.dtype
+    gu = jax.lax.ragged_dot(xs, ex["w_in"].astype(dt), group_sizes)
+    Fe = gu.shape[-1] // 2
+    h = jax.nn.silu(gu[:, :Fe].astype(F32)) * gu[:, Fe:].astype(F32)
+    return jax.lax.ragged_dot(h.astype(dt), ex["w_out"].astype(dt),
+                              group_sizes)
+
+
+@scoped("moe_combine")
+def _combine_landed(out, where, held, weights):
+    """Each token's weighted sum over its landed picks: ``assign [T,
+    rows]`` holds a pick's weight at its row and the sum is one float32
+    product, so no per-pick copy of ``out`` is made."""
+    rows = out.shape[0]
+    at = where[..., None] == jnp.arange(rows, dtype=where.dtype)
+    assign = jnp.sum(jnp.where(at & held[..., None], weights[..., None],
+                               0.0), axis=1)                  # [T, rows]
+    landed = jnp.arange(rows) < jnp.sum(held)
+    return jnp.dot(assign, jnp.where(landed[:, None], out.astype(F32), 0.0),
+                   precision=jax.lax.Precision.HIGHEST)
+
+
+# above this many cells the ``[T, rows]`` assignment of
+# :func:`_combine_landed` (and its float32 product) costs more than
+# gathering each pick's row: a decode batch and a short prompt stay
+# under it, a prompt of thousands of tokens does not
+ASSIGN_CELLS = 1 << 22
+
+
+@scoped("moe_combine")
+def _combine_gathered(out, where, held, weights):
+    """:func:`_combine_landed` for many tokens: each pick's row gathered
+    and weighted, ``[T, k, E]`` summed over the picks (rows past the
+    groups are never selected)."""
+    rows = out.shape[0]
+    ok = held & (where < rows)
+    picked = out[jnp.minimum(where, rows - 1)].astype(F32)    # [T, k, E]
+    return jnp.sum(jnp.where(ok[..., None], picked * weights[..., None],
+                             0.0), axis=1)
+
+
+def fast_rows(T: int, k: int) -> int:
+    """Rows the expert matmul is given when the landed picks fit them
+    (nearly always: 1 pick in 48 lands at LongCat-Flash's published
+    sizes, 1 in 8 at an eighth of 256 experts, and this is T / 2 or
+    128). The grouped matmul tiles its rows by ``min(rows, 512)`` and
+    computes whole tiles, so a small buffer is what keeps its work near
+    the landed picks; ``T k`` rows stay the exact fallback."""
+    return min(T * k, max(128, T // 2))
+
+
+def held_experts_part(u, order, where, held, weights, group_sizes, ex,
+                      fast=None):
+    """The held real experts' part of the layer, ``[T, E]`` float32: over
+    the first ``fast`` sorted picks (:func:`fast_rows` unless the family
+    knows its share better) when all the landed ones are among them,
+    else over all ``T k``. Exact either way. ``ex``: ``w_in [X, E, 2
+    Fe]`` (gate ; up) and ``w_out [X, Fe, E]``."""
+    T, k = weights.shape
+
+    def over(rows):
+        combine = (_combine_landed if T * rows <= ASSIGN_CELLS
+                   else _combine_gathered)
+
+        def run():
+            out = _experts(_gather_rows(u, order, k, rows), group_sizes, ex)
+            return combine(out, where, held, weights)
+        return run
+    fast = min(fast or fast_rows(T, k), T * k)
+    if fast == T * k:
+        return over(fast)()
+    return jax.lax.cond(jnp.sum(group_sizes) <= fast, over(fast),
+                        over(T * k))
+
+
+def routing_counts(picks, held, group_sizes, valid, n_routed: int):
+    """One call's row of counters (int32): the picks on each held expert,
+    then :data:`COUNTER_TAIL`. Router outputs from ``n_routed`` on are
+    zero-compute (identity) experts."""
+    v = valid[:, None]
+    identity = jnp.sum((picks >= n_routed) & v, dtype=jnp.int32)
+    absent = jnp.sum((picks < n_routed) & v & ~held, dtype=jnp.int32)
+    return jnp.concatenate([group_sizes, jnp.stack([
+        identity, absent, jnp.sum(valid, dtype=jnp.int32), jnp.int32(1),
+        jnp.sum(group_sizes > 0, dtype=jnp.int32)])])

@@ -69,6 +69,7 @@ from deepspeed_tpu.inference.kv_cache import (LatentPagedCache,
                                               latent_append_token,
                                               latent_write_prompt,
                                               paged_advance)
+from deepspeed_tpu.model_implementations import held_experts as _held
 from deepspeed_tpu.ops.pallas import latent_decode_attention as _latent
 from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 from deepspeed_tpu.profiling.trace import scoped
@@ -79,8 +80,7 @@ NEG_INF = -1e30
 # what this model keeps in LatentPagedCache.aux: routing counters
 # ``[program, column]``, the picks on each held expert first, then these
 PROGRAMS = ("decode", "prefill")
-COUNTER_TAIL = ("identity_picks", "absent_picks", "tokens_routed",
-                "layer_calls", "held_experts_hit")
+COUNTER_TAIL = _held.COUNTER_TAIL
 
 
 def aux_series(cfg: "LongcatFlashConfig", reg) -> list:
@@ -88,38 +88,7 @@ def aux_series(cfg: "LongcatFlashConfig", reg) -> list:
     ``cache.aux`` (docs/observability.md "Latent attention and the expert
     layer"), ``[program][column]``; the server adds each cell's growth
     to its series."""
-    def series(program: str) -> list:
-        by = {"program": program}
-        tail = {
-            "identity_picks": reg.counter(
-                "serve_moe_identity_picks_total", labels=by,
-                help="top-k picks on zero-compute (identity) "
-                     "experts: (sum of weights) x hidden, no matmul"),
-            "absent_picks": reg.counter(
-                "serve_moe_absent_picks_total", labels=by,
-                help="top-k picks on real experts this process does "
-                     "not hold (their holders add those parts)"),
-            "tokens_routed": reg.counter(
-                "serve_moe_tokens_routed_total", labels=by,
-                help="tokens the expert layers routed (one per "
-                     "token per MoE layer)"),
-            "layer_calls": reg.counter(
-                "serve_moe_layer_calls_total", labels=by,
-                help="expert-layer executions"),
-            "held_experts_hit": reg.counter(
-                "serve_moe_held_experts_hit_total", labels=by,
-                help="held experts with at least one pick, summed "
-                     "over expert-layer executions (the weights an "
-                     "execution has to read)"),
-        }
-        return [reg.counter(
-            "serve_moe_held_expert_picks_total",
-            help="top-k picks that landed on a real expert this "
-                 "process holds, by held expert",
-            labels={"program": program, "expert": str(x)})
-            for x in range(cfg.num_held)
-        ] + [tail[name] for name in COUNTER_TAIL]
-    return [series(program) for program in PROGRAMS]
+    return _held.counter_series(reg, cfg.num_held, PROGRAMS)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -500,82 +469,11 @@ def _route(u, moe, cfg: LongcatFlashConfig):
     return picks, weights
 
 
-@scoped("moe_dispatch")
-def _sort_picks(picks, valid, cfg: LongcatFlashConfig):
-    """The picks in the order a grouped matmul wants them: those that
-    landed on a held expert first, by expert. Returns ``order [T k]``
-    (pick numbers, sorted), ``where [T, k]`` (each pick's place in that
-    order), ``held [T, k]`` and ``group_sizes [X]``."""
-    lo, hi = cfg.experts_held
-    T, k = picks.shape
-    held = (picks >= lo) & (picks < hi) & valid[:, None]
-    key = jnp.where(held, picks - lo, cfg.num_held).reshape(-1)
-    order = jnp.argsort(key, stable=True)
-    where = jnp.argsort(order).reshape(T, k)       # the order's inverse
-    group_sizes = jnp.sum(
-        key[:, None] == jnp.arange(cfg.num_held, dtype=key.dtype)[None],
-        axis=0, dtype=jnp.int32)
-    return order, where, held, group_sizes
-
-
-@scoped("moe_dispatch")
-def _gather_rows(u, order, k: int, rows: int):
-    """The tokens of the first ``rows`` sorted picks, ``[rows, E]``."""
-    return u[order[:rows] // k]
-
-
-@scoped("moe_experts")
-def _experts(xs, group_sizes, ex):
-    """SwiGLU of each row's expert: a grouped matmul (``ragged_dot``; a
-    Mosaic kernel on a TPU) that visits only the rows inside the groups.
-    Rows past the groups come back as whatever the kernel left there."""
-    dt = xs.dtype
-    gu = jax.lax.ragged_dot(xs, ex["w_in"].astype(dt), group_sizes)
-    Fe = gu.shape[-1] // 2
-    h = jax.nn.silu(gu[:, :Fe].astype(F32)) * gu[:, Fe:].astype(F32)
-    return jax.lax.ragged_dot(h.astype(dt), ex["w_out"].astype(dt),
-                              group_sizes)
-
-
-@scoped("moe_combine")
-def _combine_landed(out, where, held, weights):
-    """Each token's weighted sum over its landed picks: ``assign [T,
-    rows]`` holds a pick's weight at its row and the sum is one float32
-    product, so no per-pick copy of ``out`` is made."""
-    rows = out.shape[0]
-    at = where[..., None] == jnp.arange(rows, dtype=where.dtype)
-    assign = jnp.sum(jnp.where(at & held[..., None], weights[..., None],
-                               0.0), axis=1)                  # [T, rows]
-    landed = jnp.arange(rows) < jnp.sum(held)
-    return jnp.dot(assign, jnp.where(landed[:, None], out.astype(F32), 0.0),
-                   precision=jax.lax.Precision.HIGHEST)
-
-
-def _fast_rows(T: int, k: int) -> int:
-    """Rows the expert matmul is given when the landed picks fit them
-    (nearly always: 1 pick in 48 lands at the published sizes, and this
-    is T / 2 or 128). The grouped matmul tiles its rows by ``min(rows,
-    512)`` and computes whole tiles, so a small buffer is what keeps its
-    work near the landed picks; ``T k`` rows stay the exact fallback."""
-    return min(T * k, max(128, T // 2))
-
-
-def _held_experts_part(u, order, where, held, weights, group_sizes, ex):
-    """The held real experts' part of the layer, ``[T, E]`` float32: over
-    the first :func:`_fast_rows` sorted picks when all the landed ones
-    are among them, else over all ``T k``. Exact either way."""
-    T, k = weights.shape
-
-    def over(rows):
-        def run():
-            out = _experts(_gather_rows(u, order, k, rows), group_sizes, ex)
-            return _combine_landed(out, where, held, weights)
-        return run
-    fast = _fast_rows(T, k)
-    if fast == T * k:
-        return over(fast)()
-    return jax.lax.cond(jnp.sum(group_sizes) <= fast, over(fast),
-                        over(T * k))
+# everything after the picks (sorting the landed ones by expert, the
+# grouped matmul over them, the weighted sum, the counters) is the held
+# share's and is shared with every family that holds one:
+# ``held_experts.py``
+_fast_rows = _held.fast_rows
 
 
 @scoped("moe_combine")
@@ -585,28 +483,18 @@ def _identity_part(u, picks, weights, cfg: LongcatFlashConfig):
     return w[:, None] * u.astype(F32)
 
 
-def _routing_counts(picks, held, group_sizes, valid,
-                    cfg: LongcatFlashConfig):
-    """One call's row of :data:`COUNTER_TAIL` counters (int32)."""
-    v = valid[:, None]
-    identity = jnp.sum((picks >= cfg.n_routed_experts) & v, dtype=jnp.int32)
-    absent = jnp.sum((picks < cfg.n_routed_experts) & v & ~held,
-                     dtype=jnp.int32)
-    return jnp.concatenate([group_sizes, jnp.stack([
-        identity, absent, jnp.sum(valid, dtype=jnp.int32), jnp.int32(1),
-        jnp.sum(group_sizes > 0, dtype=jnp.int32)])])
-
-
 def moe_layer(u, moe, cfg: LongcatFlashConfig, valid):
     """This process's part of the expert layer on ``u [T, E]`` (``valid
     [T]``: rows that are tokens, not padding or idle slots) -> (``[T,
     E]``, counters row)."""
     picks, weights = _route(u, moe, cfg)
-    order, where, held, group_sizes = _sort_picks(picks, valid, cfg)
-    m = (_held_experts_part(u, order, where, held, weights, group_sizes,
-                            moe["experts"])
+    order, where, held, group_sizes = _held.sort_picks(picks, valid,
+                                                       cfg.experts_held)
+    m = (_held.held_experts_part(u, order, where, held, weights,
+                                 group_sizes, moe["experts"])
          + _identity_part(u, picks, weights, cfg)).astype(u.dtype)
-    return m, _routing_counts(picks, held, group_sizes, valid, cfg)
+    return m, _held.routing_counts(picks, held, group_sizes, valid,
+                                   cfg.n_routed_experts)
 
 
 # ------------------------------------------------------------------ block

@@ -432,8 +432,11 @@ def _head_axis(mesh, H: int, KH: int):
 def _use_decode_kernel(cfg: InferenceTransformerConfig, H: int, KH: int,
                        window) -> bool:
     """The Pallas decode family serves plain causal attention on TPU;
-    ALiBi, windowed layers, a seq-sharded KV cache and the CPU take the
-    XLA formulation."""
+    ALiBi, windowed layers (``local_windows``: they keep their whole
+    context in the block tables and not a ring, and the kernel's window
+    is a ring's; ``model_implementations/laguna.py`` is the family whose
+    window layers decode through the kernel), a seq-sharded KV cache and
+    the CPU take the XLA formulation."""
     return (cfg.positional != "alibi" and window is None
             and jax.default_backend() == "tpu" and H % KH == 0
             and not cfg.seq_shard_kv)
@@ -447,11 +450,17 @@ def _prefill_attention(q, k, v, cfg: InferenceTransformerConfig,
     → [B, T, H, D]. ``key_mask [B, T]`` masks padded keys (encoder path);
     ``window`` is a sliding-window size (GPT-Neo local layers).
 
-    Uses the Pallas flash kernel for the causal no-bias case; ALiBi,
-    windowed, bidirectional, and CPU paths use the XLA einsum oracle.
+    Uses the Pallas flash kernel for the causal no-bias case, its
+    windowed forward (``flash_attention_window_fwd``: K blocks outside a
+    query block's windows skipped) for a ``local_windows`` layer. What
+    still takes the ``[T, T]`` XLA einsum oracle: ALiBi, bidirectional
+    and key-masked (encoder) attention, a prompt the kernel's blocks do
+    not tile (T < 128 or T % 128), and every path off the TPU. The
+    windowed kernel is forward only: this module serves, it does not
+    train.
     """
     B, T, H, D = q.shape
-    use_flash = (causal and key_mask is None and window is None
+    use_flash = (causal and key_mask is None
                  and cfg.positional != "alibi"
                  and jax.default_backend() == "tpu" and T >= 128 and
                  T % 128 == 0 and H % k.shape[2] == 0)
@@ -461,7 +470,7 @@ def _prefill_attention(q, k, v, cfg: InferenceTransformerConfig,
         spec = P(None, None, _head_axis(mesh, H, k.shape[2]), None)
         return map_kernel(
             functools.partial(flash_attention, causal=True,
-                              scale=cfg.scale),
+                              scale=cfg.scale, window=window),
             mesh, (spec, spec, spec), spec)(q, k, v)
     k = _repeat_kv(k, H // k.shape[2])
     v = _repeat_kv(v, H // v.shape[2])
@@ -565,10 +574,17 @@ def _paged_decode_attention(q, cache: PagedKVCache, layer_idx: int,
     ``live [S]`` = valid positions including the just-appended token.
     TPU fast path: the Pallas paged kernel gathers K/V blocks through the
     scalar-prefetched block table (no per-slot contiguous cache is ever
-    materialized). Fallback (CPU / ALiBi / windowed): gather through the
-    block table with XLA, then reuse :func:`_decode_attention` — gathered
-    position j is logical position j, so the math (and every masked
-    softmax bit) is identical to the dense-cache path."""
+    materialized). Fallback: gather through the block table with XLA
+    (:func:`paged_gather_kv`, ``[num_slots, max_context, H, D]`` a layer
+    a step), then reuse :func:`_decode_attention` — gathered position j
+    is logical position j, so the math (and every masked softmax bit) is
+    identical to the dense-cache path. Exactly these cases fall back:
+    every path off the TPU; ALiBi; a seq-sharded KV pool; and a
+    ``local_windows`` layer (GPT-Neo), which keeps every block of its
+    context in the tables although it reads ``window`` rows: the kernel's
+    window walks a bounded ring (``paged_window_decode_attention``), and
+    giving these layers rings is the Laguna family's pool
+    (``PagedKVCache.layer_map``), not yet this decoder's."""
     if _use_decode_kernel(cfg, q.shape[1], cache.num_kv_heads, window):
         return _paged_kernel(_kernels.paged_decode_attention, q, cache,
                              layer_idx, cfg, mesh, cache.block_tables, live)

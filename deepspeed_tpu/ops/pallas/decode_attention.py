@@ -104,7 +104,8 @@ def _layer_pools(k_pool, v_pool, D, k_scale, v_scale):
 
 def _paged_kernel(base_ref, bt_ref, offset_ref, q_ref, *rest,
                   block_size: int, head_dim: int, rep: int, span: int,
-                  scale: float, quantized: bool, batched: bool):
+                  scale: float, quantized: bool, batched: bool,
+                  window: int = 0):
     """Grid (slot, query-row block): one step walks ITS slot's live
     blocks (:func:`~deepspeed_tpu.ops.pallas.block_walk.walk_live_blocks`
     has the scaffold: two VMEM buffers a stream, the next step's first
@@ -133,6 +134,14 @@ def _paged_kernel(base_ref, bt_ref, offset_ref, q_ref, *rest,
     - else (verify, prefill chunks) a product per kv head at M = rows
       against that head's lane slice of the slab, one ``[rows, ·]``
       scratch plane a head.
+
+    ``window`` (static; 0: none, and the body above is all there is): the
+    table is a slot's RING of ``MB`` blocks (kv_cache.PagedKVCache
+    ``ring_k``): row ``r`` of it holds the newest position ``p <= base``
+    with ``p = r (mod MB*BS)``, and the one query token sees it iff ``0
+    <= p`` and ``base - p < window``. The walk is the same walk: a ring
+    has ``min(ceil((base + 1) / BS), MB)`` live blocks, so a context
+    however long reads at most the ring.
     """
     if quantized:
         (k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf,
@@ -220,8 +229,18 @@ def _paged_kernel(base_ref, bt_ref, offset_ref, q_ref, *rest,
                 return dot(kept)
             return dot(kept) + dot((p - kept.astype(jnp.float32)).astype(cdt))
 
+        if window:
+            # how many positions ago a ring row was written: the row of
+            # position ``base`` 0, the one before it 1, ... wrapping
+            newest = jax.lax.rem(base, MB * BS)
+
         def attend(j, buf):
-            visible = col <= bound - j * BS
+            if window:
+                age = newest - j * BS - col
+                age = jnp.where(age < 0, age + MB * BS, age)
+                visible = jnp.logical_and(age < window, age <= base)
+            else:
+                visible = col <= bound - j * BS
             if batched:
                 sc = jax.lax.dot_general(
                     qbd_ref[...], operand(k_buf[buf]),
@@ -269,7 +288,7 @@ def _paged_kernel(base_ref, bt_ref, offset_ref, q_ref, *rest,
 
 def _paged_attention(qg, k_pool, v_pool, block_tables, base, *, rep: int,
                      scale, interpret, name: str, layer: int = 0,
-                     k_scale=None, v_scale=None):
+                     k_scale=None, v_scale=None, window: int = 0):
     """The decode family's one ``pallas_call``, named ``name`` in the
     compiled program and the device trace (the entry point's name: the
     kernel body is shared). qg ``[S, KH, T*rep, D]``
@@ -295,6 +314,12 @@ def _paged_attention(qg, k_pool, v_pool, block_tables, base, *, rep: int,
                          f"{D} need {KH * D}")
     if not 0 <= layer < L:
         raise ValueError(f"layer {layer} of a {L}-layer pool")
+    if window and (rows != rep or quantized
+                   or window > block_tables.shape[1] * BS):
+        raise ValueError(
+            f"a window of {window} over a ring of {block_tables.shape[1]} "
+            f"blocks of {BS}: one query token a slot, a full-precision "
+            "ring at least as long as the window")
     if scale is None:
         scale = 1.0 / (D ** 0.5)
     if interpret is None:
@@ -308,14 +333,14 @@ def _paged_attention(qg, k_pool, v_pool, block_tables, base, *, rep: int,
     call = _paged_call(
         name, bool(interpret), (S, KH, rows, D), qg.dtype.name,
         tuple((x.shape, x.dtype.name) for x in pools),
-        block_tables.shape[1], rep, float(scale))
+        block_tables.shape[1], rep, float(scale), int(window))
     return call(base.astype(jnp.int32), block_tables.astype(jnp.int32),
                 jnp.full((1,), layer * NB, jnp.int32), qg, *pools)
 
 
 @functools.lru_cache(maxsize=None)
 def _paged_call(name: str, interpret: bool, q_shape, q_dtype: str, pools,
-                MB: int, rep: int, scale: float):
+                MB: int, rep: int, scale: float, window: int = 0):
     """The ``pallas_call`` of one static signature: ``(base [S], tables
     [S, MB], block offset [1], qg [S, KH, rows, D], *pools) -> [S, KH,
     rows, D]``. Grid ``(S, row blocks)``, in order; the pools (``pools``:
@@ -353,7 +378,8 @@ def _paged_call(name: str, interpret: bool, q_shape, q_dtype: str, pools,
             pltpu.VMEM((KH, rblk, D), f32)]
     kernel = functools.partial(
         _paged_kernel, block_size=BS, head_dim=D, rep=rep, span=span,
-        scale=scale, quantized=len(pools) == 4, batched=batched)
+        scale=scale, quantized=len(pools) == 4, batched=batched,
+        window=window)
     call = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -447,6 +473,65 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         interpret=interpret, name="paged_decode_attention", layer=layer,
         k_scale=k_scale, v_scale=v_scale)
     return og.reshape(S, H, D)
+
+
+def ring_tables(num_slots: int, ring_blocks: int) -> jax.Array:
+    """``[S, RB]``: slot ``s``'s ring is blocks ``s*RB .. s*RB + RB - 1``
+    of a window layer's buffer (kv_cache.PagedKVCache ``ring_k``)."""
+    return (jnp.arange(num_slots, dtype=jnp.int32)[:, None] * ring_blocks
+            + jnp.arange(ring_blocks, dtype=jnp.int32)[None])
+
+
+def paged_window_decode_attention(q: jax.Array, k_ring: jax.Array,
+                                  v_ring: jax.Array, lengths: jax.Array,
+                                  window: int,
+                                  scale: float | None = None,
+                                  interpret: bool | None = None,
+                                  layer: int = 0) -> jax.Array:
+    """One-token attention of a WINDOW layer through its rings,
+    GQA-native: the paged kernel's walk over a table computed from the
+    slot, rows masked by the position they hold.
+
+    q: ``[S, H, D]``; k_ring/v_ring: ``[Lw, S*RB, BS, KH*D]`` (all window
+    layers' rings as stored; the call attends ring layer ``layer``, a
+    static int); lengths: ``[S]`` int32 live lengths (the query sits at
+    position ``lengths[s] - 1`` and sees positions ``> lengths[s] - 1 -
+    window``). A slot reads ``min(ceil(lengths / BS), RB)`` blocks
+    whatever its context; an idle slot (length 0) reads nothing and
+    returns zeros. Returns ``[S, H, D]``."""
+    S, H, D = q.shape
+    KH = k_ring.shape[-1] // D
+    R = _group_size(H, KH)
+    og = _paged_attention(
+        q.reshape(S, KH, R, D), k_ring, v_ring,
+        ring_tables(S, k_ring.shape[1] // S),
+        lengths.astype(jnp.int32) - 1, rep=R, scale=scale,
+        interpret=interpret, name="paged_window_decode_attention",
+        layer=layer, window=window)
+    return og.reshape(S, H, D)
+
+
+def paged_window_decode_attention_reference(q, k_ring, v_ring, lengths,
+                                            window: int):
+    """Numerics oracle over ONE window layer's rings ``[S*RB, BS,
+    KH*D]`` (and the path off the TPU): every ring row with the position
+    it holds, a dense softmax over the rows inside the window."""
+    from deepspeed_tpu.inference.kv_cache import ring_newest_position
+    S, H, D = q.shape
+    k = k_ring.reshape(S, -1, k_ring.shape[-1] // D, D)     # [S, R, KH, D]
+    v = v_ring.reshape(S, -1, v_ring.shape[-1] // D, D)
+    rep = H // k.shape[2]
+    newest = lengths.astype(jnp.int32) - 1
+    pos = ring_newest_position(newest, k.shape[1])          # [S, R]
+    seen = (pos >= 0) & (pos > newest[:, None] - window)
+    s = jnp.einsum("bhd,bshd->bhs", q.astype(jnp.float32),
+                   jnp.repeat(k, rep, axis=2).astype(jnp.float32)
+                   ) / (D ** 0.5)
+    s = jnp.where(seen[:, None, :], s, NEG_INF)
+    p = jnp.where(seen[:, None, :], jax.nn.softmax(s, axis=-1), 0.0)
+    return jnp.einsum("bhs,bshd->bhd", p,
+                      jnp.repeat(v, rep, axis=2).astype(jnp.float32)
+                      ).astype(q.dtype)
 
 
 def paged_chunk_attention(q: jax.Array, k_pool: jax.Array,

@@ -63,7 +63,13 @@ def _should_interpret() -> bool:
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale: float,
-                block_q: int, block_k: int, seq_len: int, causal: bool):
+                block_q: int, block_k: int, seq_len: int, causal: bool,
+                window: int | None = None):
+    """``window`` (static; None: the kernel as it always was): query row
+    ``i`` sees keys ``j`` with ``i - window < j <= i``. K blocks wholly
+    before the block's first window are skipped, the blocks a window's
+    lower edge cuts are masked, the ones between them and the diagonal
+    are fully visible."""
     qi = pl.program_id(2)
     # keep the dot INPUTS in the storage dtype (bf16): the MXU runs bf16
     # at full rate and accumulates fp32 via preferred_element_type; an
@@ -89,6 +95,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale: float,
         num_kb = diag_start + pl.cdiv(block_q, block_k)
     else:
         diag_start = num_kb = seq_len // block_k
+    first_kb = edge_end = 0
+    if window is not None:
+        # the first row's window starts at qi*bq - window + 1; a block is
+        # inside EVERY row's window from column (last row) - window + 1
+        first_kb = jnp.maximum(qi * block_q - window + 1, 0) // block_k
+        inside = jnp.maximum(qi * block_q + block_q - window, 0)
+        edge_end = jnp.clip((inside + block_k - 1) // block_k, first_kb,
+                            diag_start)
 
     def make_body(masked):
         def body(kb, carry):
@@ -102,7 +116,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale: float,
                     jnp.int32, (bq, block_k), 0)
                 col = kb * block_k + jax.lax.broadcasted_iota(
                     jnp.int32, (bq, block_k), 1)
-                s = jnp.where(row >= col, s, NEG_INF)
+                seen = row >= col
+                if window is not None:
+                    seen = jnp.logical_and(seen, row - col < window)
+                s = jnp.where(seen, s, NEG_INF)
             m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
             p = jnp.exp(s - m_new)
             alpha = jnp.exp(m - m_new)
@@ -113,7 +130,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale: float,
             return m_new, l_new, acc_new
         return body
 
-    carry = jax.lax.fori_loop(0, diag_start, make_body(False), (m, l, acc))
+    carry = (m, l, acc)
+    if window is not None:
+        carry = jax.lax.fori_loop(first_kb, edge_end, make_body(True),
+                                  carry)
+    carry = jax.lax.fori_loop(edge_end, diag_start, make_body(False), carry)
     if causal:
         carry = jax.lax.fori_loop(diag_start, num_kb, make_body(True),
                                   carry)
@@ -122,7 +143,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale: float,
     lse_ref[0] = m + jnp.log(l)  # [BQ, 1]
 
 
-def _flash_fwd(q3, k3, v3, *, scale, block_q, block_k, causal, interpret):
+def _flash_fwd(q3, k3, v3, *, scale, block_q, block_k, causal, interpret,
+               window=None):
     """q3 ``[B*H, T, D]``; k3/v3 ``[B*HKV, T, D]`` (HKV | H — grouped-query
     attention streams each K/V head into VMEM ONCE for its whole query
     group: grid order is (kv-head, group, q-block) with the q-block axis
@@ -140,7 +162,8 @@ def _flash_fwd(q3, k3, v3, *, scale, block_q, block_k, causal, interpret):
         jax.ShapeDtypeStruct((BH, T, 1), jnp.float32),
     ]
     kernel = functools.partial(_fwd_kernel, scale=scale, block_q=block_q,
-                               block_k=block_k, seq_len=T, causal=causal)
+                               block_k=block_k, seq_len=T, causal=causal,
+                               window=window)
     qmap = lambda bkh, g, qi: (bkh * rep + g, qi, 0)  # noqa: E731
     o, lse = pl.pallas_call(
         kernel,
@@ -156,7 +179,8 @@ def _flash_fwd(q3, k3, v3, *, scale, block_q, block_k, causal, interpret):
         ],
         out_shape=out_shape,
         interpret=interpret,
-        name="flash_attention_fwd",
+        name=("flash_attention_fwd" if window is None
+              else "flash_attention_window_fwd"),
     )(q3, k3, v3)
     return o, lse
 
@@ -389,11 +413,44 @@ def _flash_attention_bwd(scale, block_q, block_k, causal, res, do3):
 _flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_attention_window(q3, k3, v3, scale, block_q, block_k, window):
+    """The windowed forward. It has no backward kernels: differentiating
+    it is refused by name instead of falling through to a backward pass
+    that would ignore the window."""
+    o, _ = _flash_fwd(q3, k3, v3, scale=scale, block_q=block_q,
+                      block_k=block_k, causal=True, window=window,
+                      interpret=_should_interpret())
+    return o
+
+
+def _flash_attention_window_fwd(q3, k3, v3, scale, block_q, block_k,
+                                window):
+    return _flash_attention_window(q3, k3, v3, scale, block_q, block_k,
+                                   window), None
+
+
+def _flash_attention_window_bwd(scale, block_q, block_k, window, res, do3):
+    raise NotImplementedError(
+        f"flash_attention(window={window}) has no backward kernels: the "
+        "windowed kernel is forward only (serving prefill); train a "
+        "windowed layer through the masked einsum")
+
+
+_flash_attention_window.defvjp(_flash_attention_window_fwd,
+                               _flash_attention_window_bwd)
+
+
 def flash_attention(q, k, v, causal: bool = True,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_k: int = DEFAULT_BLOCK_K,
-                    scale: float | None = None):
+                    scale: float | None = None,
+                    window: int | None = None):
     """Fused attention, ``q [B, T, H, D] -> [B, T, H, D]``.
+
+    ``window`` (causal only, forward only): query ``i`` sees keys ``i -
+    window < j <= i``; K blocks wholly outside a query block's windows
+    are skipped. Differentiating a windowed call raises by name.
 
     ``k``/``v`` may carry fewer heads (``[B, T, HKV, D]`` with HKV | H):
     grouped-query attention runs WITHOUT materializing the repeated k/v —
@@ -424,6 +481,13 @@ def flash_attention(q, k, v, causal: bool = True,
         h = x.shape[2]
         return jnp.swapaxes(x, 1, 2).reshape(B * h, T, D)
 
-    o3 = _flash_attention(to3(q), to3(k), to3(v), float(scale),
-                          block_q, block_k, causal)
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError(f"window={window} needs causal=True and a "
+                             "window of at least one position")
+        o3 = _flash_attention_window(to3(q), to3(k), to3(v), float(scale),
+                                     block_q, block_k, int(window))
+    else:
+        o3 = _flash_attention(to3(q), to3(k), to3(v), float(scale),
+                              block_q, block_k, causal)
     return jnp.swapaxes(o3.reshape(B, H, T, D), 1, 2)

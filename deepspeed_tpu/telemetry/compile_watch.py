@@ -285,7 +285,10 @@ SCOPES = frozenset((
     "mla_qkv", "latent_write", "mla_attn", "dense_ffn", "moe_router",
     "moe_dispatch", "moe_experts", "moe_combine",
     # model_implementations/brumby.py
-    "ret_qkvg", "ret_state", "ret_out"))
+    "ret_qkvg", "ret_state", "ret_out",
+    # model_implementations/laguna.py (an attention by the kind of its
+    # layer, over its projections, rotary, gate, cache write and kernel)
+    "attn_full", "attn_window", "moe_shared"))
 
 _INSTR = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+)\s*=\s")
 _COMPUTATION = re.compile(

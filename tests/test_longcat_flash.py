@@ -719,7 +719,10 @@ def test_configuration_file_states_the_published_sizes_once():
     cell = harness.resolve_cell(contract, CELL)
     assert cell["config"]["engine"]["num_slots"] == 256
     assert "serve_out_tokens_per_s" in cell["end_to_end"]
-    new = [m for m in contract["per_layer"] if m.get("workloads") == [CELL]]
+    # the readers this cell brought: it leads their lists (a later cell
+    # of another family that holds experts reports three of them too)
+    new = [m for m in contract["per_layer"]
+           if m.get("workloads", [])[:1] == [CELL]]
     assert len(new) == 12
     for m in new + [m for m in contract["per_layer"]
                     if CELL in m.get("workloads", ())]:

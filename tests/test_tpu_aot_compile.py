@@ -147,7 +147,38 @@ def _latent_decode():
                 ((g["slots"], MB), jnp.int32), ((g["slots"],), jnp.int32)]
 
 
+# serve-laguna-xs2-ep8-mixed-context-batch's own geometry: 96 slots, a
+# 640-row ring a slot (5 blocks) of 8 kv heads x 128, groups of 8 query
+# heads on the window layers; prompts to 8192 tokens
+LAGUNA = dict(slots=96, ring_blocks=5, window=512, kv_heads=8,
+              window_heads=64, full_heads=48, prompt=8192)
+
+
+def _window_decode():
+    """The window walk at the Laguna cell's geometry: q, the second of
+    two window layers' rings ``[Lw, S*RB, BS, KH*D]``, lengths."""
+    g = LAGUNA
+    ring = ((2, g["slots"] * g["ring_blocks"], BS, g["kv_heads"] * 128),
+            BF16)
+    fn = functools.partial(da.paged_window_decode_attention,
+                           window=g["window"], layer=1, interpret=False)
+    return fn, [((g["slots"], g["window_heads"], 128), BF16), ring, ring,
+                ((g["slots"],), jnp.int32)]
+
+
+def _flash_window():
+    """The windowed flash forward at the cell's longest bucket."""
+    g = LAGUNA
+    kv = ((1, g["prompt"], g["kv_heads"], 128), BF16)
+
+    def fwd(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True, window=g["window"])
+    return fwd, [((1, g["prompt"], g["window_heads"], 128), BF16), kv, kv]
+
+
 CASES = {
+    "paged_window_decode-ring": _window_decode,
+    "flash-window-fwd": _flash_window,
     "latent-decode": _latent_decode,
     "retention_decode": functools.partial(_retention, "decode"),
     "retention_prefill": functools.partial(_retention, "prefill"),
@@ -191,7 +222,7 @@ def test_kernel_compiles_for_v5e(case, chips, monkeypatch):
         layer = NB_ * BS_ * W * jnp.dtype(dtype).itemsize
         assert compiled.memory_analysis().temp_size_in_bytes < layer
         tiles = set()
-        if len(shapes) > 5:
+        if len(shapes) > 5 and len(shapes[-1][0]) == 4:
             L_, _, KH_, _ = whole = shapes[-1][0]
             tiles = {whole, whole[1:], (L_ * NB_, KH_, BS_)}
         assert not _copies(
@@ -495,6 +526,101 @@ def test_retention_programs_update_the_state_in_place(chips, monkeypatch,
     # activations of its 1024 tokens (74 MB), far under a layer's pool
     room = cfg.state_bytes * (1 if kind == "decode" else 4)
     assert compiled.memory_analysis().temp_size_in_bytes < room
+
+
+LAGUNA_SCOPES = {"embed", "ln", "attn_full", "attn_window", "kv_write",
+                 "dense_ffn", "moe_router", "moe_dispatch", "moe_experts",
+                 "moe_combine", "moe_shared", "lm_head", "sample"}
+
+
+@pytest.mark.parametrize("kind,kernels_want", [
+    ("decode", {"paged_decode_attention": 2,
+                "paged_window_decode_attention": 3}),
+    ("prefill", {"flash_attention_fwd": 2, "flash_attention_window_fwd": 3}),
+])
+def test_window_and_full_layers_share_one_program(chips, monkeypatch, kind,
+                                                  kernels_want):
+    """Laguna's two serving programs at the cell's widths, slots, rings,
+    pool and longest prompt (a dense full layer, three sparse window
+    layers and a sparse full layer; a small vocabulary and two held
+    experts), read back from their compiled text: module, kernel
+    and scope names; one kernel call a layer, by the layer's kind; no
+    ``kv_read`` (nothing gathers the pool or a ring); apart from the
+    kernel calls and the in-place writes nothing as large as one window
+    layer's rings or one layer of the pool is written, so no layout
+    conversion sits around a kernel call; and the decode program needs
+    no temporary of that size."""
+    from deepspeed_tpu.inference.kv_cache import init_paged_cache
+    from deepspeed_tpu.inference.server import ContinuousBatchingServer as Srv
+    from deepspeed_tpu.model_implementations import laguna as lg
+    from deepspeed_tpu.telemetry import compile_watch
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(fa, "_should_interpret", lambda: False)
+    g = LAGUNA
+    one = SingleDeviceSharding(chips[0])
+    cfg = lg.LagunaConfig(
+        vocab_size=2048, num_hidden_layers=5,
+        layer_types=(lg.FULL,) + (lg.WINDOW,) * 3 + (lg.FULL,),
+        mlp_layer_types=("dense",) + ("sparse",) * 4,
+        num_attention_heads_per_layer=(g["full_heads"],)
+        + (g["window_heads"],) * 3 + (g["full_heads"],),
+        rope_full=lg.RopeSpec(
+            rope_theta=500000, rope_type="yarn", factor=64,
+            original_max_position_embeddings=4096, beta_fast=64,
+            partial_rotary_factor=0.5),
+        rope_sliding=lg.RopeSpec(rope_theta=10000),
+        experts_held=(0, 2))
+    assert (cfg.kv_heads, cfg.head_dim, cfg.sliding_window) == (
+        g["kv_heads"], 128, g["window"])
+    abstract = functools.partial(_abstract, sharding=one)
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    params = abstract(jax.eval_shape(
+        lambda: lg.init_params(jax.random.PRNGKey(0), cfg)))
+    blocks, span = 1 + 3200, 80
+    cache = abstract(jax.eval_shape(lambda: init_paged_cache(
+        cfg.n_layer, g["slots"], blocks, BS, span, cfg.kv_heads,
+        cfg.head_dim, BF16, window_layers=cfg.window_layers,
+        window=cfg.sliding_window, aux_shape=cfg.aux_shape)))
+    assert cache.k.shape == (2, blocks, BS, 1024)
+    assert cache.ring_k.shape == (3, g["slots"] * g["ring_blocks"], BS, 1024)
+    fn, name, args = {
+        "decode": (Srv._decode_fn, "serve_decode",
+                   (params, arr((g["slots"],)), cache,
+                    arr((g["slots"],), jnp.bool_))),
+        "prefill": (Srv._prefill_fn, "serve_prefill",
+                    (params, arr((1, g["prompt"])), arr((1,)), cache,
+                     arr(()))),
+    }[kind]
+    da._paged_call.cache_clear()
+    compiled = jax.jit(compile_watch._named(
+        functools.partial(fn, cfg=cfg, mesh=None), name),
+        donate_argnames=("cache",)).lower(*args).compile()
+    text = compiled.as_text()
+    assert f"HloModule jit_{name}" in text
+    scopes, kernels = compile_watch.parse_scopes(text)
+    ours = {k: v for k, v in kernels.items() if not v.startswith("ragged")}
+    counts = {v: list(ours.values()).count(v) for v in set(ours.values())}
+    assert counts == kernels_want
+    for k, v in ours.items():
+        assert scopes[k].split("/")[0] == (
+            "attn_window" if "window" in v else "attn_full"), (k, scopes[k])
+    words = {w for v in scopes.values() if v for w in v.split("/")}
+    assert words >= LAGUNA_SCOPES, LAGUNA_SCOPES - words
+    assert "kv_read" not in words
+    ring_layer = math.prod(cache.ring_k.shape[1:]) * 2
+    pool_layer = math.prod(cache.k.shape[1:]) * 2
+    stores = (cache.k.shape, cache.ring_k.shape)
+    assert not _copies(
+        text, lambda dims, nbytes: dims not in stores and nbytes >= min(
+            ring_layer, pool_layer) and dims[-1:] == (1024,)
+        and dims[-2:] == (BS, 1024), kernels=kernels)
+    assert not [c for c in _copies(
+        text, lambda dims, nbytes: dims in stores, kernels=kernels)
+        if not any(op in c for op in _IN_PLACE)]
+    if kind == "decode":
+        assert compiled.memory_analysis().temp_size_in_bytes < ring_layer
 
 
 def test_train_model_kernels_and_scopes(chips, monkeypatch):
