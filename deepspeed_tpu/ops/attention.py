@@ -17,11 +17,10 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-from deepspeed_tpu.comm.mesh import (DATA_AXES, get_global_mesh,
-                                     has_global_mesh)
+from deepspeed_tpu.comm.mesh import DATA_AXES
 from deepspeed_tpu.ops.pallas.flash_attention import (
     DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q, flash_attention)
-from deepspeed_tpu.utils.sharding import map_kernel
+from deepspeed_tpu.utils.sharding import engine_mesh, map_kernel
 
 
 @functools.lru_cache(maxsize=1)
@@ -91,9 +90,8 @@ def _over_global_mesh(kernel, q, k, v):
     replicated. Inside an already-manual region (ring/Ulysses SP, the
     explicit-DP steps) the caller owns the mapping and the kernel runs
     as is."""
-    mesh = get_global_mesh() if has_global_mesh() else None
-    ctx = jax.sharding.get_abstract_mesh()
-    if mesh is None or (not ctx.empty and ctx.manual_axes):
+    mesh = engine_mesh()
+    if mesh is None:
         return kernel(q, k, v)
     dp = mesh.shape["data"] * mesh.shape["fsdp"]
     tp = mesh.shape["tensor"]

@@ -26,6 +26,19 @@ partitioned_param_coordinator.py:239), reduce-scatter of grads
 after the step (stage_1_and_2.py:1743) — all overlapped by the
 latency-hiding scheduler instead of a manual side stream.
 
+What these placements do NOT decide is whether a stage-3 matmul gathers
+its weight or its activation: with ``x`` and ``W`` both split over the
+zero axis the partitioner may as well leave ``W`` split and exchange
+activations (the tensor-parallel reading), and on GPT-2 1.3B over
+``fsdp=4`` it does: 30.5 GB over the wire a chip a step against the 5.8
+of two parameter gathers and one gradient scatter. The model's
+residual-stream constraint (``models/gpt2.py``:
+``maybe_constrain(x, P(DATA_AXES, "seq", None))``, resolved against the
+engine's mesh by ``utils/sharding.py``) pins activations to the batch
+axes, and that is what makes "all-gather the weight" the only reading
+left. ZeRO-3 as the table describes it rests on that constraint; a model
+without it gets correct losses and the wrong traffic.
+
 Per-leaf placement: shard the largest dimension that is divisible by the
 zero-axis size and not already claimed by tensor parallelism. Leaves smaller
 than ``param_persistence_threshold`` stay replicated — same intent as the

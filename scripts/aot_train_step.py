@@ -13,10 +13,12 @@ run it on a ``git archive`` of the parent (``--repo``) and on the tree
 (24 layers of the offload cell: ~110 s, of the x4 cell: ~5 min) and
 compare ``sha256_instructions``: the text less what only says WHERE in
 the source an instruction came from (the location tables it opens with,
-and the flash kernels' ``backend_config``, a serialized Mosaic module
-that holds the same file names and line numbers: a checkout at another
-path, or a line added above a call site, changes those bytes and
-nothing the chip runs). ``sha256`` is over every byte. (2) To read what
+each instruction's ``stack_frame_id`` into them, and the flash kernels'
+``backend_config``, a serialized Mosaic module that holds the same file
+names and line numbers: a checkout at another path, a line added above
+a call site or one more Python frame under the caller changes those
+bytes and nothing the chip runs; hashes printed before PR 44 kept the
+frame ids and cannot be compared with these). ``sha256`` is over every byte. (2) To read what
 the TPU's compiler writes (the words on ``op_name`` paths, how it wraps
 collectives, which memory space a copy crosses) before fixing a rule in
 ``compile_watch.parse``. (3) To read the ORDER of the offload stream
@@ -46,14 +48,19 @@ import time
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 LOCATION_TABLES = ("FileNames", "FunctionNames", "FileLocations",
                    "StackFrames")
 
 
+_FRAME = re.compile(r" stack_frame_id=\d+")
+
+
 def instructions(text: str) -> str:
     """``text`` without its source locations (see the module docstring)."""
     out, table = [], False
+    text = _FRAME.sub("", text)
     for line in text.splitlines():
         if line.startswith(LOCATION_TABLES):
             table = True
@@ -153,27 +160,20 @@ def stream_order(text: str, movement: dict, min_bytes: int = 1 << 20) -> dict:
             "last_store_done_at": last_done["device_to_host"]}
 
 
-def main() -> int:
-    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--repo", default=os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), help="the checkout to compile from")
-    ap.add_argument("--traffic", default="offload",
-                    help="benchmark/traffic/<name>.json of a train cell")
-    ap.add_argument("--config", default="gpt2-1.3b-train")
-    ap.add_argument("--layers", type=int, default=None,
-                    help="depth (default: the configuration's)")
-    ap.add_argument("--out", default=None, help="write the text here")
-    args = ap.parse_args()
-    repo = os.path.abspath(args.repo)
-    sys.path.insert(0, repo)
-
+def compile_step(repo: str = REPO, config: str = "gpt2-1.3b-train",
+                 traffic: str = "zero3-x4", layers=None):
+    """The engine's ``train_step`` for the cell's configuration and
+    traffic files under ``repo``, at ``layers`` deep, compiled for the
+    described v5e: ``{"text", "seconds", "chips", "layers",
+    "parameters"}`` (``parameters``: the model's count). Everything it
+    replaces in ``jax`` while the engine is built is put back, so a test
+    may call it in its own process (``tests/test_tpu_aot_compile.py``)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
     from jax.experimental import topologies
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    jax.config.update("jax_enable_compilation_cache", False)
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     SDS = jax.ShapeDtypeStruct
@@ -219,57 +219,87 @@ def main() -> int:
             return jax.tree.map(lambda v: one(v, device), x)
         return jax.tree.map(one, x, device)
 
+    from deepspeed_tpu.ops import attention
     from deepspeed_tpu.ops.pallas import flash_attention as fa
+    real_interpret = fa._should_interpret
     jax.default_backend = lambda: "tpu"
     fa._should_interpret = lambda: False
-    from benchmark.lib import harness
-    bench = os.path.join(repo, "benchmark")
-    config = harness.load_json(os.path.join(
-        bench, "configs", args.config + ".json"))
-    traffic = harness.load_json(os.path.join(
-        bench, "traffic", args.traffic + ".json"))
-    family = harness.load_family(config["model"]["family"], bench)
-    model = dict(config["model"])
-    if args.layers is not None:
-        model["n_layer"] = args.layers
-    tm = family.train_model(model)
-    import deepspeed_tpu
-    from deepspeed_tpu.comm.mesh import MeshConfig, build_mesh
-    chips = int(np.prod(list(traffic["mesh"].values())))
-    mesh = build_mesh(MeshConfig(**traffic["mesh"]),
-                      devices=list(topo.devices)[:chips])
-    everywhere = NamedSharding(mesh, P())
-    params = jax.tree.map(
-        lambda x: SDS(x.shape, x.dtype, sharding=everywhere),
-        jax.eval_shape(lambda: family.train_params(tm, 0)))
-    jax.jit, jax.device_put = jit, put
+    attention._on_tpu.cache_clear()
     try:
-        engine, _, _, _ = deepspeed_tpu.initialize(
-            model=tm, model_parameters=params, mesh=mesh,
-            config=traffic["engine"])
+        from benchmark.lib import harness
+        bench = os.path.join(repo, "benchmark")
+        model = dict(harness.load_json(os.path.join(
+            bench, "configs", config + ".json"))["model"])
+        traffic = harness.load_json(os.path.join(
+            bench, "traffic", traffic + ".json"))
+        family = harness.load_family(model["family"], bench)
+        if layers is not None:
+            model["n_layer"] = layers
+        tm = family.train_model(model)
+        import deepspeed_tpu
+        from deepspeed_tpu.comm.mesh import MeshConfig, build_mesh
+        chips = int(np.prod(list(traffic["mesh"].values())))
+        mesh = build_mesh(MeshConfig(**traffic["mesh"]),
+                          devices=list(topo.devices)[:chips])
+        everywhere = NamedSharding(mesh, P())
+        params = jax.tree.map(
+            lambda x: SDS(x.shape, x.dtype, sharding=everywhere),
+            jax.eval_shape(lambda: family.train_params(tm, 0)))
+        jax.jit, jax.device_put = jit, put
+        try:
+            engine, _, _, _ = deepspeed_tpu.initialize(
+                model=tm, model_parameters=params, mesh=mesh,
+                config=traffic["engine"])
+        finally:
+            jax.jit, jax.device_put = real_jit, real_put
+        ej = traffic["engine"]
+        rows = ej["train_micro_batch_size_per_gpu"] * ej.get(
+            "gradient_accumulation_steps", 1) * mesh.shape["data"] \
+            * mesh.shape["fsdp"]
+        batch = {"input_ids": SDS((rows, int(traffic["seq_len"])),
+                                  jnp.int32, sharding=everywhere)}
+        engine._compile_step(batch)
+        batch = jax.tree.map(lambda x, s: SDS(x.shape, x.dtype, sharding=s),
+                             batch, engine._batch_sharding(batch))
+        rng = SDS((2,), jnp.uint32, sharding=everywhere)
+        t0 = time.time()
+        text = engine._step_fn.lower(engine.state, batch, rng,
+                                     False).compile().as_text()
+        return {"text": text, "seconds": time.time() - t0, "chips": chips,
+                "layers": model["n_layer"],
+                "parameters": sum(int(np.prod(x.shape))
+                                  for x in jax.tree.leaves(params))}
     finally:
-        jax.jit, jax.device_put = real_jit, real_put
-    ej = traffic["engine"]
-    rows = ej["train_micro_batch_size_per_gpu"] * ej.get(
-        "gradient_accumulation_steps", 1) * mesh.shape["data"] \
-        * mesh.shape["fsdp"]
-    batch = {"input_ids": SDS((rows, int(traffic["seq_len"])), jnp.int32,
-                              sharding=everywhere)}
-    engine._compile_step(batch)
-    batch = jax.tree.map(lambda x, s: SDS(x.shape, x.dtype, sharding=s),
-                         batch, engine._batch_sharding(batch))
-    rng = SDS((2,), jnp.uint32, sharding=everywhere)
-    t0 = time.time()
-    text = engine._step_fn.lower(engine.state, batch, rng,
-                                 False).compile().as_text()
-    seconds = time.time() - t0
-    jax.default_backend = real_backend
+        jax.default_backend = real_backend
+        fa._should_interpret = real_interpret
+        attention._on_tpu.cache_clear()
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--repo", default=REPO,
+                    help="the checkout to compile from")
+    ap.add_argument("--traffic", default="offload",
+                    help="benchmark/traffic/<name>.json of a train cell")
+    ap.add_argument("--config", default="gpt2-1.3b-train")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="depth (default: the configuration's)")
+    ap.add_argument("--out", default=None, help="write the text here")
+    args = ap.parse_args()
+    repo = os.path.abspath(args.repo)
+    sys.path.insert(0, repo)
+
+    import jax
+    jax.config.update("jax_enable_compilation_cache", False)
+    step = compile_step(repo, args.config, args.traffic, args.layers)
+    text = step["text"]
     if args.out:
         with open(args.out, "w") as fh:
             fh.write(text)
     report = {"repo": repo, "traffic": args.traffic,
-              "layers": model["n_layer"], "chips": chips,
-              "compile_s": round(seconds, 1), "chars": len(text),
+              "layers": step["layers"], "chips": step["chips"],
+              "parameters": step["parameters"],
+              "compile_s": round(step["seconds"], 1), "chars": len(text),
               "sha256": hashlib.sha256(text.encode()).hexdigest(),
               "sha256_instructions": hashlib.sha256(
                   instructions(text).encode()).hexdigest()}

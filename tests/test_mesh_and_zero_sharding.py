@@ -184,3 +184,118 @@ def test_uneven_non_expert_tp_dim_warns_not_raises(caplog):
         ds_logger.propagate = False
     assert sh["emb"].spec == P("tensor", None)
     assert any("not divisible" in r.getMessage() for r in caplog.records)
+
+
+# ---------------------------------------------------------------------------
+# utils/sharding.maybe_constrain: which mesh a model's bare spec means.
+# The engine traces its step with explicit NamedShardings and opens no
+# mesh context, so a constraint has to find the engine's mesh itself.
+# ---------------------------------------------------------------------------
+
+RESIDUAL = P(("data", "fsdp"), "seq", None)
+
+
+def _constraints(fn, *args) -> int:
+    return str(jax.make_jaxpr(fn)(*args)).count("sharding_constraint")
+
+
+@pytest.mark.parametrize("axes,spec,rows,emitted", [
+    (dict(data=2, fsdp=4), RESIDUAL, 8, True),      # ZeRO-3 over fsdp
+    (dict(data=8), RESIDUAL, 8, True),
+    (dict(data=1, seq=8), RESIDUAL, 8, True),       # `seq` alone shards T
+    (dict(data=1, tensor=8), RESIDUAL, 8, False),   # every named axis 1
+    (dict(data=1, fsdp=1), RESIDUAL, 8, False),     # one chip
+    (dict(data=2, fsdp=4), P("nowhere", None, None), 8, False),
+    # a trained model applied to two rows under the engine's old mesh
+    (dict(data=2, fsdp=4), RESIDUAL, 2, False),
+    (None, RESIDUAL, 8, False),                     # no engine, bare jit
+], ids=["fsdp4", "data8", "seq8", "tensor8", "one-chip", "unknown-axis",
+        "batch-does-not-divide", "no-mesh"])
+def test_maybe_constrain_finds_the_engines_mesh(axes, spec, rows, emitted):
+    from deepspeed_tpu.comm.mesh import set_global_mesh
+    from deepspeed_tpu.utils.sharding import engine_mesh, maybe_constrain
+    if axes is not None:
+        n = int(np.prod(list(axes.values())))
+        set_global_mesh(build_mesh(MeshConfig(**axes),
+                                   devices=jax.devices()[:n]))
+    assert jax.sharding.get_abstract_mesh().empty
+    assert (engine_mesh() is None) == (axes is None)
+    x = jnp.zeros((rows, 16, 4))
+    fn = lambda x: maybe_constrain(x, spec) * 2.0  # noqa: E731
+    assert _constraints(fn, x) == int(emitted)
+    if emitted:
+        # over the ENGINE's mesh, though nothing opened a context
+        y = jax.jit(lambda x: maybe_constrain(x, spec))(x)
+        assert y.sharding.is_equivalent_to(
+            jax.sharding.NamedSharding(engine_mesh(), spec), x.ndim)
+    np.testing.assert_array_equal(np.asarray(jax.jit(fn)(x)), 0.0)
+
+
+@pytest.mark.parametrize("manual", [("data", "fsdp", "seq"), ("pipe",)],
+                         ids=["over-named-axes", "over-another-axis"])
+def test_maybe_constrain_inside_shard_map(manual):
+    """In a manual region the caller owns the layout: over the axes the
+    spec names the helper returns ``x`` (XLA rejects the constraint);
+    over another axis the named ones are still Auto and the context's
+    mesh, not the global one, carries the bare spec."""
+    from deepspeed_tpu.comm.mesh import set_global_mesh
+    from deepspeed_tpu.utils.sharding import engine_mesh, maybe_constrain
+    mesh = build_mesh(MeshConfig(pipe=2, data=1, fsdp=2, seq=2))
+    set_global_mesh(mesh)
+    seen = []
+
+    def body(x):
+        seen.append(engine_mesh())
+        return maybe_constrain(x, RESIDUAL) + 1.0
+    mapped = jax.shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(),
+                           axis_names=set(manual), check_vma=False)
+    x = jnp.zeros((8, 16, 4))
+    assert _constraints(mapped, x) == int(manual == ("pipe",))
+    assert seen == [None]
+    np.testing.assert_array_equal(np.asarray(jax.jit(mapped)(x)), 1.0)
+
+
+def test_maybe_constrain_under_a_mesh_context_is_the_contexts():
+    """``jax.set_mesh`` rules where a caller opened it (the abstract mesh,
+    a bare spec), whatever mesh an engine left behind."""
+    from deepspeed_tpu.comm.mesh import set_global_mesh
+    from deepspeed_tpu.utils.sharding import maybe_constrain
+    set_global_mesh(build_mesh(MeshConfig(data=1, tensor=8)))
+    mesh = build_mesh(MeshConfig(data=2, fsdp=4))
+    x = jnp.zeros((8, 16, 4))
+    with jax.set_mesh(mesh):
+        assert _constraints(lambda x: maybe_constrain(x, RESIDUAL), x) == 1
+        y = jax.jit(lambda x: maybe_constrain(x, RESIDUAL))(x)
+    assert y.sharding.is_equivalent_to(
+        jax.sharding.NamedSharding(mesh, RESIDUAL), x.ndim)
+
+
+@pytest.mark.parametrize("stage", [0, 3])
+def test_engine_step_holds_the_models_constraint(stage):
+    """The residual-stream constraint of ``models/gpt2.py`` is IN the
+    engine's traced step (it was a silent no-op while only an abstract
+    mesh could switch it on), and the step computes the loss it did."""
+    import deepspeed_tpu
+    from deepspeed_tpu.models.gpt2 import GPT2LMModel, config_for
+    model = GPT2LMModel(config_for("gpt2-125m", n_embd=32, n_layer=2,
+                                   n_head=2, vocab_size=64, n_positions=16,
+                                   dtype=jnp.float32))
+    params = model.init(jax.random.PRNGKey(0), batch_size=1, seq_len=16)
+    batch = {"input_ids": np.arange(8 * 16, dtype=np.int32).reshape(
+        8, 16) % 64}
+    want = float(model.loss_fn(params, batch))
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        model=model, model_parameters=params,
+        mesh=build_mesh(MeshConfig(data=2, fsdp=4)),
+        config={"train_micro_batch_size_per_gpu": 1,
+                "optimizer": {"type": "AdamW", "params": {"lr": 1e-3}},
+                "zero_optimization": {"stage": stage}})
+    metrics = engine.train_batch(batch)
+    np.testing.assert_allclose(float(metrics["loss"]), want, rtol=1e-5)
+    placed = jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        batch, engine._batch_sharding(batch))
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32)
+    jaxpr = str(jax.make_jaxpr(engine._step_fn, static_argnums=3)(
+        engine.state, placed, rng, False))
+    assert "sharding_constraint" in jaxpr

@@ -809,6 +809,29 @@ def test_stream_pipeline_keeps_both_directions_in_flight(chips, fsdp):
     assert most <= osm.LAG + 1 + osm.AHEAD + 1, most
 
 
+def test_zero3_step_exchanges_parameters_not_activations(chips):
+    """The x4 cell's OWN step (``deepspeed_tpu.initialize``, ZeRO-3 over
+    ``fsdp=4``, GPT-2 1.3B widths) at two layers: with the model's
+    residual-stream constraint live inside the engine's trace
+    (``utils/sharding.maybe_constrain``) the partitioner gathers weights
+    and scatters gradients and leaves activations where they are. While
+    the constraint was dead it ran the projections like tensor
+    parallelism: 18 all-to-alls and 4.7 GB at this depth, 171 and 30.5 GB
+    at 24 layers (PERF.md section 6, PR 44)."""
+    from deepspeed_tpu.telemetry import compile_watch
+    step = _aot_script().compile_step(traffic="zero3-x4", layers=2)
+    assert step["chips"] == 4
+    table = compile_watch.parse(step["text"]).movement
+    moved = compile_watch.movement_per_step(table)
+    # the embedding's lookup and its scatter-add, whatever the depth
+    assert moved["all-to-all"]["calls"] <= 2, moved
+    # a chip receives three quarters of every bf16 parameter twice
+    # (forward, backward) and sends three quarters of its gradient once
+    zero3 = 3 * 2 * step["parameters"] * 3 / 4
+    wire = moved["all-gather"]["bytes"] + moved["reduce-scatter"]["bytes"]
+    assert 0.5 * zero3 < wire < 1.2 * zero3, (wire, zero3, moved)
+
+
 def test_kernel_names_are_the_same_under_a_mesh(chips):
     """``map_kernel``'s shard_map does not rename the call: the decode
     kernel reads ``paged_decode_attention`` on four devices as on one."""
