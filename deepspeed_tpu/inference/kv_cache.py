@@ -587,13 +587,14 @@ def prefix_block_hashes(prompt, block_size: int) -> list:
     position-safe (rotary k/v, learned positions and ALiBi all depend
     on absolute position, and a chained full-prefix match pins it).
     sha256 because a collision would silently serve another prompt's
-    context; the cost is a few microseconds per admission."""
-    n = len(prompt) // block_size
+    context. The ids go in as int64 bytes: ~1 ms for a 33k-token
+    prompt, paid at ``Scheduler.submit``, off the step loop."""
+    ids = np.asarray(prompt, dtype=np.int64)
     out, prev = [], b""
-    for i in range(n):
-        span = prompt[i * block_size:(i + 1) * block_size]
+    for i in range(len(ids) // block_size):
         h = hashlib.sha256(
-            prev + b"," + ",".join(map(str, span)).encode()).digest()
+            prev + ids[i * block_size:(i + 1) * block_size].tobytes()
+        ).digest()
         out.append(h)
         prev = h
     return out
@@ -700,6 +701,25 @@ def latent_write_prompt(cache: LatentPagedCache, idx: int,
     nb = rows.shape[0] // BS
     blocks = jax.lax.dynamic_slice_in_dim(cache.block_tables, slot, 1,
                                           0)[0, :nb]
+    pool = cache.rows[idx]
+    return _with_rows(cache, idx, pool.at[blocks].set(
+        jnp.swapaxes(rows.reshape(nb, BS, -1), 1, 2).astype(pool.dtype)))
+
+
+@scoped("latent_write")
+def latent_write_chunk(cache: LatentPagedCache, idx: int, rows: jnp.ndarray,
+                       slot, start) -> LatentPagedCache:
+    """Chunked prefill: scatter a chunk's ``[C, W]`` rows of attention
+    ``idx`` into ``slot``'s blocks at positions ``start .. start + C -
+    1``. ``C`` and ``start`` are block-aligned, as for
+    :func:`paged_write_chunk` (whole blocks; a window past the table's
+    tail, and table entries past the allocated span, spill into the null
+    block)."""
+    BS = cache.block_size
+    nb = rows.shape[0] // BS
+    row = jax.lax.dynamic_slice_in_dim(cache.block_tables, slot, 1, 0)[0]
+    row = jnp.concatenate([row, jnp.zeros((nb,), jnp.int32)])
+    blocks = jax.lax.dynamic_slice_in_dim(row, start // BS, nb, 0)
     pool = cache.rows[idx]
     return _with_rows(cache, idx, pool.at[blocks].set(
         jnp.swapaxes(rows.reshape(nb, BS, -1), 1, 2).astype(pool.dtype)))

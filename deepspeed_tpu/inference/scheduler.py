@@ -296,6 +296,11 @@ class Scheduler:
                 f"request queue is full ({self.max_queued_requests}); "
                 "drain with step() before submitting more, or raise "
                 "max_queued_requests")
+        if self.enable_prefix_caching:
+            # hashed where the request arrives (a frontend's thread, or
+            # between steps while the device runs ahead), not at its
+            # admission, where the device waits for the host
+            req.prefix_hashes(self.block_size)
         self.queue.append(req)
         self._g_queue.set(len(self.queue))
 

@@ -124,6 +124,14 @@ def _safe_cache_size(fn) -> int:
         return -1
 
 
+@jax.jit
+def _set_row(table, slot, row):
+    """``table.at[slot].set(row)`` as one small program: written out on
+    the host, the indexed update costs 0.6-1.0 ms of Python a call, and
+    a refill pays two with the device idle (PERF.md section 6, PR 45)."""
+    return table.at[slot].set(row)
+
+
 def _sample(logits):
     """Greedy choice, under the serving programs' ``sample`` scope."""
     with jax.named_scope("sample"):
@@ -187,10 +195,16 @@ class _PoolKind:
     what: str = ""                 # the model and its pool, in a refusal
     serves: str = ""               # what does serve it
     refuses: tuple = ()            # (switch, is it on, why not)
+    # (switches, entry point): honoured for a model whose module has the
+    # entry point, refused with their reason for one that has not
+    by_entry_point: tuple = ((), "")
 
-    def refuse(self, cfg, draft_engine, handoff_import) -> None:
+    def refuse(self, cfg, draft_engine, handoff_import, family=None) -> None:
+        switches, entry = self.by_entry_point
+        honoured = switches if entry and hasattr(family, entry) else ()
         on = [(name, why) for name, test, why in self.refuses
-              if test(cfg, draft_engine, handoff_import)]
+              if name not in honoured
+              and test(cfg, draft_engine, handoff_import)]
         if on:
             raise NotImplementedError(
                 f"{self.what} cannot be served with " + "; ".join(
@@ -225,13 +239,19 @@ _POOL_KINDS = {
         make_pool="_make_latent_pool",
         what="a latent-attention model (latent paged cache)",
         serves="monolithic bucketed prefill and plain paged decode "
-               "serve it",
+               "serve it, chunked prefill and prefix reuse too where the "
+               "model's module has paged_prefill_chunk",
+        by_entry_point=(("enable_prefix_caching", "prefill_chunk_tokens"),
+                        "paged_prefill_chunk"),
         refuses=_rows_switches(
             "the latent pool has no int8 rows or scale tiles",
             "block payloads are read and swapped as K/V slabs",
-            "a cache hit prefills its tail through the chunk program",
-            "chunked prefill attends the pool with the K/V chunk kernel",
-            "it chains chunked prefill",
+            "a cache hit prefills its tail through a chunk program, and "
+            "this model's module has no paged_prefill_chunk",
+            "this model's module has no paged_prefill_chunk: no program "
+            "of its attends a prompt chunk against the latent pool",
+            "chunks chained on the device have no test over a latent "
+            "pool",
             "the batched verify attends the pool with the K/V verify "
             "kernel")),
     "state": _PoolKind(
@@ -291,7 +311,8 @@ class ContinuousBatchingServer:
         # slot): what a kind cannot honour is refused here by name
         self._pool_kind = _POOL_KINDS[getattr(engine.model_config,
                                               "cache_kind", "kv")]
-        self._pool_kind.refuse(engine.config, draft_engine, handoff_import)
+        self._pool_kind.refuse(engine.config, draft_engine, handoff_import,
+                               model_family(engine.model_config))
         # supervised = this server is ONE REPLICA under a ServingFrontend
         # (inference/frontend.py): the frontend owns the scrape port and
         # installs its own heartbeat watchdog on self.watchdog, so the
@@ -533,6 +554,18 @@ class ContinuousBatchingServer:
                  "tokens through the paged trunk; non-final chunks "
                  "observe the dispatch interval — they no longer "
                  "force a fetch)")
+        self._c_prompt_tokens = {
+            source: reg.counter(
+                "serve_prompt_tokens_total", labels={"source": source},
+                help="prompt tokens of chunked admissions: served from "
+                     "cached prefix blocks (no prefill compute) or "
+                     "prefilled by chunk programs")
+            for source in ("cached", "prefilled")}
+        self._c_chunk_rows = reg.counter(
+            "serve_prefill_chunk_rows_total",
+            help="query rows of the chunk programs executed "
+                 "(prefill_chunk_tokens a program, padding included); "
+                 "serve_prefill_chunk_seconds counts the programs")
         self._c_tail_reclaimed = reg.counter(
             "serve_tail_blocks_reclaimed_total",
             help="reserved-but-never-written tail blocks returned to "
@@ -1508,14 +1541,14 @@ class ContinuousBatchingServer:
         an all-null block table, so interleaved decode appends land in
         the null block until the next admission repopulates the row."""
         self._cache = self._cache.replace(
-            lengths=self._cache.lengths.at[slot].set(0))
+            lengths=_set_row(self._cache.lengths, slot, 0))
         self._set_block_row(slot, ())
         if self._draft_cache is not None:
             # the draft pool mirrors the target's tables at each use; a
             # vacated slot only needs its length zeroed so stale draft
             # KV can never be read as live context
             self._draft_cache = self._draft_cache.replace(
-                lengths=self._draft_cache.lengths.at[slot].set(0))
+                lengths=_set_row(self._draft_cache.lengths, slot, 0))
         # every slot-vacating path (retire / cancel / preempt / fault)
         # runs through here — drop its lookup state with it
         self._spec_hist.pop(slot, None)
@@ -1529,8 +1562,7 @@ class ContinuousBatchingServer:
         row = np.zeros((self.max_blocks_per_slot,), np.int32)
         row[:len(blocks)] = blocks
         self._cache = self._cache.replace(
-            block_tables=self._cache.block_tables.at[slot].set(
-                jnp.asarray(row)))
+            block_tables=_set_row(self._cache.block_tables, slot, row))
 
     def _drop_prefill_job(self, slot: int) -> None:
         """Forget any in-flight chunked prefill for a vacated slot."""
@@ -1862,6 +1894,11 @@ class ContinuousBatchingServer:
         slot, state = victim
         if state.request.priority >= head.priority:
             return False
+        if self._prefilling and self._prefilling[0]["slot"] != slot:
+            # nobody moves in behind a prefill in flight (_admit): a
+            # resident evicted now would free a slot the head cannot
+            # take yet. Only the job itself may make way for it
+            return False
         self._preempt_slot(slot, finished)
         return True
 
@@ -1873,8 +1910,16 @@ class ContinuousBatchingServer:
         (prefill_chunk_tokens / prefix caching) only claims the slot and
         installs its block table here; the prefill itself runs one
         fixed-size chunk per ``step()`` via :meth:`_run_prefill_chunk`,
-        so a long prompt never stalls the resident decoders."""
+        so a long prompt never stalls the resident decoders. Chunks
+        (chained or not) are the OLDEST job's, so a request admitted
+        behind a prefill in flight would only hold its slot and blocks
+        while it waits its turn: it stays queued instead, and where it
+        shares a prefix with the job ahead it hits the blocks that job
+        published (requests of one tenant queued together prefill their
+        shared context once, not side by side)."""
         while True:
+            if self._prefilling:
+                return
             now = self._clock() if self._deadlines else None
             swaps0 = (self.scheduler.allocator.swap_ins
                       if self._ledger is not None else 0)
@@ -1944,6 +1989,9 @@ class ContinuousBatchingServer:
             if self.chunk_tokens:
                 cached_len = state.cached_blocks * self.block_size
                 self._prefix_tokens_skipped += cached_len
+                self._c_prompt_tokens["cached"].inc(cached_len)
+                self._c_prompt_tokens["prefilled"].inc(
+                    len(sched_prompt) - cached_len)
                 # pin the slot's live length at the cached boundary NOW:
                 # decode steps that run before (or between) this slot's
                 # chunks append their masked garbage token at
@@ -1951,7 +1999,7 @@ class ContinuousBatchingServer:
                 # position the coming chunk overwrites, never offset 0
                 # of a (possibly shared) prefix block
                 self._cache = self._cache.replace(
-                    lengths=self._cache.lengths.at[slot].set(cached_len))
+                    lengths=_set_row(self._cache.lengths, slot, cached_len))
                 self._prefilling.append(
                     {"slot": slot, "state": state, "start": cached_len})
                 self._mid_prefill.add(slot)
@@ -2071,6 +2119,7 @@ class ContinuousBatchingServer:
                 jnp.int32(slot))
             self._prefill_chunks += 1
             self._prefill_token_units += C
+            self._c_chunk_rows.inc(C)
             if self._ledger is not None:
                 self._ledger.add_weight(req.request_id, C)
             job["start"] = start + C
@@ -2298,7 +2347,9 @@ class ContinuousBatchingServer:
         ``inference.async_loop`` off runs at lag 0: it commits whatever
         is in flight first, so admission, chunk scheduling, preemption,
         shedding and fault injection act on committed state, and it
-        commits the program it dispatches before it returns. Any other
+        commits the program it dispatches before it returns (but for a
+        step whose chunk finished a prefill and left nothing the next
+        step could act on: its decode starts the next chain). Any other
         step — an empty queue, or a backlog waiting behind full slots —
         runs at ``max_commit_lag``: it dispatches step N+1 chained
         from step N's device-resident outputs and commits only the
@@ -2360,8 +2411,30 @@ class ContinuousBatchingServer:
             # "steps with work waiting")
             self._check_swap_thrash()
         sp.mark("admission")     # at lag > 0: the reap/shed checks above
+        before = self._prefill_chunks
+        start = self._prefilling[0]["start"] if self._prefilling else None
         self._run_prefill_chunk(finished, sp)   # none in flight at lag > 0
-        sp.mark("prefill_chunk")
+        ran = self._prefill_chunks - before
+        # a step that ran chunks names their program on the phase's
+        # span, with the first chunk's position and how many ran
+        if ran:
+            sp.mark("prefill_chunk", program=self._chunk_jit.name,
+                    note={"start": start, "chunks": ran,
+                          "rows": self.chunk_tokens})
+        else:
+            sp.mark("prefill_chunk")
+        if ran and lag == 0 and not self._host_can_act():
+            # the prefill this step's chunk finished was its host
+            # action, and nothing is left that the NEXT step could act
+            # on (no prefill in flight, no free slot an eligible request
+            # could take): the decode behind the final chunk starts the
+            # chain that step would have started, and that step commits
+            # it. The commit and the caller's work between two steps (a
+            # 33k-token prompt queued and hashed) then run beside a
+            # program in flight, not beside an idle device; a refill
+            # with the prompt prefilled inside admission keeps its
+            # committed round (docs/serving.md "Async dispatch loop")
+            lag = self._max_lag
         if not self.scheduler.slots:
             if self._inflight:
                 # every resident retired at the last commit; the steps

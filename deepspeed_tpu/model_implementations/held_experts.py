@@ -5,7 +5,8 @@ experts (``held = (lo, hi)``).
 
 The family routes (scores, selection and weights are its own: a softmax
 with zero-compute experts, a sigmoid with normalised weights and a
-shared expert); what is here is everything after the picks: the picks
+shared expert, the same under a group limit: :func:`group_limited_top_k`);
+what is here is everything after the picks: the picks
 that landed on a held expert sorted by expert, a grouped matmul
 (``jax.lax.ragged_dot``; a Mosaic kernel on a TPU) over those rows only,
 the weighted sum back to tokens, and the routing counters. Picks on
@@ -16,6 +17,8 @@ stands in for the other chips. The scopes (``moe_dispatch``,
 Shared code: it imports no model.
 """
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -67,6 +70,25 @@ def counter_series(reg, num_held: int, programs) -> list:
             for x in range(num_held)
         ] + [tail[name] for name in COUNTER_TAIL]
     return [series(program) for program in programs]
+
+
+def group_limited_top_k(choice, k: int, n_group: int, topk_group: int):
+    """Group-limited (node-limited) selection, a routing rule that comes
+    before :func:`sort_picks`: ``choice [T, R]`` float32 (scores plus the
+    selection bias) -> picks ``[T, k]``. The ``R`` outputs are
+    ``n_group`` groups of consecutive experts; a group's score is the sum
+    of its two largest entries, the ``topk_group`` best groups are kept
+    and the ``k`` largest entries among THEIR experts are the picks. Ties
+    go to the lower group and the lower expert."""
+    T, R = choice.shape
+    per = R // n_group
+    group_score = jnp.sum(jax.lax.top_k(
+        choice.reshape(T, n_group, per), 2)[0], axis=-1)     # [T, n_group]
+    _, keep = jax.lax.top_k(group_score, topk_group)
+    kept = jnp.any(keep[:, :, None] == jnp.arange(n_group)[None, None],
+                   axis=1)                                   # [T, n_group]
+    return jax.lax.top_k(jnp.where(jnp.repeat(kept, per, axis=1), choice,
+                                   -jnp.inf), k)[1]
 
 
 @scoped("moe_dispatch")
@@ -147,6 +169,16 @@ def fast_rows(T: int, k: int) -> int:
     computes whole tiles, so a small buffer is what keeps its work near
     the landed picks; ``T k`` rows stay the exact fallback."""
     return min(T * k, max(128, T // 2))
+
+
+def expected_rows(T: int, k: int, share: float) -> int:
+    """Rows for the expert matmul of a family whose picks land on the
+    held share evenly (a pick in ``1 / share``): what ``T`` tokens'
+    ``k`` picks land there plus six standard deviations, in whole tiles
+    of 128; the rare step with more takes the exact ``T k`` fallback."""
+    picks = T * k
+    landed = picks * share + 6.0 * math.sqrt(picks * share * (1 - share))
+    return min(picks, 128 * max(1, math.ceil(landed / 128)))
 
 
 def held_experts_part(u, order, where, held, weights, group_sizes, ex,

@@ -25,7 +25,8 @@ reached by the kind of cache they are handed) run:
   a token an attention (``kv_cache.LatentPagedCache``). Prefill attends
   in the materialised form (K and V per head, flash kernel on a TPU);
   decode in the absorbed form over the latent pool
-  (``ops/pallas/latent_decode_attention.py``).
+  (``ops/pallas/latent_decode_attention.py``); both are
+  ``latent_attention.py``'s, shared with every latent family.
 
 * **the expert layer holds a share**: it routes over ALL router outputs
   (real and zero-compute experts) in float32, computes the real experts
@@ -70,12 +71,10 @@ from deepspeed_tpu.inference.kv_cache import (LatentPagedCache,
                                               latent_write_prompt,
                                               paged_advance)
 from deepspeed_tpu.model_implementations import held_experts as _held
-from deepspeed_tpu.ops.pallas import latent_decode_attention as _latent
-from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+from deepspeed_tpu.model_implementations import latent_attention as _mla
 from deepspeed_tpu.profiling.trace import scoped
 
 F32 = jnp.float32
-NEG_INF = -1e30
 
 # what this model keeps in LatentPagedCache.aux: routing counters
 # ``[program, column]``, the picks on each held expert first, then these
@@ -357,11 +356,10 @@ def init_params(rng: jax.Array, cfg: LongcatFlashConfig) -> Dict:
 
 # ------------------------------------------------------------------ math
 
-@scoped("ln")
-def _rms(x, g, eps):
-    xf = x.astype(F32)
-    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)
-    return (y * g.astype(F32)).astype(x.dtype)
+_rms = _mla.rms
+_materialised_attention = _mla.materialised_attention
+_absorbed_attention = _mla.absorbed_attention
+_attn_out = _mla.attn_out
 
 
 def _rope(x, positions, theta):
@@ -371,75 +369,11 @@ def _rope(x, positions, theta):
     return apply_rotary(x, positions, x.shape[-1], theta, True)
 
 
-@scoped("mla_qkv")
 def _mla_project(h, a, cfg: LongcatFlashConfig, positions):
-    """``h [..., E]`` -> ``q_nope [..., H, Dn]``, ``q_rope [..., H, Dr]``
-    (rotated) and the row to cache ``[c_kv ; k_rope] [..., Rkv + Dr]``."""
-    dt = h.dtype
-    Dn, Rkv = cfg.qk_nope_head_dim, cfg.kv_lora_rank
-    c_q = _rms(h @ a["wq_a"].astype(dt), a["q_norm"], cfg.rms_norm_eps)
-    q = jnp.einsum("...r,rhd->...hd", c_q, a["wq_b"].astype(dt))
-    q = q * jnp.asarray(cfg.q_latent_scale, dt)
-    kv = h @ a["wkv_a"].astype(dt)
-    c_kv = _rms(kv[..., :Rkv], a["kv_norm"], cfg.rms_norm_eps)
-    c_kv = c_kv * jnp.asarray(cfg.kv_latent_scale, dt)
-    k_rope = _rope(kv[..., None, Rkv:], positions, cfg.rope_theta)[..., 0, :]
-    q_rope = _rope(q[..., Dn:], positions, cfg.rope_theta)
-    return q[..., :Dn], q_rope, jnp.concatenate([c_kv, k_rope], -1)
-
-
-@scoped("mla_attn")
-def _materialised_attention(q_nope, q_rope, rows, a,
-                            cfg: LongcatFlashConfig):
-    """Causal attention of a whole sequence against its own rows, K and V
-    built per head from the latent: ``q_* [B, T, H, .]``, ``rows [B, T,
-    W]`` -> ``[B, T, H, Dv]``. On a TPU the flash kernel (QK width Dn +
-    Dr = 192; V is padded with zero columns to that width, which the
-    kernel asks for, and the padding cut off its output)."""
-    B, T, H, Dn = q_nope.shape
-    Rkv, Dv = cfg.kv_lora_rank, cfg.v_head_dim
-    dt = q_nope.dtype
-    c_kv = rows[..., :Rkv]
-    k = jnp.concatenate(
-        [jnp.einsum("btr,rhd->bthd", c_kv, a["wk_b"].astype(dt)),
-         jnp.broadcast_to(rows[:, :, None, Rkv:],
-                          (B, T, H, rows.shape[-1] - Rkv))], -1)
-    q = jnp.concatenate([q_nope, q_rope], -1)
-    v = jnp.einsum("btr,rhd->bthd", c_kv, a["wv_b"].astype(dt))
-    if jax.default_backend() == "tpu" and T >= 128 and T % 128 == 0:
-        pad = q.shape[-1] - Dv
-        vp = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, pad)))
-        return flash_attention(q, k, vp, causal=True,
-                               scale=cfg.attn_scale)[..., :Dv]
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                   preferred_element_type=F32) * cfg.attn_scale
-    s = jnp.where(jnp.tril(jnp.ones((T, T), bool))[None, None], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", p.astype(dt), v)
-
-
-@scoped("mla_attn")
-def _absorbed_attention(q_nope, q_rope, pool, block_tables, live, a,
-                        cfg: LongcatFlashConfig):
-    """One token a slot against the latent pool, absorbed form: ``q_*
-    [S, H, .]`` -> ``[S, H, Dv]``. The query goes into the latent space
-    through ``wk_b``, attends whole rows, and the latent output comes
-    back through ``wv_b``."""
-    Rkv = cfg.kv_lora_rank
-    dt = q_nope.dtype
-    q_lat = jnp.einsum("shd,rhd->shr", q_nope, a["wk_b"].astype(dt))
-    q = jnp.concatenate([q_lat, q_rope], -1)              # [S, H, W]
-    attend = (_latent.paged_latent_decode_attention
-              if jax.default_backend() == "tpu" else
-              _latent.paged_latent_decode_attention_reference)
-    o_lat = attend(q, pool, block_tables, live, value_dim=Rkv,
-                   scale=cfg.attn_scale)
-    return jnp.einsum("shr,rhd->shd", o_lat, a["wv_b"].astype(dt))
-
-
-@scoped("attn_out")
-def _attn_out(o, a):
-    return jnp.einsum("...hd,hde->...e", o, a["wo"].astype(o.dtype))
+    """Latent attention's projections (``latent_attention.project``)
+    under this model's rotary: plain, interleaved pairs."""
+    return _mla.project(h, a, cfg, positions,
+                        functools.partial(_rope, theta=cfg.rope_theta))
 
 
 @scoped("dense_ffn")

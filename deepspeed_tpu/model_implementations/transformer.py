@@ -60,9 +60,11 @@ def model_family(cfg):
     own decoder. A configuration of another architecture names its
     module (``cfg.family``; it has ``init_params`` and this file's
     ``paged_prefill`` / ``paged_decode_step`` / ``causal_forward`` under
-    the same contracts), and the entry points below hand over to it; no
-    model is imported here by name. The entry points such a module does
-    not have (a dense cache, a draft chunk, a prompt chunk) say so."""
+    the same contracts, and ``paged_prefill_chunk`` where a prompt chunk
+    can attend its kind of pool), and the entry points below hand over
+    to it; no model is imported here by name. The entry points such a
+    module does not have (a dense cache, a draft chunk, a batched
+    verify; a prompt chunk, for a family without one) say so."""
     name = getattr(cfg, "family", None)
     return importlib.import_module(name) if name else None
 
@@ -72,7 +74,8 @@ def _own_decoder_only(cfg, what: str) -> None:
         raise NotImplementedError(
             f"{what} is not implemented for a {type(cfg).__name__} "
             f"model ({cfg.family}): it is served through "
-            "ContinuousBatchingServer (monolithic paged prefill + paged "
+            "ContinuousBatchingServer (paged prefill, monolithic or by "
+            "chunks where its module has paged_prefill_chunk, + paged "
             "decode) and scored through InferenceEngine.forward")
 
 
@@ -1046,6 +1049,10 @@ def paged_prefill_chunk(params, cfg: InferenceTransformerConfig,
     return the chunk-tail row, which the caller discards. Chunk
     right-pad past ``length`` lands as masked garbage, overwritten by
     the first decode appends — the standard bucket-padding invariant."""
+    family = model_family(cfg)
+    if hasattr(family, "paged_prefill_chunk"):
+        return family.paged_prefill_chunk(params, cfg, input_ids, start,
+                                          length, cache, slot, mesh=mesh)
     _own_decoder_only(cfg, "paged_prefill_chunk (chunked prefill)")
     if cfg.seq_shard_kv:
         raise NotImplementedError(

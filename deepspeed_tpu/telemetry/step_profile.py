@@ -155,7 +155,8 @@ class _NullStepHandle:
 
     def mark(self, phase: str, now: Optional[float] = None,
              dispatch: bool = False, fetch: bool = False,
-             program: Optional[str] = None) -> None:
+             program: Optional[str] = None,
+             note: Optional[dict] = None) -> None:
         return None
 
     def flush_span(self, t0: float, reason: str, programs: int) -> None:
@@ -252,14 +253,16 @@ class _StepHandle:
 
     def mark(self, phase: str, now: Optional[float] = None,
              dispatch: bool = False, fetch: bool = False,
-             program: Optional[str] = None) -> float:
+             program: Optional[str] = None,
+             note: Optional[dict] = None) -> float:
         """Close the interval since the previous mark and attribute it
         to ``phase``. ``dispatch=True`` flags this boundary as a device
         program dispatch (the dispatch gap is observed against the last
         fetch); ``fetch=True`` flags it as a result-fetch completion
         (the device went idle here). ``program`` names the watched
-        program the interval dispatched or waited on. Returns the
-        boundary time so the caller can reuse the clock read."""
+        program the interval dispatched or waited on; ``note`` is what
+        else the interval's span says of it. Returns the boundary time
+        so the caller can reuse the clock read."""
         prof = self._prof
         if now is None:
             now = prof.clock()
@@ -278,7 +281,7 @@ class _StepHandle:
         attrs = None
         if program is not None:
             self.program = program
-            attrs = {"program": program}
+            attrs = dict(note or (), program=program)
         if phase == "dispatch" and self._dispatch_attrs is not None:
             attrs = dict(self._dispatch_attrs, **(attrs or {}))
             self._dispatch_attrs = None

@@ -444,8 +444,9 @@ def test_backlog_behind_full_slots_pipelines_until_a_retirement(
     with an empty queue. A retirement is found at a lagged commit; the
     NEXT step sees the free slot, flushes the chain, admits into it and
     is not pipelined; the one after (slots full again) starts a new
-    chain. The served tokens equal ``async_loop: false`` token for
-    token. Fake clock."""
+    chain. (Chunked: the step whose chunk FINISHES the refill's prefill,
+    slots full again, starts that chain itself.) The served tokens equal
+    ``async_loop: false`` token for token. Fake clock."""
     srv, prompts = _backlog_server(case, clock=FakeClock(auto=0.001))
     sched = srv.scheduler
     ids = [srv.submit(p, max_new_tokens=b)
@@ -457,7 +458,7 @@ def test_backlog_behind_full_slots_pipelines_until_a_retirement(
                 a["flushes"].get("host_action", 0), len(srv._inflight))
 
     seen = {"lagged_with_backlog": 0, "flush_then_admit": 0,
-            "restart": 0}
+            "restart": 0, "refill_started_chain": 0}
     was_lag0_refill = False
     guard = 0
     while not sched.idle:
@@ -466,8 +467,9 @@ def test_backlog_behind_full_slots_pipelines_until_a_retirement(
         full = not sched._free_slots
         backlog = bool(sched.queue)
         chunking = bool(srv._prefilling)
-        resident0 = set(sched.slots)
+        resident0 = {s.request.request_id for s in sched.slots.values()}
         lagged0, flushes0, depth0 = loop()
+        prefills0 = srv._prefills
         srv.step()
         lagged1, flushes1, depth1 = loop()
         if backlog and full and not chunking:
@@ -484,12 +486,25 @@ def test_backlog_behind_full_slots_pipelines_until_a_retirement(
             was_lag0_refill = False
         elif backlog and not full:
             # a free slot and a waiter: lag 0, flush first, then admit
-            assert lagged1 == lagged0
+            # its round is committed before it returns, unless its
+            # chunk finished the prefill and left the next step nothing
+            # to act on: then its decode starts the next chain
+            starts = int(bool(srv.chunk_tokens)
+                         and srv._prefills > prefills0
+                         and bool(sched.slots)
+                         and not srv._host_can_act())
+            assert lagged1 == lagged0 + starts
             assert flushes1 == flushes0 + (1 if depth0 else 0)
-            assert depth1 == 0
-            assert set(sched.slots) - resident0      # somebody moved in
+            assert depth1 == starts
+            seen["restart"] += starts
+            seen["refill_started_chain"] += starts
+            # somebody moved in (chunked: nobody does while a prefill
+            # is in flight)
+            assert {s.request.request_id for s in sched.slots.values()
+                    } - resident0 or chunking
             seen["flush_then_admit"] += 1 if depth0 else 0
-            was_lag0_refill = not sched._free_slots and bool(sched.queue)
+            was_lag0_refill = (not starts and not sched._free_slots
+                               and bool(sched.queue))
         else:
             was_lag0_refill = False
     out = srv.drain()
@@ -498,6 +513,8 @@ def test_backlog_behind_full_slots_pipelines_until_a_retirement(
     assert seen["flush_then_admit"] >= (2 if case.startswith("decode")
                                         else 1)
     assert seen["restart"] >= 1
+    # only a refill through chunks leaves its decode in flight
+    assert (seen["refill_started_chain"] >= 1) == bool(srv.chunk_tokens)
     assert srv.stats["retraces"] == 0
     ref, _ = _backlog_server(case, async_loop=False)
     rids = [ref.submit(p, max_new_tokens=b)

@@ -492,7 +492,8 @@ def test_famine_ladder_evict_then_preempt_then_shed(fresh_telemetry):
     rb = srv.submit([100 + i % 20 for i in range(65)], max_new_tokens=59)
     srv.step()
     rc = srv.submit([50 + i % 13 for i in range(65)], max_new_tokens=59)
-    srv.step()
+    while srv.scheduler.find_slot(rc) is None:   # admitted once rB's
+        srv.step()                               # prefill is through
     assert first_event_index(ev.PREFIX_EVICT) is not None
     assert srv.scheduler.find_slot(rb) is not None
     assert srv.scheduler.find_slot(rc) is not None

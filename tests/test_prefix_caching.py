@@ -245,6 +245,32 @@ def test_chain_hashes_are_prefix_sensitive():
     assert a[1] != c[0] and len(a) == 2
 
 
+def test_chain_hashes_read_ids_not_their_digits():
+    # ids go in as int64 bytes: [1, 23] and [12, 3] must not collide,
+    # and a list and an array of the same ids hash alike
+    a = prefix_block_hashes([1, 23], 2)
+    assert a != prefix_block_hashes([12, 3], 2)
+    assert a != prefix_block_hashes([123, 0], 2)
+    assert a == prefix_block_hashes(np.array([1, 23], np.int32), 2)
+    assert prefix_block_hashes([1, 23, 5], 2) == a      # full blocks only
+
+
+@pytest.mark.parametrize("caching", [True, False])
+def test_submit_hashes_the_prompt_where_it_arrives(caching):
+    from deepspeed_tpu.inference.scheduler import Request, Scheduler
+    sched = Scheduler(num_slots=2, num_blocks=16, block_size=4,
+                      max_blocks_per_slot=8, max_queued_requests=4,
+                      enable_prefix_caching=caching)
+    req = Request(request_id=1, prompt=list(range(10)), max_new_tokens=4)
+    sched.submit(req)
+    # the step loop's admission only looks the chain up
+    assert (req._hashes is not None) == caching
+    if caching:
+        assert req._hashes == prefix_block_hashes(list(range(10)), 4)
+        slot, state = sched.admit_next()
+        assert state.prompt_hashes is req._hashes
+
+
 # ------------------------------------------------------------ server
 
 def test_fully_aligned_prompt_still_prefills_last_token():

@@ -508,6 +508,8 @@ def _run_scenario(telemetry_overrides=None, spec=0):
            srv.submit(prefix + [5, 9] * 6, max_new_tokens=16)]
     for _ in range(3):
         srv.step()
+    while srv.scheduler.queue:    # the second waits for the shared
+        srv.step()                # prefix the first is prefilling
     # strictly higher priority on a full pool -> preemption ladder
     ids.append(srv.submit([2, 4, 6, 8] * 8, max_new_tokens=24,
                           priority=5))

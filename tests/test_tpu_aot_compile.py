@@ -147,6 +147,29 @@ def _latent_decode():
                 ((g["slots"], MB), jnp.int32), ((g["slots"],), jnp.int32)]
 
 
+# serve-gigachat3-ep16-shared-context-batch's own geometry: 48 slots of
+# 268 blocks over a pool of 2560 and the null block, chunks of 1024 rows,
+# 64 heads of 128 + 64 wide keys and 192-wide values over 512 + 64 rows
+GIGACHAT = dict(slots=48, blocks=2561, table=268, chunk=1024, heads=64,
+                width=576, rank=512, nope=128, v_dim=192)
+
+
+def _latent_chunk():
+    """The prompt-chunk kernel at the GigaChat cell's geometry: q ``[H,
+    C, Dn + Dr]``, one attention's pool ``[NB, W, BS]``, the slot's
+    table, start, W_kb ``[H, Dn, R]``, W_vb ``[H, Dv, R]``."""
+    from deepspeed_tpu.ops.pallas import latent_chunk_attention as lca
+    g = GIGACHAT
+    fn = functools.partial(lca.latent_chunk_attention, scale=0.1,
+                           interpret=False)
+    return fn, [((g["heads"], g["chunk"], g["width"] - g["rank"]
+                  + g["nope"]), BF16),
+                ((g["blocks"], g["width"], BS), BF16),
+                ((g["table"],), jnp.int32), ((), jnp.int32),
+                ((g["heads"], g["nope"], g["rank"]), BF16),
+                ((g["heads"], g["v_dim"], g["rank"]), BF16)]
+
+
 # serve-laguna-xs2-ep8-mixed-context-batch's own geometry: 96 slots, a
 # 640-row ring a slot (5 blocks) of 8 kv heads x 128, groups of 8 query
 # heads on the window layers; prompts to 8192 tokens
@@ -180,6 +203,7 @@ CASES = {
     "paged_window_decode-ring": _window_decode,
     "flash-window-fwd": _flash_window,
     "latent-decode": _latent_decode,
+    "latent-chunk": _latent_chunk,
     "retention_decode": functools.partial(_retention, "decode"),
     "retention_prefill": functools.partial(_retention, "prefill"),
     "decode": _dense_decode,
@@ -252,6 +276,9 @@ def _copies(text, is_big, pool_dims=None, kernels=()):
         name, dtype, dims, op = m.groups()
         dims = tuple(int(d) for d in dims.split(",") if d)
         if (op in _FREE or name in kernels
+                # a matmul fusion's operand re-typed in place: the
+                # compiler names such a computation itself
+                or "calls=%bitcast_fusion" in line
                 or not is_big(dims, math.prod(dims) * _ITEMSIZE[dtype])
                 or (op in _IN_PLACE and dims == pool_dims)):
             continue
@@ -462,6 +489,80 @@ def test_latent_decode_program_walks_the_pool_where_it_lies(chips,
     assert not _copies(
         text, lambda dims, nbytes: nbytes >= pool_bytes
         or sorted(dims[-2:]) == sorted(pool.shape[-2:]), kernels=kernels)
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+
+
+@pytest.mark.parametrize("kind", ["decode", "chunk"])
+def test_gigachat_programs_read_the_latent_pool_where_it_lies(
+        chips, monkeypatch, kind):
+    """GigaChat3's ``serve_decode`` and ``serve_prefill_chunk`` at the
+    cell's widths, slots, pool, table and chunk (one dense and one
+    expert layer of its six, a small vocabulary, two held experts), read
+    back from their compiled text: module, kernel and scope names; one
+    attention kernel an attention (``paged_latent_decode_attention`` /
+    ``latent_chunk_attention``); and apart from the kernels' calls and
+    the in-place writes of the donated pool nothing writes as much as
+    one attention's pool, nothing converts a pool's layout, and nothing
+    is as large as the K or V of a slot's whole context."""
+    from deepspeed_tpu.inference.kv_cache import init_latent_paged_cache
+    from deepspeed_tpu.inference.server import ContinuousBatchingServer as Srv
+    from deepspeed_tpu.model_implementations import deepseek_v3 as dv
+    from deepspeed_tpu.ops.pallas import latent_chunk_attention as lca
+    from deepspeed_tpu.ops.pallas import latent_decode_attention as lda
+    from deepspeed_tpu.telemetry import compile_watch
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(chips[0])
+    g = GIGACHAT
+    cfg = dv.DeepseekV3Config(
+        vocab_size=2048, num_hidden_layers=2, first_k_dense_replace=1,
+        num_attention_heads=g["heads"], v_head_dim=g["v_dim"],
+        rope_theta=100000.0, rope_factor=64.0, experts_held=(0, 2))
+    assert (cfg.latent_width, cfg.kv_lora_rank, cfg.qk_nope_head_dim) == (
+        g["width"], g["rank"], g["nope"])
+    abstract = functools.partial(_abstract, sharding=one)
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    params = abstract(jax.eval_shape(
+        lambda: dv.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = abstract(jax.eval_shape(lambda: init_latent_paged_cache(
+        cfg.attentions, g["slots"], g["blocks"], BS, g["table"],
+        cfg.latent_width, aux_shape=cfg.aux_shape)))
+    fn, name, args, kernel = {
+        "decode": (Srv._decode_fn, "serve_decode",
+                   (params, arr((g["slots"],)), cache,
+                    arr((g["slots"],), jnp.bool_)), lda.NAME),
+        "chunk": (Srv._chunk_fn, "serve_prefill_chunk",
+                  (params, arr((1, g["chunk"])), arr(()), arr((1,)), cache,
+                   arr(())), lca.NAME)}[kind]
+    compiled = jax.jit(compile_watch._named(
+        functools.partial(fn, cfg=cfg, mesh=None), name),
+        donate_argnames=("cache",)).lower(*args).compile()
+    text = compiled.as_text()
+    assert f"HloModule jit_{name}" in text
+    scopes, kernels = compile_watch.parse_scopes(text)
+    ours = {k: v for k, v in kernels.items()
+            if v.startswith(("paged_latent", "latent_"))}
+    assert sorted(v for v in ours.values() if v == kernel) == (
+        [kernel] * cfg.attentions)
+    assert all(scopes[k].rsplit("/", 1)[-1] == "mla_attn"
+               for k, v in ours.items() if v == kernel)
+    innermost = {v.rsplit("/", 1)[-1] for v in scopes.values() if v}
+    assert innermost >= LATENT_SCOPES | {"moe_shared"}, (
+        LATENT_SCOPES - innermost)
+    pool = cache.rows[0]
+    pool_bytes = math.prod(pool.shape) * pool.dtype.itemsize
+    # K (or V) of one slot's whole context, every head, bfloat16, is
+    # larger still than one attention's pool: one bound holds both
+    assert g["table"] * BS * g["heads"] * g["nope"] * 2 > pool_bytes
+    # (a chunk's own rows, 8 blocks of them, are turned to the pool's
+    # layout before they are written: that is no pool's conversion)
+    own = g["chunk"] // BS
+    assert not _copies(
+        text, lambda dims, nbytes: nbytes >= pool_bytes
+        or (sorted(dims[-2:]) == sorted(pool.shape[-2:])
+            and math.prod(dims[:-2]) > own),
+        pool_dims=pool.shape, kernels=kernels)
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
 
 
