@@ -8,16 +8,44 @@ recurrence, accumulating in fp32 — O(T) memory, MXU-shaped [128, D] matmuls.
 
 Layout: q ``[B, T, H, D]``; k/v may carry fewer heads (``[B, T, HKV, D]``,
 HKV | H — grouped-query attention without materializing repeated k/v).
-The kernel works on ``[B*H, T, D]`` q with a (kv-head, group, q-block)
-grid whose group axis revisits each K/V block, so one kv head streams
-through VMEM once for its whole query group. K/V for one batch-head live
-whole in VMEM (T·D·2B·2 ≤ ~8 MB ⇒ T ≤ 16k at D=128, independent of the
-group size — longer sequences shard over the ``seq`` axis via ring
-attention, see ops/ring_attention.py).
+The kernels work on ``[B*H, T, D]`` q with a (kv-head, group) grid: ONE
+GRID STEP IS ONE QUERY HEAD, whole in VMEM with K and V of its kv head
+(the group axis is the fastest, so a kv head streams in once for its
+whole query group), and the sweep over its ``[BQ, BK]`` score blocks is
+code inside the step. The wrapper counts the step's bytes: a head within
+the 16 MiB every kernel gets asks for nothing, a longer one for what it
+counts, and one that does not fit the chip is refused (T <= 32k forward,
+16k backward at D=128 in bf16 — longer sequences shard over the ``seq``
+axis via ring attention, see ops/ring_attention.py).
 
-Backward is the standard two-kernel flash decomposition (dQ sweep over K
-blocks; dK/dV sweep over Q blocks) wired through ``jax.custom_vjp`` with the
-(out, logsumexp) residuals.
+What a score block costs is set by how much of it the scheduler can
+overlap, not by the products: a block is a chain (scores -> exponentials
+-> second product -> accumulate), and a loop runs the chains one after
+another. So a head of up to ``_UNROLL_BLOCKS`` blocks (T <= 2048 at the
+default blocks) is written out block by block, with static slices and
+masks, and the scheduler interleaves one block's products with its
+neighbour's vector work: 2.5 x the looped form on the chip at T 1024
+(PERF.md section 6, PR 46). A longer head loops over its q (k) blocks;
+inside one, a row whose bounds are static (no mask) and that is itself
+at most ``_UNROLL_BLOCKS`` long is still written out (81-89 % of peak at
+T 4096 / 8192 against 53-55 % in groups), any other loops over groups of
+``_GROUP`` score blocks written out, then over the blocks left over.
+
+Forward: queries down, keys across (``s [BQ, BK]``, both products plain
+for the MXU); the row logsumexp is turned once a q block and stored with
+positions on the lanes, ``[B*H, T/BQ, BQ]``. Backward: ONE kernel (the
+``custom_vjp`` residuals are (q, k, v, out, logsumexp)). For a K block it
+sweeps the visible Q blocks once, keys DOWN and queries ACROSS (``s``,
+``p``, ``dp``, ``ds`` are ``[BK, BQ]``), so that ``lse`` and ``delta`` are
+one lane-dense row a q block, broadcast down the sublanes, and ``dv += p
+do`` and ``dk += ds q`` are plain products; only ``dq += ds^T k`` turns an
+operand. Five products and one exponential pass a block where the
+two-kernel form (a dQ sweep and a dK/dV sweep, each rebuilding ``s``,
+``p``, ``dp``, ``ds``) took seven and two, two of them turned.
+
+The windowed forward (``window=``, serving prefill of a sliding-window
+layer) is the forward's score block (:func:`_fwd_block`, one more term in
+its mask) under its own (kv-head, group, q-block) grid, a loop a q block.
 """
 from __future__ import annotations
 
@@ -32,6 +60,18 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_BLOCK_Q = 256
 DEFAULT_BLOCK_K = 256
 NEG_INF = -1e30
+# what a call may ask of VMEM (128 MiB on the chip; a call that asks for
+# nothing gets 16 MiB), and what of it is left to a step's own values
+# (score blocks, carries) beside the blocks and scratch the wrapper counts
+_VMEM_LIMIT = 96 * 1024 * 1024
+_VMEM_DEFAULT = 16 * 1024 * 1024
+_VMEM_WORKING = 8 * 1024 * 1024
+# no straight-line body holds more score blocks than this: a head of at
+# most so many is written out whole (causal T <= 2048 at the default
+# blocks: 36), a longer one loops over its q (k) blocks, an unmasked row
+# of at most so many written out inside (T <= 16k), else in groups
+_UNROLL_BLOCKS = 64
+_GROUP = 4
 
 
 def effective_block(block: int, seq: int) -> int:
@@ -58,329 +98,388 @@ def _should_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+_NT = (((1,), (1,)), ((), ()))  # a @ b^T: both operands contract their lanes
+_TN = (((0,), (0,)), ((), ()))  # a^T @ b: both contract their rows
+
+
+# ---------------------------------------------------------------------------
+# a head's sweep over its score blocks: written out, or looped
+# ---------------------------------------------------------------------------
+
+
+def _sweep(lo, hi, body, carry=(), *, static: bool, group: int = 1):
+    """``carry = body(i, carry)`` for i in [lo, hi): straight-line code
+    where ``static`` (the bounds are Python ints then), else a loop over
+    groups of ``group`` steps written out, then the steps left over one
+    by one: the scheduler overlaps one block's products with its
+    neighbour's vector work only inside straight-line code."""
+    if static:
+        for i in range(lo, hi):
+            carry = body(i, carry)
+        return carry
+    if group == 1:
+        return jax.lax.fori_loop(lo, hi, body, carry)
+    groups = (hi - lo) // group
+
+    def grouped(gi, carry):
+        for j in range(group):
+            carry = body(lo + gi * group + j, carry)
+        return carry
+    carry = jax.lax.fori_loop(0, groups, grouped, carry)
+    return jax.lax.fori_loop(lo + groups * group, hi, body, carry)
+
+
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
+def _fwd_block(qs, k_ref, v_ref, kb, block_k: int, carry, q0=None,
+               window: int | None = None):
+    """One ``[BQ, BK]`` score block of the online softmax, queries down
+    and keys across (both products plain for the MXU): ``carry`` is (m,
+    l, acc) of the q block ``qs [BQ, D]``, ``kb`` the K block. ``q0`` is
+    the first query's position where the block needs its mask (the
+    diagonal; with ``window`` also the window's lower edge: row ``i``
+    sees keys ``i - window < j <= i``), None where every query sees
+    every key."""
+    m, l, acc = carry
+    cols = pl.ds(kb * block_k, block_k)
+    k = k_ref[0, cols, :]
+    v = v_ref[0, cols, :]
+    s = jax.lax.dot_general(qs, k, _NT, preferred_element_type=jnp.float32)
+    if q0 is not None:
+        row = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        col = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        seen = row >= col
+        if window is not None:
+            seen = jnp.logical_and(seen, row - col < window)
+        s = jnp.where(seen, s, NEG_INF)
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m - m_new)
+    l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_new = acc * alpha + jnp.dot(p.astype(qs.dtype), v,
+                                    preferred_element_type=jnp.float32)
+    return m_new, l_new, acc_new
+
+
+def _fwd_carry(block_q: int, d: int):
+    return (jnp.full((block_q, 1), NEG_INF, jnp.float32),
+            jnp.zeros((block_q, 1), jnp.float32),
+            jnp.zeros((block_q, d), jnp.float32))
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale: float,
-                block_q: int, block_k: int, seq_len: int, causal: bool,
-                window: int | None = None):
-    """``window`` (static; None: the kernel as it always was): query row
-    ``i`` sees keys ``j`` with ``i - window < j <= i``. K blocks wholly
-    before the block's first window are skipped, the blocks a window's
-    lower edge cuts are masked, the ones between them and the diagonal
-    are fully visible."""
+                block_q: int, block_k: int, causal: bool, static: bool):
+    """One grid step is one query head: ``q_ref``/``o_ref [1, T, D]``,
+    ``k_ref``/``v_ref [1, T, D]`` of its kv head, ``lse_ref [1, T/BQ,
+    BQ]``."""
+    seq_len, d = q_ref.shape[1:]
+    num_kb = seq_len // block_k
+    dtype = q_ref.dtype
+    # under a looped q axis an unmasked row of K blocks still has static
+    # bounds: written out if the row alone is short enough
+    row_static = static or (not causal and num_kb <= _UNROLL_BLOCKS)
+
+    def q_block(qi, _):
+        # keep the dot INPUTS in the storage dtype (bf16): the MXU runs
+        # bf16 at full rate and accumulates fp32 via
+        # preferred_element_type; an upfront fp32 cast would quarter the
+        # matmul throughput. The softmax scale is folded into q ONCE
+        # ([BQ, D] mul) instead of into every score block
+        rows = pl.ds(qi * block_q, block_q)
+        qs = (q_ref[0, rows, :].astype(jnp.float32) * scale).astype(dtype)
+        # K blocks strictly below the diagonal are FULLY visible — only
+        # the <= cdiv(bq, bk) diagonal blocks pay the iota/compare/select
+        # passes (for kb < diag_start: (kb+1)*bk <= qi*bq, every key
+        # precedes every query)
+        diag_start = (qi * block_q) // block_k if causal else num_kb
+
+        def block(kb, carry, q0=None):
+            return _fwd_block(qs, k_ref, v_ref, kb, block_k, carry, q0)
+
+        carry = _sweep(0, diag_start, block, _fwd_carry(block_q, d),
+                       static=row_static, group=_GROUP)
+        if causal:
+            carry = _sweep(
+                diag_start, diag_start + pl.cdiv(block_q, block_k),
+                functools.partial(block, q0=qi * block_q), carry,
+                static=static, group=_GROUP)
+        m, l, acc = carry
+        o_ref[0, rows, :] = (acc / l).astype(o_ref.dtype)
+        # the backward reads lse as a ROW (its score blocks have the
+        # queries across): turn the column once a q block
+        lse = jnp.broadcast_to(m + jnp.log(l), (block_q, 128))
+        lse_ref[0, pl.ds(qi, 1), :] = lse.T[:1]
+        return _
+
+    _sweep(0, seq_len // block_q, q_block, static=static)
+
+
+def _score_blocks(seq_len: int, block_q: int, block_k: int,
+                  causal: bool) -> int:
+    """Score blocks a head's sweep visits."""
+    nq, nk = seq_len // block_q, seq_len // block_k
+    if not causal:
+        return nq * nk
+    return sum((qi * block_q) // block_k + pl.cdiv(block_q, block_k)
+               for qi in range(nq))
+
+
+def _vmem_params(nbytes: int, what: str):
+    """Compiler parameters of a whole-head call that holds ``nbytes``
+    of blocks and scratch (the blocks counted twice: the pipeline keeps
+    the next step's beside this one's). A call that fits what every
+    kernel gets asks for no more, and a longer head for what it counts,
+    to the next MiB: a raised limit is a setting of the WHOLE program,
+    and the compiler then lays out the fusions around the kernel
+    differently too."""
+    need = nbytes + _VMEM_WORKING
+    if need > _VMEM_LIMIT:
+        raise ValueError(
+            f"flash_attention: {what} keeps {nbytes / 2**20:.0f} MB of one "
+            f"head in VMEM, over {(_VMEM_LIMIT - _VMEM_WORKING) / 2**20:.0f} "
+            "MB: shard the sequence (ops/ring_attention.py)")
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=(-(-need // 2**20) * 2**20
+                          if need > _VMEM_DEFAULT else None))
+
+
+def _head_specs(BH: int, BKH: int, T: int, D: int, block_q: int):
+    """Block specs of the (kv-head, group) grid: a query head's ``[1, T,
+    D]``, its statistics' ``[1, T/BQ, BQ]``, its kv head's ``[1, T, D]``."""
+    rep = BH // BKH
+    qmap = lambda bkh, g: (bkh * rep + g, 0, 0)  # noqa: E731
+    return (pl.BlockSpec((1, T, D), qmap),
+            pl.BlockSpec((1, T // block_q, block_q), qmap),
+            pl.BlockSpec((1, T, D), lambda bkh, g: (bkh, 0, 0)))
+
+
+@functools.lru_cache(maxsize=None)
+def _fwd_call(BH: int, BKH: int, T: int, D: int, dtype, scale: float,
+              block_q: int, block_k: int, causal: bool, interpret: bool):
+    """``(q3 [B*H, T, D], k3, v3 [B*HKV, T, D]) -> (o, lse)`` (HKV | H —
+    grouped-query attention streams each K/V head into VMEM ONCE for its
+    whole query group: the grid is (kv-head, group) with the group
+    fastest, so the K/V block index is constant across a group and pallas
+    reloads it only when the kv-head changes). ``lse`` is the row
+    logsumexp ``[B*H, T/BQ, BQ]`` float32: O(BH*T) for the backward,
+    positions on the lanes. Kept per static signature and jitted, because
+    jax traces a call it has seen before from its cache: the 24 layers of
+    a step trace and lower the written-out body once."""
+    head, stat, kv = _head_specs(BH, BKH, T, D, block_q)
+    return jax.jit(pl.pallas_call(
+        functools.partial(
+            _fwd_kernel, scale=scale, block_q=block_q, block_k=block_k,
+            causal=causal,
+            static=_score_blocks(T, block_q, block_k,
+                                 causal) <= _UNROLL_BLOCKS),
+        grid=(BKH, BH // BKH),
+        in_specs=[head, kv, kv],
+        out_specs=[head, stat],
+        out_shape=[jax.ShapeDtypeStruct((BH, T, D), dtype),
+                   jax.ShapeDtypeStruct((BH, T // block_q, block_q),
+                                        jnp.float32)],
+        compiler_params=_vmem_params(
+            2 * 4 * T * D * dtype.itemsize + 2 * T * 4, "the forward"),
+        interpret=interpret,
+        name="flash_attention_fwd"))
+
+
+def _window_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale: float,
+                       block_q: int, block_k: int, window: int):
+    """The windowed forward (serving prefill of a sliding-window layer;
+    grid (kv-head, group, q-block)): query row ``i`` sees keys ``j`` with
+    ``i - window < j <= i``. K blocks wholly before the block's first
+    window are skipped, the blocks a window's lower edge cuts are masked,
+    the ones between them and the diagonal are fully visible."""
     qi = pl.program_id(2)
-    # keep the dot INPUTS in the storage dtype (bf16): the MXU runs bf16
-    # at full rate and accumulates fp32 via preferred_element_type; an
-    # upfront fp32 cast would quarter the matmul throughput
     q = q_ref[0]  # [BQ, D]
-    bq, d = q.shape
-    # fold the softmax scale into q ONCE ([BQ, D] mul) instead of into
-    # every [BQ, BK] score block: the kernel is VPU-bound at small D (the
-    # dots are tiny, the elementwise passes over the score block are not),
-    # so every saved pass over [BQ, BK] is wall-clock
     qs = (q.astype(jnp.float32) * scale).astype(q.dtype)
 
-    m = jnp.full((bq, 1), NEG_INF, jnp.float32)
-    l = jnp.zeros((bq, 1), jnp.float32)
-    acc = jnp.zeros((bq, d), jnp.float32)
+    diag_start = (qi * block_q) // block_k
+    num_kb = diag_start + pl.cdiv(block_q, block_k)
+    # the first row's window starts at qi*bq - window + 1; a block is
+    # inside EVERY row's window from column (last row) - window + 1
+    first_kb = jnp.maximum(qi * block_q - window + 1, 0) // block_k
+    inside = jnp.maximum(qi * block_q + block_q - window, 0)
+    edge_end = jnp.clip((inside + block_k - 1) // block_k, first_kb,
+                        diag_start)
 
-    if causal:
-        # K blocks strictly below the diagonal are FULLY visible — only
-        # the ≤ cdiv(bq, bk) diagonal blocks pay the iota/compare/select
-        # masking passes (for kb < diag_start: (kb+1)·bk ≤ qi·bq, i.e.
-        # every column precedes every row of this q block)
-        diag_start = (qi * block_q) // block_k
-        num_kb = diag_start + pl.cdiv(block_q, block_k)
-    else:
-        diag_start = num_kb = seq_len // block_k
-    first_kb = edge_end = 0
-    if window is not None:
-        # the first row's window starts at qi*bq - window + 1; a block is
-        # inside EVERY row's window from column (last row) - window + 1
-        first_kb = jnp.maximum(qi * block_q - window + 1, 0) // block_k
-        inside = jnp.maximum(qi * block_q + block_q - window, 0)
-        edge_end = jnp.clip((inside + block_k - 1) // block_k, first_kb,
-                            diag_start)
+    def block(kb, carry, q0=None):
+        return _fwd_block(qs, k_ref, v_ref, kb, block_k, carry, q0, window)
 
-    def make_body(masked):
-        def body(kb, carry):
-            m, l, acc = carry
-            k = k_ref[0, pl.ds(kb * block_k, block_k), :]
-            v = v_ref[0, pl.ds(kb * block_k, block_k), :]
-            s = jax.lax.dot_general(qs, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-            if masked:
-                row = qi * block_q + jax.lax.broadcasted_iota(
-                    jnp.int32, (bq, block_k), 0)
-                col = kb * block_k + jax.lax.broadcasted_iota(
-                    jnp.int32, (bq, block_k), 1)
-                seen = row >= col
-                if window is not None:
-                    seen = jnp.logical_and(seen, row - col < window)
-                s = jnp.where(seen, s, NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m - m_new)
-            l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            acc_new = acc * alpha + jax.lax.dot_general(
-                p.astype(q.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            return m_new, l_new, acc_new
-        return body
-
-    carry = (m, l, acc)
-    if window is not None:
-        carry = jax.lax.fori_loop(first_kb, edge_end, make_body(True),
-                                  carry)
-    carry = jax.lax.fori_loop(edge_end, diag_start, make_body(False), carry)
-    if causal:
-        carry = jax.lax.fori_loop(diag_start, num_kb, make_body(True),
-                                  carry)
-    m, l, acc = carry
+    masked = functools.partial(block, q0=qi * block_q)
+    carry = jax.lax.fori_loop(first_kb, edge_end, masked,
+                              _fwd_carry(*q.shape))
+    carry = jax.lax.fori_loop(edge_end, diag_start, block, carry)
+    m, l, acc = jax.lax.fori_loop(diag_start, num_kb, masked, carry)
     o_ref[0] = (acc / l).astype(o_ref.dtype)
     lse_ref[0] = m + jnp.log(l)  # [BQ, 1]
 
 
-def _flash_fwd(q3, k3, v3, *, scale, block_q, block_k, causal, interpret,
-               window=None):
-    """q3 ``[B*H, T, D]``; k3/v3 ``[B*HKV, T, D]`` (HKV | H — grouped-query
-    attention streams each K/V head into VMEM ONCE for its whole query
-    group: grid order is (kv-head, group, q-block) with the q-block axis
-    fastest, so the K/V block index is constant across an entire group and
-    pallas reloads it only when the kv-head changes)."""
+def _flash_window_fwd(q3, k3, v3, *, scale, block_q, block_k, window,
+                      interpret):
+    """The windowed forward's call: K/V of one kv head whole in VMEM, one
+    q block a grid step, the q-block axis fastest."""
     BH, T, D = q3.shape
     BKH = k3.shape[0]
     rep = BH // BKH
-    grid = (BKH, rep, T // block_q)
-    out_shape = [
-        jax.ShapeDtypeStruct(q3.shape, q3.dtype),
-        # trailing singleton lane dim satisfies TPU tiling (block last dim
-        # equals the array dim); keeps lse O(BH·T) instead of the official
-        # kernel's 128-lane broadcast
-        jax.ShapeDtypeStruct((BH, T, 1), jnp.float32),
-    ]
-    kernel = functools.partial(_fwd_kernel, scale=scale, block_q=block_q,
-                               block_k=block_k, seq_len=T, causal=causal,
-                               window=window)
     qmap = lambda bkh, g, qi: (bkh * rep + g, qi, 0)  # noqa: E731
-    o, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), qmap),
-            pl.BlockSpec((1, T, D), lambda bkh, g, qi: (bkh, 0, 0)),
-            pl.BlockSpec((1, T, D), lambda bkh, g, qi: (bkh, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, D), qmap),
-            pl.BlockSpec((1, block_q, 1), qmap),
-        ],
-        out_shape=out_shape,
+    kvmap = lambda bkh, g, qi: (bkh, 0, 0)  # noqa: E731
+    o, _ = pl.pallas_call(
+        functools.partial(_window_fwd_kernel, scale=scale, block_q=block_q,
+                          block_k=block_k, window=window),
+        grid=(BKH, rep, T // block_q),
+        in_specs=[pl.BlockSpec((1, block_q, D), qmap),
+                  pl.BlockSpec((1, T, D), kvmap),
+                  pl.BlockSpec((1, T, D), kvmap)],
+        out_specs=[pl.BlockSpec((1, block_q, D), qmap),
+                   pl.BlockSpec((1, block_q, 1), qmap)],
+        out_shape=[jax.ShapeDtypeStruct(q3.shape, q3.dtype),
+                   jax.ShapeDtypeStruct((BH, T, 1), jnp.float32)],
         interpret=interpret,
-        name=("flash_attention_fwd" if window is None
-              else "flash_attention_window_fwd"),
+        name="flash_attention_window_fwd",
     )(q3, k3, v3)
-    return o, lse
+    return o
 
 
 # ---------------------------------------------------------------------------
 # backward
 # ---------------------------------------------------------------------------
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   *, scale: float, block_q: int, block_k: int,
-                   seq_len: int, causal: bool):
-    qi = pl.program_id(2)
-    # bf16 dot inputs, fp32 accumulation (see _fwd_kernel note)
-    q = q_ref[0]
-    do = do_ref[0]
-    lse = lse_ref[0]  # [BQ, 1]
-    delta = delta_ref[0]  # [BQ, 1]
-    bq, d = q.shape
-    dq = jnp.zeros((bq, d), jnp.float32)
-    # scale folded into q for the score dot (see _fwd_kernel); the dq
-    # accumulation uses raw k and applies scale once at the end, as before
-    qs = (q.astype(jnp.float32) * scale).astype(q.dtype)
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
+                dk_ref, dv_ref, qs_ref, dq_acc, dk_acc, dv_acc, *,
+                scale: float, block_q: int, block_k: int, causal: bool,
+                rep: int, static: bool):
+    """One grid step is one query head, as in the forward; every visible
+    score block is rebuilt ONCE (``s``, ``p``, ``dp``, ``ds``, all ``[BK,
+    BQ]``, keys down) and feeds ``dv += p do``, ``dk += ds q`` and ``dq +=
+    ds^T k``. ``qs_ref [T, D]`` is q with the softmax scale folded in as
+    the forward folded it, so that ``p`` is the forward's ``p`` to the bit
+    (dk accumulates against raw q and dq against raw k, each scaled once
+    at its flush); ``dq_acc [T/BQ, BQ, D]`` float32 holds the head's
+    ``dq`` until the step's end; ``dk_acc``/``dv_acc [T, D]`` float32 add
+    up the query group of a kv head (the group axis is the INNERMOST grid
+    axis, so the dk/dv output block is revisited on consecutive steps and
+    the last group member flushes it)."""
+    g = pl.program_id(1)
+    seq_len = q_ref.shape[1]
+    num_qb, num_kb = seq_len // block_q, seq_len // block_k
+    dtype = q_ref.dtype
+    # as the forward's row_static: an unmasked column of Q blocks
+    col_static = static or (not causal and num_qb <= _UNROLL_BLOCKS)
 
-    if causal:
-        diag_start = (qi * block_q) // block_k
-        num_kb = diag_start + pl.cdiv(block_q, block_k)
-    else:
-        diag_start = num_kb = seq_len // block_k
-
-    def make_body(masked):
-        def body(kb, dq):
-            k = k_ref[0, pl.ds(kb * block_k, block_k), :]
-            v = v_ref[0, pl.ds(kb * block_k, block_k), :]
-            s = jax.lax.dot_general(qs, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-            if masked:
-                row = qi * block_q + jax.lax.broadcasted_iota(
-                    jnp.int32, (bq, block_k), 0)
-                col = kb * block_k + jax.lax.broadcasted_iota(
-                    jnp.int32, (bq, block_k), 1)
-                s = jnp.where(row >= col, s, NEG_INF)
-            p = jnp.exp(s - lse)
-            dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            ds = (p * (dp - delta)).astype(q.dtype)
-            return dq + jax.lax.dot_general(
-                ds, k, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-        return body
-
-    dq = jax.lax.fori_loop(0, diag_start, make_body(False), dq)
-    if causal:
-        dq = jax.lax.fori_loop(diag_start, num_kb, make_body(True), dq)
-    dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
-                    block_q: int, block_k: int, seq_len: int, causal: bool,
-                    rep: int):
-    ki = pl.program_id(1)
-    g = pl.program_id(2)
-    # bf16 dot inputs, fp32 accumulation (see _fwd_kernel note)
-    k = k_ref[0]  # [BK, D]
-    v = v_ref[0]
-    bk, d = k.shape
-
-    # grouped-query attention: this K/V head serves `rep` query heads.
-    # The group axis is the INNERMOST grid dim, so the dk/dv output block
-    # is revisited on consecutive steps: fp32 VMEM scratch accumulates
-    # across the group (q/do blocks stay (1, T, D) — no rep-times VMEM
-    # inflation), and the final group member flushes to the output.
     @pl.when(g == 0)
     def _init():
-        dk_acc[...] = jnp.zeros((bk, d), jnp.float32)
-        dv_acc[...] = jnp.zeros((bk, d), jnp.float32)
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    num_qb = seq_len // block_q
-    if causal:
-        # q blocks split three ways around this k block: before first_qb
-        # nothing is visible (skipped), [first_qb, diag_end) touches the
-        # diagonal (masked), [diag_end, num_qb) is fully visible — the
-        # iota/compare/select passes run on ≤ cdiv(bk, bq) blocks only
-        first_qb = (ki * block_k) // block_q
-        diag_end = -(-((ki + 1) * block_k - 1) // block_q)  # ceil div
-    else:
-        first_qb = diag_end = 0
-    # scale folded into the resident k for the score dot (see
-    # _fwd_kernel); dk accumulates against raw q, scaled once at flush
-    ks = (k.astype(jnp.float32) * scale).astype(k.dtype)
+    def init_q(qb, _):
+        rows = pl.ds(qb * block_q, block_q)
+        qs_ref[rows, :] = (q_ref[0, rows, :].astype(jnp.float32)
+                           * scale).astype(dtype)
+        dq_acc[qb] = jnp.zeros(dq_acc.shape[1:], jnp.float32)
+        return _
 
-    def make_body(masked):
-        def body(qb, carry):
-            dk, dv = carry
-            q = q_ref[0, pl.ds(qb * block_q, block_q), :]
-            do = do_ref[0, pl.ds(qb * block_q, block_q), :]
-            lse = lse_ref[0, pl.ds(qb * block_q, block_q), :]  # [BQ, 1]
-            delta = delta_ref[0, pl.ds(qb * block_q, block_q), :]
-            s = jax.lax.dot_general(q, ks, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-            if masked:
-                row = qb * block_q + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, bk), 0)
-                col = ki * block_k + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, bk), 1)
-                s = jnp.where(row >= col, s, NEG_INF)
-            p = jnp.exp(s - lse)
-            p16 = p.astype(k.dtype)
-            dv_new = dv + jax.lax.dot_general(
-                p16, do, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            ds = (p * (dp - delta)).astype(k.dtype)
-            dk_new = dk + jax.lax.dot_general(
-                ds, q, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            return dk_new, dv_new
-        return body
+    _sweep(0, num_qb, init_q, static=static)
 
-    carry = (dk_acc[...], dv_acc[...])
-    if causal:
-        carry = jax.lax.fori_loop(first_qb, diag_end, make_body(True),
-                                  carry)
-    dk, dv = jax.lax.fori_loop(diag_end, num_qb, make_body(False), carry)
-    dk_acc[...] = dk
-    dv_acc[...] = dv
+    def k_block(ki, _):
+        rows = pl.ds(ki * block_k, block_k)
+        k = k_ref[0, rows, :]
+        v = v_ref[0, rows, :]
+
+        def make_body(masked):
+            def body(qb, carry):
+                dk, dv = carry
+                q_rows = pl.ds(qb * block_q, block_q)
+                do = do_ref[0, q_rows, :]
+                s = jax.lax.dot_general(k, qs_ref[q_rows, :], _NT,
+                                        preferred_element_type=jnp.float32)
+                if masked:
+                    key = ki * block_k + jax.lax.broadcasted_iota(
+                        jnp.int32, (block_k, block_q), 0)
+                    query = qb * block_q + jax.lax.broadcasted_iota(
+                        jnp.int32, (block_k, block_q), 1)
+                    s = jnp.where(query >= key, s, NEG_INF)
+                p = jnp.exp(s - lse_ref[0, pl.ds(qb, 1), :])
+                dv = dv + jnp.dot(p.astype(dtype), do,
+                                  preferred_element_type=jnp.float32)
+                dp = jax.lax.dot_general(v, do, _NT,
+                                         preferred_element_type=jnp.float32)
+                ds = (p * (dp - delta_ref[0, pl.ds(qb, 1), :])).astype(dtype)
+                dk = dk + jnp.dot(ds, q_ref[0, q_rows, :],
+                                  preferred_element_type=jnp.float32)
+                # the one product that contracts its rows: ds is turned
+                # on the way in (a K transposed once a kv head and dq^T +=
+                # k^T ds, turned back at the end, read within 3 % of
+                # this either way: PERF.md section 6, PR 46)
+                dq_acc[qb] += jax.lax.dot_general(
+                    ds, k, _TN, preferred_element_type=jnp.float32)
+                return dk, dv
+            return body
+
+        carry = (dk_acc[rows, :], dv_acc[rows, :])
+        diag_end = 0
+        if causal:
+            # q blocks split three ways around this k block: before
+            # first_qb nothing is visible (skipped), [first_qb, diag_end)
+            # touches the diagonal (masked), [diag_end, num_qb) is fully
+            # visible
+            first_qb = (ki * block_k) // block_q
+            diag_end = -(-((ki + 1) * block_k - 1) // block_q)  # ceil div
+            carry = _sweep(first_qb, diag_end, make_body(True), carry,
+                           static=static, group=_GROUP)
+        dk, dv = _sweep(diag_end, num_qb, make_body(False), carry,
+                        static=col_static, group=_GROUP)
+        dk_acc[rows, :] = dk
+        dv_acc[rows, :] = dv
+        return _
+
+    _sweep(0, num_kb, k_block, static=static)
+
+    def flush_q(qb, _):
+        dq_ref[0, pl.ds(qb * block_q, block_q), :] = (
+            (dq_acc[qb] * scale).astype(dq_ref.dtype))
+        return _
+
+    _sweep(0, num_qb, flush_q, static=static)
 
     @pl.when(g == rep - 1)
-    def _flush():
+    def _flush_kv():
         dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _flash_bwd(q3, k3, v3, o3, lse, do3, *, scale, block_q, block_k,
-               causal, interpret):
-    BH, T, D = q3.shape
-    BKH = k3.shape[0]
-    rep = BH // BKH
-    delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
-                    axis=-1, keepdims=True)  # [BH, T, 1]
-
-    dq_kernel = functools.partial(_bwd_dq_kernel, scale=scale,
-                                  block_q=block_q, block_k=block_k,
-                                  seq_len=T, causal=causal)
-    qmap = lambda bkh, g, qi: (bkh * rep + g, qi, 0)  # noqa: E731
-    kvmap = lambda bkh, g, qi: (bkh, 0, 0)  # noqa: E731
-    dq = pl.pallas_call(
-        dq_kernel,
-        grid=(BKH, rep, T // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), qmap),
-            pl.BlockSpec((1, T, D), kvmap),
-            pl.BlockSpec((1, T, D), kvmap),
-            pl.BlockSpec((1, block_q, D), qmap),
-            pl.BlockSpec((1, block_q, 1), qmap),
-            pl.BlockSpec((1, block_q, 1), qmap),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, D), qmap),
-        out_shape=jax.ShapeDtypeStruct(q3.shape, q3.dtype),
+@functools.lru_cache(maxsize=None)
+def _bwd_call(BH: int, BKH: int, T: int, D: int, dtype, scale: float,
+              block_q: int, block_k: int, causal: bool, interpret: bool):
+    """``(q3, k3, v3, do3, lse, delta) -> (dq, dk, dv)``; ``delta`` laid
+    out as ``lse``. Kept per static signature, as :func:`_fwd_call`."""
+    head, stat, kv = _head_specs(BH, BKH, T, D, block_q)
+    item = dtype.itemsize
+    return jax.jit(pl.pallas_call(
+        functools.partial(
+            _bwd_kernel, scale=scale, block_q=block_q, block_k=block_k,
+            causal=causal, rep=BH // BKH,
+            static=_score_blocks(T, block_q, block_k,
+                                 causal) <= _UNROLL_BLOCKS),
+        grid=(BKH, BH // BKH),
+        in_specs=[head, kv, kv, head, stat, stat],
+        out_specs=[head, kv, kv],
+        out_shape=[jax.ShapeDtypeStruct((BH, T, D), dtype),
+                   jax.ShapeDtypeStruct((BKH, T, D), dtype),
+                   jax.ShapeDtypeStruct((BKH, T, D), dtype)],
+        scratch_shapes=[pltpu.VMEM((T, D), dtype),
+                        pltpu.VMEM((T // block_q, block_q, D), jnp.float32),
+                        pltpu.VMEM((T, D), jnp.float32),
+                        pltpu.VMEM((T, D), jnp.float32)],
+        compiler_params=_vmem_params(
+            (2 * 7 + 1) * T * D * item + 3 * T * D * 4 + 4 * T * 4,
+            "the backward"),
         interpret=interpret,
-        name="flash_attention_bwd_dq",
-    )(q3, k3, v3, do3, lse, delta)
-
-    dkv_kernel = functools.partial(_bwd_dkv_kernel, scale=scale,
-                                   block_q=block_q, block_k=block_k,
-                                   seq_len=T, causal=causal, rep=rep)
-    # group axis INNERMOST: consecutive grid steps revisit the same dk/dv
-    # block (and the same k/v block), so the scratch accumulation in the
-    # kernel is a legal sequential reduction and k/v stay resident in VMEM
-    # across the whole query group
-    gq = lambda bkh, ki, g: (bkh * rep + g, 0, 0)  # noqa: E731
-    kvm = lambda bkh, ki, g: (bkh, ki, 0)  # noqa: E731
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
-        grid=(BKH, T // block_k, rep),
-        in_specs=[
-            pl.BlockSpec((1, T, D), gq),
-            pl.BlockSpec((1, block_k, D), kvm),
-            pl.BlockSpec((1, block_k, D), kvm),
-            pl.BlockSpec((1, T, D), gq),
-            pl.BlockSpec((1, T, 1), gq),
-            pl.BlockSpec((1, T, 1), gq),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, D), kvm),
-            pl.BlockSpec((1, block_k, D), kvm),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(k3.shape, k3.dtype),
-            jax.ShapeDtypeStruct(v3.shape, v3.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
-        ],
-        interpret=interpret,
-        name="flash_attention_bwd_dkv",
-    )(q3, k3, v3, do3, lse, delta)
-    return dq, dk, dv
+        name="flash_attention_bwd"))
 
 
 # ---------------------------------------------------------------------------
@@ -389,25 +488,29 @@ def _flash_bwd(q3, k3, v3, o3, lse, do3, *, scale, block_q, block_k,
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash_attention(q3, k3, v3, scale, block_q, block_k, causal):
-    o, _ = _flash_fwd(q3, k3, v3, scale=scale, block_q=block_q,
-                      block_k=block_k, causal=causal,
-                      interpret=_should_interpret())
-    return o
+    return _flash_attention_fwd(q3, k3, v3, scale, block_q, block_k,
+                                causal)[0]
+
+
+def _signature(q3, k3, scale, block_q, block_k, causal):
+    return (q3.shape[0], k3.shape[0], *q3.shape[1:], q3.dtype, scale,
+            block_q, block_k, causal, _should_interpret())
 
 
 def _flash_attention_fwd(q3, k3, v3, scale, block_q, block_k, causal):
-    o, lse = _flash_fwd(q3, k3, v3, scale=scale, block_q=block_q,
-                        block_k=block_k, causal=causal,
-                        interpret=_should_interpret())
+    o, lse = _fwd_call(*_signature(q3, k3, scale, block_q, block_k,
+                                   causal))(q3, k3, v3)
     return o, (q3, k3, v3, o, lse)
 
 
 def _flash_attention_bwd(scale, block_q, block_k, causal, res, do3):
     q3, k3, v3, o3, lse = res
-    dq, dk, dv = _flash_bwd(q3, k3, v3, o3, lse, do3, scale=scale,
-                            block_q=block_q, block_k=block_k, causal=causal,
-                            interpret=_should_interpret())
-    return dq, dk, dv
+    # delta = rowsum(do * o), one fused pass that leaves it as the
+    # forward left lse: positions on the lanes
+    delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
+                    axis=-1).reshape(lse.shape)
+    return tuple(_bwd_call(*_signature(q3, k3, scale, block_q, block_k,
+                                       causal))(q3, k3, v3, do3, lse, delta))
 
 
 _flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
@@ -418,10 +521,9 @@ def _flash_attention_window(q3, k3, v3, scale, block_q, block_k, window):
     """The windowed forward. It has no backward kernels: differentiating
     it is refused by name instead of falling through to a backward pass
     that would ignore the window."""
-    o, _ = _flash_fwd(q3, k3, v3, scale=scale, block_q=block_q,
-                      block_k=block_k, causal=True, window=window,
-                      interpret=_should_interpret())
-    return o
+    return _flash_window_fwd(q3, k3, v3, scale=scale, block_q=block_q,
+                             block_k=block_k, window=window,
+                             interpret=_should_interpret())
 
 
 def _flash_attention_window_fwd(q3, k3, v3, scale, block_q, block_k,

@@ -1,5 +1,8 @@
 """Kernel/op tests — numerical parity against jnp oracles (the reference's
 tests/unit/ops strategy: each op vs a torch/numpy reference)."""
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -19,25 +22,100 @@ from deepspeed_tpu.ops.quantizer import (Quantizer, dequantize_asymmetric,
 from deepspeed_tpu.ops import random_ltd
 
 
+def _masked_softmax_attention(q, k, v, causal):
+    """The oracle of both masks, k/v expanded over the query group."""
+    rep = q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(x, rep, axis=2) for x in (k, v))
+    if causal:
+        return causal_attention_reference(q, k, v)
+    att = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(att, axis=-1), v)
+
+
+def _kernel_names(fn, *args):
+    """The Pallas kernels a traced function calls, by name."""
+    return set(re.findall(r"name=(flash_attention\w*)",
+                          str(jax.make_jaxpr(fn)(*args))))
+
+
+# T, D, H, HKV, causal, block_q, block_k, dtype: the cells' shapes (T 1024,
+# D 128), the latent prefill's (D 192), Laguna's group of query heads,
+# every D on both masks, unequal blocks both ways, blocks that fall back
+# to 128 (T 384), one block a head (T 128) and eight
+_FLASH_CASES = [
+    (256, 64, 4, 4, True, 256, 256, "float32"),
+    (128, 64, 4, 4, False, 256, 256, "float32"),
+    (128, 64, 4, 4, True, 256, 256, "float32"),
+    (384, 64, 4, 4, True, 256, 256, "float32"),
+    (256, 64, 4, 1, True, 256, 256, "float32"),
+    (256, 64, 4, 2, True, 256, 256, "float32"),
+    (256, 64, 4, 4, True, 256, 256, "bfloat16"),
+    (1024, 128, 2, 2, True, 256, 256, "bfloat16"),
+    (1024, 128, 4, 1, True, 256, 128, "float32"),
+    (2048, 128, 1, 1, True, 128, 256, "float32"),
+    (256, 192, 2, 2, True, 256, 256, "bfloat16"),
+    (512, 192, 4, 1, False, 256, 256, "float32"),
+    (384, 128, 4, 1, False, 256, 256, "bfloat16"),
+    (2048, 64, 4, 4, True, 512, 256, "bfloat16"),
+    (1024, 128, 2, 2, False, 128, 512, "float32"),
+    (384, 192, 4, 4, True, 128, 128, "float32"),
+]
+
+
 class TestFlashAttention:
     def _qkv(self, B=2, T=256, H=4, D=64, dtype=jnp.float32):
         key = jax.random.PRNGKey(0)
         return tuple(jax.random.normal(jax.random.fold_in(key, i),
                                        (B, T, H, D), dtype) for i in range(3))
 
-    def test_forward_parity(self):
-        q, k, v = self._qkv()
-        o = flash_attention(q, k, v, causal=True)
-        o_ref = causal_attention_reference(q, k, v)
-        np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
-                                   rtol=2e-4, atol=2e-4)
+    @pytest.mark.parametrize(
+        "T,D,H,HKV,causal,block_q,block_k,dtype", _FLASH_CASES,
+        ids=lambda x: str(x))
+    def test_forward_and_grad_parity(self, T, D, H, HKV, causal, block_q,
+                                     block_k, dtype):
+        """``o``, ``dq``, ``dk``, ``dv`` against the masked softmax in
+        float32 (k/v unexpanded through the kernel: dk/dv accumulate over
+        the whole query group). bf16 is the production dtype: the dots
+        take bf16 inputs with fp32 accumulation, p/ds are downcast before
+        the MXU; parity within bf16-rounding tolerances."""
+        B = 2 if T <= 512 else 1
+        q, _, _ = self._qkv(B=B, T=T, H=H, D=D, dtype=jnp.dtype(dtype))
+        _, k, v = self._qkv(B=B, T=T, H=HKV, D=D, dtype=jnp.dtype(dtype))
+        q32, k32, v32 = (x.astype(jnp.float32) for x in (q, k, v))
+        fwd_tol, grad_tol = ((dict(rtol=2e-4, atol=2e-4),
+                              dict(rtol=5e-3, atol=5e-4))
+                             if dtype == "float32" else
+                             (dict(rtol=2e-2, atol=2e-2),
+                              dict(rtol=1e-1, atol=0.15)))
+
+        def flash(q, k, v):
+            return flash_attention(q, k, v, causal=causal, block_q=block_q,
+                                   block_k=block_k)
+
+        np.testing.assert_allclose(
+            np.asarray(flash(q, k, v), np.float32),
+            np.asarray(_masked_softmax_attention(q32, k32, v32, causal)),
+            **fwd_tol)
+
+        def loss_f(q, k, v):
+            return jnp.sum(flash(q, k, v).astype(jnp.float32) ** 2)
+
+        def loss_r(q, k, v):
+            return jnp.sum(_masked_softmax_attention(q, k, v, causal) ** 2)
+
+        gf = jax.grad(loss_f, argnums=(0, 1, 2))(q, k, v)
+        gr = jax.grad(loss_r, argnums=(0, 1, 2))(q32, k32, v32)
+        for a, b in zip(gf, gr):
+            assert a.shape == b.shape and a.dtype == q.dtype
+            np.testing.assert_allclose(np.asarray(a, np.float32),
+                                       np.asarray(b), **grad_tol)
 
     @pytest.mark.slow
     def test_block_512_parity(self):
         """The bench --flash-block 512 A/B rung's tile config is
         numerically identical to the default — fwd AND grad, since the
         rung trains. T=1024 gives 2 blocks per axis so the causal bounds
-        (fwd diag_start/num_kb, bwd first_qb/diag_end) are exercised in
+        (fwd diag_start/diag_end, bwd first_qb/diag_end) are exercised in
         both the unmasked below-diagonal loop and the masked diagonal
         loop at the non-default tile, not just the degenerate 1-block
         case."""
@@ -60,74 +138,114 @@ class TestFlashAttention:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-3, atol=1e-3)
 
-    def test_noncausal_parity(self):
-        q, k, v = self._qkv(T=128)
-        o = flash_attention(q, k, v, causal=False)
-        att = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(64)
-        p = jax.nn.softmax(att, axis=-1)
-        o_ref = jnp.einsum("bhqk,bkhd->bqhd", p, v)
-        np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
-                                   rtol=2e-4, atol=2e-4)
+    @pytest.mark.parametrize("what,names", [
+        ("forward", {"flash_attention_fwd"}),
+        ("grad", {"flash_attention_fwd", "flash_attention_bwd"}),
+        ("window", {"flash_attention_window_fwd"}),
+    ])
+    def test_kernels_by_name(self, what, names):
+        """The names the benchmark's readers find the kernels by: one
+        forward, ONE backward, the windowed forward its own."""
+        q = jax.ShapeDtypeStruct((1, 512, 4, 128), jnp.bfloat16)
+        kv = jax.ShapeDtypeStruct((1, 512, 2, 128), jnp.bfloat16)
+        fn = {
+            "forward": flash_attention,
+            "grad": jax.grad(lambda q, k, v: flash_attention(
+                q, k, v).astype(jnp.float32).sum(), argnums=(0, 1, 2)),
+            "window": lambda q, k, v: flash_attention(q, k, v, window=128),
+        }[what]
+        assert _kernel_names(fn, q, kv, kv) == names
 
-    def test_grad_parity(self):
-        q, k, v = self._qkv(T=128)
+    @pytest.mark.parametrize("unroll,T,D,H,HKV,causal,block_q,block_k,dtype", [
+        (0, 2048, 128, 2, 1, True, 256, 256, "bfloat16"),
+        (0, 1536, 64, 2, 2, True, 128, 256, "float32"),
+        (0, 1024, 128, 2, 2, False, 256, 128, "float32"),
+        (0, 384, 192, 2, 2, True, 256, 256, "float32"),
+        (8, 1024, 128, 2, 2, False, 256, 128, "float32"),
+    ], ids=lambda x: str(x))
+    def test_looped_sweep_parity(self, monkeypatch, unroll, T, D, H, HKV,
+                                 causal, block_q, block_k, dtype):
+        """The sweep of a head too long to write out, forced at sizes the
+        interpreter can run: groups of four blocks, then the blocks left
+        over (whole groups, leftovers and none of either), and with no
+        mask a row of 8 K blocks (a column of 4 Q blocks) written out
+        under the looped axis."""
+        from deepspeed_tpu.ops.pallas import flash_attention as fa
+        monkeypatch.setattr(fa, "_UNROLL_BLOCKS", unroll)
+        calls = (fa._fwd_call, fa._bwd_call)   # kept per signature
+        for call in calls:
+            call.cache_clear()
+        try:
+            self.test_forward_and_grad_parity(T, D, H, HKV, causal, block_q,
+                                              block_k, dtype)
+        finally:
+            for call in calls:
+                call.cache_clear()
 
-        def loss_f(q, k, v):
-            return jnp.sum(flash_attention(q, k, v) ** 2)
+    @pytest.mark.parametrize("T,causal,block,body", [
+        (2048, True, 256, None), (4096, True, 256, (1, 10)),
+        (1024, False, 256, None), (4096, False, 256, (16, 16)),
+        (16384, False, 128, (1, 5))])
+    def test_sweep_is_written_out_or_looped(self, T, causal, block, body):
+        """Each side of the wrapper's thresholds on straight-line code, by
+        what the kernels hold. A head of up to ``_UNROLL_BLOCKS`` score
+        blocks is written out whole (``body`` None: no loop at all); a
+        longer one loops over its q (k) blocks, and ``body`` bounds the
+        score blocks written out inside: an unmasked row of up to
+        ``_UNROLL_BLOCKS`` whole (16 at T 4096), any other sweep a group
+        of ``_GROUP`` and the block left over (under a mask two such
+        sweeps), however long the head."""
+        x = jax.ShapeDtypeStruct((1, T, 1, 128), jnp.bfloat16)
+        fwd = functools.partial(flash_attention, causal=causal,
+                                block_q=block, block_k=block)
+        grad = jax.grad(lambda q, k, v: fwd(q, k, v).astype(
+            jnp.float32).sum(), argnums=(0, 1, 2))
+        for fn, products in ((fwd, 2), (grad, 2 + 5)):
+            text = str(jax.make_jaxpr(fn)(x, x, x))
+            assert bool(re.search(r"\b(while|scan)\[", text)) == (
+                body is not None)
+            if body is not None:
+                lo, hi = body
+                assert (products * lo <= text.count("dot_general")
+                        <= products * hi)
 
-        def loss_r(q, k, v):
-            return jnp.sum(causal_attention_reference(q, k, v) ** 2)
+    @pytest.mark.parametrize("T,limit", [(2048, "None"),
+                                         (8192, str(25 * 2**20))])
+    def test_asks_for_vmem_only_past_the_default(self, T, limit):
+        """A head that fits what every kernel gets (16 MiB: the cells'
+        T 1024 forward and backward, a T 2048 forward) leaves the
+        program's VMEM setting alone; a longer one asks for what it
+        counts (its blocks twice, 8 MiB of its own values), not for
+        all there is."""
+        x = jax.ShapeDtypeStruct((1, T, 1, 128), jnp.bfloat16)
+        text = str(jax.make_jaxpr(flash_attention)(x, x, x))
+        assert re.findall(r"vmem_limit_bytes=(\w+)", text) == [limit]
 
-        gf = jax.grad(loss_f, argnums=(0, 1, 2))(q, k, v)
-        gr = jax.grad(loss_r, argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(gf, gr):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=5e-3, atol=5e-4)
+    @pytest.mark.parametrize("what,T,fits", [
+        ("forward", 32768, True), ("forward", 65536, False),
+        ("grad", 16384, True), ("grad", 32768, False)])
+    def test_a_head_fits_vmem_or_is_refused(self, what, T, fits):
+        """The one threshold of the wrapper: a grid step holds a whole
+        head, which fits the chip's VMEM to T 32k forward and 16k
+        backward at D 128 in bf16 (the same kernels as at T 256); past it
+        the call is refused by name, where the K/V residency failed to
+        compile before."""
+        x = jax.ShapeDtypeStruct((1, T, 1, 128), jnp.bfloat16)
+        fn, names = flash_attention, {"flash_attention_fwd"}
+        if what == "grad":
+            fn = jax.grad(lambda q, k, v: flash_attention(
+                q, k, v).astype(jnp.float32).sum(), argnums=(0, 1, 2))
+            names = names | {"flash_attention_bwd"}
+        if fits:
+            assert _kernel_names(fn, x, x, x) == names
+        else:
+            with pytest.raises(ValueError, match="ring_attention"):
+                jax.eval_shape(fn, x, x, x)
 
     def test_rejects_ragged_seq(self):
         q, k, v = self._qkv(T=96)
         with pytest.raises(ValueError):
             flash_attention(q, k, v, block_q=128, block_k=64)
-
-    def test_block_fallback_on_128_multiples(self):
-        """The 256 defaults must not reject T that only divides by 128
-        (callers gate flash on T % 128 == 0 — ops/transformer.py:163)."""
-        q, k, v = self._qkv(T=384)
-        o = flash_attention(q, k, v, causal=True)
-        o_ref = causal_attention_reference(q, k, v)
-        np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
-                                   rtol=2e-4, atol=2e-4)
-
-    @pytest.mark.parametrize("hkv", [1, 2])
-    def test_gqa_forward_and_grad_parity(self, hkv):
-        """Grouped-query attention: unexpanded k/v ([B, T, HKV, D],
-        HKV | H) through the kernel must equal the expanded-MHA oracle,
-        including dk/dv (which accumulate over the whole query group)."""
-        q, _, _ = self._qkv(T=256, H=4)
-        _, k, v = self._qkv(T=256, H=hkv)
-        rep = 4 // hkv
-        kx = jnp.repeat(k, rep, axis=2)
-        vx = jnp.repeat(v, rep, axis=2)
-
-        o = flash_attention(q, k, v, causal=True)
-        o_ref = causal_attention_reference(q, kx, vx)
-        np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
-                                   rtol=2e-4, atol=2e-4)
-
-        def loss_f(q, k, v):
-            return jnp.sum(flash_attention(q, k, v) ** 2)
-
-        def loss_r(q, k, v):
-            o = causal_attention_reference(
-                q, jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2))
-            return jnp.sum(o ** 2)
-
-        gf = jax.grad(loss_f, argnums=(0, 1, 2))(q, k, v)
-        gr = jax.grad(loss_r, argnums=(0, 1, 2))(q, k, v)
-        for a, b in zip(gf, gr):
-            assert a.shape == b.shape
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=5e-3, atol=5e-4)
 
     def test_gqa_reference_matches_expanded(self):
         """The jnp oracle's own GQA path vs explicit expansion."""
@@ -144,30 +262,6 @@ class TestFlashAttention:
         _, k, v = self._qkv(T=128, H=3)
         with pytest.raises(ValueError):
             flash_attention(q, k, v)
-
-    def test_bf16_forward_and_grad_parity(self):
-        """The production dtype: kernel dots take bf16 inputs with fp32
-        accumulation; p/ds are downcast before the MXU dots. Parity vs the
-        fp32 reference within bf16-rounding tolerances."""
-        q, k, v = self._qkv(T=256, dtype=jnp.bfloat16)
-        q32, k32, v32 = (x.astype(jnp.float32) for x in (q, k, v))
-
-        o = flash_attention(q, k, v, causal=True)
-        o_ref = causal_attention_reference(q32, k32, v32)
-        np.testing.assert_allclose(np.asarray(o, np.float32),
-                                   np.asarray(o_ref), rtol=2e-2, atol=2e-2)
-
-        def loss_f(q, k, v):
-            return jnp.sum(flash_attention(q, k, v).astype(jnp.float32) ** 2)
-
-        def loss_r(q, k, v):
-            return jnp.sum(causal_attention_reference(q, k, v) ** 2)
-
-        gf = jax.grad(loss_f, argnums=(0, 1, 2))(q, k, v)
-        gr = jax.grad(loss_r, argnums=(0, 1, 2))(q32, k32, v32)
-        for a, b in zip(gf, gr):
-            np.testing.assert_allclose(np.asarray(a, np.float32),
-                                       np.asarray(b), rtol=1e-1, atol=0.15)
 
 
 class TestDecodeAttention:
