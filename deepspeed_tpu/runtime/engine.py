@@ -36,6 +36,8 @@ from deepspeed_tpu.comm.mesh import (build_mesh, get_data_parallel_world_size,
                                      set_global_mesh)
 from deepspeed_tpu.config.config import DeepSpeedConfig
 from deepspeed_tpu.ops.adam import Optimizer, build_optimizer
+from deepspeed_tpu.runtime.activation_checkpointing import (
+    REMAT_LIMIT_OPTION, remat_limit_percent)
 from deepspeed_tpu.runtime.lr_schedules import Schedule, build_schedule
 from deepspeed_tpu.runtime.precision import (PRECISION_DTYPES, LossScaleState,
                                              cast_tree, grads_finite,
@@ -1175,7 +1177,11 @@ class DeepSpeedEngine:
             return step3(state, batch, rng)
         return step_fn
 
-    def _compile_step(self, batch):
+    def _compile_step(self, batch, bytes_limit: Optional[int] = None):
+        """Build ``self._step_fn`` for ``batch``'s shapes. ``bytes_limit``:
+        a chip's memory where the caller knows it and the device cannot
+        say (a described device: ``scripts/aot_train_step.py``); None
+        asks the device."""
         from deepspeed_tpu.telemetry import watched_jit
         if self._onebit_axes:
             self._eager_param_staging = False
@@ -1205,14 +1211,18 @@ class DeepSpeedEngine:
             in_sh = in_sh.replace(params=self._device_param_shardings)
             out_sh = out_sh.replace(params=self._device_param_shardings)
             self._eager_param_staging = True
-        options = {}
+        compiler_options = {}
         if self._offload_stream:
             # the stream's pipeline needs its transfers in flight
             # together; the compiler's own budget of outstanding host
             # copies (5) would serialize them again
-            options["compiler_options"] = {
-                COPY_BUDGET_OPTION: copy_budget(
-                    self.state.opt_state, self.state.master is not None)}
+            compiler_options[COPY_BUDGET_OPTION] = copy_budget(
+                self.state.opt_state, self.state.master is not None)
+        remat_percent = self._remat_limit_percent(bytes_limit)
+        if remat_percent is not None:
+            compiler_options[REMAT_LIMIT_OPTION] = remat_percent
+        options = {"compiler_options": compiler_options} \
+            if compiler_options else {}
         # numerics_on is static (one retrace per toggle); in_shardings
         # cover the three dynamic args only
         self._step_fn = watched_jit(
@@ -1222,6 +1232,47 @@ class DeepSpeedEngine:
             out_shardings=(out_sh, None),
             static_argnums=(3,),
             donate_argnums=(0,), **options)
+
+    def _remat_limit_percent(self, bytes_limit: Optional[int]
+                             ) -> Optional[int]:
+        """The share of a chip's memory to give the compiler's
+        rematerialisation pass for ``train_step``
+        (``activation_checkpointing.remat_limit_percent``): the pass
+        counts the state this engine placed in pinned host memory
+        against the chip, so that share is given back. None where no
+        state lives there (the option is not set and the step's text is
+        what it was) or the chip's limit is unknown. Published as gauge
+        ``train_remat_limit_percent`` (0: left alone) and one log line."""
+        host = 0
+        for x, s in zip(jax.tree.leaves(self.state),
+                        jax.tree.leaves(self._state_shardings)):
+            if s.memory_kind == "pinned_host":
+                host += int(np.prod(s.shard_shape(x.shape))) \
+                    * jnp.dtype(x.dtype).itemsize
+        if host and bytes_limit is None:
+            # None: a backend that keeps no account (XLA:CPU)
+            stats = self.mesh.local_devices[0].memory_stats() or {}
+            bytes_limit = int(stats.get("bytes_limit", 0))
+        percent = remat_limit_percent(bytes_limit or 0, host)
+        self.telemetry.gauge(
+            "train_remat_limit_percent",
+            help="the rematerialisation pass's share of a chip's memory "
+                 "as train_step was compiled: the default plus the state "
+                 "in pinned host memory, which the pass counts against "
+                 "the chip (0: no state on the host or limit unknown, "
+                 "option not set)").set(float(percent or 0))
+        if percent is not None:
+            log_dist("train_step: {:.2f} GB of state a chip in pinned host "
+                     "memory, bytes_limit {:.2f} GB -> {} = {}".format(
+                         host / 1e9, bytes_limit / 1e9, REMAT_LIMIT_OPTION,
+                         percent), ranks=[0])
+        elif host and self.mesh.devices.flat[0].platform == "tpu":
+            logger.warning(
+                "train_step: state lives in pinned host memory and the "
+                "chip reports no bytes_limit: %s is not set, and the "
+                "compiler's rematerialisation pass will count that state "
+                "against the chip (recomputed matmuls)", REMAT_LIMIT_OPTION)
+        return percent
 
     # ------------------------------------------------------------------
     # ZeRO-Offload step: device grads → host SIMD Adam → device params

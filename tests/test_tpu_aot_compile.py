@@ -936,6 +936,81 @@ def test_zero3_step_exchanges_parameters_not_activations(chips):
     assert 0.5 * zero3 < wire < 1.2 * zero3, (wire, zero3, moved)
 
 
+def test_offload_step_report_states_its_footprint_and_its_clones(chips):
+    """The offload cell's OWN step at two layers through
+    ``scripts/aot_train_step.py``: the report reads the planned footprint
+    (``memory_analysis()``) against the v5e's stated limit and counts the
+    compiler's own rematerialisation of matmuls, so that the next reader
+    needs no chip for either (PERF.md section 6, PR 48: at 24 layers the
+    parent's step held 99 such clones, at a limit of 0 bytes)."""
+    script = _aot_script()
+    step = script.compile_step(traffic="offload", layers=2)
+    memory = step["memory"]
+    assert set(memory) == {"argument_bytes", "temp_bytes", "planned_bytes",
+                           "bytes_limit_v5e"}
+    # bf16 parameters are the arguments on the chip; the state on the
+    # host (12 bytes a parameter) is none of them
+    assert memory["argument_bytes"] == pytest.approx(
+        2 * step["parameters"], rel=0.01)
+    assert memory["planned_bytes"] == \
+        memory["argument_bytes"] + memory["temp_bytes"]
+    assert 0 < memory["planned_bytes"] < memory["bytes_limit_v5e"]
+    assert script.remat_clones(step["text"]) == {"count": 0,
+                                                 "by_product": {}}
+    # what the counter counts: a clone whose fusion holds a matmul
+    text = """
+%fused_computation.7 (p: bf16[8,8]) -> bf16[8,8] {
+  %p = bf16[8,8]{1,0} parameter(0)
+  ROOT %convolution.1 = bf16[8,8]{1,0} convolution(%p, %p), dim_labels=bf_io->bf
+}
+%fused_computation.8 (p: bf16[8,8]) -> bf16[8,8] {
+  %p = bf16[8,8]{1,0} parameter(0)
+  ROOT %add.1 = bf16[8,8]{1,0} add(%p, %p)
+}
+ENTRY %main (a: bf16[8,8]) -> bf16[8,8] {
+  %a = bf16[8,8]{1,0} parameter(0)
+  %fusion.3.remat2 = bf16[8,8]{1,0} fusion(%a), kind=kOutput, calls=%fused_computation.7, metadata={op_name="jit(train_step)/fwd_bwd/jvp(GPT2)/h_3/mlp/c_fc/dot_general"}
+  ROOT %fusion.4.remat = bf16[8,8]{1,0} fusion(%fusion.3.remat2), kind=kLoop, calls=%fused_computation.8, metadata={op_name="jit(train_step)/fwd_bwd/jvp(GPT2)/h_3/mlp/add"}
+}
+"""
+    assert script.remat_clones(text) == {
+        "count": 1,
+        "by_product": {"mlp/c_fc/dot_general -> bf16[8,8]{1,0}": 1}}
+
+
+def test_offload_step_is_not_rematerialised_for_its_host_state(chips):
+    """The offload cell's step at 16 layers, the shallowest at which the
+    compiler's rematerialisation pass, taking the optimizer state in
+    pinned host memory off its limit with the other outputs, ran out of
+    room on the parent (67 ``.remat`` clones of matmuls there, 99 at 24
+    layers; none at 12): with the host's share given back
+    (``engine._remat_limit_percent``) it clones nothing."""
+    script = _aot_script()
+    step = script.compile_step(traffic="offload", layers=16)
+    assert script.remat_clones(step["text"]) == {"count": 0,
+                                                 "by_product": {}}
+    assert step["memory"]["planned_bytes"] < step["memory"]["bytes_limit_v5e"]
+
+
+def test_this_libtpu_knows_the_remat_limit_option(chips):
+    """The option the engine sets on every step whose state lives on the
+    host is an internal one of the TPU compiler: a libtpu that drops it
+    would fail every such step at compile time, as it fails an option it
+    does not know, by name. Verified with libtpu 0.0.34."""
+    from deepspeed_tpu.runtime.activation_checkpointing import (
+        REMAT_LIMIT_OPTION)
+    x = jax.ShapeDtypeStruct((8, 128), jnp.float32, sharding=NamedSharding(
+        Mesh(np.array(chips[:1]), ("x",)), P()))
+
+    def compile_with(option):
+        return jax.jit(lambda a: a * 2, compiler_options={
+            option: 188}).lower(x).compile()
+
+    assert compile_with(REMAT_LIMIT_OPTION) is not None
+    with pytest.raises(Exception, match="xla_jf_no_such_option"):
+        compile_with("xla_jf_no_such_option")
+
+
 def test_kernel_names_are_the_same_under_a_mesh(chips):
     """``map_kernel``'s shard_map does not rename the call: the decode
     kernel reads ``paged_decode_attention`` on four devices as on one."""
