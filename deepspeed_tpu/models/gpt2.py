@@ -44,7 +44,7 @@ class GPT2Config:
     remat: bool = True
     use_flash_attention: bool = True
     # flash tile-size override (0 = kernel default 256): the long-context
-    # block-size A/B knob — bench --flash-block N
+    # block-size A/B knob
     flash_block: int = 0
     # sequence/context parallelism over the seq mesh axis (capability
     # beyond the reference — SURVEY §5.7); requires dropout == 0 in the

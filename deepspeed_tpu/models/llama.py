@@ -50,7 +50,7 @@ class LlamaConfig:
     dtype: Any = jnp.bfloat16
     remat: bool = True
     use_flash_attention: bool = True
-    # flash tile-size override (0 = kernel default 256; bench --flash-block)
+    # flash tile-size override (0 = kernel default 256)
     flash_block: int = 0
     sequence_parallel: bool = False
     sp_mode: str = "ring"

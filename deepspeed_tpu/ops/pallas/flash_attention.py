@@ -83,9 +83,7 @@ def effective_block(block: int, seq: int) -> int:
     the MXU-minimum tile every such seq accepts — rather than returning
     a sub-128 block the kernel can neither run nor should ever label a
     record with. Ragged seqs (seq % 128 != 0) keep the non-dividing
-    block so flash_attention still rejects them loudly, as before. Pure
-    int math, shared with bench.py's record labeling so salvage/baseline
-    keys always name the block that actually ran."""
+    block so flash_attention still rejects them loudly, as before."""
     b = min(block, seq)
     while b > 128 and seq % b:
         b //= 2

@@ -602,8 +602,8 @@ class DeepSpeedEngine:
         # eagerly above — to its sharding. Uncommitted scalars enter the
         # first step with empty-sharding avals while the step's outputs are
         # mesh-committed, so the second train_batch would retrace and
-        # recompile the entire program (the r01 bench-timeout pathology:
-        # ~double compile time before any steady-state step runs).
+        # recompile the entire program: ~double compile time before any
+        # steady-state step runs.
         state = jax.device_put(state, self._state_shardings)
         return state
 

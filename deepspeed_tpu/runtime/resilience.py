@@ -17,8 +17,7 @@ hang**:
   which restores params/optimizer/loss-scale/step **and the PRNG
   stream**, then replays forward — so a recovered run's loss trajectory
   and final params are bit-identical to the undisturbed one (the
-  headline oracle, pinned in tests/test_resilience.py and the bench
-  train chaos leg);
+  headline oracle, pinned in tests/test_resilience.py);
 * restarts are bounded (``resilience.max_restarts``) with exponential
   backoff between attempts; an exhausted budget ends the run with
   ``status="failed"`` and the fault chain attached.
@@ -519,8 +518,8 @@ class TrainingSupervisor:
     # ----------------------------------------------------------- surface
 
     def snapshot(self) -> Dict[str, Any]:
-        """JSON-able supervisor state — ``GET /debug/resilience`` and
-        the bench blob read this."""
+        """JSON-able supervisor state — ``GET /debug/resilience`` reads
+        this."""
         out = {
             "status": self.status,
             "step": int(self.engine.global_steps),

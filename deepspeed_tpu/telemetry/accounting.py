@@ -500,7 +500,7 @@ class RequestLedger:
         return self.tenants.snapshot()
 
     def snapshot(self) -> dict:
-        """``stats["accounting"]`` / bench view. ``residual_carry_s``
+        """``stats["accounting"]`` view. ``residual_carry_s``
         is device time that could not be attributed to any record and
         is still waiting for one — 0.0 whenever closure holds."""
         return {

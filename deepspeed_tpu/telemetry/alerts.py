@@ -343,8 +343,8 @@ class AlertEngine:
             return sum(r.resolved for r in self.rules.values())
 
     def snapshot(self) -> dict:
-        """JSON-able state: the incident bundle's alert rows, the
-        /debug/incidents listing's live half, and the bench blob."""
+        """JSON-able state: the incident bundle's alert rows and the
+        /debug/incidents listing's live half."""
         with self._lock:
             return {
                 "evaluations": self.evaluations,

@@ -218,7 +218,7 @@ class CanaryProber:
         return s[min(int(q * len(s)), len(s) - 1)]
 
     def snapshot(self) -> dict:
-        """JSON-able probe health (bench's slo blob + /debug surfaces)."""
+        """JSON-able probe health (``stats`` + /debug surfaces)."""
         with self._lock:
             lats = list(self.latencies_ms)
             results = dict(self.results)

@@ -414,8 +414,8 @@ class FaultInjector:
         the configured ``replica_kill_step``; 0/None = schedule off).
         Returns the victim index (or None when off) so chaos forensics
         can name it up front. Callers that know their own tick clock
-        (the bench A/B arms the kill RELATIVE to its measured burst,
-        not to whatever warmup consumed) pass ``at_tick`` explicitly."""
+        (to arm the kill RELATIVE to a burst, not to whatever warmup
+        consumed) pass ``at_tick`` explicitly."""
         if at_tick is None:
             at_tick = self.replica_kill_step
         if not at_tick or num_replicas < 1:

@@ -14,7 +14,7 @@ that sum to it **by construction**:
   writes, host-offload optimizer work.
 
 ``host = wall − data_wait − device``, so the histograms' sums reconcile
-exactly (bench's tier-1 smoke asserts it within 5%). The meter is
+exactly (tests/test_numerics_goodput.py pins it). The meter is
 config-gated (``telemetry.goodput``) because the device bucket requires
 one ``block_until_ready`` per step — it trades async step pipelining
 for an honest split, the same trade ``wall_clock_breakdown`` makes at
@@ -94,7 +94,7 @@ class GoodputMeter:
             labels=self._labels).set(fraction)
 
     def snapshot(self) -> dict:
-        """JSON-able totals (bench embeds this next to the histograms)."""
+        """JSON-able totals."""
         with self._lock:
             return {
                 "enabled": self.enabled,

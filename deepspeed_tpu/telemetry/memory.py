@@ -461,8 +461,7 @@ class KVPoolAccountant:
     # --------------------------------------------------------- export
 
     def snapshot(self) -> dict:
-        """JSON-able view for ``/debug/goodput`` / ``server.stats`` /
-        the bench blob."""
+        """JSON-able view for ``/debug/goodput`` / ``server.stats``."""
         return {
             "enabled": True,
             "live_tracked": len(self._acquired),

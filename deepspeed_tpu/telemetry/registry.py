@@ -339,8 +339,8 @@ class MetricRegistry:
 
     def snapshot(self) -> dict:
         """JSON-able dump of every series; histograms include derived
-        p50/p90/p99 so consumers (bench.py, dashboards) never re-derive
-        quantiles from buckets themselves."""
+        p50/p90/p99 so consumers (``/metrics.json``, dashboards) never
+        re-derive quantiles from buckets themselves."""
         self._collect()
         snap: dict = {}
         with self._lock:

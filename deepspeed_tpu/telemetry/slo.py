@@ -265,7 +265,7 @@ class SLOMonitor:
         return met / len(self.last_results)
 
     def snapshot(self) -> dict:
-        """JSON-able state (bench embeds this in its record)."""
+        """JSON-able state."""
         with self._lock:
             evals = self.evaluations
         return {

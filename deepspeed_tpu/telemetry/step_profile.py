@@ -14,8 +14,8 @@ with the same discipline:
   chain of clock marks: every interval between two consecutive marks
   is attributed to exactly one named phase, and the tail between the
   last mark and ``finish()`` lands in ``other`` — so
-  ``sum(phases) == wall`` is an identity, not an aspiration (the bench
-  smoke asserts the ``other`` residual stays ≤5%).
+  ``sum(phases) == wall`` is an identity, not an aspiration
+  (tests/test_step_profile.py pins it on a fake clock).
 * **Zero new device syncs.** Marks are monotonic-clock reads at
   boundaries the serving loop already crosses (the fetch that closes a
   decode step IS the existing ``np.asarray`` sync). With the profiler
@@ -643,8 +643,8 @@ class StepProfiler:
     # --------------------------------------------------------- snapshot
 
     def snapshot(self) -> dict:
-        """JSON-able totals for ``/debug/goodput``, ``server.stats``,
-        and the bench blob."""
+        """JSON-able totals for ``/debug/goodput`` and
+        ``server.stats``."""
         with self._lock:
             wall = self.wall_total
             device = self.device_total

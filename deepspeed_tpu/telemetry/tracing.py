@@ -46,7 +46,7 @@ from typing import Any, Callable, Dict, List, Optional
 from deepspeed_tpu.telemetry.registry import MetricRegistry, get_registry
 
 # a trace's span count is a small integer, not a latency — power-of-two
-# buckets so the bench's span-count histogram has sane resolution
+# buckets so the span-count histogram has sane resolution
 SPAN_COUNT_BUCKETS = [2.0 ** i for i in range(11)]   # 1 … 1024
 
 _ACTIVE_SPAN: contextvars.ContextVar = contextvars.ContextVar(

@@ -1,5 +1,5 @@
 """Where jax's persistent compilation cache lives — one rule for every
-entry point (``chip_smoke.py``, ``bench.py`` phase children, the engine's
+entry point (``chip_smoke.py``, ``benchmark/run.py``, the engine's
 ``compile_cache_dir`` key).
 
 The directory is part of the cache key, so a cache that moves never

@@ -307,7 +307,7 @@ def generate() -> str:
     buf.write(
         "## Subsystem configs documented elsewhere\n\n"
         "- `autotuning` — autotuning/autotuner.py (`dstpu --autotuning "
-        "run`; see docs/performance.md)\n"
+        "run`, `bin/dstpu_autotune`)\n"
         "- `elasticity` — elasticity/config.py (v0.1/v0.2 semantics, "
         "`bin/dstpu_elastic`)\n"
         "- `compression_training` — compression/compress.py (QAT, pruning, "

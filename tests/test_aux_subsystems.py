@@ -78,37 +78,6 @@ def test_flops_profiler_per_module_breakdown():
     assert sum(gbd.values()) > 2.0 * sum(bd.values())
 
 
-def test_profile_step_smoke_module_attribution(tmp_path):
-    """scripts/profile_step.py --smoke: the xplane capture+parse path
-    runs without hardware, and the r5 measured-time-per-module join
-    (device op names -> HLO proto metadata.op_name -> flax module path)
-    lands device time on the model's blocks (VERDICT r4 #7, the xprof
-    half of the reference profiler's per-module attribution)."""
-    import json
-    import os
-    import subprocess
-    import sys
-
-    root = os.path.join(os.path.dirname(__file__), "..")
-    env = os.environ.copy()
-    env.pop("XLA_FLAGS", None)   # conftest's 8-dev flag must not leak
-    env["PYTHONPATH"] = os.path.abspath(root)
-    env["JAX_PLATFORMS"] = "cpu"
-    proc = subprocess.run(
-        [sys.executable, "scripts/profile_step.py", "--smoke",
-         "--trace-dir", str(tmp_path / "trace")],
-        capture_output=True, text=True, timeout=540, cwd=root, env=env)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-    raw = proc.stdout
-    start = raw.rfind("\n{\n")
-    rep = json.loads(raw[start + 1:] if start != -1 else raw)
-    assert rep["device_total_us"] > 0
-    mods = rep["by_module"]
-    layer_keys = [k for k in mods if k.startswith("GPT2/h_")]
-    assert layer_keys, mods  # block-level attribution present
-    assert all(mods[k]["us"] > 0 for k in layer_keys)
-
-
 def test_number_to_string():
     from deepspeed_tpu.profiling.flops_profiler import number_to_string
     assert number_to_string(2.5e12) == "2.50 T"

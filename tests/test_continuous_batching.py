@@ -249,351 +249,3 @@ def test_tensor_parallel_server_matches_single():
         res = srv.drain()
         outs.append([res[i] for i in ids])
     assert outs[0] == outs[1]
-
-
-def test_bench_serve_continuous_smoke():
-    """The bench phase's CPU smoke mode runs end-to-end and records the
-    headline artifacts, including the continuous-vs-oneshot slot-unit
-    win on the staggered trace."""
-    import argparse
-    import bench
-    args = argparse.Namespace(iters=2, requests=10, arrival_rate=0.5,
-                              smoke=True)
-    rec = bench.phase_serve(args)
-    assert rec["phase"] == "serve-continuous"
-    assert rec["smoke"] is True
-    assert rec["parity_exact"] is True
-    assert rec["units_continuous"] < rec["units_oneshot"]
-    assert rec["decode_traces"] == 1
-    assert 0.0 < rec["slot_occupancy"] <= 1.0
-    for k in ("tokens_per_s", "token_lat_p50_ms", "token_lat_p90_ms"):
-        assert k in rec
-    # telemetry snapshot embedded (docs/observability.md): histograms
-    # populated, quantiles ordered, pool gauges present
-    tm = rec["telemetry"]
-    for k in ("ttft_p50_ms", "ttft_p90_ms", "queue_wait_p50_ms",
-              "queue_wait_p90_ms", "decode_token_p50_ms",
-              "slot_occupancy_last", "kv_free_blocks"):
-        assert k in tm, k
-    assert tm["ttft_count"] >= rec["requests"]     # every request + warmup
-    assert tm["requests_finished"] >= rec["requests"]
-    assert tm["ttft_p50_ms"] > 0
-    assert tm["ttft_p50_ms"] <= tm["ttft_p90_ms"]
-    assert tm["queue_wait_p50_ms"] <= tm["queue_wait_p90_ms"]
-    assert tm["decode_token_p50_ms"] > 0
-    # flight-recorder blob (docs/observability.md): one decode trace,
-    # no retraces mid-replay, compiles timed
-    fr = rec["flight_recorder"]
-    assert fr["decode_traces"] == 1
-    assert fr["retraces"] == 0
-    assert fr["prefill_traces"] >= 1
-    assert fr["compile_seconds_total"] > 0
-    # request-tracing blob (docs/observability.md "Request tracing &
-    # SLOs"): every replay request kept (sample rate 1.0), span trees
-    # non-trivial
-    tb = rec["tracing"]
-    assert tb["sample_rate"] == 1.0
-    assert tb["kept"] >= rec["requests"]      # every request + warmup
-    assert tb["started"] >= tb["kept"] >= 1
-    assert tb["spans_per_trace_p50"] >= 3     # root+queue+admission+...
-    # SLO blob: generous objectives, so a healthy replay is compliant
-    # and every configured objective was evaluated with a real value
-    sb = rec["slo"]
-    assert sb["compliance_ratio"] == 1.0
-    assert sb["evaluations"] >= 1
-    assert set(sb["objectives"]) == {"ttft_p90", "token_p50",
-                                     "queue_wait_p90", "error_rate"}
-    for obj in sb["objectives"].values():
-        assert obj["violated"] is False
-    # closed-loop mini-legs (docs/observability.md "SLOs, alerting &
-    # incidents"): the undisturbed leg must not page — any false
-    # positive is a semantics regression — while the seeded-kill leg
-    # must walk the availability rule through firing -> resolved with
-    # EXACTLY ONE incident bundle (episode rate limit) and still finish
-    # every request via failover; the canary probes the same pool
-    # throughout and must stay green on both legs
-    assert sb["false_positive_alerts"] == 0
-    assert sb["alerts_fired"] >= 1
-    assert sb["alerts_resolved"] >= 1
-    assert sb["bundle_captured"] == 1
-    assert sb["chaos_finished"] == 4
-    assert sb["canary_success_ratio"] == 1.0
-    assert 0 < sb["canary_p50_ms"] <= sb["canary_p90_ms"]
-    # shared-prefix replay (auto 8 requests in smoke mode): prefix
-    # caching must actually hit, skip prefill compute vs the cold
-    # baseline, and stay token-identical to caching-off
-    pc = rec["prefix_cache"]
-    assert pc["parity_exact"] is True
-    assert pc["hit_rate"] >= 0.5
-    assert pc["blocks_reused"] > 0
-    assert pc["prefill_tokens_skipped"] > 0
-    assert pc["prefill_token_units"] < pc["prefill_token_units_cold"]
-    assert pc["chunk_traces"] == 1
-    # overload A/B (auto in smoke mode): with the lifecycle layer on
-    # (deadlines + priorities + SLO shedding), accepted-request p90
-    # per-token latency AND goodput under the shared deadline are
-    # strictly better than plain FIFO at the same overload arrival
-    # rate — and the degradation ladder demonstrably fired
-    # (The two legs' SECONDS are compared by bench.py, which records its
-    # verdicts; this test, which shares its CPU with five other workers,
-    # asserts what the same legs COUNT: a per-token latency in step()
-    # calls, the ladder's rungs. ~0.15 s legs whose goodput differs by
-    # a tenth flip under load, three attempts or not.)
-    lc = rec["lifecycle"]
-    on, off = lc["on"], lc["off"]
-    assert isinstance(lc["p90_improved"], bool)
-    assert isinstance(lc["goodput_improved"], bool)
-    assert on["token_p90_ms"] > 0 and off["token_p90_ms"] > 0
-    assert on["goodput_tokens_per_s"] > 0
-    assert on["token_p90_steps"] < off["token_p90_steps"]
-    assert on["shed"] + on["deadline_expired"] >= 1
-    assert on["preempted"] >= 1
-    assert on["accepted"] >= 1
-    # the off-leg is the no-lifecycle baseline: nothing degraded
-    assert (off["shed"], off["deadline_expired"], off["preempted"],
-            off["cancelled"], off["failed"]) == (0, 0, 0, 0, 0)
-    assert off["accepted"] == lc["on"]["requests"]
-    # step observatory blob (docs/observability.md "Serving goodput &
-    # KV-pool accounting"): phases decompose step wall BY CONSTRUCTION
-    # (the 'other' residual stays ≤5%), the goodput fraction is a real
-    # fraction, the dispatch-gap detector saw every decode boundary,
-    # and the pool accounting is live
-    spb = rec["step_profile"]
-    assert spb["steps"] > 0
-    assert 0.0 < spb["goodput_fraction"] <= 1.0
-    assert abs(spb["goodput_fraction"] + spb["host_fraction"]
-               - 1.0) < 1e-6
-    assert 0.0 <= spb["residual_fraction"] <= 0.05
-    for ph in ("admission", "propose", "dispatch", "sync_wait",
-               "commit", "publish"):
-        assert ph in spb["phases"], ph
-    # phase totals reconcile with the step wall (identity up to float
-    # rounding in the blob)
-    assert abs(sum(p["total_s"] for p in spb["phases"].values())
-               - spb["wall_s"]) <= 0.05 * spb["wall_s"] + 1e-5
-    assert spb["dispatch_gap_count"] >= 1
-    assert spb["dispatch_gap_p90_ms"] is not None
-    assert spb["dispatch_gap_p90_ms"] >= 0.0
-    assert 0.0 <= spb["pool"]["fragmentation_free_run_ratio"] <= 1.0
-    assert spb["pool"]["block_lifetime_p50_ms"] is not None
-    assert spb["pool"]["peak_blocks_p90"] >= 1
-    # speculation A/B (auto K=4 in smoke mode, docs/serving.md
-    # "Per-slot speculative decoding"): on the lookup-friendly
-    # repetitive trace the verify forward must commit MORE than one
-    # token per slot per forward, slot-step efficiency must be strictly
-    # higher than the non-speculative leg (which is 1.0 by
-    # construction), the outputs must be token-identical, and the
-    # verify step must have compiled exactly ONE executable with zero
-    # retraces across the replay's varying acceptance lengths
-    sp = rec["speculation"]
-    assert sp["k"] == 4
-    assert sp["tokens_per_forward"] > 1.0
-    assert sp["slot_step_efficiency_off"] == 1.0
-    assert sp["slot_step_efficiency_on"] > sp["slot_step_efficiency_off"]
-    assert sp["decode_steps_on"] < sp["decode_steps_off"]
-    assert 0.0 < sp["acceptance_rate"] <= 1.0
-    assert sp["parity_exact"] is True
-    assert sp["verify_traces"] == 1
-    assert sp["retraces_on"] == 0
-    # async dispatch loop A/B (auto in smoke, docs/serving.md "Async
-    # dispatch loop"): pipelined dispatch with lag-1 commit closes the
-    # device-idle gap BY CONSTRUCTION for every dispatch that lands on
-    # a busy device (counted: some ON, none OFF), greedy output
-    # token-identical to the synchronous loop. The gap p90, the
-    # host-tax share and tokens/s are this machine's seconds: bench.py
-    # compares and records them, and a run on the chip reads them
-    al = rec["async_loop"]
-    assert al["parity_exact"] is True
-    for verdict in ("gap_improved", "host_fraction_improved",
-                    "tokens_per_s_no_worse"):
-        assert isinstance(al[verdict], bool), verdict
-    for leg in ("on", "off"):
-        assert al[leg]["dispatch_gap_p90_ms"] is not None
-        assert 0.0 < al[leg]["host_fraction"] < 1.0
-        assert al[leg]["dispatch_boundaries"] >= 1
-    assert al["on"]["pipelined_dispatches"] >= 1
-    assert al["off"]["pipelined_dispatches"] == 0
-    assert al["on"]["pipelined_steps"] >= 1
-    assert al["on"]["retraces"] == 0
-    assert al["on"]["decode_traces"] == 1     # zero new executables
-    assert al["off"]["pipelined_steps"] == 0  # the off-leg never chains
-    # the flake-class fix: the tokens/s basis is recorded
-    # unconditionally so a reader always knows which evidence (single
-    # attempt inside the symmetric floor, best-of-attempts, or the
-    # structural skip) carried the no-worse verdict
-    assert al["tokens_per_s_basis"] in (
-        "single_attempt", "best_of_attempts", "noise_floor_skip")
-    # lag-N dispatch-chain A/B (auto N=2 in smoke): deeper chains keep
-    # exact parity through the SAME decode executable, the profiler's
-    # depth histogram proves the chain deepened past lag-1, and the
-    # chained dispatches land on a busy device (gap p90 no worse)
-    cl = rec["commit_lag"]
-    assert cl["max_commit_lag"] == 2
-    assert cl["parity_exact"] is True
-    assert isinstance(cl["gap_no_worse"], bool)      # seconds: recorded
-    assert cl["gap_basis"] in ("single_attempt", "best_of_attempts")
-    assert isinstance(cl["tokens_per_s_no_worse"], bool)
-    # counted: the deeper chain lands more dispatches on a busy device
-    assert cl["lagN"]["pipelined_dispatches"] >= 1
-    assert cl["tokens_per_s_basis"] in (
-        "single_attempt", "best_of_attempts", "noise_floor_skip")
-    # the lag-2 chain demonstrably deepened past the lag-1 loop's
-    # steady state (dispatch-over-one-outstanding records depth 2)
-    assert cl["depth_max"] >= 3
-    assert cl["lag1"]["commit_lag_depth_max"] <= 2
-    assert cl["lagN"]["decode_traces"] == 1   # zero new executables
-    assert cl["lagN"]["retraces"] == 0
-    assert cl["dispatch_gap_p90_ms"] is not None
-    # chained chunked-prefill leg (auto in smoke): chaining the
-    # non-final chunks must cut the admission dispatch-gap tax —
-    # structurally (fewer device-idle events per replay,
-    # deterministic) and in total idle seconds (noise-disciplined) —
-    # at byte-identical outputs and the same ONE chunk executable
-    pfc = rec["prefill_chain"]
-    assert pfc["parity_exact"] is True
-    assert pfc["gap_samples_improved"] is True
-    assert pfc["on"]["dispatch_gap_count"] < \
-        pfc["off"]["dispatch_gap_count"]
-    assert isinstance(pfc["gap_improved"], bool)     # idle SECONDS
-    assert pfc["gap_basis"] in (
-        "single_attempt", "best_of_attempts", "noise_floor_skip")
-    assert pfc["dispatch_gap_p90_ms"] is not None
-    assert pfc["on"]["prefill_chunks"] == pfc["off"]["prefill_chunks"]
-    assert pfc["on"]["chunk_traces"] == 1
-    assert pfc["on"]["retraces"] == 0
-    # draft-model speculation A/B (auto in smoke): on the
-    # non-repetitive trace the draft proposals must convert verify
-    # width into committed tokens where lookup cannot, token-identical
-    # outputs, through the SAME verify executable
-    sd = rec["speculation_draft"]
-    assert sd["parity_exact"] is True
-    assert sd["draft_beats_lookup"] is True
-    assert sd["tokens_per_forward"] > sd["tokens_per_forward_lookup"]
-    assert sd["tokens_per_forward"] > 1.0
-    assert sd["verify_traces"] == 1
-    assert sd["retraces"] == 0
-    # KV tiering A/B (auto int8+offload in smoke, docs/serving.md "KV
-    # quantization & host tiering"): the int8 pool at 2x the slots
-    # costs LESS device memory than the fp baseline (capacity ratio
-    # >= 2 bytes/slot), actually sustains 2x the concurrent residents
-    # at exact greedy parity with ONE decode executable — and the
-    # offload replay demotes cold blocks to host RAM, swaps them back
-    # on prefix hits (token-identical to a never-evicted pool, zero
-    # evictions, zero preemptions) with host-tier bytes visible the
-    # way /debug/memory reports them
-    kt = rec["kv_tiering"]
-    assert kt["kv_dtype"] == "int8"
-    assert kt["capacity_ratio"] >= 2.0
-    assert kt["pool_bytes_int8"] <= kt["pool_bytes_fp"]
-    assert kt["max_resident_int8"] >= 2 * kt["max_resident_fp"]
-    assert kt["parity_exact"] is True
-    assert kt["decode_traces_int8"] == 1
-    assert kt["retraces_int8"] == 0
-    off = kt["offload"]
-    assert off["parity_exact"] is True
-    assert off["demotions"] > 0
-    assert off["swap_ins"] > 0
-    assert off["evictions"] == 0
-    assert off["preempted"] == 0
-    assert off["host_bytes_visible"] is True
-    assert off["swap_outs_accounted"] == off["demotions"]
-    # replicated-serving A/B (auto 2 replicas + seeded kill in smoke,
-    # docs/serving.md "Replicated serving & failover"): with a replica
-    # killed mid-decode, EVERY submitted request still finishes
-    # eos/length (availability 1.0 — the replication.availability
-    # regression gate's input) token-identical to the undisturbed leg,
-    # failover demonstrably fired with bounded replay-token overhead,
-    # and the per-replica stats rows name exactly one dead replica
-    # disaggregated prefill/decode A/B (auto in smoke, docs/serving.md
-    # "Disaggregated prefill/decode"): under the long-prompt +
-    # resident-decoder interference mix, role-split decode per-token
-    # p90 must not exceed colocated at equal total slots (the attempts/
-    # best-of noise discipline rides in decode_p90_improved), outputs
-    # token-identical, every handoff block consumed (none stranded),
-    # handoff volume per request recorded, and the decode replica kept
-    # ONE decode executable with zero retraces — the handoff reuses
-    # the existing match_prefix -> paged_swap_in machinery
-    dg = rec["disaggregation"]
-    assert dg["roles"] == ["prefill", "decode"]
-    assert dg["parity_exact"] is True
-    assert isinstance(dg["decode_p90_improved"], bool)   # seconds
-    assert dg["decode_p90_basis"] in ("single_attempt",
-                                      "best_of_attempts")   # both sampled
-    assert dg["decode_p90_ratio"] > 0
-    assert dg["disaggregated"]["handoffs"] >= dg["interferers"]
-    assert dg["disaggregated"]["handoff_blocks_published"] > 0
-    assert dg["disaggregated"]["handoff_blocks_consumed"] == \
-        dg["disaggregated"]["handoff_blocks_published"]
-    assert dg["disaggregated"]["handoff_stranded_blocks"] == 0
-    assert dg["disaggregated"]["handoff_bytes_per_request"] > 0
-    assert dg["disaggregated"]["decode_swap_ins"] > 0
-    assert dg["disaggregated"]["decode_traces"] == 1
-    assert dg["disaggregated"]["retraces"] == 0
-    assert dg["colocated"]["handoffs"] == 0    # the baseline never splits
-    rp = rec["replication"]
-    assert rp["replicas"] == 2
-    assert rp["chaos_kill"] is True
-    assert rp["availability"] == 1.0
-    assert rp["availability_undisturbed"] == 1.0
-    assert rp["parity_exact"] is True
-    assert rp["failovers"] >= 1
-    assert rp["dead_replicas"] == 1
-    assert rp["replay_tokens"] >= 1
-    assert 0.0 < rp["replay_token_overhead"] < 1.0
-    assert rp["token_p90_ms"] is not None
-    rows = rp["replicas_stats"]
-    assert len(rows) == 2
-    assert sum(1 for r in rows if r["health"] == "dead") == 1
-    assert all(r["routed"] >= 1 for r in rows)
-    # fleet observability leg (auto in smoke, docs/observability.md
-    # "Fleet observability"): the role-split + seeded-kill run must
-    # exercise every stitching path (submit, handoff AND failover hop
-    # causes), every multi-leg request's kept trace must carry its hop
-    # spans (coverage 1.0 — a lost hop is a blind leg), the federated
-    # scrape's pool rollup must equal the per-replica sums even with
-    # one replica dead (the staleness contract: last snapshot still
-    # merges), replica label cardinality stays bounded by the pool
-    # size, and the scrape p90 (the fleet_obs.scrape_p90_ms regression
-    # gate's input) is a real measured wall
-    fo = rec["fleet_obs"]
-    assert fo["replicas"] == 2
-    assert fo["finished_ok"] == fo["requests"]
-    assert fo["scrapes"] >= 3
-    assert fo["scrape_p90_ms"] is not None and fo["scrape_p90_ms"] > 0
-    assert fo["hops_by_cause"]["submit"] >= 1
-    assert fo["hops_by_cause"]["handoff"] >= 1
-    assert fo["hops_by_cause"]["failover"] >= 1
-    assert fo["hops_total"] == sum(fo["hops_by_cause"].values())
-    assert fo["hops_total"] > fo["requests"]   # somebody crossed legs
-    assert fo["multi_leg_requests"] >= 1
-    assert fo["stitched_coverage"] == 1.0
-    assert fo["merged_parity"] is True
-    assert fo["dead_replicas"] == 1
-    labels = set(fo["replica_label_values"])
-    assert {"r0", "r1", "pool"} <= labels
-    assert len(labels) <= 2 * fo["replicas"] + 1   # bounded cardinality
-    # cost accounting blob (docs/observability.md "Cost accounting &
-    # capacity"): every replay request billed (requests + warmup), the
-    # closure residual within the wall-clock tolerance (fake-clock
-    # exactness is pinned by tests/test_accounting.py — here the replay
-    # runs on the monotonic clock), per-tenant device shares summing to
-    # 1 across the three cycled tenants, unit cost positive (the
-    # cost.device_seconds_per_1k_tokens regression gate's input), and
-    # the capacity model evaluated with real post-replay rates
-    co = rec["cost"]
-    assert co["requests_billed"] == rec["requests"] + 1   # + warmup
-    assert co["device_seconds_per_1k_tokens"] > 0
-    assert co["device_seconds_total"] > 0
-    assert co["closure_residual"] <= 0.05
-    assert co["kv_block_seconds_total"] > 0
-    assert set(co["tenant_device_share"]) == {"acme", "beta", "corp"}
-    assert sum(co["tenant_device_share"].values()) == \
-        pytest.approx(1.0, abs=0.01)
-    cap = co["capacity"]
-    assert cap["enabled"] is True
-    assert cap["tokens_per_s"] > 0
-    assert cap["sustainable_tokens_per_s"] > 0
-    assert cap["admissible_requests_per_s"] > 0
-    # the whole record (snapshot included) survives a JSON round-trip
-    import json
-    assert json.loads(json.dumps(rec))["telemetry"] == tm

@@ -278,6 +278,9 @@ def _prefill_chain_parity_case(n_prompts):
     assert st_on["chunk_traces"] == 1
     assert st_on["retraces"] == 0
     assert st_on["async_loop"]["prefill_chain"] is True
+    # ... and the chained chunks leave the device idle fewer times
+    assert st_on["step_profile"]["dispatch_gap"]["count"] < \
+        st_off["step_profile"]["dispatch_gap"]["count"]
 
 
 def test_prefill_chain_parity_at_batch_size(fresh_telemetry):
@@ -338,15 +341,20 @@ def test_prefill_chain_composes_with_lag_and_prefix_cache(
 
 # ------------------------------------------------- draft-model speculation
 
+@pytest.mark.parametrize("tied", [False, True],
+                         ids=["small-draft", "tied-draft"])
 def test_draft_spec_greedy_parity_and_zero_new_target_executables(
-        fresh_telemetry):
+        tied, fresh_telemetry):
     """Draft proposals feed the SAME paged verify: output token-
     identical to one-shot generate_speculative(draft=...) (and so to
     greedy generate), with the target pinned at one verify executable
-    and zero retraces at any acceptance pattern."""
+    and zero retraces at any acceptance pattern: the small draft's
+    proposals nearly all miss, those of a draft with the target's own
+    weights all land, so the draft's mirrored pool and batched forwards
+    carried the target's own next tokens into the verify."""
     K = 4
     eng = make_engine(speculation_tokens=K)
-    draft = make_draft()
+    draft = make_engine() if tied else make_draft()
     ref = make_engine().generate_speculative(
         PROMPTS[:6], draft=draft, max_new_tokens=12, draft_tokens=K)
     assert ref == make_engine().generate(PROMPTS[:6], max_new_tokens=12)
@@ -360,7 +368,11 @@ def test_draft_spec_greedy_parity_and_zero_new_target_executables(
     assert st["retraces"] == 0
     assert sp["draft_decode_traces"] == 1  # one draft decode program
     assert sp["proposed"] == (K - 1) * srv._spec_slot_steps
-    assert sp["tokens_per_forward"] is not None
+    if tied:
+        assert sp["accepted"] == sp["proposed"]
+        assert sp["tokens_per_forward"] > 1.0
+    else:
+        assert sp["tokens_per_forward"] is not None
 
 
 def test_draft_via_config_field_wires_server(fresh_telemetry):

@@ -472,6 +472,45 @@ def test_held_violation_verdict_does_not_shed_fresh_burst(
 
 # ------------------------------------------------- famine ladder order
 
+def test_overload_lifecycle_on_bounds_the_tail_fifo_lets_grow(
+        fresh_telemetry):
+    """Two arrivals a step against two slots for eight steps, one fake
+    second a step, the same trace twice: plain FIFO serves every request
+    and the last ones wait out the whole backlog; with deadlines and
+    load shedding on, what is served finishes inside the deadline and
+    the rest is shed or reaped, in steps, on no machine's clock."""
+    def leg(on):
+        clock = FakeClock()
+        knobs = dict(enable_load_shedding=True,
+                     telemetry=SHED_TELEM) if on else {}
+        per_request = {"deadline_s": 6.0} if on else {}
+        srv = ContinuousBatchingServer(make_engine(num_slots=2, **knobs),
+                                       clock=clock)
+        born, took, step = {}, [], 0
+        while step < 8 or not srv.scheduler.idle:
+            if step < 8:
+                for j in range(2):
+                    born[srv.submit([1 + step, 2 + j], max_new_tokens=4,
+                                    **per_request)] = step
+            for rid in srv.step():
+                if srv.finish_reason(rid) in ("eos", "length"):
+                    took.append(step + 1 - born[rid])
+            clock.advance(1.0)
+            step += 1
+        return sorted(took), srv.stats
+
+    fifo, st_off = leg(False)
+    served, st_on = leg(True)
+    assert len(fifo) == 16
+    assert (st_off["shed"], st_off["deadline_expired"],
+            st_off["preempted"], st_off["cancelled"],
+            st_off["failed"]) == (0, 0, 0, 0, 0)
+    assert served and max(served) <= 7 < max(fifo)
+    assert served[int(len(served) * 0.9)] < fifo[int(len(fifo) * 0.9)]
+    assert st_on["shed"] >= 1 and st_on["deadline_expired"] >= 1
+    assert len(served) + st_on["shed"] + st_on["deadline_expired"] == 16
+
+
 def test_famine_ladder_evict_then_preempt_then_shed(fresh_telemetry):
     """The degradation ladder under block famine fires its rungs in
     order — prefix-LRU eviction, then preemption, then shedding — and
