@@ -243,11 +243,14 @@ class InferenceEngine:
 
     def _refuse_for_family(self) -> None:
         """A model of another family (``model_family``: latent attention
-        over a latent pool, retention over a state pool) runs on one
-        device with full-precision weights: its parameter tree has no
-        Megatron specs, it issues no exchange, and nothing quantizes its
-        weights. Each switch that would need one of those is refused
-        here by name."""
+        over a latent pool, retention over a state pool, window layers'
+        rings or state-space layers' states beside the K/V block pool)
+        runs on one device with full-precision weights: its parameter
+        tree has no Megatron specs, it issues no exchange, and nothing
+        quantizes its weights. Each switch that would need one of those
+        (``dtype='int8'`` / ``quant.enabled``,
+        ``quant.activation.enabled``, ``tp_size``, ``moe.ep_size``,
+        ``seq_parallel_size``) is refused here by name."""
         c = self.config
         on = [name for name, is_on in (
             ("dtype='int8' / quant.enabled", self._weight_quant),

@@ -219,6 +219,18 @@ class PagedKVCache:
     the OTHER layers only: model layer ``l`` is ``layer_map[l] = (kind,
     i)``, ``i`` the layer's index in the pool (``"full"``) or in the
     rings (``"window"``).
+
+    State layers (``layer_map`` kind ``"state"``): a layer that keeps a
+    recurrent STATE and no row a token (a state-space mixer) is a third
+    place under the same map: ``state[i]`` ``[S, *state_shape]`` (float32
+    unless asked otherwise), one slot-major buffer a state layer, and
+    ``conv[i]`` ``[taps, S, C]``, the last ``taps`` inputs of the layer's
+    short convolution (tap-major: the slots and the channels tile the
+    sublanes and lanes, a 3-wide minor dim would be padded to 128). As a
+    ring, a state is a fixed cost a slot whatever the context: it takes
+    no block of the pool and no entry of the block tables, a prefill
+    writes one slot of a donated buffer in place, and a retired slot's
+    state is overwritten by the next prefill and never read.
     aux: an int32 array that belongs to the MODEL, as
     :class:`LatentPagedCache`'s (None: the model counts nothing)."""
     k: jnp.ndarray             # [L, NB, BS, KH*D] (fp or int8)
@@ -233,6 +245,8 @@ class PagedKVCache:
     aux: Optional[jnp.ndarray] = None       # the model's; int32
     layer_map: Optional[tuple] = struct.field(pytree_node=False,
                                               default=None)
+    state: Optional[tuple] = None           # of [S, *state_shape] | None
+    conv: Optional[tuple] = None            # of [taps, S, C] | None
 
     @property
     def quantized(self) -> bool:
@@ -289,10 +303,16 @@ def window_layer_map(window_layers) -> tuple:
     """Model layer -> ``(kind, index among the layers of its kind)``,
     ``kind`` ``"window"`` (a ring) or ``"full"`` (the pool), from a bool
     a layer."""
-    counts = {"full": 0, "window": 0}
+    return kind_layer_map("window" if w else "full" for w in window_layers)
+
+
+def kind_layer_map(kinds) -> tuple:
+    """Model layer -> ``(kind, index among the layers of its kind)`` from
+    a kind a layer: ``"full"`` (blocks of the pool), ``"window"`` (a
+    ring a slot) or ``"state"`` (a recurrent state a slot)."""
+    counts = {"full": 0, "window": 0, "state": 0}
     out = []
-    for is_window in window_layers:
-        kind = "window" if is_window else "full"
+    for kind in kinds:
         out.append((kind, counts[kind]))
         counts[kind] += 1
     return tuple(out)
@@ -305,7 +325,10 @@ def init_paged_cache(num_layers: int, num_slots: int, num_blocks: int,
                      quantized: bool = False,
                      window_layers: Optional[tuple] = None,
                      window: int = 0,
-                     aux_shape: Optional[tuple] = None) -> PagedKVCache:
+                     aux_shape: Optional[tuple] = None,
+                     state_layers: Optional[tuple] = None,
+                     state_shapes: Optional[tuple] = None,
+                     state_dtype=jnp.float32) -> PagedKVCache:
     """``num_blocks`` INCLUDES the reserved null block 0, so the usable
     pool is ``num_blocks - 1`` blocks. ``quantized=True`` builds the
     int8 pool (payload dtype int8 regardless of ``dtype``) with
@@ -314,23 +337,46 @@ def init_paged_cache(num_layers: int, num_slots: int, num_blocks: int,
 
     ``window_layers`` (a bool a model layer) with ``window``: the layers
     marked true keep a ring of :func:`ring_blocks_for` blocks a slot
-    beside the pool, which then holds the other layers only."""
-    layer_map = rings = None
-    if window_layers is not None and any(window_layers):
+    beside the pool, which then holds the other layers only.
+
+    ``state_layers`` (a bool a model layer) with ``state_shapes``
+    (``(state_shape, (taps, channels))``, one slot's of one layer): the
+    layers marked true keep a recurrent state and a convolution tail a
+    slot (``state_dtype`` and ``dtype``) and no row; the pool holds the
+    other layers only."""
+    layer_map = rings = states = None
+    n_window = sum(window_layers) if window_layers is not None else 0
+    n_state = sum(state_layers) if state_layers is not None else 0
+    if n_window or n_state:
         if quantized:
-            raise NotImplementedError("window layers' rings have no int8 "
-                                      "rows or scale tiles")
-        if len(window_layers) != num_layers:
-            raise ValueError(f"{len(window_layers)} layer kinds for "
+            raise NotImplementedError(
+                "window layers' rings and state layers' states have no "
+                "int8 rows or scale tiles")
+        if n_window and n_state:
+            raise NotImplementedError("window layers' rings beside state "
+                                      "layers' states")
+        marked = window_layers if n_window else state_layers
+        if len(marked) != num_layers:
+            raise ValueError(f"{len(marked)} layer kinds for "
                              f"{num_layers} layers")
-        layer_map = window_layer_map(window_layers)
-        n_window = sum(window_layers)
-        # a model of window layers only still has a (one-layer) pool:
-        # the block tables and the null block stay what they are
-        num_layers = max(num_layers - n_window, 1)
+        beside = "window" if n_window else "state"
+        layer_map = kind_layer_map(beside if m else "full" for m in marked)
+        # a model of window (or state) layers only still has a
+        # (one-layer) pool: the block tables and the null block stay
+        # what they are
+        num_layers = max(num_layers - n_window - n_state, 1)
+    if n_window:
         rings = (n_window,
                  num_slots * ring_blocks_for(window, block_size),
                  block_size, num_kv_heads * head_dim)
+    if n_state:
+        s_shape, (taps, channels) = state_shapes
+        # one array PER layer and field: a buffer shared by two would be
+        # donated twice in the serving jits
+        states = (tuple(jnp.zeros((num_slots, *s_shape), state_dtype)
+                        for _ in range(n_state)),
+                  tuple(jnp.zeros((taps, num_slots, channels), dtype)
+                        for _ in range(n_state)))
     shape = (num_layers, num_blocks, block_size, num_kv_heads * head_dim)
     pool_dtype = jnp.int8 if quantized else dtype
 
@@ -352,7 +398,17 @@ def init_paged_cache(num_layers: int, num_slots: int, num_blocks: int,
         ring_k=None if rings is None else jnp.zeros(rings, dtype),
         ring_v=None if rings is None else jnp.zeros(rings, dtype),
         aux=None if aux_shape is None else jnp.zeros(aux_shape, jnp.int32),
-        layer_map=layer_map)
+        layer_map=layer_map,
+        state=None if states is None else states[0],
+        conv=None if states is None else states[1])
+
+
+def with_state_layer(cache: PagedKVCache, i: int, state,
+                     conv) -> PagedKVCache:
+    """``cache`` with state layer ``i``'s two buffers replaced."""
+    return cache.replace(
+        state=cache.state[:i] + (state,) + cache.state[i + 1:],
+        conv=cache.conv[:i] + (conv,) + cache.conv[i + 1:])
 
 
 def _quant_rows(cache: PagedKVCache, x: jnp.ndarray):
@@ -803,10 +859,12 @@ def pool_arrays(cache) -> tuple:
         return tuple(cache.S) + tuple(cache.z)
     if isinstance(cache, LatentPagedCache):
         return tuple(cache.rows)
-    rings = () if cache.ring_k is None else (cache.ring_k, cache.ring_v)
+    beside = () if cache.ring_k is None else (cache.ring_k, cache.ring_v)
+    if cache.state is not None:
+        beside += tuple(cache.state) + tuple(cache.conv)
     if cache.k_scale is None:
-        return (cache.k, cache.v) + rings
-    return (cache.k, cache.v, cache.k_scale, cache.v_scale) + rings
+        return (cache.k, cache.v) + beside
+    return (cache.k, cache.v, cache.k_scale, cache.v_scale) + beside
 
 
 # ------------------------------------------------------------- host tier

@@ -157,7 +157,10 @@ class _RequestTrace:
 
 # ------------------------------------------------------ kinds of pool
 # What the server does differently for each kind of cache a model's
-# programs thread through (``model_config.cache_kind``): how the pool is
+# programs thread through (``model_config.cache_kind``: ``kv`` the
+# generic decoder's K/V pool; ``kv_window`` and ``kv_state`` the same
+# pool with rings / recurrent states a slot beside it under one
+# allocator; ``latent``; ``state``): how the pool is
 # built, whether its programs read a block table, and which switches it
 # cannot honour (a model that counts on the device says so itself: its
 # configuration has an ``aux_shape``). A
@@ -235,6 +238,28 @@ _POOL_KINDS = {
             "it chains chunked prefill",
             "a rejected draft token's row cannot be taken back out of a "
             "ring")),
+    # the K/V pool with state layers in its map: a state-space layer
+    # keeps a recurrent state and a convolution tail a slot beside the
+    # block pool of the attention layers. One pool class, one allocator,
+    # one admission rule (a slot and the attention layers' blocks); what
+    # works on EVERY layer's rows cannot see a state
+    "kv_state": _PoolKind(
+        make_pool="_make_kv_pool",
+        what="a model with state layers (a recurrent state a slot beside "
+             "the block pool)",
+        serves="monolithic bucketed prefill (the chunked form inside one "
+               "program) and paged decode over state updates serve it",
+        refuses=_rows_switches(
+            "a state is float32 and has no int8 rows or scale tiles",
+            "a block's payload is every layer's slab, and a state layer "
+            "has none in a block",
+            "a cached block holds no state layer's state at its boundary; "
+            "reusing a prefix needs a snapshot of the states there",
+            "no program carries a state layer's state and convolution "
+            "tail from one prompt chunk to the next",
+            "it chains chunked prefill",
+            "a rejected draft token cannot be taken back out of a "
+            "state")),
     "latent": _PoolKind(
         make_pool="_make_latent_pool",
         what="a latent-attention model (latent paged cache)",
@@ -1197,7 +1222,17 @@ class ContinuousBatchingServer:
             quantized=self.kv_dtype == "int8",
             window_layers=getattr(mcfg, "window_layers", None),
             window=getattr(mcfg, "sliding_window", 0) or 0,
-            aux_shape=getattr(mcfg, "aux_shape", None))
+            aux_shape=getattr(mcfg, "aux_shape", None),
+            state_layers=getattr(mcfg, "state_layers", None),
+            state_shapes=getattr(mcfg, "state_shapes", None),
+            state_dtype=getattr(mcfg, "state_dtype", jnp.float32))
+        if cache.state is not None:
+            self.telemetry.gauge(
+                "serve_kv_state_bytes",
+                help="bytes of the state layers' states and convolution "
+                     "tails (every slot): a fixed cost a slot, no block of "
+                     "the pool"
+            ).set(sum(a.nbytes for a in cache.state + cache.conv))
         if cache.ring_k is not None:
             self.telemetry.gauge(
                 "serve_kv_ring_bytes",

@@ -65,6 +65,7 @@ from deepspeed_tpu.inference.kv_cache import (RecurrentStateCache,
                                               with_layer_state)
 from deepspeed_tpu.ops.pallas import power_retention as _ret
 from deepspeed_tpu.profiling.trace import scoped
+from deepspeed_tpu.telemetry.registry import ScaledCounter
 
 F32 = jnp.float32
 
@@ -72,20 +73,6 @@ F32 = jnp.float32
 PROGRAMS = ("decode", "prefill")
 COUNTERS = ("calls", "live_slots", "state_passes", "prefill_tokens",
             "prefill_chunks")
-
-
-class _Scaled:
-    """A registry counter that takes its growth in another unit."""
-
-    def __init__(self, counter, factor: float):
-        self.counter, self.factor = counter, factor
-
-    def inc(self, amount: float) -> None:
-        self.counter.inc(amount * self.factor)
-
-    @property
-    def value(self) -> float:
-        return self.counter.value
 
 
 def aux_series(cfg: "BrumbyConfig", reg) -> list:
@@ -106,7 +93,7 @@ def aux_series(cfg: "BrumbyConfig", reg) -> list:
                 "serve_retention_live_slots_total", labels=by,
                 help="live slots summed over decode steps (the "
                      "sequences whose state a step updated)"),
-            "state_passes": _Scaled(reg.counter(
+            "state_passes": ScaledCounter(reg.counter(
                 "serve_retention_state_bytes_total", labels=by,
                 help="recurrent state bytes moved: live slots x layers x "
                      "one slot-layer's S and z, read and written by "

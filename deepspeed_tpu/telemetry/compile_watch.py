@@ -288,7 +288,12 @@ SCOPES = frozenset((
     "ret_qkvg", "ret_state", "ret_out",
     # model_implementations/laguna.py (an attention by the kind of its
     # layer, over its projections, rotary, gate, cache write and kernel)
-    "attn_full", "attn_window", "moe_shared"))
+    "attn_full", "attn_window", "moe_shared",
+    # model_implementations/granite_hybrid.py (a Mamba mixer's input
+    # projections, convolution, decode state update / prefill chunked
+    # form, gated norm and output projection; its attention layers are
+    # ``attn_full``)
+    "mamba_in", "mamba_conv", "mamba_state", "mamba_scan", "mamba_out"))
 
 _INSTR = re.compile(r"^\s*(ROOT\s+)?%?([\w.\-]+)\s*=\s")
 _COMPUTATION = re.compile(

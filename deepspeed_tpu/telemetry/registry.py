@@ -89,6 +89,22 @@ class Counter:
             return self._value
 
 
+class ScaledCounter:
+    """A counter that takes its growth in another unit (a program that
+    counts passes on the device, a series in bytes): ``inc(n)`` adds ``n
+    x factor`` to the counter it wraps."""
+
+    def __init__(self, counter: Counter, factor: float):
+        self.counter, self.factor = counter, factor
+
+    def inc(self, amount: float = 1.0) -> None:
+        self.counter.inc(amount * self.factor)
+
+    @property
+    def value(self) -> float:
+        return self.counter.value
+
+
 class Gauge:
     """Last-write-wins level (occupancy, free blocks, queue depth)."""
 

@@ -728,6 +728,92 @@ def test_window_and_full_layers_share_one_program(chips, monkeypatch, kind,
         assert compiled.memory_analysis().temp_size_in_bytes < ring_layer
 
 
+GRANITE_SCOPES = {"embed", "ln", "mamba_in", "mamba_conv", "mamba_out",
+                  "attn_full", "kv_write", "moe_router", "moe_dispatch",
+                  "moe_experts", "moe_combine", "moe_shared", "lm_head",
+                  "sample"}
+
+
+@pytest.mark.parametrize("kind,kernel,scope", [
+    ("decode", "paged_decode_attention", "mamba_state"),
+    ("prefill", "flash_attention_fwd", "mamba_scan")])
+def test_state_layers_update_in_place_beside_the_block_pool(
+        chips, monkeypatch, kind, kernel, scope):
+    """The Granite hybrid's two serving programs at the published widths
+    and the cell's slots, pool and span (two Mamba layers around the
+    attention layer, two held experts and a small vocabulary), read back
+    from their compiled text: module, kernel and scope names; ONE kernel
+    call for the one attention layer, under ``attn_full``; no ``kv_read``
+    (nothing gathers the pool); and no instruction but an in-place write
+    makes anything shaped like a state layer's buffer or as large: the
+    decode update and the prefill's write of one slot happen in the
+    donated pool, and the decode program's temporaries stay under a
+    tenth of ONE state buffer."""
+    from deepspeed_tpu.inference.kv_cache import init_paged_cache
+    from deepspeed_tpu.inference.server import ContinuousBatchingServer as Srv
+    from deepspeed_tpu.model_implementations import granite_hybrid as gh
+    from deepspeed_tpu.telemetry import compile_watch
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(fa, "_should_interpret", lambda: False)
+    one = SingleDeviceSharding(chips[0])
+    slots, blocks, span, prompt = 96, 1 + 2400, 48, 1024
+    cfg = gh.GraniteHybridConfig(
+        vocab_size=2048, num_hidden_layers=3,
+        layer_types=(gh.MAMBA, gh.ATTENTION, gh.MAMBA), experts_held=(0, 2))
+    assert cfg.state_shapes == ((128, 64, 128), (3, 8448))
+    assert (cfg.n_head, cfg.kv_heads, cfg.head_dim) == (32, 8, 128)
+    abstract = functools.partial(_abstract, sharding=one)
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    params = abstract(jax.eval_shape(
+        lambda: gh.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = abstract(jax.eval_shape(lambda: init_paged_cache(
+        cfg.n_layer, slots, blocks, BS, span, cfg.kv_heads, cfg.head_dim,
+        BF16, aux_shape=cfg.aux_shape, state_layers=cfg.state_layers,
+        state_shapes=cfg.state_shapes, state_dtype=cfg.state_dtype)))
+    assert cache.k.shape == (1, blocks, BS, 1024)
+    assert [a.shape for a in cache.state] == [(slots, 128, 64, 128)] * 2
+    assert [a.shape for a in cache.conv] == [(3, slots, 8448)] * 2
+    fn, name, args = {
+        "decode": (Srv._decode_fn, "serve_decode",
+                   (params, arr((slots,)), cache, arr((slots,), jnp.bool_))),
+        "prefill": (Srv._prefill_fn, "serve_prefill",
+                    (params, arr((1, prompt)), arr((1,)), cache, arr(()))),
+    }[kind]
+    da._paged_call.cache_clear()
+    compiled = jax.jit(compile_watch._named(
+        functools.partial(fn, cfg=cfg, mesh=None), name),
+        donate_argnames=("cache",)).lower(*args).compile()
+    text = compiled.as_text()
+    assert f"HloModule jit_{name}" in text
+    scopes, kernels = compile_watch.parse_scopes(text)
+    ours = {k: v for k, v in kernels.items() if not v.startswith("ragged")}
+    assert list(ours.values()) == [kernel]
+    assert all(scopes[k].split("/")[0] == "attn_full" for k in ours)
+    words = {w for v in scopes.values() if v for w in v.split("/")}
+    assert words >= GRANITE_SCOPES | {scope}, (GRANITE_SCOPES | {scope}
+                                               ) - words
+    assert "kv_read" not in words
+    state = cache.state[0]
+    state_bytes = math.prod(state.shape) * 4
+    # (a convolution tail, 5 MB a layer, is shifted whole every step)
+    stores = (state.shape, cache.k.shape)
+    # the instructions that run, not the bodies of their fusions (whose
+    # intermediates never reach memory)
+    entry = text[text.index("\nENTRY "):]
+    assert not [c for c in _copies(
+        entry, lambda dims, nbytes: dims in stores or nbytes >= state_bytes,
+        kernels=kernels) if not any(op in c for op in _IN_PLACE)]
+    # every state layer's buffer comes back as the buffer it came in
+    assert compiled.memory_analysis().alias_size_in_bytes >= sum(
+        math.prod(a.shape) * a.dtype.itemsize
+        for a in cache.state + cache.conv + (cache.k, cache.v))
+    if kind == "decode":
+        assert compiled.memory_analysis().temp_size_in_bytes < \
+            state_bytes // 10
+
+
 def test_train_model_kernels_and_scopes(chips, monkeypatch):
     """The train step's model under the step's ``fwd_bwd`` scope: the
     two flash kernels by name, under a gradient as in the forward."""
