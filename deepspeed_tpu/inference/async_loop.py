@@ -54,12 +54,13 @@ class InFlightStep:
     """One dispatched-but-unfetched device program (see module doc)."""
 
     __slots__ = ("kind", "tokens", "states", "props", "t_dispatch",
-                 "prev_fetch")
+                 "prev_fetch", "rider")
 
     def __init__(self, kind: str, tokens: Any, states: Dict[int, Any],
                  t_dispatch: float,
                  props: Optional[Any] = None,
-                 prev_fetch: Optional[float] = None):
+                 prev_fetch: Optional[float] = None,
+                 rider: Optional[Any] = None):
         self.kind = kind              # "decode" | "verify"
         self.tokens = tokens          # device array: [S] or [S, K]
         self.states = states          # slot -> SlotState AT DISPATCH
@@ -72,6 +73,10 @@ class InFlightStep:
         # (tokens are delivered at fetches), falling back to
         # dispatch→fetch for the pipeline's first step
         self.prev_fetch = prev_fetch
+        # decode: the admission whose prompt this program prefilled
+        # beside the decode rows (the server's ``_Rider``), committed
+        # with the record
+        self.rider = rider
 
 
 class PublishWorker:

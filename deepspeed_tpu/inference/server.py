@@ -155,6 +155,18 @@ class _RequestTrace:
         self.tokens = 0       # tokens committed by decode steps
 
 
+@dataclasses.dataclass
+class _Rider:
+    """An admission handed to the step's decode round: its host part is
+    done (``_admit``), its prompt rides the step's one program
+    (``serve_decode_admit``) and its first token commits with that
+    program's record."""
+    slot: int
+    state: object
+    ids: np.ndarray        # the right-padded prompt, [1, bucket]
+    t_admit: float
+
+
 # ------------------------------------------------------ kinds of pool
 # What the server does differently for each kind of cache a model's
 # programs thread through (``model_config.cache_kind``: ``kv`` the
@@ -555,6 +567,16 @@ class ContinuousBatchingServer:
                                        help="requests retired")
         self._c_prefills = reg.counter("serve_prefills_total",
                                        help="prefill programs executed")
+        self._c_admissions = {
+            path: reg.counter(
+                "serve_admissions_total", labels={"path": path},
+                help="admissions that reached their first token, by the "
+                     "program that prefilled the prompt: rider (inside "
+                     "a step's decode program, beside decoding rows), "
+                     "alone (a program that carried no decoding row), "
+                     "chunk (chunk programs)")
+            for path in ("rider", "alone", "chunk")}
+        self._admissions = dict.fromkeys(self._c_admissions, 0)
         self._c_decode_steps = reg.counter("serve_decode_steps_total",
                                            help="decode steps executed")
         self._c_tokens = reg.counter("serve_tokens_total",
@@ -682,6 +704,7 @@ class ContinuousBatchingServer:
         # empty when the step profiler is off
         self._req_spans: Dict[int, list] = {}
         self._admitted_step = 0
+        self._rode_step = False     # this step's program carried a rider
         # when the request last ENTERED the queue (submit or preemption
         # requeue) — the shed guard's notion of "how long has this
         # waiter actually been waiting"; _submit_ts must stay the
@@ -757,6 +780,25 @@ class ContinuousBatchingServer:
                               mesh=engine.mesh),
             name="serve_decode", registry=self.telemetry,
             donate_argnames=("cache",))
+        # the decode program that also prefills one admitted prompt
+        # (docs/serving.md "Async dispatch loop", the rider round): a
+        # family that has the entry point, served monolithic and without
+        # speculation, runs it for EVERY admission and never
+        # ``serve_prefill``; one trace a prompt bucket, ``slot``,
+        # ``length`` and ``active`` are data. With no slot decoding it
+        # runs with no active row: an admission alone
+        self._admit_jit = None
+        self._rider: Optional[_Rider] = None
+        if (hasattr(model_family(mcfg), "paged_decode_admit")
+                and not self.chunk_tokens and not self.spec_tokens
+                and self.draft is None):
+            self._admit_jit = watched_jit(
+                functools.partial(self._decode_admit_fn, cfg=mcfg,
+                                  mesh=engine.mesh),
+                name="serve_decode_admit", registry=self.telemetry,
+                donate_argnames=("cache",))
+            self._idle_rows = (jnp.zeros((self.num_slots,), jnp.int32),
+                               jnp.zeros((self.num_slots,), bool))
         # the chunked-prefill program: ONE traced signature per
         # (prefill_chunk_tokens, num_slots, block_size) config — start/
         # slot/length ride as traced scalars, so neither prompt length
@@ -1198,6 +1240,14 @@ class ContinuousBatchingServer:
     def _decode_fn(params, tokens, cache, active, *, cfg, mesh):
         logits, cache = paged_decode_step(params, cfg, tokens, cache,
                                           active, mesh=mesh)
+        return _sample(logits), cache
+
+    @staticmethod
+    def _decode_admit_fn(params, tokens, cache, active, ids, length, slot,
+                         *, cfg, mesh):
+        logits, cache = model_family(cfg).paged_decode_admit(
+            params, cfg, tokens, cache, active, ids, length, slot,
+            mesh=mesh)
         return _sample(logits), cache
 
     @staticmethod
@@ -1951,7 +2001,16 @@ class ContinuousBatchingServer:
         while it waits its turn: it stays queued instead, and where it
         shares a prefix with the job ahead it hits the blocks that job
         published (requests of one tenant queued together prefill their
-        shared context once, not side by side)."""
+        shared context once, not side by side).
+
+        Where the model's family has ``paged_decode_admit`` (monolithic
+        mode, no speculation) and slots are decoding when this is
+        entered, ONE request is admitted and handed to the step's decode
+        round as its rider (``_decode_round``): its prompt is prefilled
+        inside the step's one program. Into a server with nothing
+        decoding every request that fits is admitted here as before,
+        each by that program with no active row."""
+        riding = self._admit_jit is not None and bool(self.scheduler.slots)
         while True:
             if self._prefilling:
                 return
@@ -2057,57 +2116,95 @@ class ContinuousBatchingServer:
                     bucket=T)
             ids = np.zeros((1, T), np.int32)
             ids[0, :len(sched_prompt)] = sched_prompt
-            t_pf = self._clock()
-            tok0, self._cache = self._prefill_jit(
-                self.engine.params, jnp.asarray(ids),
-                jnp.asarray([len(sched_prompt)], jnp.int32), self._cache,
-                jnp.int32(slot))
-            self._prefills += 1
-            self._prefill_token_units += T
             if self._ledger is not None:
                 # weight = the PADDED bucket actually computed, so the
                 # step's device split follows the work the device did
                 self._ledger.add_weight(req.request_id, T)
-            tok0 = int(self._fetch_tokens(tok0)[0])   # host sync: prefill done
+            if riding:
+                # slots are decoding: the prompt rides this step's decode
+                # program, which reads the weights once for both, and the
+                # first token commits with that program's record
+                # (_decode_round). One rider a step
+                self._rider = _Rider(slot, state, ids, t_admit)
+                return
+            t_pf = self._clock()
+            plen = jnp.asarray([len(sched_prompt)], jnp.int32)
+            if self._admit_jit is None:
+                tok0, self._cache = self._prefill_jit(
+                    self.engine.params, jnp.asarray(ids), plen, self._cache,
+                    jnp.int32(slot))
+                row = 0
+            else:
+                # nothing is decoding (an empty server filling up): the
+                # same program with no active row, its token at ``slot``
+                tokens, active = self._idle_rows
+                tok0, self._cache = self._admit_jit(
+                    self.engine.params, tokens, self._cache, active,
+                    jnp.asarray(ids), plen, jnp.int32(slot))
+                row = slot
+            tok0 = int(self._fetch_tokens(tok0)[row])  # host sync: prefill done
             now_t = self._clock()
-            self._request_phase(req.request_id, now_t, DECODE_SPAN)
             # prefill compute runs inside the admission phase; its
             # dispatch->fetch interval is still device-attributed (and
             # advances the dispatch-gap boundary — the device was busy)
             sp.device_interval(t_pf, now_t)
-            # prefill latency by PADDED bucket (the traced shape, not the
-            # raw prompt length — per-shape latency is what regressions
-            # in the prefill program show up against)
-            self.telemetry.histogram(
-                "serve_prefill_seconds",
-                help="prefill wall time, by padded prompt-bucket length",
-                labels={"bucket": str(T)}).observe(now_t - t_admit)
-            if not state.generated:
-                # TTFT is observed when the request's FIRST token ever
-                # leaves (generated == committed until tok0 appends): a
-                # resumed request that already emitted tokens skips it,
-                # but one preempted mid-prefill still owes its first
-                # token — hiding its (slow) TTFT would green an SLO
-                # that is actually collapsing under preemption pressure
-                self._h_ttft.observe(
-                    now_t - self._submit_ts.get(req.request_id, now_t))
-            self._c_prefills.inc()
-            self._c_tokens.inc()
-            if self.watchdog is not None:
-                # a prefill IS progress — a long admission burst must
-                # not read as a decode stall
-                self.watchdog.notify_progress()
-            if rt is not None:
-                rt.trace.end_span(rt.prefill)
-            self._draft_prefill_slot(slot, state)
-            state.generated.append(tok0)
-            state.pending = tok0
-            if self._finished(state, tok0):
-                self._retire(slot, state, finished)
-            elif rt is not None:
-                # decode residency: one span from "slot decodable" to
-                # retirement, annotated at close with tokens/steps
-                rt.decode = rt.trace.begin("decode", slot=slot)
+            self._prefilled(slot, state, T, t_admit, tok0, now_t, finished,
+                            "alone")
+
+    def _prefilled(self, slot: int, state, T: int, t_admit: float,
+                   tok0: int, now_t: float, finished: list,
+                   path: str) -> None:
+        """A monolithic admission's first token is on the host (fetched
+        with the program that prefilled its ``T``-row bucket, alone or as
+        a step's rider)."""
+        self._prefill_token_units += T
+        # prefill latency by PADDED bucket (the traced shape, not the
+        # raw prompt length — per-shape latency is what regressions
+        # in the prefill program show up against)
+        self.telemetry.histogram(
+            "serve_prefill_seconds",
+            help="prefill wall time, by padded prompt-bucket length",
+            labels={"bucket": str(T)}).observe(now_t - t_admit)
+        if self.watchdog is not None:
+            # a prefill IS progress — a long admission burst must
+            # not read as a decode stall
+            self.watchdog.notify_progress()
+        self._first_token(slot, state, tok0, now_t, finished, path)
+
+    def _first_token(self, slot: int, state, tok0: int, now: float,
+                     finished: list, path: str) -> None:
+        """The prompt is resident and the request's next token real (a
+        monolithic program's, a rider's, or the final chunk's): the
+        request's lifecycle moves from prefill to decode."""
+        req = state.request
+        self._request_phase(req.request_id, now, DECODE_SPAN)
+        if not state.generated:
+            # TTFT is observed when the request's FIRST token ever
+            # leaves (generated == committed until tok0 appends): a
+            # resumed request that already emitted tokens skips it,
+            # but one preempted mid-prefill still owes its first
+            # token — hiding its (slow) TTFT would green an SLO
+            # that is actually collapsing under preemption pressure
+            self._h_ttft.observe(
+                now - self._submit_ts.get(req.request_id, now))
+        self._c_prefills.inc()
+        self._c_tokens.inc()
+        self._prefills += 1
+        self._c_admissions[path].inc()
+        self._admissions[path] += 1
+        rt = (self._rt.get(req.request_id)
+              if self.tracer is not None else None)
+        if rt is not None:
+            rt.trace.end_span(rt.prefill)
+        self._draft_prefill_slot(slot, state)
+        state.generated.append(tok0)
+        state.pending = tok0
+        if self._finished(state, tok0):
+            self._retire(slot, state, finished)
+        elif rt is not None:
+            # decode residency: one span from "slot decodable" to
+            # retirement, annotated at close with tokens/steps
+            rt.decode = rt.trace.begin("decode", slot=slot)
 
     def _run_prefill_chunk(self, finished: list,
                            sp=NULL_STEP_HANDLE) -> None:
@@ -2216,27 +2313,8 @@ class ContinuousBatchingServer:
             # publish the cold tail's full prompt blocks — only now is
             # their content valid for another request to hit
             self.scheduler.commit_prefix(state)
-        tok0 = int(tok[0])
-        now = self._clock()
-        self._request_phase(req.request_id, now, DECODE_SPAN)
-        if not state.generated:
-            # first-ever token for this request (see the monolithic
-            # site): resumed-with-committed skips, resumed-before-first-
-            # token still observes its true TTFT
-            self._h_ttft.observe(
-                now - self._submit_ts.get(req.request_id, now))
-        self._c_prefills.inc()
-        self._c_tokens.inc()
-        self._prefills += 1
-        if rt is not None:
-            rt.trace.end_span(rt.prefill)
-        self._draft_prefill_slot(slot, state)
-        state.generated.append(tok0)
-        state.pending = tok0
-        if self._finished(state, tok0):
-            self._retire(slot, state, finished)
-        elif rt is not None:
-            rt.decode = rt.trace.begin("decode", slot=slot)
+        self._first_token(slot, state, int(tok[0]), self._clock(),
+                          finished, "chunk")
 
     def _draft_prefill_slot(self, slot: int, state) -> None:
         """Admit one slot's FULL scheduled prompt into the draft pool
@@ -2398,6 +2476,7 @@ class ContinuousBatchingServer:
               else NULL_STEP_HANDLE)
         finished: List[int] = []
         self._admitted_step = 0
+        self._rode_step = False
         self._take_deferred(finished)
         self._tick += 1
         if self.canary is not None:
@@ -2434,8 +2513,12 @@ class ContinuousBatchingServer:
             # preempt strictly-lower-priority residents for the blocked
             # waiter, re-admitting after each victim frees its slot +
             # blocks
+            # (never behind a rider, whichever _admit produced it: the
+            # head it left queued waits for a step, not for a slot, and
+            # the next step carries it or preempts for it)
             guard = self.num_slots
-            while guard > 0 and self._preempt_for_head(finished):
+            while (guard > 0 and self._rider is None
+                   and self._preempt_for_head(finished)):
                 guard -= 1
                 self._admit(finished, sp)
         if lag == 0 or self.scheduler.queue:
@@ -2529,7 +2612,7 @@ class ContinuousBatchingServer:
         not host tax."""
         slots = len(self.scheduler.slots)
         sp.finish(live=bool(slots), slots=slots,
-                  admitted=self._admitted_step)
+                  admitted=self._admitted_step, rider=self._rode_step)
 
     # ------------------------------------------------ async dispatch loop
 
@@ -2583,13 +2666,18 @@ class ContinuousBatchingServer:
         tokens = np.zeros((S,), np.int32)
         active = np.zeros((S,), bool)
         states: Dict[int, object] = {}
+        # admitted by this step (_admit): its prompt rides the program,
+        # its slot decodes from the next step on
+        rider, self._rider = self._rider, None
+        self._rode_step = rider is not None
         for slot, state in self.scheduler.slots.items():
-            if slot in self._mid_prefill:
+            if slot in self._mid_prefill or (rider is not None
+                                             and slot == rider.slot):
                 continue   # resident but still prefilling: not decoded
             tokens[slot] = state.pending
             active[slot] = True
             states[slot] = state
-        if not states:
+        if not states and rider is None:
             # every resident slot is mid-prefill — the chunk above was
             # this step's progress; nothing to decode yet
             sp.mark("propose")
@@ -2612,14 +2700,27 @@ class ContinuousBatchingServer:
         # the dispatch-gap detector measures this boundary against the
         # last fetch that drained the device
         sp.mark("propose", now=t0, dispatch=True)
-        nxt, self._cache = self._decode_jit(
-            self.engine.params,
-            # an empty chain starts from the host's pending tokens, a
-            # live one from the newest record's device-resident outputs
-            jnp.asarray(tokens) if rec is None else rec.tokens,
-            self._cache, jnp.asarray(active))
-        sp.mark("dispatch", program=self._decode_jit.name)
-        chain.append(InFlightStep("decode", nxt, states, t0))
+        if rider is None:
+            program = self._decode_jit
+            nxt, self._cache = program(
+                self.engine.params,
+                # an empty chain starts from the host's pending tokens, a
+                # live one from the newest record's device-resident
+                # outputs
+                jnp.asarray(tokens) if rec is None else rec.tokens,
+                self._cache, jnp.asarray(active))
+        else:
+            # a lag-0 step (it admitted): the chain is empty, and the
+            # record commits below with the rider's first token at its
+            # slot of the sampled vector
+            program = self._admit_jit
+            nxt, self._cache = program(
+                self.engine.params, jnp.asarray(tokens), self._cache,
+                jnp.asarray(active), jnp.asarray(rider.ids),
+                jnp.asarray([len(rider.state.request.sched_prompt)],
+                            jnp.int32), jnp.int32(rider.slot))
+        sp.mark("dispatch", program=program.name)
+        chain.append(InFlightStep("decode", nxt, states, t0, rider=rider))
         if lag:
             self._async_stats["pipeline_starts" if rec is None
                               else "pipelined_steps"] += 1
@@ -2660,9 +2761,11 @@ class ContinuousBatchingServer:
         nxt = (np.asarray(rec.tokens) if lagged
                else self._fetch_tokens(rec.tokens))
         t1 = self._clock()
+        rider = rec.rider
         if in_step:
             sp.mark("sync_wait", now=t1, fetch=True,
-                    program=self._decode_jit.name)
+                    program=(self._decode_jit if rider is None
+                             else self._admit_jit).name)
         elif self._profiler is not None:
             self._profiler.note_fetch(t1)
         self._realize_chunk_span(sp, t1)
@@ -2702,13 +2805,22 @@ class ContinuousBatchingServer:
                 self._retire(slot, state, finished)
             else:
                 state.pending = tok
+        if (rider is not None and self.scheduler.slots.get(rider.slot)
+                is rider.state
+                and rider.state.request.request_id != discard_rid):
+            # the prompt this program prefilled: its last live row's
+            # token came over at its slot
+            self._prefilled(rider.slot, rider.state, rider.ids.shape[1],
+                            rider.t_admit, int(nxt[rider.slot]), t1,
+                            finished, "rider")
         if in_step:
             sp.mark("commit")
         if n_live == 0:
             # pure garbage (every slot vanished between dispatch and
             # commit): the device step ran but served nothing — not a
             # decode step in any accounting
-            self._async_stats["garbage_steps"] += 1
+            if rider is None:
+                self._async_stats["garbage_steps"] += 1
             return t1
         self._step_clock += 1
         self._active_slot_steps += n_live
@@ -3288,11 +3400,19 @@ class ContinuousBatchingServer:
                                if units else 0.0),
             "decode_traces": _safe_cache_size(self._decode_jit),
             "prefill_traces": _safe_cache_size(self._prefill_jit),
+            # the decode program that also prefills an admitted prompt
+            # (0 where the family has no paged_decode_admit or a mode
+            # keeps it off), and how each admission was prefilled
+            "decode_admit_traces": (_safe_cache_size(self._admit_jit)
+                                    if self._admit_jit is not None else 0),
+            "admissions": dict(self._admissions),
             "chunk_traces": (_safe_cache_size(self._chunk_jit)
                              if self._chunk_jit is not None else 0),
             "retraces": (
                 len(getattr(self._decode_jit, "retraces", ()))
                 + len(getattr(self._prefill_jit, "retraces", ()))
+                + (len(getattr(self._admit_jit, "retraces", ()))
+                   if self._admit_jit is not None else 0)
                 + (len(getattr(self._chunk_jit, "retraces", ()))
                    if self._chunk_jit is not None else 0)
                 + (len(getattr(self._verify_jit, "retraces", ()))

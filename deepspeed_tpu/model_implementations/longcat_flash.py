@@ -77,8 +77,10 @@ from deepspeed_tpu.profiling.trace import scoped
 F32 = jnp.float32
 
 # what this model keeps in LatentPagedCache.aux: routing counters
-# ``[program, column]``, the picks on each held expert first, then these
-PROGRAMS = ("decode", "prefill")
+# ``[program, column]``, the picks on each held expert first, then these.
+# ``decode_admit`` has a row of its own, so that ``decode`` keeps meaning
+# the pure decode program (its readers divide by its executions)
+PROGRAMS = ("decode", "prefill", "decode_admit")
 COUNTER_TAIL = _held.COUNTER_TAIL
 
 
@@ -433,20 +435,22 @@ def moe_layer(u, moe, cfg: LongcatFlashConfig, valid):
 
 # ------------------------------------------------------------------ block
 
-def _double_block(x, layer, cfg: LongcatFlashConfig, attend, valid):
+def _double_block(x, layer, cfg: LongcatFlashConfig, attend, valid,
+                  ffn=_dense_ffn):
     """The shortcut-connected double block on ``x [..., E]``.
     ``attend(h, a, i)`` runs attention sub-block ``i`` (0 or 1) on the
-    normed hidden and returns its projected output. Returns (y, the MoE's
-    counters row)."""
+    normed hidden and returns its projected output; ``ffn`` is the dense
+    FFN, for a caller that runs it over some of its rows. Returns (y, the
+    MoE's counters row)."""
     eps = cfg.rms_norm_eps
     h1 = x + attend(_rms(x, layer["norm_in"][0], eps), layer["attn"][0], 0)
     u = _rms(h1, layer["norm_post"][0], eps)
     m, counts = moe_layer(u.reshape(-1, u.shape[-1]), layer["moe"], cfg,
                           valid.reshape(-1))
-    h2 = h1 + _dense_ffn(u, layer["ffn"][0])
+    h2 = h1 + ffn(u, layer["ffn"][0])
     h3 = h2 + attend(_rms(h2, layer["norm_in"][1], eps), layer["attn"][1], 1)
-    y = h3 + _dense_ffn(_rms(h3, layer["norm_post"][1], eps),
-                        layer["ffn"][1]) + m.reshape(x.shape)
+    y = h3 + ffn(_rms(h3, layer["norm_post"][1], eps),
+                 layer["ffn"][1]) + m.reshape(x.shape)
     return y, counts
 
 
@@ -529,6 +533,91 @@ def paged_decode_step(params, cfg: LongcatFlashConfig, tokens,
         total = total + counts
     return (_logits(params, cfg, x),
             paged_advance(_count(cache, "decode", total), active))
+
+
+def paged_decode_admit(params, cfg: LongcatFlashConfig, tokens,
+                       cache: LatentPagedCache, active, input_ids, length,
+                       slot, mesh=None):
+    """One generation step for the resident slots AND one prompt admitted
+    into pool slot ``slot``, in one forward over ``S + T`` rows, so the
+    weights are read once for both (an optional entry point: the server
+    runs it where a family has it, and :func:`paged_prefill` in a program
+    of its own where not). ``tokens [S]``, ``active [S]`` as
+    :func:`paged_decode_step`; ``input_ids [1, T]``, ``length [1]``,
+    ``slot`` as :func:`paged_prefill`. ``slot`` is NOT active in this
+    step: its decode row is idle and appends into the null block.
+
+    The row-wise layers (norms, projections, dense FFNs, router, held
+    experts, head) run once over all rows; each attention scatters the
+    prompt's rows and attends them in the materialised form, appends the
+    decode rows and attends them in the absorbed form, and projects both
+    out together. Returns (logits ``[S, V]``, cache): ``logits[slot]`` is
+    the logits of the prompt's last live row, so the sampled vector
+    holds the request's first token at ``slot`` and the next step chains
+    from it with the slot active. ``lengths[slot]`` is pinned to
+    ``length``.
+
+    With NO decode row live (an empty server filling its slots) the
+    step is the prompt's alone: an idle row costs a matmul what a live
+    one does (512 rows are past where this chip's share turns from
+    bandwidth- to compute-bound), so the decode rows' appends and
+    attention and their rows of the dense FFNs (three quarters of a
+    row's matmul work) are left out on what the program observes
+    (``any(active)``), and the admission then costs little more than
+    :func:`paged_prefill` does."""
+    S, T = tokens.shape[0], input_ids.shape[1]
+    positions = jnp.concatenate([cache.lengths, jnp.arange(T)])
+    valid = jnp.concatenate([active, jnp.arange(T) < length[0]])
+    decoding = jnp.any(active)
+    # the admitted slot's table row is the prompt's by now: its idle
+    # decode row appends where every idle row does
+    tables = jax.lax.dynamic_update_slice_in_dim(
+        cache.block_tables,
+        jnp.zeros((1, cache.block_tables.shape[1]), jnp.int32), slot, 0)
+
+    def ffn(u, f):
+        """The dense FFN over every row, or over the prompt's alone with
+        zeros in the decode rows' place."""
+        return jax.lax.cond(
+            decoding, lambda u: _dense_ffn(u, f),
+            lambda u: jnp.pad(_dense_ffn(u[S:], f), ((S, 0), (0, 0))), u)
+
+    x = _embed(params, cfg, jnp.concatenate([tokens, input_ids[0]]))
+    total = jnp.zeros((cfg.aux_shape[1],), jnp.int32)
+    for li, layer in enumerate(params["layers"]):
+        def attend(h, a, i, li=li):
+            nonlocal cache
+            idx = 2 * li + i
+            q_nope, q_rope, rows = _mla_project(h, a, cfg, positions)
+            cache = latent_write_prompt(cache, idx, rows[S:], slot)
+
+            def decode_rows(pool):
+                pool = latent_append_token(
+                    cache.replace(rows=(pool,), block_tables=tables), 0,
+                    rows[:S]).rows[0]
+                return pool, _absorbed_attention(
+                    q_nope[:S], q_rope[:S], pool, tables,
+                    cache.lengths + 1, a, cfg)
+
+            def no_rows(pool):
+                return pool, jnp.zeros(
+                    (S, cfg.num_attention_heads, cfg.v_head_dim), h.dtype)
+            pool, decoded = jax.lax.cond(decoding, decode_rows, no_rows,
+                                         cache.rows[idx])
+            cache = cache.replace(
+                rows=cache.rows[:idx] + (pool,) + cache.rows[idx + 1:])
+            return _attn_out(jnp.concatenate([
+                decoded, _materialised_attention(
+                    q_nope[None, S:], q_rope[None, S:], rows[None, S:], a,
+                    cfg)[0]]), a)
+        x, counts = _double_block(x, layer, cfg, attend, valid, ffn)
+        total = total + counts
+    last = jax.lax.dynamic_index_in_dim(x, S + length[0] - 1, 0)
+    x = jax.lax.dynamic_update_slice_in_dim(x[:S], last, slot, 0)
+    cache = paged_advance(_count(cache, "decode_admit", total), active)
+    return _logits(params, cfg, x), cache.replace(
+        lengths=jax.lax.dynamic_update_index_in_dim(
+            cache.lengths, length[0].astype(jnp.int32), slot, 0))
 
 
 def causal_forward(params, cfg: LongcatFlashConfig, input_ids,

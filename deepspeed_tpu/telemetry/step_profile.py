@@ -176,7 +176,7 @@ class _NullStepHandle:
         return None
 
     def finish(self, live: bool = True, slots: Optional[int] = None,
-               admitted: Optional[int] = None) -> None:
+               admitted: Optional[int] = None, rider: bool = False) -> None:
         return None
 
 
@@ -353,11 +353,12 @@ class _StepHandle:
         self._pipelined_mode = True
 
     def finish(self, live: bool = True, slots: Optional[int] = None,
-               admitted: Optional[int] = None) -> None:
+               admitted: Optional[int] = None, rider: bool = False) -> None:
         """Close the step: the tail since the last mark becomes the
         ``other`` residual, and ``wall == sum(phases)`` exactly.
         ``slots`` (resident after the step) and ``admitted`` (into a
-        slot during it) ride on the ``serve:step`` span.
+        slot during it) ride on the ``serve:step`` span, and ``rider``
+        where the step's decode program prefilled an admitted prompt.
 
         ``live=False`` (no sequences resident after this step) resets
         the dispatch-gap baseline: with nothing to decode the device is
@@ -380,7 +381,7 @@ class _StepHandle:
             self.device = wall
         if not live:
             self._prof._last_fetch = None
-        self._prof._record(wall, self, end, slots, admitted)
+        self._prof._record(wall, self, end, slots, admitted, rider)
         a = self._ann_step
         if a is not None:
             self._ann_step = None
@@ -579,7 +580,8 @@ class StepProfiler:
                    span_id=handle.span_id)
 
     def _record(self, wall: float, handle: _StepHandle, end: float,
-                slots: Optional[int], admitted: Optional[int]) -> None:
+                slots: Optional[int], admitted: Optional[int],
+                rider: bool = False) -> None:
         if not handle.worked:
             # idle poll: nothing dispatched, no device interval — the
             # step is counted for visibility but kept OUT of the
@@ -599,7 +601,7 @@ class StepProfiler:
         self._idle_run = 0
         self._write_spans(handle, end, {
             "source": self.source, "profiler": self.uid, "slots": slots,
-            "admitted": admitted,
+            "admitted": admitted, "rider": rider,
             "depth": self.outstanding, "device_s": handle.device,
             "pipelined": handle._pipelined_mode})
         recent = self._recent_walls

@@ -384,7 +384,7 @@ def test_queue_arriving_mid_chain_runs_one_lag0_round(fresh_telemetry,
 
 # ----------------------------------- a backlog behind full slots (ISSUE 33)
 
-def make_latent_engine(num_slots=2, **knobs):
+def make_latent_engine(num_slots=2, max_out_tokens=64, **knobs):
     """The latent-cache family (LongCat-Flash) at a tiny size: the
     other pool the one step loop serves."""
     from deepspeed_tpu.model_implementations.longcat_flash import (
@@ -398,7 +398,7 @@ def make_latent_engine(num_slots=2, **knobs):
         max_position_embeddings=1024, experts_held=(2, 6))
     params = init_latent(jax.random.PRNGKey(3), cfg)
     return InferenceEngine((cfg, params), DeepSpeedInferenceConfig(
-        dtype="float32", max_out_tokens=64, block_size=16,
+        dtype="float32", max_out_tokens=max_out_tokens, block_size=16,
         num_slots=num_slots, **knobs))
 
 
@@ -942,3 +942,281 @@ def test_async_stats_blob_shape():
         assert k in blob["worker"], k
     import json
     assert json.loads(json.dumps(blob)) == blob
+
+
+# ------------------------- an admitted prompt rides the step's program
+# (ISSUE 51; docs/serving.md "Async dispatch loop", the rider round)
+
+_RIDER_PROMPTS = [[1, 2, 3, 1, 2, 3], [4, 5, 6], [7, 8, 9, 7], [3, 2, 1],
+                  [9, 9, 8], list(range(1, 20)), [11, 12]]
+_RIDER_BUDGETS = [9, 14, 6, 11, 5, 7, 8]
+
+
+def _calls(srv, monkeypatch):
+    """Every serving program of ``srv`` counted by name as it is
+    dispatched."""
+    seen = []
+    for attr in ("_prefill_jit", "_decode_jit", "_admit_jit", "_chunk_jit",
+                 "_verify_jit"):
+        fn = getattr(srv, attr)
+        if fn is None:
+            continue
+
+        def counted(*a, _fn=fn, **k):
+            seen.append(_fn.name)
+            return _fn(*a, **k)
+        counted.name = fn.name
+        counted._cache_size = fn._cache_size
+        monkeypatch.setattr(srv, attr, counted)
+    return seen
+
+
+def _step_spans(srv):
+    """``srv``'s worked ``serve:step`` spans (the log is the
+    process's)."""
+    return [r for r in srv._profiler.span_log.snapshot(prefix="serve:")
+            if r[0] == "serve:step" and not r[6].get("idle")
+            and r[6]["profiler"] == srv._profiler.uid]
+
+
+@pytest.mark.parametrize("lag", [1, 3])
+def test_rider_serves_the_tokens_of_the_two_program_round(
+        fresh_telemetry, monkeypatch, lag):
+    """A LongCat-family server serves the same tokens with the family's
+    ``paged_decode_admit`` and with it hidden (the round every other
+    family runs: a prefill program, then the decode program). With it,
+    no ``serve_prefill`` is ever traced: an empty server's admissions
+    run the one program alone, every refill of a backlog rides a step
+    that dispatches that ONE program, and ``serve_admissions_total``
+    says which was which."""
+    from deepspeed_tpu.model_implementations import longcat_flash as lf
+    from deepspeed_tpu.telemetry import get_registry
+
+    def serve():
+        srv = ContinuousBatchingServer(make_latent_engine(
+            max_commit_lag=lag), clock=FakeClock(auto=0.001))
+        seen = _calls(srv, monkeypatch)
+        ids = [srv.submit(p, max_new_tokens=b)
+               for p, b in zip(_RIDER_PROMPTS, _RIDER_BUDGETS)]
+        per_step = []
+        while not srv.scheduler.idle:
+            n = len(seen)
+            srv.step()
+            per_step.append(seen[n:])
+        out = srv.drain()
+        return srv, [out[i] for i in ids], per_step
+
+    srv, got, per_step = serve()
+    st = srv.stats
+    assert srv._admit_jit is not None
+    assert st["prefill_traces"] == 0 and st["decode_admit_traces"] == 1
+    assert st["admissions"] == {"rider": 5, "alone": 2, "chunk": 0}
+    assert st["prefills"] == 7 and st["retraces"] == 0
+    # the fill of the empty server: two admissions alone, then the
+    # step's decode program; every later admitting step is ONE program
+    assert per_step[0] == ["serve_decode_admit"] * 2 + ["serve_decode"]
+    riders = [calls for calls in per_step[1:]
+              if "serve_decode_admit" in calls]
+    assert riders == [["serve_decode_admit"]] * 5
+    snap = get_registry().snapshot()["serve_admissions_total"]["series"]
+    assert {s["labels"]["path"]: s["value"] for s in snap} == {
+        "rider": 5.0, "alone": 2.0, "chunk": 0.0}
+    spans = _step_spans(srv)
+    rode = [r for r in spans if r[6]["rider"]]
+    assert len(rode) == 5
+    assert all(r[6]["admitted"] == 1 and not r[6]["pipelined"]
+               for r in rode)
+    assert sum(r[6]["admitted"] for r in spans) == 7
+    # the routing counters of the program have a row of their own
+    routed = {s["labels"]["program"]: s["value"] for s in
+              get_registry().snapshot()["serve_moe_tokens_routed_total"]
+              ["series"]}
+    assert routed["decode_admit"] > 0 and routed.get("prefill", 0) == 0
+
+    monkeypatch.delattr(lf, "paged_decode_admit")
+    hidden, want, per_step = serve()
+    st = hidden.stats
+    assert hidden._admit_jit is None
+    assert st["decode_admit_traces"] == 0 and st["prefill_traces"] == 1
+    assert st["admissions"] == {"rider": 0, "alone": 7, "chunk": 0}
+    assert not any(r[6]["rider"] for r in _step_spans(hidden))
+    assert got == want
+
+
+def test_k_waiters_and_k_free_slots_move_in_over_k_steps_in_queue_order(
+        fresh_telemetry, monkeypatch):
+    """With a slot decoding, three eligible requests and three free
+    slots are admitted over three steps, one rider each, in queue order
+    (every one of those steps is lag 0 and dispatches one program); the
+    same three into an EMPTY server move in within one step, each
+    through the program alone."""
+    srv = ContinuousBatchingServer(make_latent_engine(num_slots=4),
+                                   clock=FakeClock(auto=0.001))
+    seen = _calls(srv, monkeypatch)
+    first = srv.submit([5, 6, 7], max_new_tokens=40)
+    for _ in range(3):
+        srv.step()
+    assert len(srv._inflight) == 1          # decoding, pipelined
+    waiters = [srv.submit(p, max_new_tokens=4)
+               for p in ([1, 2, 3], [4, 5], [6, 7, 8, 9])]
+    order = []
+    for k in range(3):
+        n = len(seen)
+        srv.step()
+        assert seen[n:] == ["serve_decode_admit"]
+        assert not srv._inflight            # lag 0: committed
+        order.append({s.request.request_id
+                      for s in srv.scheduler.slots.values()})
+    assert order == [{first, *waiters[:k + 1]} for k in range(3)]
+    assert srv.stats["admissions"] == {"rider": 3, "alone": 1, "chunk": 0}
+    out = srv.drain()
+
+    empty = ContinuousBatchingServer(make_latent_engine(num_slots=4),
+                                     clock=FakeClock(auto=0.001))
+    seen = _calls(empty, monkeypatch)
+    again = [empty.submit(p, max_new_tokens=4)
+             for p in ([1, 2, 3], [4, 5], [6, 7, 8, 9])]
+    empty.step()
+    assert seen == ["serve_decode_admit"] * 3 + ["serve_decode"]
+    assert len(empty.scheduler.slots) == 3
+    assert empty.stats["admissions"] == {"rider": 0, "alone": 3, "chunk": 0}
+    alone = empty.drain()
+    assert [out[i] for i in waiters] == [alone[i] for i in again]
+
+
+@pytest.mark.parametrize("waiters", [2, 3])
+def test_the_ladder_preempts_for_one_rider_a_step(fresh_telemetry,
+                                                  monkeypatch, waiters):
+    """Full slots of low priority and several waiters that outrank them:
+    a step preempts ONE resident, for the head that then rides it, and
+    the next head waits for the next step (the ladder never runs behind
+    a rider: a resident evicted for a head that cannot move in this step
+    would lose its work for nothing). Each request is preempted at most
+    once, and the tokens are those of the round with the entry point
+    hidden, which preempts for every head in one step."""
+    from deepspeed_tpu.model_implementations import longcat_flash as lf
+
+    def serve(rider):
+        srv = ContinuousBatchingServer(make_latent_engine(num_slots=3),
+                                       clock=FakeClock(auto=0.001))
+        seen = _calls(srv, monkeypatch)
+        low = [srv.submit(p, max_new_tokens=30)
+               for p in _RIDER_PROMPTS[:3]]
+        for _ in range(4):
+            srv.step()
+        assert len(srv.scheduler.slots) == 3 and srv._inflight
+        vips = [srv.submit(p, max_new_tokens=5, priority=5)
+                for p in _RIDER_PROMPTS[3:3 + waiters]]
+        per_step = []
+        for _ in range(waiters):
+            n, before = len(seen), srv.stats["preempted"]
+            srv.step()
+            per_step.append((srv.stats["preempted"] - before, seen[n:],
+                             sum(srv.scheduler.find_slot(v) is not None
+                                 for v in vips)))
+        if rider:
+            # one victim, one program, one more waiter resident: a step
+            assert per_step == [(1, ["serve_decode_admit"], k + 1)
+                                for k in range(waiters)]
+            assert srv._rider is None
+        else:
+            assert per_step[0] == (
+                waiters, ["serve_prefill"] * waiters + ["serve_decode"],
+                waiters)
+        out = srv.drain()
+        st = srv.stats
+        assert st["preempted"] == waiters and st["failed"] == 0
+        assert all(srv.finish_reason(i) == "length" for i in low + vips)
+        return st, [out[i] for i in low + vips]
+
+    st, got = serve(rider=True)
+    # the preempted residents ride back in as slots come free
+    assert st["admissions"]["rider"] == 2 * waiters
+    monkeypatch.delattr(lf, "paged_decode_admit")
+    st, want = serve(rider=False)
+    assert st["admissions"] == {"rider": 0, "alone": 3 + 2 * waiters,
+                                "chunk": 0}
+    assert got == want
+
+
+def test_empty_server_traces_each_bucket_of_the_program_once(
+        fresh_telemetry):
+    """Prompts of two buckets into an empty server: both are admitted in
+    the first step, the program is traced once a bucket, and riders of
+    either bucket then reuse those traces (what the benchmark's warm-up
+    counts on: nothing compiles inside the window)."""
+    long_a = [1 + (i % 90) for i in range(130)]
+    long_b = [2 + (i % 70) for i in range(150)]
+    srv = ContinuousBatchingServer(make_latent_engine(
+        num_slots=2, max_out_tokens=512), clock=FakeClock(auto=0.001))
+    a = srv.submit([1, 2, 3], max_new_tokens=5)
+    b = srv.submit(long_a, max_new_tokens=9)
+    c = srv.submit([4, 5, 6, 7], max_new_tokens=6)
+    d = srv.submit(long_b, max_new_tokens=4)
+    srv.step()
+    assert len(srv.scheduler.slots) == 2
+    assert srv.stats["decode_admit_traces"] == 2
+    out = srv.drain()
+    st = srv.stats
+    assert st["decode_admit_traces"] == 2 and st["prefill_traces"] == 0
+    assert st["admissions"] == {"rider": 2, "alone": 2, "chunk": 0}
+    hidden = ContinuousBatchingServer(make_latent_engine(
+        num_slots=2, max_out_tokens=512, async_loop=False))
+    want = _serve(hidden, [[1, 2, 3], long_a, [4, 5, 6, 7], long_b], 9)
+    for rid, full, budget in zip((a, b, c, d), want, (5, 9, 6, 4)):
+        assert out[rid] == full[:len(out[rid])]
+        assert len(out[rid]) == len(full) - 9 + budget
+
+
+@pytest.mark.parametrize("mode", ["speculation", "chunking", "neither"])
+def test_the_rider_needs_the_entry_point_and_plain_monolithic_serving(
+        fresh_telemetry, monkeypatch, mode):
+    """Which round runs is read off the family's module and the modes
+    already configured, never a switch: a family with the entry point
+    served with speculation or chunked prefill keeps today's programs
+    (here the dense decoder behind a stand-in module, since the latent
+    family refuses both), and serves one-shot ``generate()``'s tokens;
+    without either mode the same stand-in is taken up."""
+    import types
+
+    from deepspeed_tpu.inference import server as server_mod
+
+    def never(*a, **k):
+        raise AssertionError("the entry point must not run")
+    monkeypatch.setattr(server_mod, "model_family",
+                        lambda cfg: types.SimpleNamespace(
+                            paged_decode_admit=never))
+    knobs = {"speculation": {"speculation_tokens": 4},
+             "chunking": {"prefill_chunk_tokens": 32}, "neither": {}}[mode]
+    eng = make_engine(num_slots=2, **knobs)
+    srv = ContinuousBatchingServer(eng)
+    if mode == "neither":
+        assert srv._admit_jit is not None
+        return
+    assert srv._admit_jit is None
+    assert _serve(srv, PROMPTS[:5], 6) == eng.generate(PROMPTS[:5],
+                                                       max_new_tokens=6)
+    st = srv.stats
+    assert st["decode_admit_traces"] == 0
+    assert st["admissions"] == {
+        "rider": 0, "alone": 0 if mode == "chunking" else 5,
+        "chunk": 5 if mode == "chunking" else 0}
+
+
+def test_dense_family_keeps_its_two_program_round(fresh_telemetry,
+                                                  monkeypatch):
+    """The dense decoder has no ``paged_decode_admit``: every admission
+    is ``serve_prefill`` alone and then the step's ``serve_decode``, as
+    before, and no step carries a rider."""
+    srv = ContinuousBatchingServer(make_engine(num_slots=2),
+                                   clock=FakeClock(auto=0.001))
+    assert srv._admit_jit is None
+    seen = _calls(srv, monkeypatch)
+    got = _serve(srv, _SHORT, 6)
+    assert got == make_engine().generate(_SHORT, max_new_tokens=6)
+    assert set(seen) == {"serve_prefill", "serve_decode"}
+    assert seen.count("serve_prefill") == 5
+    st = srv.stats
+    assert st["admissions"] == {"rider": 0, "alone": 5, "chunk": 0}
+    assert st["decode_admit_traces"] == 0 and st["prefill_traces"] == 1
+    assert not any(r[6]["rider"] for r in _step_spans(srv))

@@ -127,13 +127,88 @@ def test_paged_prefill_and_decode_match_the_reference(f32):
     c = np.asarray(cache.aux)
     held = cfg.num_held
     tail = dict(zip(lf.COUNTER_TAIL, c[:, held:].T))
+    # (rows: decode, prefill, decode_admit, which did not run)
     assert list(tail["tokens_routed"]) == [2 * steps * cfg.num_layers,
-                                           sum(lens) * cfg.num_layers]
+                                           sum(lens) * cfg.num_layers, 0]
     assert list(tail["layer_calls"]) == [steps * cfg.num_layers,
-                                         2 * cfg.num_layers]
+                                         2 * cfg.num_layers, 0]
     assert (c[:, :held].sum(1) + tail["identity_picks"]
             + tail["absent_picks"] == tail["tokens_routed"] * cfg.moe_topk
             ).all()
+
+
+@pytest.mark.parametrize("n,bucket,decoding", [
+    (20, 2 * BS, True), (9, BS, True), (20, 2 * BS, False)],
+    ids=["shorter-than-its-bucket", "one-block-bucket", "nothing-decoding"])
+def test_decode_admit_is_the_decode_step_and_the_prefill_in_one_forward(
+        f32, n, bucket, decoding):
+    """``paged_decode_admit`` over two decoding slots and one prompt
+    admitted into the third: the decode rows' logits are
+    ``paged_decode_step``'s, the rider's are ``paged_prefill``'s last
+    live row, and pool rows, lengths and tables are those of the two
+    programs run one after the other. Its routing counters have a row
+    of their own, the sum of what the two programs count, and leave
+    ``decode`` alone. With no slot decoding (an empty server filling up)
+    it is the prefill alone: the decode rows append nothing and are left
+    out of the attention and the dense FFNs."""
+    cfg, params, _ = f32
+    seqs = _ids(3, 40, seed=1)
+    cache = _pool(cfg)
+    prefill = jax.jit(lambda *a: lf.paged_prefill(a[0], cfg, *a[1:]))
+    decode = jax.jit(lambda *a: lf.paged_decode_step(a[0], cfg, *a[1:]))
+    admit = jax.jit(lambda *a: lf.paged_decode_admit(a[0], cfg, *a[1:]))
+    for s, m in enumerate((11, 27)):
+        ids = np.zeros((1, 2 * BS), np.int32)
+        ids[0, :m] = seqs[s, :m]
+        _, cache = prefill(params, jnp.asarray(ids),
+                           jnp.asarray([m], jnp.int32), cache, jnp.int32(s))
+    before = np.asarray(cache.aux)
+    tokens = jnp.asarray([5, 7, 0] if decoding else [0, 0, 0], jnp.int32)
+    active = jnp.asarray([decoding, decoding, False])
+    ids = np.zeros((1, bucket), np.int32)
+    ids[0, :n] = seqs[2, :n]
+    rider = (jnp.asarray(ids), jnp.asarray([n], jnp.int32), jnp.int32(2))
+    got, both = admit(params, tokens, cache, active, *rider)
+    stepped, one = decode(params, tokens, cache, active)
+    first, two = prefill(params, rider[0], rider[1], one, rider[2])
+    if decoding:
+        assert _rel(got[:2], stepped[:2]) < 1e-6
+    assert _rel(got[2], first[0]) < 1e-6
+    assert [int(x) for x in both.lengths] == [11 + decoding, 27 + decoding,
+                                              n]
+    assert (np.asarray(both.lengths) == np.asarray(two.lengths)).all()
+    assert (np.asarray(both.block_tables)
+            == np.asarray(two.block_tables)).all()
+    # (block 0 is the null block: what idle rows leave there is read by
+    # nobody; with nothing decoding no row is appended anywhere, and the
+    # two idle appends of the step run apart land beyond the live rows)
+    for a, b, c in zip(both.rows, two.rows, cache.rows):
+        if decoding:
+            assert float(jnp.abs(a[1:] - b[1:]).max()) < 1e-6
+        else:
+            blocks = np.asarray(cache.block_tables)[2, :bucket // BS]
+            assert float(jnp.abs(a[blocks] - b[blocks]).max()) < 1e-6
+            rest = np.setdiff1d(np.arange(a.shape[0]), blocks)
+            assert (np.asarray(a[rest]) == np.asarray(c[rest])).all()
+    grew = np.asarray(both.aux) - before
+    apart = np.asarray(two.aux) - before
+    d, p, da = (lf.PROGRAMS.index(x) for x in ("decode", "prefill",
+                                               "decode_admit"))
+    assert not grew[d].any() and not grew[p].any()
+    tail = {name: cfg.num_held + i for i, name in enumerate(lf.COUNTER_TAIL)}
+    # what a token or a pick adds is the two programs' sum ...
+    additive = list(range(cfg.num_held)) + [
+        tail[x] for x in ("identity_picks", "absent_picks", "tokens_routed")]
+    assert (grew[da][additive] == (apart[d] + apart[p])[additive]).all()
+    assert grew[da][tail["tokens_routed"]] == (2 * decoding + n
+                                               ) * cfg.num_layers
+    # ... and the layer ran once, over the union of their rows
+    assert grew[da][tail["layer_calls"]] == cfg.num_layers
+    hit = tail["held_experts_hit"]
+    assert max(apart[d][hit], apart[p][hit]) <= grew[da][hit] <= (
+        apart[d][hit] + apart[p][hit])
+    if not decoding:
+        assert (grew[da] == apart[p]).all()
 
 
 # --------------------------------------- absorbed = materialised attention
@@ -720,10 +795,13 @@ def test_configuration_file_states_the_published_sizes_once():
     assert cell["config"]["engine"]["num_slots"] == 256
     assert "serve_out_tokens_per_s" in cell["end_to_end"]
     # the readers this cell brought: it leads their lists (a later cell
-    # of another family that holds experts reports three of them too)
+    # of another family that holds experts reports three of them too);
+    # 12 with the cell, 2 of the host loop's with the rider round (PR 51)
     new = [m for m in contract["per_layer"]
            if m.get("workloads", [])[:1] == [CELL]]
-    assert len(new) == 12
+    assert len(new) == 14
+    assert [m["name"] for m in new[-2:]] == [
+        "longcat_admission_step_ms", "longcat_rider_admissions_pct"]
     for m in new + [m for m in contract["per_layer"]
                     if CELL in m.get("workloads", ())]:
         assert os.path.exists(os.path.join(BENCH, "metrics",
@@ -774,3 +852,38 @@ def test_the_cell_runs_at_a_tiny_size_through_the_harness(tmp_path):
         assert name in metrics, name
     assert 0 < metrics["moe_identity_pick_pct"]["value"] < 100
     assert metrics["moe_held_load_max_over_mean"]["value"] >= 1.0
+
+
+def test_the_programs_alone_script_holds_the_rider_to_the_reference(capsys):
+    """``scripts/longcat_admit_programs.py`` at its toy size (float32):
+    with every slot but one decoding and with none, both buckets,
+    ``paged_decode_admit`` serves the tokens of the two programs run
+    apart from the same pool, leaves the pool they leave, and both are
+    the float32 reference's choice; every program is timed."""
+    script = harness.load_module(
+        os.path.join(REPO, "scripts", "longcat_admit_programs.py"),
+        "longcat_admit_programs")
+    assert script.main(["--tiny", "--calls", "1", "--ref-rows", "4"]) == 0
+    lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()
+             if x.startswith("{")]
+    parity = [x["parity"] for x in lines if "parity" in x]
+    assert [(p["bucket"], p["form"]) for p in parity] == [
+        (128, "rider"), (128, "alone"), (256, "rider"), (256, "alone")]
+    for p in parity:
+        apart, to_ref = [p["rider_row"]], [p["rider_row_to_reference"]]
+        if p["form"] == "rider":
+            assert p["decode_rows"]["rows"] == 7
+            apart.append(p["decode_rows"])
+            to_ref.append(p["decode_rows_to_reference"])
+        for rows in apart:
+            assert rows["tokens_equal"] == rows["rows"]
+            assert rows["logit_gap_p50_p99_max"][-1] < 1e-4
+        for rows in to_ref:
+            assert rows["decode_admit"]["exact"] == rows["rows"]
+            assert rows["decode_admit"]["max_logit_gap"] < 1e-4
+        assert p["pool"]["beyond_a_64th_of_row_max"] == 0
+        assert p["pool"]["lengths_equal"] and p["pool"]["tables_equal"]
+    assert [x["program"] for x in lines if "program" in x] == [
+        "decode", "prefill_128", "decode_admit_128_rider",
+        "decode_admit_128_alone", "prefill_256", "decode_admit_256_rider",
+        "decode_admit_256_alone"]

@@ -496,6 +496,68 @@ def test_latent_decode_program_walks_the_pool_where_it_lies(chips,
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
 
 
+@pytest.mark.parametrize("bucket", [128, 256])
+def test_latent_decode_admit_program_updates_the_donated_pool(
+        chips, monkeypatch, bucket):
+    """LongCat's ``serve_decode_admit`` (the decode step and one admitted
+    prompt in one forward) at the cell's widths, 256 slots, pool and
+    both prompt buckets (two of its four layers, a small vocabulary, two
+    held experts), read back from its compiled text: an attention is one
+    ``paged_latent_append`` and one ``paged_latent_decode_attention``
+    for the decode rows and one ``flash_attention_fwd`` for the prompt;
+    every buffer of the donated cache comes back in the buffer it came
+    in; and nothing writes as much as one attention's pool or converts
+    its layout, so no second copy of the pool is planned."""
+    from deepspeed_tpu.inference.kv_cache import init_latent_paged_cache
+    from deepspeed_tpu.inference.server import ContinuousBatchingServer as Srv
+    from deepspeed_tpu.model_implementations import longcat_flash as lf
+    from deepspeed_tpu.ops.pallas import latent_decode_attention as lda
+    from deepspeed_tpu.telemetry import compile_watch
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(fa, "_should_interpret", lambda: False)
+    one = SingleDeviceSharding(chips[0])
+    g = LATENT
+    cfg = lf.LongcatFlashConfig(vocab_size=2048, num_layers=2,
+                                experts_held=(0, 2))
+    abstract = functools.partial(_abstract, sharding=one)
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    params = abstract(jax.eval_shape(
+        lambda: lf.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = abstract(jax.eval_shape(lambda: init_latent_paged_cache(
+        cfg.attentions, g["slots"], g["blocks"], BS, MB, cfg.latent_width,
+        aux_shape=cfg.aux_shape)))
+    compiled = jax.jit(compile_watch._named(
+        functools.partial(Srv._decode_admit_fn, cfg=cfg, mesh=None),
+        "serve_decode_admit"), donate_argnames=("cache",)).lower(
+        params, arr((g["slots"],)), cache, arr((g["slots"],), jnp.bool_),
+        arr((1, bucket)), arr((1,)), arr(())).compile()
+    text = compiled.as_text()
+    assert "HloModule jit_serve_decode_admit" in text
+    scopes, kernels = compile_watch.parse_scopes(text)
+    ours = {k: name for k, name in kernels.items()
+            if name.startswith(("paged_latent", "flash_"))}
+    assert sorted(ours.values()) == sorted(
+        [lda.NAME, "paged_latent_append", "flash_attention_fwd"]
+        * cfg.attentions)
+    innermost = {v.rsplit("/", 1)[-1] for v in scopes.values() if v}
+    assert innermost >= LATENT_SCOPES, LATENT_SCOPES - innermost
+    # the cache is donated: every one of its buffers is aliased to an
+    # output (pools, tables, lengths, counters)
+    header = text[:text.index("\n\n")]
+    donated = len(jax.tree.leaves(cache))
+    assert header.count("may-alias") + header.count("must-alias") >= donated
+    pool = cache.rows[0]
+    pool_bytes = math.prod(pool.shape) * pool.dtype.itemsize
+    assert not _copies(
+        text, lambda dims, nbytes: nbytes >= pool_bytes
+        or (sorted(dims[-2:]) == sorted(pool.shape[-2:])
+            and math.prod(dims[:-2]) > bucket // BS),
+        pool_dims=pool.shape, kernels=kernels)
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+
+
 @pytest.mark.parametrize("kind", ["decode", "chunk"])
 def test_gigachat_programs_read_the_latent_pool_where_it_lies(
         chips, monkeypatch, kind):
