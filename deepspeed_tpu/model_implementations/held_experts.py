@@ -7,12 +7,25 @@ The family routes (scores, selection and weights are its own: a softmax
 with zero-compute experts, a sigmoid with normalised weights and a
 shared expert, the same under a group limit: :func:`group_limited_top_k`);
 what is here is everything after the picks: the picks
-that landed on a held expert sorted by expert, a grouped matmul
-(``jax.lax.ragged_dot``; a Mosaic kernel on a TPU) over those rows only,
-the weighted sum back to tokens, and the routing counters. Picks on
-absent experts are left out: their holders add those parts. Nothing
-stands in for the other chips. The scopes (``moe_dispatch``,
-``moe_experts``, ``moe_combine``) are the names the trace readers know.
+that landed on a held expert sorted by expert, a grouped matmul over
+those rows only, the weighted sum back to tokens, and the routing
+counters. Picks on absent experts are left out: their holders add those
+parts. Nothing stands in for the other chips. The scopes
+(``moe_dispatch``, ``moe_experts``, ``moe_combine``) are the names the
+trace readers know.
+
+The grouped matmul has two forms of one algorithm (sorted rows against
+per-group weights), chosen from its static shapes (:func:`matmul_form`):
+the small-tile Pallas kernel of ``ops/pallas/grouped_matmul.py``
+(``held_experts_grouped_matmul``: a row tile of 16-128 rows chosen from
+the mean group, every hit expert's weights read once in blocks of
+megabytes; interpret mode off the TPU) wherever the buffer holds a row
+tile, and ``jax.lax.ragged_dot`` under one (a toy batch). On a TPU the
+compiler makes a Mosaic kernel of its own of a ``ragged_dot``
+(``ragged-dot-none``), which computes a whole row tile of up to 512 rows
+for every group that touches it and moves its weights in ``[512, 512]``
+blocks: a decode step's groups of 1-13 rows paid for 128-256 rows and a
+thousand grid steps a call.
 
 Shared code: it imports no model.
 """
@@ -23,7 +36,10 @@ import math
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.ops.pallas.grouped_matmul import (MIN_ROW_TILE,
+                                                     grouped_matmul)
 from deepspeed_tpu.profiling.trace import scoped
+from deepspeed_tpu.telemetry.registry import get_registry
 
 F32 = jnp.float32
 
@@ -115,17 +131,40 @@ def _gather_rows(u, order, k: int, rows: int):
     return u[order[:rows] // k]
 
 
+def matmul_form(R: int) -> str:
+    """``"tiled"`` or ``"ragged_dot"`` for a buffer of ``R`` rows: the
+    small-tile kernel wherever the buffer holds one of its row tiles. On
+    the chip it was measured faster than the compiler's kernel at every
+    geometry the four families give it, from 3 rows a group in a 256-row
+    buffer to 711 in 25,600 (PERF.md section 6, PR 52), so no shape with
+    a tile to fill is left to the compiler. Under a row tile (a toy
+    batch) there is nothing to tile, and the compiler expands such a
+    ``ragged_dot`` into plain products."""
+    return "tiled" if R >= MIN_ROW_TILE else "ragged_dot"
+
+
 @scoped("moe_experts")
 def _experts(xs, group_sizes, ex):
-    """SwiGLU of each row's expert: a grouped matmul (``ragged_dot``; a
-    Mosaic kernel on a TPU) that visits only the rows inside the groups.
-    Rows past the groups come back as whatever the kernel left there."""
+    """SwiGLU of each row's expert: a grouped matmul that visits only the
+    rows inside the groups, in the form :func:`matmul_form` gives its
+    shapes (counted once a traced call site in
+    ``serve_moe_expert_matmul_sites_total``). Rows past the groups come
+    back as whatever the kernel left there."""
     dt = xs.dtype
-    gu = jax.lax.ragged_dot(xs, ex["w_in"].astype(dt), group_sizes)
+    R = xs.shape[0]
+    form = matmul_form(R)
+    get_registry().counter(
+        "serve_moe_expert_matmul_sites_total",
+        help="held-experts grouped matmuls traced into a program, by the "
+             "form their static shapes chose (tiled: the small-tile "
+             "Pallas kernel; ragged_dot: the compiler's) and the rows "
+             "of their buffer",
+        labels={"form": form, "rows": str(R)}).inc()
+    matmul = grouped_matmul if form == "tiled" else jax.lax.ragged_dot
+    gu = matmul(xs, ex["w_in"].astype(dt), group_sizes)
     Fe = gu.shape[-1] // 2
     h = jax.nn.silu(gu[:, :Fe].astype(F32)) * gu[:, Fe:].astype(F32)
-    return jax.lax.ragged_dot(h.astype(dt), ex["w_out"].astype(dt),
-                              group_sizes)
+    return matmul(h.astype(dt), ex["w_out"].astype(dt), group_sizes)
 
 
 @scoped("moe_combine")
@@ -165,9 +204,12 @@ def fast_rows(T: int, k: int) -> int:
     """Rows the expert matmul is given when the landed picks fit them
     (nearly always: 1 pick in 48 lands at LongCat-Flash's published
     sizes, 1 in 8 at an eighth of 256 experts, and this is T / 2 or
-    128). The grouped matmul tiles its rows by ``min(rows, 512)`` and
-    computes whole tiles, so a small buffer is what keeps its work near
-    the landed picks; ``T k`` rows stay the exact fallback."""
+    128). The buffer bounds the dispatch gather and the combine's
+    ``[T, rows]`` product; the small-tile grouped matmul itself visits
+    only the row tiles the groups reach, so its slack costs little
+    (under the compiler's kernel, which computed a whole tile of up to
+    512 rows a group, the buffer's size WAS the matmul's work); ``T k``
+    rows stay the exact fallback."""
     return min(T * k, max(128, T // 2))
 
 

@@ -33,6 +33,7 @@ from deepspeed_tpu.inference.kv_cache import (  # noqa: E402
 from deepspeed_tpu.model_implementations import (  # noqa: E402
     longcat_flash as lf)
 from deepspeed_tpu.model_implementations import transformer  # noqa: E402
+from deepspeed_tpu.ops.pallas import grouped_matmul  # noqa: E402
 from deepspeed_tpu.ops.pallas import latent_decode_attention as lda  # noqa: E402
 
 BENCH = os.path.join(REPO, "benchmark")
@@ -526,24 +527,32 @@ def _shapes_with_experts_first(text, cfg, tokens):
 
 def test_no_dense_expert_tensor_in_the_decode_program(f32):
     """The held-experts layer gathers the landed picks and runs a grouped
-    matmul (``ragged_dot``) over them: the decode program's jaxpr holds
-    no ``[experts, tokens, ...]`` tensor. (What a backend makes of
-    ``ragged_dot`` is its own: the CPU expands it, a TPU runs a Mosaic
-    kernel; the next test compiles for one.)"""
+    matmul over them, in either of its forms (the small-tile Pallas
+    kernel or ``ragged_dot``: ``held_experts.matmul_form``): the decode
+    program's jaxpr holds no ``[experts, tokens, ...]`` tensor. (What a
+    backend makes of ``ragged_dot`` is its own: the CPU expands it, a TPU
+    runs a Mosaic kernel; the next test compiles for one.)"""
     cfg, params, _ = f32
     cache = _pool(cfg)
     jaxpr = str(jax.make_jaxpr(lambda p, t, c, a: transformer.paged_decode_step(
         p, cfg, t, c, a))(params, jnp.zeros((SLOTS,), jnp.int32), cache,
                           jnp.ones((SLOTS,), bool)))
-    assert "ragged_dot" in jaxpr
+    assert "ragged_dot" in jaxpr or grouped_matmul.NAME in jaxpr
     assert not _shapes_with_experts_first(jaxpr, cfg, SLOTS)
 
 
-def test_expert_layer_compiles_for_v5e_as_a_grouped_matmul_kernel():
-    """Compiled for a described (not attached) TPU v5e, at widths large
-    enough that the compiler does not expand the grouped matmul: the
-    expert layer is the chip's ``ragged-dot`` Mosaic custom call, and the
-    optimized module has no ``[experts, tokens, ...]`` tensor."""
+def test_expert_layer_compiles_for_v5e_as_a_grouped_matmul_kernel(
+        monkeypatch):
+    """Compiled for a described (not attached) TPU v5e, at toy widths
+    and at the published ones: the expert layer is the small-tile Pallas
+    kernel (``held_experts_grouped_matmul``) in both branches of
+    ``held_experts_part``, and the optimized module has no ``[experts,
+    tokens, ...]`` tensor. A bare ``ragged_dot`` is the chip's
+    ``ragged-dot`` Mosaic custom call, whose row tiling is pinned here:
+    it is what the kernel was measured against."""
+    # the process is on the CPU (the kernel would pick interpret mode);
+    # the compile target is not
+    monkeypatch.setattr(grouped_matmul, "_should_interpret", lambda: False)
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
@@ -577,14 +586,18 @@ def test_expert_layer_compiles_for_v5e_as_a_grouped_matmul_kernel():
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
         compilation_cache.reset_cache()
-    assert "ragged-dot" in text and "tpu_custom_call" in text
+    assert f'{grouped_matmul.NAME}/pallas_call"' in text
+    assert "ragged-dot" not in text and "tpu_custom_call" in text
     assert not _shapes_with_experts_first(text, cfg, tokens)
     assert not _shapes_with_experts_first(text, cfg, tokens * cfg.moe_topk)
-    # what ``_fast_rows`` rests on: at the published sizes the compiler
-    # tiles the grouped matmul's rows by min(rows, 512) and computes
-    # whole tiles per group, so the 128-row buffer of 256 slots costs a
-    # quarter of a 512-row tile per expert and all 3072 picks would cost
-    # a whole one. If this changes, measure ``_fast_rows`` again.
+    # what the choice of the small-tile kernel was measured against
+    # (PERF.md section 6, PR 52): the compiler's own kernel for a
+    # ``ragged_dot`` tiles the rows by the largest power of two up to
+    # 512 that divides them and computes whole tiles per group, so a
+    # 128- or 640-row buffer costs 128 rows an expert, 768 rows cost 256
+    # (PR 50 read that step as +1.7 ms) and all 3072 picks cost 512. If
+    # this changes, measure the two forms again
+    # (``scripts/grouped_matmul_micro.py``).
     import re
     full = lf.LongcatFlashConfig(vocab_size=256, experts_held=(0, 16))
     E, Fe, X = full.hidden_size, full.expert_ffn_hidden_size, full.num_held
@@ -592,14 +605,30 @@ def test_expert_layer_compiles_for_v5e_as_a_grouped_matmul_kernel():
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        for rows, tile in ((128, 128), (256 * full.moe_topk, 512)):
+        for rows, tile in ((128, 128), (640, 128), (768, 256),
+                           (256 * full.moe_topk, 512)):
             text = jax.jit(jax.lax.ragged_dot).lower(
                 on_chip(jax.ShapeDtypeStruct((rows, E), jnp.bfloat16)),
                 on_chip(jax.ShapeDtypeStruct((X, E, 2 * Fe), jnp.bfloat16)),
                 on_chip(jax.ShapeDtypeStruct((X,), jnp.int32))
             ).compile().as_text()
-            assert re.findall(r'ragged_dot_tiling="(\d+),', text) == [
-                str(tile)], (rows, tile)
+            assert "ragged-dot" in text and re.findall(
+                r'ragged_dot_tiling="(\d+),', text) == [str(tile)], (
+                    rows, tile)
+        # the decode step's expert layer at the published widths: the
+        # small-tile kernel in both branches, none of the compiler's
+        slots = 256
+        moe = jax.eval_shape(lambda k: lf._init_layer(k, full)["moe"],
+                             jax.random.PRNGKey(0))
+        text = jax.jit(lambda u, m, v: lf.moe_layer(u, m, full, v)).lower(
+            on_chip(jax.ShapeDtypeStruct((slots, E), jnp.bfloat16)),
+            on_chip(moe),
+            on_chip(jax.ShapeDtypeStruct((slots,), bool))
+        ).compile().as_text()
+        assert text.count(
+            f'{grouped_matmul.NAME}/pallas_call"') == 4, "w_in, w_out x 2"
+        assert "ragged-dot" not in text
+        assert not _shapes_with_experts_first(text, full, slots)
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
         compilation_cache.reset_cache()

@@ -24,6 +24,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 
 from deepspeed_tpu.ops.pallas import decode_attention as da
 from deepspeed_tpu.ops.pallas import flash_attention as fa
+from deepspeed_tpu.ops.pallas import grouped_matmul as gm
 from deepspeed_tpu.ops.pallas import layer_norm as ln
 
 # GPT-2 1.3B serving geometry: 16 heads x D=128, block 128, 8 slots,
@@ -199,7 +200,29 @@ def _flash_window():
     return fwd, [((1, g["prompt"], g["window_heads"], 128), BF16), kv, kv]
 
 
+def _grouped_matmul(R, X, E, Fe):
+    """The small-tile grouped matmul as the held experts' layer calls it
+    (``w_in``, the SwiGLU, ``w_out``) over a decode program's buffer of
+    ``R`` rows and ``X`` held experts at a cell's full widths."""
+    def fwd(xs, sizes, w_in, w_out):
+        gu = gm.grouped_matmul(xs, w_in, sizes).astype(jnp.float32)
+        h = jax.nn.silu(gu[:, :Fe]) * gu[:, Fe:]
+        return gm.grouped_matmul(h.astype(xs.dtype), w_out, sizes)
+    return fwd, [((R, E), BF16), ((X,), jnp.int32),
+                 ((X, E, 2 * Fe), BF16), ((X, Fe, E), BF16)]
+
+
 CASES = {
+    "grouped-matmul-granite-decode": functools.partial(
+        _grouped_matmul, 640, 36, 4096, 768),
+    "grouped-matmul-laguna-decode": functools.partial(
+        _grouped_matmul, 256, 32, 2048, 512),
+    "grouped-matmul-gigachat-decode": functools.partial(
+        _grouped_matmul, 128, 16, 7168, 2048),
+    "grouped-matmul-longcat-decode": functools.partial(
+        _grouped_matmul, 128, 16, 6144, 2048),
+    "grouped-matmul-longcat-rider": functools.partial(
+        _grouped_matmul, 256, 16, 6144, 2048),
     "paged_window_decode-ring": _window_decode,
     "flash-window-fwd": _flash_window,
     "latent-decode": _latent_decode,
@@ -235,6 +258,7 @@ def test_kernel_compiles_for_v5e(case, chips, monkeypatch):
     # process is on the CPU, the compile target is not
     monkeypatch.setattr(fa, "_should_interpret", lambda: False)
     monkeypatch.setattr(ln, "_should_interpret", lambda: False)
+    monkeypatch.setattr(gm, "_should_interpret", lambda: False)
     fn, shapes = CASES[case]()
     one = SingleDeviceSharding(chips[0])
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one)
@@ -242,6 +266,16 @@ def test_kernel_compiles_for_v5e(case, chips, monkeypatch):
     compiled = jax.jit(fn).lower(*args).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
+    if case.startswith("grouped-matmul-"):
+        # both matmuls are the small-tile kernel, and neither asked for
+        # more than the default scoped VMEM (a call that does re-lays the
+        # fusions of the whole program around it: the compiler then
+        # writes what it was given on every instruction)
+        from deepspeed_tpu.telemetry import compile_watch
+        _, kernels = compile_watch.parse_scopes(text)
+        assert sorted(kernels.values()) == [gm.NAME] * 2
+        assert set(re.findall(r'"scoped_memory_configs":\[([^\]]*)\]',
+                              text)) == {""}
     if case.startswith(("paged_", "latent-")):
         # the pool goes to the kernel as it is stored: nothing as large
         # as one layer of K (one attention's latent pool), and nothing
@@ -265,6 +299,16 @@ _ITEMSIZE = {"bf16": 2, "f16": 2, "f32": 4, "s32": 4, "u32": 4, "s8": 1,
 # what moves no byte of its operand, and what updates the pool in place
 _FREE = {"parameter", "get-tuple-element", "tuple", "bitcast"}
 _IN_PLACE = {"fusion", "scatter", "dynamic-update-slice"}
+
+
+def _without_grouped_matmuls(kernels, scopes):
+    """``kernels`` less the expert layers' grouped matmuls: the compiler's
+    (``ragged-dot-*``, which carry no scope) and ours, which must sit
+    under the scope ``moe_experts`` (the trace readers sum that scope)."""
+    tiled = [k for k, v in kernels.items() if v == gm.NAME]
+    assert all(scopes[k].rsplit("/", 1)[-1] == "moe_experts" for k in tiled)
+    return {k: v for k, v in kernels.items()
+            if v != gm.NAME and not v.startswith("ragged")}
 
 
 def _copies(text, is_big, pool_dims=None, kernels=()):
@@ -438,10 +482,13 @@ LATENT_SCOPES = {"embed", "ln", "mla_qkv", "latent_write", "mla_attn",
 def test_latent_decode_program_walks_the_pool_where_it_lies(chips,
                                                             monkeypatch):
     """LongCat's ``serve_decode`` at the cell's widths, slots and pool
-    (two of its four layers, a small vocabulary, two held experts), read
-    back from its compiled text: module, kernel and scope names; one
-    ``paged_latent_decode_attention`` and one ``paged_latent_append`` an
-    attention; and apart from those calls (the append rewrites its
+    (two of its four layers, a small vocabulary, the cell's sixteen held
+    experts), read back from its compiled text: module, kernel and scope
+    names; one ``paged_latent_decode_attention`` and one
+    ``paged_latent_append`` an attention; two
+    ``held_experts_grouped_matmul`` an expert layer and branch under the
+    scope ``moe_experts`` and no grouped matmul of the compiler's; and
+    apart from those calls (the append rewrites its
     donated pool in place) nothing writes as much as one attention's
     pool: no copy, no transpose, no temporary of that size."""
     from deepspeed_tpu.inference.kv_cache import init_latent_paged_cache
@@ -453,7 +500,7 @@ def test_latent_decode_program_walks_the_pool_where_it_lies(chips,
     one = SingleDeviceSharding(chips[0])
     g = LATENT
     cfg = lf.LongcatFlashConfig(vocab_size=2048, num_layers=2,
-                                experts_held=(0, 2))
+                                experts_held=(0, 16))
     assert (cfg.num_attention_heads, cfg.latent_width, cfg.kv_lora_rank) == (
         g["heads"], g["width"], g["value_dim"])
 
@@ -467,18 +514,29 @@ def test_latent_decode_program_walks_the_pool_where_it_lies(chips,
         cfg.attentions, g["slots"], g["blocks"], BS, MB, cfg.latent_width,
         aux_shape=cfg.aux_shape)))
     lda._latent_call.cache_clear()
-    compiled = jax.jit(compile_watch._named(
+    lowered = jax.jit(compile_watch._named(
         functools.partial(Srv._decode_fn, cfg=cfg, mesh=None),
         "serve_decode"), donate_argnames=("cache",)).lower(
         params, arr((g["slots"],)), cache,
-        arr((g["slots"],), jnp.bool_)).compile()
+        arr((g["slots"],), jnp.bool_))
+    compiled = lowered.compile()
     # four attentions, one signature: one kept call, found again thrice
     info = lda._latent_call.cache_info()
     assert (info.misses, info.hits) == (1, cfg.attentions - 1)
     text = compiled.as_text()
     assert "HloModule jit_serve_decode" in text
     scopes, kernels = compile_watch.parse_scopes(text)
-    # (the compiler's grouped matmuls are kernels of its own naming)
+    # the expert layers' grouped matmuls: ``w_in`` and ``w_out`` in each
+    # branch of ``held_experts_part`` (the 128-row buffer and the exact
+    # ``T k`` fallback), lowered once a signature (a function of its own
+    # in the module) and called a layer
+    experts = [k for k, name in kernels.items() if name == gm.NAME]
+    assert len(experts) == 4 * cfg.num_layers
+    assert all(scopes[k].rsplit("/", 1)[-1] == "moe_experts"
+               for k in experts)
+    assert not [name for name in kernels.values()
+                if name.startswith("ragged")]
+    assert lowered.as_text().count(f'kernel_name = "{gm.NAME}"') == 4
     ours = {k: name for k, name in kernels.items()
             if name.startswith("paged_latent")}
     assert sorted(ours.values()) == sorted(
@@ -767,7 +825,7 @@ def test_window_and_full_layers_share_one_program(chips, monkeypatch, kind,
     text = compiled.as_text()
     assert f"HloModule jit_{name}" in text
     scopes, kernels = compile_watch.parse_scopes(text)
-    ours = {k: v for k, v in kernels.items() if not v.startswith("ragged")}
+    ours = _without_grouped_matmuls(kernels, scopes)
     counts = {v: list(ours.values()).count(v) for v in set(ours.values())}
     assert counts == kernels_want
     for k, v in ours.items():
@@ -850,7 +908,7 @@ def test_state_layers_update_in_place_beside_the_block_pool(
     text = compiled.as_text()
     assert f"HloModule jit_{name}" in text
     scopes, kernels = compile_watch.parse_scopes(text)
-    ours = {k: v for k, v in kernels.items() if not v.startswith("ragged")}
+    ours = _without_grouped_matmuls(kernels, scopes)
     assert list(ours.values()) == [kernel]
     assert all(scopes[k].split("/")[0] == "attn_full" for k in ours)
     words = {w for v in scopes.values() if v for w in v.split("/")}
