@@ -174,8 +174,9 @@ def advance(cache: KVCache, n: int = 1) -> KVCache:
 class PagedKVCache:
     """Paged decode workspace over ``num_slots`` resident sequences.
 
-    k/v: ``[L, num_blocks, block_size, KH*D]`` global pool, ONE stacked
-    array each, stored in the byte order the paged kernels read
+    k/v: ``[L, num_blocks, block_size, KH*D]`` (v: ``KH*Dv``, a value
+    head's own width, where it is not a key head's) global pool, ONE
+    stacked array each, stored in the byte order the paged kernels read
     (ops/pallas/decode_attention.py): a position's row is its
     ``num_kv_heads`` heads side by side, ``KH*D`` lanes wide, so a block
     is one contiguous ``[block_size, KH*D]`` slab and the kernels take
@@ -187,9 +188,17 @@ class PagedKVCache:
     ``KH*D`` should be a multiple of the 128 lanes; a narrower or ragged
     row is stored the other way round by the compiler and converted
     around every call. Writers reshape the NEW rows, never the pool.
-    num_kv_heads: static; how the ``KH*D`` lanes split into heads
-    (``head_dim`` follows). Under a ``tensor`` mesh the lane dim is the
-    sharded one: heads are its major part, so a shard keeps whole heads.
+    num_kv_heads: static; how the lanes of a POOL row split into heads
+    (``head_dim``, a key head's width, and ``v_head_dim``, a value
+    head's, follow from the two arrays: equal unless the cache was built
+    with its own ``v_head_dim``). Under a ``tensor`` mesh the lane dim is
+    the sharded one: heads are its major part, so a shard keeps whole
+    heads.
+    ring_kv_heads: static; how the lanes of a RING row split (None: as
+    the pool's). The two kinds of place may keep different head counts
+    (a model whose window layers carry 8 key/value heads beside 4 on its
+    full layers): four row widths in one cache object, one allocator,
+    one set of block tables.
     block_tables: ``[num_slots, max_blocks]`` int32 — pool block ids per
     slot, in logical order (entry j covers positions
     ``j*block_size .. (j+1)*block_size-1``); unallocated entries are 0
@@ -210,8 +219,10 @@ class PagedKVCache:
     Window layers (``layer_map``; None = every layer keeps its whole
     context, the default): a layer whose attention sees only the last
     ``window`` positions keeps a bounded RING a slot instead of blocks of
-    the pool: ``ring_k`` / ``ring_v`` ``[Lw, S*RB, BS, KH*D]``, the same
-    row and block as the pool, slot ``s``'s ring the ``RB`` consecutive
+    the pool: ``ring_k`` / ``ring_v`` ``[Lw, S*RB, BS, KHw*D]`` /
+    ``[.., KHw*Dv]``, the pool's block and head widths (``KHw``:
+    ``ring_heads``, the pool's count unless ``ring_kv_heads`` says
+    otherwise), slot ``s``'s ring the ``RB`` consecutive
     blocks from ``s*RB``, position ``p`` at row ``p mod (RB*BS)`` of it.
     A ring never grows, takes no block of the pool and no entry of the
     block tables (admission counts the other layers' blocks only), and
@@ -234,19 +245,21 @@ class PagedKVCache:
     aux: an int32 array that belongs to the MODEL, as
     :class:`LatentPagedCache`'s (None: the model counts nothing)."""
     k: jnp.ndarray             # [L, NB, BS, KH*D] (fp or int8)
-    v: jnp.ndarray             # [L, NB, BS, KH*D]
+    v: jnp.ndarray             # [L, NB, BS, KH*Dv]
     block_tables: jnp.ndarray  # [S, MB] int32
     lengths: jnp.ndarray       # [S] int32
     num_kv_heads: int = struct.field(pytree_node=False)
     k_scale: Optional[jnp.ndarray] = None   # [L, NB, KH, BS] f32 | None
     v_scale: Optional[jnp.ndarray] = None
-    ring_k: Optional[jnp.ndarray] = None    # [Lw, S*RB, BS, KH*D] | None
-    ring_v: Optional[jnp.ndarray] = None
+    ring_k: Optional[jnp.ndarray] = None    # [Lw, S*RB, BS, KHw*D] | None
+    ring_v: Optional[jnp.ndarray] = None    # [Lw, S*RB, BS, KHw*Dv]
     aux: Optional[jnp.ndarray] = None       # the model's; int32
     layer_map: Optional[tuple] = struct.field(pytree_node=False,
                                               default=None)
     state: Optional[tuple] = None           # of [S, *state_shape] | None
     conv: Optional[tuple] = None            # of [taps, S, C] | None
+    ring_kv_heads: Optional[int] = struct.field(pytree_node=False,
+                                                default=None)
 
     @property
     def quantized(self) -> bool:
@@ -254,7 +267,18 @@ class PagedKVCache:
 
     @property
     def head_dim(self) -> int:
+        """A KEY head's width (a query's), in the pool and the rings."""
         return self.k.shape[3] // self.num_kv_heads
+
+    @property
+    def v_head_dim(self) -> int:
+        """A VALUE head's width: ``head_dim`` unless built otherwise."""
+        return self.v.shape[3] // self.num_kv_heads
+
+    @property
+    def ring_heads(self) -> int:
+        """Key/value heads of a ring row."""
+        return self.ring_kv_heads or self.num_kv_heads
 
     @property
     def block_size(self) -> int:
@@ -328,7 +352,9 @@ def init_paged_cache(num_layers: int, num_slots: int, num_blocks: int,
                      aux_shape: Optional[tuple] = None,
                      state_layers: Optional[tuple] = None,
                      state_shapes: Optional[tuple] = None,
-                     state_dtype=jnp.float32) -> PagedKVCache:
+                     state_dtype=jnp.float32,
+                     v_head_dim: Optional[int] = None,
+                     ring_kv_heads: Optional[int] = None) -> PagedKVCache:
     """``num_blocks`` INCLUDES the reserved null block 0, so the usable
     pool is ``num_blocks - 1`` blocks. ``quantized=True`` builds the
     int8 pool (payload dtype int8 regardless of ``dtype``) with
@@ -343,8 +369,23 @@ def init_paged_cache(num_layers: int, num_slots: int, num_blocks: int,
     (``(state_shape, (taps, channels))``, one slot's of one layer): the
     layers marked true keep a recurrent state and a convolution tail a
     slot (``state_dtype`` and ``dtype``) and no row; the pool holds the
-    other layers only."""
+    other layers only.
+
+    ``v_head_dim`` (None: ``head_dim``): a value head's width where it is
+    not a key head's; V's rows, in the pool and in the rings, are then
+    ``heads * v_head_dim`` wide beside K's ``heads * head_dim``.
+    ``ring_kv_heads`` (None: ``num_kv_heads``): the window layers' own
+    key/value head count."""
     layer_map = rings = states = None
+    v_dim = head_dim if v_head_dim is None else v_head_dim
+    if quantized and v_dim != head_dim:
+        raise NotImplementedError(
+            "int8 rows of two widths: a scale tile holds one scale a "
+            "(position, head) row for K and V alike, and no kernel or "
+            "writer has been tested over a pool whose K and V rows differ "
+            "in width")
+    if ring_kv_heads is not None and not sum(window_layers or ()):
+        raise ValueError("ring_kv_heads without window layers")
     n_window = sum(window_layers) if window_layers is not None else 0
     n_state = sum(state_layers) if state_layers is not None else 0
     if n_window or n_state:
@@ -368,7 +409,7 @@ def init_paged_cache(num_layers: int, num_slots: int, num_blocks: int,
     if n_window:
         rings = (n_window,
                  num_slots * ring_blocks_for(window, block_size),
-                 block_size, num_kv_heads * head_dim)
+                 block_size)
     if n_state:
         s_shape, (taps, channels) = state_shapes
         # one array PER layer and field: a buffer shared by two would be
@@ -377,8 +418,14 @@ def init_paged_cache(num_layers: int, num_slots: int, num_blocks: int,
                         for _ in range(n_state)),
                   tuple(jnp.zeros((taps, num_slots, channels), dtype)
                         for _ in range(n_state)))
-    shape = (num_layers, num_blocks, block_size, num_kv_heads * head_dim)
+    pool = (num_layers, num_blocks, block_size)
     pool_dtype = jnp.int8 if quantized else dtype
+    ring_heads = ring_kv_heads or num_kv_heads
+
+    def rows(place, heads, width, dtype):
+        """A place's rows: ``heads`` heads of ``width`` lanes side by
+        side."""
+        return jnp.zeros((*place, heads * width), dtype)
 
     def scales():
         # one array PER field: aliasing k_scale/v_scale to the same
@@ -390,15 +437,18 @@ def init_paged_cache(num_layers: int, num_slots: int, num_blocks: int,
             jnp.float32)
 
     return PagedKVCache(
-        k=jnp.zeros(shape, pool_dtype), v=jnp.zeros(shape, pool_dtype),
+        k=rows(pool, num_kv_heads, head_dim, pool_dtype),
+        v=rows(pool, num_kv_heads, v_dim, pool_dtype),
         block_tables=jnp.zeros((num_slots, max_blocks_per_slot),
                                jnp.int32),
         lengths=jnp.zeros((num_slots,), jnp.int32),
         num_kv_heads=num_kv_heads, k_scale=scales(), v_scale=scales(),
-        ring_k=None if rings is None else jnp.zeros(rings, dtype),
-        ring_v=None if rings is None else jnp.zeros(rings, dtype),
+        ring_k=(None if rings is None
+                else rows(rings, ring_heads, head_dim, dtype)),
+        ring_v=(None if rings is None
+                else rows(rings, ring_heads, v_dim, dtype)),
         aux=None if aux_shape is None else jnp.zeros(aux_shape, jnp.int32),
-        layer_map=layer_map,
+        layer_map=layer_map, ring_kv_heads=ring_kv_heads,
         state=None if states is None else states[0],
         conv=None if states is None else states[1])
 

@@ -1275,7 +1275,9 @@ class ContinuousBatchingServer:
             aux_shape=getattr(mcfg, "aux_shape", None),
             state_layers=getattr(mcfg, "state_layers", None),
             state_shapes=getattr(mcfg, "state_shapes", None),
-            state_dtype=getattr(mcfg, "state_dtype", jnp.float32))
+            state_dtype=getattr(mcfg, "state_dtype", jnp.float32),
+            v_head_dim=getattr(mcfg, "v_head_dim", None),
+            ring_kv_heads=getattr(mcfg, "ring_kv_heads", None))
         if cache.state is not None:
             self.telemetry.gauge(
                 "serve_kv_state_bytes",

@@ -54,6 +54,21 @@ static shapes alone:
   of ``KH``). Grouped-query attention is native on both paths: a kv
   head's slice is attended by its whole query group at once, so GQA's
   bandwidth saving survives.
+- key heads whose lanes do not tile (:func:`_ragged`: 192, where every
+  other head of a row starts inside a 128-lane tile) have no aligned
+  slice for a product of their own: they share the block-diagonal
+  product up to ``MAX_RAGGED_ROWS`` query rows (MiMo-V2's decode: 4
+  heads x 16 rows and 8 x 8, operands ``[64, 768]`` / ``[64, 1536]``,
+  still under the time the block's bytes take), and more rows are
+  refused by name.
+
+A value head may be narrower (or wider) than a key head: V's rows are
+``KH * Dv`` wide beside K's ``KH * D``, the accumulator and the output
+``Dv`` wide a head; nothing else changes. A ``sink [H]`` (float32, one
+query token a slot) is a learned logit a query head that joins the
+softmax's denominator and carries no value: the online softmax's carry
+starts at ``(m, l, acc) = (sink, 1, 0)`` in place of ``(-inf, 0, 0)``.
+With ``Dv == D`` and no sink the traced kernel is the one it was.
 
 int8 pools (kv_cache_dtype: "int8", docs/serving.md "KV quantization &
 host tiering") add per-block-per-head scale tiles ``[L, NB, KH, BS]``
@@ -86,15 +101,22 @@ MAX_QUERY_ROWS = 128
 # has rows enough for a product of its own (the shared accumulator grows
 # with the square of the head count)
 MAX_BATCHED_ROWS = 32
+# ... and what it may carry when a key head's lanes do not tile (a head
+# of 192 starts every other head in the middle of a 128-lane tile, so a
+# product per head has no aligned slice of the slab to take): such heads
+# share the block-diagonal product up to here, and more rows are refused
+MAX_RAGGED_ROWS = 64
 
 
 def _layer_pools(k_pool, v_pool, D, k_scale, v_scale):
     """What the reference oracles attend: ONE layer's pools
-    ``[NB, BS, KH*D]`` as ``[NB, BS, KH, D]``, an int8 pool dequantized
-    by XLA (scales ``[NB, KH, BS]`` broadcast against it)."""
+    ``[NB, BS, KH*D]`` / ``[NB, BS, KH*Dv]`` as ``[NB, BS, KH, D]`` /
+    ``[NB, BS, KH, Dv]`` (``D`` the query's width; a value's follows from
+    the head count), an int8 pool dequantized by XLA (scales ``[NB, KH,
+    BS]`` broadcast against it)."""
     from deepspeed_tpu.ops.quant_core import dequantize_int8
     k = k_pool.reshape(*k_pool.shape[:2], -1, D)
-    v = v_pool.reshape(*v_pool.shape[:2], -1, D)
+    v = v_pool.reshape(*v_pool.shape[:2], k.shape[2], -1)
     if k_scale is None:
         return k, v
     k = dequantize_int8(k, jnp.transpose(k_scale, (0, 2, 1))[..., None])
@@ -105,7 +127,7 @@ def _layer_pools(k_pool, v_pool, D, k_scale, v_scale):
 def _paged_kernel(base_ref, bt_ref, offset_ref, q_ref, *rest,
                   block_size: int, head_dim: int, rep: int, span: int,
                   scale: float, quantized: bool, batched: bool,
-                  window: int = 0):
+                  window: int = 0, v_head_dim: int = 0, sink: bool = False):
     """Grid (slot, query-row block): one step walks ITS slot's live
     blocks (:func:`~deepspeed_tpu.ops.pallas.block_walk.walk_live_blocks`
     has the scaffold: two VMEM buffers a stream, the next step's first
@@ -142,7 +164,18 @@ def _paged_kernel(base_ref, bt_ref, offset_ref, q_ref, *rest,
     <= p`` and ``base - p < window``. The walk is the same walk: a ring
     has ``min(ceil((base + 1) / BS), MB)`` live blocks, so a context
     however long reads at most the ring.
+
+    ``v_head_dim`` (static; 0: the keys' ``head_dim``): a value head's
+    width where it is not a key head's: the V slab is ``[BS, KH*Dv]`` and
+    the output and the accumulator ``Dv`` wide a head. ``sink`` (static):
+    one more operand, a float32 logit a query row (``sink_ref``, shaped
+    as ``m``), that joins the softmax's denominator and carries no value:
+    the carry starts at ``(m, l, acc) = (sink, 1, 0)`` in place of
+    ``(-inf, 0, 0)``. An idle slot still writes zeros.
     """
+    sink_ref = None
+    if sink:
+        sink_ref, *rest = rest
     if quantized:
         (k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf,
          sems, next_buf, m_ref, l_ref, acc_ref, *rest) = rest
@@ -158,6 +191,7 @@ def _paged_kernel(base_ref, bt_ref, offset_ref, q_ref, *rest,
     S, RB = pl.num_programs(0), pl.num_programs(1)
     MB = bt_ref.shape[1]
     D, BS = head_dim, block_size
+    Dv = v_head_dim or D
     KH = k_buf.shape[-1] // D
     rows = acc_ref.shape[-2] // KH if batched else acc_ref.shape[-2]
     cdt = q_ref.dtype
@@ -176,8 +210,12 @@ def _paged_kernel(base_ref, bt_ref, offset_ref, q_ref, *rest,
         o_ref[...] = jnp.zeros_like(o_ref)
 
     def walk(loop):
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        if sink:
+            m_ref[...] = sink_ref[...]
+            l_ref[...] = jnp.ones_like(l_ref)
+        else:
+            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
         R = acc_ref.shape[-2]
         row = jax.lax.broadcasted_iota(jnp.int32, (R, BS), 0)
@@ -257,6 +295,7 @@ def _paged_kernel(base_ref, bt_ref, offset_ref, q_ref, *rest,
                 return
             for h in range(KH):
                 lanes = slice(h * D, (h + 1) * D)
+                v_lanes = slice(h * Dv, (h + 1) * Dv)
                 sc = jax.lax.dot_general(
                     q_ref[0, h], operand(k_buf[buf, :, lanes]),
                     (((1,), (1,)), ((), ())),
@@ -268,13 +307,13 @@ def _paged_kernel(base_ref, bt_ref, offset_ref, q_ref, *rest,
                 if quantized:
                     p = p * vs_buf[buf, h:h + 1, :]
                 acc_ref[h] = acc_ref[h] * alpha + p_dot_v(
-                    p, operand(v_buf[buf, :, lanes]))
+                    p, operand(v_buf[buf, :, v_lanes]))
 
         loop(attend)
         l = jnp.maximum(l_ref[...], 1e-30)
         if batched:     # each row's own diagonal block of the accumulator
             out = functools.reduce(jnp.add, own_head(
-                lambda h: acc_ref[:, h * D:(h + 1) * D], D))
+                lambda h: acc_ref[:, h * Dv:(h + 1) * Dv], Dv))
             o_ref[0] = (out / l).astype(o_ref.dtype)
         else:
             o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
@@ -288,30 +327,47 @@ def _paged_kernel(base_ref, bt_ref, offset_ref, q_ref, *rest,
 
 def _paged_attention(qg, k_pool, v_pool, block_tables, base, *, rep: int,
                      scale, interpret, name: str, layer: int = 0,
-                     k_scale=None, v_scale=None, window: int = 0):
+                     k_scale=None, v_scale=None, window: int = 0,
+                     sink=None):
     """The decode family's one ``pallas_call``, named ``name`` in the
     compiled program and the device trace (the entry point's name: the
     kernel body is shared). qg ``[S, KH, T*rep, D]``
     (each slot's T query tokens x ``rep`` group members, token-major,
     grouped by the kv head they read); pools ``[L, NB, BS, KH*D]``, the
     whole stacked pool, of which the call attends layer ``layer``
-    (static); block_tables ``[S, MB]`` of that layer's block ids (only
+    (static), V's rows ``KH*Dv`` wide where a value is not as wide as a
+    key; block_tables ``[S, MB]`` of that layer's block ids (only
     the entries a slot's bound reaches are read); base ``[S]``: slot s's
-    token t sees key positions ``<= base[s] + t``. Returns
-    ``[S, KH, T*rep, D]``.
+    token t sees key positions ``<= base[s] + t``; ``sink [KH*rep]``
+    float32 (one query token a slot only): a logit a query head that
+    joins the softmax's denominator and carries no value. Returns
+    ``[S, KH, T*rep, Dv]``.
 
     The layer reaches the kernel as DATA (its block offset, one more
     prefetched scalar), so the calls of a model's layers are one traced
     kernel reused (:func:`_paged_call`), not a trace a layer."""
     S, KH, rows, D = qg.shape
     L, NB, BS, W = k_pool.shape
+    Wv = v_pool.shape[-1]
     quantized = k_scale is not None
     if (k_pool.dtype == jnp.int8) != quantized:
         raise ValueError("int8 pools require k_scale/v_scale (and fp "
                          "pools must not pass them)")
-    if W != KH * D:
-        raise ValueError(f"pool rows are {W} wide; {KH} kv heads x "
-                         f"{D} need {KH * D}")
+    if W != KH * D or Wv % KH or v_pool.shape[:3] != (L, NB, BS):
+        raise ValueError(f"pool rows are {W} (K) and {Wv} (V) wide; {KH} "
+                         f"kv heads x {D} need {KH * D} of K and a "
+                         f"multiple of {KH} of V")
+    if sink is not None and (rows != rep or sink.shape != (KH * rep,)):
+        raise ValueError(
+            f"a sink of shape {sink.shape} for {KH * rep} query heads and "
+            f"{rows // rep} query tokens a slot: one float32 a query head, "
+            "one query token a slot")
+    if _ragged(D) and KH * rows > MAX_RAGGED_ROWS:
+        raise ValueError(
+            f"key heads of {D} lanes do not tile the 128 lanes, so the "
+            f"heads of a block share one product, which carries at most "
+            f"{MAX_RAGGED_ROWS} query rows; {KH} kv heads x {rows} rows "
+            f"are {KH * rows}")
     if not 0 <= layer < L:
         raise ValueError(f"layer {layer} of a {L}-layer pool")
     if window and (rows != rep or quantized
@@ -326,34 +382,47 @@ def _paged_attention(qg, k_pool, v_pool, block_tables, base, *, rep: int,
         interpret = jax.default_backend() != "tpu"
     # the layers' blocks back to back: merging the two leading dims of
     # the stored array is free, and layer l's block b is block l*NB + b
-    pools = [k_pool.reshape(L * NB, BS, W), v_pool.reshape(L * NB, BS, W)]
+    pools = [k_pool.reshape(L * NB, BS, W), v_pool.reshape(L * NB, BS, Wv)]
     if quantized:
         pools += [k_scale.reshape(L * NB, KH, BS),
                   v_scale.reshape(L * NB, KH, BS)]
     call = _paged_call(
         name, bool(interpret), (S, KH, rows, D), qg.dtype.name,
         tuple((x.shape, x.dtype.name) for x in pools),
-        block_tables.shape[1], rep, float(scale), int(window))
+        block_tables.shape[1], rep, float(scale), int(window),
+        sink is not None)
+    sinks = () if sink is None else (sink.astype(jnp.float32),)
     return call(base.astype(jnp.int32), block_tables.astype(jnp.int32),
-                jnp.full((1,), layer * NB, jnp.int32), qg, *pools)
+                jnp.full((1,), layer * NB, jnp.int32), qg, *sinks, *pools)
+
+
+def _ragged(D: int) -> bool:
+    """A head of ``D`` lanes neither fills whole 128-lane tiles nor
+    divides one: every other head of a row starts inside a tile."""
+    return bool(D % 128 and 128 % D)
 
 
 @functools.lru_cache(maxsize=None)
 def _paged_call(name: str, interpret: bool, q_shape, q_dtype: str, pools,
-                MB: int, rep: int, scale: float, window: int = 0):
+                MB: int, rep: int, scale: float, window: int = 0,
+                sink: bool = False):
     """The ``pallas_call`` of one static signature: ``(base [S], tables
-    [S, MB], block offset [1], qg [S, KH, rows, D], *pools) -> [S, KH,
-    rows, D]``. Grid ``(S, row blocks)``, in order; the pools (``pools``:
+    [S, MB], block offset [1], qg [S, KH, rows, D][, sink [KH*rows]],
+    *pools) -> [S, KH, rows, Dv]``. Grid ``(S, row blocks)``, in order; the pools (``pools``:
     shape and dtype of K, V and an int8 pool's two scale-tile arrays,
     layers merged into the block dim) stay in HBM and
     :func:`_paged_kernel` copies the blocks it walks into two VMEM
     buffers a stream. The heads share one product a block when all
-    their rows fit ``MAX_BATCHED_ROWS``. Kept per signature, because
+    their rows fit ``MAX_BATCHED_ROWS`` (``MAX_RAGGED_ROWS`` where a
+    key head's lanes do not tile: :func:`_ragged`). Kept per signature, because
     jax traces a call it has seen before from its cache: the 24 layers
     of a decode program trace the kernel body once."""
     S, KH, rows, D = q_shape
     (_, BS, W), pool_dtype = pools[0]
-    batched = KH * rows <= MAX_BATCHED_ROWS
+    Wv = pools[1][0][2]
+    Dv = Wv // KH
+    batched = KH * rows <= (MAX_RAGGED_ROWS if _ragged(D)
+                            else MAX_BATCHED_ROWS)
     # tokens per row block: halve while the rows overrun the VMEM budget
     # and the halves still tile (a split block's sublane dim must be a
     # multiple of 8)
@@ -365,43 +434,53 @@ def _paged_call(name: str, interpret: bool, q_shape, q_dtype: str, pools,
     f32 = jnp.float32
     if batched:
         # heads down the rows: merging the two dims moves no byte
-        q_block = (S, KH * rows, D)
-        q_spec = pl.BlockSpec((1, KH * rows, D), lambda s, rb, *_: (s, 0, 0))
-        softmax_state = [pltpu.VMEM((KH * rows, 1), f32)] * 2 + [
-            pltpu.VMEM((KH * rows, W), f32),
+        q_block, o_block = (S, KH * rows, D), (S, KH * rows, Dv)
+        m_shape = (KH * rows, 1)
+        spec = lambda d: pl.BlockSpec(                      # noqa: E731
+            (1, KH * rows, d), lambda s, rb, *_: (s, 0, 0))
+        softmax_state = [pltpu.VMEM(m_shape, f32)] * 2 + [
+            pltpu.VMEM((KH * rows, Wv), f32),
             pltpu.VMEM((KH * rows, W), q_dtype)]    # block-diagonal q
     else:
-        q_block = q_shape
-        q_spec = pl.BlockSpec((1, KH, rblk, D),
-                              lambda s, rb, *_: (s, 0, rb, 0))
-        softmax_state = [pltpu.VMEM((KH, rblk, 1), f32)] * 2 + [
-            pltpu.VMEM((KH, rblk, D), f32)]
+        q_block, o_block = q_shape, (S, KH, rows, Dv)
+        m_shape = (KH, rblk, 1)
+        spec = lambda d: pl.BlockSpec(                      # noqa: E731
+            (1, KH, rblk, d), lambda s, rb, *_: (s, 0, rb, 0))
+        softmax_state = [pltpu.VMEM(m_shape, f32)] * 2 + [
+            pltpu.VMEM((KH, rblk, Dv), f32)]
+    # the sink: one block shaped as the running max, the same every step
+    sink_spec = [pl.BlockSpec(m_shape, lambda s, rb, *_: (0,) * len(m_shape))
+                 ] if sink else []
     kernel = functools.partial(
         _paged_kernel, block_size=BS, head_dim=D, rep=rep, span=span,
         scale=scale, quantized=len(pools) == 4, batched=batched,
-        window=window)
+        window=window, v_head_dim=Dv, sink=sink)
     call = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(S, rows // rblk),
-            in_specs=[q_spec] + [pl.BlockSpec(memory_space=pl.ANY)
-                                 ] * len(pools),
-            out_specs=q_spec,
+            in_specs=[spec(D)] + sink_spec + [
+                pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+            out_specs=spec(Dv),
             scratch_shapes=[
                 *[pltpu.VMEM((2, *shape[1:]), dtype)
                   for shape, dtype in pools],
                 pltpu.SemaphoreType.DMA((len(pools), 2)),
                 pltpu.SMEM((1,), jnp.int32), *softmax_state]),
-        out_shape=jax.ShapeDtypeStruct(q_block, q_dtype),
+        out_shape=jax.ShapeDtypeStruct(o_block, q_dtype),
         # in order: a step starts the next step's first block
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name=name,
     )
-    return lambda base, tables, offset, qg, *pools: call(
-        base, tables, offset, qg.reshape(q_block), *pools).reshape(q_shape)
+    def run(base, tables, offset, qg, *rest):
+        if sink:
+            rest = (rest[0].reshape(m_shape), *rest[1:])
+        return call(base, tables, offset, qg.reshape(q_block),
+                    *rest).reshape(*q_shape[:3], Dv)
+    return run
 
 
 def _group_size(H: int, KH: int) -> int:
@@ -448,16 +527,20 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            interpret: bool | None = None,
                            k_scale: jax.Array | None = None,
                            v_scale: jax.Array | None = None,
-                           layer: int = 0) -> jax.Array:
+                           layer: int = 0,
+                           sink: jax.Array | None = None) -> jax.Array:
     """One-token attention through a paged KV pool, GQA-native.
 
-    q: ``[S, H, D]`` (one query per slot); k_pool/v_pool:
-    ``[L, NB, BS, KH*D]`` (the PagedKVCache pool as stored, all layers;
-    the call attends layer ``layer``, a static int);
+    q: ``[S, H, D]`` (one query per slot); k_pool ``[L, NB, BS, KH*D]``
+    and v_pool ``[L, NB, BS, KH*Dv]`` (the PagedKVCache pool as stored,
+    all layers; the call attends layer ``layer``, a static int; a value
+    may be narrower or wider than a key);
     block_tables: ``[S, MB]`` int32 (entry j covers logical positions
     ``j*BS..(j+1)*BS-1``; entries beyond a slot's length are never
     read); lengths: ``[S]`` int32 live lengths (the query attends
-    positions ``< lengths[s]``). Returns ``[S, H, D]``.
+    positions ``< lengths[s]``); sink: ``[H]`` float32 or None, a
+    learned logit a query head that joins the softmax's denominator and
+    carries no value. Returns ``[S, H, Dv]``.
 
     int8 pools pass ``k_scale``/``v_scale`` ``[L, NB, KH, BS]``; the
     grid, the walk and the recurrence are unchanged (scales are two
@@ -471,8 +554,8 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
         q.reshape(S, KH, R, D), k_pool, v_pool, block_tables,
         lengths.astype(jnp.int32) - 1, rep=R, scale=scale,
         interpret=interpret, name="paged_decode_attention", layer=layer,
-        k_scale=k_scale, v_scale=v_scale)
-    return og.reshape(S, H, D)
+        k_scale=k_scale, v_scale=v_scale, sink=sink)
+    return og.reshape(S, H, -1)
 
 
 def ring_tables(num_slots: int, ring_blocks: int) -> jax.Array:
@@ -487,18 +570,21 @@ def paged_window_decode_attention(q: jax.Array, k_ring: jax.Array,
                                   window: int,
                                   scale: float | None = None,
                                   interpret: bool | None = None,
-                                  layer: int = 0) -> jax.Array:
+                                  layer: int = 0,
+                                  sink: jax.Array | None = None
+                                  ) -> jax.Array:
     """One-token attention of a WINDOW layer through its rings,
     GQA-native: the paged kernel's walk over a table computed from the
     slot, rows masked by the position they hold.
 
-    q: ``[S, H, D]``; k_ring/v_ring: ``[Lw, S*RB, BS, KH*D]`` (all window
-    layers' rings as stored; the call attends ring layer ``layer``, a
-    static int); lengths: ``[S]`` int32 live lengths (the query sits at
+    q: ``[S, H, D]``; k_ring ``[Lw, S*RB, BS, KH*D]`` and v_ring ``[Lw,
+    S*RB, BS, KH*Dv]`` (all window layers' rings as stored; the call
+    attends ring layer ``layer``, a static int); sink ``[H]`` float32 or
+    None, as :func:`paged_decode_attention`'s; lengths: ``[S]`` int32 live lengths (the query sits at
     position ``lengths[s] - 1`` and sees positions ``> lengths[s] - 1 -
     window``). A slot reads ``min(ceil(lengths / BS), RB)`` blocks
     whatever its context; an idle slot (length 0) reads nothing and
-    returns zeros. Returns ``[S, H, D]``."""
+    returns zeros. Returns ``[S, H, Dv]``."""
     S, H, D = q.shape
     KH = k_ring.shape[-1] // D
     R = _group_size(H, KH)
@@ -507,19 +593,32 @@ def paged_window_decode_attention(q: jax.Array, k_ring: jax.Array,
         ring_tables(S, k_ring.shape[1] // S),
         lengths.astype(jnp.int32) - 1, rep=R, scale=scale,
         interpret=interpret, name="paged_window_decode_attention",
-        layer=layer, window=window)
-    return og.reshape(S, H, D)
+        layer=layer, window=window, sink=sink)
+    return og.reshape(S, H, -1)
+
+
+def _sink_softmax(s, seen, sink):
+    """Softmax of ``s [B, H, n]`` over the ``seen`` columns; with a
+    ``sink [H]`` one more logit column a head, dropped after it took its
+    share of the probability."""
+    s = jnp.where(seen, s, NEG_INF)
+    if sink is None:
+        return jax.nn.softmax(s, axis=-1)
+    col = jnp.broadcast_to(sink.astype(jnp.float32)[None, :, None],
+                           (*s.shape[:2], 1))
+    return jax.nn.softmax(jnp.concatenate([s, col], -1), axis=-1)[..., :-1]
 
 
 def paged_window_decode_attention_reference(q, k_ring, v_ring, lengths,
-                                            window: int):
+                                            window: int, sink=None):
     """Numerics oracle over ONE window layer's rings ``[S*RB, BS,
-    KH*D]`` (and the path off the TPU): every ring row with the position
-    it holds, a dense softmax over the rows inside the window."""
+    KH*D]`` / ``[S*RB, BS, KH*Dv]`` (and the path off the TPU): every
+    ring row with the position it holds, a dense softmax over the rows
+    inside the window (and the ``sink [H]`` column, if any)."""
     from deepspeed_tpu.inference.kv_cache import ring_newest_position
     S, H, D = q.shape
     k = k_ring.reshape(S, -1, k_ring.shape[-1] // D, D)     # [S, R, KH, D]
-    v = v_ring.reshape(S, -1, v_ring.shape[-1] // D, D)
+    v = v_ring.reshape(S, k.shape[1], k.shape[2], -1)       # [S, R, KH, Dv]
     rep = H // k.shape[2]
     newest = lengths.astype(jnp.int32) - 1
     pos = ring_newest_position(newest, k.shape[1])          # [S, R]
@@ -527,8 +626,8 @@ def paged_window_decode_attention_reference(q, k_ring, v_ring, lengths,
     s = jnp.einsum("bhd,bshd->bhs", q.astype(jnp.float32),
                    jnp.repeat(k, rep, axis=2).astype(jnp.float32)
                    ) / (D ** 0.5)
-    s = jnp.where(seen[:, None, :], s, NEG_INF)
-    p = jnp.where(seen[:, None, :], jax.nn.softmax(s, axis=-1), 0.0)
+    p = jnp.where(seen[:, None, :],
+                  _sink_softmax(s, seen[:, None, :], sink), 0.0)
     return jnp.einsum("bhs,bshd->bhd", p,
                       jnp.repeat(v, rep, axis=2).astype(jnp.float32)
                       ).astype(q.dtype)
@@ -665,23 +764,26 @@ def paged_chunk_attention_reference(q, k_pool, v_pool, block_table, start,
 
 
 def paged_decode_attention_reference(q, k_pool, v_pool, block_tables,
-                                     lengths, k_scale=None, v_scale=None):
-    """Numerics oracle over ONE layer's pools ``[NB, BS, KH*D]``: gather
-    each slot's cache through its block table (gathered position j IS
-    logical position j), then run the dense masked-softmax reference.
-    int8 pools dequantize up front."""
+                                     lengths, k_scale=None, v_scale=None,
+                                     sink=None):
+    """Numerics oracle over ONE layer's pools ``[NB, BS, KH*D]`` /
+    ``[NB, BS, KH*Dv]``: gather each slot's cache through its block
+    table (gathered position j IS logical position j), then run the
+    dense masked-softmax reference. int8 pools dequantize up front."""
     k_pool, v_pool = _layer_pools(k_pool, v_pool, q.shape[-1], k_scale,
                                   v_scale)
     S, MB = block_tables.shape
     BS = k_pool.shape[1]
     kc = k_pool[block_tables].reshape(S, MB * BS, *k_pool.shape[2:])
     vc = v_pool[block_tables].reshape(S, MB * BS, *v_pool.shape[2:])
-    return decode_attention_reference(q, kc, vc, lengths)
+    return decode_attention_reference(q, kc, vc, lengths, sink)
 
 
-def decode_attention_reference(q, k_cache, v_cache, lengths):
+def decode_attention_reference(q, k_cache, v_cache, lengths, sink=None):
     """Numerics oracle (pure jnp, XLA) — also the CPU fallback path.
-    Same layouts as :func:`decode_attention`."""
+    Same layouts as :func:`decode_attention` (``v_cache`` may be ``[B, S,
+    KH, Dv]``; ``sink [H]``: a logit column a head whose probability is
+    dropped)."""
     B, H, D = q.shape
     S, KH = k_cache.shape[1], k_cache.shape[2]
     rep = H // KH
@@ -690,7 +792,6 @@ def decode_attention_reference(q, k_cache, v_cache, lengths):
     s = jnp.einsum("bhd,bshd->bhs", q.astype(jnp.float32),
                    kc.astype(jnp.float32)) / (D ** 0.5)
     mask = jnp.arange(S)[None, None, :] < lengths[:, None, None]
-    s = jnp.where(mask, s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
+    p = _sink_softmax(s, mask, sink)
     return jnp.einsum("bhs,bshd->bhd", p,
                       vc.astype(jnp.float32)).astype(q.dtype)

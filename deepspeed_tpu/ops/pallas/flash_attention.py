@@ -170,9 +170,11 @@ def _fwd_carry(block_q: int, d: int):
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale: float,
                 block_q: int, block_k: int, causal: bool, static: bool):
     """One grid step is one query head: ``q_ref``/``o_ref [1, T, D]``,
-    ``k_ref``/``v_ref [1, T, D]`` of its kv head, ``lse_ref [1, T/BQ,
+    ``k_ref [1, T, D]``/``v_ref [1, T, Dv]`` of its kv head (``o_ref [1,
+    T, Dv]`` where a value is not as wide as a key), ``lse_ref [1, T/BQ,
     BQ]``."""
-    seq_len, d = q_ref.shape[1:]
+    seq_len = q_ref.shape[1]
+    d = v_ref.shape[2]      # a value's width, the output's
     num_kb = seq_len // block_k
     dtype = q_ref.dtype
     # under a looped q axis an unmasked row of K blocks still has static
@@ -256,8 +258,10 @@ def _head_specs(BH: int, BKH: int, T: int, D: int, block_q: int):
 
 @functools.lru_cache(maxsize=None)
 def _fwd_call(BH: int, BKH: int, T: int, D: int, dtype, scale: float,
-              block_q: int, block_k: int, causal: bool, interpret: bool):
-    """``(q3 [B*H, T, D], k3, v3 [B*HKV, T, D]) -> (o, lse)`` (HKV | H —
+              block_q: int, block_k: int, causal: bool, interpret: bool,
+              Dv: int | None = None):
+    """``(q3 [B*H, T, D], k3, v3 [B*HKV, T, D]) -> (o, lse)`` (``v3`` and
+    ``o`` ``Dv`` wide where ``Dv`` is given; HKV | H —
     grouped-query attention streams each K/V head into VMEM ONCE for its
     whole query group: the grid is (kv-head, group) with the group
     fastest, so the K/V block index is constant across a group and pallas
@@ -266,7 +270,9 @@ def _fwd_call(BH: int, BKH: int, T: int, D: int, dtype, scale: float,
     positions on the lanes. Kept per static signature and jitted, because
     jax traces a call it has seen before from its cache: the 24 layers of
     a step trace and lower the written-out body once."""
+    Dv = D if Dv is None else Dv
     head, stat, kv = _head_specs(BH, BKH, T, D, block_q)
+    o_head, _, v_kv = _head_specs(BH, BKH, T, Dv, block_q)
     return jax.jit(pl.pallas_call(
         functools.partial(
             _fwd_kernel, scale=scale, block_q=block_q, block_k=block_k,
@@ -274,24 +280,29 @@ def _fwd_call(BH: int, BKH: int, T: int, D: int, dtype, scale: float,
             static=_score_blocks(T, block_q, block_k,
                                  causal) <= _UNROLL_BLOCKS),
         grid=(BKH, BH // BKH),
-        in_specs=[head, kv, kv],
-        out_specs=[head, stat],
-        out_shape=[jax.ShapeDtypeStruct((BH, T, D), dtype),
+        in_specs=[head, kv, v_kv],
+        out_specs=[o_head, stat],
+        out_shape=[jax.ShapeDtypeStruct((BH, T, Dv), dtype),
                    jax.ShapeDtypeStruct((BH, T // block_q, block_q),
                                         jnp.float32)],
         compiler_params=_vmem_params(
-            2 * 4 * T * D * dtype.itemsize + 2 * T * 4, "the forward"),
+            2 * 2 * T * (D + Dv) * dtype.itemsize + 2 * T * 4,
+            "the forward"),
         interpret=interpret,
         name="flash_attention_fwd"))
 
 
-def _window_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale: float,
+def _window_fwd_kernel(q_ref, k_ref, v_ref, *rest, scale: float,
                        block_q: int, block_k: int, window: int):
     """The windowed forward (serving prefill of a sliding-window layer;
     grid (kv-head, group, q-block)): query row ``i`` sees keys ``j`` with
     ``i - window < j <= i``. K blocks wholly before the block's first
     window are skipped, the blocks a window's lower edge cuts are masked,
-    the ones between them and the diagonal are fully visible."""
+    the ones between them and the diagonal are fully visible. With a
+    fourth operand, ``sink_ref [1, 1, 128]`` (the query head's learned
+    logit on every lane), the softmax's denominator has one more term
+    that carries no value: the carry starts at ``(sink, 1, 0)``."""
+    sink_ref, o_ref, lse_ref = rest if len(rest) == 3 else (None, *rest)
     qi = pl.program_id(2)
     q = q_ref[0]  # [BQ, D]
     qs = (q.astype(jnp.float32) * scale).astype(q.dtype)
@@ -309,37 +320,49 @@ def _window_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale: float,
         return _fwd_block(qs, k_ref, v_ref, kb, block_k, carry, q0, window)
 
     masked = functools.partial(block, q0=qi * block_q)
-    carry = jax.lax.fori_loop(first_kb, edge_end, masked,
-                              _fwd_carry(*q.shape))
+    start = _fwd_carry(block_q, v_ref.shape[2])
+    if sink_ref is not None:
+        start = (jnp.broadcast_to(sink_ref[0, :, :1], start[0].shape),
+                 jnp.ones_like(start[1]), start[2])
+    carry = jax.lax.fori_loop(first_kb, edge_end, masked, start)
     carry = jax.lax.fori_loop(edge_end, diag_start, block, carry)
     m, l, acc = jax.lax.fori_loop(diag_start, num_kb, masked, carry)
     o_ref[0] = (acc / l).astype(o_ref.dtype)
     lse_ref[0] = m + jnp.log(l)  # [BQ, 1]
 
 
-def _flash_window_fwd(q3, k3, v3, *, scale, block_q, block_k, window,
+def _flash_window_fwd(q3, k3, v3, sink, *, scale, block_q, block_k, window,
                       interpret):
     """The windowed forward's call: K/V of one kv head whole in VMEM, one
-    q block a grid step, the q-block axis fastest."""
+    q block a grid step, the q-block axis fastest. ``v3 [B*HKV, T, Dv]``
+    may be narrower or wider than a key; ``sink [B*H]`` float32 or None."""
     BH, T, D = q3.shape
-    BKH = k3.shape[0]
+    BKH, _, Dv = v3.shape
     rep = BH // BKH
     qmap = lambda bkh, g, qi: (bkh * rep + g, qi, 0)  # noqa: E731
     kvmap = lambda bkh, g, qi: (bkh, 0, 0)  # noqa: E731
+    sinks, sink_spec = (), []
+    if sink is not None:
+        # a head's logit on every lane of one row: a block that spans its
+        # array's last two dims
+        sinks = (jnp.broadcast_to(sink.astype(jnp.float32)[:, None, None],
+                                  (BH, 1, 128)),)
+        sink_spec = [pl.BlockSpec(
+            (1, 1, 128), lambda bkh, g, qi: (bkh * rep + g, 0, 0))]
     o, _ = pl.pallas_call(
         functools.partial(_window_fwd_kernel, scale=scale, block_q=block_q,
                           block_k=block_k, window=window),
         grid=(BKH, rep, T // block_q),
         in_specs=[pl.BlockSpec((1, block_q, D), qmap),
                   pl.BlockSpec((1, T, D), kvmap),
-                  pl.BlockSpec((1, T, D), kvmap)],
-        out_specs=[pl.BlockSpec((1, block_q, D), qmap),
+                  pl.BlockSpec((1, T, Dv), kvmap)] + sink_spec,
+        out_specs=[pl.BlockSpec((1, block_q, Dv), qmap),
                    pl.BlockSpec((1, block_q, 1), qmap)],
-        out_shape=[jax.ShapeDtypeStruct(q3.shape, q3.dtype),
+        out_shape=[jax.ShapeDtypeStruct((BH, T, Dv), q3.dtype),
                    jax.ShapeDtypeStruct((BH, T, 1), jnp.float32)],
         interpret=interpret,
         name="flash_attention_window_fwd",
-    )(q3, k3, v3)
+    )(q3, k3, v3, *sinks)
     return o
 
 
@@ -497,12 +520,17 @@ def _signature(q3, k3, scale, block_q, block_k, causal):
 
 def _flash_attention_fwd(q3, k3, v3, scale, block_q, block_k, causal):
     o, lse = _fwd_call(*_signature(q3, k3, scale, block_q, block_k,
-                                   causal))(q3, k3, v3)
+                                   causal), v3.shape[2])(q3, k3, v3)
     return o, (q3, k3, v3, o, lse)
 
 
 def _flash_attention_bwd(scale, block_q, block_k, causal, res, do3):
     q3, k3, v3, o3, lse = res
+    if v3.shape[2] != q3.shape[2]:
+        raise NotImplementedError(
+            f"flash_attention with values D_v = {v3.shape[2]} wide beside "
+            f"keys D = {q3.shape[2]} wide has no backward kernel (D_v != "
+            "D is forward only: serving prefill)")
     # delta = rowsum(do * o), one fused pass that leaves it as the
     # forward left lse: positions on the lanes
     delta = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
@@ -514,27 +542,29 @@ def _flash_attention_bwd(scale, block_q, block_k, causal, res, do3):
 _flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_attention_window(q3, k3, v3, scale, block_q, block_k, window):
-    """The windowed forward. It has no backward kernels: differentiating
-    it is refused by name instead of falling through to a backward pass
-    that would ignore the window."""
-    return _flash_window_fwd(q3, k3, v3, scale=scale, block_q=block_q,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash_attention_window(q3, k3, v3, sink, scale, block_q, block_k,
+                            window):
+    """The windowed forward (``sink [B*H]`` float32 or None). It has no
+    backward kernels: differentiating it is refused by name instead of
+    falling through to a backward pass that would ignore the window (or
+    the sink)."""
+    return _flash_window_fwd(q3, k3, v3, sink, scale=scale, block_q=block_q,
                              block_k=block_k, window=window,
                              interpret=_should_interpret())
 
 
-def _flash_attention_window_fwd(q3, k3, v3, scale, block_q, block_k,
+def _flash_attention_window_fwd(q3, k3, v3, sink, scale, block_q, block_k,
                                 window):
-    return _flash_attention_window(q3, k3, v3, scale, block_q, block_k,
-                                   window), None
+    return _flash_attention_window(q3, k3, v3, sink, scale, block_q,
+                                   block_k, window), None
 
 
 def _flash_attention_window_bwd(scale, block_q, block_k, window, res, do3):
     raise NotImplementedError(
-        f"flash_attention(window={window}) has no backward kernels: the "
-        "windowed kernel is forward only (serving prefill); train a "
-        "windowed layer through the masked einsum")
+        f"flash_attention(window={window}) has no backward kernels, with "
+        "or without a sink: the windowed kernel is forward only (serving "
+        "prefill); train a windowed layer through the masked einsum")
 
 
 _flash_attention_window.defvjp(_flash_attention_window_fwd,
@@ -545,12 +575,20 @@ def flash_attention(q, k, v, causal: bool = True,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_k: int = DEFAULT_BLOCK_K,
                     scale: float | None = None,
-                    window: int | None = None):
-    """Fused attention, ``q [B, T, H, D] -> [B, T, H, D]``.
+                    window: int | None = None,
+                    sink: jax.Array | None = None):
+    """Fused attention, ``q [B, T, H, D] -> [B, T, H, Dv]``.
 
     ``window`` (causal only, forward only): query ``i`` sees keys ``i -
     window < j <= i``; K blocks wholly outside a query block's windows
     are skipped. Differentiating a windowed call raises by name.
+
+    ``v [B, T, HKV, Dv]`` may be narrower or wider than a key (forward
+    only: differentiating ``Dv != D`` raises by name). ``sink [H]``
+    float32 (windowed forward only; refused elsewhere by name): a
+    learned logit a query head that joins the softmax's denominator and
+    carries no value, ``p_ij = exp(s_ij) / (exp(sink_h) + sum_j'
+    exp(s_ij'))``.
 
     ``k``/``v`` may carry fewer heads (``[B, T, HKV, D]`` with HKV | H):
     grouped-query attention runs WITHOUT materializing the repeated k/v —
@@ -563,9 +601,16 @@ def flash_attention(q, k, v, causal: bool = True,
     """
     B, T, H, D = q.shape
     HKV = k.shape[2]
-    if k.shape != v.shape or k.shape[:2] != (B, T) or k.shape[3] != D:
-        raise ValueError(f"k/v shape {k.shape}/{v.shape} incompatible "
-                         f"with q {q.shape}")
+    if k.shape[:3] != v.shape[:3] or k.shape[:2] != (B, T) or k.shape[3] != D:
+        raise ValueError(
+            f"k/v shape {k.shape}/{v.shape} incompatible with q {q.shape}: "
+            f"k is [B, T, HKV, D] with q's D = {D}, v [B, T, HKV, D_v] "
+            "with k's heads (D_v may differ from D)")
+    if sink is not None and (window is None or sink.shape != (H,)):
+        raise ValueError(
+            f"sink of shape {sink.shape} with window={window}: a sink is "
+            f"one float32 a query head ([{H}]) of the windowed forward; "
+            "the full forward takes none")
     if H % HKV:
         raise ValueError(f"q heads {H} not divisible by kv heads {HKV}")
 
@@ -578,16 +623,17 @@ def flash_attention(q, k, v, causal: bool = True,
         scale = 1.0 / math.sqrt(D)
 
     def to3(x):
-        h = x.shape[2]
-        return jnp.swapaxes(x, 1, 2).reshape(B * h, T, D)
+        return jnp.swapaxes(x, 1, 2).reshape(B * x.shape[2], T, x.shape[3])
 
     if window is not None:
         if not causal or window < 1:
             raise ValueError(f"window={window} needs causal=True and a "
                              "window of at least one position")
-        o3 = _flash_attention_window(to3(q), to3(k), to3(v), float(scale),
-                                     block_q, block_k, int(window))
+        o3 = _flash_attention_window(
+            to3(q), to3(k), to3(v),
+            None if sink is None else jnp.tile(sink, B), float(scale),
+            block_q, block_k, int(window))
     else:
         o3 = _flash_attention(to3(q), to3(k), to3(v), float(scale),
                               block_q, block_k, causal)
-    return jnp.swapaxes(o3.reshape(B, H, T, D), 1, 2)
+    return jnp.swapaxes(o3.reshape(B, H, T, v.shape[3]), 1, 2)

@@ -452,6 +452,152 @@ def test_pool_arrays_are_the_stored_pool(quantized):
         2 * rows * (D + 4) if quantized else 2 * rows * D * 2)
 
 
+# ------------------------------ places with their own heads and widths
+
+def test_one_cache_keeps_four_row_widths_and_defaults_mean_what_they_did():
+    """``v_head_dim`` and ``ring_kv_heads``: a pool of 2-head rows beside
+    rings of 4-head rows, keys 24 and values 16 wide, in ONE cache
+    object over one set of block tables; writers reshape the new rows
+    only; ``pool_arrays`` counts the real bytes. Without the new
+    arguments the cache is the one it always was."""
+    from deepspeed_tpu.inference.kv_cache import (paged_append_token,
+                                                  ring_append_token,
+                                                  ring_write_prompt)
+    L, S, NB, BS, MB = 3, 2, 7, 16, 3
+    cache = init_paged_cache(
+        L, S, NB, BS, MB, 2, 24, jnp.float32,
+        window_layers=(False, True, True), window=16, v_head_dim=16,
+        ring_kv_heads=4)
+    assert cache.k.shape == (1, NB, BS, 2 * 24)
+    assert cache.v.shape == (1, NB, BS, 2 * 16)
+    assert cache.ring_k.shape == (2, S * 2, BS, 4 * 24)
+    assert cache.ring_v.shape == (2, S * 2, BS, 4 * 16)
+    assert (cache.num_kv_heads, cache.ring_heads, cache.head_dim,
+            cache.v_head_dim, cache.ring_rows) == (2, 4, 24, 16, 32)
+    assert sum(a.nbytes for a in pool_arrays(cache)) == 4 * (
+        NB * BS * 2 * 40 + 2 * S * 2 * BS * 4 * 40)
+    cache = cache.replace(
+        block_tables=jnp.asarray([[1, 2, 3], [4, 5, 6]], jnp.int32),
+        lengths=jnp.asarray([17, 3], jnp.int32))
+    k, v = _rand(1, (S, 2, 24)), _rand(2, (S, 2, 16))
+    out = paged_append_token(cache, 0, k, v)
+    np.testing.assert_array_equal(out.k[0, 2, 1], k[0].reshape(-1))
+    np.testing.assert_array_equal(out.v[0, 4, 3], v[1].reshape(-1))
+    rk, rv = _rand(3, (S, 4, 24)), _rand(4, (S, 4, 16))
+    out = ring_append_token(out, 1, rk, rv)
+    np.testing.assert_array_equal(out.ring_k[1, 1, 1], rk[0].reshape(-1))
+    np.testing.assert_array_equal(out.ring_v[1, 2, 3], rv[1].reshape(-1))
+    pk, pv = _rand(5, (48, 4, 24)), _rand(6, (48, 4, 16))
+    out = ring_write_prompt(out, 0, pk, pv, jnp.int32(1), jnp.int32(40))
+    np.testing.assert_array_equal(      # position 39 at row 39 mod 32 = 7
+        out.ring_v[0, 2, 7], pv[39].reshape(-1))
+    assert out.k.shape == cache.k.shape and out.ring_v.shape == (
+        cache.ring_v.shape)
+    # the defaults: one head count, one width, everywhere
+    old = init_paged_cache(L, S, NB, BS, MB, 2, 24, jnp.float32,
+                           window_layers=(False, True, True), window=16)
+    assert old.k.shape == old.v.shape == (1, NB, BS, 48)
+    assert old.ring_k.shape == old.ring_v.shape == (2, S * 2, BS, 48)
+    assert (old.ring_kv_heads, old.ring_heads, old.v_head_dim) == (
+        None, 2, 24)
+    with pytest.raises(NotImplementedError, match="two widths"):
+        init_paged_cache(L, S, NB, BS, MB, 2, 24, quantized=True,
+                         v_head_dim=16)
+    with pytest.raises(ValueError, match="ring_kv_heads"):
+        init_paged_cache(L, S, NB, BS, MB, 2, 24, ring_kv_heads=4)
+
+
+@pytest.mark.parametrize("kind", ["full", "window"])
+@pytest.mark.parametrize("sink", [False, True], ids=["no-sink", "sink"])
+@pytest.mark.parametrize("shape", ["d24-v16", "d192-v128", "d128-v64"])
+def test_decode_kernel_takes_narrow_values_and_a_sink(shape, sink, kind):
+    """The one decode kernel body (interpret mode) with values narrower
+    than keys, a sink a query head and heads whose lanes do not tile
+    (192: the heads of a block share ONE block-diagonal product up to 64
+    query rows), over the pool and over rings, against the float32
+    oracles and against the softmax written out; an idle slot still
+    writes zeros."""
+    from deepspeed_tpu.ops.pallas import decode_attention as da
+    D, Dv, H, KH = {"d24-v16": (24, 16, 8, 2), "d192-v128": (192, 128, 64, 4),
+                    "d128-v64": (128, 64, 8, 8)}[shape]
+    if kind == "window":
+        KH = min(2 * KH, H)
+    S, BS, NB, MB = 4, 16, 13, 3
+    q = _rand(1, (S, H, D))
+    b = 2.0 + _rand(2, (H,)) if sink else None
+    assert da._ragged(192) and not da._ragged(128) and not da._ragged(64)
+    if kind == "full":
+        k = _rand(3, (2, NB, BS, KH * D))
+        v = _rand(4, (2, NB, BS, KH * Dv))
+        tables = jnp.asarray(np.arange(1, 13).reshape(4, 3), jnp.int32)
+        lengths = jnp.asarray([1, BS + 1, 0, MB * BS], jnp.int32)
+        got = da.paged_decode_attention(q, k, v, tables, lengths, layer=1,
+                                        sink=b, interpret=True)
+        want = da.paged_decode_attention_reference(q, k[1], v[1], tables,
+                                                   lengths, sink=b)
+        rows = lambda s: (k[1][tables[s]].reshape(-1, KH, D),
+                          v[1][tables[s]].reshape(-1, KH, Dv),
+                          np.arange(MB * BS) < int(lengths[s]))
+    else:
+        window, RB = BS, 2
+        k = _rand(3, (2, S * RB, BS, KH * D))
+        v = _rand(4, (2, S * RB, BS, KH * Dv))
+        lengths = jnp.asarray([1, BS + 1, 0, 5 * BS + 3], jnp.int32)
+        got = da.paged_window_decode_attention(q, k, v, lengths, window,
+                                               layer=1, sink=b,
+                                               interpret=True)
+        want = da.paged_window_decode_attention_reference(
+            q, k[1], v[1], lengths, window, sink=b)
+
+        def rows(s):
+            from deepspeed_tpu.inference.kv_cache import ring_newest_position
+            n = int(lengths[s]) - 1
+            pos = np.asarray(ring_newest_position(jnp.int32(n), RB * BS))
+            return (k[1][s * RB:(s + 1) * RB].reshape(-1, KH, D),
+                    v[1][s * RB:(s + 1) * RB].reshape(-1, KH, Dv),
+                    (pos >= 0) & (pos > n - window))
+    assert got.shape == (S, H, Dv)
+    assert not np.asarray(got[2]).any()                 # the idle slot
+    live = np.array([0, 1, 3])
+    np.testing.assert_allclose(got[live], want[live], atol=2e-5)
+    for s in live:                  # the softmax written out, a slot
+        ks, vs, seen = rows(s)
+        sc = np.einsum("hd,shd->hs", np.asarray(q[s], np.float64),
+                       np.repeat(np.asarray(ks, np.float64), H // KH, 1)
+                       ) / np.sqrt(D)
+        e = np.where(seen[None], np.exp(sc), 0.0)
+        den = e.sum(-1) + (np.exp(np.asarray(b, np.float64)) if sink
+                           else 0.0)
+        out = np.einsum("hs,shd->hd", e / den[:, None],
+                        np.repeat(np.asarray(vs, np.float64), H // KH, 1))
+        np.testing.assert_allclose(got[s], out, atol=2e-5)
+
+
+def test_decode_kernel_refuses_what_it_cannot_carry():
+    from deepspeed_tpu.ops.pallas import decode_attention as da
+    q = _rand(1, (2, 8, 16))
+    k = _rand(2, (1, 5, 16, 2 * 16))
+    tables = jnp.ones((2, 2), jnp.int32)
+    with pytest.raises(ValueError, match="sink"):       # one a query head
+        da.paged_decode_attention(q, k, k, tables, jnp.ones(2), sink=_rand(
+            3, (4,)), interpret=True)
+    with pytest.raises(ValueError, match="sink"):       # one token a slot
+        da._paged_attention(
+            q.reshape(2, 2, 4, 16).repeat(2, 2), k, k, tables,
+            jnp.ones(2, jnp.int32), rep=4, scale=None, interpret=True,
+            name="x", sink=_rand(3, (8,)))
+    with pytest.raises(ValueError, match="multiple of 2 of V"):
+        da.paged_decode_attention(q, k, k[..., :31], tables, jnp.ones(2),
+                                  interpret=True)
+    # heads of 192 lanes and more query rows than one product carries
+    q = _rand(1, (1, 4, 8 * 16, 192)).reshape(1, 4, 128, 192)
+    wide = _rand(2, (1, 3, 16, 4 * 192))
+    with pytest.raises(ValueError, match="do not tile"):
+        da._paged_attention(q, wide, wide, jnp.ones((1, 2), jnp.int32),
+                            jnp.ones(1, jnp.int32), rep=16, scale=None,
+                            interpret=True, name="x")
+
+
 def test_block_allocator_free_list():
     alloc = BlockAllocator(8)       # 7 usable, block 0 reserved
     assert alloc.free_blocks == 7

@@ -264,6 +264,70 @@ class TestFlashAttention:
             flash_attention(q, k, v)
 
 
+    @staticmethod
+    def _masked(q, k, v, window=None, sink=None):
+        """The softmax written out in float32: causal, a window, and a
+        sink as one more logit column whose probability is dropped."""
+        B, T, H, D = q.shape
+        rep = H // k.shape[2]
+        s = jnp.einsum("bqhd,bkhd->bhqk", q, jnp.repeat(k, rep, 2)
+                       ) / np.sqrt(D)
+        i, j = jnp.arange(T)[:, None], jnp.arange(T)[None]
+        seen = j <= i
+        if window is not None:
+            seen &= i - j < window
+        s = jnp.where(seen, s, -1e30)
+        if sink is not None:
+            s = jnp.concatenate([s, jnp.broadcast_to(
+                sink[None, :, None, None], (B, H, T, 1))], -1)
+        p = jax.nn.softmax(s, -1)[..., :T]
+        return jnp.einsum("bhqk,bkhd->bqhd", p, jnp.repeat(v, rep, 2))
+
+    @pytest.mark.parametrize("window,sink", [
+        (None, False), (128, False), (128, True), (100, True), (1, True),
+        (300, True)], ids=["full", "window", "window-sink", "w100-sink",
+                           "w1-sink", "w300-sink"])
+    @pytest.mark.parametrize("D,Dv,H,HKV", [(192, 128, 8, 2), (64, 128, 4, 4),
+                                            (128, 128, 4, 2)],
+                             ids=["k192-v128", "k64-v128", "k128-v128"])
+    def test_values_of_their_own_width_and_a_sink(self, D, Dv, H, HKV,
+                                                  window, sink):
+        """``v [.., D_v]`` beside ``q, k [.., D]`` in both forwards, and
+        the sink in the windowed one (a window of ONE K block, inside a
+        block, of one position, across blocks), against the softmax
+        written out (interpret mode)."""
+        key = jax.random.PRNGKey(D + Dv)
+        q = jax.random.normal(jax.random.fold_in(key, 0), (1, 512, H, D))
+        k = jax.random.normal(jax.random.fold_in(key, 1), (1, 512, HKV, D))
+        v = jax.random.normal(jax.random.fold_in(key, 2), (1, 512, HKV, Dv))
+        b = 1.0 + jax.random.normal(jax.random.fold_in(key, 3), (H,)) \
+            if sink else None
+        got = flash_attention(q, k, v, window=window, sink=b, block_q=128,
+                              block_k=128)
+        assert got.shape == (1, 512, H, Dv)
+        np.testing.assert_allclose(got, self._masked(q, k, v, window, b),
+                                   atol=5e-6)
+
+    def test_what_has_no_backward_or_no_place_says_so_by_name(self):
+        x = jnp.ones((1, 128, 2, 64), jnp.float32)
+        v = jnp.ones((1, 128, 2, 32), jnp.float32)
+        b = jnp.zeros((2,), jnp.float32)
+        with pytest.raises(NotImplementedError, match="D_v"):
+            jax.grad(lambda q: flash_attention(q, x, v).sum())(x)
+        with pytest.raises(NotImplementedError, match="sink"):
+            jax.grad(lambda q: flash_attention(q, x, x, window=64,
+                                               sink=b).sum())(x)
+        with pytest.raises(ValueError, match="sink"):       # full forward
+            flash_attention(x, x, x, sink=b)
+        with pytest.raises(ValueError, match="sink"):       # one a head
+            flash_attention(x, x, x, window=64, sink=jnp.zeros((3,)))
+        with pytest.raises(ValueError, match="D_v"):        # k's width
+            flash_attention(x, v, v)
+        # D_v == D and no sink: the kernel and its backward as they were
+        g = jax.grad(lambda q: flash_attention(q, x, x).sum())(x)
+        assert g.shape == x.shape
+
+
 class TestDecodeAttention:
     @pytest.mark.parametrize("block_k", [128, 256])
     @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-4),

@@ -848,6 +848,119 @@ def test_window_and_full_layers_share_one_program(chips, monkeypatch, kind,
         assert compiled.memory_analysis().temp_size_in_bytes < ring_layer
 
 
+MIMO_SCOPES = {"embed", "ln", "attn_full", "attn_window", "kv_write",
+               "dense_ffn", "moe_router", "moe_dispatch", "moe_experts",
+               "moe_combine", "lm_head", "sample"}
+# serve-mimo-v2-flash-ep32-reasoning-batch's own geometry
+MIMO = dict(slots=96, blocks=1 + 5400, span=96, prompt=4096, window=128)
+
+
+@pytest.mark.parametrize("kind,kernels_want", [
+    ("decode", {"paged_decode_attention": 3,
+                "paged_window_decode_attention": 9}),
+    ("prefill", {"flash_attention_fwd": 3, "flash_attention_window_fwd": 9}),
+])
+def test_mimo_programs_keep_four_row_widths_in_one_cache(chips, monkeypatch,
+                                                         kind, kernels_want):
+    """MiMo-V2-Flash's two serving programs at the cell's OWN sizes (the
+    published widths, all 12 layers of the stage, 8 held experts, 19072
+    vocabulary rows, 96 slots, 5400 blocks, the 4096-token bucket), read
+    back from their compiled text: module, kernel and scope names, one
+    kernel call a layer by the layer's kind (the decode kernel at K rows
+    of 768 / 1536 lanes and V rows of 512 / 1024, heads of 192 sharing a
+    block-diagonal product, the sink on the window layers); no
+    ``kv_read``; apart from the kernel calls and the in-place writes
+    nothing as large as one layer of the pool or of the rings is
+    written; and the bytes the configuration file states: arguments of
+    ``serve_decode`` and both programs' temporaries."""
+    from deepspeed_tpu.inference.kv_cache import init_paged_cache
+    from deepspeed_tpu.inference.server import ContinuousBatchingServer as Srv
+    from deepspeed_tpu.model_implementations import mimo_v2 as mm
+    from deepspeed_tpu.telemetry import compile_watch
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(fa, "_should_interpret", lambda: False)
+    monkeypatch.setattr(gm, "_should_interpret", lambda: False)
+    g = MIMO
+    one = SingleDeviceSharding(chips[0])
+    pattern = (0, 1, 1, 1, 1, 0, 1, 1, 1, 1, 1, 0)
+    cfg = mm.MiMoV2Config(
+        vocab_size=19072, num_hidden_layers=12,
+        hybrid_layer_pattern=pattern, moe_layer_freq=(0,) + (1,) * 11,
+        experts_held=(0, 8))
+    assert (cfg.kv_heads, cfg.ring_kv_heads, cfg.head_dim, cfg.v_head_dim,
+            cfg.sliding_window) == (4, 8, 192, 128, g["window"])
+    abstract = functools.partial(_abstract, sharding=one)
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    params = abstract(jax.eval_shape(
+        lambda: mm.init_params(jax.random.PRNGKey(0), cfg)))
+    cache = abstract(jax.eval_shape(lambda: init_paged_cache(
+        cfg.n_layer, g["slots"], g["blocks"], BS, g["span"], cfg.kv_heads,
+        cfg.head_dim, BF16, window_layers=cfg.window_layers,
+        window=cfg.sliding_window, aux_shape=cfg.aux_shape,
+        v_head_dim=cfg.v_head_dim, ring_kv_heads=cfg.ring_kv_heads)))
+    assert cache.k.shape == (3, g["blocks"], BS, 4 * 192)
+    assert cache.v.shape == (3, g["blocks"], BS, 4 * 128)
+    assert cache.ring_k.shape == (9, g["slots"] * 2, BS, 8 * 192)
+    assert cache.ring_v.shape == (9, g["slots"] * 2, BS, 8 * 128)
+    fn, name, args = {
+        "decode": (Srv._decode_fn, "serve_decode",
+                   (params, arr((g["slots"],)), cache,
+                    arr((g["slots"],), jnp.bool_))),
+        "prefill": (Srv._prefill_fn, "serve_prefill",
+                    (params, arr((1, g["prompt"])), arr((1,)), cache,
+                     arr(()))),
+    }[kind]
+    da._paged_call.cache_clear()
+    compiled = jax.jit(compile_watch._named(
+        functools.partial(fn, cfg=cfg, mesh=None), name),
+        donate_argnames=("cache",)).lower(*args).compile()
+    text = compiled.as_text()
+    assert f"HloModule jit_{name}" in text
+    scopes, kernels = compile_watch.parse_scopes(text)
+    ours = _without_grouped_matmuls(kernels, scopes)
+    # w_in and w_out a layer, in each branch of held_experts_part's
+    # ``cond`` (the expected rows, and the exact ``T k`` fallback)
+    assert len(kernels) - len(ours) == 4 * 11
+    counts = {v: list(ours.values()).count(v) for v in set(ours.values())}
+    assert counts == kernels_want
+    for k, v in ours.items():
+        assert scopes[k].split("/")[0] == (
+            "attn_window" if "window" in v else "attn_full"), (k, scopes[k])
+    words = {w for v in scopes.values() if v for w in v.split("/")}
+    assert words >= MIMO_SCOPES, MIMO_SCOPES - words
+    assert "kv_read" not in words and "moe_shared" not in words
+    stores = (cache.k.shape, cache.v.shape, cache.ring_k.shape,
+              cache.ring_v.shape)
+    smallest = min(math.prod(s[1:]) * 2 for s in stores)    # a layer of V
+    rows = {s[-2:] for s in stores}
+    assert not _copies(
+        text, lambda dims, nbytes: dims not in stores and nbytes >= smallest
+        and dims[-2:] in rows, kernels=kernels)
+    assert not [c for c in _copies(
+        text, lambda dims, nbytes: dims in stores, kernels=kernels)
+        if not any(op in c for op in _IN_PLACE)]
+    mem = compiled.memory_analysis()
+    weights = sum(math.prod(x.shape) * x.dtype.itemsize
+                  for x in jax.tree.leaves(params))
+    pool = sum(math.prod(x.shape) * x.dtype.itemsize
+               for x in jax.tree.leaves(cache))
+    print(f"mimo {kind}: weights {weights / 1e9:.3f} GB, cache "
+          f"{pool / 1e9:.3f} GB, arguments "
+          f"{mem.argument_size_in_bytes / 1e9:.3f} GB, temporaries "
+          f"{mem.temp_size_in_bytes / 1e9:.3f} GB")
+    # benchmark/configs/mimo-v2-flash-ep32-serve.json "why": 7.40 GB of
+    # weights, 6.46 GB of cache
+    assert 7.35e9 < weights < 7.45e9 and 6.4e9 < pool < 6.5e9
+    if kind == "decode":
+        assert mem.temp_size_in_bytes < smallest
+    else:
+        assert mem.temp_size_in_bytes < 2.5e9
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            < 16.9e9)
+
+
 GRANITE_SCOPES = {"embed", "ln", "mamba_in", "mamba_conv", "mamba_out",
                   "attn_full", "kv_write", "moe_router", "moe_dispatch",
                   "moe_experts", "moe_combine", "moe_shared", "lm_head",
@@ -1215,6 +1328,85 @@ def test_this_libtpu_knows_the_remat_limit_option(chips):
     assert compile_with(REMAT_LIMIT_OPTION) is not None
     with pytest.raises(Exception, match="xla_jf_no_such_option"):
         compile_with("xla_jf_no_such_option")
+
+
+# sha256[:16] and line count of ``jax.make_jaxpr`` text (the kernel
+# bodies are in it), read off the tree at 1f90ef5 (PR 52) by the same
+# function: PR 53 gave the decode kernel a value width, a sink and a
+# rule for heads of 192 lanes, and the flash forward a value width and a
+# sink; with ``D_v == D`` and no sink every accepted program traces to
+# the text it did. A PR that MEANS to change one of these programs
+# replaces its line here and says so in CHANGES.md.
+ACCEPTED_PROGRAMS = {
+    "laguna-decode": ("c2da6142f4089a74", 9654, 9),
+    "laguna-prefill": ("27010f6634f018c7", 7922, 8),
+    "gpt2-decode": ("3141ca124377a618", 8987, 24),
+    "gpt2-decode-int8": ("3bf89725c010ea29", 12107, 24),
+    "gpt2-verify": ("c4007aa33affbfb2", 11630, 24),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ACCEPTED_PROGRAMS))
+def test_accepted_decode_programs_trace_to_the_text_they_did(monkeypatch,
+                                                             case):
+    """Laguna's two programs at its AOT test's geometry and GPT-2 1.3B's
+    decode / verify programs at the batch cell's (24 layers, 32 slots,
+    257 blocks, 16 heads x 128; fp and int8 pools), traced for the TPU
+    path: the text's hash, its lines and its kernel calls."""
+    import hashlib
+
+    from deepspeed_tpu.inference.kv_cache import init_paged_cache
+    from deepspeed_tpu.inference.server import ContinuousBatchingServer as Srv
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(fa, "_should_interpret", lambda: False)
+    monkeypatch.setattr(gm, "_should_interpret", lambda: False)
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+    if case.startswith("laguna"):
+        from deepspeed_tpu.model_implementations import laguna as lg
+        cfg = lg.LagunaConfig(
+            vocab_size=2048, num_hidden_layers=5,
+            layer_types=(lg.FULL,) + (lg.WINDOW,) * 3 + (lg.FULL,),
+            mlp_layer_types=("dense",) + ("sparse",) * 4,
+            num_attention_heads_per_layer=(48, 64, 64, 64, 48),
+            rope_full=lg.RopeSpec(
+                rope_theta=500000, rope_type="yarn", factor=64,
+                original_max_position_embeddings=4096, beta_fast=64,
+                partial_rotary_factor=0.5),
+            rope_sliding=lg.RopeSpec(rope_theta=10000), experts_held=(0, 2))
+        params = jax.eval_shape(
+            lambda: lg.init_params(jax.random.PRNGKey(0), cfg))
+        cache = jax.eval_shape(lambda: init_paged_cache(
+            cfg.n_layer, 96, 3201, BS, 80, cfg.kv_heads, cfg.head_dim, BF16,
+            window_layers=cfg.window_layers, window=cfg.sliding_window,
+            aux_shape=cfg.aux_shape))
+        slots = 96
+    else:
+        from deepspeed_tpu.model_implementations.transformer import (
+            InferenceTransformerConfig, init_params)
+        cfg = InferenceTransformerConfig(
+            vocab_size=50304, n_positions=1024, n_embd=2048, n_layer=24,
+            n_head=16, dtype=BF16)
+        params = jax.eval_shape(
+            lambda: init_params(jax.random.PRNGKey(0), cfg))
+        cache = jax.eval_shape(lambda: init_paged_cache(
+            24, 32, 257, BS, 8, 16, 128, BF16,
+            quantized=case.endswith("int8")))
+        slots = 32
+    fn, args = {
+        "decode": (Srv._decode_fn, (params, arr((slots,)), cache,
+                                    arr((slots,), jnp.bool_))),
+        "prefill": (Srv._prefill_fn, (params, arr((1, 8192)), arr((1,)),
+                                      cache, arr(()))),
+        "verify": (Srv._verify_fn, (params, arr((slots, 4)), cache)),
+    }[case.split("-")[1]]
+    da._paged_call.cache_clear()
+    text = str(jax.make_jaxpr(functools.partial(fn, cfg=cfg, mesh=None))(
+        *args))
+    assert (hashlib.sha256(text.encode()).hexdigest()[:16],
+            text.count("\n"), text.count("pallas_call[")
+            ) == ACCEPTED_PROGRAMS[case]
 
 
 def test_kernel_names_are_the_same_under_a_mesh(chips):
