@@ -727,8 +727,10 @@ def paged_gather_kv(cache: PagedKVCache, layer: int):
 
 
 def paged_advance(cache, active: jnp.ndarray):
-    """Advance live slots' lengths by one; idle slots stay pinned at 0 so
-    their appends keep landing in the null block. Either kind of pool."""
+    """Advance live slots' lengths by one; idle slots stay where they
+    are (a K/V pool's idle slots are pinned at 0 over an all-null table,
+    so their appends keep landing in the null block; a latent pool's
+    write nothing). Either kind of pool."""
     return cache.replace(
         lengths=cache.lengths + active.astype(jnp.int32))
 
@@ -791,7 +793,9 @@ def init_latent_paged_cache(num_attentions: int, num_slots: int,
         aux=jnp.zeros(aux_shape, jnp.int32))
 
 
-def _with_rows(cache: LatentPagedCache, idx: int, new) -> LatentPagedCache:
+def with_latent_rows(cache: LatentPagedCache, idx: int,
+                     new) -> LatentPagedCache:
+    """``cache`` with attention ``idx``'s pool replaced."""
     return cache.replace(
         rows=cache.rows[:idx] + (new,) + cache.rows[idx + 1:])
 
@@ -808,7 +812,7 @@ def latent_write_prompt(cache: LatentPagedCache, idx: int,
     blocks = jax.lax.dynamic_slice_in_dim(cache.block_tables, slot, 1,
                                           0)[0, :nb]
     pool = cache.rows[idx]
-    return _with_rows(cache, idx, pool.at[blocks].set(
+    return with_latent_rows(cache, idx, pool.at[blocks].set(
         jnp.swapaxes(rows.reshape(nb, BS, -1), 1, 2).astype(pool.dtype)))
 
 
@@ -827,30 +831,28 @@ def latent_write_chunk(cache: LatentPagedCache, idx: int, rows: jnp.ndarray,
     row = jnp.concatenate([row, jnp.zeros((nb,), jnp.int32)])
     blocks = jax.lax.dynamic_slice_in_dim(row, start // BS, nb, 0)
     pool = cache.rows[idx]
-    return _with_rows(cache, idx, pool.at[blocks].set(
+    return with_latent_rows(cache, idx, pool.at[blocks].set(
         jnp.swapaxes(rows.reshape(nb, BS, -1), 1, 2).astype(pool.dtype)))
 
 
 @scoped("latent_write")
 def latent_append_token(cache: LatentPagedCache, idx: int,
-                        rows: jnp.ndarray) -> LatentPagedCache:
+                        rows: jnp.ndarray, active) -> LatentPagedCache:
     """Decode: append one token's ``[S, W]`` row of attention ``idx`` at
-    ``lengths[s]`` for every slot; idle slots write into the null block.
-    Lengths advance once a step (:func:`paged_advance`). On a TPU the
-    Pallas writer (it rewrites the one block a slot appends to); the
-    scatter elsewhere."""
+    ``lengths[s]`` for every active slot; any other slot writes nothing.
+    Lengths advance once a step (:func:`paged_advance`). The XLA
+    scatter: the decode path off the TPU, and the oracle of the latent
+    decode kernel, which appends on one
+    (``ops/pallas/latent_decode_attention.py``)."""
     pool = cache.rows[idx]
-    if jax.default_backend() == "tpu":
-        from deepspeed_tpu.ops.pallas.latent_decode_attention import (
-            paged_latent_append)
-        return _with_rows(cache, idx, paged_latent_append(
-            pool, rows, cache.block_tables, cache.lengths))
     BS = cache.block_size
     pos = cache.lengths
     blk = jnp.take_along_axis(cache.block_tables, (pos // BS)[:, None],
                               axis=1)[:, 0]
-    return _with_rows(cache, idx, pool.at[blk, :, pos % BS].set(
-        rows.astype(pool.dtype)))
+    # past the pool: dropped
+    blk = jnp.where(active, blk, cache.num_blocks)
+    return with_latent_rows(cache, idx, pool.at[blk, :, pos % BS].set(
+        rows.astype(pool.dtype), mode="drop"))
 
 
 # ------------------------------------------------------------- state pool

@@ -72,7 +72,6 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.inference.kv_cache import (LatentPagedCache,
-                                              latent_append_token,
                                               latent_write_chunk,
                                               latent_write_prompt,
                                               paged_advance)
@@ -581,8 +580,8 @@ def paged_decode_step(params, cfg: DeepseekV3Config, tokens,
     """One generation step for all resident slots (the contract of
     ``transformer.paged_decode_step``): ``tokens [S]`` -> (logits ``[S,
     V]``, cache). Each attention appends its row at ``lengths[s]`` and
-    attends the pool in the absorbed form; idle slots write into the null
-    block, route nowhere and are not advanced."""
+    attends the pool in the absorbed form; idle slots write nothing,
+    attend nothing, route nowhere and are not advanced."""
     positions = cache.lengths
     live = cache.lengths + 1
     x = _embed(params, cfg, tokens)
@@ -591,10 +590,9 @@ def paged_decode_step(params, cfg: DeepseekV3Config, tokens,
         a = layer["attn"]
         q_nope, q_rope, rows = _project(
             _rms(x, layer["norm_in"], cfg.rms_norm_eps), a, cfg, positions)
-        cache = latent_append_token(cache, li, rows)
-        x = x + _mla.attn_out(_mla.absorbed_attention(
-            q_nope, q_rope, cache.rows[li], cache.block_tables, live, a,
-            cfg), a)
+        cache, o = _mla.absorbed_attention(q_nope, q_rope, rows, cache, li,
+                                           active, a, cfg)
+        x = x + _mla.attn_out(o, a)
         x, counts = _ffn(x, layer, cfg, active, counts)
     cache = _count(cache, "decode", counts,
                    jnp.sum(jnp.where(active, live, 0)) * cfg.attentions)

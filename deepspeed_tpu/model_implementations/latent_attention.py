@@ -7,8 +7,8 @@ The cache holds ``[c_kv ; k_rope]`` a token an attention
 * :func:`materialised_attention`: a whole sequence against its own rows,
   K and V built per head from the latent (the flash kernel on a TPU):
   monolithic prefill and the uncached forward;
-* :func:`absorbed_attention`: one token a slot against the latent pool,
-  the query carried into the latent space
+* :func:`absorbed_attention`: one token a slot appended to the latent
+  pool and attended there, the query carried into the latent space
   (``ops/pallas/latent_decode_attention.py``): decode;
 * :func:`chunk_attention`: a prompt chunk of one slot against the slot's
   rows in the pool, its own included, K and V rebuilt block by block
@@ -33,6 +33,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.inference.kv_cache import (latent_append_token,
+                                              with_latent_rows)
 from deepspeed_tpu.ops.pallas import latent_chunk_attention as _chunk
 from deepspeed_tpu.ops.pallas import latent_decode_attention as _latent
 from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
@@ -100,21 +102,33 @@ def materialised_attention(q_nope, q_rope, rows, a, cfg):
 
 
 @scoped("mla_attn")
-def absorbed_attention(q_nope, q_rope, pool, block_tables, live, a, cfg):
-    """One token a slot against the latent pool, absorbed form: ``q_*
-    [S, H, .]`` -> ``[S, H, Dv]``. The query goes into the latent space
-    through ``wk_b``, attends whole rows, and the latent output comes
-    back through ``wv_b``."""
+def absorbed_attention(q_nope, q_rope, rows, cache, idx, active, a, cfg):
+    """One token a slot through the latent pool, absorbed form: ``q_* [S,
+    H, .]``, the step's new ``rows [S, W]`` -> (cache, ``[S, H, Dv]``).
+    Every active slot's row goes to position ``lengths[s]`` of attention
+    ``idx``'s pool (lengths advance once a step,
+    :func:`~deepspeed_tpu.inference.kv_cache.paged_advance`) and its
+    query, carried into the latent space through ``wk_b``, attends whole
+    rows up to and with that one; the latent output comes back through
+    ``wv_b``. A slot that is not active writes nothing and gets zeros.
+    On a TPU one kernel does both (the row's block is in VMEM for the
+    attention anyway); the scatter and the ``jax.numpy`` attention
+    elsewhere."""
     Rkv = cfg.kv_lora_rank
     dt = q_nope.dtype
     q_lat = jnp.einsum("shd,rhd->shr", q_nope, a["wk_b"].astype(dt))
-    q = jnp.concatenate([q_lat, q_rope], -1)              # [S, H, W]
-    attend = (_latent.paged_latent_decode_attention
-              if jax.default_backend() == "tpu" else
-              _latent.paged_latent_decode_attention_reference)
-    o_lat = attend(q, pool, block_tables, live, value_dim=Rkv,
-                   scale=cfg.attn_scale)
-    return jnp.einsum("shr,rhd->shd", o_lat, a["wv_b"].astype(dt))
+    if jax.default_backend() == "tpu":
+        o_lat, pool = _latent.paged_latent_decode_attention(
+            q_lat, q_rope, rows, cache.rows[idx], cache.block_tables,
+            jnp.where(active, cache.lengths, -1), scale=cfg.attn_scale)
+        cache = with_latent_rows(cache, idx, pool)
+    else:
+        cache = latent_append_token(cache, idx, rows, active)
+        o_lat = _latent.paged_latent_decode_attention_reference(
+            jnp.concatenate([q_lat, q_rope], -1), cache.rows[idx],
+            cache.block_tables, jnp.where(active, cache.lengths + 1, 0),
+            value_dim=Rkv, scale=cfg.attn_scale)
+    return cache, jnp.einsum("shr,rhd->shd", o_lat, a["wv_b"].astype(dt))
 
 
 @scoped("mla_attn")

@@ -67,9 +67,9 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.inference.kv_cache import (LatentPagedCache,
-                                              latent_append_token,
                                               latent_write_prompt,
-                                              paged_advance)
+                                              paged_advance,
+                                              with_latent_rows)
 from deepspeed_tpu.model_implementations import held_experts as _held
 from deepspeed_tpu.model_implementations import latent_attention as _mla
 from deepspeed_tpu.profiling.trace import scoped
@@ -516,8 +516,8 @@ def paged_decode_step(params, cfg: LongcatFlashConfig, tokens,
     """One generation step for all resident slots (the contract of
     ``transformer.paged_decode_step``): ``tokens [S]`` -> (logits ``[S,
     V]``, cache). Each attention appends its row at ``lengths[s]`` and
-    attends the pool in the absorbed form; idle slots write into the null
-    block, route nowhere and are not advanced."""
+    attends the pool in the absorbed form; idle slots write nothing,
+    attend nothing, route nowhere and are not advanced."""
     positions = cache.lengths
     x = _embed(params, cfg, tokens)
     total = jnp.zeros((cfg.aux_shape[1],), jnp.int32)
@@ -525,10 +525,9 @@ def paged_decode_step(params, cfg: LongcatFlashConfig, tokens,
         def attend(h, a, i, li=li):
             nonlocal cache
             q_nope, q_rope, rows = _mla_project(h, a, cfg, positions)
-            cache = latent_append_token(cache, 2 * li + i, rows)
-            return _attn_out(_absorbed_attention(
-                q_nope, q_rope, cache.rows[2 * li + i], cache.block_tables,
-                cache.lengths + 1, a, cfg), a)
+            cache, o = _absorbed_attention(q_nope, q_rope, rows, cache,
+                                           2 * li + i, active, a, cfg)
+            return _attn_out(o, a)
         x, counts = _double_block(x, layer, cfg, attend, active)
         total = total + counts
     return (_logits(params, cfg, x),
@@ -545,7 +544,7 @@ def paged_decode_admit(params, cfg: LongcatFlashConfig, tokens,
     of its own where not). ``tokens [S]``, ``active [S]`` as
     :func:`paged_decode_step`; ``input_ids [1, T]``, ``length [1]``,
     ``slot`` as :func:`paged_prefill`. ``slot`` is NOT active in this
-    step: its decode row is idle and appends into the null block.
+    step: its decode row is idle and appends nothing.
 
     The row-wise layers (norms, projections, dense FFNs, router, held
     experts, head) run once over all rows; each attention scatters the
@@ -569,11 +568,6 @@ def paged_decode_admit(params, cfg: LongcatFlashConfig, tokens,
     positions = jnp.concatenate([cache.lengths, jnp.arange(T)])
     valid = jnp.concatenate([active, jnp.arange(T) < length[0]])
     decoding = jnp.any(active)
-    # the admitted slot's table row is the prompt's by now: its idle
-    # decode row appends where every idle row does
-    tables = jax.lax.dynamic_update_slice_in_dim(
-        cache.block_tables,
-        jnp.zeros((1, cache.block_tables.shape[1]), jnp.int32), slot, 0)
 
     def ffn(u, f):
         """The dense FFN over every row, or over the prompt's alone with
@@ -592,20 +586,17 @@ def paged_decode_admit(params, cfg: LongcatFlashConfig, tokens,
             cache = latent_write_prompt(cache, idx, rows[S:], slot)
 
             def decode_rows(pool):
-                pool = latent_append_token(
-                    cache.replace(rows=(pool,), block_tables=tables), 0,
-                    rows[:S]).rows[0]
-                return pool, _absorbed_attention(
-                    q_nope[:S], q_rope[:S], pool, tables,
-                    cache.lengths + 1, a, cfg)
+                one, o = _absorbed_attention(
+                    q_nope[:S], q_rope[:S], rows[:S],
+                    cache.replace(rows=(pool,)), 0, active, a, cfg)
+                return one.rows[0], o
 
             def no_rows(pool):
                 return pool, jnp.zeros(
                     (S, cfg.num_attention_heads, cfg.v_head_dim), h.dtype)
             pool, decoded = jax.lax.cond(decoding, decode_rows, no_rows,
                                          cache.rows[idx])
-            cache = cache.replace(
-                rows=cache.rows[:idx] + (pool,) + cache.rows[idx + 1:])
+            cache = with_latent_rows(cache, idx, pool)
             return _attn_out(jnp.concatenate([
                 decoded, _materialised_attention(
                     q_nope[None, S:], q_rope[None, S:], rows[None, S:], a,

@@ -155,6 +155,50 @@ def test_monolithic_prefill_then_decode_match_the_reference():
     assert [int(n) for n in cache.lengths] == [48, 0, 29]
 
 
+def test_the_tpu_decode_path_leaves_what_the_cpu_path_leaves(monkeypatch):
+    """Three decode steps over two live slots and an idle one between
+    them (one slot's rows cross into a fresh block), through the TPU path
+    (the latent kernel appends and attends; it and the grouped matmul in
+    interpret mode) against the CPU path (scatter, then the
+    ``jax.numpy`` attention): logits, lengths and every pool, the null
+    block included."""
+    from deepspeed_tpu.ops.pallas import grouped_matmul
+    from deepspeed_tpu.ops.pallas import latent_decode_attention as lda
+    cfg, params = _model()
+    cache = _table(_table(_pool(cfg), 0, [3, 9, 4, 11]), 2, [7, 8, 2])
+    for slot, p in {0: _ids(37, 3), 2: _ids(15, 4)}.items():
+        ids = np.zeros((1, 48), np.int32)
+        ids[0, :len(p)] = p
+        _, cache = dv.paged_prefill(
+            params, cfg, jnp.asarray(ids), jnp.asarray([len(p)], jnp.int32),
+            cache, jnp.int32(slot))
+    active = jnp.asarray([True, False, True])
+
+    def run():
+        step = jax.jit(lambda *a: dv.paged_decode_step(a[0], cfg, *a[1:]))
+        after, logits = cache, []
+        for tokens in ([5, 0, 9], [11, 0, 2], [4, 0, 8]):
+            lg, after = step(params, jnp.asarray(tokens), after, active)
+            logits.append(lg)
+        return jnp.stack(logits), after
+    want, pools = run()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(grouped_matmul, "_should_interpret", lambda: True)
+    monkeypatch.setattr(lda, "paged_latent_decode_attention",
+                        functools.partial(lda.paged_latent_decode_attention,
+                                          interpret=True))
+    got, after = run()
+    assert _rel(got, want) < 1e-5
+    assert [int(n) for n in after.lengths] == [40, 0, 18]
+    assert (np.asarray(after.aux) == np.asarray(pools.aux)).all()
+    for a, b, c in zip(after.rows, pools.rows, cache.rows):
+        assert (np.asarray(a[0]) == np.asarray(c[0])).all()
+        assert float(jnp.abs(a - b).max()) < 1e-5 * float(jnp.abs(b).max())
+    # the first attention's first row a slot is the scatter's to the bit
+    differ = np.asarray(after.rows[0]) != np.asarray(pools.rows[0])
+    assert differ.sum() <= 2 * 2 * cfg.latent_width
+
+
 @pytest.mark.parametrize("chunk,length", [
     (16, 16), (16, 41), (32, 64), (32, 33), (48, 100), (64, 9)])
 def test_chunked_prefill_matches_the_reference(chunk, length):

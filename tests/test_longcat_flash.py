@@ -180,12 +180,12 @@ def test_decode_admit_is_the_decode_step_and_the_prefill_in_one_forward(
     assert (np.asarray(both.lengths) == np.asarray(two.lengths)).all()
     assert (np.asarray(both.block_tables)
             == np.asarray(two.block_tables)).all()
-    # (block 0 is the null block: what idle rows leave there is read by
-    # nobody; with nothing decoding no row is appended anywhere, and the
-    # two idle appends of the step run apart land beyond the live rows)
+    # (an idle row appends nothing: the null block, block 0, stays as it
+    # was; with nothing decoding no row is appended anywhere)
     for a, b, c in zip(both.rows, two.rows, cache.rows):
+        assert (np.asarray(a[0]) == np.asarray(c[0])).all()
         if decoding:
-            assert float(jnp.abs(a[1:] - b[1:]).max()) < 1e-6
+            assert float(jnp.abs(a - b).max()) < 1e-6
         else:
             blocks = np.asarray(cache.block_tables)[2, :bucket // BS]
             assert float(jnp.abs(a[blocks] - b[blocks]).max()) < 1e-6
@@ -229,11 +229,34 @@ def test_absorbed_decode_equals_materialised_attention_on_one_cache(f32):
     cache = _pool(cfg, slots=1)
     padded = jnp.zeros((2 * BS, cfg.latent_width)).at[:T].set(rows[0])
     from deepspeed_tpu.inference.kv_cache import latent_write_prompt
-    cache = latent_write_prompt(cache, 1, padded, jnp.int32(0))
-    got = lf._absorbed_attention(
-        q_nope[0, -1:], q_rope[0, -1:], cache.rows[1], cache.block_tables,
-        jnp.asarray([T], jnp.int32), a, cfg)[0]
-    assert _rel(got, want) < 1e-5
+    cache = latent_write_prompt(cache, 1, padded, jnp.int32(0)).replace(
+        lengths=jnp.asarray([T - 1], jnp.int32))
+    # (the decode step appends the last row itself, over its own copy)
+    after, got = lf._absorbed_attention(
+        q_nope[0, -1:], q_rope[0, -1:], rows[0, -1:], cache, 1,
+        jnp.asarray([True]), a, cfg)
+    assert _rel(got[0], want) < 1e-5
+    assert (np.asarray(after.rows[1]) == np.asarray(cache.rows[1])).all()
+
+
+def _reread(q, pool, tables, live, *, value_dim, scale):
+    """The kernel over a pool that holds every live row already: each
+    live slot appends the row its last position holds. -> (out, pool)"""
+    bs = pool.shape[2]
+    pos = jnp.maximum(live - 1, 0)
+    blk = jnp.take_along_axis(tables, (pos // bs)[:, None], axis=1)[:, 0]
+    return lda.paged_latent_decode_attention(
+        q[..., :value_dim], q[..., value_dim:], pool[blk, :, pos % bs],
+        pool, tables, live - 1, scale=scale, interpret=True)
+
+
+def _attend(q, pool, tables, live, **static):
+    """The kernel as a reader: the pool must come back as it went in, to
+    the bit (NaN for NaN)."""
+    out, after = _reread(q, pool, tables, live, **static)
+    np.testing.assert_array_equal(np.asarray(after, np.float32),
+                                  np.asarray(pool, np.float32))
+    return out
 
 
 @pytest.mark.parametrize("lengths", [(0, 5, 16, 41), (48, 1, 17, 32)])
@@ -247,8 +270,7 @@ def test_latent_decode_kernel_matches_its_oracle(lengths):
     tables = jnp.asarray(1 + rng.permutation(S * mb).reshape(S, mb),
                          jnp.int32)
     live = jnp.asarray(lengths, jnp.int32)
-    got = lda.paged_latent_decode_attention(
-        q, pool, tables, live, value_dim=V, scale=0.2, interpret=True)
+    got = _attend(q, pool, tables, live, value_dim=V, scale=0.2)
     want = lda.paged_latent_decode_attention_reference(
         q, pool, tables, live, value_dim=V, scale=0.2)
     assert float(jnp.abs(got - want).max()) < 1e-5
@@ -310,8 +332,8 @@ def test_latent_decode_kernel_walks_live_blocks_only(walk, pool):
     zeros."""
     lengths = _WALKS[walk]
     q, clean, bad, tables, live = _walk_case(lengths)
-    kernel = lambda rows: lda.paged_latent_decode_attention(
-        q, rows, tables, live, value_dim=32, scale=0.2, interpret=True)
+    kernel = lambda rows: _attend(q, rows, tables, live, value_dim=32,
+                                  scale=0.2)
     want = lda.paged_latent_decode_attention_reference(
         q, clean, tables, live, value_dim=32, scale=0.2)
     got = np.asarray(kernel(clean if pool == "clean" else bad))
@@ -330,9 +352,7 @@ def test_latent_decode_kernel_at_the_cell_widths_in_bfloat16():
     lengths = (0, 77, 129, 1024)
     q, clean, bad, tables, live = _walk_case(
         lengths, H=64, W=576, bs=128, mb=8, dtype=jnp.bfloat16, seed=1)
-    got = lda.paged_latent_decode_attention(
-        q, bad, tables, live, value_dim=512, scale=192 ** -0.5,
-        interpret=True)
+    got = _attend(q, bad, tables, live, value_dim=512, scale=192 ** -0.5)
     want = lda.paged_latent_decode_attention_reference(
         q.astype(jnp.float32), clean.astype(jnp.float32), tables, live,
         value_dim=512, scale=192 ** -0.5)
@@ -368,9 +388,9 @@ def test_eight_calls_of_one_signature_trace_the_kernel_once(family,
             q = jnp.asarray(rng.normal(size=(2, 8, 40)), jnp.float32)
             pools = [jnp.asarray(rng.normal(size=(9, 40, 16)), jnp.float32)
                      for _ in range(8)]
-            calls = [lambda q, pool=pool: lda.paged_latent_decode_attention(
-                q, pool, tables, live, value_dim=32, scale=0.2,
-                interpret=True) for pool in pools]
+            calls = [lambda q, pool=pool: _reread(
+                q, pool, tables, live, value_dim=32, scale=0.2)[0]
+                for pool in pools]
             oracles = [lda.paged_latent_decode_attention_reference(
                 q, pool, tables, live, value_dim=32, scale=0.2)
                 for pool in pools]
@@ -393,28 +413,169 @@ def test_eight_calls_of_one_signature_trace_the_kernel_once(family,
         assert float(jnp.abs(got - want).max()) < 1e-5
 
 
-def test_latent_append_kernel_writes_what_the_scatter_writes():
-    """The Pallas pool writer in interpret mode against the XLA scatter:
-    every slot's row lands in its block's column, idle slots in the null
-    block, nothing else moves."""
-    from deepspeed_tpu.inference.kv_cache import latent_append_token
-    cfg, _ = _model()
-    rng = np.random.default_rng(4)
+# where the step's new rows go (a slot's length before the step; -1 an
+# idle slot), over an eight-entry table of 16-position blocks: the tail
+# block as the only, second and third entry of a first group and of later
+# ones, new rows at a fresh block's first column, its second and a
+# block's last, idle slots beside live ones and alone
+_APPENDS = {
+    "every-count": tuple(n * _WALK_BS - 3 for n in range(1, 8)),
+    "block-edges": (0, 1, _WALK_BS - 1, _WALK_BS, _WALK_BS + 1,
+                    3 * _WALK_BS, 4 * _WALK_BS - 1, _WALK_MB * _WALK_BS - 1),
+    "idle-between": (-1, 5, -1, -1, 70, -1, 16, -1),
+    "first-and-last-idle": (-1, 33, 50, -1),
+    "all-idle": (-1,) * 5,
+    # a second resident slab of new rows (``lda.LANES`` slots a slab):
+    # the first slab's last slots and the second's first
+    "two-slabs": (-1,) * (lda.LANES - 3) + tuple(
+        -1 if i % 4 == 1 else (i * 29) % 127 for i in range(9)),
+}
+
+
+def _append_case(positions, neighbours=False, dtype=jnp.float32, **sizes):
+    """``_walk_case`` for a step that appends: the pool holds each live
+    slot's rows BEFORE the step (the new row's column holds large
+    garbage, every block no live slot owns holds NaN, the null block
+    among them). With ``neighbours`` the tail blocks of slots 0 and 1 lie
+    side by side in the pool. -> (q, rows, pool, tables, positions)"""
+    lengths = tuple(max(p, 0) for p in positions)
+    q, _, bad, tables, _ = _walk_case(
+        tuple(n + 1 if p >= 0 else 0 for n, p in zip(lengths, positions)),
+        dtype=dtype, **sizes)
+    bs = bad.shape[2]
+    if neighbours:
+        tables = np.array(tables)
+        (i, j) = (positions[0] // bs, positions[1] // bs)
+        a, b = int(tables[0, i]), int(tables[1, j])
+        other = np.argwhere(tables == a + 1)
+        if len(other):          # whoever held the block after a takes b
+            tables[tuple(other[0])] = b
+        tables[1, j] = a + 1
+        bad = bad.at[a + 1].set(bad[b]).at[b].set(bad[a + 1])
+        tables = jnp.asarray(tables)
+    rng = np.random.default_rng(11)
+    rows = jnp.asarray(rng.normal(size=(len(positions), q.shape[-1])), dtype)
+    for s, p in enumerate(positions):   # the new row's place: garbage yet
+        if p >= 0:
+            bad = bad.at[tables[s, p // bs], :, p % bs].set(3e4)
+    return q, rows, bad, tables, jnp.asarray(positions, jnp.int32)
+
+
+def _appended(pool, rows, tables, positions):
+    """What the XLA scatter leaves (``kv_cache.latent_append_token``)."""
+    from deepspeed_tpu.inference.kv_cache import (LatentPagedCache,
+                                                  latent_append_token)
+    cache = LatentPagedCache(rows=(pool,), block_tables=tables,
+                             lengths=jnp.maximum(positions, 0),
+                             aux=jnp.zeros((1, 1), jnp.int32))
+    return latent_append_token(cache, 0, rows, positions >= 0).rows[0]
+
+
+@pytest.mark.parametrize("case", sorted(_APPENDS) + ["neighbours"])
+def test_latent_decode_kernel_appends_what_the_scatter_writes(case):
+    """The kernel in interpret mode over a poisoned pool: the pool it
+    returns is the scatter's to the bit (every block no live slot owns,
+    block 0 among them, as it was), and its output is, to the bit, what
+    the kernel reads from that pool (the row attended in VMEM is the row
+    the pool ends up holding, where it holds it) and the oracle's over
+    it to 1e-5; an idle slot's is zeros."""
+    positions = _APPENDS.get(case, (2 * _WALK_BS - 1, 40, -1, 7))
+    q, rows, pool, tables, pos = _append_case(
+        positions, neighbours=case == "neighbours")
+    if case == "neighbours":
+        tails = [int(tables[s, positions[s] // _WALK_BS]) for s in (0, 1)]
+        assert tails[1] == tails[0] + 1
+    out, after = lda.paged_latent_decode_attention(
+        q[..., :32], q[..., 32:], rows, pool, tables, pos, scale=0.2,
+        interpret=True)
+    want = _appended(pool, rows, tables, pos)
+    np.testing.assert_array_equal(np.asarray(after), np.asarray(want))
+    changed = np.asarray(after != pool) & ~np.isnan(np.asarray(pool))
+    assert changed.sum() == sum(p >= 0 for p in positions) * q.shape[-1]
+    out = np.asarray(out)
+    assert np.isfinite(out).all()
+    np.testing.assert_array_equal(out, np.asarray(_attend(
+        q, want, tables, pos + 1, value_dim=32, scale=0.2)))
+    clean = jnp.nan_to_num(want)
+    oracle = lda.paged_latent_decode_attention_reference(
+        q, clean, tables, pos + 1, value_dim=32, scale=0.2)
+    assert float(np.abs(out - np.asarray(oracle)).max()) < 1e-5
+    assert not out[np.asarray(positions) < 0].any()
+
+
+def test_latent_decode_kernel_appends_at_the_cell_widths_in_bfloat16():
+    """Rows of 512 + 64 values in blocks of 128, bfloat16 (a packed
+    dtype: the slab of new rows is turned as 32-bit words), over sixteen
+    slots and over three: pool and output to the bit."""
+    for positions in ((-1, 76, 128, 1023) * 4, (300, -1, 127)):
+        q, rows, pool, tables, pos = _append_case(
+            positions, dtype=jnp.bfloat16, H=64, W=576, bs=128, mb=8)
+        out, after = lda.paged_latent_decode_attention(
+            q[..., :512], q[..., 512:], rows, pool, tables, pos,
+            scale=192 ** -0.5, interpret=True)
+        want = _appended(pool, rows, tables, pos)
+        np.testing.assert_array_equal(np.asarray(after, np.float32),
+                                      np.asarray(want, np.float32))
+        np.testing.assert_array_equal(
+            np.asarray(out, np.float32), np.asarray(_attend(
+                q, want, tables, pos + 1, value_dim=512,
+                scale=192 ** -0.5), np.float32))
+
+
+@pytest.mark.parametrize("program", ["step", "admit"])
+def test_the_tpu_decode_path_leaves_what_the_cpu_path_leaves(
+        f32, monkeypatch, program):
+    """``paged_decode_step`` and ``paged_decode_admit`` (one bucket) over
+    two decoding slots and an idle one, through the TPU path (the latent
+    kernel appends and attends; it and the grouped matmul in interpret
+    mode) against the CPU path (scatter, then the ``jax.numpy``
+    attention): logits, lengths and every pool, the null block
+    included. The first attention's pool is the scatter's to the bit;
+    the later ones follow attentions that differ in their last bits."""
+    import functools
+    cfg, params, _ = f32
+    seqs = _ids(3, 40, seed=1)
     cache = _pool(cfg)
-    cache = cache.replace(
-        rows=tuple(jnp.asarray(rng.normal(size=r.shape), r.dtype)
-                   for r in cache.rows),
-        lengths=jnp.asarray([0, 17, 2 * BS - 1], jnp.int32),
-        block_tables=cache.block_tables.at[0].set(0))      # slot 0 idle
-    rows = jnp.asarray(rng.normal(size=(SLOTS, cfg.latent_width)),
-                       jnp.float32)
-    want = latent_append_token(cache, 1, rows).rows[1]
-    got = lda.paged_latent_append(cache.rows[1], rows, cache.block_tables,
-                                  cache.lengths, interpret=True)
-    np.testing.assert_array_equal(got, want)
-    assert float(jnp.abs(got[1 + MB + 1, :, 1] - rows[1]).max()) == 0
-    assert int((np.asarray(got) != np.asarray(cache.rows[1])).sum()) \
-        <= SLOTS * cfg.latent_width
+    prefill = jax.jit(lambda *a: lf.paged_prefill(a[0], cfg, *a[1:]))
+    for s, m in enumerate((11, 2 * BS - 1)):
+        ids = np.zeros((1, 2 * BS), np.int32)
+        ids[0, :m] = seqs[s, :m]
+        _, cache = prefill(params, jnp.asarray(ids),
+                           jnp.asarray([m], jnp.int32), cache, jnp.int32(s))
+    args = [jnp.asarray([5, 7, 0], jnp.int32), cache,
+            jnp.asarray([True, True, False])]
+    if program == "admit":
+        ids = np.zeros((1, BS), np.int32)
+        ids[0, :9] = seqs[2, :9]
+        args += [jnp.asarray(ids), jnp.asarray([9], jnp.int32), jnp.int32(2)]
+    fn = {"step": lf.paged_decode_step, "admit": lf.paged_decode_admit}[
+        program]
+
+    def run():
+        # two steps: the second appends to a fresh tail block of slot 1
+        logits, after = jax.jit(lambda *a: fn(a[0], cfg, *a[1:]))(
+            params, *args)
+        again, after = jax.jit(lambda *a: lf.paged_decode_step(
+            a[0], cfg, *a[1:]))(params, args[0], after, args[2])
+        return logits, again, after
+    want = run()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(grouped_matmul, "_should_interpret", lambda: True)
+    monkeypatch.setattr(lda, "paged_latent_decode_attention",
+                        functools.partial(lda.paged_latent_decode_attention,
+                                          interpret=True))
+    got = run()
+    for a, b in zip(got[:2], want[:2]):
+        assert _rel(a, b) < 1e-5
+    assert (np.asarray(got[2].lengths) == np.asarray(want[2].lengths)).all()
+    # (after the second step: the first step's row of the first
+    # attention is the scatter's, the second's follows the first's logits)
+    for i, (a, b, c) in enumerate(zip(got[2].rows, want[2].rows,
+                                      cache.rows)):
+        assert (np.asarray(a[0]) == np.asarray(c[0])).all()
+        assert float(jnp.abs(a - b).max()) < 1e-5 * float(jnp.abs(b).max())
+    first = (np.asarray(got[2].rows[0]) != np.asarray(want[2].rows[0]))
+    assert first.sum() <= 2 * cfg.latent_width     # the second step's rows
 
 
 # ------------------------------------------------- the share and the model
@@ -916,3 +1077,21 @@ def test_the_programs_alone_script_holds_the_rider_to_the_reference(capsys):
         "decode", "prefill_128", "decode_admit_128_rider",
         "decode_admit_128_alone", "prefill_256", "decode_admit_256_rider",
         "decode_admit_256_alone"]
+
+
+def test_the_kernel_alone_script_runs_both_geometries(capsys):
+    """``scripts/latent_decode_micro.py`` at its toy sizes (float32,
+    interpret mode): a timed reading for each geometry, idle slots among
+    the live ones, several live blocks a slot (GigaChat's under a shared
+    context)."""
+    script = harness.load_module(
+        os.path.join(REPO, "scripts", "latent_decode_micro.py"),
+        "latent_decode_micro")
+    script.main(["--tiny", "--calls", "2", "--reps", "2"])
+    lines = [json.loads(x) for x in capsys.readouterr().out.splitlines()
+             if x.startswith("{")]
+    timed = {x["geometry"]: x for x in lines if x.get("form") == "fused"}
+    assert sorted(timed) == ["gigachat", "longcat"]
+    assert all(x["ms_per_call"] > 0 for x in timed.values())
+    assert timed["gigachat"]["live_blocks_mean"] > 12 > (
+        timed["longcat"]["live_blocks_mean"]) > 1

@@ -136,15 +136,23 @@ LATENT = dict(slots=256, blocks=2049, heads=64, width=576, value_dim=512)
 
 
 def _latent_decode():
-    """The latent decode kernel at the LongCat cell's geometry: q, one
-    attention's pool ``[NB, W, BS]``, table, lengths."""
+    """The latent decode kernel at the LongCat cell's geometry: the
+    query's latent part, one attention's pool ``[NB, W, BS]`` (donated:
+    the kernel appends), the query's rotary part, the new rows, table,
+    positions."""
     from deepspeed_tpu.ops.pallas import latent_decode_attention as lda
     g = LATENT
-    fn = functools.partial(lda.paged_latent_decode_attention,
-                           value_dim=g["value_dim"], scale=192 ** -0.5,
-                           interpret=False)
-    return fn, [((g["slots"], g["heads"], g["width"]), BF16),
+
+    def fn(q_lat, pool, q_rope, rows, table, positions):
+        return lda.paged_latent_decode_attention(
+            q_lat, q_rope, rows, pool, table, positions,
+            scale=192 ** -0.5, interpret=False)
+    fn.donate = (1,)
+    rope = g["width"] - g["value_dim"]
+    return fn, [((g["slots"], g["heads"], g["value_dim"]), BF16),
                 ((g["blocks"], g["width"], BS), BF16),
+                ((g["slots"], g["heads"], rope), BF16),
+                ((g["slots"], g["width"]), BF16),
                 ((g["slots"], MB), jnp.int32), ((g["slots"],), jnp.int32)]
 
 
@@ -263,7 +271,8 @@ def test_kernel_compiles_for_v5e(case, chips, monkeypatch):
     one = SingleDeviceSharding(chips[0])
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one)
             for shape, dtype in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
+    compiled = jax.jit(fn, donate_argnums=getattr(fn, "donate", ())
+                       ).lower(*args).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     if case.startswith("grouped-matmul-"):
@@ -477,6 +486,29 @@ def test_serving_programs_carry_their_names(chips, monkeypatch, kind,
 LATENT_SCOPES = {"embed", "ln", "mla_qkv", "latent_write", "mla_attn",
                  "attn_out", "dense_ffn", "moe_router", "moe_dispatch",
                  "moe_experts", "moe_combine", "lm_head", "sample"}
+# a decode step has no pool writer of its own: the kernel appends
+LATENT_DECODE_SCOPES = LATENT_SCOPES - {"latent_write"}
+
+
+def _appends_in_the_kernel(text, kernels, scopes, attentions, slots, width):
+    """A compiled program that decodes over a latent pool: one
+    ``paged_latent_decode_attention`` an attention under the scope
+    ``mla_attn``, each with its pool aliased in and out, no
+    ``paged_latent_append``, and no array with the rows' ``W`` values
+    down the sublanes over one live lane (``[S, W, 1]``)."""
+    from deepspeed_tpu.ops.pallas import latent_decode_attention as lda
+    ours = {k: name for k, name in kernels.items()
+            if name.startswith("paged_latent")}
+    assert sorted(ours.values()) == [lda.NAME] * attentions
+    assert all(scopes[k].rsplit("/", 1)[-1] == "mla_attn" for k in ours)
+    calls = [line for line in text.splitlines() if " custom-call(" in line
+             and line.split(" = ")[0].split()[-1].lstrip("%") in ours]
+    assert len(calls) == attentions
+    # output 1, the pool, is operand 5 (after positions, tables, the
+    # query's two parts and the rows)
+    assert all("output_to_operand_aliasing={{1}: (5, {})}" in line
+               for line in calls)
+    assert f"[{slots},{width},1]" not in text
 
 
 def test_latent_decode_program_walks_the_pool_where_it_lies(chips,
@@ -484,13 +516,14 @@ def test_latent_decode_program_walks_the_pool_where_it_lies(chips,
     """LongCat's ``serve_decode`` at the cell's widths, slots and pool
     (two of its four layers, a small vocabulary, the cell's sixteen held
     experts), read back from its compiled text: module, kernel and scope
-    names; one ``paged_latent_decode_attention`` and one
-    ``paged_latent_append`` an attention; two
-    ``held_experts_grouped_matmul`` an expert layer and branch under the
-    scope ``moe_experts`` and no grouped matmul of the compiler's; and
-    apart from those calls (the append rewrites its
-    donated pool in place) nothing writes as much as one attention's
-    pool: no copy, no transpose, no temporary of that size."""
+    names; one ``paged_latent_decode_attention`` an attention, which
+    appends the step's rows to its donated pool in place (no
+    ``paged_latent_append``, no ``latent_write`` scope, no ``[S, W, 1]``
+    operand); two ``held_experts_grouped_matmul`` an expert layer and
+    branch under the scope ``moe_experts`` and no grouped matmul of the
+    compiler's; and apart from those calls nothing writes as much as one
+    attention's pool: no copy, no transpose, no temporary of that
+    size."""
     from deepspeed_tpu.inference.kv_cache import init_latent_paged_cache
     from deepspeed_tpu.inference.server import ContinuousBatchingServer as Srv
     from deepspeed_tpu.model_implementations import longcat_flash as lf
@@ -537,15 +570,12 @@ def test_latent_decode_program_walks_the_pool_where_it_lies(chips,
     assert not [name for name in kernels.values()
                 if name.startswith("ragged")]
     assert lowered.as_text().count(f'kernel_name = "{gm.NAME}"') == 4
-    ours = {k: name for k, name in kernels.items()
-            if name.startswith("paged_latent")}
-    assert sorted(ours.values()) == sorted(
-        [lda.NAME, "paged_latent_append"] * cfg.attentions)
-    assert all(scopes[k].rsplit("/", 1)[-1] ==
-               ("mla_attn" if name == lda.NAME else "latent_write")
-               for k, name in ours.items())
+    _appends_in_the_kernel(text, kernels, scopes, cfg.attentions,
+                           g["slots"], g["width"])
     innermost = {v.rsplit("/", 1)[-1] for v in scopes.values() if v}
-    assert innermost >= LATENT_SCOPES, LATENT_SCOPES - innermost
+    assert innermost >= LATENT_DECODE_SCOPES, (
+        LATENT_DECODE_SCOPES - innermost)
+    assert "latent_write" not in innermost
     pool = cache.rows[0]
     pool_bytes = math.prod(pool.shape) * pool.dtype.itemsize
     assert not _copies(
@@ -561,8 +591,10 @@ def test_latent_decode_admit_program_updates_the_donated_pool(
     prompt in one forward) at the cell's widths, 256 slots, pool and
     both prompt buckets (two of its four layers, a small vocabulary, two
     held experts), read back from its compiled text: an attention is one
-    ``paged_latent_append`` and one ``paged_latent_decode_attention``
-    for the decode rows and one ``flash_attention_fwd`` for the prompt;
+    ``paged_latent_decode_attention`` for the decode rows, which appends
+    them too (no ``paged_latent_append``, no ``[S, W, 1]`` operand), and
+    one ``flash_attention_fwd`` for the prompt, whose rows the scatter
+    under ``latent_write`` stores;
     every buffer of the donated cache comes back in the buffer it came
     in; and nothing writes as much as one attention's pool or converts
     its layout, so no second copy of the pool is planned."""
@@ -594,11 +626,11 @@ def test_latent_decode_admit_program_updates_the_donated_pool(
     text = compiled.as_text()
     assert "HloModule jit_serve_decode_admit" in text
     scopes, kernels = compile_watch.parse_scopes(text)
-    ours = {k: name for k, name in kernels.items()
-            if name.startswith(("paged_latent", "flash_"))}
-    assert sorted(ours.values()) == sorted(
-        [lda.NAME, "paged_latent_append", "flash_attention_fwd"]
-        * cfg.attentions)
+    _appends_in_the_kernel(text, kernels, scopes, cfg.attentions,
+                           g["slots"], g["width"])
+    assert sorted(name for name in kernels.values()
+                  if name.startswith("flash_")) == (
+        ["flash_attention_fwd"] * cfg.attentions)
     innermost = {v.rsplit("/", 1)[-1] for v in scopes.values() if v}
     assert innermost >= LATENT_SCOPES, LATENT_SCOPES - innermost
     # the cache is donated: every one of its buffers is aliased to an
@@ -623,8 +655,10 @@ def test_gigachat_programs_read_the_latent_pool_where_it_lies(
     cell's widths, slots, pool, table and chunk (one dense and one
     expert layer of its six, a small vocabulary, two held experts), read
     back from their compiled text: module, kernel and scope names; one
-    attention kernel an attention (``paged_latent_decode_attention`` /
-    ``latent_chunk_attention``); and apart from the kernels' calls and
+    attention kernel an attention (``latent_chunk_attention``, or
+    ``paged_latent_decode_attention``, which appends the step's rows to
+    its aliased pool itself: no ``paged_latent_append``, no ``[S, W,
+    1]`` operand); and apart from the kernels' calls and
     the in-place writes of the donated pool nothing writes as much as
     one attention's pool, nothing converts a pool's layout, and nothing
     is as large as the K or V of a slot's whole context."""
@@ -667,13 +701,17 @@ def test_gigachat_programs_read_the_latent_pool_where_it_lies(
     scopes, kernels = compile_watch.parse_scopes(text)
     ours = {k: v for k, v in kernels.items()
             if v.startswith(("paged_latent", "latent_"))}
-    assert sorted(v for v in ours.values() if v == kernel) == (
-        [kernel] * cfg.attentions)
-    assert all(scopes[k].rsplit("/", 1)[-1] == "mla_attn"
-               for k, v in ours.items() if v == kernel)
+    assert sorted(ours.values()) == [kernel] * cfg.attentions
+    assert all(scopes[k].rsplit("/", 1)[-1] == "mla_attn" for k in ours)
     innermost = {v.rsplit("/", 1)[-1] for v in scopes.values() if v}
-    assert innermost >= LATENT_SCOPES | {"moe_shared"}, (
-        LATENT_SCOPES - innermost)
+    expected = (LATENT_DECODE_SCOPES if kind == "decode"
+                else LATENT_SCOPES) | {"moe_shared"}
+    assert innermost >= expected, expected - innermost
+    if kind == "decode":
+        # the kernel appends the step's rows: the program has no writer
+        _appends_in_the_kernel(text, kernels, scopes, cfg.attentions,
+                               g["slots"], g["width"])
+        assert "latent_write" not in innermost
     pool = cache.rows[0]
     pool_bytes = math.prod(pool.shape) * pool.dtype.itemsize
     # K (or V) of one slot's whole context, every head, bfloat16, is
