@@ -54,13 +54,13 @@ class InFlightStep:
     """One dispatched-but-unfetched device program (see module doc)."""
 
     __slots__ = ("kind", "tokens", "states", "props", "t_dispatch",
-                 "prev_fetch", "rider")
+                 "prev_fetch", "rider", "ticket")
 
     def __init__(self, kind: str, tokens: Any, states: Dict[int, Any],
                  t_dispatch: float,
                  props: Optional[Any] = None,
                  prev_fetch: Optional[float] = None,
-                 rider: Optional[Any] = None):
+                 rider: Optional[Any] = None, ticket: int = 0):
         self.kind = kind              # "decode" | "verify"
         self.tokens = tokens          # device array: [S] or [S, K]
         self.states = states          # slot -> SlotState AT DISPATCH
@@ -77,6 +77,9 @@ class InFlightStep:
         # beside the decode rows (the server's ``_Rider``), committed
         # with the record
         self.rider = rider
+        # what the fetch of ``tokens`` names to the step profile's
+        # ``serve:program`` FIFO (0: profile off, nothing noted)
+        self.ticket = ticket
 
 
 class PublishWorker:

@@ -2150,6 +2150,10 @@ class ContinuousBatchingServer:
             # dispatch->fetch interval is still device-attributed (and
             # advances the dispatch-gap boundary — the device was busy)
             sp.device_interval(t_pf, now_t)
+            sp.program_fetched(self._launched(
+                self._prefill_jit if self._admit_jit is None
+                else self._admit_jit, t_pf, T,
+                prompt_tokens=len(sched_prompt)), now_t, since=t_pf)
             self._prefilled(slot, state, T, t_admit, tok0, now_t, finished,
                             "alone")
 
@@ -2270,6 +2274,7 @@ class ContinuousBatchingServer:
             # on this chunk's cache output.
             t1 = self._clock()
             self._h_prefill_chunk.observe(t1 - t0)   # dispatch interval
+            self._launched(self._chunk_jit, t0, C, prompt_tokens=valid)
             if self._chunk_pending_t0 is None:
                 # ONE dispatch note per pending chain: the whole chain
                 # realizes through ONE fetch note (_realize_chunk_span),
@@ -2304,6 +2309,8 @@ class ContinuousBatchingServer:
                            if self._chunk_pending_t0 is not None
                            else t0, t1,
                            note_dispatch=self._chunk_pending_t0 is None)
+        sp.program_fetched(self._launched(
+            self._chunk_jit, t0, C, prompt_tokens=valid), t1, since=t0)
         self._chunk_pending_t0 = None
         if ck is not None:
             rt.trace.end_span(ck)
@@ -2343,6 +2350,8 @@ class ContinuousBatchingServer:
             self.draft.params, jnp.asarray(ids),
             jnp.asarray([plen], jnp.int32), self._draft_cache,
             jnp.int32(slot))
+        # behind the fetch of the target's prefill: no clock read of its own
+        self._launched(self._draft_prefill_jit, None, T, prompt_tokens=plen)
 
     def _draft_propose(self, states: Dict[int, object]):
         """One draft proposal round for the given slot→state snapshot:
@@ -2722,7 +2731,12 @@ class ContinuousBatchingServer:
                 jnp.asarray([len(rider.state.request.sched_prompt)],
                             jnp.int32), jnp.int32(rider.slot))
         sp.mark("dispatch", program=program.name)
-        chain.append(InFlightStep("decode", nxt, states, t0, rider=rider))
+        ticket = self._launched(
+            program, t0, None if rider is None else rider.ids.shape[1],
+            len(states), 0 if rider is None
+            else len(rider.state.request.sched_prompt))
+        chain.append(InFlightStep("decode", nxt, states, t0, rider=rider,
+                                  ticket=ticket))
         if lag:
             self._async_stats["pipeline_starts" if rec is None
                               else "pipelined_steps"] += 1
@@ -2764,6 +2778,7 @@ class ContinuousBatchingServer:
                else self._fetch_tokens(rec.tokens))
         t1 = self._clock()
         rider = rec.rider
+        self._proven(sp, rec.ticket, t1)
         if in_step:
             sp.mark("sync_wait", now=t1, fetch=True,
                     program=(self._decode_jit if rider is None
@@ -2962,8 +2977,11 @@ class ContinuousBatchingServer:
         t_toks, self._cache = self._verify_jit(
             self.engine.params, tok_arg, self._cache)
         sp.mark("dispatch", program=self._verify_jit.name)
+        for _ in range(K if use_draft else 0):
+            self._launched(self._draft_decode_jit, t0, None, len(states))
         rec = InFlightStep("verify", t_toks, states, t0, props=props,
-                           prev_fetch=prev_fetch)
+                           prev_fetch=prev_fetch, ticket=self._launched(
+                               self._verify_jit, t0, K, len(states)))
         if lag:
             if prev_fetch is None:      # nothing was in flight: a start
                 self._async_stats["pipeline_starts"] += 1
@@ -3003,6 +3021,7 @@ class ContinuousBatchingServer:
         props_src = (rec.props if isinstance(rec.props, dict)
                      else np.asarray(rec.props))
         t1 = self._clock()
+        self._proven(sp, rec.ticket, t1)
         if in_step and getattr(sp, "_pipelined_mode", False):
             sp.mark("sync_wait", now=t1, program=self._verify_jit.name)
             # device busy from step begin (the round was in flight
@@ -3214,6 +3233,26 @@ class ContinuousBatchingServer:
                     FLUSH_SPAN, t_flush, self._clock(),
                     attrs={"reason": reason, "programs": depth})
         self._drain_publishing()
+
+    def _launched(self, program, t0: Optional[float],
+                  bucket: Optional[int] = None, rows: int = 0,
+                  prompt_tokens: int = 0) -> int:
+        """Note one launch of a watched program for its ``serve:program``
+        record (``StepProfiler.program_launched``): the ticket its fetch
+        names, 0 with the step profile off."""
+        if self._profiler is None:
+            return 0
+        return self._profiler.program_launched(
+            program.name, t0, bucket, rows, prompt_tokens)
+
+    def _proven(self, sp, ticket: int, t1: float) -> None:
+        """The fetch that returned at ``t1`` proves program ``ticket``
+        finished; called BEFORE the mark that closes the wait. Between
+        steps (no handle) how long the host blocked is not known."""
+        if sp is not NULL_STEP_HANDLE:
+            sp.program_fetched(ticket, t1)
+        elif self._profiler is not None:
+            self._profiler.program_fetched(ticket, t1)
 
     def _fetch_tokens(self, tokens) -> np.ndarray:
         """Fetch of a program's sampled tokens where no later program

@@ -84,6 +84,11 @@ stat. A worked step slower than ``max(SLOW_STEP_S, SLOW_STEP_FACTOR x
 running median)`` leaves one ``slow_step`` event in the flight-recorder
 ring with its phases, chain depth and the program it waited on.
 
+Beside all of that, and read by none of it, every device program the
+loop launches leaves one ``serve:program`` record when the fetch that
+proves it finished returns (:meth:`StepProfiler.program_launched`,
+:meth:`StepProfiler.program_fetched`; docs/observability.md "Spans").
+
 Host-pure: no jax import (the annotations go through
 ``telemetry.spans.annotation``). Config-gated by
 ``telemetry.step_profile`` (default ON — the cost is a handful of clock
@@ -107,6 +112,7 @@ PHASES = ("admission", "prefill_chunk", "propose", "dispatch", "sync_wait",
 STEP_SPAN = "serve:step"
 FLUSH_SPAN = "serve:flush"
 PHASE_ANNOTATION = "serve:phase"
+PROGRAM_SPAN = "serve:program"
 # the request lifecycle the server records from its own stamps: the
 # three phases tile the request, each requeue its own queue_wait
 REQUEST_SPAN = "serve:request"
@@ -129,6 +135,10 @@ SLOW_STEP_MIN_HISTORY = 8
 # the host blocks on it); prefill intervals attribute via
 # device_interval() because they nest inside the admission phase
 DEVICE_PHASES = frozenset({"dispatch", "sync_wait"})
+
+# launched programs the FIFO behind ``serve:program`` holds at most (a
+# chained prefill of a 33k-token prompt launches ~130 chunks in a step)
+MAX_LAUNCHED = 4096
 
 
 def _hist_p50(hist: Dict[int, int]) -> int:
@@ -173,6 +183,10 @@ class _NullStepHandle:
         return None
 
     def pipelined_mode(self) -> None:
+        return None
+
+    def program_fetched(self, ticket: int, now: float,
+                        since: Optional[float] = None) -> None:
         return None
 
     def finish(self, live: bool = True, slots: Optional[int] = None,
@@ -352,6 +366,16 @@ class _StepHandle:
         a later :meth:`pipelined` tail (the async verify round)."""
         self._pipelined_mode = True
 
+    def program_fetched(self, ticket: int, now: float,
+                        since: Optional[float] = None) -> None:
+        """The fetch that returned at ``now`` proves program ``ticket``
+        finished (:meth:`StepProfiler.program_fetched`); the host blocked
+        in it from ``since``, by default this step's last mark: call it
+        BEFORE the mark that closes the wait."""
+        self._prof.program_fetched(
+            ticket, now, max(now - (self._last if since is None
+                                    else since), 0.0))
+
     def finish(self, live: bool = True, slots: Optional[int] = None,
                admitted: Optional[int] = None, rider: bool = False) -> None:
         """Close the step: the tail since the last mark becomes the
@@ -460,6 +484,12 @@ class StepProfiler:
         # construction. None (default) costs one attribute read.
         self.on_step_device: Optional[Callable[[float], None]] = None
         self._handle = _StepHandle(self)
+        # serve:program records: the programs launched and not yet
+        # proven finished by a fetch, oldest first, and where the newest
+        # record ended. BESIDE the accounting above, which never reads it
+        self._launched: Deque[tuple] = deque()
+        self._tickets = 0
+        self._program_end = 0.0
         reg = self.registry
         self._h_wall = reg.histogram(
             "serve_step_wall_seconds",
@@ -484,6 +514,14 @@ class StepProfiler:
                  "async_loop max_commit_lag)",
             buckets=[float(i) for i in range(1, 17)])
         self._phase_hist: Dict[str, object] = {}
+        self._c_span_errors = {
+            site: reg.counter(
+                "serve_program_span_errors_total",
+                help="faults in the serve:program bookkeeping (a launch "
+                     "or a fetch that raised, a FIFO nothing proved): "
+                     "the record is dropped, the step goes on",
+                labels={"site": site})
+            for site in ("launch", "fetch")}
 
     # ------------------------------------------------------------ steps
 
@@ -548,6 +586,75 @@ class StepProfiler:
         outstanding-dispatch pairing exact when no step handle is
         live."""
         self._note_fetch(now)
+
+    # ------------------------------------------------- serve:program
+
+    def program_launched(self, program: str, now: Optional[float],
+                         bucket: Optional[int] = None, rows: int = 0,
+                         prompt_tokens: int = 0) -> int:
+        """A device program left the host at ``now`` (None: no clock
+        read of its own, it opens where the program before it closed).
+        Returns the ticket its fetch names, 0 where nothing was noted; a
+        program no fetch waits for on its own (a non-final chunk, a
+        draft forward) is proven by the next ticket's. Never raises."""
+        try:
+            q = self._launched
+            if len(q) >= MAX_LAUNCHED:
+                q.clear()
+                raise RuntimeError(
+                    f"{MAX_LAUNCHED} programs launched and none proven")
+            self._tickets += 1
+            q.append((self._tickets, program,
+                      float("-inf") if now is None else now, bucket, rows,
+                      prompt_tokens, self._seq, len(q) + 1))
+            return self._tickets
+        except Exception:  # noqa: BLE001: tracing must not end a run
+            self._program_span_error("launch")
+            return 0
+
+    def program_fetched(self, ticket: int, now: float,
+                        waited: Optional[float] = None) -> None:
+        """The fetch that returned at ``now`` proves program ``ticket``
+        finished, and with it every program launched before it (the
+        device runs one stream in dispatch order): each leaves its
+        ``serve:program`` record, ``start = max(its own dispatch, the
+        end of the record before it)``. ``ticket``'s ends at ``now``
+        with ``waited`` (seconds the host blocked in the fetch; None for
+        a fetch between steps, which has no mark before it); one that no
+        fetch named closes where the next one opens, ``waited`` None. A
+        ticket the FIFO does not hold (0, or proven already) proves
+        nothing. Never raises."""
+        try:
+            q = self._launched
+            end = self._program_end
+            while q and q[0][0] <= ticket:
+                (tk, program, t, bucket, rows, prompt_tokens, seq,
+                 depth) = q.popleft()
+                start = max(t, end)
+                end = max(now, start)
+                if tk != ticket and q:    # no fetch of its own
+                    end = min(max(q[0][2], start), end)
+                self.span_log.record(PROGRAM_SPAN, start, end, key=seq, attrs={
+                    "program": program, "bucket": bucket, "rows": rows,
+                    "prompt_tokens": prompt_tokens, "dispatched_in": seq,
+                    "fetched_in": self._seq, "depth": depth,
+                    "waited": waited if tk == ticket else None})
+                self._program_end = end
+        except Exception:  # noqa: BLE001: tracing must not end a run
+            self._program_span_error("fetch")
+
+    def _program_span_error(self, site: str) -> None:
+        """Counted, logged once a site, never raised."""
+        try:
+            counter = self._c_span_errors[site]
+            counter.inc()
+            if counter.value == 1:
+                from deepspeed_tpu.utils.logging import logger
+                logger.warning("serve:program bookkeeping failed at its "
+                               "%s (counted, logged once)", site,
+                               exc_info=True)
+        except Exception:  # noqa: BLE001
+            pass
 
     def recent_gap_s(self) -> float:
         """Mean of the last ≤32 dispatch-gap observations (0.0 with no
