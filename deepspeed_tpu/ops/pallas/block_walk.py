@@ -15,10 +15,14 @@ paid once a slot, not once a table entry.
 
 :func:`walk_live_blocks` is that scaffold and nothing else: which arrays
 stream, how a table entry becomes a block id and what is done with a
-block that has landed are the kernel's: ``decode_attention._paged_kernel``
-streams K, V and an int8 pool's scale tiles, a table entry an iteration;
-``latent_decode_attention._kernel`` streams one latent pool several
-entries an iteration, each through a stream of its own.
+block that has landed are the kernel's. Both callers walk several table
+entries an iteration where a block is too small to hide the latency of
+its own chain, each entry through streams of its own:
+``decode_attention._paged_kernel`` streams K and V (one to four entries
+an iteration by the bytes of a block; an int8 pool's scale tiles, verify
+windows and prefill chunks an entry an iteration),
+``latent_decode_attention._kernel`` one latent pool, three entries an
+iteration.
 """
 from __future__ import annotations
 

@@ -27,6 +27,25 @@ the last block's iteration starts the first block of the next grid step
 that has one. A dead table entry is never read; an idle slot (bound
 below zero) costs one empty grid step that writes zeros.
 
+Several entries an iteration. On the chip a block costs the larger of
+its bytes' time and the latency of one iteration's chain (wait for the
+copy, score product, two lane reductions, ``exp``, ``p . v``, the
+accumulator's rescale: 0.75-0.9 us, where a MiMo-V2 full layer's 327 KB
+block is 0.40 us of bytes), and the loop runs one chain after another.
+So the one-token walk over a full-precision pool covers ``G`` table
+entries an iteration (:func:`_entries_per_iteration`: about a mebibyte
+of K + V, at most two where each head has a product of its own, from
+the static shapes alone), entry ``j*G + k`` through K and
+V streams ``k`` of its own (``2 G`` streams, two buffers each; an entry
+past the slot's last live block sits out: no copy, no arithmetic): all
+``G`` score products first, which depend on nothing but their blocks,
+then the online-softmax recurrence over them in table order, carried in
+values and stored once. The float32 sums and their order are the
+one-entry walk's, so the outputs are its outputs to the bit; a call
+whose blocks are a mebibyte already (16 heads of 128), a verify window,
+a prefill chunk and an int8 pool walk one entry an iteration, and their
+traced kernel is the one it was.
+
 The arithmetic. K and V go to the MXU as they are stored (bfloat16
 pools: no float32 copy of a block; int8 blocks are cast to the query's
 dtype, which holds them exactly), the softmax scale is applied to the
@@ -47,13 +66,16 @@ static shapes alone:
   in its diagonal ``D``-wide lane block, picked out once at the end.
   Each K and V tile passes through the MXU once, as it would anyway;
   what goes is a product, a cast and a one-sublane softmax per head.
+  With ``G`` entries an iteration the ``G`` products of an iteration
+  are issued together, ahead of the recurrence that consumes them.
 - more rows (prefill chunks, wide verify windows): a product per kv
   head at M = rows against that head's static lane slice of the slab
   (the TPU lowering only takes blocks whose last two dims are
   tile-aligned or span the array, so a copy cannot pick one kv head out
-  of ``KH``). Grouped-query attention is native on both paths: a kv
-  head's slice is attended by its whole query group at once, so GQA's
-  bandwidth saving survives.
+  of ``KH``). With ``G`` entries an iteration a head's ``G`` products
+  come first and its recurrence after them, head by head. Grouped-query
+  attention is native on both paths: a kv head's slice is attended by
+  its whole query group at once, so GQA's bandwidth saving survives.
 - key heads whose lanes do not tile (:func:`_ragged`: 192, where every
   other head of a row starts inside a 128-lane tile) have no aligned
   slice for a product of their own: they share the block-diagonal
@@ -89,6 +111,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.pallas.block_walk import live_blocks, walk_live_blocks
+from deepspeed_tpu.telemetry.registry import get_registry
 
 NEG_INF = -1e30
 DEFAULT_BLOCK_K = 256
@@ -127,7 +150,8 @@ def _layer_pools(k_pool, v_pool, D, k_scale, v_scale):
 def _paged_kernel(base_ref, bt_ref, offset_ref, q_ref, *rest,
                   block_size: int, head_dim: int, rep: int, span: int,
                   scale: float, quantized: bool, batched: bool,
-                  window: int = 0, v_head_dim: int = 0, sink: bool = False):
+                  window: int = 0, v_head_dim: int = 0, sink: bool = False,
+                  entries: int = 1):
     """Grid (slot, query-row block): one step walks ITS slot's live
     blocks (:func:`~deepspeed_tpu.ops.pallas.block_walk.walk_live_blocks`
     has the scaffold: two VMEM buffers a stream, the next step's first
@@ -172,20 +196,29 @@ def _paged_kernel(base_ref, bt_ref, offset_ref, q_ref, *rest,
     as ``m``), that joins the softmax's denominator and carries no value:
     the carry starts at ``(m, l, acc) = (sink, 1, 0)`` in place of
     ``(-inf, 0, 0)``. An idle slot still writes zeros.
+
+    ``entries`` (static, ``G``; 1: a table entry an iteration, and the
+    body above is all there is): an iteration of the walk covers table
+    entries ``j*G .. j*G + G - 1`` of its slot, each through K and V
+    streams of its own (``2 G`` streams; an entry past the slot's last
+    live block sits out: no copy, no arithmetic), so the trip count is
+    ``ceil(live blocks / G)``. One query token a slot over a
+    full-precision pool only (:func:`_entries_per_iteration`).
     """
+    G = entries
     sink_ref = None
     if sink:
         sink_ref, *rest = rest
-    if quantized:
-        (k_hbm, v_hbm, ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf,
-         sems, next_buf, m_ref, l_ref, acc_ref, *rest) = rest
-        streams = ((k_hbm, k_buf), (v_hbm, v_buf), (ks_hbm, ks_buf),
-                   (vs_hbm, vs_buf))
-    else:
-        (k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, next_buf, m_ref, l_ref,
-         acc_ref, *rest) = rest
-        ks_buf = vs_buf = None
-        streams = ((k_hbm, k_buf), (v_hbm, v_buf))
+    # K, V and an int8 pool's two scale-tile arrays; a pair of VMEM
+    # buffers each, G times over
+    P = 4 if quantized else 2
+    pools, (o_ref, *rest) = rest[:P], rest[P:]
+    bufs, (sems, next_buf, m_ref, l_ref, acc_ref, *rest) = (
+        rest[:P * G], rest[P * G:])
+    streams = tuple((pools[i % P], buf) for i, buf in enumerate(bufs))
+    k_bufs, v_bufs = bufs[0::P], bufs[1::P]
+    k_buf, v_buf = k_bufs[0], v_bufs[0]
+    ks_buf, vs_buf = bufs[2:] if quantized else (None, None)
     qbd_ref, = rest or (None,)      # the block-diagonal q, if batched
     s, rb = pl.program_id(0), pl.program_id(1)
     S, RB = pl.num_programs(0), pl.num_programs(1)
@@ -197,14 +230,31 @@ def _paged_kernel(base_ref, bt_ref, offset_ref, q_ref, *rest,
     cdt = q_ref.dtype
 
     base = base_ref[s] + rb * span     # bound of this block's first token
-    n = live_blocks(base + span, BS, MB)
+    blocks = live_blocks(base + span, BS, MB)
+
+    def trips(blocks):
+        """Loop iterations that walk ``blocks`` table entries."""
+        return blocks if G == 1 else jax.lax.div(blocks + (G - 1), G)
     # the grid step after this one, and whether it has a block to fetch
     wraps = rb + 1 == RB
     s_next = jnp.minimum(jnp.where(wraps, s + 1, s), S - 1)
     rb_next = jnp.where(wraps, 0, rb + 1)
     n_next = jnp.where(
         jnp.logical_and(wraps, s + 1 == S), 0,
-        live_blocks(base_ref[s_next] + (rb_next + 1) * span, BS, MB))
+        trips(live_blocks(base_ref[s_next] + (rb_next + 1) * span, BS, MB)))
+
+    def block_ids(slot, j):
+        """The blocks of group ``j`` of ``slot``, one a stream. ``G > 1``
+        walks one query token a slot, so a slot's live blocks are the
+        slot's alone; the group's first entry is live whenever the walk
+        reaches the group."""
+        if G == 1:
+            return bt_ref[slot, j] + offset_ref[0]
+        live = live_blocks(base_ref[slot] + span, BS, MB)
+        ids = [bt_ref[slot, j * G] + offset_ref[0]] + [
+            (bt_ref[slot, jnp.minimum(j * G + k, MB - 1)] + offset_ref[0],
+             j * G + k < live) for k in range(1, G)]
+        return tuple(block for block in ids for _ in range(P))
 
     def idle():
         o_ref[...] = jnp.zeros_like(o_ref)
@@ -272,18 +322,23 @@ def _paged_kernel(base_ref, bt_ref, offset_ref, q_ref, *rest,
             # position ``base`` 0, the one before it 1, ... wrapping
             newest = jax.lax.rem(base, MB * BS)
 
-        def attend(j, buf):
+        def visible_in(j):
+            """Which columns of table entry ``j`` each query row sees."""
             if window:
                 age = newest - j * BS - col
                 age = jnp.where(age < 0, age + MB * BS, age)
-                visible = jnp.logical_and(age < window, age <= base)
-            else:
-                visible = col <= bound - j * BS
+                return jnp.logical_and(age < window, age <= base)
+            return col <= bound - j * BS
+
+        def score(q, k):
+            return jax.lax.dot_general(
+                q, operand(k), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+
+        def attend(j, buf):
+            visible = visible_in(j)
             if batched:
-                sc = jax.lax.dot_general(
-                    qbd_ref[...], operand(k_buf[buf]),
-                    (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32) * scale
+                sc = score(qbd_ref[...], k_buf[buf])
                 if quantized:
                     sc = sc * rows_of(ks_buf[buf])
                 p, alpha, m_ref[...], l_ref[...] = softmax_step(
@@ -296,10 +351,7 @@ def _paged_kernel(base_ref, bt_ref, offset_ref, q_ref, *rest,
             for h in range(KH):
                 lanes = slice(h * D, (h + 1) * D)
                 v_lanes = slice(h * Dv, (h + 1) * Dv)
-                sc = jax.lax.dot_general(
-                    q_ref[0, h], operand(k_buf[buf, :, lanes]),
-                    (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32) * scale
+                sc = score(q_ref[0, h], k_buf[buf, :, lanes])
                 if quantized:
                     sc = sc * ks_buf[buf, h:h + 1, :]
                 p, alpha, m_ref[h], l_ref[h] = softmax_step(
@@ -309,7 +361,39 @@ def _paged_kernel(base_ref, bt_ref, offset_ref, q_ref, *rest,
                 acc_ref[h] = acc_ref[h] * alpha + p_dot_v(
                     p, operand(v_buf[buf, :, v_lanes]))
 
-        loop(attend)
+        def attend_entries(j, buf, live: int):
+            """The first ``live`` entries of group ``j``, entry ``k`` in
+            buffer ``buf`` of streams ``k``: every entry's score product
+            first (they wait for nothing but their block), then the
+            recurrence over them in table order, carried in values and
+            stored once. The sums and their order are :func:`attend`'s:
+            the outputs are a walk's of one entry an iteration, to the
+            bit."""
+            if batched:     # one product over all heads, else one a head
+                heads = [(Ellipsis, qbd_ref[...], slice(None), slice(None))]
+            else:
+                heads = [(h, q_ref[0, h], slice(h * D, (h + 1) * D),
+                          slice(h * Dv, (h + 1) * Dv)) for h in range(KH)]
+            visible = [visible_in(j * G + k) for k in range(live)]
+            for i, q, lanes, v_lanes in heads:
+                scores = [score(q, k_bufs[k][buf, :, lanes])
+                          for k in range(live)]
+                m, l, acc = m_ref[i], l_ref[i], acc_ref[i]
+                for k, sc in enumerate(scores):
+                    p, alpha, m, l = softmax_step(sc, m, l, visible[k])
+                    acc = acc * alpha + p_dot_v(
+                        p, operand(v_bufs[k][buf, :, v_lanes]))
+                m_ref[i], l_ref[i], acc_ref[i] = m, l, acc
+
+        def attend_group(j, buf):
+            # only a walk's last group can be short of entries: a branch
+            # a live count, one taken
+            live = jnp.minimum(blocks - j * G, G)
+            for k in range(1, G + 1):
+                pl.when(live == k)(
+                    functools.partial(attend_entries, j, buf, k))
+
+        loop(attend if G == 1 else attend_group)
         l = jnp.maximum(l_ref[...], 1e-30)
         if batched:     # each row's own diagonal block of the accumulator
             out = functools.reduce(jnp.add, own_head(
@@ -319,16 +403,15 @@ def _paged_kernel(base_ref, bt_ref, offset_ref, q_ref, *rest,
             o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
     walk_live_blocks(
-        streams, sems, next_buf,
-        lambda slot, j: bt_ref[slot, j] + offset_ref[0],
-        slot=s, n=n, first=jnp.logical_and(s == 0, rb == 0),
+        streams, sems, next_buf, block_ids,
+        slot=s, n=trips(blocks), first=jnp.logical_and(s == 0, rb == 0),
         slot_next=s_next, n_next=n_next, idle=idle, walk=walk)
 
 
 def _paged_attention(qg, k_pool, v_pool, block_tables, base, *, rep: int,
                      scale, interpret, name: str, layer: int = 0,
                      k_scale=None, v_scale=None, window: int = 0,
-                     sink=None):
+                     sink=None, entries: int | None = None):
     """The decode family's one ``pallas_call``, named ``name`` in the
     compiled program and the device trace (the entry point's name: the
     kernel body is shared). qg ``[S, KH, T*rep, D]``
@@ -341,7 +424,9 @@ def _paged_attention(qg, k_pool, v_pool, block_tables, base, *, rep: int,
     token t sees key positions ``<= base[s] + t``; ``sink [KH*rep]``
     float32 (one query token a slot only): a logit a query head that
     joins the softmax's denominator and carries no value. Returns
-    ``[S, KH, T*rep, Dv]``.
+    ``[S, KH, T*rep, Dv]``. ``entries`` pins the table entries a loop
+    iteration attends, for a test or a measurement (None: what
+    :func:`_entries_per_iteration` gives the call's shapes).
 
     The layer reaches the kernel as DATA (its block offset, one more
     prefetched scalar), so the calls of a model's layers are one traced
@@ -390,7 +475,7 @@ def _paged_attention(qg, k_pool, v_pool, block_tables, base, *, rep: int,
         name, bool(interpret), (S, KH, rows, D), qg.dtype.name,
         tuple((x.shape, x.dtype.name) for x in pools),
         block_tables.shape[1], rep, float(scale), int(window),
-        sink is not None)
+        sink is not None, entries)
     sinks = () if sink is None else (sink.astype(jnp.float32),)
     return call(base.astype(jnp.int32), block_tables.astype(jnp.int32),
                 jnp.full((1,), layer * NB, jnp.int32), qg, *sinks, *pools)
@@ -402,10 +487,49 @@ def _ragged(D: int) -> bool:
     return bool(D % 128 and 128 % D)
 
 
+# K + V bytes a loop iteration of the one-token walk should carry, and
+# the most table entries it may take to carry them: where one product
+# carries all heads of a block, and where each head has a product of its
+# own. The kernel alone on a v5e (PERF.md section 7, PR 57; us a call at
+# 1 / 2 / 3 / 4 entries an iteration, outputs equal to the bit). One
+# product: MiMo-V2 full layers (blocks of 328 KB) 1605 / 1188 / 1054 /
+# 1029; its rings of two blocks (655 KB) 268 / 225; Granite (524 KB)
+# 1202 / 1017 / 1019 / 1021. A product a head gains at two entries and
+# loses past them: Laguna full layers (524 KB, 8 heads) 1782 / 1487 /
+# 1955 / 1818, its rings of five 454 / 427 / 526 / 605, 4 heads x 16
+# rows over 262 KB blocks (no cell's) 1392 / 984 / 1197 / 1080. GPT-2
+# 1.3B (1049 KB) 202 at every count. A mebibyte, rounded to whole
+# blocks, takes the best or within 2.3 % of it in each
+ITERATION_BYTES = 1 << 20
+MAX_ENTRIES = 4
+MAX_ENTRIES_A_HEAD = 2
+
+
+def _entries_per_iteration(slab_bytes: int, MB: int, one_token: bool,
+                           quantized: bool, batched: bool) -> int:
+    """Table entries a loop iteration of :func:`_paged_kernel` attends
+    (its ``G``), from what a call can see: ``slab_bytes`` of K + V a
+    table entry, tables of ``MB`` entries, whether one product carries
+    all heads of a block (``batched``). A block's cost on the chip is
+    the larger of its bytes' time and the latency of one iteration's
+    chain (wait for the copy, score product, two lane reductions,
+    ``exp``, ``p . v`` in two parts, the accumulator's rescale: 0.75-0.9
+    us), so small blocks walk several an iteration, about
+    ``ITERATION_BYTES`` in all, and a block that size or larger one.
+    Only the one-token full-precision signature takes more than one:
+    verify windows and prefill chunks do a block's worth of work a
+    block, and int8 pools run in no measured cell."""
+    if not one_token or quantized:
+        return 1
+    most = MAX_ENTRIES if batched else MAX_ENTRIES_A_HEAD
+    return max(1, min((ITERATION_BYTES + slab_bytes // 2) // slab_bytes,
+                      MB, most))
+
+
 @functools.lru_cache(maxsize=None)
 def _paged_call(name: str, interpret: bool, q_shape, q_dtype: str, pools,
                 MB: int, rep: int, scale: float, window: int = 0,
-                sink: bool = False):
+                sink: bool = False, entries: int | None = None):
     """The ``pallas_call`` of one static signature: ``(base [S], tables
     [S, MB], block offset [1], qg [S, KH, rows, D][, sink [KH*rows]],
     *pools) -> [S, KH, rows, Dv]``. Grid ``(S, row blocks)``, in order; the pools (``pools``:
@@ -414,7 +538,11 @@ def _paged_call(name: str, interpret: bool, q_shape, q_dtype: str, pools,
     :func:`_paged_kernel` copies the blocks it walks into two VMEM
     buffers a stream. The heads share one product a block when all
     their rows fit ``MAX_BATCHED_ROWS`` (``MAX_RAGGED_ROWS`` where a
-    key head's lanes do not tile: :func:`_ragged`). Kept per signature, because
+    key head's lanes do not tile: :func:`_ragged`), and an iteration of
+    the walk attends :func:`_entries_per_iteration` table entries
+    (``entries`` pins them), which the gauge
+    ``paged_decode_entries_per_iteration{kernel, slab_bytes}`` says of
+    every signature built. Kept per signature, because
     jax traces a call it has seen before from its cache: the 24 layers
     of a decode program trace the kernel body once."""
     S, KH, rows, D = q_shape
@@ -423,6 +551,18 @@ def _paged_call(name: str, interpret: bool, q_shape, q_dtype: str, pools,
     Dv = Wv // KH
     batched = KH * rows <= (MAX_RAGGED_ROWS if _ragged(D)
                             else MAX_BATCHED_ROWS)
+    slab = BS * (W + Wv) * jnp.dtype(pool_dtype).itemsize
+    G = entries or _entries_per_iteration(slab, MB, rows == rep,
+                                          len(pools) == 4, batched)
+    if G > 1 and (rows != rep or len(pools) == 4 or G > MB):
+        raise ValueError(
+            f"{G} table entries an iteration: one query token a slot, a "
+            f"full-precision pool, tables of at least {G} entries")
+    get_registry().gauge(
+        "paged_decode_entries_per_iteration",
+        "table entries a loop iteration of the paged decode kernel "
+        "attends, by kernel name and K + V bytes a table entry",
+        labels={"kernel": name, "slab_bytes": str(slab)}).set(G)
     # tokens per row block: halve while the rows overrun the VMEM budget
     # and the halves still tile (a split block's sublane dim must be a
     # multiple of 8)
@@ -454,7 +594,7 @@ def _paged_call(name: str, interpret: bool, q_shape, q_dtype: str, pools,
     kernel = functools.partial(
         _paged_kernel, block_size=BS, head_dim=D, rep=rep, span=span,
         scale=scale, quantized=len(pools) == 4, batched=batched,
-        window=window, v_head_dim=Dv, sink=sink)
+        window=window, v_head_dim=Dv, sink=sink, entries=G)
     call = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -465,8 +605,8 @@ def _paged_call(name: str, interpret: bool, q_shape, q_dtype: str, pools,
             out_specs=spec(Dv),
             scratch_shapes=[
                 *[pltpu.VMEM((2, *shape[1:]), dtype)
-                  for shape, dtype in pools],
-                pltpu.SemaphoreType.DMA((len(pools), 2)),
+                  for shape, dtype in pools] * G,
+                pltpu.SemaphoreType.DMA((len(pools) * G, 2)),
                 pltpu.SMEM((1,), jnp.int32), *softmax_state]),
         out_shape=jax.ShapeDtypeStruct(o_block, q_dtype),
         # in order: a step starts the next step's first block

@@ -7,6 +7,8 @@ to attention, (3) the paged pool + block table reproduces the dense
 cache bit-for-bit through the gather, and the null block isolates idle
 slots from live ones.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -571,6 +573,141 @@ def test_decode_kernel_takes_narrow_values_and_a_sink(shape, sink, kind):
         out = np.einsum("hs,shd->hd", e / den[:, None],
                         np.repeat(np.asarray(vs, np.float64), H // KH, 1))
         np.testing.assert_allclose(got[s], out, atol=2e-5)
+
+
+# the one-token walk at several table entries an iteration: kv heads,
+# query rows a head, key / value lanes, ring blocks (0: a pool), window,
+# sink, and lengths whose live-block counts hit every remainder mod 2, 3
+# and 4 (a slot of one row, a ring not yet wrapped, idle slots first,
+# last and between)
+_WALKS = {
+    # 4 x 16 rows over heads of 192 lanes: one block-diagonal product
+    "ragged-batched": (4, 16, 192, 128, 0, 0, False,
+                       [0, 1, 16, 17, 40, 48, 0, 64, 70, 96, 144, 0]),
+    # 8 x 6 rows: a product a head
+    "per-head": (8, 6, 128, 128, 0, 0, False,
+                 [0, 1, 16, 17, 40, 48, 0, 64, 70, 96, 144, 0]),
+    "ring2-sink": (8, 8, 192, 128, 2, 16, True,
+                   [0, 1, 15, 16, 17, 0, 33, 500, 0]),
+    "ring5": (8, 8, 128, 128, 5, 64, False,
+              [0, 3, 16, 47, 64, 65, 0, 80, 81, 1000, 0]),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _walk_case(case):
+    """One ``_WALKS`` case over a two-layer POISONED pool (blocks of 16
+    rows; :func:`_poisoned`; a ring's unwritten rows likewise: NaN in
+    the blocks its walk does not reach, large garbage behind the newest
+    row of the block it does): the kernel as a function of the entries
+    an iteration, the float32 oracle over the clean second layer, the
+    live slots, and the walk of one entry an iteration (once a case)."""
+    from deepspeed_tpu.ops.pallas import decode_attention as da
+    KH, rep, D, Dv, RB, window, sink, lengths = _WALKS[case]
+    S, BS, L = len(lengths), 16, 2
+    q = _rand(1, (S, KH * rep, D))
+    b = 2.0 + _rand(2, (KH * rep,)) if sink else None
+    seen = np.asarray(lengths)
+    if RB:
+        MB, NB = RB, S * RB
+        tables = np.asarray(da.ring_tables(S, RB))
+    else:
+        MB = -(-max(lengths) // BS)
+        NB = 1 + S * MB
+        tables = 1 + np.random.default_rng(5).permutation(S * MB).reshape(
+            S, MB)
+    k, v = _rand(3, (L, NB, BS, KH * D)), _rand(4, (L, NB, BS, KH * Dv))
+    pk, pv, _ = _poisoned(k, v, {}, tables, np.minimum(seen, MB * BS))
+    if RB:
+        want = da.paged_window_decode_attention_reference(
+            q, k[1], v[1], jnp.asarray(seen), window, sink=b)
+    else:
+        want = da.paged_decode_attention_reference(
+            q, k[1], v[1], jnp.asarray(tables), jnp.asarray(seen), sink=b)
+
+    def run(entries):
+        return np.asarray(da._paged_attention(
+            q.reshape(S, KH, rep, D), pk, pv, jnp.asarray(tables, jnp.int32),
+            jnp.asarray(seen - 1, jnp.int32), rep=rep, scale=None,
+            interpret=True, name="x", layer=1, window=window, sink=b,
+            entries=entries)).reshape(S, KH * rep, Dv)
+    return run, np.asarray(want), seen > 0, run(1)
+
+
+@pytest.mark.parametrize("case,entries", [
+    (case, entries) for case in sorted(_WALKS) for entries in (1, 2, 3, 4)
+    if entries <= (_WALKS[case][4] or 4)])      # at most the ring
+def test_decode_walk_of_several_entries_is_the_walk_of_one(case, entries):
+    """The one-token kernel (interpret mode) attending ``entries`` table
+    entries a loop iteration, pinned through ``_paged_attention``'s
+    private keyword: equal TO THE BIT to the walk of one entry an
+    iteration (the float32 sums and their order are the same), which is
+    the float32 oracle's to tolerance; over a poisoned pool, so a copy
+    of a dead entry or a row past a slot's bound shows."""
+    run, want, live, one = _walk_case(case)
+    np.testing.assert_allclose(one[live], want[live], atol=2e-5)
+    assert not one[~live].any()                     # idle slots: zeros
+    if entries > 1:
+        np.testing.assert_array_equal(run(entries), one)
+
+
+def test_entries_an_iteration_follow_the_bytes_of_a_block():
+    """The rule at the five cells' shapes (blocks of 128 bfloat16 rows;
+    K + V bytes a table entry, table entries): about a mebibyte an
+    iteration, at most four entries (two where each head has a product
+    of its own) and at most the table; GPT-2 1.3B's block is a mebibyte,
+    so its walk is the one it was. Verify windows,
+    prefill chunks and int8 pools walk an entry an iteration whatever
+    their blocks weigh, and the gauge says what each signature got."""
+    from deepspeed_tpu.ops.pallas import decode_attention as da
+    from deepspeed_tpu.telemetry.registry import get_registry
+    rule = da._entries_per_iteration
+
+    def slab(kv_heads, D, Dv):
+        return 128 * kv_heads * (D + Dv) * 2
+    one, each = dict(batched=True), dict(batched=False)   # product(s) a block
+    assert rule(slab(4, 192, 128), 96, True, False, **one) == 3   # MiMo full
+    assert rule(slab(8, 192, 128), 2, True, False, **one) == 2    # MiMo ring
+    assert rule(slab(8, 128, 128), 80, True, False, **each) == 2  # Laguna full
+    assert rule(slab(8, 128, 128), 5, True, False, **each) == 2   # Laguna ring
+    assert rule(slab(8, 128, 128), 48, True, False, **one) == 2   # Granite
+    assert rule(slab(16, 128, 128), 8, True, False, **one) == 1   # GPT-2 1.3B
+    assert rule(slab(1, 64, 64), 96, True, False, **one) == 4     # at most 4
+    assert rule(slab(1, 64, 64), 3, True, False, **one) == 3      # ... the table
+    assert rule(slab(4, 128, 128), 80, True, False, **each) == 2  # ... 2 a head
+    assert rule(slab(4, 192, 128), 96, False, False, **one) == 1  # tokens
+    assert rule(slab(4, 192, 128), 96, True, True, **one) == 1    # int8
+
+    # through the entry points, on toy blocks (a rule that looked at the
+    # bytes alone would give each of these four)
+    S, H, KH, D, NB, BS, MB, T = 2, 4, 2, 16, 9, 16, 4, 4
+    k, v, scales = _layered_pools(1, NB, BS, KH, D, False)
+    k8, v8, scales8 = _layered_pools(1, NB, BS, KH, D, True)
+    tables = jnp.arange(1, 1 + S * MB, dtype=jnp.int32).reshape(S, MB)
+    lengths = jnp.asarray([5, 40], jnp.int32)
+    da._paged_call.cache_clear()
+    da.paged_decode_attention(_rand(1, (S, H, D)), k, v, tables, lengths,
+                              interpret=True)
+    da.paged_decode_attention(_rand(1, (S, H, D)), k8, v8, tables, lengths,
+                              interpret=True, **scales8)
+    da.paged_verify_attention(_rand(1, (S, T, H, D)), k, v, tables, lengths,
+                              interpret=True)
+    da.paged_chunk_attention(_rand(1, (BS, H, D)), k, v, tables[1],
+                             jnp.int32(BS), interpret=True)
+
+    def got(kernel, nbytes):
+        return get_registry().gauge(
+            "paged_decode_entries_per_iteration", labels={
+                "kernel": kernel, "slab_bytes": str(nbytes)}).value
+    fp, int8 = BS * KH * 2 * D * 4, BS * KH * 2 * D
+    assert got("paged_decode_attention", fp) == 4
+    assert got("paged_decode_attention", int8) == 1
+    assert got("paged_verify_attention", fp) == 1
+    assert got("paged_chunk_attention", fp) == 1
+    with pytest.raises(ValueError, match="table entries an iteration"):
+        da._paged_attention(
+            _rand(1, (S, KH, T * H // KH, D)), k, v, tables, lengths,
+            rep=H // KH, scale=None, interpret=True, name="x", entries=2)
 
 
 def test_decode_kernel_refuses_what_it_cannot_carry():

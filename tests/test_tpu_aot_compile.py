@@ -198,6 +198,44 @@ def _window_decode():
                 ((g["slots"],), jnp.int32)]
 
 
+def _one_token_walk(slots, heads, kv_heads, D, Dv, blocks, table,
+                    window=0, sink=False):
+    """The one-token decode kernel at a cell's own geometry: q, the last
+    of three layers' pools ``[L, NB, BS, KH*D]`` / ``[.., KH*Dv]`` (a
+    window layer's rings, ``table`` blocks a slot), table (a pool's),
+    lengths[, sink]."""
+    if window:
+        kernel = functools.partial(da.paged_window_decode_attention,
+                                   window=window)
+        blocks, tables = slots * table, []
+    else:
+        kernel, tables = da.paged_decode_attention, [
+            ((slots, table), jnp.int32)]
+
+    def fn(q, k, v, *rest):
+        rest, sinks = (rest[:-1], rest[-1]) if sink else (rest, None)
+        return kernel(q, k, v, *rest, interpret=False, layer=2, sink=sinks)
+    return fn, [((slots, heads, D), BF16),
+                ((3, blocks, BS, kv_heads * D), BF16),
+                ((3, blocks, BS, kv_heads * Dv), BF16), *tables,
+                ((slots,), jnp.int32),
+                *([((heads,), jnp.float32)] if sink else [])]
+
+
+# the cells whose one-token walk attends several table entries a loop
+# iteration: the kernel's name, K + V bytes a table entry, the entries
+# the rule gives them (``paged_decode_entries_per_iteration``)
+WALKS = {
+    "paged_decode-mimo-full": ("paged_decode_attention", 327680, 3),
+    "paged_window_decode-mimo-ring": ("paged_window_decode_attention",
+                                      655360, 2),
+    "paged_decode-laguna-full": ("paged_decode_attention", 524288, 2),
+    "paged_window_decode-ring": ("paged_window_decode_attention", 524288,
+                                 2),
+    "paged_decode-fp": ("paged_decode_attention", 1048576, 1),
+}
+
+
 def _flash_window():
     """The windowed flash forward at the cell's longest bucket."""
     g = LAGUNA
@@ -232,6 +270,12 @@ CASES = {
     "grouped-matmul-longcat-rider": functools.partial(
         _grouped_matmul, 256, 16, 6144, 2048),
     "paged_window_decode-ring": _window_decode,
+    "paged_decode-mimo-full": functools.partial(
+        _one_token_walk, 96, 64, 4, 192, 128, 5401, 96),
+    "paged_window_decode-mimo-ring": functools.partial(
+        _one_token_walk, 96, 64, 8, 192, 128, 0, 2, window=128, sink=True),
+    "paged_decode-laguna-full": functools.partial(
+        _one_token_walk, 96, 48, 8, 128, 128, 3201, 80),
     "flash-window-fwd": _flash_window,
     "latent-decode": _latent_decode,
     "latent-chunk": _latent_chunk,
@@ -271,10 +315,24 @@ def test_kernel_compiles_for_v5e(case, chips, monkeypatch):
     one = SingleDeviceSharding(chips[0])
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one)
             for shape, dtype in shapes]
+    da._paged_call.cache_clear()
     compiled = jax.jit(fn, donate_argnums=getattr(fn, "donate", ())
                        ).lower(*args).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
+    if case in WALKS:
+        # the walk the rule gave these shapes, under the kernel's own
+        # name, inside the scoped VMEM every kernel gets (a call that
+        # asks for more is written on every instruction)
+        from deepspeed_tpu.telemetry import compile_watch
+        from deepspeed_tpu.telemetry.registry import get_registry
+        name, slab, entries = WALKS[case]
+        assert get_registry().gauge(
+            "paged_decode_entries_per_iteration", labels={
+                "kernel": name, "slab_bytes": str(slab)}).value == entries
+        assert set(compile_watch.parse_scopes(text)[1].values()) == {name}
+        assert set(re.findall(r'"scoped_memory_configs":\[([^\]]*)\]',
+                              text)) == {""}
     if case.startswith("grouped-matmul-"):
         # both matmuls are the small-tile kernel, and neither asked for
         # more than the default scoped VMEM (a call that does re-lays the
@@ -1374,9 +1432,13 @@ def test_this_libtpu_knows_the_remat_limit_option(chips):
 # rule for heads of 192 lanes, and the flash forward a value width and a
 # sink; with ``D_v == D`` and no sink every accepted program traces to
 # the text it did. A PR that MEANS to change one of these programs
-# replaces its line here and says so in CHANGES.md.
+# replaces its line here and says so in CHANGES.md: PR 57 replaced
+# ``laguna-decode`` (c2da6142f4089a74, 9654 lines: its two one-token
+# walks attend two table entries an iteration, a product a head, so a
+# kernel body holds a whole group and a group of one; the three
+# ``gpt2-*`` programs walk an entry an iteration, as they did).
 ACCEPTED_PROGRAMS = {
-    "laguna-decode": ("c2da6142f4089a74", 9654, 9),
+    "laguna-decode": ("90e462daa2cb3975", 21558, 9),
     "laguna-prefill": ("27010f6634f018c7", 7922, 8),
     "gpt2-decode": ("3141ca124377a618", 8987, 24),
     "gpt2-decode-int8": ("3bf89725c010ea29", 12107, 24),
