@@ -242,6 +242,12 @@ class PagedKVCache:
     no block of the pool and no entry of the block tables, a prefill
     writes one slot of a donated buffer in place, and a retired slot's
     state is overwritten by the next prefill and never read.
+
+    Layers that keep NOTHING (``layer_map`` kind ``"none"``): a layer
+    with no mixer over the sequence (an expert layer or an MLP that is a
+    layer of its own) has a place in the map and none in the cache: no
+    slab of the pool, no ring, no state. The pool's ``L`` counts the
+    ``"full"`` layers only.
     aux: an int32 array that belongs to the MODEL, as
     :class:`LatentPagedCache`'s (None: the model counts nothing)."""
     k: jnp.ndarray             # [L, NB, BS, KH*D] (fp or int8)
@@ -333,8 +339,10 @@ def window_layer_map(window_layers) -> tuple:
 def kind_layer_map(kinds) -> tuple:
     """Model layer -> ``(kind, index among the layers of its kind)`` from
     a kind a layer: ``"full"`` (blocks of the pool), ``"window"`` (a
-    ring a slot) or ``"state"`` (a recurrent state a slot)."""
-    counts = {"full": 0, "window": 0, "state": 0}
+    ring a slot), ``"state"`` (a recurrent state a slot) or ``"none"`` (a
+    layer that keeps nothing: its index counts such layers and names no
+    buffer)."""
+    counts = {"full": 0, "window": 0, "state": 0, "none": 0}
     out = []
     for kind in kinds:
         out.append((kind, counts[kind]))
@@ -354,7 +362,9 @@ def init_paged_cache(num_layers: int, num_slots: int, num_blocks: int,
                      state_shapes: Optional[tuple] = None,
                      state_dtype=jnp.float32,
                      v_head_dim: Optional[int] = None,
-                     ring_kv_heads: Optional[int] = None) -> PagedKVCache:
+                     ring_kv_heads: Optional[int] = None,
+                     cacheless_layers: Optional[tuple] = None
+                     ) -> PagedKVCache:
     """``num_blocks`` INCLUDES the reserved null block 0, so the usable
     pool is ``num_blocks - 1`` blocks. ``quantized=True`` builds the
     int8 pool (payload dtype int8 regardless of ``dtype``) with
@@ -375,7 +385,11 @@ def init_paged_cache(num_layers: int, num_slots: int, num_blocks: int,
     not a key head's; V's rows, in the pool and in the rings, are then
     ``heads * v_head_dim`` wide beside K's ``heads * head_dim``.
     ``ring_kv_heads`` (None: ``num_kv_heads``): the window layers' own
-    key/value head count."""
+    key/value head count.
+
+    ``cacheless_layers`` (a bool a model layer): the layers marked true
+    keep nothing (kind ``"none"`` in the map); the pool holds a slab for
+    each of the others that is no window or state layer."""
     layer_map = rings = states = None
     v_dim = head_dim if v_head_dim is None else v_head_dim
     if quantized and v_dim != head_dim:
@@ -388,7 +402,8 @@ def init_paged_cache(num_layers: int, num_slots: int, num_blocks: int,
         raise ValueError("ring_kv_heads without window layers")
     n_window = sum(window_layers) if window_layers is not None else 0
     n_state = sum(state_layers) if state_layers is not None else 0
-    if n_window or n_state:
+    n_none = sum(cacheless_layers) if cacheless_layers is not None else 0
+    if n_window or n_state or n_none:
         if quantized:
             raise NotImplementedError(
                 "window layers' rings and state layers' states have no "
@@ -396,16 +411,24 @@ def init_paged_cache(num_layers: int, num_slots: int, num_blocks: int,
         if n_window and n_state:
             raise NotImplementedError("window layers' rings beside state "
                                       "layers' states")
-        marked = window_layers if n_window else state_layers
-        if len(marked) != num_layers:
-            raise ValueError(f"{len(marked)} layer kinds for "
+        marked = (window_layers if n_window else state_layers if n_state
+                  else (False,) * num_layers)
+        nothing = cacheless_layers or (False,) * num_layers
+        if len(marked) != num_layers or len(nothing) != num_layers:
+            raise ValueError(f"{len(marked)} layer kinds and "
+                             f"{len(nothing)} cacheless marks for "
                              f"{num_layers} layers")
+        if any(m and c for m, c in zip(marked, nothing)):
+            raise ValueError("a layer marked cacheless keeps a ring or a "
+                             "state")
         beside = "window" if n_window else "state"
-        layer_map = kind_layer_map(beside if m else "full" for m in marked)
+        layer_map = kind_layer_map(
+            "none" if c else beside if m else "full"
+            for m, c in zip(marked, nothing))
         # a model of window (or state) layers only still has a
         # (one-layer) pool: the block tables and the null block stay
         # what they are
-        num_layers = max(num_layers - n_window - n_state, 1)
+        num_layers = max(num_layers - n_window - n_state - n_none, 1)
     if n_window:
         rings = (n_window,
                  num_slots * ring_blocks_for(window, block_size),

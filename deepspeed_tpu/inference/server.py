@@ -1277,7 +1277,8 @@ class ContinuousBatchingServer:
             state_shapes=getattr(mcfg, "state_shapes", None),
             state_dtype=getattr(mcfg, "state_dtype", jnp.float32),
             v_head_dim=getattr(mcfg, "v_head_dim", None),
-            ring_kv_heads=getattr(mcfg, "ring_kv_heads", None))
+            ring_kv_heads=getattr(mcfg, "ring_kv_heads", None),
+            cacheless_layers=getattr(mcfg, "cacheless_layers", None))
         if cache.state is not None:
             self.telemetry.gauge(
                 "serve_kv_state_bytes",

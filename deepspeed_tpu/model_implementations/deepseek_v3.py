@@ -302,9 +302,8 @@ def router_bias(cfg: "DeepseekV3Config") -> jax.Array:
     """The seeded selection bias ``[n_routed_experts]`` float32: every
     aligned group of 16 experts carries the same evenly spaced, centred
     set."""
-    i = jnp.arange(cfg.n_routed_experts)
-    spread = 2.0 * ((7 * i) % 16 + 0.5) / 16.0 - 1.0
-    return (INIT_SCALES["router_bias_spread"] * spread).astype(F32)
+    return _held.spread_selection_bias(cfg.n_routed_experts,
+                                       INIT_SCALES["router_bias_spread"])
 
 
 def _gains(key, shape, sd):
@@ -437,15 +436,9 @@ def _route(u, moe, cfg: DeepseekV3Config):
     bias moves the selection (of groups and of experts) and never the
     weights; the weights are the picked scores normalised to sum to the
     scaling factor."""
-    scores = jax.nn.sigmoid(jnp.dot(
-        u.astype(F32), moe["router"].astype(F32),
-        precision=jax.lax.Precision.HIGHEST))
-    choice = scores + moe["router_bias"].astype(F32)
-    k = cfg.num_experts_per_tok
-    picks = _held.group_limited_top_k(choice, k, cfg.n_group, cfg.topk_group)
-    picked = jnp.take_along_axis(scores, picks, axis=-1)
-    return picks, cfg.routed_scaling_factor * picked / (
-        jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+    return _held.sigmoid_route(
+        u, moe["router"], moe["router_bias"], cfg.num_experts_per_tok,
+        cfg.n_group, cfg.topk_group, cfg.routed_scaling_factor)
 
 
 def moe_layer(u, moe, cfg: DeepseekV3Config, valid):

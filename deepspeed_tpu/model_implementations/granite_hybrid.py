@@ -15,13 +15,14 @@ run over ONE ``kv_cache.PagedKVCache`` whose ``layer_map`` holds both:
   ``attention_multiplier`` (1/128 at the published sizes, not 1 /
   sqrt(128)). They keep block tables over the shared pool
   (``PagedKVCache.k`` / ``v``) and decode through the paged kernel.
-* **Mamba layers** (``mamba``) are Mamba-2 mixers (Dao & Gu 2024,
-  arXiv:2405.21060): a short causal depthwise convolution, then a
-  recurrence with one scalar decay a head a token over a float32 state
-  ``S [heads, head_dim, d_state]`` a slot (``PagedKVCache.state``) and the
-  convolution's last inputs (``PagedKVCache.conv``). A state is a fixed
-  cost a slot whatever the context: no block, no table entry. Prefill
-  runs the chunked (SSD) form inside one program; decode the recurrence.
+* **Mamba layers** (``mamba``) are Mamba-2 mixers (``mamba2.py``, shared
+  with the other state-space hybrid; Dao & Gu 2024, arXiv:2405.21060): a
+  short causal depthwise convolution, then a recurrence with one scalar
+  decay a head a token over a float32 state ``S [heads, head_dim,
+  d_state]`` a slot (``PagedKVCache.state``) and the convolution's last
+  inputs (``PagedKVCache.conv``). A state is a fixed cost a slot whatever
+  the context: no block, no table entry. Prefill runs the chunked (SSD)
+  form inside one program; decode the recurrence.
 * **every layer's FFN** is an expert layer: float32 router logits over
   ALL experts, the ``k`` largest, a softmax over THOSE ``k`` (not over
   all), the held experts' part through ``held_experts.py`` (picks on
@@ -37,11 +38,12 @@ One layer (``N`` RMSNorm, ``r`` ``residual_multiplier``)::
     Mix, Mamba-2 (h = N_in(x)):
       z = h W_z [Di]   xBC = h W_xBC [Di + 2 N]   dt = h W_dt [H]
       xBC <- silu(conv_k(xBC) + b)          causal, depthwise, k taps
-      [x | B | C] = xBC                     x [H, P];  B, C [N], one group
+      [x | B | C] = xBC                     x [H, P];  B, C [G, N]; the
+                                            published sizes have one group
       dt <- softplus(dt + dt_bias)          a = exp(dt A),  A = -exp(A_log)
-      S_t[h] = a_t[h] S_{t-1}[h] + dt_t[h] x_t[h] (x) B_t
-      y_t[h] = S_t[h] C_t + D[h] x_t[h]
-      Mix = (N_Di(y silu(z)) g) W_out       the gate BEFORE the norm
+      S_t[h] = a_t[h] S_{t-1}[h] + dt_t[h] x_t[h] (x) B_t[g(h)]
+      y_t[h] = S_t[h] C_t[g(h)] + D[h] x_t[h]
+      Mix = (N_{Di/G}(y silu(z)) g) W_out   the gate BEFORE the norm
     Mix, attention: softmax(q k^T m_attn + causal) v W_o
 
 around it ``x0 = embedding_multiplier * Emb(ids)`` and ``logits =
@@ -54,19 +56,18 @@ What the published ``config.json`` does not state and is assumed here
 ``in_proj`` is cut ``[z | xBC | dt]`` (three matrices here, so that
 ``dt`` leaves its matmul in float32); ``dt`` has no upper clamp
 (``time_step_limit`` ``(0, inf)``); the gate is applied before the
-grouped norm, with one group; no bias but the convolution's. Out of
-scope: chunked prefill, prefix reuse, speculation, int8 rows and a host
-tier (refused by the server by switch name: a state has no rows),
-several B/C groups, training.
+grouped norm (``mamba_n_groups`` groups of B, C and the norm: one at the
+published sizes, any divisor of the heads runs); no bias but the
+convolution's. Out of scope: chunked prefill, prefix reuse, speculation,
+int8 rows and a host tier (refused by the server by switch name: a state
+has no rows), training.
 
 Parameter schema::
 
     wte [V, E]   norm_f [E]
     layers: list of
       norm_in [E]  norm_post [E]
-      mamba {w_z [E, Di]  w_xbc [E, Di + 2 N]  w_dt [E, H]     Mamba layers
-             conv_w [k, Di + 2 N]  conv_b [Di + 2 N]
-             dt_bias [H]  A_log [H]  D [H]  norm [Di]  w_out [Di, E]}
+      mamba {mamba2.py's schema}                               Mamba layers
       attn {wq [E, Hq, d]  wk [E, KH, d]  wv [E, KH, d]        attention
             wo [Hq, d, E]}                                     layers
       moe {router [E, n_experts]
@@ -89,64 +90,22 @@ from deepspeed_tpu.inference.kv_cache import (PagedKVCache, kind_layer_map,
                                               paged_write_prompt,
                                               with_state_layer)
 from deepspeed_tpu.model_implementations import held_experts as _held
-from deepspeed_tpu.ops.pallas import decode_attention as _kernels
-from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+from deepspeed_tpu.model_implementations import mamba2 as _mamba
+from deepspeed_tpu.model_implementations import nope_attention as _attn
+# the mixer's parts under the names they had here (the family's tests
+# reach them through this module)
+from deepspeed_tpu.model_implementations.mamba2 import (  # noqa: F401
+    decay_rates as _decay_rates, mixer_out as _mamba_out,
+    scan_sequence as _scan_sequence, state_token as _state_token)
 from deepspeed_tpu.profiling.trace import scoped
-from deepspeed_tpu.telemetry.registry import ScaledCounter
 
 F32 = jnp.float32
-NEG_INF = -1e30
 MAMBA, ATTENTION = "mamba", "attention"
 
-# what this model keeps in PagedKVCache.aux, ``[program, column]``: the
-# expert layer's routing row (held_experts.COUNTER_TAIL after the picks
-# on each held expert), then these. A state PASS is one slot's state and
-# convolution tail of one layer, read and written by decode, written by
-# prefill; a K/V row is one position of one attention layer, K and V
-PROGRAMS = ("decode", "prefill")
-COUNTERS = ("calls", "live_slots", "state_passes", "kv_rows_read",
-            "prefill_tokens", "prefill_chunks")
-
-
-def aux_series(cfg: "GraniteHybridConfig", reg) -> list:
-    """The registry counter behind each cell of this model's
-    ``cache.aux`` (docs/observability.md "State layers beside attention
-    layers"), ``[program][column]``. The device counts state PASSES; the
-    series is bytes, so a reader need not know the layout."""
-    out = _held.counter_series(reg, cfg.num_held, PROGRAMS)
-    for program, series in zip(PROGRAMS, out):
-        by = {"program": program}
-        named = {
-            "calls": reg.counter(
-                "serve_hybrid_steps_total", labels=by,
-                help="executions of a state + attention hybrid's program"),
-            "live_slots": reg.counter(
-                "serve_hybrid_live_slots_total", labels=by,
-                help="live slots summed over decode steps (the sequences "
-                     "whose states a step updated)"),
-            "state_passes": ScaledCounter(reg.counter(
-                "serve_hybrid_state_bytes_total", labels=by,
-                help="state layers' bytes moved: live slots x state layers "
-                     "x one slot-layer's state and convolution tail, read "
-                     "and written by decode, written by prefill"),
-                cfg.state_bytes * (2 if program == "decode" else 1)),
-            "kv_rows_read": reg.counter(
-                "serve_kv_rows_read_total",
-                labels={"program": program, "kind": "full"},
-                help="cache rows (one position of one layer, K and V) a "
-                     "decode step had to read, by layer kind: a live "
-                     "slot's whole context a full layer, min(context, "
-                     "window) a window layer"),
-            "prefill_tokens": reg.counter(
-                "serve_hybrid_prefill_tokens_total", labels=by,
-                help="live prompt tokens run through the chunked form"),
-            "prefill_chunks": reg.counter(
-                "serve_hybrid_prefill_chunks_total", labels=by,
-                help="chunks of the chunked form that held a live token, "
-                     "summed over state layers"),
-        }
-        series.extend(named[name] for name in COUNTERS)
-    return out
+# what this model keeps in ``PagedKVCache.aux``: a state + attention
+# hybrid's counters (``mamba2.py``)
+PROGRAMS, COUNTERS, aux_series = (_mamba.PROGRAMS, _mamba.COUNTERS,
+                                  _mamba.aux_series)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -204,10 +163,11 @@ class GraniteHybridConfig:
                 f"{self.num_key_value_heads} key/value heads")
         if self.hidden_size % self.num_attention_heads:
             raise ValueError("hidden_size is not a whole number of heads")
-        if self.mamba_n_groups != 1:
-            raise NotImplementedError(
-                f"mamba_n_groups {self.mamba_n_groups}: B and C are shared "
-                "by all heads here (one group)")
+        if (self.mamba_n_heads % self.mamba_n_groups
+                or self.d_inner % self.mamba_n_groups):
+            raise ValueError(
+                f"{self.mamba_n_heads} Mamba heads do not split into "
+                f"mamba_n_groups = {self.mamba_n_groups} groups of B and C")
         if self.mamba_n_heads * self.mamba_d_head != self.d_inner:
             raise ValueError(
                 f"mamba_n_heads x mamba_d_head = "
@@ -257,8 +217,7 @@ class GraniteHybridConfig:
 
     @property
     def conv_channels(self) -> int:
-        """``[x | B | C]``: what the short convolution runs over."""
-        return self.d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
+        return _mamba.conv_channels(self)
 
     @property
     def state_layers(self) -> Tuple[bool, ...]:
@@ -267,22 +226,16 @@ class GraniteHybridConfig:
 
     @property
     def state_shapes(self) -> Tuple[tuple, tuple]:
-        """One slot's state of one layer, and its convolution tail's
-        ``(taps, channels)``."""
-        return ((self.mamba_n_heads, self.mamba_d_head, self.mamba_d_state),
-                (self.mamba_d_conv - 1, self.conv_channels))
+        return _mamba.state_shapes(self)
 
     @property
     def state_bytes(self) -> int:
         """One slot's state and convolution tail of one layer."""
-        s_shape, conv_shape = self.state_shapes
-        return (math.prod(s_shape) * jnp.dtype(self.state_dtype).itemsize
-                + math.prod(conv_shape) * jnp.dtype(self.dtype).itemsize)
+        return _mamba.state_bytes(self)
 
     @property
     def aux_shape(self) -> Tuple[int, int]:
-        return (len(PROGRAMS), self.num_held + len(_held.COUNTER_TAIL)
-                + len(COUNTERS))
+        return _mamba.aux_shape(self)
 
     @property
     def layer_map(self) -> tuple:
@@ -293,10 +246,8 @@ class GraniteHybridConfig:
 # ---------------------------------------------------------------- params
 
 # Seeded-weight scales (no checkpoint is loaded in tests or the
-# benchmark). Matrices are N(0, 1 / fan_in), norm gains 1, and the
-# Mamba-2 reference initialisation: ``A_log = log U[1, 16]``, ``dt_bias``
-# the inverse softplus of a log-uniform ``[dt_min, dt_max]``, ``D = 1``,
-# convolution taps U(-1, 1) / sqrt(k). These depart from that, so that
+# benchmark). Matrices are N(0, 1 / fan_in), norm gains 1, the mixers'
+# own draws ``mamba2.init_mixer``'s. These depart from that, so that
 # the benchmark's check against the float32 reference bites while the
 # bfloat16 program stays inside it (PERF.md section 6, PR 50, has the
 # readings behind each):
@@ -309,16 +260,6 @@ class GraniteHybridConfig:
 #   this RMS, a thirtieth of what twenty residual branches add, and its
 #   own logit stays inside the others' spread; the final norm's gain
 #   brings the logits back to a standard deviation of ~1;
-# * ``a_global``: a head remembers ``1 / (dt |A|)`` tokens, 0.6 to 1000
-#   under the reference initialisation (median 14): every head would be
-#   local, and a state kept in bfloat16 would only add unbiased noise
-#   that a local head forgets. The second half of a layer's heads are
-#   GLOBAL, ``|A|`` log-uniform over ``a_global`` (memories of hundreds to
-#   tens of thousands of tokens, as a model served at 131072 positions
-#   has): there ``(1 - a) S`` is under half a bfloat16 step, so a
-#   bfloat16 state stops decaying and keeps only its largest inputs,
-#   which is where the state's precision is decided (the retention
-#   family's lesson, PR 34);
 # * ``attn_out_x``: a softmax over n random keys averages its values to
 #   ~sqrt(exp(var) / n) of one; ``W_o`` is scaled so that the attention
 #   layer stays a visible share of the stream at the cell's contexts (a
@@ -337,21 +278,7 @@ class GraniteHybridConfig:
 #   not see them; 8-bit weights lose the small channels).
 INIT_SCALES = {"embedding_rms": 1.0 / 32, "final_norm_gain": 96.0,
                "attn_out_x": 12.0, "attn_logit_x": 16.0, "router_std": 1.5,
-               "expert_out_x": 2.0, "ffn_gain_sd": 2.0,
-               "dt_min": 1e-3, "dt_max": 1e-1, "a_local": (1.0, 16.0),
-               "a_global": (2.0 ** -9, 2.0 ** -3)}
-
-
-def _decay_rates(key, H: int):
-    """``|A| [H]``: the first half of the heads uniform over ``a_local``
-    (the reference initialisation), the second half log-uniform over
-    ``a_global``."""
-    k0, k1 = jax.random.split(key)
-    lo, hi = INIT_SCALES["a_global"]
-    return jnp.concatenate([
-        jax.random.uniform(k0, (H // 2,), F32, *INIT_SCALES["a_local"]),
-        jnp.exp(jax.random.uniform(k1, (H - H // 2,), F32, math.log(lo),
-                                   math.log(hi)))])
+               "expert_out_x": 2.0, "ffn_gain_sd": 2.0}
 
 
 def _dense(key, shape, fan_in, dt, times=1.0):
@@ -372,29 +299,6 @@ def _swiglu(key, lead, d_in, d_hidden, dt, out_x=1.0):
     return {"w_in": (w_in / math.sqrt(d_in)).astype(dt),
             "w_out": (w_out * (out_x / math.sqrt(d_hidden))
                       / c[..., None]).astype(dt)}
-
-
-def _init_mamba(key, cfg: "GraniteHybridConfig") -> Dict:
-    E, Di, C, H = (cfg.hidden_size, cfg.d_inner, cfg.conv_channels,
-                   cfg.mamba_n_heads)
-    dt, s = cfg.dtype, INIT_SCALES
-    k = jax.random.split(key, 7)
-    step = jnp.exp(jax.random.uniform(k[4], (H,), F32)
-                   * (math.log(s["dt_max"]) - math.log(s["dt_min"]))
-                   + math.log(s["dt_min"]))
-    return {
-        "w_z": _dense(k[0], (E, Di), E, dt),
-        "w_xbc": _dense(k[1], (E, C), E, dt),
-        "w_dt": _dense(k[2], (E, H), E, dt),
-        "conv_w": (jax.random.uniform(k[3], (cfg.mamba_d_conv, C), F32, -1.0,
-                                      1.0) / math.sqrt(cfg.mamba_d_conv)),
-        "conv_b": jnp.zeros((C,), F32),
-        # softplus(dt_bias) = step
-        "dt_bias": step + jnp.log(-jnp.expm1(-step)),
-        "A_log": jnp.log(_decay_rates(k[5], H)),
-        "D": jnp.ones((H,), F32),
-        "norm": jnp.ones((Di,), dt),
-        "w_out": _dense(k[6], (Di, E), Di, dt)}
 
 
 def _init_attention(key, cfg: "GraniteHybridConfig") -> Dict:
@@ -422,7 +326,7 @@ def _init_layer(key, cfg: "GraniteHybridConfig", kind: str) -> Dict:
                  "shared": _swiglu(k[3], (), E,
                                    cfg.shared_intermediate_size, dt)}}
     if kind == MAMBA:
-        layer["mamba"] = _init_mamba(k[0], cfg)
+        layer["mamba"] = _mamba.init_mixer(k[0], cfg)
     else:
         layer["attn"] = _init_attention(k[0], cfg)
     return layer
@@ -476,189 +380,6 @@ def _swiglu_ffn(x, f):
 
 
 _shared_mlp = scoped("moe_shared")(_swiglu_ffn)
-
-
-# ------------------------------------------------------------ Mamba mixer
-
-@scoped("mamba_in")
-def _mamba_in(h, m):
-    """``h [..., E]`` -> ``z [..., Di]``, ``xBC [..., C]`` (the
-    activations' type) and the raw ``dt [..., H]`` float32."""
-    dt = h.dtype
-    return (h @ m["w_z"].astype(dt), h @ m["w_xbc"].astype(dt),
-            jnp.dot(h, m["w_dt"].astype(dt), preferred_element_type=F32))
-
-
-def _split(xbc, raw_dt, m, cfg: "GraniteHybridConfig"):
-    """The convolved ``xBC [..., C]`` float32 and the raw ``dt`` -> ``x
-    [..., H, P]``, ``B`` / ``C [..., N]``, the step ``dt [..., H]`` and
-    ``A [H]`` (negative), all float32."""
-    Di, N = cfg.d_inner, cfg.mamba_d_state
-    x = xbc[..., :Di].reshape(*xbc.shape[:-1], cfg.mamba_n_heads,
-                              cfg.mamba_d_head)
-    return (x, xbc[..., Di:Di + N], xbc[..., Di + N:],
-            jax.nn.softplus(raw_dt + m["dt_bias"].astype(F32)),
-            -jnp.exp(m["A_log"].astype(F32)))
-
-
-@scoped("mamba_conv")
-def _conv_sequence(xbc, m, length):
-    """The causal depthwise convolution over one sequence ``xbc [T, C]``
-    -> (``silu(conv + b) [T, C]`` float32, the tail ``[k - 1, C]``: the
-    inputs at positions ``length - k + 1 .. length - 1``, zeros before
-    position 0, so a bucket's padding never reaches it)."""
-    w = m["conv_w"].astype(F32)                          # [k, C]
-    k, T = w.shape[0], xbc.shape[0]
-    xf = xbc.astype(F32)
-    padded = jnp.concatenate([jnp.zeros((k - 1, xf.shape[1]), F32), xf])
-    out = m["conv_b"].astype(F32) + sum(
-        w[j] * padded[j:j + T] for j in range(k))
-    # padded row i holds position i - (k - 1): the tail starts at
-    # position length - (k - 1), which is padded row ``length``
-    tail = jax.lax.dynamic_slice_in_dim(padded, length, k - 1, 0)
-    return jax.nn.silu(out), tail.astype(xbc.dtype)
-
-
-@scoped("mamba_conv")
-def _conv_token(xbc, tail, m):
-    """One token a slot: ``xbc [S, C]`` after the tail ``[k - 1, S, C]``
-    -> (``silu(conv + b) [S, C]`` float32, the shifted tail)."""
-    w = m["conv_w"].astype(F32)
-    window = jnp.concatenate([tail, xbc[None].astype(tail.dtype)])
-    out = m["conv_b"].astype(F32) + jnp.sum(
-        w[:, None, :] * window.astype(F32), axis=0)
-    return jax.nn.silu(out), window[1:]
-
-
-@scoped("mamba_scan")
-def _scan_sequence(x, B, C, dt, A, D, length, chunk: int, mm):
-    """The chunked (SSD) form of the recurrence over one sequence from a
-    zero state: ``x [T, H, P]``, ``B`` / ``C [T, N]``, ``dt [T, H]``,
-    all float32 -> (``y [T, H, P]``, the state after ``length`` tokens
-    ``[H, P, N]``). Positions past ``length`` get ``dt = 0``: they
-    neither decay nor feed the state. Decays, their sums and the carried
-    state are float32; the matmuls take their operands in ``mm`` (the
-    activations' type) and accumulate in float32."""
-    T, H, P = x.shape
-    L = min(chunk, T)
-    nc = -(-T // L)
-    dt = jnp.where((jnp.arange(T) < length)[:, None], dt, 0.0)
-    if nc * L != T:     # a last chunk of dt = 0 rows (no cell's bucket)
-        pad = lambda a: jnp.pad(a, ((0, nc * L - T),) + ((0, 0),)
-                                * (a.ndim - 1))
-        x_, B, C, dt = pad(x), pad(B), pad(C), pad(dt)
-    else:
-        x_ = x
-    dtx = (dt[..., None] * x_).reshape(nc, L, H, P)
-    # the decay's log summed inside a chunk, position ``l`` included
-    cum = jnp.cumsum((dt * A).reshape(nc, L, H), axis=1)
-    Bc, Cc = B.reshape(nc, L, -1), C.reshape(nc, L, -1)
-    causal = jnp.arange(L)[:, None] >= jnp.arange(L)[None]
-    dot = functools.partial(jnp.einsum, preferred_element_type=F32)
-
-    def one(S, c):
-        dtx_c, cum_c, B_c, C_c = c
-        # inside the chunk: (L o (C B^T)) (dt x), L_ij = exp(sum_{j<k<=i})
-        decay = jnp.exp(jnp.where(
-            causal[None], cum_c.T[:, :, None] - cum_c.T[:, None, :],
-            -jnp.inf))                                       # [H, L, L]
-        scores = dot("ln,sn->ls", C_c.astype(mm), B_c.astype(mm))
-        y = dot("hls,shp->lhp", (decay * scores[None]).astype(mm),
-                dtx_c.astype(mm))
-        # what the chunks before left: C S_prev, decayed to each position
-        y = y + dot("ln,hpn->lhp", C_c.astype(mm),
-                    S.astype(mm)) * jnp.exp(cum_c)[..., None]
-        # the state at the chunk's end
-        keep = jnp.exp(cum_c[-1][None] - cum_c)              # [L, H]
-        S = (jnp.exp(cum_c[-1])[:, None, None] * S
-             + dot("lhp,ln->hpn", (dtx_c * keep[..., None]).astype(mm),
-                   B_c.astype(mm)))
-        return S, y
-
-    S, y = jax.lax.scan(one, jnp.zeros((H, P, B.shape[-1]), F32),
-                        (dtx, cum, Bc, Cc))
-    return y.reshape(nc * L, H, P)[:T] + D[:, None] * x, S
-
-
-@scoped("mamba_state")
-def _state_token(x, B, C, dt, A, D, active, S):
-    """The recurrence's one step for every slot: ``x [S, H, P]``, ``B`` /
-    ``C [S, N]``, ``dt [S, H]`` float32 over the pool ``S [slots, H, P,
-    N]`` -> (``y [S, H, P]``, the pool). An idle slot's ``dt`` is 0: its
-    state is neither decayed nor fed."""
-    dt = jnp.where(active[:, None], dt, 0.0)
-    a = jnp.exp(dt * A)
-    S = (a[..., None, None] * S.astype(F32)
-         + (dt[..., None] * x)[..., None] * B[:, None, None, :])
-    y = jnp.einsum("shpn,sn->shp", S, C) + D[:, None] * x
-    return y, S
-
-
-@scoped("mamba_out")
-def _mamba_out(y, z, m, cfg: "GraniteHybridConfig"):
-    """``y [..., H, P]`` float32 gated by ``z [..., Di]`` BEFORE the norm
-    over all ``Di`` channels (one group), through ``W_out``."""
-    g = y.reshape(*y.shape[:-2], -1) * jax.nn.silu(z.astype(F32))
-    g = g * jax.lax.rsqrt(jnp.mean(g * g, -1, keepdims=True)
-                          + cfg.rms_norm_eps)
-    g = (g * m["norm"].astype(F32)).astype(z.dtype)
-    return g @ m["w_out"].astype(z.dtype)
-
-
-def _mamba_sequence(h, m, cfg: "GraniteHybridConfig", length):
-    """The mixer over one sequence ``h [T, E]`` -> (``[T, E]``, the final
-    state ``[H, P, N]``, the convolution tail ``[k - 1, C]``)."""
-    z, xbc, raw_dt = _mamba_in(h, m)
-    xbc, tail = _conv_sequence(xbc, m, length)
-    x, B, C, dt, A = _split(xbc, raw_dt, m, cfg)
-    y, S = _scan_sequence(x, B, C, dt, A, m["D"].astype(F32), length,
-                          cfg.mamba_chunk_size, h.dtype)
-    return _mamba_out(y, z, m, cfg), S, tail
-
-
-# -------------------------------------------------------------- attention
-
-def _project(h, a):
-    dt = h.dtype
-    return (jnp.einsum("...e,ehd->...hd", h, a["wq"].astype(dt)),
-            jnp.einsum("...e,ehd->...hd", h, a["wk"].astype(dt)),
-            jnp.einsum("...e,ehd->...hd", h, a["wv"].astype(dt)))
-
-
-def _sequence_attention(q, k, v, scale: float):
-    """Causal attention of one sequence against itself, no positional
-    encoding: ``q [T, H, d]``, ``k`` / ``v [T, KH, d]`` -> ``[T, H, d]``.
-    On a TPU the flash kernel; the masked einsum elsewhere and for a
-    prompt the kernel's blocks do not tile."""
-    T, H, d = q.shape
-    if jax.default_backend() == "tpu" and T >= 128 and T % 128 == 0:
-        return flash_attention(q[None], k[None], v[None], causal=True,
-                               scale=scale)[0]
-    rep = H // k.shape[1]
-    s = jnp.einsum("qhd,khd->hqk", q, jnp.repeat(k, rep, axis=1),
-                   preferred_element_type=F32) * scale
-    seen = jnp.arange(T)[None] <= jnp.arange(T)[:, None]
-    p = jax.nn.softmax(jnp.where(seen[None], s, NEG_INF), axis=-1)
-    return jnp.einsum("hqk,khd->qhd", p.astype(v.dtype),
-                      jnp.repeat(v, rep, axis=1))
-
-
-def _token_attention(q, cache: PagedKVCache, i: int, live, scale: float):
-    """One token a slot against attention layer ``i`` of the pool: ``q
-    [S, H, d]`` -> ``[S, H, d]``; ``live [S]`` counts the token just
-    appended. The paged kernel on a TPU; its oracle elsewhere (which
-    scales by 1 / sqrt(d): the query carries the difference)."""
-    if jax.default_backend() == "tpu":
-        return _kernels.paged_decode_attention(
-            q, cache.k, cache.v, cache.block_tables, live, layer=i,
-            scale=scale)
-    q = (q.astype(F32) * (scale * math.sqrt(q.shape[-1]))).astype(q.dtype)
-    return _kernels.paged_decode_attention_reference(
-        q, cache.k[i], cache.v[i], cache.block_tables, live)
-
-
-def _attn_out(a, attn):
-    return jnp.einsum("...hd,hde->...e", a, attn["wo"].astype(a.dtype))
 
 
 # ----------------------------------------------------------- expert layer
@@ -737,16 +458,6 @@ def _logits(params, cfg, x):
                       preferred_element_type=F32) / cfg.logits_scaling
 
 
-def _count(cache: PagedKVCache, program: str, routing, **counts):
-    row = jnp.concatenate([routing, jnp.stack(
-        [jnp.asarray(counts.get(name, 0), jnp.int32) for name in COUNTERS])])
-    return cache.replace(aux=cache.aux.at[PROGRAMS.index(program)].add(row))
-
-
-def _routing_zero(cfg: "GraniteHybridConfig"):
-    return jnp.zeros((cfg.num_held + len(_held.COUNTER_TAIL),), jnp.int32)
-
-
 def _sequence_trunk(params, cfg: "GraniteHybridConfig", ids, length,
                     cache=None, slot=None):
     """Embed -> layers over one right-padded sequence ``ids [T]`` with
@@ -756,11 +467,12 @@ def _sequence_trunk(params, cfg: "GraniteHybridConfig", ids, length,
     stream ``[T, E]``, the cache and the summed routing counters."""
     valid = jnp.arange(ids.shape[0]) < length
     x = _embed(params, cfg, ids)
-    counts = _routing_zero(cfg)
+    counts = _mamba.routing_zero(cfg)
     for layer, (kind, i) in zip(params["layers"], cfg.layer_map):
         h = _rms(x, layer["norm_in"], cfg.rms_norm_eps)
         if kind == "state":
-            mix, S, tail = _mamba_sequence(h, layer["mamba"], cfg, length)
+            mix, S, tail = _mamba.mixer_sequence(h, layer["mamba"], cfg,
+                                                      length)
             if cache is not None:
                 cache = with_state_layer(
                     cache, i,
@@ -772,10 +484,10 @@ def _sequence_trunk(params, cfg: "GraniteHybridConfig", ids, length,
                         slot, 1))
         else:
             with jax.named_scope("attn_full"):
-                q, k, v = _project(h, layer["attn"])
+                q, k, v = _attn.project(h, layer["attn"])
                 if cache is not None:
                     cache = paged_write_prompt(cache, i, k, v, slot)
-                mix = _attn_out(_sequence_attention(
+                mix = _attn.attn_out(_attn.sequence_attention(
                     q, k, v, cfg.attention_multiplier), layer["attn"])
         x = _residual(x, mix, cfg)
         x, counts = _ffn(x, layer, cfg, valid, counts)
@@ -796,9 +508,9 @@ def paged_prefill(params, cfg: "GraniteHybridConfig", input_ids, length,
                                        slot)
     n_state = sum(cfg.state_layers)
     chunk = min(cfg.mamba_chunk_size, input_ids.shape[1])
-    cache = _count(cache, "prefill", counts, calls=1, state_passes=n_state,
-                   prefill_tokens=n,
-                   prefill_chunks=-(-n // chunk) * n_state).replace(
+    cache = _mamba.count(
+        cache, "prefill", counts, calls=1, state_passes=n_state,
+        prefill_tokens=n, prefill_chunks=-(-n // chunk) * n_state).replace(
         lengths=jax.lax.dynamic_update_index_in_dim(cache.lengths, n, slot,
                                                     0))
     last = jax.lax.dynamic_slice_in_dim(x, n - 1, 1, 0)
@@ -817,31 +529,25 @@ def paged_decode_step(params, cfg: "GraniteHybridConfig", tokens,
     are not advanced."""
     live = cache.lengths + 1
     x = _embed(params, cfg, tokens)
-    counts = _routing_zero(cfg)
+    counts = _mamba.routing_zero(cfg)
     for layer, (kind, i) in zip(params["layers"], cfg.layer_map):
         h = _rms(x, layer["norm_in"], cfg.rms_norm_eps)
         if kind == "state":
-            m = layer["mamba"]
-            z, xbc, raw_dt = _mamba_in(h, m)
-            xbc, tail = _conv_token(xbc, cache.conv[i], m)
-            tail = jnp.where(active[None, :, None], tail, cache.conv[i])
-            xs, B, C, dt, A = _split(xbc, raw_dt, m, cfg)
-            y, S = _state_token(xs, B, C, dt, A, m["D"].astype(F32), active,
-                                cache.state[i])
-            cache = with_state_layer(cache, i,
-                                     S.astype(cache.state[i].dtype), tail)
-            mix = _mamba_out(y, z, m, cfg)
+            mix, S, tail = _mamba.mixer_token(
+                h, layer["mamba"], cfg, active, cache.state[i],
+                cache.conv[i])
+            cache = with_state_layer(cache, i, S, tail)
         else:
             with jax.named_scope("attn_full"):
-                q, k, v = _project(h, layer["attn"])
+                q, k, v = _attn.project(h, layer["attn"])
                 cache = paged_append_token(cache, i, k, v)
-                mix = _attn_out(_token_attention(
+                mix = _attn.attn_out(_attn.token_attention(
                     q, cache, i, live, cfg.attention_multiplier),
                     layer["attn"])
         x = _residual(x, mix, cfg)
         x, counts = _ffn(x, layer, cfg, active, counts)
     n_live = jnp.sum(active, dtype=jnp.int32)
-    cache = _count(
+    cache = _mamba.count(
         cache, "decode", counts, calls=1, live_slots=n_live,
         state_passes=n_live * sum(cfg.state_layers),
         kv_rows_read=jnp.sum(jnp.where(active, live, 0))

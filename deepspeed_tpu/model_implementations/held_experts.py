@@ -5,7 +5,9 @@ experts (``held = (lo, hi)``).
 
 The family routes (scores, selection and weights are its own: a softmax
 with zero-compute experts, a sigmoid with normalised weights and a
-shared expert, the same under a group limit: :func:`group_limited_top_k`);
+shared expert, the same under a group limit: :func:`group_limited_top_k`,
+:func:`sigmoid_route`) and names its experts' form (:data:`EXPERT_FORMS`:
+gated SwiGLU, ungated relu squared);
 what is here is everything after the picks: the picks
 that landed on a held expert sorted by expert, a grouped matmul over
 those rows only, the weighted sum back to tokens, and the routing
@@ -96,6 +98,8 @@ def group_limited_top_k(choice, k: int, n_group: int, topk_group: int):
     of its two largest entries, the ``topk_group`` best groups are kept
     and the ``k`` largest entries among THEIR experts are the picks. Ties
     go to the lower group and the lower expert."""
+    if topk_group == n_group:       # every group is kept: no limit
+        return jax.lax.top_k(choice, k)[1]
     T, R = choice.shape
     per = R // n_group
     group_score = jnp.sum(jax.lax.top_k(
@@ -105,6 +109,32 @@ def group_limited_top_k(choice, k: int, n_group: int, topk_group: int):
                    axis=1)                                   # [T, n_group]
     return jax.lax.top_k(jnp.where(jnp.repeat(kept, per, axis=1), choice,
                                    -jnp.inf), k)[1]
+
+
+def spread_selection_bias(n: int, spread: float):
+    """A seeded model's selection bias ``[n]`` float32 that is NOT drawn
+    from the seed: +-``spread``, evenly spaced and centred, the same set
+    in every aligned group of 16 experts (so any contiguous share of
+    whole groups carries the same set)."""
+    i = jnp.arange(n)
+    return (spread * (2.0 * ((7 * i) % 16 + 0.5) / 16.0 - 1.0)).astype(F32)
+
+
+def sigmoid_route(u, router, bias, k: int, n_group: int, topk_group: int,
+                  scale: float):
+    """The sigmoid router with a selection bias (DeepSeek-V3's): ``u [T,
+    E]`` -> picks ``[T, k]`` and their weights ``[T, k]`` float32. Scores
+    are a float32 sigmoid over ALL router outputs; the bias moves the
+    selection (of groups and of experts) and never the weights; the
+    weights are the picked scores normalised to sum to ``scale``."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        u.astype(F32), router.astype(F32),
+        precision=jax.lax.Precision.HIGHEST))
+    picks = group_limited_top_k(scores + bias.astype(F32), k, n_group,
+                                topk_group)
+    picked = jnp.take_along_axis(scores, picks, axis=-1)
+    return picks, scale * picked / (
+        jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
 
 
 @scoped("moe_dispatch")
@@ -143,13 +173,30 @@ def matmul_form(R: int) -> str:
     return "tiled" if R >= MIN_ROW_TILE else "ragged_dot"
 
 
+def swiglu(gu):
+    """``silu(gate) * up`` of ``gu [R, 2 Fe]`` (gate ; up), float32."""
+    Fe = gu.shape[-1] // 2
+    return jax.nn.silu(gu[:, :Fe].astype(F32)) * gu[:, Fe:].astype(F32)
+
+
+def relu2(u):
+    """``relu(u)^2`` of an UNGATED expert's ``u [R, Fe]``, float32."""
+    return jnp.square(jax.nn.relu(u.astype(F32)))
+
+
+# an expert's form, by the name a family gives :func:`held_experts_part`:
+# what stands between its two grouped matmuls. ``w_in`` is ``[X, E, 2
+# Fe]`` (gate ; up) for a gated form and ``[X, E, Fe]`` for an ungated one
+EXPERT_FORMS = {"swiglu": swiglu, "relu2": relu2}
+
+
 @scoped("moe_experts")
-def _experts(xs, group_sizes, ex):
-    """SwiGLU of each row's expert: a grouped matmul that visits only the
-    rows inside the groups, in the form :func:`matmul_form` gives its
-    shapes (counted once a traced call site in
-    ``serve_moe_expert_matmul_sites_total``). Rows past the groups come
-    back as whatever the kernel left there."""
+def _experts(xs, group_sizes, ex, act: str = "swiglu"):
+    """Each row's expert (``act``: :data:`EXPERT_FORMS`): a grouped
+    matmul that visits only the rows inside the groups, in the form
+    :func:`matmul_form` gives its shapes (counted once a traced call site
+    in ``serve_moe_expert_matmul_sites_total``). Rows past the groups
+    come back as whatever the kernel left there."""
     dt = xs.dtype
     R = xs.shape[0]
     form = matmul_form(R)
@@ -158,12 +205,10 @@ def _experts(xs, group_sizes, ex):
         help="held-experts grouped matmuls traced into a program, by the "
              "form their static shapes chose (tiled: the small-tile "
              "Pallas kernel; ragged_dot: the compiler's) and the rows "
-             "of their buffer",
-        labels={"form": form, "rows": str(R)}).inc()
+             "of their buffer, and the experts' own form (act)",
+        labels={"form": form, "rows": str(R), "act": act}).inc()
     matmul = grouped_matmul if form == "tiled" else jax.lax.ragged_dot
-    gu = matmul(xs, ex["w_in"].astype(dt), group_sizes)
-    Fe = gu.shape[-1] // 2
-    h = jax.nn.silu(gu[:, :Fe].astype(F32)) * gu[:, Fe:].astype(F32)
+    h = EXPERT_FORMS[act](matmul(xs, ex["w_in"].astype(dt), group_sizes))
     return matmul(h.astype(dt), ex["w_out"].astype(dt), group_sizes)
 
 
@@ -224,12 +269,13 @@ def expected_rows(T: int, k: int, share: float) -> int:
 
 
 def held_experts_part(u, order, where, held, weights, group_sizes, ex,
-                      fast=None):
+                      fast=None, act: str = "swiglu"):
     """The held real experts' part of the layer, ``[T, E]`` float32: over
     the first ``fast`` sorted picks (:func:`fast_rows` unless the family
     knows its share better) when all the landed ones are among them,
     else over all ``T k``. Exact either way. ``ex``: ``w_in [X, E, 2
-    Fe]`` (gate ; up) and ``w_out [X, Fe, E]``."""
+    Fe]`` (gate ; up; ``[X, E, Fe]`` under an ungated ``act``) and
+    ``w_out [X, Fe, E]``."""
     T, k = weights.shape
 
     def over(rows):
@@ -237,7 +283,8 @@ def held_experts_part(u, order, where, held, weights, group_sizes, ex,
                    else _combine_gathered)
 
         def run():
-            out = _experts(_gather_rows(u, order, k, rows), group_sizes, ex)
+            out = _experts(_gather_rows(u, order, k, rows), group_sizes, ex,
+                           act)
             return combine(out, where, held, weights)
         return run
     fast = min(fast or fast_rows(T, k), T * k)

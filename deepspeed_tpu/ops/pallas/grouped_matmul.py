@@ -35,6 +35,15 @@ to choose: a ``w`` that fits its VMEM budget whole (Laguna's ``w_out``,
 64 MiB of a v5e's 128) it copies there ahead of the call, under the
 program's earlier instructions, and the kernel's block copies then
 never touch HBM.
+
+An ``N`` that is no multiple of the 128 lanes is ONE block (a toy
+width) or refused (:func:`column_tile`): a width such as 1856 (14.5 x
+128) is served STORED rounded up to whole lanes, zeros past the width
+(``nemotron_h.expert_stored_width``; an ungated ``relu(.)^2`` keeps
+them zero). Tiles cannot cure what the storage causes: the chip lays a
+``w [64, 2688, 1856]`` out with the 2688 minor, the call wants it
+row-major, and the program then copies the whole 638 MB array before
+every call (compiled for a described v5e: PERF.md section 6, PR 58).
 """
 from __future__ import annotations
 
@@ -77,9 +86,18 @@ def row_tile(R: int, X: int, K: int, itemsize: int) -> int:
 
 def column_tile(K: int, N: int, itemsize: int) -> int:
     """The widest tile of whole lanes that divides ``N`` with a weight
-    block ``[K, tn]`` of at most :data:`WEIGHT_BLOCK_BYTES` (all of
-    ``N`` when it is no multiple of the lanes)."""
+    block ``[K, tn]`` of at most :data:`WEIGHT_BLOCK_BYTES`. An ``N``
+    that is no multiple of the lanes is one block if that fits the
+    budget (a toy width) and refused if not: its weights are to be
+    stored padded to whole lanes."""
     if N % LANES:
+        if K * N * itemsize > WEIGHT_BLOCK_BYTES:
+            raise ValueError(
+                f"grouped_matmul: N = {N} is no multiple of {LANES} and a "
+                f"[{K}, {N}] weight block is over {WEIGHT_BLOCK_BYTES} "
+                "bytes: store the weights padded to whole lanes (zero "
+                "columns of an up projection, zero rows of the down "
+                "projection that contracts over them)")
         return N
     fit = [tn for tn in range(LANES, N + 1, LANES)
            if N % tn == 0 and K * tn * itemsize <= WEIGHT_BLOCK_BYTES]

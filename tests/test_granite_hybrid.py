@@ -492,8 +492,8 @@ def test_the_pool_and_the_config_refuse_what_they_cannot_be():
         _cfg(layer_types=LAYERS[:3])
     with pytest.raises(ValueError, match="experts_held"):
         _cfg(experts_held=(8, 20))
-    with pytest.raises(NotImplementedError, match="mamba_n_groups"):
-        _cfg(mamba_n_groups=2)
+    with pytest.raises(ValueError, match="mamba_n_groups"):
+        _cfg(mamba_n_groups=3)      # 8 heads: 1, 2, 4 and 8 groups run
     with pytest.raises(ValueError, match="mamba_expand"):
         _cfg(mamba_n_heads=4)
 

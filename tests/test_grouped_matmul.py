@@ -190,3 +190,71 @@ def test_both_forms_give_the_layer_the_same_rows():
     assert sites == {("ragged_dot", "15"), ("tiled", "32")}
     np.testing.assert_allclose(np.asarray(padded[:12]),
                                np.asarray(small[:12]), rtol=1e-4, atol=1e-4)
+
+
+# ------------------------- widths that are no multiple of the 128 lanes
+
+# name: (E, the expert's width, the width as stored): an up projection
+# ``[E, stored]`` whose columns past the width are zeros and the down
+# projection ``[stored, E]`` that contracts over them, and a toy width
+# stored as it is (one block)
+STORED_WIDTHS = {
+    "half-a-lane-over": (192, 464, 512),
+    "one-column-over": (256, 257, 384),
+    "toy-width-one-block": (200, 72, 72),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(STORED_WIDTHS))
+def test_an_expert_stored_padded_to_whole_lanes_is_the_expert(case, dtype):
+    """An ungated expert whose width is no multiple of 128, stored padded
+    with zeros to whole lanes, through the kernel (interpret mode)
+    against ``ragged_dot`` over the weights at their own width: the
+    padding's columns come out zero, ``relu(0)^2`` keeps them zero and
+    the down projection's zero rows add nothing."""
+    E, F, stored = STORED_WIDTHS[case]
+    sizes = [30, 0, 35, 17, 9, 0, 20, 5]
+    R, X = 128, len(sizes)
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(E + F), 3)
+    xs = jax.random.normal(k1, (R, E), dtype)
+    up = (jax.random.normal(k2, (X, E, F), jnp.float32) / np.sqrt(E)
+          ).astype(dtype)
+    down = (jax.random.normal(k3, (X, F, E), jnp.float32) / np.sqrt(F)
+            ).astype(dtype)
+    gs = jnp.asarray(sizes, jnp.int32)
+    pad = stored - F
+
+    def expert(mm, up, down):
+        h = jnp.square(jax.nn.relu(mm(xs, up, gs).astype(jnp.float32)))
+        return h, mm(h.astype(dtype), down, gs)
+    h, got = expert(gm.grouped_matmul,
+                    jnp.pad(up, ((0, 0), (0, 0), (0, pad))),
+                    jnp.pad(down, ((0, 0), (0, pad), (0, 0))))
+    _, want = expert(jax.lax.ragged_dot, up, down)
+    landed = sum(sizes)
+    assert h.shape == (R, stored) and got.shape == (R, E)
+    assert not np.asarray(h[:landed, F:]).any()
+    tol = 3e-2 if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(np.asarray(got[:landed], np.float32),
+                               np.asarray(want[:landed], np.float32),
+                               rtol=tol, atol=tol)
+
+
+def test_a_width_that_is_no_multiple_of_the_lanes_is_one_block_or_refused():
+    """``[2688, 1856]`` (an up projection 14.5 x 128 wide) would be one
+    9.98 MB block, two in flight 20 MB against 16 MiB of scoped VMEM: it
+    is refused, and the message says how to store it; stored 1920 wide it
+    tiles in whole lanes inside the 4 MiB budget, as the down projection
+    that contracts over 1920 does. A toy width stays one block."""
+    with pytest.raises(ValueError, match="padded to whole lanes"):
+        gm.column_tile(2688, 1856, 2)
+    assert gm.column_tile(2688, 1920, 2) == 640
+    assert gm.column_tile(1920, 2688, 2) == 896
+    for Kk, Nn in ((2688, 1920), (1920, 2688)):
+        tn = gm.column_tile(Kk, Nn, 2)
+        assert tn % gm.LANES == 0 and Kk * tn * 2 <= gm.WEIGHT_BLOCK_BYTES
+        assert Nn // tn == 3
+    assert gm.column_tile(32, 48, 4) == 48
+    assert gm.row_tile(1024, 64, 2688, 2) == 16       # 12 rows a group

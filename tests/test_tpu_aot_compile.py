@@ -258,7 +258,20 @@ def _grouped_matmul(R, X, E, Fe):
                  ((X, E, 2 * Fe), BF16), ((X, Fe, E), BF16)]
 
 
+def _grouped_matmul_ungated(R, X, E, Fe):
+    """The same kernel under UNGATED relu² experts (``w_in [X, E, Fe]``)
+    ``Fe`` wide: 1920, as the Nemotron-H family stores its 1856."""
+    def fwd(xs, sizes, w_in, w_out):
+        u = gm.grouped_matmul(xs, w_in, sizes).astype(jnp.float32)
+        h = jnp.square(jax.nn.relu(u))
+        return gm.grouped_matmul(h.astype(xs.dtype), w_out, sizes)
+    return fwd, [((R, E), BF16), ((X,), jnp.int32),
+                 ((X, E, Fe), BF16), ((X, Fe, E), BF16)]
+
+
 CASES = {
+    "grouped-matmul-nemotron-decode": functools.partial(
+        _grouped_matmul_ungated, 1024, 64, 2688, 1920),
     "grouped-matmul-granite-decode": functools.partial(
         _grouped_matmul, 640, 36, 4096, 768),
     "grouped-matmul-laguna-decode": functools.partial(
@@ -1135,6 +1148,82 @@ def test_state_layers_update_in_place_beside_the_block_pool(
         entry, lambda dims, nbytes: dims in stores or nbytes >= state_bytes,
         kernels=kernels) if not any(op in c for op in _IN_PLACE)]
     # every state layer's buffer comes back as the buffer it came in
+    assert compiled.memory_analysis().alias_size_in_bytes >= sum(
+        math.prod(a.shape) * a.dtype.itemsize
+        for a in cache.state + cache.conv + (cache.k, cache.v))
+    if kind == "decode":
+        assert compiled.memory_analysis().temp_size_in_bytes < \
+            state_bytes // 10
+
+
+@pytest.mark.parametrize("kind,kernel,scope", [
+    ("decode", "paged_decode_attention", "mamba_state"),
+    ("prefill", "flash_attention_fwd", "mamba_scan")])
+def test_one_mixer_a_layer_serves_without_a_copy_of_a_weight_or_a_state(
+        chips, monkeypatch, kind, kernel, scope):
+    """The Nemotron-H family's two serving programs at the published
+    widths and the cell's slots, pool and span (pattern ``ME*EM``, 16
+    held experts, too many for the compiler to park a layer's weights in
+    VMEM ahead of the call, and a small vocabulary), read back from their
+    compiled text: ONE kernel call for the one attention layer; the pool holds
+    that ONE layer's rows (the expert layers keep nothing); the state
+    update over 8 B/C groups happens in the donated pool; and NO
+    instruction copies an expert's weights: stored 1920 wide they are
+    row-major as the chip lays them out (stored 1856 wide the chip's own
+    layout puts the 2688 minor and every call copied ``w_in``)."""
+    from deepspeed_tpu.inference.kv_cache import init_paged_cache
+    from deepspeed_tpu.inference.server import ContinuousBatchingServer as Srv
+    from deepspeed_tpu.model_implementations import nemotron_h as nh
+    from deepspeed_tpu.telemetry import compile_watch
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(fa, "_should_interpret", lambda: False)
+    monkeypatch.setattr(gm, "_should_interpret", lambda: False)
+    one = SingleDeviceSharding(chips[0])
+    slots, blocks, span, prompt = 256, 1 + 2048, 80, 1024
+    cfg = nh.NemotronHConfig(vocab_size=2048, hybrid_override_pattern="ME*EM",
+                             num_hidden_layers=5, experts_held=(0, 16))
+    assert cfg.state_shapes == ((64, 64, 128), (3, 6144))
+    assert (cfg.n_head, cfg.kv_heads, cfg.head_dim) == (32, 2, 128)
+    abstract = functools.partial(_abstract, sharding=one)
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    params = abstract(jax.eval_shape(
+        lambda: nh.init_params(jax.random.PRNGKey(0), cfg)))
+    experts = params["layers"][1]["moe"]["experts"]
+    assert experts["w_in"].shape == (16, 2688, 1920)
+    cache = abstract(jax.eval_shape(lambda: init_paged_cache(
+        cfg.n_layer, slots, blocks, BS, span, cfg.kv_heads, cfg.head_dim,
+        BF16, aux_shape=cfg.aux_shape, state_layers=cfg.state_layers,
+        state_shapes=cfg.state_shapes, state_dtype=cfg.state_dtype,
+        cacheless_layers=cfg.cacheless_layers)))
+    assert cache.k.shape == (1, blocks, BS, 256)
+    assert [a.shape for a in cache.state] == [(slots, 64, 64, 128)] * 2
+    fn, name, args = {
+        "decode": (Srv._decode_fn, "serve_decode",
+                   (params, arr((slots,)), cache, arr((slots,), jnp.bool_))),
+        "prefill": (Srv._prefill_fn, "serve_prefill",
+                    (params, arr((1, prompt)), arr((1,)), cache, arr(()))),
+    }[kind]
+    da._paged_call.cache_clear()
+    compiled = jax.jit(compile_watch._named(
+        functools.partial(fn, cfg=cfg, mesh=None), name),
+        donate_argnames=("cache",)).lower(*args).compile()
+    text = compiled.as_text()
+    scopes, kernels = compile_watch.parse_scopes(text)
+    assert list(kernels.values()).count(gm.NAME) >= 4    # two layers x two
+    ours = _without_grouped_matmuls(kernels, scopes)
+    assert list(ours.values()) == [kernel]
+    words = {w for v in scopes.values() if v for w in v.split("/")}
+    assert words >= GRANITE_SCOPES | {scope}
+    state_bytes = math.prod(cache.state[0].shape) * 4
+    weight_bytes = math.prod(experts["w_in"].shape) * 2
+    stores = (cache.state[0].shape, cache.k.shape,
+              experts["w_in"].shape, experts["w_out"].shape)
+    entry = text[text.index("\nENTRY "):]
+    assert not [c for c in _copies(
+        entry, lambda dims, nbytes: dims in stores or nbytes >= weight_bytes,
+        kernels=kernels) if not any(op in c for op in _IN_PLACE)]
     assert compiled.memory_analysis().alias_size_in_bytes >= sum(
         math.prod(a.shape) * a.dtype.itemsize
         for a in cache.state + cache.conv + (cache.k, cache.v))
