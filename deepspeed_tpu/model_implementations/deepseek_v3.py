@@ -450,14 +450,13 @@ def moe_layer(u, moe, cfg: DeepseekV3Config, valid):
     picks, weights = _route(u, moe, cfg)
     order, where, held, group_sizes = _held.sort_picks(picks, valid,
                                                        cfg.experts_held)
-    m = (_held.held_experts_part(u, order, where, held, weights,
-                                 group_sizes, moe["experts"],
-                                 fast=_held.expected_rows(
-                                     u.shape[0], cfg.num_experts_per_tok,
-                                     cfg.num_held / cfg.n_routed_experts))
-         + _shared_expert(u, moe["shared"]).astype(F32)).astype(u.dtype)
+    m, walked = _held.held_experts_part(
+        u, order, where, held, weights, group_sizes, moe["experts"],
+        fast=_held.expected_rows(u.shape[0], cfg.num_experts_per_tok,
+                                 cfg.num_held / cfg.n_routed_experts))
+    m = (m + _shared_expert(u, moe["shared"]).astype(F32)).astype(u.dtype)
     counts = _held.routing_counts(picks, held, group_sizes, valid,
-                                  cfg.n_routed_experts)
+                                  cfg.n_routed_experts, walked)
     return m, counts
 
 

@@ -427,11 +427,12 @@ def moe_layer(u, moe, cfg: "GraniteHybridConfig", valid):
     order, where, held, group_sizes = _held.sort_picks(picks, valid,
                                                        cfg.experts_held)
     fast = _expert_rows(u.shape[0], cfg)
-    m = (_held.held_experts_part(u, order, where, held, weights,
-                                 group_sizes, moe["experts"], fast=fast)
-         + _shared_mlp(u, moe["shared"]).astype(F32)).astype(u.dtype)
+    m, walked = _held.held_experts_part(u, order, where, held, weights,
+                                        group_sizes, moe["experts"],
+                                        fast=fast)
+    m = (m + _shared_mlp(u, moe["shared"]).astype(F32)).astype(u.dtype)
     return m, _held.routing_counts(picks, held, group_sizes, valid,
-                                   cfg.num_local_experts)
+                                   cfg.num_local_experts, walked)
 
 
 def _ffn(x, layer, cfg: "GraniteHybridConfig", valid, counts):

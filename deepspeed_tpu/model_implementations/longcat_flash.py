@@ -426,11 +426,11 @@ def moe_layer(u, moe, cfg: LongcatFlashConfig, valid):
     picks, weights = _route(u, moe, cfg)
     order, where, held, group_sizes = _held.sort_picks(picks, valid,
                                                        cfg.experts_held)
-    m = (_held.held_experts_part(u, order, where, held, weights,
-                                 group_sizes, moe["experts"])
-         + _identity_part(u, picks, weights, cfg)).astype(u.dtype)
+    m, walked = _held.held_experts_part(u, order, where, held, weights,
+                                        group_sizes, moe["experts"])
+    m = (m + _identity_part(u, picks, weights, cfg)).astype(u.dtype)
     return m, _held.routing_counts(picks, held, group_sizes, valid,
-                                   cfg.n_routed_experts)
+                                   cfg.n_routed_experts, walked)
 
 
 # ------------------------------------------------------------------ block

@@ -524,11 +524,11 @@ def moe_layer(u, moe, cfg: MiMoV2Config, valid):
     picks, weights = _route(u, moe, cfg)
     order, where, held, group_sizes = _held.sort_picks(picks, valid,
                                                        cfg.experts_held)
-    m = _held.held_experts_part(u, order, where, held, weights,
-                                group_sizes, moe["experts"],
-                                fast=_expert_rows(u.shape[0], cfg))
+    m, walked = _held.held_experts_part(u, order, where, held, weights,
+                                        group_sizes, moe["experts"],
+                                        fast=_expert_rows(u.shape[0], cfg))
     return m.astype(u.dtype), _held.routing_counts(
-        picks, held, group_sizes, valid, cfg.n_routed_experts)
+        picks, held, group_sizes, valid, cfg.n_routed_experts, walked)
 
 
 # ------------------------------------------------------------------ block

@@ -448,12 +448,12 @@ def moe_layer(u, moe, cfg: "NemotronHConfig", valid):
     picks, weights = _route(u, moe, cfg)
     order, where, held, group_sizes = _held.sort_picks(picks, valid,
                                                        cfg.experts_held)
-    m = (_held.held_experts_part(
-            u, order, where, held, weights, group_sizes, moe["experts"],
-            fast=_expert_rows(u.shape[0], cfg), act="relu2")
-         + _shared_expert(u, moe["shared"]).astype(F32)).astype(u.dtype)
+    m, walked = _held.held_experts_part(
+        u, order, where, held, weights, group_sizes, moe["experts"],
+        fast=_expert_rows(u.shape[0], cfg), act="relu2")
+    m = (m + _shared_expert(u, moe["shared"]).astype(F32)).astype(u.dtype)
     return m, _held.routing_counts(picks, held, group_sizes, valid,
-                                   cfg.n_routed_experts)
+                                   cfg.n_routed_experts, walked)
 
 
 # ------------------------------------------------------------------ block

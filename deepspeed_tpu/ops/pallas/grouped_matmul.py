@@ -1,6 +1,9 @@
-"""A grouped matmul for SHORT groups: ``xs [R, K]`` sorted by group
-against ``w [X, K, N]``, row ``r`` of group ``g`` times ``w[g]``, with
-``jax.lax.ragged_dot``'s meaning for the rows inside the groups.
+"""A grouped matmul for SHORT groups: ``xs [R, K]`` against ``w [X, K,
+N]``, every group's rows starting on a row-tile boundary of the buffer
+(row ``astart[g] + r`` times ``w[g]`` for ``r < group_sizes[g]``,
+``astart`` the exclusive cumulative sum of the sizes rounded up to whole
+tiles: :func:`aligned_starts`), with ``jax.lax.ragged_dot``'s meaning for
+the rows inside the groups.
 
 The compiler's own ``ragged_dot`` kernel tiles its rows by the largest
 power of two up to 512 that divides ``R`` (128 at 640 rows, 256 at 768)
@@ -8,23 +11,31 @@ and computes a whole tile for every group that touches it, in weight
 blocks of ``[512, 512]``: a decode step's buffer (36 groups of ~13 rows
 in 640) pays for 128 rows a group and a thousand grid steps a call, and
 ran at 45-60 % of the weight read that should bound it. This kernel
-walks (group, row tile) pairs with a row tile no larger than the groups
-it is given (megablox's walk,
-``jax.experimental.pallas.ops.tpu.megablox``) and whole-``K`` weight
-blocks of megabytes; on a v5e it streams a decode step's expert weights
-at ~675 GB/s of the chip's 819 (PERF.md section 6, PR 52):
+walks the buffer's row tiles with a tile sized to the groups it is given
+(:func:`row_tile`) and whole-``K`` weight blocks of megabytes:
 
-* the work list comes from ``group_sizes`` by scalar prefetch, at most
-  ``X + ceil(R / tm) - 1`` items; an empty group gets none, so its
+* a row tile belongs to ONE group (the layout's doing: the caller lays
+  the groups out on tile boundaries, ``held_experts._align``), so an item
+  of the walk is a tile, a hit expert's weight block goes through the MXU
+  ``ceil(n_g / tm)`` times, and the kernel is a dot and a store. (A walk
+  over groups packed end to end, megablox's, computes a tile once for
+  every group that straddles it: 1.4-1.7 passes a weight block at 12-14
+  rows a group, each pass MXU time that the one-item lookahead of the
+  pipeline cannot hide under the next block's fetch: -12 to -14 % of a
+  call at 64 groups in 1024 rows, PERF.md section 6, PR 59.)
+* the tiles' groups come from ``group_sizes`` by scalar prefetch
+  (:func:`work_items`): ``R / tm`` items, of which the first ``n`` (the
+  tiles the groups reach) do anything; an empty group gets none, so its
   weights are never fetched;
 * ``K`` is whole in a block (no accumulator across grid steps) and the
   column tile ``tn`` is as wide as :data:`WEIGHT_BLOCK_BYTES` lets it
   be; the grid is (column tile, item) with the items innermost, so the
-  items of one group keep one weight block (no second copy) and every
+  tiles of one group keep one weight block (no second copy) and every
   ``(group, column tile)`` block crosses HBM once a call; the row tile
   is read again for each column tile, which is kilobytes;
-* rows of a tile that belong to a neighbouring group are masked on
-  store; rows past the last group are never written;
+* rows of a group's last tile past its size are computed from whatever
+  the caller put there (a finite row) and are the caller's to mask;
+  tiles past the last group are never written;
 * float32 sums, the output in the rows' dtype.
 
 Two weight buffers of at most 4 MiB and the row and output tiles stay
@@ -48,7 +59,6 @@ every call (compiled for a described v5e: PERF.md section 6, PR 58).
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -72,12 +82,18 @@ def _should_interpret() -> bool:
 
 
 def row_tile(R: int, X: int, K: int, itemsize: int) -> int:
-    """The smallest power of two from :data:`MIN_ROW_TILE` that is not
-    below the mean group (``R / X`` rounded up), held to
-    :data:`MAX_ROW_TILE` and to a row block of
-    :data:`ROW_BLOCK_BYTES`."""
+    """The smallest power of two from :data:`MIN_ROW_TILE` ABOVE the mean
+    group of ``R`` rows packed end to end (``R / X``), held to
+    :data:`MAX_ROW_TILE` and to a row block of :data:`ROW_BLOCK_BYTES`.
+    A tile belongs to one group, so a group longer than the tile pays one
+    more pass of its weight block through the MXU, and groups are uneven
+    (the busiest near twice the mean): a tile AT the mean is outgrown by
+    the busy half of the groups (16 against 32 at 64 groups in 1024 rows:
+    1.22 passes a hit expert against 1.0-1.02 and -7.5 % against -12 % of
+    the packed walk's time; a tile twice as wide again only pads: PERF.md
+    section 7, PR 59)."""
     tm = MIN_ROW_TILE
-    while tm < min(math.ceil(R / X), MAX_ROW_TILE):
+    while tm * X <= R and tm < MAX_ROW_TILE:
         tm *= 2
     while tm > MIN_ROW_TILE and tm * K * itemsize > ROW_BLOCK_BYTES:
         tm //= 2
@@ -104,58 +120,64 @@ def column_tile(K: int, N: int, itemsize: int) -> int:
     return max(fit, default=LANES)
 
 
-def work_items(group_sizes, R: int, tm: int):
-    """The walk: ``(group [I], tile [I], start [X], end [X], n [1])``
-    int32, ``I = X + ceil(R / tm) - 1``. Item ``i < n`` is row tile
-    ``tile[i]`` of group ``group[i]``, whose rows are ``start .. end``;
-    groups in order and a group's tiles in order, empty groups left out.
-    Items from ``n`` on repeat the last one, so that they move nothing."""
+def aligned_rows(R: int, X: int, tm: int) -> int:
+    """Rows of the buffer that holds any ``R`` rows in ``X`` groups laid
+    out on boundaries of ``tm``: a group that is not empty wastes at most
+    ``tm - 1`` rows of its last tile, in whole tiles."""
+    return (R + min(X, R) * (tm - 1)) // tm * tm
+
+
+def aligned_starts(group_sizes, tm: int):
+    """``(astart [X], aend [X])`` int32: the row each group starts on
+    and the row after its last tile, every group given whole tiles of
+    ``tm`` (an empty group none)."""
+    padded = pl.cdiv(group_sizes.astype(jnp.int32), tm) * tm
+    aend = jnp.cumsum(padded)
+    return aend - padded, aend
+
+
+def work_items(group_sizes, tiles: int, tm: int):
+    """The walk: ``(group [tiles], n [1])`` int32. Item ``i < n`` is row
+    tile ``i`` of the buffer and belongs to ``group[i]``; ``n`` is the
+    tiles the groups reach (``ceil(size / tm)`` a group, groups in
+    order). Items from ``n`` on repeat the last one, so that they move
+    nothing."""
     X = group_sizes.shape[0]
-    items = X + pl.cdiv(R, tm) - 1
-    end = jnp.minimum(jnp.cumsum(group_sizes.astype(jnp.int32)), R)
-    start = jnp.concatenate([jnp.zeros(1, jnp.int32), end[:-1]])
-    first = start // tm
-    tiles = jnp.where(end > start, (end - 1) // tm - first + 1, 0)
-    item_end = jnp.cumsum(tiles)
-    n = item_end[-1]
-    i = jnp.clip(jnp.arange(items, dtype=jnp.int32), 0, jnp.maximum(n - 1, 0))
-    group = jnp.minimum(jnp.sum(item_end[None] <= i[:, None], axis=1,
+    tile_end = aligned_starts(group_sizes, tm)[1] // tm
+    n = jnp.minimum(tile_end[-1], tiles)
+    i = jnp.clip(jnp.arange(tiles, dtype=jnp.int32), 0, jnp.maximum(n - 1, 0))
+    group = jnp.minimum(jnp.sum(tile_end[None] <= i[:, None], axis=1,
                                 dtype=jnp.int32), X - 1)
-    tile = first[group] + i - (item_end[group] - tiles[group])
-    return group, jnp.maximum(tile, 0), start, end, n[None]
+    return group, n[None]
 
 
-def _kernel(group, tile, start, end, n, x_ref, w_ref, o_ref, *, tm: int):
-    i = pl.program_id(1)
-
-    @pl.when(i < n[0])
+def _kernel(group, n, x_ref, w_ref, o_ref):
+    @pl.when(pl.program_id(1) < n[0])
     def _():
-        g = group[i]
-        acc = jnp.dot(x_ref[...], w_ref[...],
-                      preferred_element_type=jnp.float32)
-        row = tile[i] * tm + jax.lax.broadcasted_iota(jnp.int32, acc.shape, 0)
-        mine = (row >= start[g]) & (row < end[g])
-        o_ref[...] = jnp.where(mine, acc, o_ref[...].astype(jnp.float32)
-                               ).astype(o_ref.dtype)
+        o_ref[...] = jnp.dot(x_ref[...], w_ref[...],
+                             preferred_element_type=jnp.float32
+                             ).astype(o_ref.dtype)
 
 
-def _call(R: int, X: int, K: int, N: int, dtype, interpret: bool):
+def _call(R: int, X: int, K: int, N: int, tm: int, dtype, interpret: bool):
     """The ``pallas_call`` of one static signature."""
     itemsize = jnp.dtype(dtype).itemsize
-    tm, tn = row_tile(R, X, K, itemsize), column_tile(K, N, itemsize)
+    tn = column_tile(K, N, itemsize)
+
+    def tile(i, n):         # items past the walk stay on its last tile
+        return jnp.minimum(i, jnp.maximum(n[0] - 1, 0))
     return pl.pallas_call(
-        functools.partial(_kernel, tm=tm),
+        _kernel,
         out_shape=jax.ShapeDtypeStruct((R, N), dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
-            grid=(N // tn, X + pl.cdiv(R, tm) - 1),
+            num_scalar_prefetch=2,
+            grid=(N // tn, R // tm),
             in_specs=[
-                pl.BlockSpec((tm, K), lambda j, i, g, t, *_: (t[i], 0)),
-                pl.BlockSpec((None, K, tn),
-                             lambda j, i, g, t, *_: (g[i], 0, j)),
+                pl.BlockSpec((tm, K), lambda j, i, g, n: (tile(i, n), 0)),
+                pl.BlockSpec((None, K, tn), lambda j, i, g, n: (g[i], 0, j)),
             ],
             out_specs=pl.BlockSpec((tm, tn),
-                                   lambda j, i, g, t, *_: (t[i], j)),
+                                   lambda j, i, g, n: (tile(i, n), j)),
         ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
@@ -168,22 +190,29 @@ def _call(R: int, X: int, K: int, N: int, dtype, interpret: bool):
     )
 
 
-@functools.partial(jax.jit, static_argnames="interpret")
-def _grouped_matmul(xs, w, group_sizes, interpret: bool):
+@functools.partial(jax.jit, static_argnames=("tm", "interpret"))
+def _grouped_matmul(xs, w, group_sizes, tm: int, interpret: bool):
     R, K = xs.shape
     X, _, N = w.shape
     dtype = jnp.dtype(xs.dtype)
-    items = work_items(group_sizes, R, row_tile(R, X, K, dtype.itemsize))
-    return _call(R, X, K, N, dtype, interpret)(*items, xs, w.astype(dtype))
+    if R % tm:
+        raise ValueError(f"grouped_matmul: {R} rows are no whole tiles of "
+                         f"{tm}")
+    items = work_items(group_sizes, R // tm, tm)
+    return _call(R, X, K, N, tm, dtype, interpret)(*items, xs,
+                                                   w.astype(dtype))
 
 
-def grouped_matmul(xs, w, group_sizes):
+def grouped_matmul(xs, w, group_sizes, tm: int):
     """``xs [R, K]`` x ``w [X, K, N]`` by ``group_sizes [X]`` -> ``[R,
-    N]`` in ``xs``'s dtype: the first ``group_sizes[0]`` rows times
-    ``w[0]``, the next ``group_sizes[1]`` times ``w[1]``, ... Rows past
-    the groups come back as whatever was there. A function under ``jit``
-    of its own, so a program whose layers call it with one signature
-    traces and lowers the walk and the kernel once, not once a layer
-    (Laguna's six programs hold 264 call sites: +7 s of set-up
+    N]`` in ``xs``'s dtype, the groups laid out on boundaries of the row
+    tile ``tm`` (:func:`aligned_starts`; ``R`` whole tiles,
+    :func:`aligned_rows` for any ``R`` rows packed end to end): rows
+    ``astart[g] .. astart[g] + group_sizes[g]`` times ``w[g]``. The rest
+    of a group's last tile comes back as the product of whatever was
+    there, tiles past the last group as whatever was there. A function
+    under ``jit`` of its own, so a program whose layers call it with one
+    signature traces and lowers the walk and the kernel once, not once a
+    layer (Laguna's six programs hold 264 call sites: +7 s of set-up
     otherwise)."""
-    return _grouped_matmul(xs, w, group_sizes, _should_interpret())
+    return _grouped_matmul(xs, w, group_sizes, tm, _should_interpret())

@@ -11,12 +11,19 @@ For each geometry: ``w_in [X, E, 2 Fe]`` alone, ``w_out [X, Fe, E]``
 alone and the whole expert layer (both and the SwiGLU), each as a chain
 of ``--chain`` calls under one ``jit``, the best of three; the weights of
 the experts HIT over that time as GB/s; and the largest difference
-between the two forms over the rows inside the groups. One JSON line a
+between the two forms over the rows inside the groups. ``ragged_dot``
+reads the rows packed end to end, the kernel the same rows laid out on
+boundaries of its row tile (``grouped_matmul.aligned_starts``, the layout
+``held_experts._align`` builds); ``row_tiles_walked`` over ``hit`` is the
+times a hit expert's weight block goes through the MXU. ``--aligned-draw``
+replaces the draw by groups of exactly one row tile (one pass a block:
+the floor of that ratio; ISSUE 59's first chip call). One JSON line a
 geometry, all of them in ``chiprun_out/grouped_matmul_micro.jsonl``.
 """
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -28,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deepspeed_tpu.model_implementations.held_experts import expert_row_tile
 from deepspeed_tpu.ops.pallas import grouped_matmul as gm
 
 # name: (rows, held experts, E, Fe, landed picks): the decode programs of
@@ -56,7 +64,23 @@ def group_sizes(rng, X: int, landed: int):
     return rng.multinomial(landed, p).astype(np.int32)
 
 
-FORMS = {"ragged": jax.lax.ragged_dot, "tiled": gm.grouped_matmul}
+def laid_out(packed, sizes, tm: int):
+    """``packed [R, K]`` (the groups end to end) on boundaries of ``tm``
+    in the buffer any ``R`` rows fit, and each group row's place in it."""
+    astart = np.asarray(gm.aligned_starts(jnp.asarray(sizes), tm)[0])
+    at = np.concatenate([a + np.arange(n) for a, n in zip(astart, sizes)])
+    rows = gm.aligned_rows(packed.shape[0], len(sizes), tm)
+    return jnp.zeros((rows, packed.shape[1]), packed.dtype).at[at].set(
+        packed[:at.size]), at
+
+
+def draw(rng, a, R: int, X: int, landed: int, tm: int):
+    """The group sizes of one geometry and the rows they are packed in:
+    the cells' own spread, or under ``--aligned-draw`` every group exactly
+    one row tile."""
+    if a.aligned_draw:
+        return np.full(X, tm, np.int32), max(R, X * tm)
+    return group_sizes(rng, X, landed), R
 
 
 def timed(fn, args, chain: int) -> float:
@@ -84,6 +108,7 @@ def main() -> int:
     ap.add_argument("--only", default="")
     ap.add_argument("--chain", type=int, default=20)
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--aligned-draw", action="store_true")
     a = ap.parse_args()
     geometries = TINY if a.tiny else GEOMETRIES
     if a.only:
@@ -99,33 +124,42 @@ def main() -> int:
     out = open("chiprun_out/grouped_matmul_micro.jsonl", "a")
     rng = np.random.default_rng(a.seed)
     for name, (R, X, E, Fe, landed) in geometries.items():
+        itemsize = jnp.dtype(dtype).itemsize
+        tm = expert_row_tile(R, X, E, Fe, itemsize)
+        sizes, R = draw(rng, a, R, X, landed, tm)
+        landed = int(sizes.sum())
         k1, k2, k3 = jax.random.split(jax.random.PRNGKey(a.seed), 3)
-        xs = jax.random.normal(k1, (R, E), dtype)
-        hs = jax.random.normal(k1, (R, Fe), dtype)
+        packed = (jax.random.normal(k1, (R, E), dtype),
+                  jax.random.normal(k1, (R, Fe), dtype))
+        (xs, at), (hs, _) = (laid_out(p, sizes, tm) for p in packed)
+        operands = {"ragged": packed, "tiled": (xs, hs)}
         w_in = jax.random.normal(k2, (X, E, 2 * Fe), dtype) / np.sqrt(E)
         w_out = jax.random.normal(k3, (X, Fe, E), dtype) / np.sqrt(Fe)
-        sizes = group_sizes(rng, X, landed)
         gs = jnp.asarray(sizes)
         hit = int((sizes > 0).sum())
-        itemsize = jnp.dtype(dtype).itemsize
-        line = {"geometry": name, "rows": R,
+        walked = int((-(-sizes // tm)).sum())
+        line = {"geometry": name, "rows": R, "aligned_rows": xs.shape[0],
                 "experts": X, "E": E, "Fe": Fe, "landed": landed,
                 "hit": hit, "largest_group": int(sizes.max()),
+                "row_tiles_walked": walked,
+                "passes_a_weight_block": round(walked / max(hit, 1), 3),
+                "aligned_draw": a.aligned_draw,
                 "device": device.device_kind,
-                "tiles_in": [gm.row_tile(R, X, E, itemsize),
-                             gm.column_tile(E, 2 * Fe, itemsize)],
-                "tiles_out": [gm.row_tile(R, X, Fe, itemsize),
-                              gm.column_tile(Fe, E, itemsize)]}
+                "tiles_in": [tm, gm.column_tile(E, 2 * Fe, itemsize)],
+                "tiles_out": [tm, gm.column_tile(Fe, E, itemsize)]}
+        forms = {"ragged": jax.lax.ragged_dot,
+                 "tiled": functools.partial(gm.grouped_matmul, tm=tm)}
         outs = {}
-        for label, mm in FORMS.items():
+        for label, mm in forms.items():
+            xs, hs = operands[label]
 
             def layer(xs, w_in, w_out, gs, mm=mm):
                 gu = mm(xs, w_in, gs)
                 h = (jax.nn.silu(gu[:, :Fe].astype(jnp.float32))
                      * gu[:, Fe:].astype(jnp.float32))
                 return mm(h.astype(xs.dtype), w_out, gs)
-            outs[label] = np.asarray(jax.jit(layer)(xs, w_in, w_out, gs)
-                                     [:landed], np.float32)
+            out_ = np.asarray(jax.jit(layer)(xs, w_in, w_out, gs), np.float32)
+            outs[label] = out_[at] if label == "tiled" else out_[:landed]
             ms_in = timed(mm, (xs, w_in, gs), a.chain)
             ms_out = timed(mm, (hs, w_out, gs), a.chain)
             ms = timed(layer, (xs, w_in, w_out, gs), a.chain)
@@ -141,7 +175,7 @@ def main() -> int:
         print(json.dumps(line), flush=True)
         out.write(json.dumps(line) + "\n")
         out.flush()
-        del xs, hs, w_in, w_out
+        del xs, hs, packed, operands, w_in, w_out
     return 0
 
 

@@ -159,7 +159,9 @@ def test_float32_program_matches_the_reference_through_ring_laps(prompt):
     assert aux[0, -2] == 2 * seen.sum()                       # full rows
     assert aux[0, -1] == 4 * np.minimum(seen, WINDOW).sum()   # window rows
     tail = aux[:, 16:16 + len(held_experts.COUNTER_TAIL)]
-    assert tail[0].tolist() == [0, 0, 5 * steps, 5 * steps, tail[0, 4]]
+    # one token's two picks are under a row tile: ``ragged_dot`` walks none
+    assert tail[0].tolist() == [0, 0, 5 * steps, 5 * steps, tail[0, 4],
+                                0]
     assert tail[1, 2] == 5 * prompt and aux[:, :16].sum() == 2 * (
         5 * steps + 5 * prompt)
 

@@ -489,12 +489,26 @@ def test_experts_are_stored_padded_with_zeros_and_the_padding_is_inert():
     # mean reaches no token as a fixed vector
     assert float(jnp.abs(ex["w_out"][:, :24].sum(1)).max()) < 1e-5
     cut = {"w_in": ex["w_in"][..., :24], "w_out": ex["w_out"][:, :24]}
-    xs = jax.random.normal(jax.random.PRNGKey(0), (32, 32))
     gs = jnp.asarray([3, 0, 9, 1, 0, 0, 5, 2, 0, 4, 0, 6], jnp.int32)
+    xs, at = _on_tiles(jax.random.normal(jax.random.PRNGKey(0), (30, 32)),
+                       gs.tolist())
     np.testing.assert_allclose(
-        held_experts._experts(xs, gs, ex, "relu2")[:30],
-        held_experts._experts(xs, gs, cut, "relu2")[:30], rtol=1e-5,
+        held_experts._experts(xs, gs, ex, "relu2", tm=16)[at],
+        held_experts._experts(xs, gs, cut, "relu2", tm=16)[at], rtol=1e-5,
         atol=1e-6)
+
+
+def _on_tiles(packed, sizes, tm=16):
+    """Rows packed end to end by group, laid out as the grouped matmul
+    wants them (every group on a boundary of ``tm``; zeros between), and
+    each packed row's place."""
+    at, row = [], 0
+    for n in sizes:
+        at += range(row, row + n)
+        row += -(-n // tm) * tm
+    at = np.asarray(at)
+    return jnp.zeros((row, packed.shape[1]), packed.dtype).at[at].set(
+        packed), at
 
 
 @pytest.mark.parametrize("act", ["swiglu", "relu2"])
@@ -507,21 +521,22 @@ def test_both_expert_forms_run_through_the_same_grouped_matmuls(act):
     ex = {"w_in": jax.random.normal(k[0], (X, E, wide)) / 8,
           "w_out": jax.random.normal(k[1], (X, Fe, E)) / 7}
     gs = jnp.asarray([5, 0, 11], jnp.int32)
-    xs = jax.random.normal(k[2], (32, E))
+    packed = jax.random.normal(k[2], (16, E))
+    xs, at = _on_tiles(packed, [5, 0, 11])
     reg = MetricRegistry()
     was = set_registry(reg)
     try:
-        got = held_experts._experts(xs, gs, ex, act)
+        got = held_experts._experts(xs, gs, ex, act, tm=16)
     finally:
         set_registry(was)
     want, row = [], 0
     for x, n in enumerate([5, 0, 11]):
-        u = xs[row:row + n] @ ex["w_in"][x]
+        u = packed[row:row + n] @ ex["w_in"][x]
         h = (jax.nn.silu(u[:, :Fe]) * u[:, Fe:] if act == "swiglu"
              else jnp.square(jnp.maximum(u, 0)))
         want.append(h @ ex["w_out"][x])
         row += n
-    np.testing.assert_allclose(got[:16], jnp.concatenate(want), rtol=1e-4,
+    np.testing.assert_allclose(got[at], jnp.concatenate(want), rtol=1e-4,
                                atol=1e-5)
     series = reg.snapshot()["serve_moe_expert_matmul_sites_total"]["series"]
     assert [(s["labels"]["form"], s["labels"]["act"]) for s in series] == [

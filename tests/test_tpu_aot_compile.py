@@ -246,26 +246,41 @@ def _flash_window():
     return fwd, [((1, g["prompt"], g["window_heads"], 128), BF16), kv, kv]
 
 
+def _aligned(R, X, E, Fe):
+    """The row tile an expert's two matmuls share and the buffer ``R``
+    landed picks are laid out in on its boundaries, as
+    ``held_experts._align`` builds it."""
+    from deepspeed_tpu.model_implementations.held_experts import (
+        expert_row_tile)
+    tm = expert_row_tile(R, X, E, Fe, 2)
+    return tm, gm.aligned_rows(R, X, tm)
+
+
 def _grouped_matmul(R, X, E, Fe):
     """The small-tile grouped matmul as the held experts' layer calls it
-    (``w_in``, the SwiGLU, ``w_out``) over a decode program's buffer of
-    ``R`` rows and ``X`` held experts at a cell's full widths."""
+    (``w_in``, the SwiGLU, ``w_out``) over the aligned buffer of a decode
+    program's ``R`` landed picks and ``X`` held experts at a cell's full
+    widths."""
+    tm, rows = _aligned(R, X, E, Fe)
+
     def fwd(xs, sizes, w_in, w_out):
-        gu = gm.grouped_matmul(xs, w_in, sizes).astype(jnp.float32)
+        gu = gm.grouped_matmul(xs, w_in, sizes, tm).astype(jnp.float32)
         h = jax.nn.silu(gu[:, :Fe]) * gu[:, Fe:]
-        return gm.grouped_matmul(h.astype(xs.dtype), w_out, sizes)
-    return fwd, [((R, E), BF16), ((X,), jnp.int32),
+        return gm.grouped_matmul(h.astype(xs.dtype), w_out, sizes, tm)
+    return fwd, [((rows, E), BF16), ((X,), jnp.int32),
                  ((X, E, 2 * Fe), BF16), ((X, Fe, E), BF16)]
 
 
 def _grouped_matmul_ungated(R, X, E, Fe):
     """The same kernel under UNGATED relu² experts (``w_in [X, E, Fe]``)
     ``Fe`` wide: 1920, as the Nemotron-H family stores its 1856."""
+    tm, rows = _aligned(R, X, E, Fe)
+
     def fwd(xs, sizes, w_in, w_out):
-        u = gm.grouped_matmul(xs, w_in, sizes).astype(jnp.float32)
+        u = gm.grouped_matmul(xs, w_in, sizes, tm).astype(jnp.float32)
         h = jnp.square(jax.nn.relu(u))
-        return gm.grouped_matmul(h.astype(xs.dtype), w_out, sizes)
-    return fwd, [((R, E), BF16), ((X,), jnp.int32),
+        return gm.grouped_matmul(h.astype(xs.dtype), w_out, sizes, tm)
+    return fwd, [((rows, E), BF16), ((X,), jnp.int32),
                  ((X, E, Fe), BF16), ((X, Fe, E), BF16)]
 
 
@@ -356,6 +371,14 @@ def test_kernel_compiles_for_v5e(case, chips, monkeypatch):
         assert sorted(kernels.values()) == [gm.NAME] * 2
         assert set(re.findall(r'"scoped_memory_configs":\[([^\]]*)\]',
                               text)) == {""}
+        # the weights go to the kernel as they are stored: no instruction
+        # writes a whole ``w_in`` or ``w_out`` in another layout (a
+        # ``copy-start`` / ``copy-done`` pair is the compiler's prefetch
+        # of Laguna's 64 MiB ``w_out`` into VMEM, as stored)
+        weights = {shape for shape, _ in shapes[2:]}
+        assert not [c for c in _copies(
+            text, lambda dims, nbytes: dims in weights)
+            if not c.endswith(("copy-start", "copy-done"))]
     if case.startswith(("paged_", "latent-")):
         # the pool goes to the kernel as it is stored: nothing as large
         # as one layer of K (one attention's latent pool), and nothing
@@ -1525,10 +1548,18 @@ def test_this_libtpu_knows_the_remat_limit_option(chips):
 # ``laguna-decode`` (c2da6142f4089a74, 9654 lines: its two one-token
 # walks attend two table entries an iteration, a product a head, so a
 # kernel body holds a whole group and a group of one; the three
-# ``gpt2-*`` programs walk an entry an iteration, as they did).
+# ``gpt2-*`` programs walk an entry an iteration, as they did); PR 59
+# replaced both ``laguna-*`` lines with the ones below (they were
+# 90e462daa2cb3975, 21558 lines and 27010f6634f018c7, 7922 lines: the
+# expert layers lay their groups out on row-tile boundaries, the grouped
+# matmul's kernel is a dot and a store, a decode batch is dispatched by a
+# one-hot product, the combine's product takes the weights in three
+# bfloat16 parts and the routing row gained ``row_tiles_walked``; the
+# ``gpt2-*`` programs hold no expert layer and trace to the text they
+# did).
 ACCEPTED_PROGRAMS = {
-    "laguna-decode": ("90e462daa2cb3975", 21558, 9),
-    "laguna-prefill": ("27010f6634f018c7", 7922, 8),
+    "laguna-decode": ("f359b8214c08e792", 21257, 9),
+    "laguna-prefill": ("0637feab3876d315", 7928, 8),
     "gpt2-decode": ("3141ca124377a618", 8987, 24),
     "gpt2-decode-int8": ("3bf89725c010ea29", 12107, 24),
     "gpt2-verify": ("c4007aa33affbfb2", 11630, 24),
